@@ -43,7 +43,18 @@ def main(argv=None) -> int:
         p = mod.add_parser(sub)
         p.set_defaults(func=mod.run)
     args = parser.parse_args(argv)
-    return args.func(args) or 0
+    try:
+        return args.func(args) or 0
+    except RuntimeError as e:
+        # a second process on a one-chip host: say what happened and what
+        # fits, instead of libtpu's traceback about a lockfile
+        from ..utils.device import explain_backend_failure
+
+        message = explain_backend_failure(args.command, e)
+        if message is None:
+            raise
+        print(message, file=sys.stderr)
+        return 3
 
 
 def _unavailable(name: str, e: Exception) -> int:

@@ -3,6 +3,8 @@
 
 from __future__ import annotations
 
+import logging
+
 
 def _probe_engine_factory(spec, cfg):
     """One weight load for ``serve --autotune --measure``; the returned
@@ -380,10 +382,10 @@ def run(args) -> int:
     # when opted in (--log-json or DABT_LOG_JSON=1); no-op otherwise
     setup_json_logging(force=bool(getattr(args, "log_json", False)))
 
-    # point XLA's persistent compilation cache at a stable dir BEFORE any model
-    # loads/warms: a second boot then skips the one-time kernel-compile tax
-    # (~285 s at 1M-corpus KNN scale — VERDICT r5 #6).  DABT_COMPILE_CACHE_DIR
-    # overrides the location; DABT_COMPILE_CACHE_OFF=1 opts out.
+    # turn on XLA's persistent compilation cache BEFORE any model loads/warms:
+    # a second boot then skips the warm-up compiles.  It lives where
+    # JAX_COMPILATION_CACHE_DIR says, else at <checkout>/.cache/xla
+    # (utils/compile_cache.py); DABT_COMPILE_CACHE_OFF=1 opts out.
     enable_persistent_compile_cache()
 
     if args.tiny:
@@ -598,6 +600,11 @@ def run(args) -> int:
         print(_json.dumps({"autotune": results}, indent=2))
         return 0
 
+    # name the device before any weight lands on it: a serve that came up on
+    # the CPU (or on fewer chips than meant) must say so in its first line
+    from ..utils.device import device_info, device_line
+
+    logging.getLogger(__name__).info("serving on %s", device_line(device_info()))
     registry = ModelRegistry.from_config(config)
     # cross-process fleet plane (serving/fleet.py; docs/FLEET.md): attach it
     # HERE so create_app reuses the CLI-configured identity/pool/peer list

@@ -25,6 +25,13 @@ def add_parser(sub):
 
 
 def run(args) -> int:
+    # take (or be refused) the device BEFORE leasing any task: tasks run on
+    # worker threads, where a backend that cannot come up — another process
+    # holds the chip — would surface as retried task failures, not as the
+    # one clear error cli/main.py prints for it
+    from ..utils.device import device_info, device_line
+
+    logger.info("worker on %s", device_line(device_info()))
     # register all task modules
     from ..bot import tasks as bot_tasks  # noqa: F401
     from ..processing import signals, tasks as processing_tasks  # noqa: F401

@@ -14,6 +14,11 @@ with zero egress.
 
 Weight VALUES are random (generation quality is meaningless); every code path
 is the production one.
+
+:func:`synth_native` is the full-width sibling: seeded random weights at a
+published config's own widths, drawn on the device and saved in the native
+checkpoint layout ``ModelSpec.checkpoint`` loads (``chip_smoke.py`` serves a
+7B-class model from one).
 """
 
 from __future__ import annotations
@@ -147,3 +152,49 @@ def synth_encoder(
     model.save_pretrained(out_dir, safe_serialization=True)
     fast.save_pretrained(out_dir)
     return out_dir
+
+
+def synth_native(
+    out_dir: str, kind: str, cfg, *, seed: int = 0, int8: bool = False
+) -> bool:
+    """Write seeded random weights at ``cfg``'s own widths as a NATIVE
+    checkpoint (``checkpoint.py`` layout) — what ``ModelSpec.checkpoint``
+    loads — drawing them on the device instead of through torch on the host,
+    so a 7B-class model takes seconds, not a 16 GB host init.
+
+    ``int8`` (decoders) draws the layer projections directly as int8
+    ``QTensor`` s (:func:`..models.llama.init_int8`), the layout a
+    ``quantize = "int8"`` spec serves as-is.  No tokenizer is written: the
+    serving plane's byte tokenizer covers it.
+
+    Returns False, writing nothing, when ``out_dir`` already holds a
+    checkpoint stamped with the same kind, config, seed and format.
+    """
+    import json
+
+    import jax
+
+    from ..checkpoint import _config_to_dict, read_manifest, save_model
+    from . import encoder, llama
+
+    stamp = {"seed": int(seed), "int8": bool(int8)}
+    if os.path.exists(os.path.join(out_dir, "manifest.json")):
+        meta = read_manifest(out_dir)["meta"]
+        # the manifest's config went through JSON (tuples became lists)
+        want = json.loads(json.dumps(_config_to_dict(cfg)))
+        if (
+            meta.get("kind") == kind
+            and meta.get("config") == want
+            and meta.get("synth") == stamp
+        ):
+            return False
+    rng = jax.random.key(seed)
+    if kind == "encoder":
+        params = encoder.init(cfg, rng)
+    elif int8:
+        params = llama.init_int8(cfg, rng)
+    else:
+        params = llama.init(cfg, rng)
+    os.makedirs(os.path.dirname(os.path.abspath(out_dir)), exist_ok=True)
+    save_model(out_dir, kind, cfg, params, meta={"synth": stamp})
+    return True

@@ -1,4 +1,8 @@
-"""Lazy g++ build of native libraries, cached by source hash."""
+"""Lazy g++ build of native libraries from the committed sources.
+
+Libraries land in :data:`BUILD_DIR` (``<checkout>/.cache/native``, git-ignored)
+under a name carrying the source hash, so an edited ``.cpp`` rebuilds and no
+prebuilt ``.so`` is ever committed."""
 
 from __future__ import annotations
 
@@ -13,6 +17,7 @@ from typing import Optional
 logger = logging.getLogger(__name__)
 
 _SRC_DIR = os.path.dirname(os.path.abspath(__file__))
+BUILD_DIR = os.path.join(os.path.dirname(os.path.dirname(_SRC_DIR)), ".cache", "native")
 _lock = threading.Lock()
 _cache: dict[str, Optional[str]] = {}
 
@@ -37,12 +42,8 @@ def _build(source_name: str) -> Optional[str]:
         return None
     with open(src, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    cache_dir = os.environ.get(
-        "DABT_NATIVE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "dabt_native"),
-    )
-    os.makedirs(cache_dir, exist_ok=True)
-    out = os.path.join(cache_dir, f"lib{source_name}-{digest}.so")
+    os.makedirs(BUILD_DIR, exist_ok=True)
+    out = os.path.join(BUILD_DIR, f"lib{source_name}-{digest}.so")
     if os.path.exists(out):
         return out
     cmd = [gxx, "-O2", "-shared", "-fPIC", "-std=c++17", src, "-o", out]

@@ -623,6 +623,48 @@ def flash_attention(
     return out.reshape(B, H, Sq, D)
 
 
+def sharded_flash_attention(
+    q: jnp.ndarray,  # [B, H, Sq, D]
+    k: jnp.ndarray,  # [B, H, Sk, D]
+    v: jnp.ndarray,
+    mesh,
+    *,
+    causal: bool = False,
+    window: Optional[int] = None,
+) -> jnp.ndarray:
+    """:func:`flash_attention` under a multi-device mesh.
+
+    Mosaic kernels cannot be partitioned by the SPMD partitioner ("Mosaic
+    kernels cannot be automatically partitioned"), so the call is wrapped in
+    a ``shard_map``: batch over ``data`` and heads over ``model`` — the layout
+    the projections already produce — and every device runs the kernel on its
+    own [B/d, H/m, S, D] block with no collective.  A dim that does not
+    divide its axis (a one-row prefill on a data-parallel mesh) stays whole
+    on every device of that axis: duplicated work, same result, still the
+    kernel.  Sequence and head_dim are never split here.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+    B, H = q.shape[0], q.shape[1]
+    spec = P(
+        DATA_AXIS if B % mesh.shape[DATA_AXIS] == 0 else None,
+        MODEL_AXIS if H % mesh.shape[MODEL_AXIS] == 0 else None,
+        None,
+        None,
+    )
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal, window=window),
+        mesh=mesh,
+        in_specs=(spec, spec, spec),
+        out_specs=spec,
+        # a dim left whole is computed identically on every device of its
+        # axis, which the static replication check cannot prove
+        check_vma=False,
+    )(q, k, v)
+
+
 def attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -635,15 +677,19 @@ def attention(
 ) -> jnp.ndarray:
     """Dispatch: pallas flash kernel on TPU for long un-masked sequences, jnp otherwise.
 
-    Decode steps (Sq==1) and padded/masked batches use the jnp path — at those shapes
-    the projections dominate and XLA's fused softmax is already bandwidth-optimal.
-    ``window`` (sliding-window attention) rides the flash path: the kernel skips
-    kv blocks below the band entirely.
+    The choice is by shape on a TPU and by platform off it: decode steps
+    (Sq==1), offset chunks and padded/masked batches use the jnp path on every
+    platform — at those shapes the projections dominate and XLA's fused
+    softmax is already bandwidth-optimal — and the CPU (tests, clients) has no
+    Mosaic compiler, so the jnp path is the only one that runs there.  A
+    kernel-shaped call on a TPU always reaches the kernel: bare on one device,
+    through :func:`sharded_flash_attention` under the active multi-device mesh
+    (``parallel.sharding.mesh_scope``).  ``window`` (sliding-window attention)
+    rides the flash path: the kernel skips kv blocks below the band entirely.
     """
     D = q.shape[-1]
-    use_flash = (
-        jax.default_backend() == "tpu"
-        and mask is None
+    kernel_shaped = (
+        mask is None
         and q.shape[2] >= 256
         and q.shape[2] % 128 == 0
         and k.shape[2] % 128 == 0
@@ -651,8 +697,15 @@ def attention(
         and isinstance(q_offset, int)
         and q_offset == 0
     )
-    if use_flash:
-        return flash_attention(q, k, v, causal=causal, window=window)
+    if kernel_shaped and jax.default_backend() == "tpu":
+        from ..parallel.sharding import active_mesh
+
+        mesh = active_mesh()
+        if mesh is None or mesh.size == 1:
+            return flash_attention(q, k, v, causal=causal, window=window)
+        return sharded_flash_attention(
+            q, k, v, mesh, causal=causal, window=window
+        )
     return dot_product_attention(
         q, k, v, causal=causal, mask=mask, q_offset=q_offset, window=window
     )

@@ -70,9 +70,7 @@ def ring_attention(
     """shard_map'd ring attention.  q/k/v sequence dims must be divisible by the
     ``seq`` axis size; batch rides ``data`` untouched."""
     spec = P(None, None, axis_name, None)
-    from ..parallel.sharding import compat_shard_map
-
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_body, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -39,7 +39,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import optax
-from .sharding import compat_shard_map as shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import llama

@@ -46,25 +46,6 @@ DEFAULT_RULES: Mapping[str, Optional[str]] = {
 }
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at the top level with ``check_vma``; older releases
-    (<= 0.4.x) ship ``jax.experimental.shard_map.shard_map`` where the same
-    knob is called ``check_rep``.  Callers use the new spelling; this shim
-    keeps the package importable (and the 8-device CPU test mesh green) on
-    both."""
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm_old
-
-        return sm_old(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma)
-
-
 def logical_to_pspec(
     logical_axes: tuple[Optional[str], ...],
     rules: Mapping[str, Optional[str]] = DEFAULT_RULES,
@@ -179,6 +160,39 @@ def shard_pytree(
     return jax.tree.map(put, params, shardings)
 
 
+_scope = threading.local()
+
+
+@contextlib.contextmanager
+def mesh_scope(mesh: Optional[Mesh]):
+    """Trace/run device steps under ``mesh`` (``None`` = no mesh: a no-op).
+
+    Enters the mesh so :func:`with_constraint`'s PartitionSpecs bind, and
+    records it for this thread so code that must partition by hand — the
+    Pallas kernel, which the SPMD partitioner cannot split
+    (``ops.attention.attention``) — can find it through :func:`active_mesh`.
+    """
+    if mesh is None:
+        yield
+        return
+    prev = getattr(_scope, "mesh", None)
+    _scope.mesh = mesh
+    try:
+        with mesh:
+            yield
+    finally:
+        _scope.mesh = prev
+
+
+def active_mesh() -> Optional[Mesh]:
+    """The :func:`mesh_scope` mesh whose axes are still automatic here: None
+    outside any scope, and None inside a ``shard_map`` body traced under
+    :func:`constraints_disabled` (every axis is manual there already)."""
+    if getattr(_constraints_off, "depth", 0):
+        return None
+    return getattr(_scope, "mesh", None)
+
+
 _constraints_off = threading.local()
 
 
@@ -188,10 +202,8 @@ def constraints_disabled():
 
     Inside a ``shard_map`` body every mesh axis is manual and the body is
     already explicitly partitioned — the logical-axis constraints the model
-    code emits are advisory there at best, and older jax rejects them at
-    LOWERING time ("axis ... also found in manual_axes"), where the call-site
-    try/except below can't reach.  Wrapping the shard_map call keeps the
-    primitive out of the trace entirely."""
+    code emits would name manual axes there.  Wrapping the shard_map call
+    keeps the primitive out of the trace entirely."""
     prev = getattr(_constraints_off, "depth", 0)
     _constraints_off.depth = prev + 1
     try:
@@ -205,10 +217,18 @@ def with_constraint(
     logical_axes: tuple[Optional[str], ...],
     rules: Mapping[str, Optional[str]] = DEFAULT_RULES,
 ) -> jax.Array:
-    """`with_sharding_constraint` by logical axis names (no-op outside jit/mesh)."""
+    """`with_sharding_constraint` by logical axis names; a no-op when no mesh
+    is in context (single-device engines and plain ``jax.jit`` callers trace
+    the same model code without one).
+
+    With a mesh in context the constraint always binds: JAX accepts dims that
+    do not divide their mesh axis (uneven sharding), so nothing here ever
+    falls back to replication, and a mis-annotated rank raises."""
     if getattr(_constraints_off, "depth", 0):
         return x
     try:
         return jax.lax.with_sharding_constraint(x, logical_to_pspec(logical_axes, rules))
-    except (ValueError, RuntimeError):
+    except RuntimeError:
+        # the only RuntimeError with_sharding_constraint raises for a
+        # PartitionSpec: no mesh in context
         return x

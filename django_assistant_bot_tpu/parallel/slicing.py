@@ -144,6 +144,25 @@ class MeshPlanner:
             )
         self._lock = threading.Lock()
         self._in_use: set = set()  # slice ids
+        if n_slices > 1 and replica_devices > 1 and devices[0].platform == "tpu":
+            # Measured on four v5e chips (JAX 0.9.0, libtpu 0.0.34; CHANGES.md
+            # PR 21): every program of a TP-2 replica on devices [2, 3] halted
+            # at launch ("The program continuator has halted unexpectedly";
+            # once "schecklt: Invalid logical z: enhanced-barrier-parent-
+            # phase-1") whenever its executable was LOADED from the persistent
+            # compile cache, 4 runs of 4, and ran whenever it was compiled
+            # fresh, 2 runs of 2; devices [0, 1] and all four were fine either
+            # way.  Supervision restarts the replica and the router re-routes
+            # its requests, so only the restart counters show it.  Until that
+            # is understood, a process that carves multi-chip slices compiles
+            # everything fresh.
+            from ..utils.compile_cache import disable_persistent_compile_cache
+
+            disable_persistent_compile_cache(
+                f"{n_slices} device slices of {replica_devices} TPU chips: "
+                "executables for a slice without chip 0 halt at launch when "
+                "loaded from the cache (parallel/slicing.py)"
+            )
 
     @property
     def n_slices(self) -> int:

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import time
 from typing import Any, Dict, Mapping, Optional
 
 import jax
@@ -276,6 +277,11 @@ class ModelRegistry:
         # /healthz and /metrics read their stats; stop() halts them FIRST so
         # no scale decision races engine shutdown
         self.autoscalers: Dict[str, Any] = {}
+        # set-up wall times by model name: {"load_s", "warmup_s"} — checkpoint
+        # read + device placement, and the warm-up compiles (0.0 when the spec
+        # asks for none).  /healthz reports them; they are set-up numbers,
+        # never rates.
+        self.boot_s: Dict[str, Dict[str, float]] = {}
         for spec in (specs or {}).values():
             self.load(spec)
 
@@ -409,6 +415,8 @@ class ModelRegistry:
             spec.kv_host_bytes = 1 << 28
         tokenizer_path = spec.path
         logger.info("loading model %r (%s, tiny=%s)", name, spec.kind, spec.tiny)
+        t_start = time.monotonic()
+        warmup_s = 0.0
 
         if spec.checkpoint:
             from ..checkpoint import load_model
@@ -433,6 +441,7 @@ class ModelRegistry:
                 raise ValueError(f"model {name}: need path, checkpoint, or tiny=true")
             with self.mesh:
                 params = shard_pytree(params, encoder.logical_axes(cfg), self.mesh)
+            jax.block_until_ready(params)
             eng = EmbeddingEngine(
                 cfg,
                 params,
@@ -443,7 +452,9 @@ class ModelRegistry:
                 mesh=self.mesh,
             )
             if spec.warmup:
+                t_warm = time.monotonic()
                 eng.warmup()
+                warmup_s = time.monotonic() - t_warm
             eng.start()
             self.embedders[name] = eng
         elif spec.kind == "decoder":
@@ -559,6 +570,7 @@ class ModelRegistry:
                     params = shard_pytree(
                         params, llama.logical_axes(cfg), self.mesh
                     )
+                jax.block_until_ready(params)
             from .faults import FaultInjector
 
             def _build_sched():
@@ -610,6 +622,7 @@ class ModelRegistry:
                 slice from the planner (NoCapacity propagates — the router/
                 autoscaler turn it into the honest `no_capacity` decision)
                 and places the shared host weights onto THAT slice only."""
+                nonlocal warmup_s
                 rep_slice = None
                 rep_mesh = self.mesh
                 rep_params = params
@@ -639,9 +652,12 @@ class ModelRegistry:
                     )
                 try:
                     if spec.warmup or spec.warmup_json:
-                        # the persistent XLA compile cache makes replica
-                        # 2..N's warmup a cache replay, not a recompile
+                        # replicas on one mesh share compiled programs;
+                        # sliced TPU replicas each compile fresh (the planner
+                        # turns the persistent cache off: parallel/slicing.py)
+                        t_warm = time.monotonic()
                         eng.warmup(json=spec.warmup_json)
+                        warmup_s += time.monotonic() - t_warm
                     eng.start()
                 except Exception:
                     # a failed warmup/start (transient compile error, OOM)
@@ -754,6 +770,15 @@ class ModelRegistry:
         else:
             raise ValueError(f"model {name}: unknown kind {spec.kind!r}")
         self.specs[name] = spec
+        total_s = time.monotonic() - t_start
+        self.boot_s[name] = {
+            "load_s": round(total_s - warmup_s, 3),
+            "warmup_s": round(warmup_s, 3),
+        }
+        logger.info(
+            "model %r ready: load %.1fs, warmup %.1fs",
+            name, total_s - warmup_s, warmup_s,
+        )
 
     def stop(self):
         # autoscalers first: a scale decision must not race engine shutdown

@@ -28,6 +28,7 @@ from typing import Any, Mapping, Optional
 
 from aiohttp import web
 
+from ..utils.device import device_info
 from .engine import EngineUnavailable
 from .kv_pool import WireIntegrityError, WireVersionError
 from .obs import new_trace_id, rag_plane_snapshot, render_prometheus
@@ -287,6 +288,8 @@ def create_app(
     # before on_cleanup stops the engines (which fails anything left).
     drain = {"draining": False, "deadline_s": float(drain_deadline_s)}
     app[DRAIN_KEY] = drain
+    # the engines already hold the backend; read once, report on every probe
+    device = device_info()
 
     async def embeddings(request: web.Request) -> web.Response:
         if drain["draining"]:
@@ -467,6 +470,12 @@ def create_app(
             generators[name] = g
         payload = {
             "status": status,
+            # platform / device_kind / count as JAX reports them: a server
+            # that came up on the CPU says so here (utils/device.py)
+            "device": device,
+            # set-up wall times per model (checkpoint read + placement, and
+            # warm-up compiles) — not rates
+            "boot_s": getattr(registry, "boot_s", {}),
             "models": sorted(registry.specs),
             "generators": generators,
             "embedders": {

@@ -280,8 +280,6 @@ def _sharded_adc_shortlist(mesh, centroids, codebooks, codes, lvalid, rowpos, q,
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.sharding import compat_shard_map
-
     key = (id(mesh), nprobe, shortlist, codes.shape, q.shape)
     fn = _sharded_adc_cache.get(key)
     if fn is None:
@@ -316,7 +314,7 @@ def _sharded_adc_shortlist(mesh, centroids, codebooks, codes, lvalid, rowpos, q,
             return s_fin, p_fin
 
         fn = jax.jit(
-            compat_shard_map(
+            jax.shard_map(
                 local_scan,
                 mesh=mesh,
                 in_specs=(P("data"), P("data"), P("data"), P(), P(), P()),

@@ -82,7 +82,8 @@ def make_train_step(
     """Build a jittable ``(params, opt_state, input_ids, loss_mask) ->
     (params, opt_state, metrics)`` step.
 
-    Call under a mesh with sharded inputs; XLA derives every collective.  With
+    Call under ``parallel.sharding.mesh_scope(mesh)`` with sharded inputs; XLA
+    derives every collective.  With
     ``remat=True`` the loss is wrapped in :func:`jax.checkpoint` so activations are
     recomputed in the backward pass instead of held in HBM.  With
     ``long_context_mesh`` the forward uses ring attention over the ``seq`` axis

@@ -1,15 +1,19 @@
 """Persistent XLA compilation cache wiring.
 
-A production ``serve`` boot at 1M-corpus scale pays ~285 s of one-time XLA
-kernel compiles (PERF.md: the KNN build's dominant cold cost), and every bench
-section subprocess re-pays its share — all of it redundant across boots of the
-same binary on the same topology.  JAX ships a persistent on-disk compilation
-cache that eliminates exactly this tax; nothing wired it (VERDICT r5 #6).
+A ``serve`` boot compiles every warmed prefill/decode/embed shape, and every
+bench section child and test process would re-pay its share — all of it
+redundant across processes of the same code on the same device.  JAX's
+persistent on-disk compilation cache removes that; this module decides where
+it lives, in ONE place, so every process of a command shares it.
 
-One call, safe anywhere: before the first compile it points the cache at a
-stable directory; later calls (or unsupported jax versions) degrade to a no-op
-with a log line instead of failing the caller — cache wiring must never be the
-reason a server doesn't boot.
+The directory is part of the cache key's locality: a path that moves never
+hits.  So there are exactly two places it can be:
+
+- ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it — JAX reads that
+  variable itself, and nothing here overrides it;
+- otherwise :data:`IN_CHECKOUT_DIR` (``<checkout>/.cache/xla``, git-ignored):
+  a fixed path that is the same for every process started from this checkout,
+  never ``~``, a temporary name, a pid or a time.
 """
 
 from __future__ import annotations
@@ -20,44 +24,47 @@ from typing import Optional
 
 logger = logging.getLogger(__name__)
 
-ENV_DIR = "DABT_COMPILE_CACHE_DIR"
+ENV_JAX_DIR = "JAX_COMPILATION_CACHE_DIR"
 ENV_DISABLE = "DABT_COMPILE_CACHE_OFF"
 
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+IN_CHECKOUT_DIR = os.path.join(_CHECKOUT, ".cache", "xla")
 
-def default_cache_dir() -> str:
-    return os.environ.get(ENV_DIR) or os.path.join(
-        os.path.expanduser("~"), ".cache", "dabt-xla-cache"
-    )
 
+def enable_persistent_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache and return its directory.
 
-def enable_persistent_compile_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``path`` (default:
-    ``$DABT_COMPILE_CACHE_DIR`` or ``~/.cache/dabt-xla-cache``).
-
-    Returns the directory in use, or None when disabled/unavailable.  Must run
-    before the first jit compile to cover everything (later is still useful —
-    subsequent compiles cache).  ``DABT_COMPILE_CACHE_OFF=1`` opts out (e.g.
-    a cold-boot measurement run).
+    Must run before the first jit compile to cover everything (later is still
+    useful — subsequent compiles cache).  ``DABT_COMPILE_CACHE_OFF=1`` opts out
+    (a cold-compile measurement run) and returns None.
     """
     if os.environ.get(ENV_DISABLE, "") not in ("", "0"):
         return None
-    path = path or default_cache_dir()
-    try:
-        import jax
+    import jax
 
+    path = os.environ.get(ENV_JAX_DIR)
+    if not path:
+        path = IN_CHECKOUT_DIR
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-    except Exception as e:  # pragma: no cover - depends on jax version/fs
-        logger.warning("persistent compile cache unavailable (%s): %s", path, e)
-        return None
-    try:
-        # default threshold skips sub-second programs; the serving program set
-        # is dominated by multi-second prefill/KNN compiles either way, but a
-        # low floor lets the many small bucket shapes hit too.  Optional knob:
-        # the cache above is already ACTIVE, so a version lacking it must not
-        # make us report the cache as off.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:  # pragma: no cover - depends on jax version
-        pass
+    # JAX's default floor (1 s) skips most of the small bucket shapes a warmup
+    # compiles; 0.5 s lets them hit while still keeping trivial programs out,
+    # so the in-checkout directory stays small (the chip tool copies the
+    # checkout as it stands on disk)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     logger.info("persistent XLA compile cache at %s", path)
     return path
+
+
+def disable_persistent_compile_cache(reason: str) -> None:
+    """Turn the persistent cache off for the rest of this process, reads and
+    writes, and say why.  For code that knows cached executables would be
+    wrong for it (``parallel/slicing.py``); operators use ``DABT_COMPILE_CACHE_OFF``."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()  # the cache latches "in use" at its first compile
+    logger.warning("persistent XLA compile cache turned OFF for this process: %s", reason)

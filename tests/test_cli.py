@@ -210,21 +210,143 @@ def test_fetch_models_hub_id_not_swallowed_by_local_dir(tmp_path, monkeypatch, c
     assert "treating it as a hub id" not in capsys.readouterr().out
 
 
-def test_persistent_compile_cache_wiring(tmp_path, monkeypatch):
-    """enable_persistent_compile_cache points jax at the dir, creates it, and
-    honors the opt-out env; failures must degrade to None, never raise."""
+_CACHE_PROBE = """
+import json, os, sys
+from django_assistant_bot_tpu.utils import compile_cache as cc
+first, second = cc.enable_persistent_compile_cache(), cc.enable_persistent_compile_cache()
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.jit(lambda x: x * 2 + 1)(jnp.ones((4,))).block_until_ready()
+print(json.dumps({"first": first, "second": second,
+                  "config": jax.config.jax_compilation_cache_dir,
+                  "entries": len(os.listdir(first))}))
+"""
+
+
+def _cache_probe(env_dir):
+    import json
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "DABT_COMPILE_CACHE_OFF")}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    p = subprocess.run([sys.executable, "-c", _CACHE_PROBE], cwd=root, env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_follows_jax_env_var(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself, the function
+    returns that path, and the program's compiles land there."""
+    target = str(tmp_path / "from-env")
+    got = _cache_probe(target)
+    assert got["first"] == got["second"] == got["config"] == target
+    assert got["entries"] >= 1
+
+
+def test_compile_cache_defaults_to_one_fixed_in_checkout_path():
+    """Unset: <checkout>/.cache/xla — the same in every call and every
+    process, never ~, a temporary name, a pid or a time."""
+    import os
+
+    from django_assistant_bot_tpu.utils import compile_cache as cc
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.IN_CHECKOUT_DIR == os.path.join(root, ".cache", "xla")
+    a, b = _cache_probe(None), _cache_probe(None)
+    for got in (a, b):
+        assert got["first"] == got["second"] == got["config"] == cc.IN_CHECKOUT_DIR
+        assert got["entries"] >= 1
+
+
+def test_compile_cache_sets_no_directory_in_code_when_env_is_set(tmp_path, monkeypatch):
     import jax
 
     from django_assistant_bot_tpu.utils import compile_cache as cc
 
-    prev = jax.config.jax_compilation_cache_dir
-    target = tmp_path / "xla-cache"
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update", lambda k, v: (updates.append(k), real_update(k, v))[1]
+    )
+    monkeypatch.setenv(cc.ENV_JAX_DIR, str(tmp_path / "elsewhere"))
+    prev = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
-        got = cc.enable_persistent_compile_cache(str(target))
-        assert got == str(target)
-        assert target.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(target)
+        assert cc.enable_persistent_compile_cache() == str(tmp_path / "elsewhere")
+        assert "jax_compilation_cache_dir" not in updates
+        assert not (tmp_path / "elsewhere").exists()  # JAX creates it on first write
         monkeypatch.setenv(cc.ENV_DISABLE, "1")
-        assert cc.enable_persistent_compile_cache(str(target)) is None
+        assert cc.enable_persistent_compile_cache() is None
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        real_update("jax_persistent_cache_min_compile_time_secs", prev)
+
+
+def test_compile_cache_has_one_way_to_be_placed():
+    """No second spelling of the directory, no temporary directories."""
+    import inspect
+
+    from django_assistant_bot_tpu.utils import compile_cache as cc
+
+    src = inspect.getsource(cc)
+    assert not hasattr(cc, "ENV_DIR") and "DABT_COMPILE_CACHE_DIR" not in src
+    assert "expanduser" not in src and "mkdtemp" not in src and "getpid" not in src
+    assert not inspect.signature(cc.enable_persistent_compile_cache).parameters
+
+
+def test_second_process_on_a_held_chip_gets_one_clear_error(monkeypatch, capsys):
+    """What a one-chip host's second JAX process really raises (observed on a
+    v5e while `serve` held the chip) becomes one message naming the cause and
+    what fits, exit code 3 — not libtpu's traceback about a lockfile."""
+    from django_assistant_bot_tpu.cli import search
+    from django_assistant_bot_tpu.cli.main import main
+
+    held = (
+        "Unable to initialize backend 'tpu': ABORTED: Internal error when accessing "
+        'libtpu multi-process lockfile. Run "$ sudo rm /tmp/libtpu_lockfile". '
+        "(set JAX_PLATFORMS='' to automatically choose an available backend)"
+    )
+
+    def taken(args):
+        raise RuntimeError(held)
+
+    monkeypatch.setattr(search, "run", taken)
+    assert main(["search", "hello"]) == 3
+    err = capsys.readouterr().err
+    assert err.startswith("search: JAX could not initialise its backend")
+    assert "ONE process at a time" in err and "JAX_PLATFORMS=cpu" in err
+    assert "tpu:" in err and "gpu_service:" in err
+
+    # any other RuntimeError is not ours to explain
+    monkeypatch.setattr(search, "run", lambda args: (_ for _ in ()).throw(RuntimeError("boom")))
+    import pytest
+
+    with pytest.raises(RuntimeError, match="boom"):
+        main(["search", "hello"])
+
+
+def test_compile_cache_can_be_turned_off_for_the_process_and_slicing_leaves_it_on_off_the_tpu():
+    """`disable_persistent_compile_cache` stops reads and writes for the rest
+    of the process (what `MeshPlanner` does for multi-chip TPU slices, whose
+    cached executables halt at launch); CPU planners never touch it."""
+    import jax
+
+    from django_assistant_bot_tpu.parallel import MeshPlanner
+    from django_assistant_bot_tpu.utils import compile_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        MeshPlanner(2, devices=jax.devices()[:4])  # two 2-device CPU slices
+        assert jax.config.jax_enable_compilation_cache is True
+        cc.disable_persistent_compile_cache("test")
+        assert jax.config.jax_enable_compilation_cache is False
+    finally:
+        from jax.experimental.compilation_cache import compilation_cache
+
+        jax.config.update("jax_enable_compilation_cache", prev)
+        compilation_cache.reset_cache()

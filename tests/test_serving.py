@@ -500,6 +500,29 @@ def test_http_healthz_and_models(http_client):
     loop.run_until_complete(go())
 
 
+def test_http_healthz_names_the_device_and_boot_times(http_client):
+    """/healthz says which backend came up — platform, device_kind and count
+    exactly as JAX reports them — and each model's set-up times."""
+    import jax
+
+    loop, client = http_client
+
+    async def go():
+        data = await (await client.get("/healthz")).json()
+        devices = jax.devices()
+        assert data["device"] == {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+        }
+        assert data["device"]["platform"] == "cpu"  # the suite never takes a chip
+        assert set(data["boot_s"]) == set(data["models"])
+        for times in data["boot_s"].values():
+            assert times["load_s"] >= 0.0 and times["warmup_s"] >= 0.0
+
+    loop.run_until_complete(go())
+
+
 def _llama3_style_tokenizer():
     """A tiny tokenizer with the REAL Llama-3 chat template: char-level vocab,
     the four Llama-3 specials, and (like Meta's shipped fast tokenizer) a

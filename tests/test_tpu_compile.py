@@ -1,0 +1,285 @@
+"""Real-size compiles with the chip's own compiler, against a DESCRIBED
+``v5e:2x2`` topology (on-chip-measurement §2.3): nothing runs, no chip is
+needed, and what the compiler would refuse on the chip — a slice off the
+tiling, too much VMEM, a program that does not fit HBM, a Mosaic kernel the
+partitioner cannot split — it refuses here.  A compile that passes is not a
+chip run and is never reported as one; ``chip_smoke.py`` is the chip run.
+
+Shapes are the ones the smoke serves: the flash kernel at every whole-bucket
+prefill length of Mistral-7B-v0.1 (D=128, 32 heads, the model's window), the
+D=64 encoder-class head, the shard-mapped kernel on a four-device mesh, and
+the paged decode/prefill programs and the encoder/KNN kernels at full widths.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding  # noqa: E402
+
+from django_assistant_bot_tpu.models import encoder, llama  # noqa: E402
+from django_assistant_bot_tpu.models.config import DecoderConfig, EncoderConfig  # noqa: E402
+from django_assistant_bot_tpu.ops import attention as attn  # noqa: E402
+from django_assistant_bot_tpu.ops.quant import QTensor  # noqa: E402
+from django_assistant_bot_tpu.parallel import MeshAxes, make_mesh  # noqa: E402
+
+MISTRAL_7B = DecoderConfig(
+    vocab_size=32_000, hidden_size=4096, intermediate_size=14_336, num_layers=32,
+    num_heads=32, num_kv_heads=8, head_dim=128, max_seq_len=32_768,
+    rope_theta=10_000.0, sliding_window=4096, dtype=jnp.bfloat16,
+)
+SLOTS, MAX_SEQ, PAGE = 8, 2048, 512  # the smoke's serving geometry
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A described-device compile is written to the persistent cache but can
+    never be read back without a chip; keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _int8_decoder_shapes(cfg: DecoderConfig, sharding):
+    """The int8 parameter tree's shapes, built by hand: ``llama.init_int8`` and
+    ``quantize_decoder_params`` do host work ``jax.eval_shape`` cannot trace."""
+    L, E, F, V = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    H, KH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+
+    def dense(*shape):
+        return _sds(shape, cfg.dtype, sharding)
+
+    def q8(*shape):
+        return QTensor(
+            q=_sds(shape, jnp.int8, sharding),
+            scale=_sds(shape[:-2] + (1, shape[-1]), jnp.float32, sharding),
+        )
+
+    return {
+        "tok_embed": dense(V, E),
+        "final_norm": dense(E),
+        "lm_head": dense(E, V),
+        "layers": {
+            "attn_norm": dense(L, E), "mlp_norm": dense(L, E),
+            "wq": q8(L, E, H * D), "wk": q8(L, E, KH * D), "wv": q8(L, E, KH * D),
+            "wo": q8(L, H * D, E),
+            "w_gate": q8(L, E, F), "w_up": q8(L, E, F), "w_down": q8(L, F, E),
+        },
+    }
+
+
+def _paged_cache_shapes(cfg: DecoderConfig, kv_dtype, sharding):
+    n_pages = SLOTS * MAX_SEQ // PAGE
+    shape = (cfg.num_layers, n_pages, cfg.num_kv_heads, PAGE, cfg.head_dim)
+    return llama.PagedKVCache(
+        k=_sds(shape, kv_dtype, sharding),
+        v=_sds(shape, kv_dtype, sharding),
+        lengths=_sds((SLOTS,), jnp.int32, sharding),
+    )
+
+
+FLASH_CASES = [
+    # (B, H, S, D, window, id)
+    (1, 32, 256, 128, 4096, "mistral-bucket-256"),
+    (1, 32, 512, 128, 4096, "mistral-bucket-512"),
+    (1, 32, 1024, 128, 4096, "mistral-bucket-1024"),
+    (1, 32, 2048, 128, 4096, "mistral-bucket-2048"),
+    (8, 32, 512, 128, 4096, "mistral-wave-8x512"),
+    (1, 32, 1024, 64, None, "head-dim-64"),
+    (1, 32, 1024, 128, 256, "window-256-bites"),
+    (1, 32, 16384, 64, None, "chunked-kv-16384"),
+]
+
+
+@pytest.mark.parametrize(
+    "B,H,S,D,window", [c[:5] for c in FLASH_CASES], ids=[c[5] for c in FLASH_CASES]
+)
+def test_flash_kernel_compiles_for_one_v5e(topo, B, H, S, D, window):
+    one = SingleDeviceSharding(topo.devices[0])
+    x = _sds((B, H, S, D), jnp.bfloat16, one)
+    compiled = (
+        jax.jit(lambda q, k, v: attn.flash_attention(q, k, v, causal=True, window=window))
+        .lower(x, x, x)
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "axes,B",
+    [(MeshAxes(model=4), 1), (MeshAxes(data=2, model=2), 8), (MeshAxes(data=2, model=2), 1)],
+    ids=["tp4-one-row", "dp2xtp2-wave", "dp2xtp2-one-row-replicated-batch"],
+)
+def test_shard_mapped_flash_kernel_compiles_on_a_four_device_mesh(topo, axes, B):
+    """Bare, this call is refused on a mesh ("Mosaic kernels cannot be
+    automatically partitioned"); under shard_map every device gets its heads."""
+    mesh = make_mesh(axes, devices=topo.devices)
+    sharding = NamedSharding(mesh, P("data" if B % axes.data == 0 else None, "model", None, None))
+    x = _sds((B, 32, 1024, 128), jnp.bfloat16, sharding)
+    compiled = (
+        jax.jit(
+            lambda q, k, v: attn.sharded_flash_attention(
+                q, k, v, mesh, causal=True, window=4096
+            )
+        )
+        .lower(x, x, x)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # heads split over `model`: no device computes more than its share
+    assert f"bf16[{B // axes.data if B % axes.data == 0 else B},{32 // axes.model},1024,128]" in text
+
+
+def test_bare_flash_kernel_is_refused_on_a_mesh(topo):
+    """The fault the shard_map repairs, kept as a test so the reason stays true."""
+    mesh = make_mesh(MeshAxes(model=4), devices=topo.devices)
+    x = _sds((1, 32, 1024, 128), jnp.bfloat16, NamedSharding(mesh, P(None, "model", None, None)))
+    with pytest.raises(Exception, match="Mosaic kernels cannot be automatically partitioned"):
+        jax.jit(lambda q, k, v: attn.flash_attention(q, k, v, causal=True)).lower(x, x, x).compile()
+
+
+def test_attention_dispatch_reaches_the_kernel_under_a_mesh(topo, monkeypatch):
+    """``attention()`` asks JAX for the backend, which is the CPU here; the test
+    steers that one question and checks the dispatch takes the shard-mapped
+    kernel inside ``mesh_scope`` and never the jnp path."""
+    from django_assistant_bot_tpu.parallel.sharding import mesh_scope
+
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        attn, "dot_product_attention", lambda *a, **k: pytest.fail("fell to the jnp path")
+    )
+    mesh = make_mesh(MeshAxes(model=4), devices=topo.devices)
+    x = _sds((1, 32, 512, 128), jnp.bfloat16, NamedSharding(mesh, P(None, "model", None, None)))
+    with mesh_scope(mesh):
+        compiled = (
+            jax.jit(lambda q, k, v: attn.attention(q, k, v, causal=True, window=4096))
+            .lower(x, x, x)
+            .compile()
+        )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.float8_e4m3fn, jnp.bfloat16], ids=["fp8-kv", "bf16-kv"])
+def test_paged_decode_compiles_at_mistral_7b_widths(topo, kv_dtype):
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _int8_decoder_shapes(MISTRAL_7B, one)
+    cache = _paged_cache_shapes(MISTRAL_7B, kv_dtype, one)
+    tokens = _sds((SLOTS,), jnp.int32, one)
+    bt = _sds((SLOTS, MAX_SEQ // PAGE), jnp.int32, one)
+    compiled = (
+        jax.jit(lambda p, t, c, b: llama.decode_step_paged(p, MISTRAL_7B, t, c, b))
+        .lower(params, tokens, cache, bt)
+        .compile()
+    )
+    mem = compiled.memory_analysis()
+    # int8 weights resident (~7.5 GB) + the page pool, inside one chip's 16 GB
+    assert 7.0e9 < mem.argument_size_in_bytes < 12.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+
+
+def test_paged_prefill_chunk_compiles_at_mistral_7b_widths(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _int8_decoder_shapes(MISTRAL_7B, one)
+    cache = _paged_cache_shapes(MISTRAL_7B, jnp.bfloat16, one)
+    scalar = _sds((), jnp.int32, one)
+    jax.jit(
+        lambda p, i, c, bt, s, st, v: llama.prefill_chunk_paged(
+            p, MISTRAL_7B, i, c, bt, s, st, v
+        )
+    ).lower(
+        params, _sds((1, 1024), jnp.int32, one), cache,
+        _sds((MAX_SEQ // PAGE,), jnp.int32, one), scalar, scalar, scalar,
+    ).compile()
+
+
+def test_paged_suffix_prefill_wave_loads_beside_the_weights(topo):
+    """The program that stopped the first chip boot: a full admission wave
+    (8 rows x bucket 1024) of prefix-hit suffix prefill.  Gathering every
+    layer's rows up front needed 8.9 GB of temporaries next to 9.7 GB of
+    weights and pool; per layer, with the pool updated in place, it is ~2.3 GB
+    (almost all of it one layer's f32 attention scores)."""
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _int8_decoder_shapes(MISTRAL_7B, one)
+    cache = _paged_cache_shapes(MISTRAL_7B, jnp.bfloat16, one)
+
+    def i32(*shape):
+        return _sds(shape, jnp.int32, one)
+
+    compiled = (
+        jax.jit(
+            lambda p, i, c, bt, sl, st, v: llama.prefill_suffix_paged(
+                p, MISTRAL_7B, i, c, bt, sl, st, v
+            ),
+            donate_argnums=(2,),
+        )
+        .lower(params, i32(SLOTS, 1024), cache, i32(SLOTS, MAX_SEQ // PAGE),
+               i32(SLOTS), i32(SLOTS), i32(SLOTS))
+        .compile()
+    )
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2.0e9  # the donated pool is updated in place
+    assert mem.temp_size_in_bytes < 3.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.0e9
+
+
+def test_whole_bucket_prefill_takes_the_kernel_at_mistral_7b_widths(topo, monkeypatch):
+    """The program the smoke's 512-1024-token prompt runs: ``llama.prefill`` at
+    bucket 1024, int8 — the Pallas kernel inside the layer scan."""
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    params = _int8_decoder_shapes(MISTRAL_7B, one)
+    compiled = (
+        jax.jit(lambda p, i, n: llama.prefill(p, MISTRAL_7B, i, n))
+        .lower(params, _sds((1, 1024), jnp.int32, one), _sds((1,), jnp.int32, one))
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_encoder_compiles_at_rubert_base_geometry(topo):
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg = EncoderConfig()
+    shapes = jax.eval_shape(lambda: encoder.init(cfg, jax.random.key(0)))
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one), shapes)
+    ids = _sds((32, 128), jnp.int32, one)
+    jax.jit(lambda p, i, m: encoder.encode(p, cfg, i, m)).lower(params, ids, ids).compile()
+
+
+@pytest.mark.parametrize("rows", [131_072, 1_048_576], ids=["100k-padded", "1M-padded"])
+def test_knn_topk_compiles_at_corpus_scale(topo, rows):
+    """Exact KNN scoring + top-k at the smoke's corpus (100,000 rows pad to
+    2^17) and at the 1M corpus the README quotes (pads to 2^20)."""
+    from django_assistant_bot_tpu.storage.knn import _topk_scores_impl
+
+    one = SingleDeviceSharding(topo.devices[0])
+    jax.jit(_topk_scores_impl, static_argnums=(3,)).lower(
+        _sds((rows, 768), jnp.bfloat16, one),
+        _sds((8, 768), jnp.float32, one),
+        _sds((rows,), jnp.bool_, one),
+        16,
+    ).compile()
