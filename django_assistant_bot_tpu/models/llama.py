@@ -449,6 +449,12 @@ def _embed(params: Params, cfg: DecoderConfig, ids: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
+# Block names (jax.named_scope): metadata on the compiled operations, so a
+# fusion in a device trace reads as the block it belongs to — attn/qkv,
+# attn/kv_write, attn/kv_read (attn/core where prefill attends to what it just
+# computed), attn/out, ffn/gate_up, ffn/down, head; the engine's tick adds
+# sample.  No operation is added or moved by them.
+@jax.named_scope("head")
 def _head_logits(params: Params, cfg: DecoderConfig, x: jnp.ndarray) -> jnp.ndarray:
     """Logits projection ``[..., E] -> [..., V]`` in model dtype.
 
@@ -470,17 +476,27 @@ def _mlp(cfg: DecoderConfig, p: Params, x: jnp.ndarray) -> jnp.ndarray:
     if cfg.is_moe:
         from .mixtral import moe_mlp
 
-        return moe_mlp(cfg, p, x)
+        with jax.named_scope("ffn/moe"):
+            return moe_mlp(cfg, p, x)
     act = (
         functools.partial(jax.nn.gelu, approximate=True)
         if cfg.hidden_act == "gelu_tanh"
         else jax.nn.silu
     )
-    h = act(qeinsum("bse,ef->bsf", x, p["w_gate"], cfg.dtype)) * qeinsum("bse,ef->bsf", x, p["w_up"], cfg.dtype)
-    h = with_constraint(h, ("batch", "length", "mlp"))
-    return qeinsum("bsf,fe->bse", h, p["w_down"], cfg.dtype)
+    with jax.named_scope("ffn/gate_up"):
+        h = act(qeinsum("bse,ef->bsf", x, p["w_gate"], cfg.dtype)) * qeinsum("bse,ef->bsf", x, p["w_up"], cfg.dtype)
+        h = with_constraint(h, ("batch", "length", "mlp"))
+    with jax.named_scope("ffn/down"):
+        return qeinsum("bsf,fe->bse", h, p["w_down"], cfg.dtype)
 
 
+@jax.named_scope("attn/out")
+def _attn_out(cfg: DecoderConfig, p: Params, o: jnp.ndarray) -> jnp.ndarray:
+    """The attention output projection ``[B,S,H*D] -> [B,S,E]``."""
+    return qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+
+
+@jax.named_scope("attn/qkv")
 def _attn_proj(cfg: DecoderConfig, p: Params, x: jnp.ndarray, cos, sin):
     """QKV projections + RoPE.  Returns q:[B,H,S,D], k/v:[B,KH,S,D]."""
     B, S, E = x.shape
@@ -500,6 +516,28 @@ def _attn_proj(cfg: DecoderConfig, p: Params, x: jnp.ndarray, cos, sin):
     q = with_constraint(q.transpose(0, 2, 1, 3), ("batch", "heads", "length", "head_dim"))
     k = with_constraint(k.transpose(0, 2, 1, 3), ("batch", "kv_heads", "length", "head_dim"))
     v = with_constraint(v.transpose(0, 2, 1, 3), ("batch", "kv_heads", "length", "head_dim"))
+    return q, k, v
+
+
+@jax.named_scope("attn/qkv")
+def _decode_qkv(cfg: DecoderConfig, p: Params, h: jnp.ndarray, cos, sin):
+    """One decode step's QKV projections + RoPE at per-slot positions
+    (``h``: [B,1,E]).  Returns q:[B,H,1,D], k/v:[B,KH,1,D]."""
+    B = h.shape[0]
+    H, KH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    q = qeinsum("bse,eo->bso", h, p["wq"], cfg.dtype)
+    k = qeinsum("bse,eo->bso", h, p["wk"], cfg.dtype)
+    v = qeinsum("bse,eo->bso", h, p["wv"], cfg.dtype)
+    if cfg.attn_bias:
+        q = q + p["bq"]
+        k = k + p["bk"]
+        v = v + p["bv"]
+    q = q.reshape(B, 1, H, D)
+    k = k.reshape(B, 1, KH, D)
+    v = v.reshape(B, 1, KH, D)
+    q = apply_rope(q, cos, sin).transpose(0, 2, 1, 3)
+    k = apply_rope(k, cos, sin).transpose(0, 2, 1, 3)
+    v = v.transpose(0, 2, 1, 3)
     return q, k, v
 
 
@@ -580,7 +618,7 @@ def forward(
             else:
                 o = dot_product_attention(q, k, v, causal=True, mask=mask, window=window)
             o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             return with_constraint(x, ("batch", "length", "embed")), None
@@ -617,7 +655,7 @@ def forward_layers(
         k, v = _repeat_kv(cfg, k), _repeat_kv(cfg, v)
         o = attention(q, k, v, causal=True)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
-        x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+        x = x + _attn_out(cfg, p, o)
         h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
         x = x + _mlp(cfg, p, h)
         return x, None
@@ -663,7 +701,7 @@ def forward_long(
         k, v = _repeat_kv(cfg, k), _repeat_kv(cfg, v)
         o = ring_attention(q, k, v, mesh, causal=True)
         o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
-        x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+        x = x + _attn_out(cfg, p, o)
         h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
         x = x + _mlp(cfg, p, h)
         return with_constraint(x, ("batch", "length", "embed")), None
@@ -674,6 +712,7 @@ def forward_long(
     return with_constraint(logits.astype(jnp.float32), ("batch", "length", "vocab_out"))
 
 
+@jax.named_scope("attn/kv_write")
 def _write_cache(cache_k, new_k, starts):
     """vmap'd dynamic_update_slice: cache_k [B,KH,S,D], new_k [B,KH,Sn,D], starts [B]."""
     def upd(c, n, s):
@@ -710,7 +749,7 @@ def prefill(
             # buckets — windowed too (the kernel skips kv blocks below the band).
             o = attention(q, kr, vr, causal=True, window=window)
             o = o.transpose(0, 2, 1, 3).reshape(B, S, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             return with_constraint(x, ("batch", "length", "embed")), (k, v)
@@ -811,7 +850,7 @@ def prefill_chunk(
             # grouped attention reads the cache row once (no q_per_kv repeat)
             o = gqa_dot_product_attention(q, k_row, v_row, mask=attn_mask)  # [1, H, C, D]
             o = o.transpose(0, 2, 1, 3).reshape(B, C, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             return x, (k_row, v_row)
@@ -887,7 +926,7 @@ def prefill_suffix(
             v_row = _write_cache(v_row, v, starts)
             o = gqa_dot_product_attention(q, k_row, v_row, mask=attn_mask)
             o = o.transpose(0, 2, 1, 3).reshape(B, C, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             return x, (k_row, v_row)
@@ -1019,6 +1058,7 @@ def copy_pages(
     return PagedKVCache(k=k, v=v, lengths=cache.lengths)
 
 
+@jax.named_scope("attn/kv_read")
 def _gather_layer_rows(
     pool: jnp.ndarray,  # [L, P, KH, page, D]
     layer: jnp.ndarray,  # scalar int32
@@ -1045,6 +1085,7 @@ def _gather_layer_rows(
     return rows.transpose(0, 2, 1, 3, 4).reshape(B, KH, NB * page, D)
 
 
+@jax.named_scope("attn/kv_write")
 def _scatter_layer_rows(
     pool: jnp.ndarray,  # [L, P, KH, page, D]
     layer: jnp.ndarray,  # scalar int32
@@ -1151,7 +1192,7 @@ def prefill_suffix_paged(
             )
             o = gqa_dot_product_attention(q, k_row, v_row, mask=attn_mask)
             o = o.transpose(0, 2, 1, 3).reshape(B, C, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             k_pool = _scatter_layer_rows(k_pool, layer, k_row, block_tables, write_mask)
@@ -1222,7 +1263,7 @@ def prefill_chunk_paged(
             )
             o = gqa_dot_product_attention(q, k_row, v_row, mask=attn_mask)
             o = o.transpose(0, 2, 1, 3).reshape(B, C, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             k_pool = _scatter_layer_rows(k_pool, layer, k_row, bt, write_mask)
@@ -1270,7 +1311,6 @@ def decode_step_paged(
     L, P, KH, page, D = cache.k.shape
     NB = block_tables.shape[1]
     S = NB * page
-    H = cfg.num_heads
     if active is None:
         active = jnp.ones((B,), bool)
     active = active & (cache.lengths < S)
@@ -1289,31 +1329,20 @@ def decode_step_paged(
         def body(x, inputs):
             p, k_pool, v_pool = inputs  # [P, KH, page, D] per layer
             h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q = qeinsum("bse,eo->bso", h, p["wq"], cfg.dtype)
-            k = qeinsum("bse,eo->bso", h, p["wk"], cfg.dtype)
-            v = qeinsum("bse,eo->bso", h, p["wv"], cfg.dtype)
-            if cfg.attn_bias:
-                q = q + p["bq"]
-                k = k + p["bk"]
-                v = v + p["bv"]
-            q = q.reshape(B, 1, H, D)
-            k = k.reshape(B, 1, KH, D)
-            v = v.reshape(B, 1, KH, D)
-            q = apply_rope(q, cos, sin).transpose(0, 2, 1, 3)
-            k = apply_rope(k, cos, sin).transpose(0, 2, 1, 3)
-            v = v.transpose(0, 2, 1, 3)
-            k_pool = k_pool.at[phys_w, :, off, :].set(
-                k[:, :, 0, :].astype(k_pool.dtype), mode="drop"
-            )
-            v_pool = v_pool.at[phys_w, :, off, :].set(
-                v[:, :, 0, :].astype(v_pool.dtype), mode="drop"
-            )
+            q, k, v = _decode_qkv(cfg, p, h, cos, sin)
+            with jax.named_scope("attn/kv_write"):
+                k_pool = k_pool.at[phys_w, :, off, :].set(
+                    k[:, :, 0, :].astype(k_pool.dtype), mode="drop"
+                )
+                v_pool = v_pool.at[phys_w, :, off, :].set(
+                    v[:, :, 0, :].astype(v_pool.dtype), mode="drop"
+                )
             o = paged_gqa_decode_attention(
                 q, k_pool, v_pool, block_tables, positions,
                 active=active, window=window, fp8_dot=attn_fp8,
             )  # [B,H,1,D]
             o = o.transpose(0, 2, 1, 3).reshape(B, 1, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             return x, (k_pool, v_pool)
@@ -1331,6 +1360,7 @@ def decode_step_paged(
     return logits.astype(jnp.float32), new_cache
 
 
+@jax.named_scope("attn/qkv")
 def _tree_qkv(cfg: DecoderConfig, p: Params, h: jnp.ndarray, cos, sin):
     """QKV projections + RoPE for the tree-verify forward, ``h`` [B, T, E].
 
@@ -1422,7 +1452,7 @@ def _verify_tree_forward(
             vals = jnp.concatenate([v_row.astype(v.dtype), v], axis=2)
             o = gqa_dot_product_attention(q, keys, vals, mask=attn_mask)
             o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             return x, (k, v)
@@ -1502,7 +1532,7 @@ def verify_tree_step_paged(
                 anc_mask, depths, window=window,
             )
             o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             return x, (k, v)
@@ -1652,8 +1682,6 @@ def decode_step(
     kpos = jnp.arange(S)[None, :]
     causal_keep = (kpos <= positions[:, None])[:, None, None, :]  # [B,1,1,S]
 
-    H, KH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-
     def make_body(window):
         attn_mask = causal_keep
         if window is not None:
@@ -1665,19 +1693,7 @@ def decode_step(
         def body(x, inputs):
             p, k_cache, v_cache = inputs
             h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q = qeinsum("bse,eo->bso", h, p["wq"], cfg.dtype)
-            k = qeinsum("bse,eo->bso", h, p["wk"], cfg.dtype)
-            v = qeinsum("bse,eo->bso", h, p["wv"], cfg.dtype)
-            if cfg.attn_bias:
-                q = q + p["bq"]
-                k = k + p["bk"]
-                v = v + p["bv"]
-            q = q.reshape(B, 1, H, D)
-            k = k.reshape(B, 1, KH, D)
-            v = v.reshape(B, 1, KH, D)
-            q = apply_rope(q, cos, sin).transpose(0, 2, 1, 3)
-            k = apply_rope(k, cos, sin).transpose(0, 2, 1, 3)
-            v = v.transpose(0, 2, 1, 3)
+            q, k, v = _decode_qkv(cfg, p, h, cos, sin)
             k_cache = _write_cache(k_cache, k, positions)
             v_cache = _write_cache(v_cache, v, positions)
             # grouped attention: the multi-GB slot cache is read ONCE per step
@@ -1692,7 +1708,7 @@ def decode_step(
             else:
                 o = gqa_dot_product_attention(q, k_cache, v_cache, mask=attn_mask)  # [B,H,1,D]
             o = o.transpose(0, 2, 1, 3).reshape(B, 1, -1)
-            x = x + qeinsum("bso,oe->bse", o, p["wo"], cfg.dtype)
+            x = x + _attn_out(cfg, p, o)
             h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
             x = x + _mlp(cfg, p, h)
             return x, (k_cache, v_cache)

@@ -70,6 +70,7 @@ def dot_product_attention(
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+@jax.named_scope("attn/kv_read")
 def gqa_dot_product_attention(
     q: jnp.ndarray,  # [B, H, Sq, D]
     k: jnp.ndarray,  # [B, KH, Sk, D] — KV heads NOT repeated
@@ -106,6 +107,7 @@ def gqa_dot_product_attention(
     return out.reshape(B, H, Sq, D)
 
 
+@jax.named_scope("attn/kv_read")
 def chunked_gqa_decode_attention(
     q: jnp.ndarray,  # [B, H, 1, D]
     k: jnp.ndarray,  # [B, KH, S, D] slot cache, storage dtype (bf16 / fp8)
@@ -225,6 +227,7 @@ def chunked_gqa_decode_attention(
     return out.reshape(B, H, 1, D)
 
 
+@jax.named_scope("attn/kv_read")
 def paged_gqa_decode_attention(
     q: jnp.ndarray,  # [B, H, 1, D]
     k_pool: jnp.ndarray,  # [P, KH, page, D] page pool, storage dtype (bf16 / fp8)
@@ -325,6 +328,7 @@ def paged_gqa_decode_attention(
     return out.reshape(B, H, 1, D)
 
 
+@jax.named_scope("attn/kv_read")
 def paged_tree_attention(
     q: jnp.ndarray,  # [B, H, T, D] — one query per speculation-tree node
     k_pool: jnp.ndarray,  # [P, KH, page, D] page pool, storage dtype
@@ -665,6 +669,7 @@ def sharded_flash_attention(
     )(q, k, v)
 
 
+@jax.named_scope("attn/core")
 def attention(
     q: jnp.ndarray,
     k: jnp.ndarray,

@@ -70,6 +70,7 @@ def top_k_auto(x: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
 _top_k = top_k_auto  # internal alias used by sample_logits
 
 
+@jax.named_scope("sample")
 def sample_logits(
     logits: jnp.ndarray,  # [batch, vocab] float
     rng: jax.Array,

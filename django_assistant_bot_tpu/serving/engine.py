@@ -41,7 +41,7 @@ import numpy as np
 from ..models import DecoderConfig, EncoderConfig, encoder, llama
 from ..ops.sampling import sample_logits
 from ..parallel.sharding import mesh_scope
-from .obs import EngineObs, new_trace_id
+from .obs import EngineObs, LoopLedger, new_trace_id
 from .scheduler import DeadlineExceeded, RequestScheduler, SchedulerRejected
 from .tokenizer import Tokenizer
 
@@ -120,11 +120,15 @@ class GenerationResult:
     length_limited: bool
     ttft_s: float = 0.0
     latency_s: float = 0.0
+    # per-request spans and counts from the socket inward (serving/obs.py
+    # TIMING_KEYS): filled by ``_finish`` from the stamps below; the server
+    # adds ``deliver_s`` / ``stream_*`` when it writes the terminal event
+    timings: Optional[dict] = None
 
     def usage_dict(self, model: str) -> dict:
         """The wire-format usage object (HTTP responses, provider AIResponse
         usage, SSE terminal events) — one construction for every consumer."""
-        return {
+        out = {
             "model": model,
             "prompt_tokens": self.prompt_tokens,
             "completion_tokens": self.completion_tokens,
@@ -132,6 +136,9 @@ class GenerationResult:
             "ttft_s": self.ttft_s,
             "latency_s": self.latency_s,
         }
+        if self.timings is not None:
+            out["timings"] = dict(self.timings)
+        return out
 
 
 @dataclasses.dataclass
@@ -175,6 +182,18 @@ class _Request:
     # host tier and uploaded it into fresh pages ahead of the suffix prefill
     # (the restores-in-flight gauge decrements when the slot activates)
     restored_from_host: bool = False
+    # receipt at the socket (the /dialog/ handler's first line, or the top of
+    # generate()): encode_s = submitted_at - received_at.  None = submit()
+    # was the entry point
+    received_at: Optional[float] = None
+    # what the prefill program this request rode looked like: its sequence
+    # bucket, the wave's real rows and the batch bucket they padded to
+    # (chunked prefills: chunk_size, 1, 1), and the prompt tokens a prefix
+    # hit spared it
+    prefill_bucket: int = 0
+    wave_rows: int = 0
+    wave_rows_padded: int = 0
+    prefix_hit_tokens: int = 0
 
 
 # slot-cache precision knob -> concrete dtype (None = the model's cfg.dtype);
@@ -234,6 +253,9 @@ class _Slot:
     # resident_steps so piggybacked (continuous-batching) prefill work
     # doesn't vanish from the predicted queue wait / Retry-After math
     prefill_chunks: int = 0
+    # tick results that carried at least one token for this slot (the
+    # activation's first token included)
+    decode_ticks: int = 0
 
 
 @dataclasses.dataclass
@@ -338,11 +360,16 @@ class GenerationEngine:
         # Observability plane (serving/obs.py, docs/OBSERVABILITY.md): span
         # traces, metric histograms and the crash flight recorder.  On by
         # default — recording is pure host bookkeeping over values the tick
-        # path already holds (enforced by dabtlint's DABT104 registry), and
-        # the bench's obs_* A/B keeps the overhead claim honest.  obs=False
-        # is the A/B off-arm: no recorder object exists at all, the hot path
-        # pays one `is None` check (the faults-plane discipline).
+        # path already holds (enforced by dabtlint's DABT104 registry); what
+        # it costs was measured on the chip (PERF.md section 6, PR 24: four
+        # runs of one seed, two of them under the profiler).  obs=False
+        # leaves no recorder object at all (no histograms, no trace ring, no
+        # flight ring); the hot path pays one `is None` check.  The loop
+        # ledger below and the per-request stamps are the engine's own, as
+        # submitted_at/started_at always were: tick_stats() and
+        # usage.timings read the same either way.
         self.name = name
+        self._ledger = LoopLedger(clock)
         if obs:
             self.obs = EngineObs(name=name, clock=clock, dump_dir=obs_dump_dir)
         else:
@@ -819,13 +846,11 @@ class GenerationEngine:
         # docs/STATIC_ANALYSIS.md.
         self._iter_lock = threading.Lock()
         # Per-tick wall breakdown (engine thread only): where a decode token's
-        # time actually goes — `issue_s` is dispatch enqueue, `block_s` is
-        # waiting on a tick's sampled ids in
-        # _process_tick, everything else is host bookkeeping.  Read via
+        # time actually goes — the ledger's `tick_issue` phase is dispatch
+        # enqueue, `tick_block` is waiting on a tick's sampled ids in
+        # _process_tick, the other phases are host bookkeeping.  Read via
         # :meth:`tick_stats`; the roofline work (VERDICT r3 weak #2) tunes
         # burst/slots from these instead of guessing.
-        self._tick_issue_s = 0.0
-        self._tick_block_s = 0.0
         self._ticks_issued = 0
         self._ticks_processed = 0
 
@@ -1468,6 +1493,7 @@ class GenerationEngine:
         deadline_s: Optional[float] = None,
         stream: Any = None,
         trace_id: Optional[str] = None,
+        received_at: Optional[float] = None,
     ) -> Future:
         """Thread-safe submission; returns a concurrent Future[GenerationResult].
 
@@ -1490,7 +1516,11 @@ class GenerationEngine:
         ``trace_id``: the request's correlation id (client ``X-Request-Id``
         or a router-assigned id); generated here when absent, stamped on the
         ``_Request``, and carried through the obs plane's trace ring and
-        flight recorder (docs/OBSERVABILITY.md)."""
+        flight recorder (docs/OBSERVABILITY.md).
+
+        ``received_at``: when the request reached the process, on this
+        engine's clock (the ``/dialog/`` handler's first line): the start of
+        ``usage.timings``' ``encode_s``."""
         trace_id = trace_id or new_trace_id()
         if self.degraded():
             # restart circuit open: fail fast (503 at the server) instead of
@@ -1573,6 +1603,7 @@ class GenerationEngine:
                 stream=stream,
                 kv_pages=kv_pages,
                 trace_id=trace_id,
+                received_at=received_at,
             )
         )
         # A stop() racing (or preceding) the put above would leave the request
@@ -1596,12 +1627,15 @@ class GenerationEngine:
         tenant: str = "default",
         deadline_s: Optional[float] = None,
         trace_id: Optional[str] = None,
+        received_at: Optional[float] = None,
     ) -> GenerationResult:
         """Async convenience: tokenize (chat-templating message lists), run, decode."""
         import asyncio
 
         from .tokenizer import encode_chat_split
 
+        if received_at is None:  # no socket above: encode_s is the tokenizer's
+            received_at = self._clock()
         if isinstance(prompt, str):
             ids, plen = self.tokenizer.encode(prompt), 0
         else:
@@ -1619,6 +1653,7 @@ class GenerationEngine:
             tenant=tenant,
             deadline_s=deadline_s,
             trace_id=trace_id,
+            received_at=received_at,
         )
         return await asyncio.wrap_future(fut)
 
@@ -1634,6 +1669,7 @@ class GenerationEngine:
         tenant: str = "default",
         deadline_s: Optional[float] = None,
         trace_id: Optional[str] = None,
+        received_at: Optional[float] = None,
     ):
         """Async iterator of :class:`~.streaming.StreamChunk`: per-token
         UTF-8-safe text deltas as device results resolve, then one terminal
@@ -1657,6 +1693,8 @@ class GenerationEngine:
         from .streaming import IncrementalDetokenizer, StreamChunk, TokenStream
         from .tokenizer import encode_chat_split
 
+        if received_at is None:
+            received_at = self._clock()
         if isinstance(prompt, str):
             ids, plen = self.tokenizer.encode(prompt), 0
         else:
@@ -1676,14 +1714,15 @@ class GenerationEngine:
             deadline_s=deadline_s,
             stream=stream,
             trace_id=trace_id,
+            received_at=received_at,
         )
         detok = IncrementalDetokenizer(self.tokenizer)
         idx = 0
         try:
-            async for kind, payload in stream:
+            async for kind, payload, at in stream.stamped():
                 if kind == "token":
                     text = detok.push(payload)
-                    yield StreamChunk(index=idx, token_id=payload, text=text)
+                    yield StreamChunk(index=idx, token_id=payload, text=text, at=at)
                     idx += 1
                     continue
                 if isinstance(payload, BaseException):
@@ -1900,9 +1939,12 @@ class GenerationEngine:
         idle predicate).  Factored out of :meth:`_loop` so deterministic
         tests can crank iterations single-threaded (tests/test_contbatch.py's
         lockstep bit-identity rig)."""
+        span = self._ledger.span
         with self._iter_lock:  # excludes probe_decode (see there)
-            self._reap_dead_slots()
-            admitted = self._admit()
+            with span("reap"):
+                self._reap_dead_slots()
+            with span("admit"):  # its prefill dispatches open spans of their own
+                admitted = self._admit()
             ticked = False
             if self._chunking is not None:
                 if (
@@ -1917,7 +1959,8 @@ class GenerationEngine:
                     # final chunk always runs sequentially: its logits feed
                     # the activation (first-token sample), which is its own
                     # program.
-                    self._piggyback_step()
+                    with span("tick_issue", piggyback=1):
+                        self._piggyback_step()
                     ticked = True
                 else:
                     if self.num_active > 0:
@@ -1925,10 +1968,18 @@ class GenerationEngine:
                         # chunk — the displacement the piggybacked path
                         # exists to remove (prefill_displacement_frac)
                         self._prefill_displaced_ticks += 1
-                    self._chunk_step()
+                    with span(
+                        "prefill_dispatch",
+                        bucket=self.chunk_size,
+                        rows=1,
+                        rows_padded=1,
+                        chunk=self._chunking.step,
+                    ):
+                        self._chunk_step()
                 admitted = True
             if self.num_active > 0 and not ticked:
-                self._issue_tick()
+                with span("tick_issue"):
+                    self._issue_tick()
             # process results `lookahead` ticks behind; drain fully
             # when no slot is live (remaining in-flight ticks carry
             # final tokens)
@@ -1940,7 +1991,8 @@ class GenerationEngine:
             # double-buffer next tick's sampling/block-table
             # uploads against the ticks still in flight (the
             # finishes above are what dirtied the arrays)
-            self._prestage_uploads()
+            with span("prestage"):
+                self._prestage_uploads()
         return admitted
 
     def _loop(self):
@@ -1955,16 +2007,19 @@ class GenerationEngine:
                     # backoff escalates over CONSECUTIVE failures only)
                     self._consecutive_failures = 0
                     if not admitted and self.num_active == 0 and not self._inflight:
-                        self._sleep(self.idle_poll_s)
+                        with self._ledger.span("idle_wait"):
+                            self._sleep(self.idle_poll_s)
                 except Exception as e:
                     logger.exception(
                         "engine-fatal loop error; attempting crash-only restart"
                     )
-                    with self._iter_lock:
-                        self._restart(e)
+                    with self._ledger.span("recover"):
+                        with self._iter_lock:
+                            self._restart(e)
                     # bounded exponential backoff between restarts: a
                     # persistent device fault must not spin the loop hot
-                    self._backoff_after_failure()
+                    with self._ledger.span("idle_wait"):
+                        self._backoff_after_failure()
         finally:
             self._shutdown()
 
@@ -1987,9 +2042,10 @@ class GenerationEngine:
             return True
         # new work fast-fails in submit(); anything already queued keeps
         # honoring deadlines/cancels while the engine cools down
-        with self._iter_lock:
+        with self._iter_lock, self._ledger.span("reap"):
             self._reap_dead_slots()
-        self._sleep(min(0.05, max(0.0, (self._degraded_until or now) - now)))
+        with self._ledger.span("idle_wait"):
+            self._sleep(min(0.05, max(0.0, (self._degraded_until or now) - now)))
         return False
 
     def _backoff_after_failure(self) -> None:
@@ -2369,6 +2425,7 @@ class GenerationEngine:
                     break
             free.pop(0)
             self._count_prefix(req, hit)
+            req.prefix_hit_tokens = hit.length if hit is not None else 0
             if n_eff > self.chunk_size:
                 self._begin_chunked(slot, req, prefix=hit)
                 admitted = True
@@ -2726,45 +2783,56 @@ class GenerationEngine:
             max(len(r.prompt_ids) for r in reqs), self.prefill_buckets, self.chunk_size
         )
         Bp = pick_bucket(B, self._batch_buckets(), self.max_slots)
-        pad = Bp - B
-        ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
-        lengths = np.zeros((Bp,), np.int32)
-        # pad rows: legacy aliases the first real slot (the insert scan's row
-        # order makes the real row win); paged scatters with drop semantics,
-        # so pads carry the max_slots / page sentinels instead
-        slot_arr = np.full(
-            (Bp,), self.max_slots if self.paged else slots[0], np.int32
-        )
-        for j, req in enumerate(reqs):
-            n = len(req.prompt_ids)
-            ids[pad + j, :n] = req.prompt_ids
-            lengths[pad + j] = n
-            slot_arr[pad + j] = slots[j]
-        with self._mesh_scope():
-            logits, ks, vs = self._prefill(
-                self.params, jnp.asarray(ids), jnp.asarray(lengths)
+        with self._ledger.span("prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp):
+            pad = Bp - B
+            ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
+            lengths = np.zeros((Bp,), np.int32)
+            # pad rows: legacy aliases the first real slot (the insert scan's row
+            # order makes the real row win); paged scatters with drop semantics,
+            # so pads carry the max_slots / page sentinels instead
+            slot_arr = np.full(
+                (Bp,), self.max_slots if self.paged else slots[0], np.int32
             )
-            if self.paged:
-                self._cache = self._insert(
-                    self._cache,
-                    ks,
-                    vs,
-                    jnp.asarray(lengths),
-                    jnp.asarray(slot_arr),
-                    jnp.asarray(self._wave_block_tables(slots, pad)),
+            for j, req in enumerate(reqs):
+                n = len(req.prompt_ids)
+                ids[pad + j, :n] = req.prompt_ids
+                lengths[pad + j] = n
+                slot_arr[pad + j] = slots[j]
+            with self._mesh_scope():
+                logits, ks, vs = self._prefill(
+                    self.params, jnp.asarray(ids), jnp.asarray(lengths)
                 )
-            else:
-                self._cache = self._insert(
-                    self._cache, ks, vs, jnp.asarray(lengths), jnp.asarray(slot_arr)
-                )
-        # a miss with a declared prefix: capture its K/V for future requests
-        # (pure device slice, async — admission never blocks on it)
-        for slot, req in batch:
-            self._maybe_register_prefix(slot, req)
-        # activation consumes the FULL [Bp, V] logits so its (eager) sampling
-        # and scatter shapes key on the batch bucket, not the wave size —
-        # otherwise every distinct wave size would trigger fresh compiles
-        self._activate_batch(slots, reqs, logits, pad=pad)
+                if self.paged:
+                    self._cache = self._insert(
+                        self._cache,
+                        ks,
+                        vs,
+                        jnp.asarray(lengths),
+                        jnp.asarray(slot_arr),
+                        jnp.asarray(self._wave_block_tables(slots, pad)),
+                    )
+                else:
+                    self._cache = self._insert(
+                        self._cache, ks, vs, jnp.asarray(lengths), jnp.asarray(slot_arr)
+                    )
+            self._note_wave(reqs, int(lengths.sum()), bucket, Bp)
+            # a miss with a declared prefix: capture its K/V for future requests
+            # (pure device slice, async — admission never blocks on it)
+            for slot, req in batch:
+                self._maybe_register_prefix(slot, req)
+            # activation consumes the FULL [Bp, V] logits so its (eager) sampling
+            # and scatter shapes key on the batch bucket, not the wave size —
+            # otherwise every distinct wave size would trigger fresh compiles
+            self._activate_batch(slots, reqs, logits, pad=pad)
+
+    def _note_wave(self, reqs: List[_Request], real: int, bucket: int, Bp: int) -> None:
+        """One prefill program dispatched: the padding counters, and on each
+        request the shape of the program it rode (``usage.timings``)."""
+        self._ledger.note_prefill(real, Bp, bucket)
+        for req in reqs:
+            req.prefill_bucket = bucket
+            req.wave_rows = len(reqs)
+            req.wave_rows_padded = Bp
 
     def _start_suffix_batch(self, group: List[tuple[int, _Request, Any]]):
         """Admit a wave of prefix-cache hits: make each slot's cache row carry
@@ -2783,56 +2851,62 @@ class GenerationEngine:
             self.chunk_size,
         )
         Bp = pick_bucket(B, self._batch_buckets(), self.max_slots)
-        pad = Bp - B
-        ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
-        starts = np.zeros((Bp,), np.int32)
-        valids = np.zeros((Bp,), np.int32)
-        slot_arr = np.full(
-            (Bp,), self.max_slots if self.paged else slots[0], np.int32
-        )
-        for j, (req, hit) in enumerate(zip(reqs, hits)):
-            # the bucketed write window [start, start+bucket) must not cross
-            # max_seq_len — dynamic_update_slice would CLAMP the start and
-            # smear the window over the prefix.  Slide the window left instead
-            # (prefill_chunk's final-chunk discipline): the re-fed prefix
-            # tokens recompute to identical K/V at identical positions.
-            # (Paged hits never need the slide: _paged_usable_hit rejects
-            # them, because a slid window would re-write SHARED pages.)
-            start = min(hit.length, self.max_seq_len - bucket)
-            chunk = req.prompt_ids[start : start + bucket]
-            ids[pad + j, : len(chunk)] = chunk
-            starts[pad + j] = start
-            valids[pad + j] = len(chunk)
-            slot_arr[pad + j] = slots[j]
-        with self._mesh_scope():
-            if self.paged:
-                logits, self._cache = self._prefill_suffix(
-                    self.params,
-                    jnp.asarray(ids),
-                    self._cache,
-                    jnp.asarray(self._wave_block_tables(slots, pad)),
-                    jnp.asarray(slot_arr),
-                    jnp.asarray(starts),
-                    jnp.asarray(valids),
-                )
-            else:
-                for slot, hit in zip(slots, hits):
-                    self._cache = self._insert_prefix(
-                        self._cache, hit.pk, hit.pv, jnp.asarray(slot, jnp.int32)
+        with self._ledger.span(
+            "prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp, suffix=1
+        ):
+            pad = Bp - B
+            ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
+            starts = np.zeros((Bp,), np.int32)
+            valids = np.zeros((Bp,), np.int32)
+            slot_arr = np.full(
+                (Bp,), self.max_slots if self.paged else slots[0], np.int32
+            )
+            for j, (req, hit) in enumerate(zip(reqs, hits)):
+                # the bucketed write window [start, start+bucket) must not cross
+                # max_seq_len — dynamic_update_slice would CLAMP the start and
+                # smear the window over the prefix.  Slide the window left instead
+                # (prefill_chunk's final-chunk discipline): the re-fed prefix
+                # tokens recompute to identical K/V at identical positions.
+                # (Paged hits never need the slide: _paged_usable_hit rejects
+                # them, because a slid window would re-write SHARED pages.)
+                start = min(hit.length, self.max_seq_len - bucket)
+                chunk = req.prompt_ids[start : start + bucket]
+                ids[pad + j, : len(chunk)] = chunk
+                starts[pad + j] = start
+                valids[pad + j] = len(chunk)
+                slot_arr[pad + j] = slots[j]
+            with self._mesh_scope():
+                if self.paged:
+                    logits, self._cache = self._prefill_suffix(
+                        self.params,
+                        jnp.asarray(ids),
+                        self._cache,
+                        jnp.asarray(self._wave_block_tables(slots, pad)),
+                        jnp.asarray(slot_arr),
+                        jnp.asarray(starts),
+                        jnp.asarray(valids),
                     )
-                logits, self._cache = self._prefill_suffix(
-                    self.params,
-                    jnp.asarray(ids),
-                    self._cache,
-                    jnp.asarray(slot_arr),
-                    jnp.asarray(starts),
-                    jnp.asarray(valids),
-                )
-        # a hit whose DECLARED split extends past the matched prefix (multi-turn:
-        # the history grew) registers the longer prefix for the next turn
-        for slot, req in zip(slots, reqs):
-            self._maybe_register_prefix(slot, req)
-        self._activate_batch(slots, reqs, logits, pad=pad)
+                else:
+                    for slot, hit in zip(slots, hits):
+                        self._cache = self._insert_prefix(
+                            self._cache, hit.pk, hit.pv, jnp.asarray(slot, jnp.int32)
+                        )
+                    logits, self._cache = self._prefill_suffix(
+                        self.params,
+                        jnp.asarray(ids),
+                        self._cache,
+                        jnp.asarray(slot_arr),
+                        jnp.asarray(starts),
+                        jnp.asarray(valids),
+                    )
+            self._note_wave(
+                reqs, sum(len(r.prompt_ids) - h.length for r, h in zip(reqs, hits)), bucket, Bp
+            )
+            # a hit whose DECLARED split extends past the matched prefix (multi-turn:
+            # the history grew) registers the longer prefix for the next turn
+            for slot, req in zip(slots, reqs):
+                self._maybe_register_prefix(slot, req)
+            self._activate_batch(slots, reqs, logits, pad=pad)
 
     def _prefix_bucket(self, prefix_len: int) -> int:
         """Device shape for a cached prefix: the smallest prefill bucket that
@@ -2911,10 +2985,17 @@ class GenerationEngine:
             request=req, slot=slot, ids=ids, starts=starts, n=n
         )
 
+    def _note_chunk(self, st: "_ChunkedPrefill", j: int) -> None:
+        """Chunk ``j`` dispatched (alone or inside a tick): one row of
+        ``chunk_size``; the sliding last chunk re-feeds what it overlaps."""
+        new = self.chunk_size if j == 0 else st.starts[j] - st.starts[j - 1]
+        self._note_wave([st.request], new, self.chunk_size, 1)
+
     def _chunk_step(self):
         st = self._chunking
         assert st is not None
         j = st.step
+        self._note_chunk(st, j)
         with self._mesh_scope():
             if self.paged:
                 logits, self._cache = self._prefill_chunk(
@@ -2981,7 +3062,6 @@ class GenerationEngine:
         json/speculative state is live."""
         st = self._chunking
         assert st is not None and self._piggyback_tick is not None
-        t0 = self._clock()
         if self._faults is not None:
             # same chaos sites as the plain tick: a raise here is engine-
             # fatal mid-piggyback (the chaos case tests/test_contbatch.py
@@ -2993,6 +3073,7 @@ class GenerationEngine:
         self._refresh_sampling()
         self._decode_steps_effective = self.burst
         j = st.step
+        self._note_chunk(st, j)
         with self._mesh_scope():
             toks, last, self._cache, self._rng = self._piggyback_tick(
                 self.params,
@@ -3011,7 +3092,6 @@ class GenerationEngine:
         toks.copy_to_host_async()
         self._tokens_dev = last
         self.steps += self.burst
-        self._tick_issue_s += self._clock() - t0
         self._ticks_issued += 1
         self._kv_frac_sum += self._kv_read_frac()
         live = [
@@ -3171,10 +3251,13 @@ class GenerationEngine:
         means the device is the bottleneck and burst/slots are
         the knobs; `issue` dominating means dispatch enqueue is."""
         n = max(1, self._ticks_issued)
+        led = self._ledger
         out = {
             "ticks": self._ticks_issued,
-            "issue_ms": round(self._tick_issue_s / n * 1e3, 3),
-            "block_ms": round(self._tick_block_s / max(1, self._ticks_processed) * 1e3, 3),
+            "issue_ms": round(led.seconds("tick_issue") / n * 1e3, 3),
+            "block_ms": round(
+                led.seconds("tick_block") / max(1, self._ticks_processed) * 1e3, 3
+            ),
             # average fraction of the allocated KV cache the decode attention
             # actually read (< 1 whenever live contexts are shorter than the
             # allocation and the chunked read is on; 1.0 with it disabled)
@@ -3187,6 +3270,9 @@ class GenerationEngine:
         # (json_fsm slots downgrade to 1), the weight format's bit width,
         # and how much of the upload traffic the double-buffer absorbed
         out.update(self.decode_path_stats())
+        # running totals a window's difference can be taken of: the engine
+        # thread's time by loop phase, and what the prefill programs padded
+        out.update(self.loop_stats())
         if self.speculative:
             out.update(self.spec_stats())
         # KV memory plane gauges: pool occupancy, sharing fraction, allocator
@@ -3202,6 +3288,19 @@ class GenerationEngine:
             # queue-pressure snapshot: depth/pressure/shed/wait percentiles
             out["sched"] = self.scheduler.stats()
         return out
+
+    def loop_stats(self) -> dict:
+        """The engine-loop time ledger (serving/obs.py ``LoopLedger``), as
+        running totals: ``loop`` = ``{phase: {"s": exclusive seconds, "n":
+        spans}}`` over the engine thread's whole life (the phases tile its
+        wall time), and the prefill positions run: prompt tokens (``real``)
+        against rows x bucket of the dispatched programs (``padded``)."""
+        led = self._ledger
+        return {
+            "loop": led.snapshot(),
+            "prefill_tokens_real": led.prefill_tokens_real,
+            "prefill_tokens_padded": led.prefill_tokens_padded,
+        }
 
     def decode_path_stats(self) -> dict:
         """Decode fast-path gauges for tick_stats / /healthz / /metrics:
@@ -3552,7 +3651,6 @@ class GenerationEngine:
         input chains device-to-device from the previous tick (the rng state
         too); the sampled ids stream back asynchronously and are consumed by
         :meth:`_process_tick`."""
-        t0 = self._clock()
         if self._faults is not None:
             # deterministic chaos (serving/faults.py): a thrown device
             # dispatch (engine-fatal -> crash-only restart) or injected
@@ -3578,7 +3676,7 @@ class GenerationEngine:
                 if rung is None:
                     self.spec_skipped_accept += 1
                 else:
-                    self._issue_spec_tick(t0, rung)
+                    self._issue_spec_tick(rung)
                     return
         # (a load- or acceptance-disabled speculative engine falls through to
         # the plain tick: _decode_tick is built at the same decode_steps
@@ -3622,7 +3720,6 @@ class GenerationEngine:
         toks.copy_to_host_async()
         self._tokens_dev = last
         self.steps += issued_steps
-        self._tick_issue_s += self._clock() - t0
         self._ticks_issued += 1
         self._kv_frac_sum += self._kv_read_frac()
         live = [
@@ -3630,7 +3727,7 @@ class GenerationEngine:
         ]
         self._inflight.append(_TickRef(nxt=toks, slots=live))
 
-    def _issue_spec_tick(self, t0: float, rung: tuple):
+    def _issue_spec_tick(self, rung: tuple):
         """Dispatch one fused tree-speculative tick at the controller's
         current (width, depth) rung (draft + verify + accept + commit on
         device, chained state — same pipelining discipline as the burst
@@ -3656,7 +3753,6 @@ class GenerationEngine:
         self.steps += self.burst
         self._decode_steps_effective = self.burst
         self.spec_ticks_issued += 1
-        self._tick_issue_s += self._clock() - t0
         self._ticks_issued += 1
         self._kv_frac_sum += 1.0  # the tree verify reads the full cache row
         live = [
@@ -3668,23 +3764,25 @@ class GenerationEngine:
 
     def _process_tick(self):
         """Consume the oldest in-flight result (blocks until it arrives)."""
-        try:
-            self._process_tick_inner()
-        finally:
-            # deferred stream wakeups: one notify per touched stream per tick
-            # (see TokenStream.push_token), flushed even on a mid-tick error
-            # so no consumer is left waiting on already-appended events
-            if self._stream_notify:
-                for st in self._stream_notify:
-                    st.notify_now()
-                self._stream_notify.clear()
-
-    def _process_tick_inner(self):
         ref = self._inflight.popleft()
-        t0 = self._clock()
-        vals = np.asarray(ref.nxt)
-        block_s = self._clock() - t0
-        self._tick_block_s += block_s
+        led = self._ledger
+        blocked = led.seconds("tick_block")
+        with led.span("tick_block"):
+            vals = np.asarray(ref.nxt)
+        with led.span("consume"):
+            try:
+                self._process_tick_inner(ref, vals, led.seconds("tick_block") - blocked)
+            finally:
+                # deferred stream wakeups: one notify per touched stream per
+                # tick (see TokenStream.push_token), flushed even on a
+                # mid-tick error so no consumer is left waiting on
+                # already-appended events
+                if self._stream_notify:
+                    for st in self._stream_notify:
+                        st.notify_now()
+                    self._stream_notify.clear()
+
+    def _process_tick_inner(self, ref: "_TickRef", vals, block_s: float):
         self._ticks_processed += 1
         if self.obs is not None:
             # tick-duration histogram + periodic flight-ring summary — host
@@ -3710,6 +3808,7 @@ class GenerationEngine:
                 if s is None or self._slot_epoch[slot] != epoch:
                     continue
                 s.resident_steps += 1
+                s.decode_ticks += 1
                 self._consume_token(slot, s, int(vals[ref.offset + j]), now)
             return
         if ref.n_new is not None:  # speculative tick: variable tokens/slot
@@ -3727,6 +3826,8 @@ class GenerationEngine:
                     # step; charging the tokens committed keeps the per-token
                     # service rate honest on speculative engines too
                     s.resident_steps += max(1, n)
+                    if step == 0:
+                        s.decode_ticks += 1
                     # greedy rows proposed K drafts and n-1 were accepted
                     if s.request.temperature <= 0:
                         self.spec_drafted += K
@@ -3755,6 +3856,7 @@ class GenerationEngine:
             s = self._slots[slot]
             if s is not None and self._slot_epoch[slot] == epoch:
                 s.resident_steps += vals.shape[0]
+                s.decode_ticks += 1
         for k in range(vals.shape[0]):  # fused-tick steps, oldest first
             for slot, epoch in ref.slots:
                 s = self._slots[slot]
@@ -3806,7 +3908,9 @@ class GenerationEngine:
                 self.obs.on_token_gap(now - s.last_token_at)
         s.last_token_at = now
         if req.stream is not None and tok != self.tokenizer.eos_id:
-            if req.stream.push_token(tok, notify=False):
+            # `at=now`: tokens of one tick share the stamp this method was
+            # handed — the server measures stream lag from it, no clock read here
+            if req.stream.push_token(tok, notify=False, at=now):
                 self._stream_notify.add(req.stream)
 
     def _should_finish(self, slot: int, tok: int) -> bool:
@@ -3863,14 +3967,39 @@ class GenerationEngine:
             _safe_resolve(req.future, exc=e)
             return
         detok_s = max(0.0, self._clock() - now)
+        # usage.timings (serving/obs.py TIMING_KEYS): consecutive spans from
+        # receipt to here, so queue_s + prefill_s is ttft_s and adding
+        # decode_s gives latency_s; `now` is where the last token was
+        # consumed (s.last_token_at is the stamp of the tick that carried it)
+        first = req.first_token_at if req.first_token_at is not None else now
+        started = min(req.started_at if req.started_at is not None else first, first)
+        received = req.received_at if req.received_at is not None else req.submitted_at
+        timings = {
+            "recv_mono_s": received,
+            "encode_s": max(0.0, req.submitted_at - received),
+            "queue_s": started - req.submitted_at,
+            "prefill_s": first - started,
+            "decode_s": now - first,
+            "detok_s": detok_s,
+            "prefill_bucket": req.prefill_bucket,
+            "wave_rows": req.wave_rows,
+            "wave_rows_padded": req.wave_rows_padded,
+            "prefill_chunks": s.prefill_chunks,
+            "prefix_hit_tokens": req.prefix_hit_tokens,
+            "decode_ticks": s.decode_ticks,
+            # fused-tick steps the slot sat through; the activation's one
+            # (its first token came with the prefill) is not a decode step
+            "decode_steps": max(0, s.resident_steps - 1),
+        }
         result = GenerationResult(
             token_ids=ids,
             text=text,
             prompt_tokens=len(req.prompt_ids),
             completion_tokens=len(ids),
             length_limited=not hit_eos,
-            ttft_s=(req.first_token_at or now) - req.submitted_at,
+            ttft_s=first - req.submitted_at,
             latency_s=now - req.submitted_at,
+            timings=timings,
         )
         if self.scheduler is not None:
             # feed the estimated-wait admission model with true service time:
@@ -3891,9 +4020,9 @@ class GenerationEngine:
                 tokens=max(1, s.resident_steps + s.prefill_chunks),
             )
         if self.obs is not None:
-            # close the request's span trace from the host timestamps the
-            # tick path already stamped — deliver is the resolve below
-            self.obs.on_finish(req, result, now=now + detok_s, detok_s=detok_s)
+            # close the request's trace: the ring keeps `timings` itself, so
+            # the deliver span the server stamps later shows up there too
+            self.obs.on_finish(req, result)
         _safe_resolve(req.future, result=result)
 
     def _quarantine(self, slot: int, err: BaseException) -> None:

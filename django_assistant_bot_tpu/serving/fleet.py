@@ -598,6 +598,7 @@ class FleetResult:
         reroutes: int,
         trace_id: str,
         handoff: Optional[dict] = None,
+        timings: Optional[dict] = None,
     ):
         self.token_ids = token_ids
         self.text = text
@@ -608,15 +609,21 @@ class FleetResult:
         self.reroutes = reroutes
         self.trace_id = trace_id
         self.handoff = handoff
+        # the serving peer's usage.timings, unchanged: its spans are on ITS
+        # clock (recv_mono_s is that process's), wire time is not in them
+        self.timings = timings
 
     def usage_dict(self, model: str) -> dict:
-        return {
+        out = {
             "model": model,
             "prompt_tokens": self.prompt_tokens,
             "completion_tokens": self.completion_tokens,
             "total_tokens": self.prompt_tokens + self.completion_tokens,
             "peer": self.peer,
         }
+        if self.timings is not None:
+            out["timings"] = dict(self.timings)
+        return out
 
 
 # -------------------------------------------------------------- fleet router
@@ -982,6 +989,7 @@ class FleetRouter:
         stream: Any = None,
         trace_id: Optional[str] = None,
         attempt: int = 0,
+        received_at: Optional[float] = None,
     ) -> Future:
         """The :meth:`EngineRouter.submit` contract over the wire.  Returns
         a ``Future[FleetResult]``; raises synchronously only for contract
@@ -990,7 +998,11 @@ class FleetRouter:
         ``attempt`` is the CALLER's retry ordinal: it feeds the idempotency
         key (``trace_id:attempt``), so a caller-level retry that WANTS a
         fresh execution bumps it, while the router's own internal
-        timeout-retries reuse the same key and dedup server-side."""
+        timeout-retries reuse the same key and dedup server-side.
+
+        ``received_at`` is accepted for the contract's sake and not sent: it
+        is a stamp on this process's clock, and the serving peer stamps its
+        own receipt (``usage.timings`` comes back as the peer made it)."""
         if stream is not None:
             raise ValueError(
                 "FleetRouter does not stream across processes; send streaming "
@@ -1412,6 +1424,7 @@ class FleetRouter:
             reroutes=st.hops,
             trace_id=st.trace_id,
             handoff=resp.get("handoff"),
+            timings=usage.get("timings"),
         )
 
     # ----------------------------------------------------------------- stats

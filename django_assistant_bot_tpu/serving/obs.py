@@ -9,11 +9,22 @@ module is the missing layer, three pillars in one place:
 - **Per-request tracing.**  Every request carries a ``trace_id`` (client
   ``X-Request-Id`` or generated at admission) on the engine's ``_Request``,
   across router re-route hops, and over the ``gpu_service:`` provider wire.
-  Span timings come from host-side timestamps the tick path already stamps
-  (``submitted_at`` / ``started_at`` / ``first_token_at`` / finish) — the
-  recorder adds ZERO device syncs, enforced mechanically by dabtlint's
-  DABT104 hot-path registry (the ``EngineObs.on_*`` entry points are roots).
-  Completed traces land in a bounded ring (:meth:`EngineObs.traces`).
+  Span timings come from host-side timestamps stamped where the work
+  happens (receipt at the socket, ``submitted_at`` / ``started_at`` /
+  ``first_token_at`` / finish in the engine, the terminal event's write in
+  the server) — the recorder adds ZERO device syncs, enforced mechanically
+  by dabtlint's DABT104 hot-path registry (the ``EngineObs.on_*`` entry
+  points are roots).  The same stamps travel to the client as
+  ``usage.timings`` (:data:`TIMING_KEYS`); completed traces land in a
+  bounded ring (:meth:`EngineObs.traces`) whose span list is built from
+  that one dict (:func:`request_spans`).
+- **Engine-loop time ledger.**  :class:`LoopLedger` / ``span(name)``: every
+  step of the engine thread's loop adds its elapsed time to a per-phase
+  total on the injectable clock AND, while a profiler session runs, wraps
+  the same interval in ``jax.profiler.TraceAnnotation("dabt/<name>")`` — a
+  host event on the device trace's own clock.  The ledger is the engine's
+  own (it exists with ``obs=False`` too); ``tick_stats()["loop"]`` and the
+  ``dabt_engine_loop_*`` families read it.
 - **Prometheus metrics.**  Fixed-bucket :class:`Histogram` state updated from
   ``_process_tick``'s host bookkeeping (TTFT, inter-token latency, queue
   wait, tick duration, speculative accept ratio) plus the existing
@@ -447,6 +458,158 @@ class FlightRecorder:
         return path
 
 
+# ------------------------------------------------------------ loop time ledger
+# The engine loop's phases, in the order one iteration runs them.  Exclusive
+# times: a span opened inside another (``prefill_dispatch`` inside ``admit``)
+# pauses its parent, so the totals tile the engine thread's wall time.
+LOOP_PHASES: Tuple[str, ...] = (
+    "reap",              # _reap_dead_slots: deadlines, cancelled futures
+    "admit",             # _admit bookkeeping: queue drain, prefix lookup, pages
+    "prefill_dispatch",  # _start_batch / _start_suffix_batch / _chunk_step
+    "tick_issue",        # _issue_tick / _piggyback_step: one decode dispatch
+    "tick_block",        # np.asarray(ref.nxt): waiting for a tick's result
+    "consume",           # token bookkeeping, detokenise at finish, stream wake-ups
+    "prestage",          # _prestage_uploads
+    "idle_wait",         # the idle / degraded / backoff sleep
+    "recover",           # crash-only restart after an engine-fatal error
+)
+TRACE_PREFIX = "dabt/"
+
+
+class _PhaseSpan:
+    """One phase's reusable enter/exit pair (phases never nest in themselves,
+    so the hot path allocates nothing but the profiler's own annotation)."""
+
+    __slots__ = ("_ledger", "name", "_label", "_t0", "_args", "_ann", "s", "n")
+
+    def __init__(self, ledger: "LoopLedger", name: str):
+        self._ledger = ledger
+        self.name = name
+        self._label = TRACE_PREFIX + name
+        self._t0 = 0.0
+        self._args: Optional[dict] = None
+        self._ann: Any = None
+        self.s = 0.0  # exclusive seconds
+        self.n = 0  # spans closed
+
+    def __enter__(self) -> "_PhaseSpan":
+        led = self._ledger
+        now = led._clock()
+        stack = led._stack
+        if stack:
+            parent = stack[-1]
+            parent.s += now - parent._t0
+        stack.append(self)
+        self._t0 = now
+        if led._annotation.is_enabled():  # one flag test while no session runs
+            self._ann = led._annotation(self._label, **(self._args or {}))
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
+        led = self._ledger
+        now = led._clock()
+        self.s += now - self._t0
+        self.n += 1
+        stack = led._stack
+        stack.pop()
+        if stack:
+            stack[-1]._t0 = now
+        return False
+
+
+class LoopLedger:
+    """Where the engine thread's time went, by phase (see :data:`LOOP_PHASES`).
+
+    ``with ledger.span("tick_issue"):`` costs two reads of the injectable
+    clock and one flag test.  While a ``jax.profiler`` session runs, the
+    interval is also a ``dabt/<name>`` event on the calling thread's host
+    line of the same xplane the device's events land in, on its clock
+    (nested there, exclusive here).  Written by the engine thread only;
+    :meth:`snapshot` may be read from any thread (each total is one float).
+
+    Also carries the prefill padding counters: tokens the prompts held
+    (``real``) against rows x bucket of the programs that ran them
+    (``padded``)."""
+
+    def __init__(
+        self,
+        clock: Callable[[], float],
+        phases: Tuple[str, ...] = LOOP_PHASES,
+        annotation: Any = None,
+    ):
+        if annotation is None:
+            from jax.profiler import TraceAnnotation as annotation
+        self._clock = clock
+        self._annotation = annotation
+        self._stack: List[_PhaseSpan] = []
+        self._spans: Dict[str, _PhaseSpan] = {p: _PhaseSpan(self, p) for p in phases}
+        self.prefill_tokens_real = 0
+        self.prefill_tokens_padded = 0
+
+    def span(self, name: str, **args: Any) -> _PhaseSpan:
+        """The phase's enter/exit pair; ``args`` become the profiler
+        annotation's arguments (bucket, rows, ...) when a session runs."""
+        sp = self._spans[name]
+        sp._args = args or None
+        return sp
+
+    def note_prefill(self, real: int, rows_padded: int, bucket: int) -> None:
+        self.prefill_tokens_real += int(real)
+        self.prefill_tokens_padded += int(rows_padded) * int(bucket)
+
+    def seconds(self, name: str) -> float:
+        return self._spans[name].s
+
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        """``{phase: {"s": exclusive seconds, "n": spans closed}}``."""
+        return {p: {"s": sp.s, "n": sp.n} for p, sp in self._spans.items()}
+
+
+# --------------------------------------------------------- per-request timings
+# The keys of ``usage.timings`` (docs/OBSERVABILITY.md "usage.timings").  The
+# engine fills the first block at finish; the server adds ``deliver_s`` and
+# the ``stream_*`` keys when it hands the terminal event to the socket.
+TIMING_KEYS: Tuple[str, ...] = (
+    "recv_mono_s", "encode_s", "queue_s", "prefill_s", "decode_s", "detok_s",
+    "deliver_s", "stream_lag_max_s", "stream_lag_sum_s", "stream_events",
+    "prefill_bucket", "wave_rows", "wave_rows_padded", "prefill_chunks",
+    "prefix_hit_tokens", "decode_ticks", "decode_steps",
+)
+# (span name, timings key) in wire order: consecutive, so they tile the
+# interval from receipt to the terminal event's write
+_SPAN_KEYS: Tuple[Tuple[str, str], ...] = (
+    ("encode", "encode_s"),
+    ("queue_wait", "queue_s"),
+    ("prefill", "prefill_s"),
+    ("decode", "decode_s"),
+    ("detok", "detok_s"),
+    ("deliver", "deliver_s"),
+)
+
+
+def request_spans(timings: Mapping[str, Any], *, tokens: Optional[int] = None) -> List[dict]:
+    """The ``/traces`` span list of one request, from its ``timings``: a
+    ``request`` root from receipt to the last stamp known, and its children
+    back to back (``t_s`` is seconds since receipt).  ``deliver`` appears
+    once the server has written the terminal event (streamed responses)."""
+    spans: List[dict] = []
+    t = 0.0
+    for name, key in _SPAN_KEYS:
+        dur = timings.get(key)
+        if dur is None:
+            continue
+        sp = {"name": name, "parent": "request", "t_s": round(t, 6), "dur_s": round(dur, 6)}
+        if name == "decode" and tokens is not None:
+            sp["tokens"] = tokens
+        spans.append(sp)
+        t += dur
+    return [{"name": "request", "parent": None, "t_s": 0.0, "dur_s": round(t, 6)}] + spans
+
+
 # ----------------------------------------------------------------- engine obs
 class EngineObs:
     """Per-engine observability: span traces, metric histograms, flight ring.
@@ -524,31 +687,13 @@ class EngineObs:
             "shed", trace_id=trace_id, reason=reason, priority=priority
         )
 
-    def on_finish(self, req: Any, result: Any, *, now: float, detok_s: float) -> None:
-        """Close a request's trace from the host timestamps the tick path
-        already stamped; observes queue-wait and appends to the trace ring."""
-        t0 = req.submitted_at
-        started = req.started_at if req.started_at is not None else t0
-        first = req.first_token_at if req.first_token_at is not None else now
-        queue_wait = max(0.0, started - t0)
-        self.queue_wait_s.observe(queue_wait)
-        spans = [
-            {"name": "admit", "t_s": 0.0},
-            {"name": "queue_wait", "t_s": 0.0, "dur_s": round(queue_wait, 6)},
-            {
-                "name": "prefill",
-                "t_s": round(started - t0, 6),
-                "dur_s": round(max(0.0, first - started), 6),
-            },
-            {
-                "name": "decode",
-                "t_s": round(first - t0, 6),
-                "dur_s": round(max(0.0, now - first - detok_s), 6),
-                "tokens": result.completion_tokens,
-            },
-            {"name": "detok", "t_s": round(now - t0 - detok_s, 6), "dur_s": round(detok_s, 6)},
-            {"name": "deliver", "t_s": round(now - t0, 6)},
-        ]
+    def on_finish(self, req: Any, result: Any) -> None:
+        """Close a request's trace: observes queue-wait and appends the
+        record to the trace ring.  The record keeps ``result.timings`` itself
+        (one dict: what ``usage.timings`` carries, and what the server later
+        adds ``deliver_s`` to); :meth:`traces` builds the span list from it."""
+        tm = result.timings
+        self.queue_wait_s.observe(tm["queue_s"])
         trace = {
             "trace_id": req.trace_id,
             "engine": self.name,
@@ -560,9 +705,8 @@ class EngineObs:
             # submission stamp in the engine's monotonic clock domain: only
             # DIFFERENCES are meaningful, which is exactly what the workload
             # trace export needs (relative arrival offsets — workload/capture.py)
-            "t_submit_s": round(t0, 6),
-            "total_s": round(now - t0, 6),
-            "spans": spans,
+            "t_submit_s": round(req.submitted_at, 6),
+            "timings": tm,
         }
         with self._lock:
             self._traces.append(trace)
@@ -571,18 +715,29 @@ class EngineObs:
             "finish",
             trace_id=req.trace_id,
             tokens=result.completion_tokens,
-            total_s=round(now - t0, 4),
+            total_s=round(result.latency_s + tm["detok_s"], 4),
         )
+
+    @staticmethod
+    def _rendered(trace: dict) -> dict:
+        """A ring record as ``/traces`` shows it: spans and their total from
+        the timings as they stand now (``deliver`` once the server wrote)."""
+        out = dict(trace)
+        out["timings"] = dict(trace["timings"])
+        out["spans"] = request_spans(out["timings"], tokens=trace["completion_tokens"])
+        out["total_s"] = out["spans"][0]["dur_s"]
+        return out
 
     def traces(self) -> List[dict]:
         with self._lock:
-            return list(self._traces)
+            raw = list(self._traces)
+        return [self._rendered(t) for t in raw]
 
     def trace(self, trace_id: str) -> Optional[dict]:
         with self._lock:
             for t in reversed(self._traces):
                 if t["trace_id"] == trace_id:
-                    return t
+                    return self._rendered(t)
         return None
 
 
@@ -751,6 +906,17 @@ def render_prometheus(registry: Any) -> str:
         x.add("dabt_engine_circuit_trips_total", "counter", "restart-circuit trips", sup["circuit_trips"], lab)
         x.add("dabt_engine_restart_resubmitted_total", "counter", "token-less requests salvaged across restarts", sup["restarted_requests_resubmitted"], lab)
         x.add("dabt_engine_reclaimed_slots_total", "counter", "slots reclaimed before finish (deadline/cancel)", eng.reclaimed_slots, lab)
+        led_fn = getattr(eng, "loop_stats", None)
+        if callable(led_fn):
+            # engine-loop time ledger (LoopLedger): where the engine thread's
+            # time went, and what the prefill programs padded
+            ls = led_fn()
+            for phase, tot in ls["loop"].items():
+                plab = {**lab, "phase": phase}
+                x.add("dabt_engine_loop_seconds_total", "counter", "engine-thread seconds by loop phase (exclusive)", tot["s"], plab)
+                x.add("dabt_engine_loop_spans_total", "counter", "engine-loop spans closed, by phase", tot["n"], plab)
+            x.add("dabt_prefill_tokens_total", "counter", "prefill positions: prompt tokens run (real) vs rows x bucket of the programs (padded)", ls["prefill_tokens_real"], {**lab, "kind": "real"})
+            x.add("dabt_prefill_tokens_total", "counter", "prefill positions: prompt tokens run (real) vs rows x bucket of the programs (padded)", ls["prefill_tokens_padded"], {**lab, "kind": "padded"})
         dec_fn = getattr(eng, "decode_path_stats", None)
         if callable(dec_fn):
             # decode fast-path gauges (docs/QUANT.md): configured vs

@@ -181,9 +181,10 @@ class ModelSpec:
     max_request_restarts: int = 2
     # --- observability (serving/obs.py; docs/OBSERVABILITY.md) ---
     # per-request span traces, /metrics histograms, and the crash flight
-    # recorder.  On by default (host-side bookkeeping only — the bench's
-    # obs_* A/B keeps the overhead claim within noise); False is the
-    # rollback/A-B arm: no recorder object exists at all.
+    # recorder.  On by default (host-side bookkeeping only; its cost was
+    # measured on the chip: PERF.md section 6, PR 24); False leaves no
+    # recorder object at all — the engine's loop ledger and usage.timings
+    # are not part of it and stay.
     obs: bool = True
     # flight-recorder dump directory (None = DABT_FLIGHT_DIR env, else
     # <tmpdir>/dabt-flight)

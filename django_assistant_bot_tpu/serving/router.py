@@ -259,10 +259,12 @@ class _StreamShim:
         self.inner = inner
         self.tokens = 0
 
-    def push_token(self, tok: int, *, notify: bool = True) -> bool:
+    def push_token(
+        self, tok: int, *, notify: bool = True, at: Optional[float] = None
+    ) -> bool:
         self.tokens += 1
         if self.inner is not None:
-            return self.inner.push_token(tok, notify=notify)
+            return self.inner.push_token(tok, notify=notify, at=at)
         return False
 
     def notify_now(self) -> None:
@@ -548,6 +550,7 @@ class EngineRouter:
         deadline_s: Optional[float] = None,
         stream: Any = None,
         trace_id: Optional[str] = None,
+        received_at: Optional[float] = None,
     ) -> Future:
         """Thread-safe fleet submission; returns Future[GenerationResult].
 
@@ -580,6 +583,9 @@ class EngineRouter:
                 # the flight-recorder events of each replica carry ONE id —
                 # a failed leg and its retry correlate by trace_id alone
                 trace_id=trace_id or new_trace_id(),
+                # receipt at the socket rides every hop: usage.timings'
+                # encode_s then includes the routing (and any re-route)
+                **({} if received_at is None else {"received_at": received_at}),
             ),
             outer,
             _StreamShim(stream),

@@ -24,7 +24,8 @@ import json
 import logging
 import math
 import re
-from typing import Any, Mapping, Optional
+import time
+from typing import Any, Callable, Mapping, Optional
 
 from aiohttp import web
 
@@ -176,8 +177,53 @@ def _sse(payload) -> bytes:
     return f"data: {data}\n\n".encode("utf-8")
 
 
+class _StreamLag:
+    """Engine stamp of a token -> the moment its delta is handed to the
+    socket, over one streamed response: what the asyncio hop, the
+    detokenizer and the event loop's backlog add between tokens."""
+
+    __slots__ = ("max_s", "sum_s", "events")
+
+    def __init__(self) -> None:
+        self.max_s = 0.0
+        self.sum_s = 0.0
+        self.events = 0
+
+    def note(self, at: Optional[float], now: float) -> None:
+        if at is None:
+            return
+        lag = max(0.0, now - at)
+        self.events += 1
+        self.sum_s += lag
+        if lag > self.max_s:
+            self.max_s = lag
+
+    def close(self, timings: Optional[dict], now: float) -> None:
+        """Stamp the terminal event's hand-over into the request's timings
+        (the dict the /traces record shares): ``deliver_s`` runs from the end
+        of the engine's detokenisation, so the spans stay back to back."""
+        if timings is None:
+            return
+        finished = timings["recv_mono_s"] + sum(
+            timings[k] for k in ("encode_s", "queue_s", "prefill_s", "decode_s", "detok_s")
+        )
+        timings.update(
+            deliver_s=max(0.0, now - finished),
+            stream_lag_max_s=self.max_s,
+            stream_lag_sum_s=self.sum_s,
+            stream_events=self.events,
+        )
+
+
 async def _stream_dialog(
-    request: web.Request, eng, model: str, messages, rid: str, **gen_kwargs
+    request: web.Request,
+    eng,
+    model: str,
+    messages,
+    rid: str,
+    *,
+    clock: Callable[[], float],
+    **gen_kwargs,
 ) -> web.StreamResponse:
     """``"stream": true`` -> ``text/event-stream`` (wire format in
     docs/STREAMING.md): one ``data:`` event per emitted text delta, a terminal
@@ -215,6 +261,7 @@ async def _stream_dialog(
         },
     )
     await resp.prepare(request)
+    lag = _StreamLag()
     try:
         chunk = first
         while chunk is not None:
@@ -224,6 +271,7 @@ async def _stream_dialog(
                         _sse({"delta": chunk.text, "index": chunk.index})
                     )
                 r = chunk.result
+                lag.close(r.timings, clock())
                 await resp.write(
                     _sse(
                         {
@@ -238,6 +286,7 @@ async def _stream_dialog(
                 )
                 break
             if chunk.text:
+                lag.note(chunk.at, clock())
                 await resp.write(_sse({"delta": chunk.text, "index": chunk.index}))
             try:
                 chunk = await agen.__anext__()
@@ -278,8 +327,14 @@ async def _stream_dialog(
 
 
 def create_app(
-    registry: ModelRegistry, *, drain_deadline_s: float = 30.0
+    registry: ModelRegistry,
+    *,
+    drain_deadline_s: float = 30.0,
+    clock: Callable[[], float] = time.monotonic,
 ) -> web.Application:
+    """``clock`` stamps a request's receipt and its events' hand-over to the
+    socket; it has to be the engines' clock (``time.monotonic`` in
+    production) for ``usage.timings`` to tile."""
     app = web.Application()
     app[REGISTRY_KEY] = registry
     # graceful-drain state (the SIGTERM path, docs/RESILIENCE.md): once the
@@ -316,6 +371,7 @@ def create_app(
             return web.json_response({"detail": str(e)}, status=500)
 
     async def dialog(request: web.Request) -> web.Response:
+        received_at = clock()  # usage.timings: the start of encode_s
         rid = _request_id(request)
         if drain["draining"]:
             return _draining_response(rid)
@@ -354,12 +410,14 @@ def create_app(
                 model,
                 messages,
                 rid,
+                clock=clock,
                 max_tokens=max_tokens,
                 temperature=temperature,
                 top_p=top_p,
                 priority=priority,
                 tenant=tenant,
                 deadline_s=deadline_s,
+                received_at=received_at,
             )
         try:
             # json_format enables grammar-constrained decoding: a JSON token-FSM
@@ -377,6 +435,7 @@ def create_app(
                 tenant=tenant,
                 deadline_s=deadline_s,
                 trace_id=rid,
+                received_at=received_at,
             )
             return web.json_response(
                 {
@@ -579,6 +638,7 @@ def create_app(
         same sampling/scheduling validation as /dialog/, plus the fleet
         extras: prefix_len (warm-prefix restore), prefill_only + push_to
         (the disaggregated handoff), and force (pool-role bypass)."""
+        received_at = clock()
         rid = _request_id(request)
         if drain["draining"]:
             return _draining_response(rid)
@@ -669,6 +729,7 @@ def create_app(
                     tenant=tenant,
                     deadline_s=deadline_s,
                     trace_id=trace_id,
+                    received_at=received_at,
                 )
                 result = await asyncio.wrap_future(fut)
             except SchedulerRejected as e:

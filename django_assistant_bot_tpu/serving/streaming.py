@@ -51,7 +51,9 @@ class StreamChunk:
     terminal chunk has ``done=True``, the ``finish_reason`` (``"stop"`` on
     EOS, ``"length"`` when length-limited), any held-back text tail, and the
     full :class:`~.engine.GenerationResult` — whose ``text`` equals the
-    concatenation of every ``text`` delta, byte for byte."""
+    concatenation of every ``text`` delta, byte for byte.  ``at`` is the
+    engine's stamp of the tick that carried the token (its clock; None on the
+    terminal chunk): the server measures stream lag from it."""
 
     index: int
     token_id: Optional[int]
@@ -59,6 +61,7 @@ class StreamChunk:
     done: bool = False
     finish_reason: Optional[str] = None
     result: Any = None
+    at: Optional[float] = None
 
 
 class TokenStream:
@@ -72,7 +75,7 @@ class TokenStream:
     """
 
     def __init__(self) -> None:
-        self._events: "collections.deque[Tuple[str, Any]]" = collections.deque()
+        self._events: "collections.deque[Tuple[str, Any, Optional[float]]]" = collections.deque()
         self._lock = threading.Lock()
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._wake: Optional[asyncio.Event] = None
@@ -94,8 +97,12 @@ class TokenStream:
         return self
 
     # --------------------------------------------------------- producer side
-    def push_token(self, tok: int, *, notify: bool = True) -> bool:
-        """Append a token event.  With ``notify=False`` the wakeup is the
+    def push_token(
+        self, tok: int, *, notify: bool = True, at: Optional[float] = None
+    ) -> bool:
+        """Append a token event; ``at`` is the producer's stamp of it (the
+        engine passes the ``now`` its tick already holds: no clock read per
+        token).  With ``notify=False`` the wakeup is the
         caller's responsibility (:meth:`notify_now`) — the engine defers it to
         the end of its tick processing so a burst of pushes costs ONE
         cross-thread wakeup per stream per tick, fired right before the
@@ -110,7 +117,7 @@ class TokenStream:
                 # engine thread on a consumer — drop and count instead
                 self.dropped += 1
                 return False
-            self._events.append(("token", tok))
+            self._events.append(("token", tok, at))
             need_notify = not self._notify_pending
             self._notify_pending = True
         if need_notify and notify:
@@ -133,7 +140,7 @@ class TokenStream:
             if self._closed:
                 return
             self._closed = True
-            self._events.append(("done", payload))
+            self._events.append(("done", payload, None))
             self._notify_pending = True
         # terminal always notifies: it must never coalesce into a wakeup the
         # consumer already consumed
@@ -150,6 +157,12 @@ class TokenStream:
 
     # --------------------------------------------------------- consumer side
     async def __aiter__(self) -> AsyncIterator[Tuple[str, Any]]:
+        async for kind, payload, _ in self.stamped():
+            yield kind, payload
+
+    async def stamped(self) -> AsyncIterator[Tuple[str, Any, Optional[float]]]:
+        """The events with the producer's stamp of each: ``(kind, payload,
+        at)``."""
         assert self._wake is not None, "bind() the consumer loop before iterating"
         while True:
             self._wake.clear()

@@ -345,6 +345,34 @@ def test_dabt104_obs_recorder_entry_points_are_roots(tmp_path):
     assert "EngineObs.on_finish" not in by_symbol
 
 
+@pytest.mark.parametrize(
+    "relpath, pattern",
+    [
+        ("serving/obs.py", "*_PhaseSpan.__enter__"),
+        ("serving/obs.py", "*_PhaseSpan.__exit__"),
+        ("serving/obs.py", "*LoopLedger.span"),
+        ("serving/obs.py", "*LoopLedger.seconds"),
+        ("serving/obs.py", "*LoopLedger.note_prefill"),
+        ("serving/server.py", "*_StreamLag.note"),
+    ],
+)
+def test_loop_ledger_entry_points_are_registered_hot_path_roots(relpath, pattern):
+    """The span enter/exit pair runs around every dispatch and result wait of
+    the engine loop, the stream-lag stamp between a token and its write: each
+    registry pattern names a real function (a rename would silently un-root
+    it), and the shipped modules stay DABT105-disciplined."""
+    import fnmatch
+
+    from dabtlint.checks import HOT_PATH_PATTERNS, _module_has_clock_convention
+    from dabtlint.project import Project
+
+    path = REPO_ROOT / "django_assistant_bot_tpu" / relpath
+    (mod,) = Project.load([str(path)]).modules
+    assert pattern in HOT_PATH_PATTERNS
+    assert any(fnmatch.fnmatch(q, pattern) for q in mod.functions), sorted(mod.functions)[:40]
+    assert _module_has_clock_convention(mod)
+
+
 def test_real_obs_module_is_hot_path_clean_and_clock_disciplined():
     """The shipped serving/obs.py: its recorder entry points are in the
     hot-path registry and the module carries the DABT105 injectable-clock

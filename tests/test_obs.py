@@ -174,16 +174,21 @@ def test_trace_id_propagates_and_spans_close(tmp_path):
         tr = eng.obs.trace("req-1")
         assert tr is not None and tr["engine"] == "traced"
         names = [s["name"] for s in tr["spans"]]
-        assert names == ["admit", "queue_wait", "prefill", "decode", "detok", "deliver"]
+        # real spans under one root; the zero-length admit/deliver markers
+        # are gone (deliver is the server's: absent without a socket)
+        assert names == ["request", "encode", "queue_wait", "prefill", "decode", "detok"]
         assert tr["completion_tokens"] == len(r.token_ids)
-        # span arithmetic: queue_wait + prefill + decode + detok == total
+        # span arithmetic: encode + queue_wait + prefill + decode + detok == total
         spans = {s["name"]: s for s in tr["spans"]}
+        assert spans["request"]["parent"] is None
+        assert all(s["parent"] == "request" for s in tr["spans"][1:])
         parts = sum(
             spans[n].get("dur_s", 0.0)
-            for n in ("queue_wait", "prefill", "decode", "detok")
+            for n in ("encode", "queue_wait", "prefill", "decode", "detok")
         )
         assert abs(parts - tr["total_s"]) < 1e-3
         assert spans["decode"]["tokens"] == tr["completion_tokens"]
+        assert tr["timings"] == r.timings
         # generated ids when the caller sends none; unique per request
         f1 = eng.submit([4, 5], max_tokens=2, temperature=0.0)
         f2 = eng.submit([6, 7], max_tokens=2, temperature=0.0)

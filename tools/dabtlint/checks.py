@@ -68,6 +68,16 @@ HOT_PATH_PATTERNS: Tuple[str, ...] = (
     "*EngineObs.on_token_gap",
     "*Histogram.observe",
     "*FlightRecorder.record",
+    # engine-loop time ledger (serving/obs.py LoopLedger): span enter/exit
+    # run ~10 times per loop iteration around every dispatch and the tick's
+    # result wait; the padding counters ride each prefill dispatch.  The
+    # server's per-delta stream-lag stamp sits between a token and its write
+    "*_PhaseSpan.__enter__",
+    "*_PhaseSpan.__exit__",
+    "*LoopLedger.span",
+    "*LoopLedger.seconds",
+    "*LoopLedger.note_prefill",
+    "*_StreamLag.note",
 )
 
 # Modules under these path segments are clock-disciplined candidates for
