@@ -529,6 +529,14 @@ def phase_serve(cfg: SmokeConfig, boot_timeout_s: float = 1000.0) -> Dict[str, A
             gen.get("decode", {}).get("weight_bits") == (8 if cfg.int8 else 16),
             gen.get("decode"),
         )
+        # on a TPU the decode step's K/V write and read are the Pallas kernel
+        # (no fallback there); the tests' tiny CPU run takes the plain path
+        kv_path = gen.get("decode", {}).get("decode_kv_path")
+        checks.check(
+            "decode K/V path",
+            kv_path == ("kernel" if device.get("platform") == "tpu" else "xla"),
+            gen.get("decode"),
+        )
 
         # --- SIGTERM: drain and exit 0 ---------------------------------------
         t0 = time.monotonic()
@@ -550,6 +558,7 @@ def phase_serve(cfg: SmokeConfig, boot_timeout_s: float = 1000.0) -> Dict[str, A
             "request_wall_s": walls,
             "json_attempts": attempts,
             "prefix": {k: kv.get(k) for k in ("prefix_hits", "prefix_misses", "kv_shared_pages")},
+            "decode_kv_path": kv_path,
             "checks_passed": checks.passed,
         }
     finally:

@@ -32,6 +32,9 @@ from ..ops.attention import (
     chunked_gqa_decode_attention,
     dot_product_attention,
     gqa_dot_product_attention,
+    paged_decode_kv_path,
+    paged_decode_plan,
+    paged_decode_update_attend,
     paged_gqa_decode_attention,
     paged_tree_attention,
 )
@@ -1297,16 +1300,33 @@ def decode_step_paged(
     """Paged :func:`decode_step`: one autoregressive step for every active
     slot against the page pool -> (logits [B,V] f32, cache).
 
-    The attention read is :func:`~..ops.attention.paged_gqa_decode_attention`
-    — inherently chunked at page granularity, with the same loop bounds and
-    online-softmax discipline as the contiguous ``kv_chunk`` path (chunk ==
-    page), so outputs are bit-identical to the legacy layout for mirrored
-    pool contents.  The K/V write is a per-row scatter into
-    ``block_table[b, pos // page]`` at offset ``pos % page``; inactive rows
-    and rows whose position has run past their allocation scatter to the P
-    sentinel and DROP — unlike the contiguous path's harmless garbage writes,
-    a paged garbage write could land in a page since re-assigned to another
-    request, so masking is part of the correctness contract."""
+    Per layer the step writes one ``[KH, D]`` row per slot into
+    ``block_table[b, pos // page]`` at offset ``pos % page`` and reads the
+    pages the slot's query can see.  Inactive rows and rows whose position
+    has run past their allocation write NOTHING — unlike the contiguous
+    path's harmless garbage writes, a paged garbage write could land in a
+    page since re-assigned to another request, so this is part of the
+    correctness contract on both paths below.
+
+    Which path is :func:`~..ops.attention.paged_decode_kv_path`'s answer,
+    from the platform and the pool's shape (docs/KV_PAGING.md "Decode
+    read/write"):
+
+    - ``"kernel"`` (a TPU): the 5-D pool rides the layer scan's CARRY with the
+      layer index in ``xs``, and one Pallas call per layer
+      (:func:`~..ops.attention.paged_decode_update_attend`) patches the row in
+      place and DMAs the live pages — nothing in the step loop or the layer
+      loop makes a value the size of a layer of the pool.  Scanning the pool
+      ``xs -> ys`` around an XLA scatter and gather, the form below, made XLA
+      slice, re-lay-out and write back a whole layer for K and for V in every
+      layer: 5.5 ms of a 17.8 ms step at 7B widths (PERF.md section 5).
+    - ``"xla"`` (the CPU; toy shapes; ``attn_fp8``): a per-row scatter with
+      ``mode="drop"`` (the P sentinel drops) and
+      :func:`~..ops.attention.paged_gqa_decode_attention` — chunked at page
+      granularity with the contiguous ``kv_chunk`` path's loop bounds and
+      online-softmax discipline, so bit-identical to the legacy layout for
+      mirrored pool contents (tests/test_kv_paging.py).  The kernel is tested
+      against this form (tests/test_paged_decode_kernel.py)."""
     B = tokens.shape[0]
     L, P, KH, page, D = cache.k.shape
     NB = block_tables.shape[1]
@@ -1320,36 +1340,75 @@ def decode_step_paged(
     sin = sin_t[positions][:, None, :]
 
     x = _embed(params, cfg, tokens)[:, None, :]  # [B,1,E]
-    blk = positions // page
-    off = positions % page
-    phys = jnp.take_along_axis(block_tables, blk[:, None], axis=1)[:, 0]
-    phys_w = jnp.where(active, jnp.minimum(phys, P), P)
 
-    def make_body(window):
-        def body(x, inputs):
-            p, k_pool, v_pool = inputs  # [P, KH, page, D] per layer
-            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = _decode_qkv(cfg, p, h, cos, sin)
-            with jax.named_scope("attn/kv_write"):
-                k_pool = k_pool.at[phys_w, :, off, :].set(
-                    k[:, :, 0, :].astype(k_pool.dtype), mode="drop"
-                )
-                v_pool = v_pool.at[phys_w, :, off, :].set(
-                    v[:, :, 0, :].astype(v_pool.dtype), mode="drop"
-                )
-            o = paged_gqa_decode_attention(
-                q, k_pool, v_pool, block_tables, positions,
-                active=active, window=window, fp8_dot=attn_fp8,
-            )  # [B,H,1,D]
-            o = o.transpose(0, 2, 1, 3).reshape(B, 1, -1)
-            x = x + _attn_out(cfg, p, o)
-            h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(cfg, p, h)
-            return x, (k_pool, v_pool)
+    def layer(x, p, attend):
+        """One decoder layer; ``attend(q, k, v) -> (o [B,H,1,D], pools)``
+        writes the step's K/V and reads the cache."""
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        o, pools = attend(*_decode_qkv(cfg, p, h, cos, sin))
+        o = o.transpose(0, 2, 1, 3).reshape(B, 1, -1)
+        x = x + _attn_out(cfg, p, o)
+        h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+        return x + _mlp(cfg, p, h), pools
 
-        return body
+    if paged_decode_kv_path(cache.k.dtype, page, D, fp8_dot=attn_fp8) == "kernel":
 
-    x, (ks, vs) = _scan_window_split(cfg, make_body, x, (params["layers"], cache.k, cache.v))
+        def make_body(window):
+            # the pages this step touches: the same for every layer
+            plan = paged_decode_plan(
+                block_tables, positions, active, n_pages=P, page=page, window=window
+            )
+
+            def body(carry, inputs):
+                x, k_pool, v_pool = carry
+                p, layer_idx = inputs
+
+                def attend(q, k, v):
+                    o, k_new, v_new = paged_decode_update_attend(
+                        q, k, v, k_pool, v_pool, layer_idx, block_tables,
+                        positions, plan, window=window,
+                    )
+                    return o, (k_new, v_new)
+
+                x, pools = layer(x, p, attend)
+                return (x, *pools), None
+
+            return body
+
+        (x, ks, vs), _ = _scan_window_split(
+            cfg, make_body, (x, cache.k, cache.v), (params["layers"], jnp.arange(L))
+        )
+    else:
+        blk = positions // page
+        off = positions % page
+        phys = jnp.take_along_axis(block_tables, blk[:, None], axis=1)[:, 0]
+        phys_w = jnp.where(active, jnp.minimum(phys, P), P)
+
+        def make_body(window):
+            def body(x, inputs):
+                p, k_pool, v_pool = inputs  # [P, KH, page, D] per layer
+
+                def attend(q, k, v):
+                    with jax.named_scope("attn/kv_write"):
+                        k_new = k_pool.at[phys_w, :, off, :].set(
+                            k[:, :, 0, :].astype(k_pool.dtype), mode="drop"
+                        )
+                        v_new = v_pool.at[phys_w, :, off, :].set(
+                            v[:, :, 0, :].astype(v_pool.dtype), mode="drop"
+                        )
+                    o = paged_gqa_decode_attention(
+                        q, k_new, v_new, block_tables, positions,
+                        active=active, window=window, fp8_dot=attn_fp8,
+                    )
+                    return o, (k_new, v_new)
+
+                return layer(x, p, attend)
+
+            return body
+
+        x, (ks, vs) = _scan_window_split(
+            cfg, make_body, x, (params["layers"], cache.k, cache.v)
+        )
     new_cache = PagedKVCache(
         k=ks,
         v=vs,

@@ -429,6 +429,337 @@ def paged_tree_attention(
 
 
 # ---------------------------------------------------------------------------
+# Pallas paged decode: the step's K/V row written in place, live pages read by DMA
+# ---------------------------------------------------------------------------
+
+
+def _packed_rows(dtype) -> int:
+    """Rows of one packed (8, 128) x 32-bit tile: 8 / 16 / 32 for 4 / 2 / 1-byte types."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def paged_decode_kv_path(
+    kv_dtype, page: int, head_dim: int, *, fp8_dot: bool = False
+) -> str:
+    """Which implementation a paged decode step takes: ``"kernel"``
+    (:func:`paged_decode_update_attend`) or ``"xla"`` (scatter +
+    :func:`paged_gqa_decode_attention`).
+
+    By platform and shape, as :func:`attention` chooses the flash kernel: the
+    CPU has no Mosaic compiler, and the kernel moves whole ``[page, head_dim]``
+    tiles, so it takes lane-wide heads and pages of whole packed tiles (every
+    served geometry; toy models keep the plain function).  ``fp8_dot`` — fp8
+    operands THROUGH the dots, where the kernel dequantises per page — stays
+    on the plain function too.  On a TPU a kernel-shaped step always reaches
+    the kernel: one that does not compile fails the boot, it does not fall
+    back."""
+    kernel_shaped = head_dim % 128 == 0 and page % _packed_rows(kv_dtype) == 0
+    if kernel_shaped and not fp8_dot and jax.default_backend() == "tpu":
+        return "kernel"
+    return "xla"
+
+
+def paged_decode_plan(
+    block_tables: jnp.ndarray,  # [B, NB] int32; entries >= n_pages unallocated
+    positions: jnp.ndarray,  # [B] int32
+    active: jnp.ndarray,  # [B] bool
+    *,
+    n_pages: int,
+    page: int,
+    window: Optional[int] = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The pages one decode step touches, as a work list for the kernel:
+    ``items[i] = slot * NB + block`` for every (active slot, logical block)
+    whose keys the slot's query can see and whose table entry names a page —
+    slot-major, blocks ascending — and their count ``[1]``.
+
+    Per ROW (``[lo_b, hi_b)`` from the row's own position and window), not
+    the batch-wide ``[lo, hi)`` of :func:`paged_gqa_decode_attention`: a block
+    the plain loop visits for another row's sake is fully masked for this one
+    and contributes exactly zero there, so leaving it out changes no result.
+    Inactive rows and sentinel blocks are not on the list: nothing of theirs
+    is read, and a slot whose write block is not on the list writes nothing.
+    The same for every layer of a step, so it is built once outside the scan."""
+    B, NB = block_tables.shape
+    blk = jnp.arange(NB, dtype=jnp.int32)[None, :]
+    live = active[:, None] & (blk <= (positions // page)[:, None])
+    if window is not None:
+        live &= blk >= (jnp.maximum(positions - window + 1, 0) // page)[:, None]
+    live &= (block_tables >= 0) & (block_tables < n_pages)
+    flat = live.reshape(-1)
+    items = jnp.nonzero(flat, size=B * NB, fill_value=0)[0].astype(jnp.int32)
+    return items, jnp.sum(flat, dtype=jnp.int32)[None]
+
+
+def _paged_decode_kernel(
+    # scalar prefetch (SMEM)
+    items_ref,  # [B*NB] the plan's work list
+    n_ref,  # [1] live entries of it
+    bt_ref,  # [B*NB] block tables, flat
+    pos_ref,  # [B]
+    layer_ref,  # [1]
+    # inputs
+    q_ref,  # [B, KH, Gp, D] VMEM, query groups padded to whole sublane tiles
+    kn_ref,  # [B, KH, 1, D] f32 VMEM: the step's new K row per slot
+    vn_ref,
+    k_hbm,  # [L, P, KH, page, D] HBM: never loaded whole
+    v_hbm,
+    # outputs
+    o_ref,  # [B, KH, Gp, D] f32 VMEM: the accumulator, normalised at the end
+    k_out,  # the same buffers as k_hbm / v_hbm (input_output_aliases)
+    v_out,
+    # scratch
+    kbuf,  # [2, KH, page, D] double-buffered page
+    vbuf,
+    m_scr,  # [B, KH, Gp, 128] f32 running max (every lane the same)
+    l_scr,  # [B, KH, Gp, 128] f32 running sum
+    rsem,  # DMA [2 (k/v), 2 (buffer)]
+    wsem,  # DMA [2 (k/v)]
+    *,
+    nb: int,
+    page: int,
+    window: Optional[int],
+    sub: int,  # rows of one packed sublane tile of the pool's dtype
+):
+    """One layer of one decode step over the plan's work list.
+
+    Per item — one page of one slot — the page is DMA'd HBM -> VMEM (the next
+    item's DMA is already in flight), folded into that slot's online softmax,
+    and, where it is the page the slot's position falls in, first patched with
+    the step's new row and the one ``sub``-row tile that holds it DMA'd back:
+    the only bytes of the pool this kernel writes."""
+    KH, Gp, D = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    layer = layer_ref[0]
+    n = n_ref[0]
+    scale = D ** -0.5
+
+    def page_copies(i, s):
+        phys = bt_ref[items_ref[i]]
+        return (
+            pltpu.make_async_copy(k_hbm.at[layer, phys], kbuf.at[s], rsem.at[0, s]),
+            pltpu.make_async_copy(v_hbm.at[layer, phys], vbuf.at[s], rsem.at[1, s]),
+        )
+
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    @pl.when(n > 0)
+    def _first():
+        for c in page_copies(0, 0):
+            c.start()
+
+    def item(i, carry):
+        s = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n)
+        def _prefetch():
+            for c in page_copies(i + 1, 1 - s):
+                c.start()
+
+        it = items_ref[i]
+        slot = it // nb
+        j = it - slot * nb
+        phys = bt_ref[it]
+        pos = pos_ref[slot]
+        writes = j == pos // page  # the page the step's own row lands in
+        off = pos - j * page
+        al = pl.multiple_of((off // sub) * sub, sub)
+        for c in page_copies(i, s):
+            c.wait()
+
+        def tile_copies():
+            rows = pl.ds(al, sub)
+            return (
+                pltpu.make_async_copy(
+                    kbuf.at[s, :, rows, :], k_out.at[layer, phys, :, rows, :], wsem.at[0]
+                ),
+                pltpu.make_async_copy(
+                    vbuf.at[s, :, rows, :], v_out.at[layer, phys, :, rows, :], wsem.at[1]
+                ),
+            )
+
+        @pl.when(writes)
+        def _write_row():
+            # through float32 and back: exact for every pool dtype, and the
+            # select never runs on a packed type
+            at_row = jax.lax.broadcasted_iota(jnp.int32, (KH, sub, D), 1) == off - al
+            for buf, new_ref in ((kbuf, kn_ref), (vbuf, vn_ref)):
+                tile = buf[s, :, pl.ds(al, sub), :].astype(jnp.float32)
+                buf[s, :, pl.ds(al, sub), :] = jnp.where(
+                    at_row, new_ref[slot], tile
+                ).astype(buf.dtype)
+            for c in tile_copies():
+                c.start()
+
+        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (Gp, page), 1)
+        keep = kpos <= pos
+        if window is not None:
+            keep &= kpos > pos - window
+        for h in range(KH):
+            q = q_ref[slot, h]  # [Gp, D]
+            k = kbuf[s, h].astype(q.dtype)  # [page, D]; a pure convert for fp8 pools
+            v = vbuf[s, h].astype(q.dtype)
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            ) * scale  # [Gp, page]
+            sc = jnp.where(keep, sc, NEG_INF)
+            m_prev = m_scr[slot, h][:, :1]
+            l_prev = l_scr[slot, h][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            o_ref[slot, h] = alpha * o_ref[slot, h] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32
+            )
+            m_scr[slot, h] = jnp.broadcast_to(m_new, (Gp, 128))
+            l_scr[slot, h] = jnp.broadcast_to(l_new, (Gp, 128))
+
+        @pl.when(writes)
+        def _drain():  # the buffer is the next-but-one item's DMA target
+            for c in tile_copies():
+                c.wait()
+
+        return carry
+
+    jax.lax.fori_loop(0, n, item, 0)
+
+    def normalise(b, carry):
+        for h in range(KH):
+            o_ref[b, h] = o_ref[b, h] / jnp.maximum(l_scr[b, h][:, :1], 1e-30)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0], normalise, 0)
+
+
+def _paged_decode_call(
+    q, k_new, v_new, k_pool, v_pool, layer, block_tables, positions, items, n_items,
+    *, window: Optional[int], interpret: bool,
+):
+    """The bare kernel call on one device's share of the heads."""
+    B, H, Sq, D = q.shape
+    L, P, KH, page, _ = k_pool.shape
+    NB = block_tables.shape[1]
+    G = H // KH
+    if Sq != 1:
+        raise ValueError(f"decode attention expects Sq=1 queries, got {Sq}")
+    sub = _packed_rows(k_pool.dtype)
+    if page % sub or D % 128:
+        raise ValueError(
+            f"paged decode kernel needs page % {sub} == 0 and head_dim % 128 == 0, "
+            f"got page={page}, head_dim={D}"
+        )
+    Gp = -(-G // 16) * 16  # whole bf16 sublane tiles; pad rows are zero queries
+    qg = q.reshape(B, KH, G, D)
+    if Gp != G:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    page_bytes = KH * page * D * jnp.dtype(k_pool.dtype).itemsize
+    o, k_pool, v_pool = pl.pallas_call(
+        functools.partial(
+            _paged_decode_kernel, nb=NB, page=page, window=window, sub=sub
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(1,),
+            in_specs=[vmem, vmem, vmem, hbm, hbm],
+            out_specs=[vmem, hbm, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, KH, page, D), k_pool.dtype),
+                pltpu.VMEM((2, KH, page, D), v_pool.dtype),
+                pltpu.VMEM((B, KH, Gp, 128), jnp.float32),
+                pltpu.VMEM((B, KH, Gp, 128), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, KH, Gp, D), jnp.float32),
+            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
+        ],
+        # operand numbering counts the five scalar-prefetch arguments
+        input_output_aliases={8: 1, 9: 2},
+        compiler_params=pltpu.CompilerParams(
+            # four page buffers + the per-slot state and the score tiles
+            vmem_limit_bytes=int(4 * page_bytes + (16 << 20)),
+        ),
+        name="paged_decode",
+        interpret=interpret,
+    )(
+        items, n_items, block_tables.reshape(-1).astype(jnp.int32),
+        positions.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+        qg, k_new.astype(jnp.float32), v_new.astype(jnp.float32), k_pool, v_pool,
+    )
+    out = o[:, :, :G].astype(q.dtype).reshape(B, H, 1, D)
+    return out, k_pool, v_pool
+
+
+@jax.named_scope("attn/kv_read")
+def paged_decode_update_attend(
+    q: jnp.ndarray,  # [B, H, 1, D]
+    k_new: jnp.ndarray,  # [B, KH, 1, D] the step's key per slot (after RoPE)
+    v_new: jnp.ndarray,  # [B, KH, 1, D]
+    k_pool: jnp.ndarray,  # [L, P, KH, page, D] the whole pool, every layer
+    v_pool: jnp.ndarray,
+    layer: jnp.ndarray,  # scalar int32
+    block_tables: jnp.ndarray,  # [B, NB] int32
+    positions: jnp.ndarray,  # [B] int32
+    plan: tuple[jnp.ndarray, jnp.ndarray],  # paged_decode_plan(...), same window
+    *,
+    window: Optional[int] = None,
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The decode step's K/V write and attention read as ONE Pallas call that
+    touches the pool only where it must -> ``(o [B,H,1,D], k_pool, v_pool)``.
+
+    The pools stay in HBM (``memory_space=ANY``) and are returned aliased
+    (``input_output_aliases``): a custom call takes its operand in the default
+    layout, which is the layout the pool has at the program's edge, so XLA has
+    neither a reason nor the freedom to re-lay it out between the scatter's
+    and the gather's preferred tilings — the copy that cost 5.5 ms of every
+    17.8 ms step (PERF.md §5).  Per slot the kernel reads the pages its block
+    table names over ``[lo_b, hi_b)`` and writes one ``[KH, D]`` row at
+    ``(block_table[b, pos // page], pos % page)``; slots and blocks that are
+    not on the plan (inactive, sentinel, past their allocation) read and WRITE
+    nothing — the no-write rule is part of the page-sharing contract
+    (``llama.decode_step_paged``).
+
+    Numerics: :func:`paged_gqa_decode_attention`'s — operands in the query's
+    dtype, float32 scores, running max/sum and accumulator, one page per
+    online-softmax update — summed per row over the row's own pages, so equal
+    to it up to the order of the page sums.  Inactive rows come back zero.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import MODEL_AXIS
+    from ..parallel.sharding import active_mesh
+
+    call = functools.partial(_paged_decode_call, window=window, interpret=interpret)
+    args = (q, k_new, v_new, k_pool, v_pool, layer, block_tables, positions, *plan)
+    mesh = active_mesh()
+    if mesh is None or mesh.size == 1:
+        return call(*args)
+    # Mosaic kernels cannot be partitioned automatically (sharded_flash_attention):
+    # KV heads over `model`, the pool's own sharding (llama.paged_cache_shardings),
+    # each device on its heads' pages with no collective; where the KV heads do
+    # not divide the axis the pool is replicated and so is the work.
+    split = k_pool.shape[2] % mesh.shape[MODEL_AXIS] == 0
+    heads = P(None, MODEL_AXIS if split else None, None, None)
+    pool = P(None, None, MODEL_AXIS if split else None, None, None)
+    rep = P()
+    return jax.shard_map(
+        call,
+        mesh=mesh,
+        in_specs=(heads, heads, heads, pool, pool, rep, rep, rep, rep, rep),
+        out_specs=(heads, pool, pool),
+        check_vma=False,
+    )(*args)
+
+
+# ---------------------------------------------------------------------------
 # Pallas flash attention
 # ---------------------------------------------------------------------------
 

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import DecoderConfig, EncoderConfig, encoder, llama
+from ..ops.attention import paged_decode_kv_path
 from ..ops.sampling import sample_logits
 from ..parallel.sharding import mesh_scope
 from .obs import EngineObs, LoopLedger, new_trace_id
@@ -679,6 +680,25 @@ class GenerationEngine:
                     "attn_fp8=True on the legacy KV layout requires the "
                     "chunked decode read (decode_kv_chunk != None)"
                 )
+        # Which implementation the decode tick's K/V write and attention read
+        # take (docs/KV_PAGING.md "Decode read/write"): "kernel" — the Pallas
+        # call that writes one row per slot in place and reads only the pages
+        # the block tables name — on a TPU with the paged layout, "xla"
+        # everywhere else.  A gauge and a boot log line, so a run that took
+        # the plain path on a chip cannot pass for the kernel.
+        self.decode_kv_path = (
+            paged_decode_kv_path(
+                self.kv_cache_dtype or cfg.dtype, self.kv_page_size,
+                cfg.head_dim, fp8_dot=self.attn_fp8,
+            )
+            if self.paged
+            else "xla"
+        )
+        logger.info(
+            "decode K/V path: %s (kv_layout=%s, platform=%s)",
+            self.decode_kv_path, "paged" if self.paged else "legacy",
+            jax.default_backend(),
+        )
         # Admission-controlled scheduling (serving/scheduler.py): when present,
         # submit() runs its admission test (bounded queue, estimated wait) and
         # _admit pulls requests in weighted-fair-share order instead of FIFO.
@@ -3331,6 +3351,9 @@ class GenerationEngine:
             # fp8 in-dot attention (docs/QUANT.md): whether the decode
             # attention dots read the KV operand at fp8 storage width
             "attn_fp8": self.attn_fp8,
+            # "kernel": the decode step writes its K/V row in place and reads
+            # only the pages the block tables name; "xla": scatter + gather
+            "decode_kv_path": self.decode_kv_path,
         }
 
     def slice_stats(self) -> dict:

@@ -936,6 +936,7 @@ def render_prometheus(registry: Any) -> str:
             x.add("dabt_prefill_chunks_piggybacked_total", "counter", "prefill chunks run inside a fused decode tick", dec.get("prefill_chunks_piggybacked"), lab)
             x.add("dabt_prefill_piggyback", "gauge", "piggybacked-prefill program compiled for this engine", dec.get("prefill_piggyback"), lab)
             x.add("dabt_attn_fp8", "gauge", "fp8 in-dot decode attention engaged", dec.get("attn_fp8"), lab)
+            x.add("dabt_decode_kv_kernel", "gauge", "decode K/V write+read is the Pallas paged kernel (1) or the plain XLA path (0)", dec.get("decode_kv_path") == "kernel", lab)
         sl_fn = getattr(eng, "slice_stats", None)
         if callable(sl_fn):
             # mesh-sliced fleet (docs/MULTICHIP.md): which devices this

@@ -1422,6 +1422,7 @@ class EngineRouter:
                 4,
             ),
             "attn_fp8": per[0].get("attn_fp8", False),
+            "decode_kv_path": per[0].get("decode_kv_path", "xla"),
             "replicas": per,
         }
 

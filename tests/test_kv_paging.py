@@ -740,6 +740,10 @@ def test_tick_stats_and_healthz_carry_kv_gauges():
             for key in ("kv_pages_used", "kv_pages_free", "kv_shared_page_frac",
                         "kv_evictions", "kv_cow_copies"):
                 assert key in kv
+            # which decode K/V implementation is live: a label, not a rate.
+            # The CPU has no Mosaic compiler, so here it is the plain path;
+            # a TPU serving a kernel-shaped geometry must say "kernel"
+            assert body["generators"]["tiny-chat"]["decode"]["decode_kv_path"] == "xla"
         finally:
             await client.close()
 
@@ -747,5 +751,6 @@ def test_tick_stats_and_healthz_carry_kv_gauges():
         asyncio.new_event_loop().run_until_complete(drive())
         eng = registry.get_generator("tiny-chat")
         assert eng.tick_stats()["kv"]["kv_layout"] == "paged"
+        assert eng.tick_stats()["decode_kv_path"] == "xla"
     finally:
         registry.stop()

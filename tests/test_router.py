@@ -535,6 +535,7 @@ def test_router_registry_serves_and_healthz_aggregates(replica_registry):
         assert len(g["router"]["replicas"]) == 2
         assert len(g["supervision"]["replicas"]) == 2
         assert g["kv"]["kv_layout_effective"] == "paged"
+        assert g["decode"]["decode_kv_path"] == "xla"  # CPU replicas: the plain path
 
         # one dead replica of two: the fleet reports degraded with the dead
         # replica identifiable, but /dialog/ keeps serving from the survivor

@@ -31,7 +31,12 @@ MISTRAL_7B = DecoderConfig(
     num_heads=32, num_kv_heads=8, head_dim=128, max_seq_len=32_768,
     rope_theta=10_000.0, sliding_window=4096, dtype=jnp.bfloat16,
 )
-SLOTS, MAX_SEQ, PAGE = 8, 2048, 512  # the smoke's serving geometry
+QWEN25_7B = DecoderConfig(  # the benchmark's configuration (benchmarks/configs/qwen2.5-7b-instruct.json)
+    vocab_size=152_064, hidden_size=3584, intermediate_size=18_944, num_layers=28,
+    num_heads=28, num_kv_heads=4, head_dim=128, max_seq_len=32_768,
+    rope_theta=1_000_000.0, attn_bias=True, dtype=jnp.bfloat16,
+)
+SLOTS, MAX_SEQ, PAGE = 8, 2048, 512  # the smoke's and the benchmark's serving geometry
 
 
 @pytest.fixture(scope="module")
@@ -79,16 +84,19 @@ def _int8_decoder_shapes(cfg: DecoderConfig, sharding):
             scale=_sds(shape[:-2] + (1, shape[-1]), jnp.float32, sharding),
         )
 
+    layers = {
+        "attn_norm": dense(L, E), "mlp_norm": dense(L, E),
+        "wq": q8(L, E, H * D), "wk": q8(L, E, KH * D), "wv": q8(L, E, KH * D),
+        "wo": q8(L, H * D, E),
+        "w_gate": q8(L, E, F), "w_up": q8(L, E, F), "w_down": q8(L, F, E),
+    }
+    if cfg.attn_bias:
+        layers.update(bq=dense(L, H * D), bk=dense(L, KH * D), bv=dense(L, KH * D))
     return {
         "tok_embed": dense(V, E),
         "final_norm": dense(E),
         "lm_head": dense(E, V),
-        "layers": {
-            "attn_norm": dense(L, E), "mlp_norm": dense(L, E),
-            "wq": q8(L, E, H * D), "wk": q8(L, E, KH * D), "wv": q8(L, E, KH * D),
-            "wo": q8(L, H * D, E),
-            "w_gate": q8(L, E, F), "w_up": q8(L, E, F), "w_down": q8(L, F, E),
-        },
+        "layers": layers,
     }
 
 
@@ -200,6 +208,121 @@ def test_paged_decode_compiles_at_mistral_7b_widths(topo, kv_dtype):
     # int8 weights resident (~7.5 GB) + the page pool, inside one chip's 16 GB
     assert 7.0e9 < mem.argument_size_in_bytes < 12.0e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+
+
+def _fused_tick(cfg, steps=8):
+    """``GenerationEngine._make_decode_tick``'s body (plain, paged): the params
+    behind an ``optimization_barrier``, ``decode_step_paged``, sampling, scanned
+    ``steps`` times with the cache in the carry."""
+    from django_assistant_bot_tpu.ops.sampling import sample_logits
+
+    def tick(params, tokens, cache, active, bt, temps, top_ps, rng):
+        def body(carry, _):
+            tokens, cache, rng = carry
+            p = jax.lax.optimization_barrier(params)
+            rng, sub = jax.random.split(rng)
+            logits, cache = llama.decode_step_paged(p, cfg, tokens, cache, bt, active=active)
+            nxt = sample_logits(logits, sub, temperature=temps, top_k=0, top_p=top_ps)
+            return (nxt, cache, rng), nxt
+
+        (tokens, cache, rng), toks = jax.lax.scan(body, (tokens, cache, rng), None, length=steps)
+        return toks, tokens, cache, rng
+
+    return tick
+
+
+def _tick_args(cfg, params_sharding, cache_shardings, rep):
+    cache = _paged_cache_shapes(cfg, jnp.bfloat16, rep)
+    cache = llama.PagedKVCache(
+        k=_sds(cache.k.shape, cache.k.dtype, cache_shardings.k),
+        v=_sds(cache.v.shape, cache.v.dtype, cache_shardings.v),
+        lengths=cache.lengths,
+    )
+    return (
+        _int8_decoder_shapes(cfg, params_sharding), _sds((SLOTS,), jnp.int32, rep), cache,
+        _sds((SLOTS,), jnp.bool_, rep), _sds((SLOTS, MAX_SEQ // PAGE), jnp.int32, rep),
+        _sds((SLOTS,), jnp.float32, rep), _sds((SLOTS,), jnp.float32, rep),
+        _sds((2,), jnp.uint32, rep),
+    )
+
+
+# opcodes that name a pool-sized value without making one: the loops' own
+# plumbing, and the kernel, whose pool outputs alias its pool operands
+_NO_NEW_BUFFER = {
+    "parameter", "get-tuple-element", "bitcast", "tuple", "while", "conditional",
+    "call", "opt-barrier", "custom-call",
+}
+
+
+def _pool_sized_values_made_in_loops(text: str, shapes) -> list[str]:
+    """Instructions outside the entry computation whose result holds a whole
+    layer of the pool, or the whole pool, and that would have to write it."""
+    import re
+
+    made, in_entry = [], False
+    for line in text.splitlines():
+        if line.startswith(("ENTRY ", "%", "}")) and not line.startswith("  "):
+            in_entry = line.startswith("ENTRY ")
+            continue
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (.*?) ([a-z][\w\-]*)\(", line)
+        if not m or in_entry or m.group(2) in _NO_NEW_BUFFER:
+            continue
+        if any(f"bf16[{shape}]" in m.group(1) for shape in shapes):
+            made.append(line.strip()[:160])
+    return made
+
+
+@pytest.mark.parametrize(
+    "cfg,temp_limit",
+    # Qwen: 1.41 GB of temporaries before, 0.94 GB of it the pool's double; 0.48 GB now.
+    # Mistral's pool is 2.15 GB (8 KV heads, 32 layers): 0.81 GB of temporaries cannot hold
+    # a second one, nor one of K or V alone (1.07 GB)
+    [(QWEN25_7B, 0.6e9), (MISTRAL_7B, 1.0e9)],
+    ids=["qwen2.5-7b-cell", "mistral-7b-smoke"],
+)
+def test_fused_tick_touches_the_pool_only_where_it_must(topo, monkeypatch, cfg, temp_limit):
+    """ISSUE 25's compiled-program criterion, at the benchmark cell's widths and
+    the smoke's: the donated pool is updated in place by the kernel, there is
+    no second pool among the temporaries, and no operation inside the tick's
+    step loop or layer loop makes a value the size of one layer of the pool.  The xs -> ys form
+    sliced, re-laid-out and wrote back a whole layer, for K and for V, in every
+    layer of every step: 5.5 ms of a 17.8 ms step (PERF.md section 5)."""
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    args = _tick_args(cfg, one, llama.PagedKVCache(k=one, v=one, lengths=one), one)
+    compiled = jax.jit(_fused_tick(cfg), donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * 2 * cfg.num_layers * (SLOTS * MAX_SEQ // PAGE) * cfg.num_kv_heads * PAGE * cfg.head_dim
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < temp_limit
+    n_pages, kh = SLOTS * MAX_SEQ // PAGE, cfg.num_kv_heads
+    layer = f"{n_pages},{kh},{PAGE},{cfg.head_dim}"
+    assert _pool_sized_values_made_in_loops(text, [layer, f"{cfg.num_layers},{layer}"]) == []
+
+
+def test_fused_tick_kernel_is_shard_mapped_over_kv_heads_on_a_four_device_mesh(topo, monkeypatch):
+    """Bare, a Mosaic call on a mesh is refused (above); under ``mesh_scope``
+    the kernel runs per device on its KV heads' share of every page."""
+    from django_assistant_bot_tpu.parallel.sharding import mesh_scope
+
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    cfg = QWEN25_7B
+    mesh = make_mesh(MeshAxes(model=4), devices=topo.devices)
+    rep = NamedSharding(mesh, P())
+    with mesh_scope(mesh):
+        args = _tick_args(cfg, rep, llama.paged_cache_shardings(cfg, mesh, SLOTS), rep)
+        compiled = jax.jit(_fused_tick(cfg), donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    n_pages, kh = SLOTS * MAX_SEQ // PAGE, cfg.num_kv_heads // 4
+    assert f"bf16[{cfg.num_layers},{n_pages},{kh},{PAGE},{cfg.head_dim}]" in text  # each device its head
+    layer = f"{n_pages},{kh},{PAGE},{cfg.head_dim}"
+    assert _pool_sized_values_made_in_loops(text, [layer, f"{cfg.num_layers},{layer}"]) == []
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        2 * 2 * cfg.num_layers * n_pages * kh * PAGE * cfg.head_dim
+    )
 
 
 def test_paged_prefill_chunk_compiles_at_mistral_7b_widths(topo):
