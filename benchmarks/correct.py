@@ -10,14 +10,17 @@ reference logit lies below the reference's best at its position, in units of
 the standard deviation of that row's drawn columns.  Greedy decoding in the
 stated precision picks the reference's best token or a near-tie; a lower
 precision, a wrong chat format or a wrong tokenisation picks tokens the
-reference ranks far lower.  ``LIMITS`` holds each number's limit; PERF.md gives
-the readings they were set from.
+reference ranks far lower.
 
-The controls put the reference in the program's place in the nearest precision
-below the configuration's: every projection re-quantised to int4 (below the
-stated int8 weights), and keys and values rounded through float8 (below the
-stated bfloat16 cache).  At each position they read the gap of the token the
-lower precision puts first.  The benchmark's own runs never compute them;
+The reference, the controls and the limits of the numbers read from it belong
+to the configuration's *family* (``benchmarks/families/<name>.py``): its
+``reference_logits`` is the plain forward pass, its ``CONTROLS`` name the
+lower precisions it can put in the program's place (for ``llama``: int4
+weights below the stated int8, float8 keys and values below the stated
+bfloat16 cache), its ``LIMITS`` hold each number's limit with the readings it
+was set from.  The exact comparisons (``EXACT``) are the same for every
+family.  At each position a control reads the gap of the token the lower
+precision puts first.  The benchmark's own runs never compute the controls;
 ``run.py --controls`` and the tests do.
 """
 
@@ -27,17 +30,16 @@ from typing import Any, Dict, List, Sequence
 
 import numpy as np
 
-from benchmarks import weights
-from benchmarks.reference import decoder
-
-# each number compared and its limit (PERF.md section 2 has the readings)
-LIMITS = {
-    "logit_gap_max": 0.30,     # PERF.md section 2: sound runs' largest / the controls' smallest
-    "short_outputs": 0,        # exact: every finished request streamed max_tokens characters
-    "prompt_mismatches": 0,    # exact: the server counted the prompt tokens traffic_gen.prompt_ids counts
+# the exact comparisons, for every family
+EXACT = {
+    "short_outputs": 0,        # every finished request streamed max_tokens characters
+    "prompt_mismatches": 0,    # the server counted the prompt tokens traffic_gen.prompt_ids counts
 }
-INT4_GROUP = 64
-KV_CONTROL_DTYPE = "float8_e4m3fn"
+
+
+def limits(family) -> Dict[str, float]:
+    """Each number compared and its limit: the family's own, and the exact ones."""
+    return {**family.LIMITS, **EXACT}
 
 
 def sample(events: List[Dict[str, Any]], seed: int, n: int) -> List[Dict[str, Any]]:
@@ -72,30 +74,23 @@ def _summary(g: np.ndarray, prefix: str) -> Dict[str, float]:
     }
 
 
-def logit_gaps(conf: Dict[str, Any], seed: int, picked: List[Dict[str, Any]],
+def logit_gaps(family, conf: Dict[str, Any], seed: int, picked: List[Dict[str, Any]],
                controls: bool = False, dump: str = "") -> Dict[str, float]:
-    hf = conf["hf"]
     lo, hi = conf["weights"]["head_ids"]
     cols = list(range(lo, hi + 1))
     seqs = [list(e["prompt_ids"]) + list(e["tokens"]) for e in picked]
     firsts = [len(e["prompt_ids"]) - 1 for e in picked]
-    top = weights.dequantised_top(hf, seed, (lo, hi))
-
-    def run(int4_group: int = 0, kv_round=None):
-        return decoder.logits_at(hf, lambda i: weights.dequantised_layer(hf, seed, i, int4_group), top,
-                                 seqs, firsts, kv_round=kv_round, columns=cols)
-
-    ref = run()
+    ref = family.reference_logits(conf, seed, seqs, firsts, cols)
     served = [np.asarray(e["tokens"]) - lo for e in picked]
     g = _gaps(ref, served)
     out = {**_summary(g, "logit_"), "checked_tokens": float(len(g))}
     all_gaps = {"served": g.tolist()}
     if controls:
-        for name, low in (("control_int4_", run(int4_group=INT4_GROUP)),
-                          ("control_kv_fp8_", run(kv_round=KV_CONTROL_DTYPE))):
+        for name in family.CONTROLS:
+            low = family.reference_logits(conf, seed, seqs, firsts, cols, control=name)
             cg = _gaps(ref, [C.argmax(axis=1) for C in low])
-            out.update(_summary(cg, name))
-            all_gaps[name] = cg.tolist()
+            out.update(_summary(cg, f"control_{name}_"))
+            all_gaps[f"control_{name}_"] = cg.tolist()
     if dump:  # every gap, for setting a limit from the readings
         import json
 
@@ -104,5 +99,5 @@ def logit_gaps(conf: Dict[str, Any], seed: int, picked: List[Dict[str, Any]],
     return out
 
 
-def verdict(numbers: Dict[str, float]) -> bool:
-    return all(numbers[k] <= LIMITS[k] for k in LIMITS if k in numbers)
+def verdict(numbers: Dict[str, float], limits: Dict[str, float]) -> bool:
+    return all(k in numbers and numbers[k] <= limits[k] for k in limits)  # a limit with no number to hold: not correct

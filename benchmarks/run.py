@@ -5,8 +5,9 @@
 
 Everything of a cell is data found by name: ``BENCHMARK.json`` names the cell's
 configuration (``benchmarks/configs/``) and traffic mix (``benchmarks/traffic/``)
-and the metrics it reports; each per-layer metric has a reader of its own in
-``benchmarks/layer_metrics/``.
+and the metrics it reports; the configuration names its architecture's family
+(``benchmarks/families/``: seeded weights, plain reference, limits, counts);
+each per-layer metric has a reader of its own in ``benchmarks/layer_metrics/``.
 
 Two processes.  This one never touches JAX: it plans the traffic, starts the
 child that holds the chip (``benchmarks/sut.py``: seeded weights to a native
@@ -68,6 +69,19 @@ def load_cell(workload: str, bench_path: str, root: str):
     conf = _load_json(os.path.join(root, cfg_entry["file"]))
     mix = _load_json(os.path.join(data, "traffic", cell["traffic"] + ".json"))
     return bench, cell, conf, mix, data
+
+
+def load_family(conf, data_dir: str):
+    """The configuration's family module; a family that has no file ends the
+    run here, before the child that holds the chip is started."""
+    from benchmarks import families
+
+    before = "jax" in sys.modules  # only ever true in a test's process
+    family = families.load(conf, data_dir)
+    if "jax" in sys.modules and not before:
+        raise SystemExit(f"{family.__file__} imports JAX as it is loaded: this process never touches JAX, "
+                         "import it inside the functions")
+    return family
 
 
 def metrics_for(bench, group: str, cell_name: str):
@@ -169,6 +183,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     bench, cell, conf, mix, data_dir = load_cell(args.workload, args.benchmark_json, args.data_root)
+    family = load_family(conf, data_dir)
     from benchmarks.traffic_gen import Plan
 
     plan = Plan(mix, args.seed, args.seconds)
@@ -188,17 +203,17 @@ def main(argv=None) -> int:
     overrides = {k: json.loads(v) for k, v in (s.split("=", 1) for s in args.spec)}
     job = {"conf": conf, "seed": args.seed, "chips": int(cell["chips"]), "rehearsal": args.rehearsal,
            "port": port, "model": MODEL, "checkpoint": os.path.join(cache, "benchmarks_ckpt", cell["config"]),
-           "spec_overrides": overrides}
+           "spec_overrides": overrides, "data_dir": data_dir}
     child = Child(args.sut, job, os.path.join(cache, "benchmarks_log", args.workload + ".log"))
     try:
-        return _run_cell(args, bench, cell, conf, mix, data_dir, plan, child, base, cache, overrides)
+        return _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base, cache, overrides)
     finally:
         if child.proc.poll() is None:  # never leave the chip's holder behind
             child.proc.kill()
             child.proc.wait()
 
 
-def _run_cell(args, bench, cell, conf, mix, data_dir, plan, child, base, cache, overrides) -> int:
+def _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base, cache, overrides) -> int:
     from benchmarks import correct, driver, metrics, roofline, trace_reduce
 
     booting = child.read()  # the child has a device, a checkpoint, a warmed engine; or it has ended
@@ -254,13 +269,14 @@ def _run_cell(args, bench, cell, conf, mix, data_dir, plan, child, base, cache, 
         raise SystemExit(f"the serving child ended with rc={rc} (log: {child.log_path})")
     numbers = {"short_outputs": e2e["short_outputs"], "prompt_mismatches": e2e["prompt_mismatches"],
                **checked["numbers"]}
-    is_correct = bool(picked) and e2e["failed"] == 0 and correct.verdict(numbers)
+    limits = correct.limits(family)
+    is_correct = bool(picked) and e2e["failed"] == 0 and correct.verdict(numbers, limits)
 
     c0, c1 = snap["s0"]["counters"], snap["s1"]["counters"]
     ctx = {
         "cell": cell["name"], "conf": conf, "mix": mix, "device": device, "e2e": e2e,
         "events": events, "t_open": t_open, "t_close": t_close, "late_ms": res["late_ms"],
-        "c0": c0, "c1": c1, "trace": None, "roofline": roofline,
+        "c0": c0, "c1": c1, "trace": None, "roofline": roofline, "family": family,
         "compiles_in_window": snap["s1"]["compiles"] - snap["s0"]["compiles"],
         "hbm_peak_bytes": peak, "samples": samples,
         "trace_span": (snap.get("trace_t0"), snap.get("trace_t1")),
@@ -306,6 +322,7 @@ def _run_cell(args, bench, cell, conf, mix, data_dir, plan, child, base, cache, 
         # what the rate was made of: where in the window, and from how many ticks and steps
         **metrics.window_profile(events, t_open, t_close),
         "ticks_in_window": c1["ticks"] - c0["ticks"],
+        "decode_kv_path": c1["tick_stats"].get("decode_kv_path"),  # "kernel" on a TPU, "xla" elsewhere
         "prefill_chunks_piggybacked": (c1.get("prefill_chunks_piggybacked") or 0) - (c0.get("prefill_chunks_piggybacked") or 0),
         "gen_late_max_ms": max(res["late_ms"], default=0.0),
         "engine_restarts": end["counters"]["engine_restarts"], "poisoned_requests": end["counters"]["poisoned_requests"],
@@ -320,15 +337,21 @@ def _run_cell(args, bench, cell, conf, mix, data_dir, plan, child, base, cache, 
         "hbm_peak_gb": peak / 1e9,
         "setup_parts_s": dict(booting["setup_parts_s"], program_boot=booting["boot_s"], warm_traffic=plan.warm_s),
         "spec_overrides": overrides, "check_s": checked["check_s"],
-        "compared": {k: [v, correct.LIMITS.get(k)] for k, v in numbers.items()},
+        # a traced run: the ten named scopes that took most device time (None without xplane_pb2)
+        "device_scopes": trace_reduce.top(ctx["trace"]["scope_s"] or {}) if ctx["trace"] else None,
+        "compared": {k: [v, limits.get(k)] for k, v in numbers.items()},
     }
     print(json.dumps(diag))
     result = {"correct": is_correct, "attempted": e2e["attempted"], "failed": e2e["failed"],
               "metrics": out_metrics, "device": dev_out}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    result["compared"] = diag["compared"]  # each number beside its limit (null: read, not held), last in the line
     print(json.dumps(result))
     sys.stdout.flush()
+    for k, (v, limit) in diag["compared"].items():  # and as the run's last lines on standard error
+        print(f"compared {k} {v} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

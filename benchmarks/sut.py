@@ -8,9 +8,10 @@ line of JSON (the job) on its standard input and reads JSON lines back.  The
 child
 
 1. names its device and refuses anything but a TPU with enough chips;
-2. draws the seeded weights on the device (``benchmarks/weights.py``), saves
-   them with the program's own ``save_model`` as a native checkpoint under the
-   checkout, and frees them;
+2. draws the seeded weights on the device (``served_params`` of the
+   configuration's family, ``benchmarks/families/``), wraps the tree in the
+   program's types, saves it with the program's own ``save_model`` as a native
+   checkpoint under the checkout, and frees it;
 3. boots the program the way ``cli serve --config ... --warmup`` does: the
    persistent compile cache, a ``ModelRegistry`` from a model config whose
    entries are the configuration file's ``serving`` block word for word
@@ -18,11 +19,12 @@ child
    checkpoint through ``load_model``, places it, builds the engine and warms
    it up, and then ``run_server`` on a local port, blocking;
 4. meanwhile answers commands from ``run.py`` on a side thread: counter
-   snapshots (the engine's public ``tick_stats``/``kv_stats``/``wait_stats``),
+   snapshots (the engine's public ``tick_stats``/``kv_stats``/``wait_stats``,
+   twelve picked keys and the whole dictionaries),
    the profiler around the traced part of the window, gauge samples;
 5. on ``finish`` sends itself SIGTERM (the server drains and stops its
    engines), frees the program and checks the sample ``run.py`` wrote against
-   the plain reference (``benchmarks/correct.py``).
+   the family's plain reference (``benchmarks/correct.py``).
 
 Nothing here computes a metric or decides ``correct``.
 """
@@ -32,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 import gc
 import json
+import math
 import os
 import shutil
 import signal
@@ -102,23 +105,31 @@ class CompileCounter:
             self.misses += 1
 
 
-def write_checkpoint(conf: Dict[str, Any], seed: int, path: str) -> None:
-    """The benchmark's seeded weights, in the program's parameter layout,
-    written by the program's ``save_model``: what ``ModelSpec.checkpoint``
-    loads.  Made on the device in one jitted call and freed before the boot."""
+def wrap_params(tree, act):
+    """A family's tree in the program's types, at any depth: an ``(int8
+    payload, float32 scale)`` tuple becomes a ``QTensor``, every other leaf is
+    cast to the serving dtype."""
+    import jax
+
+    from django_assistant_bot_tpu.ops.quant import QTensor
+
+    quantised = lambda x: isinstance(x, tuple)
+    return jax.tree.map(lambda leaf: QTensor(q=leaf[0], scale=leaf[1]) if quantised(leaf) else leaf.astype(act),
+                        tree, is_leaf=quantised)
+
+
+def write_checkpoint(family, conf: Dict[str, Any], seed: int, path: str) -> None:
+    """The family's seeded weights, in the program's parameter layout, written
+    by the program's ``save_model``: what ``ModelSpec.checkpoint`` loads.  Made
+    on the device from the seed and freed before the boot."""
     import jax
     import jax.numpy as jnp
 
-    from benchmarks import weights
     from django_assistant_bot_tpu.checkpoint import save_model
     from django_assistant_bot_tpu.models.config import DecoderConfig
-    from django_assistant_bot_tpu.ops.quant import QTensor
 
     act = getattr(jnp, conf["serving"].get("dtype", "bfloat16"))
-    w = weights.stacked(conf["hf"], seed, conf["weights"]["head_ids"])
-    layers = {name: QTensor(q=leaf[0], scale=leaf[1]) if isinstance(leaf, tuple) else leaf.astype(act)
-              for name, leaf in w["layers"].items()}
-    params = {"layers": layers, **{k: v.astype(act) for k, v in w["top"].items()}}
+    params = wrap_params(family.served_params(conf, seed), act)
     jax.block_until_ready(params)
     shutil.rmtree(path, ignore_errors=True)
     os.makedirs(os.path.dirname(path), exist_ok=True)
@@ -137,8 +148,67 @@ def boot_registry(conf: Dict[str, Any], model: str, checkpoint: str, overrides: 
     return ModelRegistry.from_config({model: spec})
 
 
+def engine_programs(family, conf: Dict[str, Any], sharding):
+    """For ``sizing.py``: the program's decode step, chunk prefill and a full
+    admission wave of suffix prefill at the configuration's geometry, over the
+    family's own parameter tree, as (name, jitted function, argument shapes).
+    Shapes only: the chip is described, not attached."""
+    import jax
+    import jax.numpy as jnp
+
+    from django_assistant_bot_tpu.models import llama
+    from django_assistant_bot_tpu.models.config import DecoderConfig
+
+    cfg = DecoderConfig.from_hf(conf["hf"], dtype=getattr(jnp, conf["serving"].get("dtype", "bfloat16")))
+    s = conf["serving"]
+    slots, page, pages = int(s["max_slots"]), int(s["kv_page_size"]), int(s["kv_pages"])
+    blocks, chunk = int(s["max_seq_len"]) // page, int(s["chunk_size"])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(lambda: wrap_params(family.served_params(conf, 0), cfg.dtype)))
+    pool = (cfg.num_layers, pages, cfg.num_kv_heads, page, cfg.head_dim)
+    cache = llama.PagedKVCache(k=sds(pool, cfg.dtype), v=sds(pool, cfg.dtype), lengths=sds((slots,), jnp.int32))
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    return [
+        ("decode_step_paged, %d slots" % slots,
+         jax.jit(lambda p, t, c, b: llama.decode_step_paged(p, cfg, t, c, b)),
+         (params, i32(slots), cache, i32(slots, blocks))),
+        ("prefill_chunk_paged, 1 x %d" % chunk,
+         jax.jit(lambda p, i, c, bt, sl, st, v: llama.prefill_chunk_paged(p, cfg, i, c, bt, sl, st, v), donate_argnums=(2,)),
+         (params, i32(1, chunk), cache, i32(blocks), i32(), i32(), i32())),
+        ("prefill_suffix_paged, %d x %d" % (slots, chunk),
+         jax.jit(lambda p, i, c, bt, sl, st, v: llama.prefill_suffix_paged(p, cfg, i, c, bt, sl, st, v), donate_argnums=(2,)),
+         (params, i32(slots, chunk), cache, i32(slots, blocks), i32(slots), i32(slots), i32(slots))),
+    ]
+
+
+DROP = object()
+
+
+def json_safe(x):
+    """``x`` as JSON can carry it: finite numbers, strings, None, and dicts and
+    lists of those; anything else is dropped (``DROP``)."""
+    if x is None or isinstance(x, (bool, str, int)):
+        return x
+    if isinstance(x, float):
+        return x if math.isfinite(x) else DROP
+    if isinstance(x, dict):
+        return {str(k): v for k, v in ((k, json_safe(v)) for k, v in x.items()) if v is not DROP}
+    if isinstance(x, (list, tuple)):
+        return [v for v in map(json_safe, x) if v is not DROP]
+    if getattr(x, "shape", None) == () and hasattr(x, "item"):  # a NumPy or JAX scalar
+        return json_safe(x.item())
+    return DROP
+
+
 def counters(engine) -> Dict[str, Any]:
-    """One flat snapshot of the program's own counters (all public calls)."""
+    """One snapshot of the program's own counters (all public calls): twelve
+    picked keys, flat, and the whole ``tick_stats``/``kv_stats``/``wait_stats``
+    dictionaries under those names, so that a reader added later reads a
+    counter this file has never heard of."""
     ts = engine.tick_stats()
     kv = ts.get("kv", {})
     sup = ts.get("supervision", {})
@@ -156,10 +226,14 @@ def counters(engine) -> Dict[str, Any]:
         "engine_restarts": sup.get("engine_restarts", 0),
         "poisoned_requests": sup.get("poisoned_requests", 0),
     }
+    out["tick_stats"] = json_safe(ts)
+    out["kv_stats"] = json_safe(engine.kv_stats())
     if engine.scheduler is not None:
-        w = engine.scheduler.wait_stats().get("interactive")
+        waits = engine.scheduler.wait_stats()
+        w = waits.get("interactive")
         if w and w["n"]:
             out["sched_wait_p95_ms"] = w["p95_ms"]
+        out["wait_stats"] = json_safe(waits)
     return out
 
 
@@ -233,6 +307,9 @@ def main() -> int:
 
     job = json.loads(sys.stdin.readline())
     conf, seed = job["conf"], int(job["seed"])
+    from benchmarks import families
+
+    family = families.load(conf, job["data_dir"])
     device = device_info(int(job["chips"]), bool(job["rehearsal"]))
     t_device = time.monotonic() - t_start
     enable_compile_cache()
@@ -242,7 +319,7 @@ def main() -> int:
     import jax.numpy as jnp
 
     t = time.monotonic()
-    write_checkpoint(conf, seed, job["checkpoint"])
+    write_checkpoint(family, conf, seed, job["checkpoint"])
     gc.collect()
     t_weights = time.monotonic() - t
     t = time.monotonic()
@@ -280,7 +357,7 @@ def main() -> int:
     with open(finish["sample"]) as f:
         picked = json.load(f)
     controls = bool(finish.get("controls"))
-    numbers = correct.logit_gaps(conf, seed, picked, controls,
+    numbers = correct.logit_gaps(family, conf, seed, picked, controls,
                                  dump=finish["sample"] + ".gaps.json" if controls else "") if picked else {}
     say({"event": "checked", "numbers": numbers, "check_s": time.monotonic() - t})
     return 0

@@ -1,8 +1,9 @@
 """The command end to end at a tiny size on the CPU (``--rehearsal``): two
 processes, a seeded checkpoint, the program's registry and server, requests
 over HTTP.  The control that must come out not correct, a broken timed path
-that must come out not correct, and a cell added as files of its own.  This
-process never imports JAX; the children do."""
+that must come out not correct, a cell added as files of its own, and an
+architecture added as files of its own.  This process never imports JAX; the
+children do."""
 import asyncio
 import json
 import os
@@ -13,6 +14,7 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.join(HERE, "rehearsal.json")
 ROOT = os.path.dirname(os.path.dirname(HERE))
+DATA_DIRS = ("configs", "traffic", "layer_metrics", "families")  # what a later PR adds files to
 
 
 def _run(capsys, *argv):
@@ -62,7 +64,7 @@ def test_a_cell_added_as_files_of_its_own_runs_without_editing_any(capsys, tmp_p
     """A later PR adds a configuration, a mix, a per-layer metric and their
     entries; nothing that was there is edited."""
     data = tmp_path / "benchmarks"
-    for sub in ("configs", "traffic", "layer_metrics"):
+    for sub in DATA_DIRS:
         shutil.copytree(os.path.join(ROOT, "benchmarks", sub), data / sub)
     conf = json.load(open(data / "configs" / "tiny-rehearsal.json"))
     conf["name"] = "tiny-wider"
@@ -95,6 +97,92 @@ def test_a_cell_added_as_files_of_its_own_runs_without_editing_any(capsys, tmp_p
     assert res["metrics"]["prefix_hits"]["value"] > 0 and res["metrics"]["rows_active_mean"]["value"] > 0
     assert res["device"]["busy_s"] > 0 and res["device"]["window_s"] > 0
     assert len(res["breakdown"]["device_ops"]) <= 10
+
+
+# the llama family's reference under the new family's weights: what a check against the wrong block reads
+WRONG_REFERENCE = """
+import os
+from benchmarks import families
+
+_here = os.path.dirname(os.path.dirname(__file__))
+_own, _llama = (families.load({"family": name}, _here) for name in ("tiny_vscale", "llama"))
+served_params, LIMITS, CONTROLS = _own.served_params, _own.LIMITS, ()
+reference_logits = _llama.reference_logits
+decode_step_bytes, decode_step_flops = _llama.decode_step_bytes, _llama.decode_step_flops
+"""
+
+
+@pytest.fixture(scope="module")
+def second_architecture(tmp_path_factory):
+    """What a ``model_config`` PR brings, written into a copy of the data
+    directories: a family, its configuration and cell, three readers, and their
+    entries.  No file that was there is edited."""
+    root = tmp_path_factory.mktemp("second_architecture")
+    data = root / "benchmarks"
+    for sub in DATA_DIRS:
+        shutil.copytree(os.path.join(ROOT, "benchmarks", sub), data / sub)
+    before = {os.path.join(d, f): open(os.path.join(d, f), "rb").read() for d, _, fs in os.walk(data) for f in fs}
+    shutil.copy(os.path.join(HERE, "vscale_family.py"), data / "families" / "tiny_vscale.py")
+    (data / "families" / "tiny_vscale_wrong_reference.py").write_text(WRONG_REFERENCE)
+    bench = json.load(open(BENCH))
+    for name, family in (("tiny-vscale", "tiny_vscale"), ("tiny-vscale-wrong", "tiny_vscale_wrong_reference")):
+        conf = json.load(open(data / "configs" / "tiny-rehearsal.json"))
+        conf.update(name=name, family=family)
+        json.dump(conf, open(data / "configs" / f"{name}.json", "w"))
+        bench["configs"].append({"name": name, "source": "none", "why": "test", "reduced": [],
+                                 "file": f"benchmarks/configs/{name}.json"})
+        bench["workloads"].append({"name": name + ".open", "config": name, "traffic": "tiny-open",
+                                   "chips": 1, "why": "test"})
+    readers = {
+        # a key of the engine's loop ledger, which only the pass-through of the whole tick_stats carries
+        "loop_tick_block_s": "def read(ctx):\n    at = lambda c: c['tick_stats']['loop']['tick_block']['s']\n"
+                             "    return at(ctx['c1']) - at(ctx['c0'])\n",
+        # the time of an operation that is not among the ten longest
+        "op_outside_the_ten_s": "def read(ctx):\n    t = ctx['trace']\n    top = {n for n, _ in t['device_ops']}\n"
+                                "    rest = [v for k, v in t['op_s'].items() if k not in top]\n"
+                                "    return max(rest) if rest else None\n",
+        # the family's own count, through ctx
+        "family_step_mb": "def read(ctx):\n    return ctx['family'].decode_step_bytes(ctx['conf'], 100.0) / 1e6\n",
+    }
+    for name, text in readers.items():
+        (data / "layer_metrics" / (name + ".py")).write_text(text)
+        bench["per_layer"].append({"name": name, "unit": "s", "better": "lower", "source": "program_span",
+                                   "layer": "engine", "moves": "tpot_p50_ms", "workloads": ["tiny-vscale.open"]})
+    json.dump(bench, open(root / "BENCHMARK.json", "w"))
+    yield root
+    assert all(open(p, "rb").read() == b for p, b in before.items())  # nothing that was there was edited
+
+
+def _run_second(capsys, root, workload, *more):
+    return _run(capsys, "--benchmark-json", str(root / "BENCHMARK.json"), "--data-root", str(root),
+                "--workload", workload, "--seed", "7", "--seconds", "3", "--rehearsal", *more)
+
+
+def test_an_architecture_added_as_files_of_its_own_runs_and_is_held_to_its_own_limits(capsys, second_architecture):
+    diag, res = _run_second(capsys, second_architecture, "tiny-vscale.open", "--trace", "1", "--controls")
+    assert res["correct"] is True and res["failed"] == 0, diag["compared"]
+    gap, limit = diag["compared"]["logit_gap_max"]
+    assert limit == 0.05 and gap < 0.01  # the family's own limit, not llama's 0.30
+    assert diag["compared"]["control_int4_gap_max"][0] > 0.3  # its own control, through its own reference
+    assert "control_kv_fp8_gap_max" not in diag["compared"]   # and only the controls it names
+    assert list(res)[-1] == "compared" and res["compared"] == diag["compared"]  # last in the result's line too
+    assert res["metrics"]["loop_tick_block_s"]["value"] > 0
+    assert res["metrics"]["op_outside_the_ten_s"]["value"] > 0
+    assert res["metrics"]["family_step_mb"]["value"] > 0
+    assert res["metrics"]["rows_active_mean"]["value"] > 0  # the readers that were there still read
+
+
+def test_the_new_architecture_checked_against_the_llama_reference_is_not_correct(capsys, second_architecture):
+    diag, res = _run_second(capsys, second_architecture, "tiny-vscale-wrong.open", "--trace", "0")
+    assert res["correct"] is False and res["failed"] == 0
+    assert diag["compared"]["logit_gap_max"][0] > 0.3  # far past either family's limit
+
+
+def test_the_new_architecture_with_a_tampered_timed_path_is_not_correct(capsys, second_architecture):
+    diag, res = _run_second(capsys, second_architecture, "tiny-vscale.open", "--trace", "0",
+                            "--sut", os.path.join(HERE, "tampered_sut.py"))
+    assert res["correct"] is False and res["failed"] == 0
+    assert diag["compared"]["logit_gap_max"][0] > diag["compared"]["logit_gap_max"][1] == 0.05
 
 
 async def _fake_dialog_server(log):

@@ -89,7 +89,7 @@ def test_the_rehearsal_reports_all_four(capsys, tmp_path):
     """The command end to end on the CPU with the four entries added.  A CPU trace has no compiled-program
     line, so ``decode_step_dev_ms`` reads nothing there: a reader of a fixed step time stands in for it."""
     data = tmp_path / "benchmarks"
-    for sub in ("configs", "traffic", "layer_metrics"):
+    for sub in ("configs", "traffic", "layer_metrics", "families"):
         shutil.copytree(os.path.join(ROOT, "benchmarks", sub), data / sub)
     (data / "layer_metrics" / "decode_step_dev_ms.py").write_text("def read(ctx):\n    return 0.05 if ctx['trace'] else None\n")
     bench = json.load(open(os.path.join(HERE, "rehearsal.json")))

@@ -2,9 +2,12 @@
 busy time inside the window, time per compiled program and per operation, and
 the idle gaps with a label for each.
 
-Read with ``jax.profiler.ProfileData`` alone.  A TPU trace has one plane per
-chip (``/device:TPU:<n>``) whose line ``XLA Ops`` holds one event per operation
-run and ``XLA Modules`` one per compiled program run.  A CPU trace (the tests'
+Read with ``jax.profiler.ProfileData``; only the block an operation belongs to
+(the named scope in its ``tf_op`` stat, which ``ProfileData`` does not show)
+is read from the protocol buffer itself, with TensorFlow's ``xplane_pb2``
+loaded by its file's path (importing ``tensorflow`` takes 8 s and JAX with it).
+A TPU trace has one plane per chip (``/device:TPU:<n>``) whose line ``XLA Ops``
+holds one event per operation run and ``XLA Modules`` one per compiled program run.  A CPU trace (the tests'
 rehearsal only) has no device plane: XLA's CPU client threads on the host plane
 stand in.  The window is marked by two host annotations that the harness
 writes, ``bench_window_open`` and ``bench_window_close``; without them it is
@@ -56,6 +59,51 @@ def op_name(event_name: str) -> str:
     return m.group(1) if m else event_name[:64]
 
 
+def _xplane_pb2():
+    """TensorFlow's generated ``xplane_pb2`` module, loaded from its file
+    without importing ``tensorflow``; None where it is not installed."""
+    import importlib.util
+
+    try:
+        pkg = importlib.util.find_spec("tensorflow")
+        path = os.path.join(pkg.submodule_search_locations[0], "tsl", "profiler", "protobuf", "xplane_pb2.py")
+        spec = importlib.util.spec_from_file_location("benchmarks_xplane_pb2", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+    except Exception:  # not installed, moved, or a protobuf runtime that refuses it: no scopes, the rest stands
+        return None
+
+
+def op_scopes(path: str) -> Optional[Dict[str, Dict[str, str]]]:
+    """For each device plane, an operation's event name -> its ``tf_op`` stat:
+    the named scopes it was traced under and its primitive, as the profiler
+    wrote them (``jit(tick)/.../ffn/gate_up/dot_general:``).  None when
+    ``xplane_pb2`` cannot be loaded."""
+    pb = _xplane_pb2()
+    if pb is None:
+        return None
+    space = pb.XSpace()
+    with open(path, "rb") as f:
+        space.ParseFromString(f.read())
+    out: Dict[str, Dict[str, str]] = {}
+    for plane in space.planes:
+        if not plane.name.startswith("/device:TPU:"):
+            continue
+        stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+        scopes = out.setdefault(plane.name, {})
+        for md in plane.event_metadata.values():
+            for s in md.stats:
+                if stat_names.get(s.metadata_id) == "tf_op":
+                    scopes.setdefault(md.name, s.str_value or (stat_names.get(s.ref_value, "") if s.ref_value else ""))
+    return out
+
+
+def top(seconds_by_name: Dict[str, float], n: int = 10) -> List[List[Any]]:
+    """The ``n`` largest, as ``[[name, seconds], ...]``."""
+    return [[k, v] for k, v in sorted(seconds_by_name.items(), key=lambda kv: -kv[1])[:n]]
+
+
 def reduce(path: str, label: Optional[Callable[[float, float], str]] = None) -> Dict[str, Any]:
     """``label(t0, t1)`` names an idle gap given its edges in seconds from the
     window's opening; gaps are summed by label."""
@@ -87,16 +135,22 @@ def reduce(path: str, label: Optional[Callable[[float, float], str]] = None) -> 
     hi = marks.get(CLOSE_MARK, max(b for _, _, b in all_ops))
     window_s = hi - lo
     busy, op_time, prog_time, prog_runs = [], {}, {}, {}
+    scopes = op_scopes(path)
+    scope_time: Optional[Dict[str, float]] = None if scopes is None else {}
     gaps_by_label: Dict[str, float] = {}
     longest_gap = 0.0
     for i, (name, d) in enumerate(sorted(devices.items())):
         ops = [(n, max(a, lo), min(b, hi)) for n, a, b in d.get("XLA Ops", []) if b > lo and a < hi]
         iv = union([(a, b) for _, a, b in ops])
         busy.append(sum(b - a for a, b in iv))
+        plane_scopes = (scopes or {}).get(name, {})
         for n, a, b in ops:
             if op_name(n).startswith(CONTAINERS):
                 continue  # a loop's event spans its body's events: counted there
             op_time[op_name(n)] = op_time.get(op_name(n), 0.0) + (b - a) / len(devices)
+            if scope_time is not None:
+                scope = plane_scopes.get(n, "")  # "": an operation under no named scope
+                scope_time[scope] = scope_time.get(scope, 0.0) + (b - a) / len(devices)
         for n, a, b in d.get("XLA Modules", []):
             if b > lo and a < hi:
                 p = program_name(n)
@@ -109,13 +163,14 @@ def reduce(path: str, label: Optional[Callable[[float, float], str]] = None) -> 
                     longest_gap = max(longest_gap, b - a)
                     what = label(a - lo, b - lo) if label else "unlabelled"
                     gaps_by_label[what] = gaps_by_label.get(what, 0.0) + (b - a)
-    top = lambda d: [[k, v] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:10]]
     return {
         "window_s": window_s,
         "busy_s": sum(busy) / len(busy),
         "devices": len(devices),
         "marked": OPEN_MARK in marks and CLOSE_MARK in marks,
         "device_ops": top(op_time),
+        "op_s": op_time,        # every operation's time, by the name ``device_ops`` prints
+        "scope_s": scope_time,  # the same time by the operation's ``tf_op`` scope; None without ``xplane_pb2``
         "idle_gaps": top(gaps_by_label),
         "longest_gap_s": longest_gap,
         "program_s": prog_time,
