@@ -1,0 +1,16 @@
+"""kernels: the ``mla_moe`` family's decode step as a share of its HBM roofline (%): the bytes a
+step must read (``family.decode_step_bytes``: every weight once, of the held experts those HIT by
+the program's counter, and the live latent rows once per layer) over the published bandwidth,
+divided by ``decode_step_dev_ms``.  ``decode_step_roofline`` reads the ``llama`` family's counts."""
+
+
+def read(ctx):
+    f = ctx["family"]
+    step_ms = ctx["read"]("decode_step_dev_ms") if ctx.get("trace") else None
+    if not step_ms or not hasattr(f, "experts_hit_per_layer_step"):
+        return None
+    hit, live = f.experts_hit_per_layer_step(ctx), f.live_context_tokens(ctx)
+    if not hit or live is None:
+        return None
+    bw = ctx["roofline"].peaks(ctx["device"]["kind"])["hbm_bytes_per_s"]
+    return 100.0 * f.decode_step_bytes(ctx["conf"], live, hit) / bw / (step_ms * 1e-3)

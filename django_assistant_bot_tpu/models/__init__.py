@@ -9,11 +9,20 @@ with three families, all jit/pjit-first:
 - :mod:`.llama`   — Llama-3-family decoder (RMSNorm, RoPE, GQA, SwiGLU), layers
   stacked for ``lax.scan`` (fast compiles, PP-ready), KV-cache prefill/decode.
 - :mod:`.mixtral` — Mixtral-style MoE decoder: top-2 router with capacity-based
-  dense dispatch einsums (MXU-friendly), experts sharded over the ``expert`` axis.
+  dense dispatch einsums (MXU-friendly), experts sharded over the ``expert`` axis;
+  and the dropless sigmoid-routed layer that is told which experts it holds.
+- :mod:`.mla_moe` — latent-attention (MLA) decoder over a paged latent cache with
+  a leading dense layer and routed + shared experts (DeepSeek-V3 family), with
+  :mod:`.llama`'s paged entry points; :func:`module_for` picks by ``cfg.arch``.
 
 Parameters are plain pytrees of jnp arrays with a parallel pytree of logical axis
 names consumed by :mod:`..parallel.sharding`.
 """
 
 from .config import DecoderConfig, EncoderConfig  # noqa: F401
-from . import encoder, llama, mixtral  # noqa: F401
+from . import encoder, llama, mixtral, mla_moe  # noqa: F401
+
+
+def module_for(cfg: DecoderConfig):
+    """The module whose entry points run ``cfg``: chosen once, by ``cfg.arch``."""
+    return {"llama": llama, "mla_moe": mla_moe}[cfg.arch]

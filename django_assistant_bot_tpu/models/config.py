@@ -66,8 +66,89 @@ class EncoderConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentMoEConfig:
+    """What the latent-attention + routed-expert block (DeepSeek-V3 family:
+    ``kv_lora_rank``, ``n_routed_experts``) needs beyond :class:`DecoderConfig`.
+
+    Attention: queries through a rank-``q_lora_rank`` bottleneck with its own
+    RMSNorm; keys and values through ONE rank-``kv_lora_rank`` latent plus a
+    ``qk_rope_head_dim``-wide rotary key shared by all heads.  The cache holds
+    that ``kv_lora_rank + qk_rope_head_dim`` row per token per layer.
+
+    Feed-forward: the first ``first_dense_layers`` layers are dense SwiGLU
+    (``DecoderConfig.intermediate_size``), the rest route every token over
+    ``router_experts`` sigmoid-scored experts of width
+    ``moe_intermediate_size`` (top ``DecoderConfig.experts_per_token``, picks
+    limited to the ``topk_group`` best of ``n_group`` groups, weights
+    normalised over the picks and scaled) beside ``n_shared_experts`` shared
+    ones.  **This process holds only the experts of its rank**:
+    ``[ep_rank * experts_held, (ep_rank + 1) * experts_held)`` of a
+    ``ep_size``-way expert-parallel deployment; it routes over all of them,
+    computes its own, and leaves the rest of the sum to the absent ranks (no
+    code stands in for them or their exchange).
+    """
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    moe_intermediate_size: int
+    router_experts: int
+    first_dense_layers: int = 1
+    n_shared_experts: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # yarn with mscale_all_dim: the softmax scale is qk_head_dim^-0.5 times this
+    # (mscale(factor, mscale_all_dim)^2); cos/sin carry mscale/mscale_all_dim
+    softmax_scale_mult: float = 1.0
+    ep_size: int = 1
+    ep_rank: int = 0
+
+    def __post_init__(self):
+        if self.router_experts % self.ep_size or not 0 <= self.ep_rank < self.ep_size:
+            raise ValueError(
+                f"{self.router_experts} experts do not divide over ep_size={self.ep_size}, "
+                f"or ep_rank={self.ep_rank} is outside it"
+            )
+        if self.router_experts % self.n_group or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(
+                f"{self.router_experts} experts do not split into n_group={self.n_group} "
+                f"groups of which topk_group={self.topk_group} are kept"
+            )
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def experts_held(self) -> int:
+        return self.router_experts // self.ep_size
+
+    @property
+    def first_expert(self) -> int:
+        return self.ep_rank * self.experts_held
+
+    @property
+    def latent_width(self) -> int:
+        """One cached row: latent | rotary key, padded to whole 128-lane tiles."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+
+# keys that change a block's mathematics and that the plain block ignores: a
+# config carrying one is another family, never a dense Llama
+_OTHER_BLOCK_KEYS = ("kv_lora_rank", "n_routed_experts", "layer_types")
+_PLAIN_MODEL_TYPES = ("llama", "mistral", "mixtral", "qwen2", "phi3", "gemma")
+_LATENT_MOE_MODEL_TYPES = ("axk1", "deepseek_v3")
+
+
+@dataclasses.dataclass(frozen=True)
 class DecoderConfig:
-    """Llama-3 family decoder; ``num_experts > 0`` turns the MLP into Mixtral MoE."""
+    """Llama-3 family decoder; ``num_experts > 0`` turns the MLP into Mixtral MoE;
+    ``latent_moe`` set makes it the latent-attention + routed-expert block
+    (``models/mla_moe.py``; ``arch`` names which module runs the config)."""
 
     vocab_size: int = 128_256
     hidden_size: int = 4096
@@ -102,9 +183,12 @@ class DecoderConfig:
     num_experts: int = 0
     experts_per_token: int = 2
     expert_capacity_factor: float = 1.25
+    latent_moe: Optional[LatentMoEConfig] = None
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
+        if isinstance(self.latent_moe, Mapping):  # a checkpoint's JSON
+            object.__setattr__(self, "latent_moe", LatentMoEConfig(**self.latent_moe))
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.hidden_size // self.num_heads)
         if self.rope_scaling and self.rope_scaling[0] == "longrope":
@@ -134,8 +218,25 @@ class DecoderConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    @property
+    def arch(self) -> str:
+        """Which model module runs this config (``models.module_for``)."""
+        return "mla_moe" if self.latent_moe is not None else "llama"
+
     @classmethod
     def from_hf(cls, hf: Mapping[str, Any], dtype=jnp.bfloat16) -> "DecoderConfig":
+        model_type = hf.get("model_type")
+        other = [k for k in _OTHER_BLOCK_KEYS if hf.get(k)]
+        if model_type in _LATENT_MOE_MODEL_TYPES or {"kv_lora_rank", "n_routed_experts"} <= set(other):
+            return cls._from_hf_latent_moe(hf, dtype)
+        if other and model_type not in _PLAIN_MODEL_TYPES:
+            # Llama's six keys are all there, and so are keys this block would
+            # ignore: reading it as a dense Llama would serve another model
+            raise ValueError(
+                f"model_type {model_type!r} is not known and its config carries {other}: "
+                "these change the block's mathematics and the plain decoder block would "
+                "ignore them; refusing to read it as a dense Llama"
+            )
         num_experts = hf.get("num_local_experts", 0)
         is_gemma = hf.get("model_type") == "gemma"
         act = hf.get("hidden_activation") or hf.get("hidden_act") or "silu"
@@ -251,6 +352,84 @@ class DecoderConfig:
             num_experts=num_experts,
             experts_per_token=hf.get("num_experts_per_tok", 2),
             dtype=dtype,
+        )
+
+    @classmethod
+    def _from_hf_latent_moe(cls, hf: Mapping[str, Any], dtype) -> "DecoderConfig":
+        """The DeepSeek-V3 family's keys (``model_type`` ``deepseek_v3`` / ``axk1``).
+
+        What cannot be honoured is refused, not dropped.  The expert share: a
+        published config has ``ep_size`` 1 and ``n_routed_experts`` is all of
+        them.  A deployment's config names ``ep_rank`` beside ``ep_size``; there
+        ``n_routed_experts`` counts the experts held by THIS rank and the router
+        keeps its published width, ``n_routed_experts * ep_size``."""
+        import math
+
+        def refuse(why):
+            raise ValueError(f"latent-attention MoE config (model_type {hf.get('model_type')!r}): {why}")
+
+        if hf.get("scoring_func", "sigmoid") != "sigmoid":
+            refuse(f"scoring_func {hf.get('scoring_func')!r}: only sigmoid scores are implemented")
+        if hf.get("topk_method", "none") not in ("none", "group_limited_greedy"):
+            # noaux_tc adds a learnt correction bias to the scores that pick the
+            # experts; a checkpoint that has one must not be served without it
+            refuse(f"topk_method {hf.get('topk_method')!r}: the score-correction bias is not implemented")
+        if int(hf.get("moe_layer_freq", 1)) != 1:
+            refuse("moe_layer_freq != 1: only 'leading dense layers, then expert layers' is implemented")
+        if hf.get("attention_bias"):
+            refuse("attention_bias: the latent projections carry no biases here")
+        if not hf.get("q_lora_rank"):
+            refuse("q_lora_rank is null: full-rank queries are not implemented")
+        if (hf.get("hidden_act") or "silu") != "silu":
+            refuse(f"hidden_act {hf.get('hidden_act')!r}")
+        ep_size = int(hf.get("ep_size", 1))
+        held = int(hf["n_routed_experts"])
+        if "ep_rank" in hf:
+            ep_rank, router = int(hf["ep_rank"]), held * ep_size
+        elif ep_size == 1:
+            ep_rank, router = 0, held
+        else:
+            refuse(f"ep_size {ep_size} without ep_rank: which share of the experts is held here?")
+        rs = hf.get("rope_scaling")
+        rope_scaling, scale_mult = None, 1.0
+        if rs:
+            kind = rs.get("rope_type") or rs.get("type")
+            if kind != "yarn":
+                refuse(f"rope_scaling type {kind!r}: only yarn is implemented for this family")
+            factor = float(rs["factor"])
+
+            def mscale(m):
+                return 1.0 if factor <= 1.0 or not m else 0.1 * float(m) * math.log(factor) + 1.0
+
+            m_all = rs.get("mscale_all_dim") or 0
+            scale_mult = mscale(m_all) ** 2
+            rope_scaling = (
+                "yarn", factor, float(rs.get("beta_fast") or 32), float(rs.get("beta_slow") or 1),
+                float(rs.get("original_max_position_embeddings") or hf.get("max_position_embeddings", 4096)),
+                mscale(rs.get("mscale") or 1) / mscale(m_all), True,
+            )
+        lm = LatentMoEConfig(
+            q_lora_rank=int(hf["q_lora_rank"]), kv_lora_rank=int(hf["kv_lora_rank"]),
+            qk_nope_head_dim=int(hf["qk_nope_head_dim"]), qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
+            v_head_dim=int(hf["v_head_dim"]), moe_intermediate_size=int(hf["moe_intermediate_size"]),
+            router_experts=router, first_dense_layers=int(hf.get("first_k_dense_replace", 0)),
+            n_shared_experts=int(hf.get("n_shared_experts") or 0),
+            n_group=int(hf.get("n_group") or 1), topk_group=int(hf.get("topk_group") or 1),
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            softmax_scale_mult=scale_mult, ep_size=ep_size, ep_rank=ep_rank,
+        )
+        if not 0 < lm.first_dense_layers < int(hf["num_hidden_layers"]):
+            refuse("needs at least one leading dense layer and one expert layer")
+        return cls(
+            vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"], num_layers=hf["num_hidden_layers"],
+            num_heads=hf["num_attention_heads"], num_kv_heads=hf["num_attention_heads"],
+            head_dim=lm.qk_head_dim, max_seq_len=hf.get("max_position_embeddings", 8192),
+            rope_theta=float(hf.get("rope_theta", 10_000.0)), rope_scaling=rope_scaling,
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+            tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            experts_per_token=int(hf["num_experts_per_tok"]), latent_moe=lm, dtype=dtype,
         )
 
     @classmethod

@@ -113,6 +113,13 @@ def load_decoder(model_dir: str, dtype=None) -> tuple[DecoderConfig, Dict[str, A
     dtype = dtype or jnp.bfloat16
     hf = read_hf_config(model_dir)
     model_type = hf.get("model_type")
+    if model_type in ("axk1", "deepseek_v3") or hf.get("kv_lora_rank"):
+        raise ValueError(
+            f"model_type {model_type!r}: loading a latent-attention MoE checkpoint directory is not "
+            "implemented (its parameter names are not mapped, and kv_b_proj and the rotary pairs need "
+            "permuting into models/mla_moe.py's layout); serve it from a native checkpoint written by "
+            "checkpoint.save_model"
+        )
     if model_type is not None and model_type not in _SUPPORTED_DECODERS:
         raise ValueError(
             f"unsupported decoder model_type {model_type!r}; "

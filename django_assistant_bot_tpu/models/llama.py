@@ -1015,6 +1015,18 @@ class PagedKVCache(NamedTuple):
         return self.k.shape[3]
 
 
+KV_KIND = "kv"  # what a cached token is: keys and values per KV head (models.module_for)
+
+
+def kv_bytes_per_token(cfg: DecoderConfig, kv_dtype=None) -> int:
+    """Bytes one cached token takes over all layers, K and V."""
+    return cfg.num_layers * cfg.num_kv_heads * cfg.head_dim * 2 * jnp.dtype(kv_dtype or cfg.dtype).itemsize
+
+
+def decode_kv_path(cfg: DecoderConfig, kv_dtype, page: int, *, fp8_dot: bool = False) -> str:
+    return paged_decode_kv_path(kv_dtype or cfg.dtype, page, cfg.head_dim, fp8_dot=fp8_dot)
+
+
 def init_paged_cache(
     cfg: DecoderConfig, batch: int, n_pages: int, page_size: int, dtype=None
 ) -> PagedKVCache:

@@ -1,0 +1,535 @@
+"""Latent-attention + routed-expert decoder (DeepSeek-V3 family), served as one
+rank of an expert-parallel deployment.
+
+The block, per layer (``n`` RMSNorm; ``cfg.latent_moe`` has the sizes):
+
+- attention: ``c_q = n(h W_DQ)``, ``[q_nope | q_rope] = c_q W_UQ`` per head;
+  ``[c | k_r] = h W_DKV``, ``c_kv = n(c)``, ``k_rope = rope(k_r)`` (one per
+  token, shared by every head), ``q_rope = rope(q_rope)``; keys are
+  ``[c_kv W_UK | k_rope]``, values ``c_kv W_UV``; softmax scale
+  ``qk_head_dim^-0.5 * softmax_scale_mult``; out ``= concat(o) W_O``.
+- the cache holds ``[c_kv | k_rope]`` (after the norm, after the rotation),
+  padded to whole 128-lane tiles: ONE row per token per layer, the same row as
+  key and as value (:class:`LatentKVCache`).  Prefill expands keys and values
+  from the rows (flash kernel at the published widths); a decode step attends
+  over the latent itself with the up-projections absorbed (``q_abs = q_nope
+  W_UK^T``, ``o = (softmax(...) c_kv) W_UV``) and never expands the cache.
+- feed-forward: the first ``first_dense_layers`` layers a dense SwiGLU; the
+  rest ``shared(h) + sum over picked experts HELD HERE of g_e expert_e(h)``
+  (:func:`.mixtral.held_experts_mlp`: sigmoid scores, group-limited top-k, no
+  capacity, no dropped token).  What the absent ranks' experts would add is
+  left out, and that partial result goes on to the next layer.
+
+Two stacks of weights, ``dense_layers`` and ``moe_layers``, each scanned over
+its own leading axis.  The same entry points as :mod:`.llama`'s paged path
+(``serving/engine.py`` picks the module once, by ``cfg.arch``):
+``prefill``, ``insert_sequences_paged``, ``copy_pages``,
+``prefill_chunk_paged``, ``prefill_suffix_paged``, ``decode_step_paged``,
+``init_paged_cache``, ``paged_cache_shardings``.  The contiguous cache,
+speculation and quantised weights are not implemented for this block; the
+registry refuses them.
+
+Rotary pairs are half-split (``ops/rope.py``), as everywhere in this repo; a
+published checkpoint (interleaved pairs, ``kv_b_proj`` with keys and values
+interleaved per head) is permuted at load time into ``w_uk`` / ``w_uv``.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.attention import (
+    attention,
+    latent_decode_attention,
+    latent_decode_kv_path,
+    latent_decode_update_attend,
+    paged_decode_plan,
+)
+from ..ops.norms import rms_norm
+from ..ops.rope import apply_rope, rope_frequencies
+from ..parallel.sharding import with_constraint
+from .config import DecoderConfig
+from .llama import _embed, _head_logits
+from .mixtral import MOE_STAT_HEAD, held_experts_mlp, shared_experts_mlp
+
+Params = Dict[str, Any]
+
+KV_KIND = "latent"
+
+
+class LatentKVCache(NamedTuple):
+    """Page pool of latent rows.  kv: [L, P, page, W], one row per token per
+    layer (``c_kv | k_rope | zero pad``); lengths: [B] tokens present per slot;
+    stats: int32 [2, MOE_STAT_HEAD + experts_held], the routed layers' counters
+    since the last tick read them (row 0: decode steps, row 1: prefill), summed
+    on the device and handed out with a tick's tokens.  Block tables are the
+    host's, as for :class:`.llama.PagedKVCache`."""
+
+    kv: jnp.ndarray
+    lengths: jnp.ndarray
+    stats: jnp.ndarray
+
+    @property
+    def n_pages(self) -> int:
+        return self.kv.shape[1]
+
+    @property
+    def page_size(self) -> int:
+        return self.kv.shape[2]
+
+
+_NOT_HERE = "is not implemented for the latent-attention MoE block (models/mla_moe.py)"
+
+
+def check_serving(*, kv_layout="paged", speculative=0, prefix_cache=0, kv_cache_dtype=None,
+                  attn_fp8=False, kv_host_tier=False, quantize=None) -> None:
+    """Refuse, with the reason, every serving option this block does not
+    implement, before any weight is loaded or program built (the registry and
+    the engine both ask)."""
+    if kv_layout != "paged":
+        raise ValueError(f"kv_layout={kv_layout!r}: the contiguous (non-paged) cache {_NOT_HERE}; "
+                         "use kv_layout='paged' with a page size that divides max_seq_len")
+    if speculative:
+        raise ValueError(f"speculative={speculative}: tree verification (verify_tree_step_paged) {_NOT_HERE}")
+    if prefix_cache:
+        raise ValueError(f"prefix_cache={prefix_cache}: the prefix cache's page gather/restore names a K and a V pool "
+                         f"and {_NOT_HERE}; set prefix_cache=0")
+    if kv_host_tier:
+        raise ValueError(f"kv_host_bytes / kv_spill_dir: the host KV tier {_NOT_HERE}")
+    if kv_cache_dtype or attn_fp8:
+        raise ValueError(f"kv_cache_dtype={kv_cache_dtype!r} / attn_fp8: a reduced-precision latent cache {_NOT_HERE}")
+    if quantize:
+        raise ValueError(f"quantize={quantize!r}: int8/int4 of the latent projections and the experts {_NOT_HERE}; "
+                         "serve the weights in the checkpoint's dtype")
+
+
+def kv_bytes_per_token(cfg: DecoderConfig, kv_dtype=None) -> int:
+    """Bytes one cached token takes over all layers (pad lanes included)."""
+    return cfg.num_layers * cfg.latent_moe.latent_width * jnp.dtype(kv_dtype or cfg.dtype).itemsize
+
+
+def decode_kv_path(cfg: DecoderConfig, kv_dtype, page: int, *, fp8_dot: bool = False) -> str:
+    return latent_decode_kv_path(kv_dtype or cfg.dtype, page, cfg.latent_moe.latent_width)
+
+
+def init_paged_cache(cfg: DecoderConfig, batch: int, n_pages: int, page_size: int, dtype=None) -> LatentKVCache:
+    lm = cfg.latent_moe
+    return LatentKVCache(
+        kv=jnp.zeros((cfg.num_layers, n_pages, page_size, lm.latent_width), dtype or cfg.dtype),
+        lengths=jnp.zeros((batch,), jnp.int32),
+        stats=jnp.zeros((2, MOE_STAT_HEAD + lm.experts_held), jnp.int32),
+    )
+
+
+def paged_cache_shardings(cfg: DecoderConfig, mesh, batch: int) -> LatentKVCache:
+    """Replicated: a latent row belongs to every head, so there is no head axis
+    to shard the pool over (data-parallel attention is one pool per replica)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    rep = NamedSharding(mesh, P())
+    return LatentKVCache(kv=rep, lengths=rep, stats=rep)
+
+
+def _stack_sizes(cfg: DecoderConfig) -> tuple[int, int]:
+    nd = cfg.latent_moe.first_dense_layers
+    return nd, cfg.num_layers - nd
+
+
+def logical_axes(cfg: DecoderConfig) -> Params:
+    E, F = "embed", "mlp"
+    attn = {
+        "attn_norm": (None, E), "w_dq": (None, E, None), "q_norm": (None, None),
+        "w_uq": (None, None, "heads"), "w_dkv": (None, E, None), "kv_norm": (None, None),
+        "w_uk": (None, None, "heads"), "w_uv": (None, None, "heads"), "wo": (None, "heads", E),
+        "mlp_norm": (None, E),
+    }
+    dense = dict(attn, w_gate=(None, E, F), w_up=(None, E, F), w_down=(None, F, E))
+    # the held experts stay whole on every device of this process: "expert" is the
+    # axis ACROSS ranks (parallel/sharding.py), and a rank is one process here
+    moe = dict(
+        attn, router=(None, E, None),
+        w_gate=(None, None, E, F), w_up=(None, None, E, F), w_down=(None, None, F, E),
+        ws_gate=(None, E, F), ws_up=(None, E, F), ws_down=(None, F, E),
+    )
+    axes = {"tok_embed": ("vocab_in", E), "final_norm": (E,), "dense_layers": dense, "moe_layers": moe}
+    if not cfg.tie_embeddings:
+        axes["lm_head"] = (E, "vocab_out")
+    return axes
+
+
+def init(cfg: DecoderConfig, rng: jax.Array) -> Params:
+    """Random parameters in the served layout: ``dense_layers`` and
+    ``moe_layers`` stacked on a leading axis each, only the held experts drawn."""
+    lm = cfg.latent_moe
+    E, F, H = cfg.hidden_size, cfg.intermediate_size, cfg.num_heads
+    R, C, dn, dr, dv = lm.q_lora_rank, lm.kv_lora_rank, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim
+    Fm, Fs, Xh = lm.moe_intermediate_size, lm.moe_intermediate_size * lm.n_shared_experts, lm.experts_held
+    nd, nm = _stack_sizes(cfg)
+    keys = iter(jax.random.split(rng, 40))
+
+    def dense(shape, fan_in):
+        return (jax.random.normal(next(keys), shape) * fan_in ** -0.5).astype(cfg.dtype)
+
+    def attn(L):
+        return {
+            "attn_norm": jnp.ones((L, E), cfg.dtype), "w_dq": dense((L, E, R), E),
+            "q_norm": jnp.ones((L, R), cfg.dtype), "w_uq": dense((L, R, H * (dn + dr)), R),
+            "w_dkv": dense((L, E, C + dr), E), "kv_norm": jnp.ones((L, C), cfg.dtype),
+            "w_uk": dense((L, C, H * dn), C), "w_uv": dense((L, C, H * dv), C),
+            "wo": dense((L, H * dv, E), H * dv), "mlp_norm": jnp.ones((L, E), cfg.dtype),
+        }
+
+    params = {
+        "tok_embed": dense((cfg.vocab_size, E), 1.0),
+        "final_norm": jnp.ones((E,), cfg.dtype),
+        "dense_layers": dict(attn(nd), w_gate=dense((nd, E, F), E), w_up=dense((nd, E, F), E),
+                             w_down=dense((nd, F, E), F)),
+        "moe_layers": dict(
+            attn(nm), router=dense((nm, E, lm.router_experts), E),
+            w_gate=dense((nm, Xh, E, Fm), E), w_up=dense((nm, Xh, E, Fm), E), w_down=dense((nm, Xh, Fm, E), Fm),
+            ws_gate=dense((nm, E, Fs), E), ws_up=dense((nm, E, Fs), E), ws_down=dense((nm, Fs, E), Fs),
+        ),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense((E, cfg.vocab_size), E)
+    return params
+
+
+# ---------------------------------------------------------------------------
+# the block
+# ---------------------------------------------------------------------------
+
+
+def softmax_scale(cfg: DecoderConfig) -> float:
+    lm = cfg.latent_moe
+    return float(lm.qk_head_dim ** -0.5 * lm.softmax_scale_mult)
+
+
+def _rope_tables(cfg: DecoderConfig, max_len: int):
+    cos, sin = rope_frequencies(
+        cfg.latent_moe.qk_rope_head_dim, max_len, cfg.rope_theta,
+        scaling=cfg.rope_scaling, deployed_len=cfg.max_seq_len,
+    )
+    return jnp.asarray(cos), jnp.asarray(sin)
+
+
+def _mm(pattern: str, x, w, dtype):
+    return jnp.einsum(pattern, x, w.astype(dtype))
+
+
+def _queries_and_row(cfg: DecoderConfig, p: Params, h: jnp.ndarray, cos, sin):
+    """-> (q_nope [B,S,H,dn], q_rope [B,S,H,dr] rotated, row [B,S,W]): the
+    queries of ``h`` and the row the cache keeps for it."""
+    lm = cfg.latent_moe
+    B, S, _ = h.shape
+    H, dn, dr, C = cfg.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.kv_lora_rank
+    with jax.named_scope("attn/q_down"):
+        c_q = rms_norm(_mm("bse,er->bsr", h, p["w_dq"], cfg.dtype), p["q_norm"], cfg.rms_norm_eps)
+    with jax.named_scope("attn/q_up"):
+        q = _mm("bsr,ro->bso", c_q, p["w_uq"], cfg.dtype).reshape(B, S, H, dn + dr)
+        q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
+    with jax.named_scope("attn/kv_down"):
+        ckv = _mm("bse,ec->bsc", h, p["w_dkv"], cfg.dtype)
+        c_kv = rms_norm(ckv[..., :C], p["kv_norm"], cfg.rms_norm_eps)
+        k_rope = apply_rope(ckv[..., None, C:], cos, sin)[..., 0, :]
+        pad = lm.latent_width - C - dr
+        row = jnp.concatenate([c_kv, k_rope] + ([jnp.zeros((B, S, pad), c_kv.dtype)] if pad else []), axis=-1)
+    return q_nope, q_rope, row
+
+
+def _expanded_attention(cfg: DecoderConfig, p: Params, q_nope, q_rope, rows, *, causal=False, mask=None):
+    """Prefill's form: keys and values expanded from latent ``rows`` [B,Sk,W]
+    -> o [B,Sq,H*dv].  The flash kernel takes it where it is kernel-shaped
+    (``ops.attention.attention``): query/key width padded with zeros to whole
+    lane tiles (192 -> 256), value width as it is."""
+    lm = cfg.latent_moe
+    B, Sk, _ = rows.shape
+    H, dn, dr, dv, C = cfg.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim, lm.kv_lora_rank
+    with jax.named_scope("attn/kv_up"):
+        c_kv = rows[..., :C].astype(cfg.dtype)
+        k_rope = rows[..., C:C + dr].astype(cfg.dtype)
+        k_nope = _mm("bsc,co->bso", c_kv, p["w_uk"], cfg.dtype).reshape(B, Sk, H, dn)
+        v = _mm("bsc,co->bso", c_kv, p["w_uv"], cfg.dtype).reshape(B, Sk, H, dv)
+        D = dn + dr
+        pad = (-D) % 128 if D > 64 else 0
+        zq = [jnp.zeros(q_nope.shape[:-1] + (pad,), q_nope.dtype)] if pad else []
+        zk = [jnp.zeros((B, Sk, H, pad), k_nope.dtype)] if pad else []
+        q = jnp.concatenate([q_nope, q_rope] + zq, axis=-1).transpose(0, 2, 1, 3)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, Sk, H, dr))] + zk, axis=-1)
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)
+        q = with_constraint(q, ("batch", "heads", "length", "head_dim"))
+    o = attention(q, k, v, causal=causal, mask=mask, scale=softmax_scale(cfg))  # attn/core
+    return o.transpose(0, 2, 1, 3).reshape(B, q_nope.shape[1], H * dv)
+
+
+@jax.named_scope("attn/out")
+def _attn_out(cfg: DecoderConfig, p: Params, o: jnp.ndarray) -> jnp.ndarray:
+    return _mm("bso,oe->bse", o, p["wo"], cfg.dtype)
+
+
+def _dense_mlp(cfg: DecoderConfig, p: Params, x: jnp.ndarray) -> jnp.ndarray:
+    with jax.named_scope("ffn/gate_up"):
+        h = jax.nn.silu(_mm("bse,ef->bsf", x, p["w_gate"], cfg.dtype)) * _mm("bse,ef->bsf", x, p["w_up"], cfg.dtype)
+        h = with_constraint(h, ("batch", "length", "mlp"))
+    with jax.named_scope("ffn/down"):
+        return _mm("bsf,fe->bse", h, p["w_down"], cfg.dtype)
+
+
+def _ffn(cfg: DecoderConfig, p: Params, x: jnp.ndarray, valid, is_moe: bool):
+    """-> (y, routed-layer counters or zeros)."""
+    h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
+    if not is_moe:
+        return _dense_mlp(cfg, p, h), jnp.zeros((MOE_STAT_HEAD + cfg.latent_moe.experts_held,), jnp.int32)
+    y, stats = held_experts_mlp(cfg, p, h, valid)
+    if cfg.latent_moe.n_shared_experts:
+        y = y + shared_experts_mlp(cfg, p, h)
+    return y, stats
+
+
+def _scan_stacks(cfg: DecoderConfig, params: Params, make_body, carry):
+    """``lax.scan`` over the dense stack, then the expert stack; ``make_body
+    (is_moe)`` returns a scan body over ``(layer params, layer index)``.  Two
+    compiled bodies whatever the depth; per-layer outputs concatenate on the
+    layer axis."""
+    nd, nm = _stack_sizes(cfg)
+    carry, y_d = jax.lax.scan(make_body(False), carry, (params["dense_layers"], jnp.arange(nd)))
+    carry, y_m = jax.lax.scan(make_body(True), carry, (params["moe_layers"], jnp.arange(nd, nd + nm)))
+    return carry, jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), y_d, y_m)
+
+
+def _finish(params: Params, cfg: DecoderConfig, x_last: jnp.ndarray) -> jnp.ndarray:
+    return _head_logits(params, cfg, rms_norm(x_last, params["final_norm"], cfg.rms_norm_eps)).astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# prefill
+# ---------------------------------------------------------------------------
+
+
+def prefill(params: Params, cfg: DecoderConfig, input_ids: jnp.ndarray, lengths: jnp.ndarray):
+    """Right-padded prompts through the model -> (last-token logits [B,V] f32,
+    latent rows [L,B,S,W], routed-layer counters): the second and third go to
+    :func:`insert_sequences_paged` where the llama path hands ``ks, vs``."""
+    B, S = input_ids.shape
+    cos, sin = _rope_tables(cfg, S)
+    valid = jnp.arange(S)[None, :] < lengths[:, None]
+    x = _embed(params, cfg, input_ids)
+
+    def make_body(is_moe):
+        def body(x, inputs):
+            p, _ = inputs
+            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+            q_nope, q_rope, row = _queries_and_row(cfg, p, h, cos, sin)
+            # right-padded input: causal masking alone keeps real queries on real keys
+            x = x + _attn_out(cfg, p, _expanded_attention(cfg, p, q_nope, q_rope, row, causal=True))
+            y, stats = _ffn(cfg, p, x, valid, is_moe)
+            return with_constraint(x + y, ("batch", "length", "embed")), (row, stats)
+
+        return body
+
+    x, (rows, stats) = _scan_stacks(cfg, params, make_body, x)
+    last = jnp.take_along_axis(x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
+    return _finish(params, cfg, last), rows, stats.sum(0)
+
+
+def insert_sequences_paged(
+    cache: LatentKVCache,
+    rows: jnp.ndarray,  # [L, B, Sb, W] from prefill
+    stats: jnp.ndarray,  # prefill's routed-layer counters
+    lengths: jnp.ndarray,  # [B]
+    slots: jnp.ndarray,  # [B] int32 (max_slots sentinel = pad row)
+    block_tables: jnp.ndarray,  # [B, NB]; pad rows carry the P sentinel
+) -> LatentKVCache:
+    """Write prefilled rows into their slots' pages (positions [0, Sb)), whole
+    pages at a time; blocks past a row's allocation and pad rows drop."""
+    L, P, page, W = cache.kv.shape
+    Sb = rows.shape[2]
+    nbw = min(block_tables.shape[1], -(-Sb // page))
+    if nbw * page != Sb:
+        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, nbw * page - Sb), (0, 0)))
+    kv = cache.kv
+    for j in range(nbw):
+        blk = jax.lax.slice_in_dim(rows, j * page, (j + 1) * page, axis=2)
+        kv = kv.at[:, jnp.minimum(block_tables[:, j], P)].set(blk.astype(kv.dtype), mode="drop")
+    return LatentKVCache(
+        kv=kv,
+        lengths=cache.lengths.at[slots].set(lengths.astype(cache.lengths.dtype), mode="drop"),
+        stats=cache.stats.at[1].add(stats),
+    )
+
+
+def copy_pages(cache: LatentKVCache, src: jnp.ndarray, dst: jnp.ndarray) -> LatentKVCache:
+    """Clone whole pages inside the pool (the allocator's copy-on-write
+    primitive); dst entries >= P drop."""
+    P = cache.n_pages
+    kv = cache.kv.at[:, jnp.minimum(dst, P)].set(jnp.take(cache.kv, jnp.clip(src, 0, P - 1), axis=1), mode="drop")
+    return cache._replace(kv=kv)
+
+
+def _gather_rows(pool, layer, block_tables):
+    """One layer's logical view of each row's pages -> [B, NB*page, W]."""
+    L, P, page, W = pool.shape
+    B, NB = block_tables.shape
+    layer_pool = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+    rows = jnp.take(layer_pool, jnp.clip(block_tables, 0, P - 1).reshape(-1), axis=0)
+    return rows.reshape(B, NB * page, W)
+
+
+def _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, valids):
+    """Chunk and suffix prefill: ``C`` new tokens per row at positions
+    ``starts + [0, C)`` against what the row's pages already hold.  Per layer
+    the new rows are scattered into the pool token by token (pad tokens and
+    unallocated blocks drop, so a shared prefix page is never written), then
+    the row's logical view is gathered and attended in the expanded form."""
+    B, C = input_ids.shape
+    L, P, page, W = cache.kv.shape
+    NB = block_tables.shape[1]
+    S = NB * page
+    pos = starts[:, None] + jnp.arange(C)[None, :]  # [B, C]
+    real = jnp.arange(C)[None, :] < valids[:, None]
+    cos_t, sin_t = _rope_tables(cfg, S)
+    safe = jnp.minimum(pos, S - 1)
+    cos, sin = cos_t[safe], sin_t[safe]
+    phys = jnp.take_along_axis(block_tables, jnp.minimum(pos // page, NB - 1), axis=1)
+    phys = jnp.where(real & (pos < S), jnp.minimum(phys, P), P)
+    off = pos % page
+    mask = (jnp.arange(S)[None, None, None, :] <= pos[:, None, :, None])  # [B,1,C,S]
+    x = _embed(params, cfg, input_ids)
+
+    def make_body(is_moe):
+        def body(carry, inputs):
+            x, pool = carry
+            p, layer = inputs
+            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+            q_nope, q_rope, row = _queries_and_row(cfg, p, h, cos, sin)
+            with jax.named_scope("attn/kv_write"):
+                pool = pool.at[layer, phys, off].set(row.astype(pool.dtype), mode="drop")
+            with jax.named_scope("attn/kv_read"):
+                rows = _gather_rows(pool, layer, block_tables)
+            x = x + _attn_out(cfg, p, _expanded_attention(cfg, p, q_nope, q_rope, rows, mask=mask))
+            y, stats = _ffn(cfg, p, x, real, is_moe)
+            return (x + y, pool), stats
+
+        return body
+
+    (x, pool), stats = _scan_stacks(cfg, params, make_body, (x, cache.kv))
+    last = jnp.take_along_axis(x, jnp.maximum(valids - 1, 0)[:, None, None], axis=1)[:, 0]
+    return _finish(params, cfg, last), pool, stats.sum(0)
+
+
+def prefill_suffix_paged(params, cfg, input_ids, cache, block_tables, slots, starts, valids):
+    """Suffix tokens ``[B, C]`` after each row's ``starts`` cached tokens ->
+    (logits [B,V] f32, cache)."""
+    logits, pool, stats = _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, valids)
+    lengths = cache.lengths.at[slots].set((starts + valids).astype(cache.lengths.dtype), mode="drop")
+    return logits, LatentKVCache(kv=pool, lengths=lengths, stats=cache.stats.at[1].add(stats))
+
+
+def prefill_chunk_paged(params, cfg, input_ids, cache, block_table, slot, start, valid):
+    """One chunk ``[1, C]`` of one long prompt extends the slot's page chain ->
+    (logits [1,V] f32, cache)."""
+    logits, pool, stats = _prefill_against_cache(
+        params, cfg, input_ids, cache, block_table[None, :], jnp.reshape(start, (1,)), jnp.reshape(valid, (1,))
+    )
+    lengths = jax.lax.dynamic_update_index_in_dim(
+        cache.lengths, (start + valid).astype(cache.lengths.dtype), slot, 0
+    )
+    return logits, LatentKVCache(kv=pool, lengths=lengths, stats=cache.stats.at[1].add(stats))
+
+
+# ---------------------------------------------------------------------------
+# decode
+# ---------------------------------------------------------------------------
+
+
+def decode_step_paged(
+    params: Params,
+    cfg: DecoderConfig,
+    tokens: jnp.ndarray,  # [B] int32
+    cache: LatentKVCache,
+    block_tables: jnp.ndarray,  # [B, NB] int32
+    *,
+    active: Optional[jnp.ndarray] = None,
+    attn_fp8: bool = False,
+) -> tuple[jnp.ndarray, LatentKVCache]:
+    """One autoregressive step for every active slot over the latent pool ->
+    (logits [B,V] f32, cache).
+
+    Per layer the step writes ONE ``[W]`` row per slot at ``(block_table[b, pos
+    // page], pos % page)`` and attends over the latent rows themselves:
+    ``q_abs = q_nope W_UK^T`` per head, scores ``(q_abs . c_kv + q_rope .
+    k_rope) * scale``, ``o = (softmax c_kv) W_UV``.  The pool rides the layer
+    scans' CARRY with the layer index in ``xs``; on a TPU one Pallas call per
+    layer (:func:`~..ops.attention.latent_decode_update_attend`) patches the
+    row in place and DMAs the live pages, each once for scores and values, so
+    nothing in the step makes a value the size of a layer of the pool (PERF.md
+    section 5, PR 25).  Elsewhere a drop-mode scatter and the plain gather
+    (:func:`~..ops.attention.latent_decode_attention`) do the same.  Inactive
+    rows and rows past their allocation write nothing on either path."""
+    if attn_fp8:
+        raise NotImplementedError("attn_fp8 is not implemented for the latent cache")
+    lm = cfg.latent_moe
+    B = tokens.shape[0]
+    L, P, page, W = cache.kv.shape
+    NB = block_tables.shape[1]
+    S = NB * page
+    H, dn, dr, dv, C = cfg.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim, lm.kv_lora_rank
+    if active is None:
+        active = jnp.ones((B,), bool)
+    active = active & (cache.lengths < S)
+    positions = jnp.minimum(cache.lengths, S - 1)
+    cos_t, sin_t = _rope_tables(cfg, S)
+    cos, sin = cos_t[positions][:, None, :], sin_t[positions][:, None, :]
+    scale = softmax_scale(cfg)
+    kernel = latent_decode_kv_path(cache.kv.dtype, page, W) == "kernel"
+    if kernel:
+        plan = paged_decode_plan(block_tables, positions, active, n_pages=P, page=page)
+    else:
+        phys = jnp.take_along_axis(block_tables, (positions // page)[:, None], axis=1)[:, 0]
+        phys_w = jnp.where(active, jnp.minimum(phys, P), P)
+        off = positions % page
+    x = _embed(params, cfg, tokens)[:, None, :]
+    valid = active[:, None]
+
+    def make_body(is_moe):
+        def body(carry, inputs):
+            x, pool = carry
+            p, layer = inputs
+            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+            q_nope, q_rope, row = _queries_and_row(cfg, p, h, cos, sin)
+            with jax.named_scope("attn/absorb"):
+                q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], p["w_uk"].astype(cfg.dtype).reshape(C, H, dn))
+                pad = W - C - dr
+                q = jnp.concatenate(
+                    [q_abs, q_rope[:, 0]] + ([jnp.zeros((B, H, pad), q_abs.dtype)] if pad else []), axis=-1
+                )
+            if kernel:
+                o_lat, pool = latent_decode_update_attend(
+                    q, row[:, 0], pool, layer, block_tables, positions, plan, scale=scale, value_width=C
+                )
+            else:
+                with jax.named_scope("attn/kv_write"):
+                    pool = pool.at[layer, phys_w, off].set(row[:, 0].astype(pool.dtype), mode="drop")
+                o_lat = latent_decode_attention(
+                    q, jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False), block_tables, positions,
+                    scale=scale, value_width=C, active=active,
+                )
+            with jax.named_scope("attn/absorb"):
+                o = jnp.einsum("bhc,chd->bhd", o_lat, p["w_uv"].astype(cfg.dtype).reshape(C, H, dv))
+            x = x + _attn_out(cfg, p, o.reshape(B, 1, H * dv))
+            y, stats = _ffn(cfg, p, x, valid, is_moe)
+            return (x + y, pool), stats
+
+        return body
+
+    (x, pool), stats = _scan_stacks(cfg, params, make_body, (x, cache.kv))
+    new_cache = LatentKVCache(
+        kv=pool,
+        lengths=jnp.where(active, cache.lengths + 1, cache.lengths),
+        stats=cache.stats.at[0].add(stats.sum(0)),
+    )
+    return _finish(params, cfg, x[:, 0]), new_cache

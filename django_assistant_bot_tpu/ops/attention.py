@@ -48,8 +48,10 @@ def dot_product_attention(
     mask: Optional[jnp.ndarray] = None,  # broadcastable to [B, H, Sq, Sk]; True=keep
     q_offset: int | jnp.ndarray = 0,  # absolute position of q[0] (decode w/ KV cache)
     window: Optional[int] = None,  # sliding window: keep iff kpos > qpos - window
+    scale: Optional[float] = None,  # default: D ** -0.5
 ) -> jnp.ndarray:
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     scores = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
@@ -760,6 +762,227 @@ def paged_decode_update_attend(
 
 
 # ---------------------------------------------------------------------------
+# Latent (MLA) paged decode: one row per token, read once as key and as value
+# ---------------------------------------------------------------------------
+
+
+def latent_decode_kv_path(kv_dtype, page: int, width: int) -> str:
+    """:func:`paged_decode_kv_path` for a latent pool ``[L, P, page, width]``:
+    ``"kernel"`` (:func:`latent_decode_update_attend`) on a TPU when rows are
+    whole lane tiles and pages whole packed tiles, ``"xla"`` elsewhere."""
+    kernel_shaped = width % 128 == 0 and page % _packed_rows(kv_dtype) == 0
+    return "kernel" if kernel_shaped and jax.default_backend() == "tpu" else "xla"
+
+
+def _latent_decode_kernel(
+    # scalar prefetch (SMEM)
+    items_ref, n_ref, bt_ref, pos_ref, layer_ref,
+    # inputs
+    q_ref,  # [B, H, W] VMEM: absorbed query | rotary query | zero pad
+    new_ref,  # [B, 1, W] f32 VMEM: the step's latent row per slot
+    pool_hbm,  # [L, P, page, W] HBM: never loaded whole
+    # outputs
+    o_ref,  # [B, H, Wv] f32 VMEM
+    pool_out,  # the same buffer as pool_hbm (input_output_aliases)
+    # scratch
+    buf,  # [2, page, W] double-buffered page
+    m_scr,  # [B, H, 128] f32 running max (every lane the same)
+    l_scr,  # [B, H, 128] f32 running sum
+    rsem,  # DMA [2 (buffer)]
+    wsem,  # DMA [1]
+    *,
+    nb: int,
+    page: int,
+    sub: int,
+    scale: float,
+    value_width: int,
+):
+    """:func:`_paged_decode_kernel` for a latent pool: per work item one page of
+    ONE array is DMA'd in, patched with the step's row where it is the slot's
+    write page (that ``sub``-row tile DMA'd back: the only bytes written), and
+    folded into the slot's online softmax with all heads as the matmul's M
+    dimension.  The same page is the keys (all ``W`` lanes) and the values (the
+    first ``value_width`` lanes): it is read from HBM once."""
+    H = q_ref.shape[1]
+    layer = layer_ref[0]
+    n = n_ref[0]
+
+    def page_copy(i, s):
+        return pltpu.make_async_copy(pool_hbm.at[layer, bt_ref[items_ref[i]]], buf.at[s], rsem.at[s])
+
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    @pl.when(n > 0)
+    def _first():
+        page_copy(0, 0).start()
+
+    def item(i, carry):
+        s = jax.lax.rem(i, 2)
+
+        @pl.when(i + 1 < n)
+        def _prefetch():
+            page_copy(i + 1, 1 - s).start()
+
+        it = items_ref[i]
+        slot = it // nb
+        j = it - slot * nb
+        phys = bt_ref[it]
+        pos = pos_ref[slot]
+        writes = j == pos // page
+        off = pos - j * page
+        al = pl.multiple_of((off // sub) * sub, sub)
+        page_copy(i, s).wait()
+
+        def tile_copy():
+            rows = pl.ds(al, sub)
+            return pltpu.make_async_copy(buf.at[s, rows, :], pool_out.at[layer, phys, rows, :], wsem.at[0])
+
+        @pl.when(writes)
+        def _write_row():
+            at_row = jax.lax.broadcasted_iota(jnp.int32, (sub, buf.shape[2]), 0) == off - al
+            tile = buf[s, pl.ds(al, sub), :].astype(jnp.float32)
+            buf[s, pl.ds(al, sub), :] = jnp.where(at_row, new_ref[slot], tile).astype(buf.dtype)
+            tile_copy().start()
+
+        q = q_ref[slot]  # [H, W]
+        rows = buf[s].astype(q.dtype)  # [page, W]
+        sc = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [H, page]
+        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (H, page), 1)
+        sc = jnp.where(kpos <= pos, sc, NEG_INF)
+        m_prev = m_scr[slot][:, :1]
+        l_prev = l_scr[slot][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        o_ref[slot] = alpha * o_ref[slot] + jnp.dot(
+            p.astype(q.dtype), rows[:, :value_width], preferred_element_type=jnp.float32
+        )
+        m_scr[slot] = jnp.broadcast_to(m_new, (H, 128))
+        l_scr[slot] = jnp.broadcast_to(alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), (H, 128))
+
+        @pl.when(writes)
+        def _drain():  # the buffer is the next-but-one item's DMA target
+            tile_copy().wait()
+
+        return carry
+
+    jax.lax.fori_loop(0, n, item, 0)
+
+    def normalise(b, carry):
+        o_ref[b] = o_ref[b] / jnp.maximum(l_scr[b][:, :1], 1e-30)
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[0], normalise, 0)
+
+
+@jax.named_scope("attn/kv_read")
+def latent_decode_update_attend(
+    q: jnp.ndarray,  # [B, H, W]: absorbed query | rotary query | zeros, pool's lane layout
+    row_new: jnp.ndarray,  # [B, W] the step's latent row per slot (norm and rotation applied)
+    pool: jnp.ndarray,  # [L, P, page, W] the whole pool, every layer
+    layer: jnp.ndarray,  # scalar int32
+    block_tables: jnp.ndarray,  # [B, NB] int32
+    positions: jnp.ndarray,  # [B] int32
+    plan: tuple[jnp.ndarray, jnp.ndarray],  # paged_decode_plan(...)
+    *,
+    scale: float,
+    value_width: int,
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """A decode step's latent-row write and attention read over the latent
+    itself, as ONE Pallas call -> ``(o [B, H, value_width], pool)``.
+
+    :func:`paged_decode_update_attend`'s contract on a pool of one array: the
+    pool stays in HBM and comes back aliased, a slot writes one row at
+    ``(block_table[b, pos // page], pos % page)`` and reads the pages its table
+    names over ``[0, pos]``; slots and blocks off the plan read and WRITE
+    nothing.  Scores are ``q . row`` over all ``W`` lanes (the absorbed and the
+    rotary part in one contraction; pad lanes are zero on both sides) in
+    float32, values are the row's first ``value_width`` lanes.  Not sharded:
+    the latent row is shared by every head, so a mesh replicates the pool."""
+    B, H, W = q.shape
+    L, P, page, _ = pool.shape
+    NB = block_tables.shape[1]
+    sub = _packed_rows(pool.dtype)
+    if page % sub or W % 128 or value_width % 128 or H % 8:
+        raise ValueError(
+            f"latent decode kernel needs page % {sub} == 0, row and value widths in whole "
+            f"lane tiles and heads % 8 == 0, got page={page}, W={W}, Wv={value_width}, H={H}"
+        )
+    items, n_items = plan
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    o, pool = pl.pallas_call(
+        functools.partial(
+            _latent_decode_kernel, nb=NB, page=page, sub=sub, scale=float(scale),
+            value_width=value_width,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(1,),
+            in_specs=[vmem, vmem, hbm],
+            out_specs=[vmem, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, page, W), pool.dtype),
+                pltpu.VMEM((B, H, 128), jnp.float32),
+                pltpu.VMEM((B, H, 128), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((1,)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, value_width), jnp.float32),
+            jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        ],
+        input_output_aliases={7: 1},  # counts the five scalar-prefetch arguments
+        compiler_params=pltpu.CompilerParams(
+            # queries, the float32 accumulator and running state of every slot,
+            # two page buffers; room for the score tile and Pallas's own copies
+            vmem_limit_bytes=2 * (B * H * (W * 2 + value_width * 4 + 2 * 128 * 4)) + 4 * page * W * 2 + (16 << 20),
+        ),
+        name="latent_decode",
+        interpret=interpret,
+    )(
+        items, n_items, block_tables.reshape(-1).astype(jnp.int32),
+        positions.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+        q, row_new.astype(jnp.float32)[:, None, :], pool,
+    )
+    return o.astype(q.dtype), pool
+
+
+@jax.named_scope("attn/kv_read")
+def latent_decode_attention(
+    q: jnp.ndarray,  # [B, H, W]
+    pool_layer: jnp.ndarray,  # [P, page, W] one layer, the step's rows already written
+    block_tables: jnp.ndarray,  # [B, NB]
+    positions: jnp.ndarray,  # [B]
+    *,
+    scale: float,
+    value_width: int,
+    active: Optional[jnp.ndarray] = None,
+) -> jnp.ndarray:
+    """The plain function the kernel is tested against (and the CPU's path):
+    gather each slot's pages, float32 scores over ``[0, pos]``, softmax, values
+    from the same rows -> ``[B, H, value_width]``; inactive rows come back zero."""
+    P, page, W = pool_layer.shape
+    B, NB = block_tables.shape
+    rows = jnp.take(pool_layer, jnp.clip(block_tables, 0, P - 1).reshape(-1), axis=0)
+    rows = rows.reshape(B, NB * page, W).astype(q.dtype)
+    sc = jnp.einsum("bhw,bsw->bhs", q, rows, preferred_element_type=jnp.float32) * scale
+    keep = jnp.arange(NB * page)[None, :] <= positions[:, None]
+    keep &= jnp.repeat((block_tables >= 0) & (block_tables < P), page, axis=1)
+    if active is not None:
+        keep &= active[:, None]
+    sc = jnp.where(keep[:, None, :], sc, NEG_INF)
+    probs = jnp.where(keep[:, None, :], jax.nn.softmax(sc, axis=-1), 0.0).astype(q.dtype)
+    return jnp.einsum("bhs,bsw->bhw", probs, rows[..., :value_width])
+
+
+# ---------------------------------------------------------------------------
 # Pallas flash attention
 # ---------------------------------------------------------------------------
 
@@ -779,6 +1002,7 @@ def _flash_kernel(
     causal: bool,
     q_block: int,
     window: "Optional[int]" = None,
+    scale: "Optional[float]" = None,
 ):
     """One (batch*head, q-block, kv-chunk) program: online softmax, chunked KV.
 
@@ -807,7 +1031,8 @@ def _flash_kernel(
     # keep operands in their storage dtype (bf16): the MXU's fast path; accumulate
     # in f32 via preferred_element_type.  Scaling folds into the f32 scores.
     q = q_ref[:]
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
 
     spc = chunk_kv // block_kv  # sub-blocks per chunk
     num_kv_blocks = kv_len // block_kv
@@ -864,13 +1089,13 @@ def _flash_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "block_q", "block_kv", "interpret", "window", "chunk_kv"
+        "causal", "block_q", "block_kv", "interpret", "window", "chunk_kv", "scale"
     ),
 )
 def flash_attention(
     q: jnp.ndarray,  # [B, H, Sq, D]
     k: jnp.ndarray,  # [B, H, Sk, D]
-    v: jnp.ndarray,
+    v: jnp.ndarray,  # [B, H, Sk, Dv]: Dv may differ from D (latent attention: 192 / 128)
     *,
     causal: bool = False,
     block_q: int = 128,
@@ -878,14 +1103,15 @@ def flash_attention(
     interpret: bool = False,
     window: Optional[int] = None,
     chunk_kv: Optional[int] = None,  # default: min(8192, Sk); tests force smaller
+    scale: Optional[float] = None,  # default: D ** -0.5
 ) -> jnp.ndarray:
     B, H, Sq, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     block_q = min(block_q, Sq)
     block_kv = min(block_kv, Sk)
     if Sq % block_q or Sk % block_kv:
         raise ValueError(f"seq lens ({Sq},{Sk}) must be multiples of blocks ({block_q},{block_kv})")
-    if block_q % 8 or block_kv % 8 or D % 128 and D != 64:
+    if block_q % 8 or block_kv % 8 or D % 128 and D != 64 or Dv % 128 and Dv != 64:
         # Mosaic requires (8,128)-tile-aligned loads; reject early with a clear error
         # instead of a deep compiler failure.  Callers pad to a bucket first.
         raise ValueError(
@@ -895,7 +1121,7 @@ def flash_attention(
 
     qf = q.reshape(B * H, Sq, D)
     kf = k.reshape(B * H, Sk, D)
-    vf = v.reshape(B * H, Sk, D)
+    vf = v.reshape(B * H, Sk, Dv)
 
     # K/V stream through VMEM one chunk per grid step (double-buffered by the
     # pallas pipeline).  A whole-row [Sk, D] resident block dies at Sk=16k
@@ -921,6 +1147,7 @@ def flash_attention(
         causal=causal,
         q_block=block_q,
         window=window,
+        scale=scale,
     )
     def kv_index(bh, qi, ci):
         # Clamp dead chunks onto the nearest live one: grid steps whose chunk
@@ -944,18 +1171,18 @@ def flash_attention(
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda bh, qi, ci: (bh, qi, 0)),
             pl.BlockSpec((None, chunk_kv, D), kv_index),
-            pl.BlockSpec((None, chunk_kv, D), kv_index),
+            pl.BlockSpec((None, chunk_kv, Dv), kv_index),
         ],
-        out_specs=pl.BlockSpec((None, block_q, D), lambda bh, qi, ci: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
+        out_specs=pl.BlockSpec((None, block_q, Dv), lambda bh, qi, ci: (bh, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),  # m (col 0 used)
             pltpu.VMEM((block_q, 128), jnp.float32),  # l (col 0 used)
-            pltpu.VMEM((block_q, D), jnp.float32),  # acc
+            pltpu.VMEM((block_q, Dv), jnp.float32),  # acc
         ],
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(B, H, Sq, D)
+    return out.reshape(B, H, Sq, Dv)
 
 
 def sharded_flash_attention(
@@ -966,6 +1193,7 @@ def sharded_flash_attention(
     *,
     causal: bool = False,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """:func:`flash_attention` under a multi-device mesh.
 
@@ -990,7 +1218,7 @@ def sharded_flash_attention(
         None,
     )
     return jax.shard_map(
-        functools.partial(flash_attention, causal=causal, window=window),
+        functools.partial(flash_attention, causal=causal, window=window, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
@@ -1010,6 +1238,7 @@ def attention(
     mask: Optional[jnp.ndarray] = None,
     q_offset: int | jnp.ndarray = 0,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Dispatch: pallas flash kernel on TPU for long un-masked sequences, jnp otherwise.
 
@@ -1023,13 +1252,14 @@ def attention(
     (``parallel.sharding.mesh_scope``).  ``window`` (sliding-window attention)
     rides the flash path: the kernel skips kv blocks below the band entirely.
     """
-    D = q.shape[-1]
+    D, Dv = q.shape[-1], v.shape[-1]
     kernel_shaped = (
         mask is None
         and q.shape[2] >= 256
         and q.shape[2] % 128 == 0
         and k.shape[2] % 128 == 0
         and (D == 64 or D % 128 == 0)
+        and (Dv == 64 or Dv % 128 == 0)
         and isinstance(q_offset, int)
         and q_offset == 0
     )
@@ -1038,10 +1268,10 @@ def attention(
 
         mesh = active_mesh()
         if mesh is None or mesh.size == 1:
-            return flash_attention(q, k, v, causal=causal, window=window)
+            return flash_attention(q, k, v, causal=causal, window=window, scale=scale)
         return sharded_flash_attention(
-            q, k, v, mesh, causal=causal, window=window
+            q, k, v, mesh, causal=causal, window=window, scale=scale
         )
     return dot_product_attention(
-        q, k, v, causal=causal, mask=mask, q_offset=q_offset, window=window
+        q, k, v, causal=causal, mask=mask, q_offset=q_offset, window=window, scale=scale
     )

@@ -38,8 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models import DecoderConfig, EncoderConfig, encoder, llama
-from ..ops.attention import paged_decode_kv_path
+from ..models import DecoderConfig, EncoderConfig, encoder, module_for
 from ..ops.sampling import sample_logits
 from ..parallel.sharding import mesh_scope
 from .obs import EngineObs, LoopLedger, new_trace_id
@@ -259,6 +258,17 @@ class _Slot:
     decode_ticks: int = 0
 
 
+def _take_stats(cache):
+    """Inside a tick program: the counters a cache carries (``cache.stats``,
+    e.g. the routed layers' of ``models/mla_moe.py``) as a 1-tuple to return
+    with the tokens, and the cache with them zeroed, so each count is handed
+    out once; ``()`` for a cache that carries none."""
+    stats = getattr(cache, "stats", None)
+    if stats is None:
+        return cache, ()
+    return cache._replace(stats=jnp.zeros_like(stats)), (stats,)
+
+
 @dataclasses.dataclass
 class _TickRef:
     """One issued-but-not-yet-processed device result.
@@ -286,6 +296,9 @@ class _TickRef:
     # issue a narrower/shallower rung than the config maximum — acceptance
     # accounting needs the per-tick value, not the engine knob)
     spec_rung: Any = None
+    # the program's own counters for this tick (a cache that carries `stats`):
+    # a device array that arrives with `nxt`, or None
+    aux: Any = None
 
 
 @dataclasses.dataclass
@@ -329,6 +342,7 @@ class GenerationEngine:
         spec_explore_every: int = 32,
         decode_kv_chunk: Optional[int] = 0,
         prefill_piggyback: bool = True,
+        prefill_wave: int = 0,
         attn_fp8: bool = False,
         kv_layout: str = "paged",
         kv_page_size: int = 0,
@@ -376,9 +390,27 @@ class GenerationEngine:
         else:
             self.obs = None
         self.cfg = cfg
+        # the module whose entry points run this config (models.module_for):
+        # picked once, here; every device program below goes through it
+        self._model = module_for(cfg)
+        check = getattr(self._model, "check_serving", None)
+        if check is not None:  # a block that implements the paged plane only
+            check(
+                kv_layout=kv_layout, speculative=speculative, prefix_cache=prefix_cache_size,
+                kv_cache_dtype=kv_cache_dtype, attn_fp8=attn_fp8,
+                kv_host_tier=bool(int(kv_host_bytes) > 0 or kv_spill_dir),
+            )
+        # routed-expert counters (tick_stats()["moe"]): summed on the device
+        # inside the programs, handed out with each tick's tokens
+        self._moe_totals: Optional[np.ndarray] = None
+        self._tick_aux = None
         self.params = params
         self.tokenizer = tokenizer
         self.max_slots = max_slots
+        # the most rows one prefill dispatch admits (0 = all slots): bounds the
+        # largest prefill program's temporaries, and with them the programs
+        # warm-up compiles ({1, 4, wave} x seq buckets)
+        self.prefill_wave = min(max_slots, int(prefill_wave) or max_slots)
         self.max_seq_len = int(min(max_seq_len or cfg.max_seq_len, cfg.max_seq_len))
         self.top_k = top_k
         self.prefill_buckets = tuple(b for b in prefill_buckets if b <= self.max_seq_len) or (
@@ -583,6 +615,8 @@ class GenerationEngine:
                     self.max_seq_len,
                     page or None,
                 )
+                if check is not None:
+                    check(kv_layout="legacy")
                 self.paged = False
             else:
                 self.kv_page_size = page
@@ -597,16 +631,8 @@ class GenerationEngine:
 
                 from .kv_pool import PageAllocator
 
-                kv_itemsize = _jnp.dtype(
-                    self.kv_cache_dtype or cfg.dtype
-                ).itemsize
-                page_bytes = (
-                    cfg.num_layers
-                    * cfg.num_kv_heads
-                    * page
-                    * cfg.head_dim
-                    * 2  # K and V
-                    * kv_itemsize
+                page_bytes = page * self._model.kv_bytes_per_token(
+                    cfg, self.kv_cache_dtype
                 )
                 # --- host KV tier (docs/KV_PAGING.md "Tiered KV") ---------
                 # kv_host_bytes > 0 (or a spill dir) arms the durability
@@ -687,9 +713,8 @@ class GenerationEngine:
         # everywhere else.  A gauge and a boot log line, so a run that took
         # the plain path on a chip cannot pass for the kernel.
         self.decode_kv_path = (
-            paged_decode_kv_path(
-                self.kv_cache_dtype or cfg.dtype, self.kv_page_size,
-                cfg.head_dim, fp8_dot=self.attn_fp8,
+            self._model.decode_kv_path(
+                cfg, self.kv_cache_dtype, self.kv_page_size, fp8_dot=self.attn_fp8
             )
             if self.paged
             else "xla"
@@ -776,9 +801,9 @@ class GenerationEngine:
         self.mesh = mesh
         if mesh is not None:
             self._cache_shardings = (
-                llama.paged_cache_shardings(cfg, mesh, max_slots)
+                self._model.paged_cache_shardings(cfg, mesh, max_slots)
                 if self.paged
-                else llama.cache_shardings(cfg, mesh, max_slots)
+                else self._model.cache_shardings(cfg, mesh, max_slots)
             )
         else:
             self._cache_shardings = None
@@ -926,19 +951,19 @@ class GenerationEngine:
             insert_out = chunk_out = None
 
         def _prefill(params, ids, lengths):
-            return llama.prefill(params, cfg_c, ids, lengths)
+            return self._model.prefill(params, cfg_c, ids, lengths)
 
         self._prefill = jax.jit(_prefill)
         # donate the cache here too: slot insertion is a scatter into HBM, not a copy
         if self.paged:
             self._insert = jax.jit(
-                llama.insert_sequences_paged,
+                self._model.insert_sequences_paged,
                 donate_argnums=(0,),
                 out_shardings=insert_out,
             )
 
             def _prefill_chunk_paged(params, ids, cache, bt_row, slot, start, valid):
-                return llama.prefill_chunk_paged(
+                return self._model.prefill_chunk_paged(
                     params, cfg_c, ids, cache, bt_row, slot, start, valid
                 )
 
@@ -947,7 +972,7 @@ class GenerationEngine:
             )
 
             def _prefill_suffix_paged(params, ids, cache, bt, slots, starts, valids):
-                return llama.prefill_suffix_paged(
+                return self._model.prefill_suffix_paged(
                     params, cfg_c, ids, cache, bt, slots, starts, valids
                 )
 
@@ -962,7 +987,7 @@ class GenerationEngine:
             # the allocator's COW primitive: clone the boundary page a prefix
             # sharer will write its own suffix into
             self._copy_pages = jax.jit(
-                llama.copy_pages, donate_argnums=(0,), out_shardings=insert_out
+                self._model.copy_pages, donate_argnums=(0,), out_shardings=insert_out
             )
             # host-tier spill/restore primitives (docs/KV_PAGING.md "Tiered
             # KV").  The gather does NOT donate the cache — it is a read-only
@@ -982,7 +1007,7 @@ class GenerationEngine:
             self._gather_pages = jax.jit(_gather_pages, out_shardings=gather_out)
 
             def _write_pages(cache, idx, k, v):
-                return llama.PagedKVCache(
+                return self._model.PagedKVCache(
                     k=cache.k.at[:, idx].set(k.astype(cache.k.dtype)),
                     v=cache.v.at[:, idx].set(v.astype(cache.v.dtype)),
                     lengths=cache.lengths,
@@ -994,21 +1019,21 @@ class GenerationEngine:
             self._insert_prefix = self._extract_prefix = None
         else:
             self._insert = jax.jit(
-                llama.insert_sequences, donate_argnums=(0,), out_shardings=insert_out
+                self._model.insert_sequences, donate_argnums=(0,), out_shardings=insert_out
             )
 
             def _prefill_chunk(params, ids, cache, slot, start, valid):
-                return llama.prefill_chunk(params, cfg_c, ids, cache, slot, start, valid)
+                return self._model.prefill_chunk(params, cfg_c, ids, cache, slot, start, valid)
 
             self._prefill_chunk = jax.jit(
                 _prefill_chunk, donate_argnums=(2,), out_shardings=chunk_out
             )
 
             def _prefill_suffix(params, ids, cache, slots, starts, valids):
-                return llama.prefill_suffix(params, cfg_c, ids, cache, slots, starts, valids)
+                return self._model.prefill_suffix(params, cfg_c, ids, cache, slots, starts, valids)
 
             if mesh is not None:
-                pfx = llama.prefix_shardings(cfg, mesh)
+                pfx = self._model.prefix_shardings(cfg, mesh)
                 suffix_out = (_replicated(mesh), self._cache_shardings)
                 extract_out = (pfx, pfx)
             else:
@@ -1017,10 +1042,10 @@ class GenerationEngine:
                 _prefill_suffix, donate_argnums=(2,), out_shardings=suffix_out
             )
             self._insert_prefix = jax.jit(
-                llama.insert_prefix, donate_argnums=(0,), out_shardings=insert_out
+                self._model.insert_prefix, donate_argnums=(0,), out_shardings=insert_out
             )
             self._extract_prefix = jax.jit(
-                llama.extract_prefix, static_argnums=(2,), out_shardings=extract_out
+                self._model.extract_prefix, static_argnums=(2,), out_shardings=extract_out
             )
             self._copy_pages = None
             self._gather_pages = self._write_pages = None
@@ -1063,6 +1088,33 @@ class GenerationEngine:
             out = None
         return jax.jit(act, out_shardings=out, static_argnames=("initial",))
 
+    def _n_tick_aux(self) -> int:
+        """How many counter outputs a tick program of this engine's cache kind
+        appends (:func:`_take_stats`), from a toy cache's shapes alone."""
+        if not self.paged:
+            return 0
+        return len(jax.eval_shape(lambda: _take_stats(self._model.init_paged_cache(self.cfg, 1, 2, 8))[1]))
+
+    def _with_aux(self, jitted, n_aux: int):
+        """A tick program whose cache carries counters returns them as one more
+        output; the callers' tuples stay as they are and the counters wait in
+        ``_tick_aux`` for the ``_TickRef`` (:meth:`_take_aux`)."""
+        if not n_aux:
+            return jitted
+
+        def call(*args):
+            *out, self._tick_aux = jitted(*args)
+            return tuple(out)
+
+        call.lower = jitted.lower
+        return call
+
+    def _take_aux(self):
+        aux, self._tick_aux = self._tick_aux, None
+        if aux is not None:
+            aux.copy_to_host_async()
+        return aux
+
     def _make_decode_tick(self, json_mode: bool, steps: Optional[int] = None):
         """Build the jitted fused tick: ``steps`` chained decode steps in one
         dispatch -> (toks [K,B], last tokens [B], cache[, fsm states]).
@@ -1102,12 +1154,12 @@ class GenerationEngine:
                 p = jax.lax.optimization_barrier(params) if burst_c > 1 else params
                 rng, sub = jax.random.split(rng)
                 if paged_c:
-                    logits, cache = llama.decode_step_paged(
+                    logits, cache = self._model.decode_step_paged(
                         p, cfg_c, tokens, cache, bt, active=active,
                         attn_fp8=fp8_c,
                     )
                 else:
-                    logits, cache = llama.decode_step(
+                    logits, cache = self._model.decode_step(
                         p, cfg_c, tokens, cache, active=active,
                         kv_chunk=kv_chunk_c, attn_fp8=fp8_c,
                     )
@@ -1138,16 +1190,18 @@ class GenerationEngine:
             # the advanced rng is an output: the host threads it call-to-call as
             # opaque device state — an eager jax.random.split per burst would be
             # one more dispatch round trip on the critical host path
+            cache, aux = _take_stats(cache)
             if json_mode:
-                return toks, tokens, cache, rng, fsm_s
-            return toks, tokens, cache, rng
+                return (toks, tokens, cache, rng, fsm_s) + aux
+            return (toks, tokens, cache, rng) + aux
 
+        n_aux = self._n_tick_aux()
         if self.mesh is not None:
             rep = _replicated(self.mesh)
-            out = (rep, rep, self._cache_shardings, rep) + ((rep,) if json_mode else ())
+            out = (rep, rep, self._cache_shardings, rep) + ((rep,) if json_mode else ()) + (rep,) * n_aux
         else:
             out = None
-        return jax.jit(tick, donate_argnums=(2,), out_shardings=out)
+        return self._with_aux(jax.jit(tick, donate_argnums=(2,), out_shardings=out), n_aux)
 
     def _make_piggyback_tick(self):
         """Continuous-batching tick: ONE jitted program runs a bounded prefill
@@ -1179,11 +1233,11 @@ class GenerationEngine:
             # --- the piggybacked prefill chunk (admitting slot only) -------
             if paged_c:
                 bt_row = jax.lax.dynamic_index_in_dim(bt, c_slot, 0, keepdims=False)
-                _, cache = llama.prefill_chunk_paged(
+                _, cache = self._model.prefill_chunk_paged(
                     params, cfg_c, c_ids, cache, bt_row, c_slot, c_start, c_valid
                 )
             else:
-                _, cache = llama.prefill_chunk(
+                _, cache = self._model.prefill_chunk(
                     params, cfg_c, c_ids, cache, c_slot, c_start, c_valid
                 )
 
@@ -1193,12 +1247,12 @@ class GenerationEngine:
                 p = jax.lax.optimization_barrier(params) if burst_c > 1 else params
                 rng, sub = jax.random.split(rng)
                 if paged_c:
-                    logits, cache = llama.decode_step_paged(
+                    logits, cache = self._model.decode_step_paged(
                         p, cfg_c, tokens, cache, bt, active=active,
                         attn_fp8=fp8_c,
                     )
                 else:
-                    logits, cache = llama.decode_step(
+                    logits, cache = self._model.decode_step(
                         p, cfg_c, tokens, cache, active=active,
                         kv_chunk=kv_chunk_c, attn_fp8=fp8_c,
                     )
@@ -1216,14 +1270,16 @@ class GenerationEngine:
                 (tokens, cache, rng), toks = jax.lax.scan(
                     body, carry, None, length=burst_c
                 )
-            return toks, tokens, cache, rng
+            cache, aux = _take_stats(cache)
+            return (toks, tokens, cache, rng) + aux
 
+        n_aux = self._n_tick_aux()
         if self.mesh is not None:
             rep = _replicated(self.mesh)
-            out = (rep, rep, self._cache_shardings, rep)
+            out = (rep, rep, self._cache_shardings, rep) + (rep,) * n_aux
         else:
             out = None
-        return jax.jit(tick, donate_argnums=(2,), out_shardings=out)
+        return self._with_aux(jax.jit(tick, donate_argnums=(2,), out_shardings=out), n_aux)
 
     def _ensure_fsm(self):
         """Build the JSON token-FSM tables on first constrained request (one-time:
@@ -1319,11 +1375,11 @@ class GenerationEngine:
                 draft = build_tree_draft(history, cache.lengths, tokens, N, K)
                 tree = flatten_tree(tokens, draft)  # [B, 1 + N*K]
                 if paged_c:
-                    logits, tks, tvs = llama.verify_tree_step_paged(
+                    logits, tks, tvs = self._model.verify_tree_step_paged(
                         p, cfg_c, tree, cache, bt, depths_c, anc_c
                     )
                 else:
-                    logits, tks, tvs = llama.verify_tree_step(
+                    logits, tks, tvs = self._model.verify_tree_step(
                         p, cfg_c, tree, cache, depths_c, anc_c
                     )
                 out, n_new, bonus, path_idx, rng = accept_tree(
@@ -1337,14 +1393,14 @@ class GenerationEngine:
                     # sentinel — a paged garbage write could land in a page
                     # since handed to another request, so masking is part of
                     # the contract
-                    cache = llama.commit_tree_path_paged(
+                    cache = self._model.commit_tree_path_paged(
                         cache, tks, tvs, path_idx, bt, n_new, active
                     )
                 else:
                     # contiguous rows tolerate the rejected tail: it sits
                     # past the new valid length, masked/overwritten like all
                     # garbage
-                    cache = llama.commit_tree_path(cache, tks, tvs, path_idx)
+                    cache = self._model.commit_tree_path(cache, tks, tvs, path_idx)
                 # persist this step's input token + accepted tokens into the
                 # history at sequence positions lengths..lengths+K+1;
                 # positions beyond the accepted run hold garbage that later
@@ -1397,12 +1453,12 @@ class GenerationEngine:
             n_pages, page = self._kv_pool.n_pages, self.kv_page_size
 
             def make():
-                return llama.init_paged_cache(
+                return self._model.init_paged_cache(
                     self.cfg, self.max_slots, n_pages, page, dtype=dt
                 )
         else:
             def make():
-                return llama.init_cache(
+                return self._model.init_cache(
                     self.cfg, self.max_slots, self.max_seq_len, dtype=dt
                 )
 
@@ -2389,7 +2445,7 @@ class GenerationEngine:
         now = self._clock()
         free = self._free_slots()
         batch: List[tuple[int, _Request, Any]] = []
-        while free:
+        while free and len(batch) < self.prefill_wave:
             req = self._peek_next(now)
             if req is None:
                 break
@@ -2773,11 +2829,12 @@ class GenerationEngine:
         return (mx // c + 1) / n_chunks
 
     def _batch_buckets(self) -> tuple:
-        """Prefill batch-dim buckets: {1, 4, max_slots} — a whole admission wave
-        prefills in ONE dispatch while the compiled-shape space stays 3 x
-        seq-buckets (pow-of-two padding would explode it) and single-request
-        admission pays no padding."""
-        return tuple(sorted({1, min(4, self.max_slots), self.max_slots}))
+        """Prefill batch-dim buckets: {1, 4, prefill_wave} (the wave is
+        max_slots unless capped) — a whole admission wave prefills in ONE
+        dispatch while the compiled-shape space stays 3 x seq-buckets
+        (pow-of-two padding would explode it) and single-request admission
+        pays no padding."""
+        return tuple(sorted({1, min(4, self.prefill_wave), self.prefill_wave}))
 
     def _wave_block_tables(self, slots: List[int], pad: int) -> np.ndarray:
         """Block-table rows for a prefill wave ([Bp, n_blocks]); the first
@@ -2802,7 +2859,7 @@ class GenerationEngine:
         bucket = pick_bucket(
             max(len(r.prompt_ids) for r in reqs), self.prefill_buckets, self.chunk_size
         )
-        Bp = pick_bucket(B, self._batch_buckets(), self.max_slots)
+        Bp = pick_bucket(B, self._batch_buckets(), self.prefill_wave)
         with self._ledger.span("prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp):
             pad = Bp - B
             ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
@@ -2870,7 +2927,7 @@ class GenerationEngine:
             self.prefill_buckets,
             self.chunk_size,
         )
-        Bp = pick_bucket(B, self._batch_buckets(), self.max_slots)
+        Bp = pick_bucket(B, self._batch_buckets(), self.prefill_wave)
         with self._ledger.span(
             "prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp, suffix=1
         ):
@@ -3117,7 +3174,7 @@ class GenerationEngine:
         live = [
             (i, self._slot_epoch[i]) for i, s in enumerate(self._slots) if s is not None
         ]
-        self._inflight.append(_TickRef(nxt=toks, slots=live))
+        self._inflight.append(_TickRef(nxt=toks, slots=live, aux=self._take_aux()))
         st.step += 1
         self._prefill_chunks_piggybacked += 1
         # the same mid-prefill reaping as _chunk_step (the decode side of the
@@ -3298,6 +3355,9 @@ class GenerationEngine:
         # KV memory plane gauges: pool occupancy, sharing fraction, allocator
         # eviction/COW counters (paged), or the pinned-prefix footprint (legacy)
         out["kv"] = self.kv_stats()
+        moe = self.moe_stats()
+        if moe is not None:
+            out["moe"] = moe
         out["reclaimed_slots"] = self.reclaimed_slots
         # device-slice identity + per-slice HBM ledger (docs/MULTICHIP.md)
         out["slice"] = self.slice_stats()
@@ -3356,6 +3416,28 @@ class GenerationEngine:
             "decode_kv_path": self.decode_kv_path,
         }
 
+    def moe_stats(self) -> Optional[dict]:
+        """Routed-expert counters of an expert-parallel rank (``tick_stats()
+        ["moe"]``, ``dabt_moe_*``), or None for a block without routed experts:
+        running totals, for decode steps and for prefill programs apart, of
+        routed picks and of those that landed on experts held here, tokens per
+        held expert, layer-steps run and distinct held experts hit in them."""
+        lm = getattr(self.cfg, "latent_moe", None)
+        if lm is None:
+            return None
+        tot = self._moe_totals
+        if tot is None:
+            tot = np.zeros((2, 4 + lm.experts_held), np.int64)
+        out = {"experts_held": lm.experts_held, "first_expert": lm.first_expert,
+               "router_experts": lm.router_experts, "ep_size": lm.ep_size, "ep_rank": lm.ep_rank}
+        for row, kind in enumerate(("decode", "prefill")):
+            out[kind] = {
+                "picks": int(tot[row, 0]), "picks_local": int(tot[row, 1]),
+                "layer_steps": int(tot[row, 2]), "experts_hit": int(tot[row, 3]),
+                "tokens_per_expert": [int(v) for v in tot[row, 4:]],
+            }
+        return out
+
     def slice_stats(self) -> dict:
         """Device-slice identity + HBM ledger for tick_stats / /healthz /
         /metrics (docs/MULTICHIP.md): which devices this replica's mesh
@@ -3413,6 +3495,10 @@ class GenerationEngine:
         # longer fall back: the tree verify commits through the block table.)
         out["kv_layout_requested"] = self.kv_layout_requested
         out["kv_layout_effective"] = out["kv_layout"]
+        # what a cached token is ("kv": keys and values per KV head; "latent":
+        # one latent row read as both) and what it takes over all layers
+        out["kv_cache_kind"] = self._model.KV_KIND
+        out["kv_bytes_per_token"] = int(self._model.kv_bytes_per_token(self.cfg, self.kv_cache_dtype))
         if self.paged:
             out.update(self._kv_pool.stats())
             if self._kv_host is not None:
@@ -3748,7 +3834,7 @@ class GenerationEngine:
         live = [
             (i, self._slot_epoch[i]) for i, s in enumerate(self._slots) if s is not None
         ]
-        self._inflight.append(_TickRef(nxt=toks, slots=live))
+        self._inflight.append(_TickRef(nxt=toks, slots=live, aux=self._take_aux()))
 
     def _issue_spec_tick(self, rung: tuple):
         """Dispatch one fused tree-speculative tick at the controller's
@@ -3792,6 +3878,9 @@ class GenerationEngine:
         blocked = led.seconds("tick_block")
         with led.span("tick_block"):
             vals = np.asarray(ref.nxt)
+            if ref.aux is not None:  # same program as `nxt`: already here
+                aux = np.asarray(ref.aux).astype(np.int64)
+                self._moe_totals = aux if self._moe_totals is None else self._moe_totals + aux
         with led.span("consume"):
             try:
                 self._process_tick_inner(ref, vals, led.seconds("tick_block") - blocked)

@@ -917,6 +917,20 @@ def render_prometheus(registry: Any) -> str:
                 x.add("dabt_engine_loop_spans_total", "counter", "engine-loop spans closed, by phase", tot["n"], plab)
             x.add("dabt_prefill_tokens_total", "counter", "prefill positions: prompt tokens run (real) vs rows x bucket of the programs (padded)", ls["prefill_tokens_real"], {**lab, "kind": "real"})
             x.add("dabt_prefill_tokens_total", "counter", "prefill positions: prompt tokens run (real) vs rows x bucket of the programs (padded)", ls["prefill_tokens_padded"], {**lab, "kind": "padded"})
+        moe_fn = getattr(eng, "moe_stats", None)
+        moe = moe_fn() if callable(moe_fn) else None
+        if moe:
+            # an expert-parallel rank's routed layers (models/mla_moe.py): where the
+            # picks went and how evenly the held experts were loaded
+            x.add("dabt_moe_experts_held", "gauge", "routed experts this rank holds", moe["experts_held"], lab)
+            for kind in ("decode", "prefill"):
+                klab = {**lab, "kind": kind}
+                x.add("dabt_moe_picks_total", "counter", "routed picks over all experts", moe[kind]["picks"], klab)
+                x.add("dabt_moe_picks_local_total", "counter", "routed picks that landed on experts held here", moe[kind]["picks_local"], klab)
+                x.add("dabt_moe_layer_steps_total", "counter", "expert layers run (one per layer per step or program)", moe[kind]["layer_steps"], klab)
+                x.add("dabt_moe_experts_hit_total", "counter", "distinct held experts hit, summed over layer-steps", moe[kind]["experts_hit"], klab)
+                for e, n in enumerate(moe[kind]["tokens_per_expert"]):
+                    x.add("dabt_moe_expert_tokens_total", "counter", "tokens routed to a held expert", n, {**klab, "expert": str(moe["first_expert"] + e)})
         dec_fn = getattr(eng, "decode_path_stats", None)
         if callable(dec_fn):
             # decode fast-path gauges (docs/QUANT.md): configured vs

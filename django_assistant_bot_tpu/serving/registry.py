@@ -12,7 +12,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time
-from typing import Any, Dict, Mapping, Optional
+from typing import Any, Dict, List, Mapping, Optional
 
 import jax
 
@@ -56,6 +56,14 @@ class ModelSpec:
     # (prefill_displacement_frac in tick_stats).  Token-identical to the
     # sequential path; False is the one-flag rollback (sequential chunking).
     prefill_piggyback: bool = True
+    # prefill program shapes (serving/engine.py warm-up compiles seq buckets x
+    # {1, 4, wave}): the sequence buckets (None = the engine's powers of two
+    # up to chunk_size) and the most rows one prefill dispatch admits (0 = all
+    # slots).  A deployment whose prompts span two buckets and arrive one at a
+    # time names them and boots in a fraction of the compile time; a 32-slot
+    # engine's full wave at bucket 1024 is also its largest program by far.
+    prefill_buckets: Optional[List[int]] = None
+    prefill_wave: int = 0
     # fp8 in-dot decode attention: keep the fp8 KV read operand in fp8
     # through the QK/PV dots (per-block scales applied to the f32 partials,
     # mirroring the int4 in-dot discipline) instead of dequantizing to bf16
@@ -107,6 +115,11 @@ class ModelSpec:
     # whole max_seq_len row.  "legacy": the contiguous slot cache — the
     # one-flag rollback and the bench A/B arm.
     kv_layout: str = "paged"
+    # the block the checkpoint must be (``DecoderConfig.arch``: "llama",
+    # "mla_moe"); None = whatever the checkpoint says.  A deployment that
+    # states it is refused at load, before any program is built, when the
+    # checkpoint holds another block.
+    arch: Optional[str] = None
     # page size in tokens; 0 = align with decode_kv_chunk (or its auto pick)
     kv_page_size: int = 0
     # pool size in pages; 0 = byte parity with the legacy layout
@@ -298,7 +311,7 @@ class ModelRegistry:
     def load(self, spec: ModelSpec):
         import jax.numpy as jnp
 
-        from ..models import DecoderConfig, EncoderConfig, encoder, llama
+        from ..models import DecoderConfig, EncoderConfig, encoder, llama, module_for
         from ..models.hf_loader import load_decoder, load_encoder
         from ..parallel import shard_pytree
         from .engine import EmbeddingEngine, GenerationEngine
@@ -420,12 +433,33 @@ class ModelRegistry:
         warmup_s = 0.0
 
         if spec.checkpoint:
-            from ..checkpoint import load_model
+            from ..checkpoint import load_model, read_manifest
 
+            if spec.kind == "decoder":
+                # what the checkpoint's block does not implement is refused from
+                # its manifest alone, before gigabytes of weights are read
+                ck_cfg = read_manifest(spec.checkpoint)["meta"].get("config", {})
+                if ck_cfg.get("latent_moe"):
+                    from ..models import mla_moe
+
+                    try:
+                        mla_moe.check_serving(
+                            kv_layout=spec.kv_layout, speculative=spec.speculative,
+                            prefix_cache=spec.prefix_cache, kv_cache_dtype=spec.kv_cache_dtype,
+                            attn_fp8=spec.attn_fp8, quantize=spec.quantize,
+                            kv_host_tier=bool(spec.kv_host_bytes or spec.kv_spill_dir),
+                        )
+                    except ValueError as e:
+                        raise ValueError(f"model {name}: {e}") from None
             kind, _cfg, _params, _meta = load_model(spec.checkpoint, dtype=dtype)
             if kind != spec.kind:
                 raise ValueError(
                     f"model {name}: checkpoint is a {kind}, spec says {spec.kind}"
+                )
+            if spec.arch and getattr(_cfg, "arch", None) != spec.arch:
+                raise ValueError(
+                    f"model {name}: checkpoint holds a {getattr(_cfg, 'arch', kind)!r} block, "
+                    f"spec says arch={spec.arch!r}"
                 )
             tokenizer_path = tokenizer_path or _meta.get("tokenizer")
         tokenizer = load_tokenizer(tokenizer_path)
@@ -564,12 +598,12 @@ class ModelRegistry:
                         f"({planner.n_slices} slice(s) of "
                         f"{spec.replica_devices} device(s))"
                     )
-                logical_tree = llama.logical_axes(cfg)
+                logical_tree = module_for(cfg).logical_axes(cfg)
                 host_params = params
             else:
                 with self.mesh:
                     params = shard_pytree(
-                        params, llama.logical_axes(cfg), self.mesh
+                        params, module_for(cfg).logical_axes(cfg), self.mesh
                     )
                 jax.block_until_ready(params)
             from .faults import FaultInjector
@@ -700,6 +734,12 @@ class ModelRegistry:
                         else int(spec.decode_kv_chunk)
                     ),
                     prefill_piggyback=spec.prefill_piggyback,
+                    prefill_wave=spec.prefill_wave,
+                    **(
+                        {"prefill_buckets": tuple(int(b) for b in spec.prefill_buckets)}
+                        if spec.prefill_buckets
+                        else {}
+                    ),
                     attn_fp8=spec.attn_fp8,
                     kv_layout=spec.kv_layout,
                     kv_page_size=spec.kv_page_size,

@@ -406,3 +406,97 @@ def test_knn_topk_compiles_at_corpus_scale(topo, rows):
         _sds((rows,), jnp.bool_, one),
         16,
     ).compile()
+
+
+# ---------------------------------------------------------------------------
+# the latent-attention MoE block at the benchmark configuration's sizes
+# (benchmarks/configs/a.x-k1-ep16.json: rank 0 of a 16-way expert-parallel
+# deployment, one dense + six expert layers, 32 slots x 2048, pages of 512)
+# ---------------------------------------------------------------------------
+
+LM_SLOTS, LM_PAGES = 32, 128
+
+
+def _latent_moe_cfg():
+    import json
+
+    from django_assistant_bot_tpu.models import mla_moe  # noqa: F401
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "a.x-k1-ep16.json")
+    with open(path) as f:
+        return DecoderConfig.from_hf(json.load(f)["hf"], dtype=jnp.bfloat16)
+
+
+def _latent_moe_args(cfg, one):
+    from django_assistant_bot_tpu.models import mla_moe
+
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one),
+                          jax.eval_shape(lambda: mla_moe.init(cfg, jax.random.key(0))))
+    cache = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one),
+                         jax.eval_shape(lambda: mla_moe.init_paged_cache(cfg, LM_SLOTS, LM_PAGES, PAGE)))
+    return params, cache
+
+
+def test_latent_moe_fused_tick_touches_the_latent_pool_only_where_it_must(topo, monkeypatch):
+    """PR 25's criterion for the pool of one array: the compiled tick reaches
+    the Pallas call, the donated pool is aliased through, and nothing inside
+    the step loop or the layer loops makes a value the size of a layer of it."""
+    from django_assistant_bot_tpu.models import mla_moe
+    from django_assistant_bot_tpu.ops.sampling import sample_logits
+
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    cfg = _latent_moe_cfg()
+    one = SingleDeviceSharding(topo.devices[0])
+    params, cache = _latent_moe_args(cfg, one)
+
+    def tick(params, tokens, cache, active, bt, temps, top_ps, rng):
+        def body(carry, _):
+            tokens, cache, rng = carry
+            p = jax.lax.optimization_barrier(params)
+            rng, sub = jax.random.split(rng)
+            logits, cache = mla_moe.decode_step_paged(p, cfg, tokens, cache, bt, active=active)
+            nxt = sample_logits(logits, sub, temperature=temps, top_k=0, top_p=top_ps)
+            return (nxt, cache, rng), nxt
+
+        (tokens, cache, rng), toks = jax.lax.scan(body, (tokens, cache, rng), None, length=8)
+        return toks, tokens, cache, rng
+
+    args = (
+        params, _sds((LM_SLOTS,), jnp.int32, one), cache, _sds((LM_SLOTS,), jnp.bool_, one),
+        _sds((LM_SLOTS, MAX_SEQ // PAGE), jnp.int32, one), _sds((LM_SLOTS,), jnp.float32, one),
+        _sds((LM_SLOTS,), jnp.float32, one), _sds((2,), jnp.uint32, one),
+    )
+    compiled = jax.jit(tick, donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    W = cfg.latent_moe.latent_width
+    pool_bytes = 2 * cfg.num_layers * LM_PAGES * PAGE * W
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # weights 9.7 GB + pool 0.59 GB resident; no second pool among the temporaries
+    assert 9.0e9 < mem.argument_size_in_bytes < 11.5e9
+    assert mem.temp_size_in_bytes < 0.5e9
+    layer = f"{LM_PAGES},{PAGE},{W}"
+    assert _pool_sized_values_made_in_loops(text, [layer, f"{cfg.num_layers},{layer}"]) == []
+
+
+@pytest.mark.parametrize("bucket", [256, 1024])
+def test_latent_moe_prefill_takes_the_flash_kernel_and_fits(topo, monkeypatch, bucket):
+    """One admission's prefill at the cell's buckets: the flash kernel at key
+    width 192 (padded to 256) against value width 128, the grouped matmul over
+    the held experts, and temporaries that fit beside 10.3 GB of weights and pool."""
+    from django_assistant_bot_tpu.models import mla_moe
+
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    cfg = _latent_moe_cfg()
+    one = SingleDeviceSharding(topo.devices[0])
+    params, _ = _latent_moe_args(cfg, one)
+    compiled = (
+        jax.jit(lambda p, i, n: mla_moe.prefill(p, cfg, i, n))
+        .lower(params, _sds((1, bucket), jnp.int32, one), _sds((1,), jnp.int32, one))
+        .compile()
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes < 14.5e9
