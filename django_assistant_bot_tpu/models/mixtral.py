@@ -21,6 +21,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..ops import moe as moe_ops
 from ..ops.quant import deq
 
 from ..parallel.sharding import with_constraint
@@ -81,6 +82,8 @@ def moe_mlp(cfg: DecoderConfig, p, x: jnp.ndarray) -> jnp.ndarray:
 # rows of one grouped-matmul tile, and the largest token count the dense pass takes
 GROUP_TILE = 128
 DENSE_MAX_TOKENS = 64
+# the held experts' three matrices: one layer's ``[held, ...]`` or the whole stack ``[layers, held, ...]``
+HELD_KEYS = ("w_gate", "w_up", "w_down")
 # counters a routed layer returns, per call: [picks, picks on held experts,
 # layer-steps with a token, held experts hit] then tokens per held expert
 MOE_STAT_HEAD = 4
@@ -122,33 +125,56 @@ def _swiglu_tile(x, wg, wu, wd, w_row, dtype):
                       preferred_element_type=jnp.float32)
 
 
-def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray):
+def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray, layer=None):
     """The routed part of an expert layer, as THIS rank computes it:
     ``sum over picked experts held here of g_e * expert_e(x)`` -> (y [B, S, E],
     stats int32 [MOE_STAT_HEAD + experts_held]).
 
+    ``p["w_gate"]``, ``p["w_up"]``, ``p["w_down"]`` are one layer's held experts
+    ``[held, ...]`` or, with ``layer`` (a traced index), the WHOLE stack
+    ``[expert layers, held, ...]``: the layer scans close over the stack and
+    pass the index, so that the experts can be read where they lie.
+
     Routes over all ``router_experts``; picks that land on another rank's
     experts add nothing here (their ranks add them; nothing stands in for the
-    exchange).  **No capacity and no dropped token**, whatever the router does:
+    exchange).  **No capacity and no dropped token**, whatever the router does.
+    Two call shapes, by the static row count:
 
-    - up to ``DENSE_MAX_TOKENS`` tokens (a decode step): every held expert runs
-      over every token and a ``[T, held]`` combine mask keeps the picks.  Exact,
-      memory-bound (every held expert's weights are read once a step), and the
-      work does not depend on the routing.
-    - more (prefill): picks are sorted by expert and the held experts run as a
-      grouped matmul over row tiles of ``GROUP_TILE``: a loop whose trip count is
-      the number of LIVE tiles (``sum ceil(count_e / tile)``), so work follows
-      the picks that landed here, and an expert that draws every token simply
-      takes more tiles.
+    - up to ``DENSE_MAX_TOKENS`` tokens (a decode step): an expert runs over
+      every row with its column of a ``[T, held]`` combine matrix as row
+      weights (zero for rows that did not pick it): no sort, no gather.
+    - more (prefill): picks are sorted by expert and run as row tiles of
+      ``GROUP_TILE``, ``sum ceil(count_e / tile)`` of them, so work follows the
+      picks that landed here, and an expert that draws every token simply takes
+      more tiles.
+
+    Two implementations of both, by platform and shape
+    (:func:`~..ops.moe.held_experts_path`).  ``kernel`` (a TPU, lane-wide
+    widths): one Pallas call a layer (:func:`~..ops.moe.grouped_swiglu`) over
+    a work list of the experts HIT (decode) or of the live tiles (prefill),
+    each listed expert's matrices DMA'd from the stack by ``(layer, expert)``:
+    a step reads only the experts a token landed on, and no layer's experts
+    are copied out of the stack.  ``xla`` (the CPU, toy widths; the kernel's
+    test oracle): the layer's experts sliced from the stack, then a dense
+    masked pass that reads every held expert once a step (decode) or a loop
+    over the live tiles (prefill).  Same mathematics on both: bfloat16
+    operands, float32 accumulation, the row weight applied in float32 before
+    the down-projection.
 
     ``valid`` [B, S] marks real tokens (pad positions and frozen slots route
     too, their rows are discarded by the caller; they are kept out of the
-    counters and, in the grouped path, out of the work)."""
+    counters and out of the work lists)."""
     lm = cfg.latent_moe
     B, S, E = x.shape
     T, K, Xh = B * S, cfg.experts_per_token, lm.experts_held
     xt = x.reshape(T, E)
     ok = valid.reshape(T)
+    stack = tuple(p[k] for k in HELD_KEYS)
+    kernel = moe_ops.held_experts_path(E, stack[0].shape[-1]) == "kernel"
+    if kernel and layer is None:
+        stack, layer = tuple(w[None] for w in stack), jnp.zeros((), jnp.int32)
+    elif not kernel and layer is not None:
+        stack = tuple(jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False) for w in stack)
     idx, w = route_sigmoid_groups(lm, K, xt, p["router"])
     with jax.named_scope("moe/dispatch"):
         local = idx - lm.first_expert  # [T, K]
@@ -156,52 +182,75 @@ def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray):
         local = jnp.where(here, local, Xh)  # Xh: not held here
         onehot = jax.nn.one_hot(local, Xh + 1, dtype=jnp.float32)[..., :Xh]  # [T, K, Xh]
         per_expert = onehot.sum((0, 1)).astype(jnp.int32)  # tokens per held expert
+        hit = per_expert > 0
         stats = jnp.concatenate([
-            jnp.stack([ok.sum() * K, here.sum(), ok.any().astype(jnp.int32), (per_expert > 0).sum()]).astype(jnp.int32),
+            jnp.stack([ok.sum() * K, here.sum(), ok.any().astype(jnp.int32), hit.sum()]).astype(jnp.int32),
             per_expert,
         ])
     if T <= DENSE_MAX_TOKENS:
         with jax.named_scope("moe/dispatch"):
             combine = jnp.einsum("tkx,tk->tx", onehot, w)  # [T, Xh] f32
+            if kernel:
+                # the work list: the experts hit, in order, each over all T rows (padded to whole sublane
+                # tiles).  Compacted by comparison, not by nonzero + gather: a few small fused operations
+                # in place of a dozen, six times a step
+                place = (jnp.cumsum(hit) - 1 == jnp.arange(Xh)[:, None]) & hit  # [item, expert]
+                experts = (place * jnp.arange(Xh)).sum(1)
+                pad = -T % 16
+                rows = jnp.pad(xt, ((0, pad), (0, 0)))
+                w_rows = jnp.pad(jnp.where(place[:, :, None], combine.T[None], 0.0).sum(1), ((0, 0), (0, pad)))
         with jax.named_scope("moe/experts"):
-            h = jax.nn.silu(jnp.einsum("te,xef->txf", xt, p["w_gate"].astype(cfg.dtype))) * jnp.einsum(
-                "te,xef->txf", xt, p["w_up"].astype(cfg.dtype))
-            h = (h.astype(jnp.float32) * combine[:, :, None]).astype(cfg.dtype)
-            y = jnp.einsum("txf,xfe->te", h, p["w_down"].astype(cfg.dtype), preferred_element_type=jnp.float32)
+            if kernel:
+                y = moe_ops.grouped_swiglu(rows, w_rows, *stack, layer, experts, hit.sum()[None], shared_rows=True)[:T]
+            else:
+                wg, wu, wd = (m.astype(cfg.dtype) for m in stack)
+                h = jax.nn.silu(jnp.einsum("te,xef->txf", xt, wg)) * jnp.einsum("te,xef->txf", xt, wu)
+                h = (h.astype(jnp.float32) * combine[:, :, None]).astype(cfg.dtype)
+                y = jnp.einsum("txf,xfe->te", h, wd, preferred_element_type=jnp.float32)
         return y.astype(cfg.dtype).reshape(B, S, E), stats
 
     tm = GROUP_TILE
     with jax.named_scope("moe/dispatch"):
         N = T * K
-        flat_e = local.reshape(N)
-        order = jnp.argsort(flat_e, stable=True)  # held experts first, by expert; the rest last
+        order = jnp.argsort(local.reshape(N), stable=True)  # held experts first, by expert; the rest last
         sorted_tok = (order // K).astype(jnp.int32)
         sorted_w = w.reshape(N)[order]
         offs = jnp.cumsum(per_expert) - per_expert  # first sorted position of each expert
         tiles = -(-per_expert // tm)
         tile_end = jnp.cumsum(tiles)
         n_tiles = tile_end[-1]
+        # the work list, one item a tile, at its static maximum: a token picks an expert
+        # once, so an expert has at most T rows and the held experts T * min(K, Xh)
+        max_tiles = min(T * min(K, Xh) // tm + Xh, Xh * -(-T // tm))
+        t = jnp.arange(max_tiles, dtype=jnp.int32)
+        e = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"), Xh - 1).astype(jnp.int32)
+        pos = (offs[e] + (t - (tile_end[e] - tiles[e])) * tm)[:, None] + jnp.arange(tm, dtype=jnp.int32)[None, :]
+        live = (pos < (offs[e] + per_expert[e])[:, None]) & (t < n_tiles)[:, None]
+        pos = jnp.minimum(pos, N - 1)
+        tok = sorted_tok[pos]  # [max_tiles, tm]: a real token in every row, dead rows weigh nothing
+        w_rows = jnp.where(live, sorted_w[pos], 0.0)
 
-    def tile_body(t, acc):
-        with jax.named_scope("moe/dispatch"):
-            e = jnp.searchsorted(tile_end, t, side="right").astype(jnp.int32)
-            j = t - (tile_end[e] - tiles[e])
-            pos = offs[e] + j * tm + jnp.arange(tm, dtype=jnp.int32)
-            live = pos < offs[e] + per_expert[e]
-            pos = jnp.minimum(pos, N - 1)
-            tok = sorted_tok[pos]
-            w_row = jnp.where(live, sorted_w[pos], 0.0)
-            x_tile = xt[tok]
+    if kernel:
+        with jax.named_scope("moe/dispatch"):  # only the live tiles' rows are gathered; the rest is never read
+            rows = jax.lax.fori_loop(
+                0, n_tiles, lambda i, buf: jax.lax.dynamic_update_slice_in_dim(buf, xt[tok[i]], i * tm, 0),
+                jax.lax.empty((max_tiles * tm, E), xt.dtype))
         with jax.named_scope("moe/experts"):
-            y_tile = _swiglu_tile(
-                x_tile,
-                jax.lax.dynamic_index_in_dim(p["w_gate"], e, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(p["w_up"], e, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(p["w_down"], e, 0, keepdims=False),
-                w_row, cfg.dtype,
-            )
-        with jax.named_scope("moe/combine"):
-            return acc.at[tok].add(y_tile)  # dead rows add zeros
+            tiles_y = moe_ops.grouped_swiglu(rows, w_rows, *stack, layer, e, n_tiles[None], shared_rows=False)
+
+        def tile_body(i, acc):
+            with jax.named_scope("moe/combine"):  # dead rows add zeros
+                return acc.at[tok[i]].add(jax.lax.dynamic_slice_in_dim(tiles_y, i * tm, tm, 0))
+    else:
+        def tile_body(i, acc):
+            with jax.named_scope("moe/dispatch"):
+                x_tile = xt[tok[i]]
+            with jax.named_scope("moe/experts"):
+                y_tile = _swiglu_tile(
+                    x_tile, *(jax.lax.dynamic_index_in_dim(m, e[i], 0, keepdims=False) for m in stack),
+                    w_rows[i], cfg.dtype)
+            with jax.named_scope("moe/combine"):
+                return acc.at[tok[i]].add(y_tile)  # dead rows add zeros
 
     y = jax.lax.fori_loop(0, n_tiles, tile_body, jnp.zeros((T, E), jnp.float32))
     return y.astype(cfg.dtype).reshape(B, S, E), stats

@@ -21,8 +21,12 @@ The block, per layer (``n`` RMSNorm; ``cfg.latent_moe`` has the sizes):
   left out, and that partial result goes on to the next layer.
 
 Two stacks of weights, ``dense_layers`` and ``moe_layers``, each scanned over
-its own leading axis.  The same entry points as :mod:`.llama`'s paged path
-(``serving/engine.py`` picks the module once, by ``cfg.arch``):
+its own leading axis, but for the held experts' three matrices: the scan
+bodies close over those stacks whole and index them by ``(layer, expert)``
+(:func:`_scan_stacks`), so that a step reads only the experts a token landed
+on and no program copies a layer's experts.  The same entry points as
+:mod:`.llama`'s paged path (``serving/engine.py`` picks the module once, by
+``cfg.arch``):
 ``prefill``, ``insert_sequences_paged``, ``copy_pages``,
 ``prefill_chunk_paged``, ``prefill_suffix_paged``, ``decode_step_paged``,
 ``init_paged_cache``, ``paged_cache_shardings``.  The contiguous cache,
@@ -48,12 +52,13 @@ from ..ops.attention import (
     latent_decode_update_attend,
     paged_decode_plan,
 )
+from ..ops.moe import held_experts_path
 from ..ops.norms import rms_norm
 from ..ops.rope import apply_rope, rope_frequencies
 from ..parallel.sharding import with_constraint
 from .config import DecoderConfig
 from .llama import _embed, _head_logits
-from .mixtral import MOE_STAT_HEAD, held_experts_mlp, shared_experts_mlp
+from .mixtral import HELD_KEYS, MOE_STAT_HEAD, held_experts_mlp, shared_experts_mlp
 
 Params = Dict[str, Any]
 
@@ -113,6 +118,11 @@ def kv_bytes_per_token(cfg: DecoderConfig, kv_dtype=None) -> int:
 
 def decode_kv_path(cfg: DecoderConfig, kv_dtype, page: int, *, fp8_dot: bool = False) -> str:
     return latent_decode_kv_path(kv_dtype or cfg.dtype, page, cfg.latent_moe.latent_width)
+
+
+def moe_experts_path(cfg: DecoderConfig) -> str:
+    """``"kernel"`` or ``"xla"``: how the held experts run (:func:`~..ops.moe.held_experts_path`)."""
+    return held_experts_path(cfg.hidden_size, cfg.latent_moe.moe_intermediate_size)
 
 
 def init_paged_cache(cfg: DecoderConfig, batch: int, n_pages: int, page_size: int, dtype=None) -> LatentKVCache:
@@ -279,12 +289,14 @@ def _dense_mlp(cfg: DecoderConfig, p: Params, x: jnp.ndarray) -> jnp.ndarray:
         return _mm("bsf,fe->bse", h, p["w_down"], cfg.dtype)
 
 
-def _ffn(cfg: DecoderConfig, p: Params, x: jnp.ndarray, valid, is_moe: bool):
-    """-> (y, routed-layer counters or zeros)."""
+def _ffn(cfg: DecoderConfig, p: Params, x: jnp.ndarray, valid, held: Optional[Params], layer):
+    """-> (y, routed-layer counters or zeros).  ``held``: None in the dense
+    stack, else the expert stack's held experts, whole, with ``layer`` the
+    model's layer index."""
     h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-    if not is_moe:
+    if held is None:
         return _dense_mlp(cfg, p, h), jnp.zeros((MOE_STAT_HEAD + cfg.latent_moe.experts_held,), jnp.int32)
-    y, stats = held_experts_mlp(cfg, p, h, valid)
+    y, stats = held_experts_mlp(cfg, dict(p, **held), h, valid, layer - cfg.latent_moe.first_dense_layers)
     if cfg.latent_moe.n_shared_experts:
         y = y + shared_experts_mlp(cfg, p, h)
     return y, stats
@@ -292,12 +304,24 @@ def _ffn(cfg: DecoderConfig, p: Params, x: jnp.ndarray, valid, is_moe: bool):
 
 def _scan_stacks(cfg: DecoderConfig, params: Params, make_body, carry):
     """``lax.scan`` over the dense stack, then the expert stack; ``make_body
-    (is_moe)`` returns a scan body over ``(layer params, layer index)``.  Two
-    compiled bodies whatever the depth; per-layer outputs concatenate on the
-    layer axis."""
+    (held)`` returns a scan body over ``(layer params, layer index)`` that
+    hands ``held`` and the index to :func:`_ffn`.  Two compiled bodies whatever
+    the depth; per-layer outputs concatenate on the layer axis.
+
+    The held experts do NOT ride the expert scan's ``xs``: a scan's slice of
+    them is a value the size of a layer's experts (1.06 GB at A.X-K1's widths)
+    that XLA either reads whole (the decode step's einsums) or first copies out
+    so that a loop can index it (prefill).  The body closes over the whole
+    stack and the layer index comes from ``xs``, as the latent pool rides the
+    carry, so the experts are read in place, by ``(layer, expert)``
+    (:func:`.mixtral.held_experts_mlp`).  Router, norms, shared expert and
+    attention weights stay in ``xs``."""
     nd, nm = _stack_sizes(cfg)
-    carry, y_d = jax.lax.scan(make_body(False), carry, (params["dense_layers"], jnp.arange(nd)))
-    carry, y_m = jax.lax.scan(make_body(True), carry, (params["moe_layers"], jnp.arange(nd, nd + nm)))
+    moe = params["moe_layers"]
+    held = {k: moe[k] for k in HELD_KEYS}
+    sliced = {k: v for k, v in moe.items() if k not in HELD_KEYS}
+    carry, y_d = jax.lax.scan(make_body(None), carry, (params["dense_layers"], jnp.arange(nd)))
+    carry, y_m = jax.lax.scan(make_body(held), carry, (sliced, jnp.arange(nd, nd + nm)))
     return carry, jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), y_d, y_m)
 
 
@@ -319,14 +343,14 @@ def prefill(params: Params, cfg: DecoderConfig, input_ids: jnp.ndarray, lengths:
     valid = jnp.arange(S)[None, :] < lengths[:, None]
     x = _embed(params, cfg, input_ids)
 
-    def make_body(is_moe):
+    def make_body(held):
         def body(x, inputs):
-            p, _ = inputs
+            p, layer = inputs
             h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
             q_nope, q_rope, row = _queries_and_row(cfg, p, h, cos, sin)
             # right-padded input: causal masking alone keeps real queries on real keys
             x = x + _attn_out(cfg, p, _expanded_attention(cfg, p, q_nope, q_rope, row, causal=True))
-            y, stats = _ffn(cfg, p, x, valid, is_moe)
+            y, stats = _ffn(cfg, p, x, valid, held, layer)
             return with_constraint(x + y, ("batch", "length", "embed")), (row, stats)
 
         return body
@@ -400,7 +424,7 @@ def _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, 
     mask = (jnp.arange(S)[None, None, None, :] <= pos[:, None, :, None])  # [B,1,C,S]
     x = _embed(params, cfg, input_ids)
 
-    def make_body(is_moe):
+    def make_body(held):
         def body(carry, inputs):
             x, pool = carry
             p, layer = inputs
@@ -411,7 +435,7 @@ def _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, 
             with jax.named_scope("attn/kv_read"):
                 rows = _gather_rows(pool, layer, block_tables)
             x = x + _attn_out(cfg, p, _expanded_attention(cfg, p, q_nope, q_rope, rows, mask=mask))
-            y, stats = _ffn(cfg, p, x, real, is_moe)
+            y, stats = _ffn(cfg, p, x, real, held, layer)
             return (x + y, pool), stats
 
         return body
@@ -495,7 +519,7 @@ def decode_step_paged(
     x = _embed(params, cfg, tokens)[:, None, :]
     valid = active[:, None]
 
-    def make_body(is_moe):
+    def make_body(held):
         def body(carry, inputs):
             x, pool = carry
             p, layer = inputs
@@ -521,7 +545,7 @@ def decode_step_paged(
             with jax.named_scope("attn/absorb"):
                 o = jnp.einsum("bhc,chd->bhd", o_lat, p["w_uv"].astype(cfg.dtype).reshape(C, H, dv))
             x = x + _attn_out(cfg, p, o.reshape(B, 1, H * dv))
-            y, stats = _ffn(cfg, p, x, valid, is_moe)
+            y, stats = _ffn(cfg, p, x, valid, held, layer)
             return (x + y, pool), stats
 
         return body

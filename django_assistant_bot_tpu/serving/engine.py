@@ -724,6 +724,14 @@ class GenerationEngine:
             self.decode_kv_path, "paged" if self.paged else "legacy",
             jax.default_backend(),
         )
+        # ... and the held experts of an expert-parallel rank (ops/moe.py):
+        # "kernel" reads only the experts a token landed on, where they lie;
+        # None for a block without them
+        path_fn = getattr(self._model, "moe_experts_path", None)
+        with mesh_scope(mesh):
+            self.moe_experts_path = path_fn(cfg) if path_fn else None
+        if path_fn:
+            logger.info("held experts path: %s (platform=%s)", self.moe_experts_path, jax.default_backend())
         # Admission-controlled scheduling (serving/scheduler.py): when present,
         # submit() runs its admission test (bounded queue, estimated wait) and
         # _admit pulls requests in weighted-fair-share order instead of FIFO.
@@ -3414,6 +3422,8 @@ class GenerationEngine:
             # "kernel": the decode step writes its K/V row in place and reads
             # only the pages the block tables name; "xla": scatter + gather
             "decode_kv_path": self.decode_kv_path,
+            # the same for an expert-parallel rank's held experts (None: none here)
+            "moe_experts_path": self.moe_experts_path,
         }
 
     def moe_stats(self) -> Optional[dict]:
@@ -3421,7 +3431,9 @@ class GenerationEngine:
         ["moe"]``, ``dabt_moe_*``), or None for a block without routed experts:
         running totals, for decode steps and for prefill programs apart, of
         routed picks and of those that landed on experts held here, tokens per
-        held expert, layer-steps run and distinct held experts hit in them."""
+        held expert, layer-steps run and distinct held experts hit in them;
+        ``experts_skipped_share``: 1 - hit / (held x layer-steps), the share of
+        held experts no token landed on, which the ``kernel`` path never reads."""
         lm = getattr(self.cfg, "latent_moe", None)
         if lm is None:
             return None
@@ -3435,6 +3447,7 @@ class GenerationEngine:
                 "picks": int(tot[row, 0]), "picks_local": int(tot[row, 1]),
                 "layer_steps": int(tot[row, 2]), "experts_hit": int(tot[row, 3]),
                 "tokens_per_expert": [int(v) for v in tot[row, 4:]],
+                "experts_skipped_share": round(1.0 - float(tot[row, 3]) / max(1, lm.experts_held * int(tot[row, 2])), 4),
             }
         return out
 

@@ -929,6 +929,7 @@ def render_prometheus(registry: Any) -> str:
                 x.add("dabt_moe_picks_local_total", "counter", "routed picks that landed on experts held here", moe[kind]["picks_local"], klab)
                 x.add("dabt_moe_layer_steps_total", "counter", "expert layers run (one per layer per step or program)", moe[kind]["layer_steps"], klab)
                 x.add("dabt_moe_experts_hit_total", "counter", "distinct held experts hit, summed over layer-steps", moe[kind]["experts_hit"], klab)
+                x.add("dabt_moe_experts_skipped_share", "gauge", "held experts no token landed on, of held x layer-steps (the kernel path does not read them)", moe[kind]["experts_skipped_share"], klab)
                 for e, n in enumerate(moe[kind]["tokens_per_expert"]):
                     x.add("dabt_moe_expert_tokens_total", "counter", "tokens routed to a held expert", n, {**klab, "expert": str(moe["first_expert"] + e)})
         dec_fn = getattr(eng, "decode_path_stats", None)
@@ -951,6 +952,8 @@ def render_prometheus(registry: Any) -> str:
             x.add("dabt_prefill_piggyback", "gauge", "piggybacked-prefill program compiled for this engine", dec.get("prefill_piggyback"), lab)
             x.add("dabt_attn_fp8", "gauge", "fp8 in-dot decode attention engaged", dec.get("attn_fp8"), lab)
             x.add("dabt_decode_kv_kernel", "gauge", "decode K/V write+read is the Pallas paged kernel (1) or the plain XLA path (0)", dec.get("decode_kv_path") == "kernel", lab)
+            if dec.get("moe_experts_path"):
+                x.add("dabt_moe_experts_kernel", "gauge", "held experts run as the Pallas grouped kernel over the experts hit (1) or the plain XLA pass (0)", dec["moe_experts_path"] == "kernel", lab)
         sl_fn = getattr(eng, "slice_stats", None)
         if callable(sl_fn):
             # mesh-sliced fleet (docs/MULTICHIP.md): which devices this

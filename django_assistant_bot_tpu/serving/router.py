@@ -1423,6 +1423,7 @@ class EngineRouter:
             ),
             "attn_fp8": per[0].get("attn_fp8", False),
             "decode_kv_path": per[0].get("decode_kv_path", "xla"),
+            "moe_experts_path": per[0].get("moe_experts_path"),
             "replicas": per,
         }
 

@@ -149,6 +149,27 @@ def test_engine_streams_the_references_greedy_tokens_and_counts_the_picks(served
         reg.stop()
 
 
+def test_engine_names_the_held_experts_path_and_the_share_of_experts_skipped(served):
+    """PR 30: the label beside ``decode_kv_path`` and the counter that says how often skipping engages."""
+    from django_assistant_bot_tpu.serving.obs import render_prometheus
+
+    conf, family, cfg, params, path = served
+    reg = ModelRegistry.from_config(_spec(path))
+    try:
+        eng = reg.get_generator("m")
+        assert eng.moe_experts_path == "xla" == eng.tick_stats()["moe_experts_path"]  # the CPU; "kernel" on a TPU
+        eng.submit([40 + i for i in range(9)], max_tokens=6, temperature=0.0).result(timeout=300)
+        moe = eng.tick_stats()["moe"]
+        for kind in ("decode", "prefill"):
+            m = moe[kind]
+            assert m["layer_steps"] > 0 and 0.0 <= m["experts_skipped_share"] < 1.0
+            assert m["experts_skipped_share"] == round(1 - m["experts_hit"] / (16 * m["layer_steps"]), 4)
+        text = render_prometheus(reg)
+        assert 'dabt_moe_experts_skipped_share{' in text and 'dabt_moe_experts_kernel{' in text
+    finally:
+        reg.stop()
+
+
 @pytest.mark.parametrize("over,why", [
     ({"speculative": 4}, "tree verification"),
     ({"kv_layout": "legacy"}, "contiguous"),

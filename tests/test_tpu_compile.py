@@ -415,6 +415,7 @@ def test_knn_topk_compiles_at_corpus_scale(topo, rows):
 # ---------------------------------------------------------------------------
 
 LM_SLOTS, LM_PAGES = 32, 128
+LM_LAYER_EXPERTS = ["12,7168,2048", "12,2048,7168"]  # one layer's held experts: gate / up, down
 
 
 def _latent_moe_cfg():
@@ -479,6 +480,10 @@ def test_latent_moe_fused_tick_touches_the_latent_pool_only_where_it_must(topo, 
     assert mem.temp_size_in_bytes < 0.5e9
     layer = f"{LM_PAGES},{PAGE},{W}"
     assert _pool_sized_values_made_in_loops(text, [layer, f"{cfg.num_layers},{layer}"]) == []
+    # the held experts are read where they lie, by (layer, expert), by the step's own Pallas
+    # call: no loop makes a value the size of a layer's experts (1.06 GB for the three)
+    assert "held_experts" in text
+    assert _pool_sized_values_made_in_loops(text, LM_LAYER_EXPERTS) == []
 
 
 @pytest.mark.parametrize("bucket", [256, 1024])
@@ -497,6 +502,36 @@ def test_latent_moe_prefill_takes_the_flash_kernel_and_fits(topo, monkeypatch, b
         .lower(params, _sds((1, bucket), jnp.int32, one), _sds((1,), jnp.int32, one))
         .compile()
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "flash_attention" in text and "held_experts" in text
+    # no copy of a layer's 12 held experts out of the stack for a tile loop to index
+    # (a scan's slice of them was one: 10% of the cell's device time, PERF.md section 6, PR 30)
+    assert _pool_sized_values_made_in_loops(text, LM_LAYER_EXPERTS) == []
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes < 14.5e9
+
+
+def test_latent_moe_chunk_prefill_reads_the_held_experts_in_place(topo, monkeypatch):
+    """The third program that runs the expert layers (a 1,024-token chunk against the
+    slot's pages): the same call over the live tiles, no layer's experts copied, the
+    donated pool aliased through."""
+    from django_assistant_bot_tpu.models import mla_moe
+
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    cfg = _latent_moe_cfg()
+    one = SingleDeviceSharding(topo.devices[0])
+    params, cache = _latent_moe_args(cfg, one)
+    scalar = _sds((), jnp.int32, one)
+    compiled = (
+        jax.jit(lambda p, i, c, bt, s, st, v: mla_moe.prefill_chunk_paged(p, cfg, i, c, bt, s, st, v),
+                donate_argnums=(2,))
+        .lower(params, _sds((1, 1024), jnp.int32, one), cache, _sds((MAX_SEQ // PAGE,), jnp.int32, one),
+               scalar, scalar, scalar)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "held_experts" in text
+    assert _pool_sized_values_made_in_loops(text, LM_LAYER_EXPERTS) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cfg.num_layers * LM_PAGES * PAGE * cfg.latent_moe.latent_width
+    assert mem.temp_size_in_bytes < 1.0e9
