@@ -334,6 +334,13 @@ def experts_hit_per_layer_step(ctx) -> Optional[float]:
     return w["experts_hit"] / w["layer_steps"] if w and w["layer_steps"] else None
 
 
+def window_counts(ctx) -> Dict[str, float]:
+    """For every run's diagnostics line: what the routing made of the window,
+    the number the held experts' bytes follow."""
+    hit = experts_hit_per_layer_step(ctx)
+    return {} if hit is None else {"experts_hit_per_layer_step": hit}
+
+
 def live_context_tokens(ctx) -> Optional[float]:
     """Mean, over the traced span, of the context tokens of the requests
     decoding (prompt and tokens served so far): the client's log."""

@@ -17,6 +17,18 @@ and times every token on its own clock.  It serves warm traffic, measures for
 ``--seconds``, has the child stop the server, free the program and check a
 sample of what the window served against the plain reference.
 
+Every run does the same work whatever ``--seed``.  The weights are the
+configuration's: drawn from the seed its file states (``weights.seed``), the
+same checkpoint in every run, as a deployment has one checkpoint and many
+users.  ``--seed`` reaches the traffic (which request gets which length, the
+prompts' text, the arrivals: ``traffic_gen.Plan``) and the sample that
+``correct`` checks, and nothing else.
+
+However this process ends, the child does not outlive it: SIGTERM, SIGINT and
+the run's own deadline raise through ``main``'s ``finally``, which kills the
+child's process group, and a child whose control pipe reaches end-of-file
+(this process killed outright) ends itself.
+
 A line of diagnostics (JSON, ``"diagnostics"``) is printed before the result
 line in every run, traced or not.
 """
@@ -33,6 +45,7 @@ import importlib.util
 import json
 import os
 import shutil
+import signal
 import socket
 import subprocess
 import sys
@@ -49,7 +62,11 @@ TRACE_SECONDS = 3.0
 # the traced part ends this long before the window; the trace is written out only
 # after the window has closed (writing it stalled the engine for 6.5 s mid-window once)
 TRACE_BEFORE_CLOSE_S = 0.5
-BOOT_TIMEOUT_S = 1100.0
+# A run ends itself just before the driver would: it has to be done within 360 s of
+# its start, and a cell's first run in a checkout, which compiles, within 1,200.  At
+# the deadline the run kills its child and exits with no result, as it does on SIGTERM.
+DEADLINE_S = 355.0
+FIRST_RUN_DEADLINE_S = 1190.0
 MODEL = "bench"  # the name requests address the served model by
 
 
@@ -69,6 +86,24 @@ def load_cell(workload: str, bench_path: str, root: str):
     conf = _load_json(os.path.join(root, cfg_entry["file"]))
     mix = _load_json(os.path.join(data, "traffic", cell["traffic"] + ".json"))
     return bench, cell, conf, mix, data
+
+
+def weights_seed(conf, run_seed: int, rehearsal: bool) -> int:
+    """The seed the configuration's weights are drawn from: its file's
+    ``weights.seed``.  The one place that reads the key; the child and, through
+    it, the reference are handed this number and never ``--seed``.  There is no
+    default.  (Only the tests' CPU rehearsal lets a configuration from before
+    the key draw from the run's seed, as it did then: ``tests/data/`` is not
+    the benchmark's to edit.)"""
+    seed = conf["weights"].get("seed")
+    if isinstance(seed, int) and not isinstance(seed, bool) and seed >= 0:
+        return seed
+    if seed is None and rehearsal:
+        print(f"configuration {conf.get('name')!r} states no weights.seed: the rehearsal draws its weights "
+              f"from --seed {run_seed}", file=sys.stderr)
+        return int(run_seed)
+    raise SystemExit(f"configuration {conf.get('name')!r}: weights.seed has to be a whole number, the seed its "
+                     f"weights are drawn from in every run (got {seed!r}); there is no default")
 
 
 def load_family(conf, data_dir: str):
@@ -103,8 +138,10 @@ class Child:
         os.makedirs(os.path.dirname(log_path), exist_ok=True)
         self.log_path = log_path
         self.log = open(log_path, "w")
+        # a process group of its own, so that whatever the child starts ends with it
         self.proc = subprocess.Popen([sys.executable, sut_path], cwd=ROOT, stdin=subprocess.PIPE,
-                                     stdout=subprocess.PIPE, stderr=self.log, text=True, bufsize=1)
+                                     stdout=subprocess.PIPE, stderr=self.log, text=True, bufsize=1,
+                                     start_new_session=True)
         self.proc.stdin.write(json.dumps(job) + "\n")
         self.proc.stdin.flush()
         self.turn = threading.Lock()  # one question and its answer at a time
@@ -128,7 +165,8 @@ class Child:
         raise SystemExit(f"{why}\n--- {self.log_path} (tail) ---\n{tail}")
 
     def stop(self, timeout: float = 120.0) -> int:
-        """Waits for the child to end; kills it when it does not."""
+        """Waits for the child to end (end-of-file on its control pipe tells it
+        to); kills it when it does not."""
         try:
             self.proc.stdin.close()
         except OSError:
@@ -136,10 +174,17 @@ class Child:
         try:
             rc = self.proc.wait(timeout=timeout)
         except subprocess.TimeoutExpired:
-            self.proc.kill()
-            rc = self.proc.wait()
+            rc = self.kill()
         self.log.close()
         return rc
+
+    def kill(self) -> int:
+        """SIGKILL to the child's whole group, and the wait for the child."""
+        try:
+            os.killpg(self.proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        return self.proc.wait()
 
 
 def _free_port() -> int:
@@ -158,9 +203,7 @@ def _wait_healthy(child: Child, base: str) -> dict:
                     return json.loads(r.read())
         except OSError:
             pass
-        if time.monotonic() - T_START > BOOT_TIMEOUT_S:
-            child.fail("the server did not answer /healthz in time")
-        time.sleep(0.05)
+        time.sleep(0.05)  # the run's deadline ends a boot that never answers
 
 
 def main(argv=None) -> int:
@@ -183,6 +226,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     bench, cell, conf, mix, data_dir = load_cell(args.workload, args.benchmark_json, args.data_root)
+    cache = os.path.join(ROOT, ".cache")
+    overrides = {k: json.loads(v) for k, v in (s.split("=", 1) for s in args.spec)}
+    job = child_job(args, cell, conf, data_dir, cache, overrides)  # refuses a configuration that states no weights.seed
     family = load_family(conf, data_dir)
     from benchmarks.traffic_gen import Plan
 
@@ -192,28 +238,63 @@ def main(argv=None) -> int:
 
     # the compile cache lives inside the checkout, at a fixed path; the program
     # takes the directory this variable names
-    cache = os.path.join(ROOT, ".cache")
     env = os.environ
     env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(cache, "benchmarks_xla")
     env.pop("JAX_COMPILATION_CACHE_MAX_SIZE", None)
     os.makedirs(env["JAX_COMPILATION_CACHE_DIR"], exist_ok=True)
     env.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
-    port = _free_port()
-    base = f"http://127.0.0.1:{port}"
-    overrides = {k: json.loads(v) for k, v in (s.split("=", 1) for s in args.spec)}
-    job = {"conf": conf, "seed": args.seed, "chips": int(cell["chips"]), "rehearsal": args.rehearsal,
-           "port": port, "model": MODEL, "checkpoint": os.path.join(cache, "benchmarks_ckpt", cell["config"]),
-           "spec_overrides": overrides, "data_dir": data_dir}
-    child = Child(args.sut, job, os.path.join(cache, "benchmarks_log", args.workload + ".log"))
+    base = f"http://127.0.0.1:{job['port']}"
+    # a cell that has ended a run in this checkout finds its programs in the cache
+    ran_before = os.path.join(cache, "benchmarks_log", args.workload + ".ran")
+    # the command's clock started with the process; a test that calls main() starts one with the call
+    deadline = (T_START if argv is None else time.monotonic()) + (
+        DEADLINE_S if os.path.exists(ran_before) else FIRST_RUN_DEADLINE_S)
+    child = None
+    restore = _raise_on_signals(deadline)
     try:
-        return _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base, cache, overrides)
+        child = Child(args.sut, job, os.path.join(cache, "benchmarks_log", args.workload + ".log"))
+        rc = _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base, cache, overrides, job)
+        open(ran_before, "w").close()
+        return rc
     finally:
-        if child.proc.poll() is None:  # never leave the chip's holder behind
-            child.proc.kill()
-            child.proc.wait()
+        restore()
+        if child is not None and child.proc.poll() is None:  # never leave the chip's holder behind
+            child.kill()
 
 
-def _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base, cache, overrides) -> int:
+def child_job(args, cell, conf, data_dir: str, cache: str, overrides) -> dict:
+    """What the child is told: the weights' seed is the configuration's, and
+    the run's seed is beside it for the log alone."""
+    return {"conf": conf, "weights_seed": weights_seed(conf, args.seed, args.rehearsal), "seed": args.seed,
+            "chips": int(cell["chips"]), "rehearsal": args.rehearsal, "port": _free_port(), "model": MODEL,
+            "checkpoint": os.path.join(cache, "benchmarks_ckpt", cell["config"]),
+            "spec_overrides": overrides, "data_dir": data_dir}
+
+
+def _raise_on_signals(deadline: float):
+    """SIGTERM, SIGINT and the deadline (SIGALRM) raise ``SystemExit`` in the
+    main thread, so that ``main``'s ``finally`` runs and no result is printed.
+    Returns what puts the process's handlers back (the tests call ``main``)."""
+    if threading.current_thread() is not threading.main_thread():
+        return lambda: None
+
+    def ended(signum, frame):
+        why = "its deadline" if signum == signal.SIGALRM else signal.Signals(signum).name
+        raise SystemExit(f"run.py: ended by {why} after {time.monotonic() - T_START:.0f} s; the child is killed, no result")
+
+    signals = (signal.SIGTERM, signal.SIGINT, signal.SIGALRM)
+    before = [signal.signal(s, ended) for s in signals]
+    signal.setitimer(signal.ITIMER_REAL, max(1.0, deadline - time.monotonic()))
+
+    def restore():
+        signal.setitimer(signal.ITIMER_REAL, 0.0)
+        for s, h in zip(signals, before):
+            signal.signal(s, h)
+
+    return restore
+
+
+def _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base, cache, overrides, job) -> int:
     from benchmarks import correct, driver, metrics, roofline, trace_reduce
 
     booting = child.read()  # the child has a device, a checkpoint, a warmed engine; or it has ended
@@ -228,6 +309,7 @@ def _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base,
     def on_open():
         snap["s0"] = child.ask("snapshot")
         snap["setup_s"] = time.monotonic() - T_START
+        print(f"window open: child {child.proc.pid} serves at {base}", file=sys.stderr, flush=True)
 
     def on_close():
         snap["s1"] = child.ask("snapshot")
@@ -301,6 +383,10 @@ def _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base,
         dev_out["window_s"] = red["window_s"]
         breakdown = {"device_ops": red["device_ops"], "idle_gaps": red["idle_gaps"]}
 
+    counter_metrics = {m["name"]: float(v) for m in metrics_for(bench, "per_layer", cell["name"])
+                       if m["source"] == "program_counter" and (v := ctx["read"](m["name"])) is not None}
+    if hasattr(family, "window_counts"):  # a family's own counts over the window, for the diagnostics line
+        counter_metrics.update(family.window_counts(ctx))
     out_metrics = {}
     if args.trace:
         for m in metrics_for(bench, "per_layer", cell["name"]):
@@ -312,7 +398,8 @@ def _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base,
             out_metrics[m["name"]] = {"value": float(e2e[m["name"]]), "unit": m["unit"]}
 
     diag = {
-        "diagnostics": cell["name"], "seed": args.seed, "seconds": args.seconds, "trace": args.trace,
+        "diagnostics": cell["name"], "seed": args.seed, "weights_seed": job["weights_seed"],
+        "seconds": args.seconds, "trace": args.trace,
         "compiles_in_window": ctx["compiles_in_window"],
         "cache_misses_total": end["cache_misses"],
         # programs first met after the boot (warm traffic, window): should be few and cached
@@ -322,6 +409,9 @@ def _run_cell(args, bench, cell, conf, mix, data_dir, family, plan, child, base,
         # what the rate was made of: where in the window, and from how many ticks and steps
         **metrics.window_profile(events, t_open, t_close),
         "ticks_in_window": c1["ticks"] - c0["ticks"],
+        # what the program counted over the window, in every run: the cell's per-layer metrics that
+        # are read from counters alone (a traced run prints them again among its metrics)
+        "counter_metrics": counter_metrics,
         "decode_kv_path": c1["tick_stats"].get("decode_kv_path"),  # "kernel" on a TPU, "xla" elsewhere
         "prefill_chunks_piggybacked": (c1.get("prefill_chunks_piggybacked") or 0) - (c0.get("prefill_chunks_piggybacked") or 0),
         "gen_late_max_ms": max(res["late_ms"], default=0.0),

@@ -8,10 +8,12 @@ line of JSON (the job) on its standard input and reads JSON lines back.  The
 child
 
 1. names its device and refuses anything but a TPU with enough chips;
-2. draws the seeded weights on the device (``served_params`` of the
-   configuration's family, ``benchmarks/families/``), wraps the tree in the
-   program's types, saves it with the program's own ``save_model`` as a native
-   checkpoint under the checkout, and frees it;
+2. draws the configuration's weights on the device (``served_params`` of the
+   configuration's family, ``benchmarks/families/``, from the job's
+   ``weights_seed``: the configuration file's ``weights.seed``, never the
+   run's ``--seed``), wraps the tree in the program's types, saves it with the
+   program's own ``save_model`` as a native checkpoint under the checkout, and
+   frees it;
 3. boots the program the way ``cli serve --config ... --warmup`` does: the
    persistent compile cache, a ``ModelRegistry`` from a model config whose
    entries are the configuration file's ``serving`` block word for word
@@ -24,7 +26,10 @@ child
    the profiler around the traced part of the window, gauge samples;
 5. on ``finish`` sends itself SIGTERM (the server drains and stops its
    engines), frees the program and checks the sample ``run.py`` wrote against
-   the family's plain reference (``benchmarks/correct.py``).
+   the family's plain reference (``benchmarks/correct.py``), over weights drawn
+   again from ``weights_seed``;
+6. ends itself when the control pipe reaches end-of-file before ``finish``:
+   ``run.py`` is gone, and nothing may go on holding the chip and the port.
 
 Nothing here computes a metric or decides ``correct``.
 """
@@ -121,7 +126,7 @@ def wrap_params(tree, act):
 def write_checkpoint(family, conf: Dict[str, Any], seed: int, path: str) -> None:
     """The family's seeded weights, in the program's parameter layout, written
     by the program's ``save_model``: what ``ModelSpec.checkpoint`` loads.  Made
-    on the device from the seed and freed before the boot."""
+    on the device from ``seed`` (the weights' seed) and freed before the boot."""
     import jax
     import jax.numpy as jnp
 
@@ -135,7 +140,7 @@ def write_checkpoint(family, conf: Dict[str, Any], seed: int, path: str) -> None
     os.makedirs(os.path.dirname(path), exist_ok=True)
     # the checkpoint's context cap is the deployment's: the engine clamps to it anyway
     cfg = dataclasses.replace(DecoderConfig.from_hf(conf["hf"], dtype=act), max_seq_len=int(conf["serving"]["max_seq_len"]))
-    save_model(path, "decoder", cfg, params, meta={"benchmark_seed": int(seed)})
+    save_model(path, "decoder", cfg, params, meta={"benchmark_weights_seed": int(seed)})
 
 
 def boot_registry(conf: Dict[str, Any], model: str, checkpoint: str, overrides: Dict[str, Any]):
@@ -237,14 +242,22 @@ def counters(engine) -> Dict[str, Any]:
     return out
 
 
+# how long a child whose parent is gone gives the server's graceful stop before it just exits
+ORPHAN_GRACE_S = 8.0
+
+
 class Control(threading.Thread):
     """Answers ``run.py``'s commands, one JSON line each, while the main
-    thread serves."""
+    thread boots and serves; the engine is bound once there is one (``run.py``
+    asks nothing before it is told of the boot).  End-of-file before
+    ``finish`` means ``run.py`` is gone: the child ends itself, whatever the
+    main thread is doing."""
 
-    def __init__(self, say, engine, compiles: CompileCounter):
+    def __init__(self, say, compiles: CompileCounter):
         super().__init__(daemon=True, name="bench-control")
-        self.say, self.engine, self.compiles = say, engine, compiles
+        self.say, self.engine, self.compiles = say, None, compiles
         self.finish: Optional[Dict[str, Any]] = None
+        self.checked = False  # the main thread has sent the check's numbers: end-of-file is the run's end
         self.samples: Dict[str, list] = {"rows_active": [], "kv_pages_used": []}
         self._sampling = threading.Event()
 
@@ -291,8 +304,20 @@ class Control(threading.Thread):
                 self.finish = msg
                 self.say(reply)
                 os.kill(os.getpid(), signal.SIGTERM)  # the server's own graceful stop
-                return
+                continue
             self.say(reply)
+        if self.checked:
+            return
+        # end-of-file and nobody left to read a result.  During the check: out at once.  Before
+        # it, SIGTERM ends a boot at once and makes a server drain (and the main thread then
+        # returns); whatever is still there after the grace is cut
+        print("control pipe closed before the run's end: run.py is gone, ending", file=sys.stderr, flush=True)
+        if self.finish is not None:
+            os._exit(3)
+        cut = threading.Timer(ORPHAN_GRACE_S, os._exit, (3,))
+        cut.daemon = True
+        cut.start()
+        os.kill(os.getpid(), signal.SIGTERM)
 
 
 def main() -> int:
@@ -306,7 +331,10 @@ def main() -> int:
         proto.flush()
 
     job = json.loads(sys.stdin.readline())
-    conf, seed = job["conf"], int(job["seed"])
+    # the weights are the configuration's; the run's seed is the traffic's and reaches nothing here
+    conf, weights_seed = job["conf"], int(job["weights_seed"])
+    print(f"job: configuration {conf.get('name')!r}, weights' seed {weights_seed}, run's seed {job.get('seed')}",
+          file=sys.stderr, flush=True)
     from benchmarks import families
 
     family = families.load(conf, job["data_dir"])
@@ -314,12 +342,14 @@ def main() -> int:
     t_device = time.monotonic() - t_start
     enable_compile_cache()
     compiles = CompileCounter()
+    control = Control(say, compiles)
+    control.start()
 
     import jax
     import jax.numpy as jnp
 
     t = time.monotonic()
-    write_checkpoint(family, conf, seed, job["checkpoint"])
+    write_checkpoint(family, conf, weights_seed, job["checkpoint"])
     gc.collect()
     t_weights = time.monotonic() - t
     t = time.monotonic()
@@ -333,20 +363,20 @@ def main() -> int:
     jnp.asarray([False] * int(conf["serving"]["max_slots"])).block_until_ready()
     jnp.asarray([0], jnp.int32).block_until_ready()
     t_boot = time.monotonic() - t
+    control.engine = engine
     say({"event": "booting", "device": device, "boot_s": registry.boot_s.get(job["model"]),
          "programs_in_setup": compiles.n,
          "setup_parts_s": {"imports_and_device": t_device, "weights_and_checkpoint": t_weights,
                            "load_place_warmup": t_boot}})
 
-    control = Control(say, engine, compiles)
-    control.start()
     from django_assistant_bot_tpu.serving.server import run_server
 
     run_server(registry=registry, host="127.0.0.1", port=int(job["port"]), drain_deadline_s=5.0)
 
     # -- the server has stopped its engines: free the program, then the reference
     finish = control.finish
-    del control, engine, registry
+    control.engine = None
+    del engine, registry
     gc.collect()
     jax.clear_caches()
     if not finish or not finish.get("sample"):
@@ -357,8 +387,9 @@ def main() -> int:
     with open(finish["sample"]) as f:
         picked = json.load(f)
     controls = bool(finish.get("controls"))
-    numbers = correct.logit_gaps(family, conf, seed, picked, controls,
+    numbers = correct.logit_gaps(family, conf, weights_seed, picked, controls,
                                  dump=finish["sample"] + ".gaps.json" if controls else "") if picked else {}
+    control.checked = True
     say({"event": "checked", "numbers": numbers, "check_s": time.monotonic() - t})
     return 0
 

@@ -1,7 +1,11 @@
 """The one traffic generator: a mix file of parameters in, a plan of requests out.
 
 A mix (``benchmarks/traffic/<name>.json``) states how requests arrive and how
-long they are.  The plan it gives does the same work whatever the seed:
+long they are.  The plan it gives does the same work whatever the seed, which
+is the traffic's half of the benchmark's one rule (``README.md``: the weights
+are the configuration's, drawn from its file's ``weights.seed`` whatever
+``--seed``; this module is all that ``--seed`` reaches, beside the sample that
+``correct`` draws):
 
 - lengths are taken at evenly spaced quantiles of the stated distribution: one
   multiset of prompt and output lengths, the same for every seed;
