@@ -209,7 +209,6 @@ def write_serving_config(cfg: SmokeConfig) -> str:
         f"max_seq_len = {cfg.max_seq_len}",
         f"max_slots = {cfg.max_slots}",
         f"chunk_size = {cfg.chunk_size}",
-        'kv_layout = "paged"',
     ]
     if cfg.int8:
         lines.append('quantize = "int8"')
@@ -519,10 +518,8 @@ def phase_serve(cfg: SmokeConfig, boot_timeout_s: float = 1000.0) -> Dict[str, A
         kv = gen.get("kv", {})
         checks.check(
             "prefix pages shared",
-            kv.get("kv_layout_effective") == "paged"
-            and kv.get("prefix_hits", 0) >= 2
-            and kv.get("kv_shared_pages", 0) >= 1,
-            {k: v for k, v in kv.items() if k.startswith(("kv_layout", "prefix_", "kv_shared"))},
+            kv.get("prefix_hits", 0) >= 2 and kv.get("kv_shared_pages", 0) >= 1,
+            {k: v for k, v in kv.items() if k.startswith(("prefix_", "kv_shared"))},
         )
         checks.check(
             "decoder is the configured one",

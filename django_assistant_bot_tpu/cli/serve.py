@@ -55,7 +55,6 @@ def _probe_engine_factory(spec, cfg):
             spec_width=spec.spec_width,
             prefill_piggyback=spec.prefill_piggyback,
             attn_fp8=spec.attn_fp8,
-            kv_layout=spec.kv_layout,
             kv_page_size=int(cand["kv_page_size"]),
             prefix_cache_size=0,
             scheduler=None,
@@ -261,27 +260,20 @@ def add_parser(sub):
         "deadline, then exits 0 (default 30)",
     )
     p.add_argument(
-        "--kv-layout",
-        choices=("paged", "legacy"),
-        default=None,
-        help="KV cache layout for every decoder: 'paged' (block-table page "
-        "pool with prefix sharing — the default) or 'legacy' (contiguous "
-        "per-slot rows; the one-flag rollback — docs/KV_PAGING.md)",
-    )
-    p.add_argument(
         "--kv-pages",
         type=int,
         default=None,
         metavar="N",
-        help="page-pool size in pages for every decoder (0 = byte parity "
-        "with the legacy layout: max_slots * max_seq_len / page_size)",
+        help="page-pool size in pages for every decoder (0 = a whole context "
+        "per slot: max_slots * max_seq_len / page_size)",
     )
     p.add_argument(
         "--kv-page-size",
         type=int,
         default=None,
         metavar="TOKENS",
-        help="KV page size in tokens (0 = align with decode_kv_chunk)",
+        help="KV page size in tokens (0 = the largest of 512 ... 8 that divides "
+        "max_seq_len at least twice)",
     )
     p.add_argument(
         "--kv-host-bytes",
@@ -435,8 +427,6 @@ def run(args) -> int:
         sched_overrides["pool"] = args.pool
     if getattr(args, "slo_itl_p95_s", None) is not None:
         sched_overrides["autoscale_slo_itl_p95_s"] = args.slo_itl_p95_s
-    if getattr(args, "kv_layout", None) is not None:
-        sched_overrides["kv_layout"] = args.kv_layout
     if getattr(args, "kv_pages", None) is not None:
         sched_overrides["kv_pages"] = args.kv_pages
     if getattr(args, "kv_page_size", None) is not None:

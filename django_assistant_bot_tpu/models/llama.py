@@ -5,11 +5,11 @@ stream (reference: assistant/ai/providers/transformers.py:35-94).  Differences t
 matter on TPU:
 
 - layers stacked on a leading axis, iterated with ``lax.scan`` — one compiled body;
-- a slot-based, static-shape KV cache carried through the scan (continuous batching
-  updates per-slot positions with vmap'd ``dynamic_update_slice`` — no dynamic shapes
-  ever reach XLA);
-- prefill uses the pallas flash-attention kernel for long buckets; decode uses the
-  jnp path (projections dominate at Sq=1);
+- a static-shape page pool shared by every slot, addressed through per-slot block
+  tables (continuous batching: no dynamic shapes ever reach XLA);
+- prefill uses the pallas flash-attention kernel for long buckets; decode writes the
+  step's K/V row in place and reads only the live pages (a Pallas kernel on a TPU,
+  the jnp path elsewhere);
 - tensor parallelism: heads/mlp sharded over the ``model`` mesh axis via logical
   axis annotations; XLA inserts the per-layer psums over ICI.
 
@@ -29,7 +29,6 @@ import numpy as np
 
 from ..ops.attention import (
     attention,
-    chunked_gqa_decode_attention,
     dot_product_attention,
     gqa_dot_product_attention,
     paged_decode_kv_path,
@@ -47,87 +46,6 @@ from .config import DecoderConfig
 Params = Dict[str, Any]
 
 _logger = logging.getLogger(__name__)
-
-
-class KVCache(NamedTuple):
-    """Static-shape slot cache.  k/v: [L, B, KH, S, D]; lengths: [B] tokens present."""
-
-    k: jnp.ndarray
-    v: jnp.ndarray
-    lengths: jnp.ndarray  # int32 [B]
-
-    @property
-    def max_len(self) -> int:
-        return self.k.shape[3]
-
-
-CACHE_AXES = KVCache(
-    k=(None, "batch", "kv_heads", None, "head_dim"),
-    v=(None, "batch", "kv_heads", None, "head_dim"),
-    lengths=("batch",),
-)
-
-
-def cache_shardings(cfg: DecoderConfig, mesh, batch: int) -> KVCache:
-    """NamedShardings for the slot cache on ``mesh``, derived from CACHE_AXES.
-
-    KV heads shard over the ``model`` (TP) axis and slots over ``data`` — each
-    dropped to replication when the dimension doesn't divide the mesh axis (e.g.
-    tiny test models on a wide mesh).  ``lengths`` is a [B] int32 — replicated.
-    """
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
-    from ..parallel.sharding import DEFAULT_RULES, logical_to_pspec
-
-    rules = dict(DEFAULT_RULES)
-    if batch % mesh.shape[DATA_AXIS] != 0:
-        rules["batch"] = None
-        if mesh.shape[DATA_AXIS] > 1:
-            _logger.warning(
-                "KV cache slots (%d) don't divide mesh data axis (%d): slot dim "
-                "replicated per data group — round max_slots up to a multiple to "
-                "shard it",
-                batch,
-                mesh.shape[DATA_AXIS],
-            )
-    if cfg.num_kv_heads % mesh.shape[MODEL_AXIS] != 0:
-        rules["kv_heads"] = None
-        if mesh.shape[MODEL_AXIS] > 1:
-            _logger.warning(
-                "num_kv_heads (%d) doesn't divide mesh model axis (%d): KV cache "
-                "replicated across the TP axis — every chip holds a full copy",
-                cfg.num_kv_heads,
-                mesh.shape[MODEL_AXIS],
-            )
-    return KVCache(
-        k=NamedSharding(mesh, logical_to_pspec(CACHE_AXES.k, rules)),
-        v=NamedSharding(mesh, logical_to_pspec(CACHE_AXES.v, rules)),
-        lengths=NamedSharding(mesh, P()),
-    )
-
-
-def prefix_shardings(cfg: DecoderConfig, mesh):
-    """NamedSharding for cached prefix K/V tensors ([L, KH, P, D]): kv_heads
-    over the TP axis like the slot cache, dropped to replication when the
-    head count doesn't divide the axis (same rule as :func:`cache_shardings`)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ..parallel.mesh import MODEL_AXIS
-
-    if cfg.num_kv_heads % mesh.shape[MODEL_AXIS] == 0 and mesh.shape[MODEL_AXIS] > 1:
-        return NamedSharding(mesh, P(None, MODEL_AXIS, None, None))
-    return NamedSharding(mesh, P())
-
-
-def init_cache(cfg: DecoderConfig, batch: int, max_len: int, dtype=None) -> KVCache:
-    dtype = dtype or cfg.dtype
-    shape = (cfg.num_layers, batch, cfg.num_kv_heads, max_len, cfg.head_dim)
-    return KVCache(
-        k=jnp.zeros(shape, dtype),
-        v=jnp.zeros(shape, dtype),
-        lengths=jnp.zeros((batch,), jnp.int32),
-    )
 
 
 def logical_axes(cfg: DecoderConfig) -> Params:
@@ -733,8 +651,8 @@ def prefill(
     """Run prompts through the model.
 
     Returns (last-token logits [B,V] f32, ks [L,B,KH,S,D], vs) — the K/V tensors are
-    inserted into cache slots by :func:`insert_sequences` (prefill runs on its own
-    small batch so it never touches other live slots' cache rows).
+    written into the slots' pages by :func:`insert_sequences_paged` (prefill runs on
+    its own small batch so it never touches other live slots' cache rows).
     """
     B, S = input_ids.shape
     cos, sin = _rope_tables(cfg, S)
@@ -766,228 +684,6 @@ def prefill(
     )[:, 0]  # [B, E]
     logits = _head_logits(params, cfg, last)
     return logits.astype(jnp.float32), ks, vs
-
-
-def insert_sequences(
-    cache: KVCache,
-    ks: jnp.ndarray,  # [L, B, KH, S, D] from prefill
-    vs: jnp.ndarray,
-    lengths: jnp.ndarray,  # [B]
-    slots: jnp.ndarray,  # [B] int32 target slot per prefilled row
-) -> KVCache:
-    """Write prefilled K/V rows into their cache slots (positions [0, S)).
-
-    A ``lax.scan`` over the prefill batch — one compiled body regardless of how many
-    rows a prefill carries (a Python loop would unroll and recompile per batch size).
-    """
-
-    def body(carry, inp):
-        k, v, lens = carry
-        row_k, row_v, length, slot = inp  # row_k: [L, KH, S, D]
-        k = jax.lax.dynamic_update_slice(
-            k, row_k[:, None].astype(k.dtype), (0, slot, 0, 0, 0)
-        )
-        v = jax.lax.dynamic_update_slice(
-            v, row_v[:, None].astype(v.dtype), (0, slot, 0, 0, 0)
-        )
-        lens = jax.lax.dynamic_update_index_in_dim(lens, length, slot, 0)
-        return (k, v, lens), None
-
-    rows_k = jnp.moveaxis(ks, 1, 0)  # [B, L, KH, S, D]
-    rows_v = jnp.moveaxis(vs, 1, 0)
-    (k, v, cache_lengths), _ = jax.lax.scan(
-        body, (cache.k, cache.v, cache.lengths), (rows_k, rows_v, lengths, slots)
-    )
-    return KVCache(k=k, v=v, lengths=cache_lengths)
-
-
-def prefill_chunk(
-    params: Params,
-    cfg: DecoderConfig,
-    input_ids: jnp.ndarray,  # [1, C] one chunk of one prompt (C static; pad tail)
-    cache: KVCache,
-    slot: jnp.ndarray,  # scalar int32 — target cache slot
-    start: jnp.ndarray,  # scalar int32 — tokens already written for this slot
-    valid: jnp.ndarray,  # scalar int32 — real (non-pad) tokens in this chunk
-) -> tuple[jnp.ndarray, KVCache]:
-    """Extend one slot's cache by a chunk of prompt tokens.
-
-    The disaggregation primitive (SURVEY.md §7 hard part (c)): instead of one
-    monolithic prefill call that stalls every live decode stream for its full
-    duration, the engine splits long prompts into fixed-size chunks and interleaves
-    one chunk per decode tick — the decode head-of-line delay is bounded by a chunk,
-    not the prompt.  ``slot``/``start``/``valid`` are traced scalars, so one compiled
-    program serves every chunk position of every request.
-
-    Returns (logits [1, V] f32 at chunk index ``valid-1``, cache with
-    ``lengths[slot] = start + valid``).  Only the final chunk's logits are used.
-    """
-    B, C = input_ids.shape
-    S = cache.max_len
-    L = cfg.num_layers
-    H, KH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    pos = start + jnp.arange(C)
-    cos_t, sin_t = _rope_tables(cfg, S)
-    cos, sin = cos_t[pos], sin_t[pos]  # [C, hd/2]
-    x = _embed(params, cfg, input_ids)  # [1, C, E]
-    # queries attend to every cache position up to their own absolute position
-    kpos = jnp.arange(S)[None, None, None, :]
-    causal_keep = kpos <= pos[None, None, :, None]  # [1, 1, C, S]
-
-    k_rows = jax.lax.dynamic_slice(cache.k, (0, slot, 0, 0, 0), (L, 1, KH, S, D))
-    v_rows = jax.lax.dynamic_slice(cache.v, (0, slot, 0, 0, 0), (L, 1, KH, S, D))
-
-    def make_body(window):
-        attn_mask = causal_keep
-        if window is not None:
-            # banded over the slot cache: only the window's most recent
-            # absolute positions (including this chunk's own writes) survive
-            attn_mask = attn_mask & (kpos > pos[None, None, :, None] - window)
-
-        def body(x, inputs):
-            p, k_row, v_row = inputs  # k_row: [1, KH, S, D]
-            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = _attn_proj(cfg, p, h, cos, sin)
-            k_row = jax.lax.dynamic_update_slice(k_row, k.astype(k_row.dtype), (0, 0, start, 0))
-            v_row = jax.lax.dynamic_update_slice(v_row, v.astype(v_row.dtype), (0, 0, start, 0))
-            # grouped attention reads the cache row once (no q_per_kv repeat)
-            o = gqa_dot_product_attention(q, k_row, v_row, mask=attn_mask)  # [1, H, C, D]
-            o = o.transpose(0, 2, 1, 3).reshape(B, C, -1)
-            x = x + _attn_out(cfg, p, o)
-            h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(cfg, p, h)
-            return x, (k_row, v_row)
-
-        return body
-
-    x, (k_rows, v_rows) = _scan_window_split(
-        cfg, make_body, x, (params["layers"], k_rows, v_rows)
-    )
-    k = jax.lax.dynamic_update_slice(cache.k, k_rows.astype(cache.k.dtype), (0, slot, 0, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache.v, v_rows.astype(cache.v.dtype), (0, slot, 0, 0, 0))
-    lengths = jax.lax.dynamic_update_index_in_dim(
-        cache.lengths, (start + valid).astype(cache.lengths.dtype), slot, 0
-    )
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    last = jax.lax.dynamic_index_in_dim(x[0], jnp.maximum(valid - 1, 0), 0, keepdims=False)
-    logits = _head_logits(params, cfg, last)[None]
-    return logits.astype(jnp.float32), KVCache(k=k, v=v, lengths=lengths)
-
-
-def prefill_suffix(
-    params: Params,
-    cfg: DecoderConfig,
-    input_ids: jnp.ndarray,  # [B, C] right-padded suffix tokens (C static bucket)
-    cache: KVCache,
-    slots: jnp.ndarray,  # [B] int32 — target cache slot per row
-    starts: jnp.ndarray,  # [B] int32 — tokens already present (the prefix length)
-    valids: jnp.ndarray,  # [B] int32 — real (non-pad) tokens per row
-) -> tuple[jnp.ndarray, KVCache]:
-    """Batched continuation prefill on top of already-cached prefixes.
-
-    The prefix-KV-cache primitive: each row's slot already holds ``starts[b]``
-    tokens of K/V (a shared system/RAG-context prefix inserted from the prefix
-    cache — the reference re-sends that context in full every turn,
-    assistant/bot/services/context_service/steps/final_prompt.py:14, and
-    re-prefills it from scratch).  Here only the per-request suffix runs
-    through the model: queries take absolute positions ``starts[b] + i`` (so
-    RoPE matches a monolithic prefill exactly) and attend to the slot's whole
-    cache row up to their own position.
-
-    One dispatch serves a whole admission wave (unlike :func:`prefill_chunk`,
-    which advances a single slot) — ``slots``/``starts``/``valids`` are traced,
-    so one compiled program per (batch-bucket, C) shape.
-
-    Returns (logits [B, V] f32 at each row's last real token, cache with
-    ``lengths[slot] = start + valid``).
-    """
-    B, C = input_ids.shape
-    S = cache.max_len
-    pos = starts[:, None] + jnp.arange(C)[None, :]  # [B, C] absolute positions
-    cos_t, sin_t = _rope_tables(cfg, S)
-    cos, sin = cos_t[pos], sin_t[pos]  # [B, C, hd/2] — per-row gather
-    x = _embed(params, cfg, input_ids)  # [B, C, E]
-    kpos = jnp.arange(S)[None, None, None, :]
-    causal_keep = kpos <= pos[:, None, :, None]  # [B, 1, C, S]
-
-    # each row's slot cache: [L, B, KH, S, D] (gather, not dynamic_slice — the
-    # rows are independent per-request slots)
-    k_rows = jnp.take(cache.k, slots, axis=1)
-    v_rows = jnp.take(cache.v, slots, axis=1)
-
-    def make_body(window):
-        attn_mask = causal_keep
-        if window is not None:
-            attn_mask = attn_mask & (kpos > pos[:, None, :, None] - window)
-
-        def body(x, inputs):
-            p, k_row, v_row = inputs  # k_row: [B, KH, S, D]
-            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = _attn_proj(cfg, p, h, cos, sin)
-            # write this chunk's K/V at each row's own start (vmap'd slice)
-            k_row = _write_cache(k_row, k, starts)
-            v_row = _write_cache(v_row, v, starts)
-            o = gqa_dot_product_attention(q, k_row, v_row, mask=attn_mask)
-            o = o.transpose(0, 2, 1, 3).reshape(B, C, -1)
-            x = x + _attn_out(cfg, p, o)
-            h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(cfg, p, h)
-            return x, (k_row, v_row)
-
-        return body
-
-    x, (k_rows, v_rows) = _scan_window_split(
-        cfg, make_body, x, (params["layers"], k_rows, v_rows)
-    )
-
-    # Scatter the updated rows back into their slots via insert_sequences'
-    # sequential scan: batch-bucket pad rows alias a real slot, and a
-    # gather-scatter with duplicate indices has UNDEFINED winner — the
-    # row-order scan makes the later (real) row deterministically overwrite
-    # the pad row's garbage.  (Full-width rows: S == cache.max_len.)
-    cache = insert_sequences(
-        cache, k_rows, v_rows, (starts + valids).astype(cache.lengths.dtype), slots
-    )
-    k, v, lengths = cache.k, cache.v, cache.lengths
-    x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    last = jnp.take_along_axis(
-        x, jnp.maximum(valids - 1, 0)[:, None, None], axis=1
-    )[:, 0]  # [B, E]
-    logits = _head_logits(params, cfg, last)
-    return logits.astype(jnp.float32), KVCache(k=k, v=v, lengths=lengths)
-
-
-def insert_prefix(
-    cache: KVCache,
-    pk: jnp.ndarray,  # [L, KH, Pb, D] roped prefix K (positions [0, Pb))
-    pv: jnp.ndarray,
-    slot: jnp.ndarray,  # scalar int32
-) -> KVCache:
-    """Copy a cached prefix's K/V into a slot's cache row (positions [0, Pb)).
-
-    Pure HBM copy — no model compute.  ``Pb`` may exceed the true prefix
-    length (bucket padding); the garbage tail is overwritten or masked by the
-    suffix prefill, which also sets the slot's true length.
-    """
-    k = jax.lax.dynamic_update_slice(
-        cache.k, pk[:, None].astype(cache.k.dtype), (0, slot, 0, 0, 0)
-    )
-    v = jax.lax.dynamic_update_slice(
-        cache.v, pv[:, None].astype(cache.v.dtype), (0, slot, 0, 0, 0)
-    )
-    return KVCache(k=k, v=v, lengths=cache.lengths)
-
-
-def extract_prefix(cache: KVCache, slot: jnp.ndarray, pb: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Slice the first ``pb`` cached positions of a slot row -> ([L, KH, pb, D]) x2.
-
-    Captures a just-prefilled request's prefix K/V for the prefix cache (the
-    K values are post-RoPE at absolute positions [0, pb) — position-correct
-    for every future consumer, which places the prefix at the same offsets).
-    """
-    pk = jnp.take(cache.k, slot, axis=1)[:, :, :pb]
-    pv = jnp.take(cache.v, slot, axis=1)[:, :, :pb]
-    return pk, pv
 
 
 # ---------------------------------------------------------------------------
@@ -1041,8 +737,9 @@ def init_paged_cache(
 
 def paged_cache_shardings(cfg: DecoderConfig, mesh, batch: int) -> PagedKVCache:
     """NamedShardings for the page pool: KV heads over the TP (``model``) axis
-    like the slot cache; the page axis stays replicated across ``data`` — the
-    block-table gather is global, so sharding pages would need collectives
+    (replicated when the head count does not divide it); the page axis stays
+    replicated across ``data`` — the block-table gather is global, so sharding
+    pages would need collectives
     (multi-chip serving promotes to per-replica pools instead, ROADMAP 3)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -1081,8 +778,7 @@ def _gather_layer_rows(
 ) -> jnp.ndarray:
     """One layer's logical KV view of each row, from its pages ->
     ``[B, KH, NB*page, D]``.  Unallocated blocks gather a clamped page —
-    garbage the caller masks, exactly like the contiguous rows' invalid
-    positions.
+    garbage the caller masks.
 
     Per LAYER, inside the layer scan, with the pool riding the scan carry
     (updated in place): gathering every layer's rows up front, scanning them
@@ -1131,10 +827,9 @@ def insert_sequences_paged(
     slots: jnp.ndarray,  # [B] int32 — target slot (max_slots sentinel = pad row)
     block_tables: jnp.ndarray,  # [B, NB] — pad rows carry the P sentinel
 ) -> PagedKVCache:
-    """Paged analog of :func:`insert_sequences`: write prefilled K/V rows into
-    their slots' pages (positions [0, Sb)).  Blocks past a row's allocation
-    (bucket padding beyond the reserved demand) and pad rows drop via the
-    sentinel — no aliasing trick needed, unlike the contiguous scan."""
+    """Write prefilled K/V rows into their slots' pages (positions [0, Sb)).
+    Blocks past a row's allocation (bucket padding beyond the reserved demand)
+    and pad rows drop via the sentinel."""
     L, P, KH, page, D = cache.k.shape
     B, Sb = ks.shape[1], ks.shape[3]
     NB = block_tables.shape[1]
@@ -1166,14 +861,28 @@ def prefill_suffix_paged(
     starts: jnp.ndarray,  # [B] int32 — tokens already present (the prefix length)
     valids: jnp.ndarray,  # [B] int32 — real (non-pad) tokens per row
 ) -> tuple[jnp.ndarray, PagedKVCache]:
-    """Paged :func:`prefill_suffix`: layer by layer, gather each row's logical
-    view from its pages, run the identical suffix forward (same masks, same
-    RoPE positions — the compute is byte-for-byte the contiguous path's), then
-    scatter back ONLY the blocks overlapping the written window
-    ``[start, start+C)``.  Blocks below it are the shared prefix pages —
-    physically shared with other requests, so they must not be touched (their
-    gathered values are unchanged, but a duplicate-index scatter's winner is
-    undefined)."""
+    """Batched continuation prefill on top of already-cached prefixes.
+
+    Each row's page chain already holds ``starts[b]`` tokens of K/V (a shared
+    system/RAG-context prefix found in the page pool's registry — the
+    reference re-sends that context in full every turn,
+    assistant/bot/services/context_service/steps/final_prompt.py:14, and
+    re-prefills it from scratch).  Only the per-request suffix runs through
+    the model: queries take absolute positions ``starts[b] + i`` (so RoPE
+    matches a monolithic prefill exactly) and attend to the row's whole
+    logical view up to their own position.  One dispatch serves a whole
+    admission wave; ``slots``/``starts``/``valids`` are traced, so one
+    compiled program per (batch-bucket, C) shape.
+
+    Layer by layer: gather each row's logical view from its pages, run the
+    suffix forward, then scatter back ONLY the blocks overlapping the written
+    window ``[start, start+C)``.  Blocks below it are the shared prefix pages
+    — physically shared with other requests, so they must not be touched
+    (their gathered values are unchanged, but a duplicate-index scatter's
+    winner is undefined).
+
+    Returns (logits [B, V] f32 at each row's last real token, cache with
+    ``lengths[slot] = start + valid``)."""
     B, C = input_ids.shape
     L, P, KH, page, D = cache.k.shape
     NB = block_tables.shape[1]
@@ -1240,10 +949,19 @@ def prefill_chunk_paged(
     start: jnp.ndarray,  # scalar int32 — tokens already written for this slot
     valid: jnp.ndarray,  # scalar int32 — real (non-pad) tokens in this chunk
 ) -> tuple[jnp.ndarray, PagedKVCache]:
-    """Paged :func:`prefill_chunk`: one chunk of one long prompt extends the
-    slot's page chain.  Same forward as the contiguous path over the gathered
-    logical row; write-back covers only the blocks overlapping
-    ``[start, start+C)`` (earlier blocks may be shared prefix pages)."""
+    """Extend one slot's page chain by a chunk of prompt tokens.
+
+    The disaggregation primitive (SURVEY.md §7 hard part (c)): instead of one
+    monolithic prefill call that stalls every live decode stream for its full
+    duration, the engine splits long prompts into fixed-size chunks and
+    interleaves one chunk per decode tick — the decode head-of-line delay is
+    bounded by a chunk, not the prompt.  ``slot``/``start``/``valid`` are
+    traced scalars, so one compiled program serves every chunk position of
+    every request.  Write-back covers only the blocks overlapping
+    ``[start, start+C)`` (earlier blocks may be shared prefix pages).
+
+    Returns (logits [1, V] f32 at chunk index ``valid-1``, cache with
+    ``lengths[slot] = start + valid``).  Only the final chunk's logits are used."""
     B, C = input_ids.shape
     L, P, KH, page, D = cache.k.shape
     NB = block_table.shape[0]
@@ -1309,15 +1027,14 @@ def decode_step_paged(
     active: Optional[jnp.ndarray] = None,  # [B] bool; inactive slots are frozen
     attn_fp8: bool = False,  # static: fp8 in-dot attention (requires fp8 pool)
 ) -> tuple[jnp.ndarray, PagedKVCache]:
-    """Paged :func:`decode_step`: one autoregressive step for every active
-    slot against the page pool -> (logits [B,V] f32, cache).
+    """One autoregressive step for every active slot against the page pool
+    -> (logits [B,V] f32, cache).
 
     Per layer the step writes one ``[KH, D]`` row per slot into
     ``block_table[b, pos // page]`` at offset ``pos % page`` and reads the
     pages the slot's query can see.  Inactive rows and rows whose position
-    has run past their allocation write NOTHING — unlike the contiguous
-    path's harmless garbage writes, a paged garbage write could land in a
-    page since re-assigned to another request, so this is part of the
+    has run past their allocation write NOTHING: a garbage write could land
+    in a page since re-assigned to another request, so this is part of the
     correctness contract on both paths below.
 
     Which path is :func:`~..ops.attention.paged_decode_kv_path`'s answer,
@@ -1334,11 +1051,10 @@ def decode_step_paged(
       layer: 5.5 ms of a 17.8 ms step at 7B widths (PERF.md section 5).
     - ``"xla"`` (the CPU; toy shapes; ``attn_fp8``): a per-row scatter with
       ``mode="drop"`` (the P sentinel drops) and
-      :func:`~..ops.attention.paged_gqa_decode_attention` — chunked at page
-      granularity with the contiguous ``kv_chunk`` path's loop bounds and
-      online-softmax discipline, so bit-identical to the legacy layout for
-      mirrored pool contents (tests/test_kv_paging.py).  The kernel is tested
-      against this form (tests/test_paged_decode_kernel.py)."""
+      :func:`~..ops.attention.paged_gqa_decode_attention` — an online
+      softmax over the live pages (tests/test_kv_paging.py compares it with
+      plain masked attention).  The kernel is tested against this form
+      (tests/test_paged_decode_kernel.py)."""
     B = tokens.shape[0]
     L, P, KH, page, D = cache.k.shape
     NB = block_tables.shape[1]
@@ -1443,7 +1159,7 @@ def _tree_qkv(cfg: DecoderConfig, p: Params, h: jnp.ndarray, cos, sin):
     351 -> 702 on a seq=2 mesh; the root cause of the old engine-level
     greedy-equivalence xfail).  A <= 32-wide dim is not worth sequence-
     sharding anyway, so the tree forward keeps it unannotated/replicated,
-    exactly like :func:`decode_step`'s Sq=1."""
+    exactly like :func:`decode_step_paged`'s Sq=1."""
     B, T, _ = h.shape
     H, KH, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = qeinsum("bse,eo->bso", h, p["wq"], cfg.dtype)
@@ -1459,110 +1175,6 @@ def _tree_qkv(cfg: DecoderConfig, p: Params, h: jnp.ndarray, cos, sin):
     return q, k, v
 
 
-def _verify_tree_forward(
-    params: Params,
-    cfg: DecoderConfig,
-    tree: jnp.ndarray,  # [B, T] flat tree tokens (col 0 = root/input token)
-    lengths: jnp.ndarray,  # [B] valid cache tokens per row
-    k_rows: jnp.ndarray,  # [L, B, KH, S, D] logical cache rows (read-only)
-    v_rows: jnp.ndarray,
-    depths: jnp.ndarray,  # [T] int32 node depth (root = 0)
-    anc_mask: jnp.ndarray,  # [T, T] bool — anc_mask[t, u]: u ancestor-or-self of t
-):
-    """Shared body of the tree-verify step: one forward over every tree node.
-
-    Node t takes absolute position ``lengths[b] + depths[t]`` (RoPE matches
-    what sequential decode would use), attends to the VERIFIED prefix
-    (cache positions < lengths — the cache is never written here) plus its
-    own root-path ancestors through the tree's freshly-projected K/V, and
-    returns logits for every node plus the per-layer tree K/V stacks the
-    caller commits for the accepted path only.
-
-    The whole forward traces under ``constraints_disabled()``: any logical
-    ``length`` annotation on the tiny tree dim (e.g. :func:`_mlp`'s hidden
-    constraint) lets this jaxlib's SPMD partitioner sequence-shard it when
-    T happens to divide the mesh ``seq`` axis, and that miscompiles the
-    fused speculative tick (observed: the "replicated" input tokens come
-    back summed across the axis, 351 -> 702 on a seq=2 mesh — the root
-    cause of the old engine-level greedy-equivalence xfail).  A <= 32-wide
-    dim gains nothing from sequence sharding; the heavy dims still shard by
-    propagation from the params and cache operands, exactly like
-    :func:`decode_step`'s Sq=1 forward.
-    """
-    from ..parallel.sharding import constraints_disabled
-
-    B, T = tree.shape
-    S = k_rows.shape[3]
-    pos = lengths[:, None] + depths[None, :]  # [B, T] absolute positions
-    pos = jnp.minimum(pos, S - 1)
-    cos_t, sin_t = _rope_tables(cfg, S)
-    cos, sin = cos_t[pos], sin_t[pos]  # [B, T, hd/2]
-    x = _embed(params, cfg, tree)  # [B, T, E]
-    kpos = jnp.arange(S)[None, None, None, :]
-    # cache part: every node sees the verified prefix only (strictly below
-    # lengths — the root's own K/V lives in the tree part, keeping the key
-    # set identical to a plain decode step at the same position)
-    prefix_keep = kpos < lengths[:, None, None, None]  # [B, 1, T, S]
-    prefix_keep = jnp.broadcast_to(prefix_keep, (B, 1, T, S))
-
-    def make_body(window):
-        cache_mask = prefix_keep
-        tree_keep = anc_mask[None, None]  # [1, 1, T, T]
-        if window is not None:
-            cache_mask = cache_mask & (kpos > pos[:, None, :, None] - window)
-            upos = lengths[:, None, None, None] + depths[None, None, None, :]
-            tree_keep = tree_keep & (upos > pos[:, None, :, None] - window)
-        tree_keep = jnp.broadcast_to(tree_keep, (B, 1, T, T))
-        attn_mask = jnp.concatenate([cache_mask, tree_keep], axis=3)
-
-        def body(x, inputs):
-            p, k_row, v_row = inputs  # [B, KH, S, D] cache rows, read-only
-            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = _tree_qkv(cfg, p, h, cos, sin)
-            keys = jnp.concatenate([k_row.astype(k.dtype), k], axis=2)
-            vals = jnp.concatenate([v_row.astype(v.dtype), v], axis=2)
-            o = gqa_dot_product_attention(q, keys, vals, mask=attn_mask)
-            o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
-            x = x + _attn_out(cfg, p, o)
-            h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(cfg, p, h)
-            return x, (k, v)
-
-        return body
-
-    with constraints_disabled():
-        x, (tks, tvs) = _scan_window_split(
-            cfg, make_body, x, (params["layers"], k_rows, v_rows)
-        )
-        x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-        logits = _head_logits(params, cfg, x)  # [B, T, V]
-    return logits.astype(jnp.float32), tks, tvs
-
-
-def verify_tree_step(
-    params: Params,
-    cfg: DecoderConfig,
-    tree: jnp.ndarray,  # [B, T] int32 flat speculation tree (col 0 = input)
-    cache: KVCache,
-    depths: jnp.ndarray,  # [T] int32
-    anc_mask: jnp.ndarray,  # [T, T] bool
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Tree-verify forward against the contiguous slot cache.
-
-    READ-ONLY with respect to the cache: unlike the old linear verify step
-    (which wrote K/V for every candidate and relied on the
-    garbage-beyond-length discipline), the tree step returns the candidate
-    K/V stacks ``(logits [B,T,V], tks, tvs [L,B,KH,T,D])`` and the caller
-    commits ONLY the accepted root-to-leaf path via
-    :func:`commit_tree_path` — the shape of write the paged layout can also
-    express (:func:`commit_tree_path_paged`), which is what lets
-    speculative engines keep ``kv_layout="paged"``.
-    """
-    return _verify_tree_forward(
-        params, cfg, tree, cache.lengths, cache.k, cache.v, depths, anc_mask
-    )
-
-
 def verify_tree_step_paged(
     params: Params,
     cfg: DecoderConfig,
@@ -1572,15 +1184,28 @@ def verify_tree_step_paged(
     depths: jnp.ndarray,
     anc_mask: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Paged :func:`verify_tree_step`: the same read-only tree forward, with
-    the prefix read IN PLACE from the page pool
+    """Tree-verify step: one forward over every node of the speculation tree.
+
+    Node t takes absolute position ``lengths[b] + depths[t]`` (RoPE matches
+    what sequential decode would use), attends to the VERIFIED prefix (cache
+    positions < lengths) plus its own root-path ancestors through the tree's
+    freshly-projected K/V.  READ-ONLY with respect to the cache: returns
+    ``(logits [B,T,V], tks, tvs [L,B,KH,T,D])`` and the caller commits ONLY
+    the accepted root-to-leaf path via :func:`commit_tree_path_paged`.
+
+    The prefix is read IN PLACE from the page pool
     (:func:`~..ops.attention.paged_tree_attention` — one block-table gather
     per logical page inside the online-softmax loop, the decode read's
-    structure with tree-wide queries).  The speculative tick is the paged
-    plane's steady-state decode path, so it must not materialise a dense
+    structure with tree-wide queries).  The speculative tick is a
+    steady-state decode path, so it must not materialise a dense
     [L, B, KH, S, D] copy of every logical row per tick the way the
-    batched-prefill gathers do.  Traces under ``constraints_disabled()``
-    for the same partitioner reason as :func:`_verify_tree_forward`."""
+    batched-prefill gathers do.
+
+    Traces under ``constraints_disabled()``: any logical ``length``
+    annotation on the tiny tree dim (e.g. :func:`_mlp`'s hidden constraint)
+    lets this jaxlib's SPMD partitioner sequence-shard it when T happens to
+    divide the mesh ``seq`` axis, and that miscompiles the fused speculative
+    tick (see :func:`_tree_qkv`)."""
     from ..parallel.sharding import constraints_disabled
 
     B, T = tree.shape
@@ -1628,35 +1253,6 @@ def _gather_tree_path(tks: jnp.ndarray, path_idx: jnp.ndarray) -> jnp.ndarray:
     return jnp.take_along_axis(tks, idx, axis=3)
 
 
-def commit_tree_path(
-    cache: KVCache,
-    tks: jnp.ndarray,  # [L, B, KH, T, D] from verify_tree_step
-    tvs: jnp.ndarray,
-    path_idx: jnp.ndarray,  # [B, C] flat tree ids: root + winning branch
-) -> KVCache:
-    """Write the accepted path's K/V at contiguous positions
-    ``[lengths, lengths + C)`` of each slot row.
-
-    Positions beyond the accepted run receive the rejected remainder of the
-    winning branch — garbage past the new valid length, masked out of every
-    future attention and overwritten when real tokens land there: the exact
-    discipline the contiguous layout already relies on, so no masking is
-    needed here.  ``cache.lengths`` is NOT advanced (the caller sets it to
-    ``lengths + n_new`` once acceptance is known).  Callers must guarantee
-    ``lengths + C <= max_len`` for rows whose acceptance they will take (the
-    engine finishes spec-mode requests ``C-1`` tokens before the cache
-    limit, so live rows always fit)."""
-    pk = _gather_tree_path(tks, path_idx)
-    pv = _gather_tree_path(tvs, path_idx)
-
-    def upd(c, n, s):
-        return jax.lax.dynamic_update_slice(c, n.astype(c.dtype), (0, 0, s, 0))
-
-    k = jax.vmap(upd, in_axes=(1, 1, 0), out_axes=1)(cache.k, pk, cache.lengths)
-    v = jax.vmap(upd, in_axes=(1, 1, 0), out_axes=1)(cache.v, pv, cache.lengths)
-    return KVCache(k=k, v=v, lengths=cache.lengths)
-
-
 def commit_tree_path_paged(
     cache: PagedKVCache,
     tks: jnp.ndarray,  # [L, B, KH, T, D] from verify_tree_step_paged
@@ -1666,11 +1262,13 @@ def commit_tree_path_paged(
     n_commit: jnp.ndarray,  # [B] — tokens of the path to commit (1 + accepted)
     active: jnp.ndarray,  # [B] bool
 ) -> PagedKVCache:
-    """Paged accepted-path commit: a drop-masked ``[B, C]`` scatter through
+    """Accepted-path commit: a drop-masked ``[B, C]`` scatter through
     the block table — position ``lengths + j`` lands in page
     ``block_table[b, (lengths+j) // page]`` at offset ``(lengths+j) % page``.
+    ``cache.lengths`` is NOT advanced (the caller sets it once acceptance is
+    known).
 
-    Unlike the contiguous commit, the paged layout may NOT write garbage:
+    The commit may NOT write garbage:
     a rejected-candidate write beyond the accepted run could land in the
     slot's reservation tail — harmless — but one beyond the reservation
     would alias a page since handed to another request.  So the scatter
@@ -1700,103 +1298,3 @@ def commit_tree_path_paged(
         k = k.at[:, phys_w, :, off, :].set(kj.astype(k.dtype), mode="drop")
         v = v.at[:, phys_w, :, off, :].set(vj.astype(v.dtype), mode="drop")
     return PagedKVCache(k=k, v=v, lengths=lengths)
-
-
-def decode_step(
-    params: Params,
-    cfg: DecoderConfig,
-    tokens: jnp.ndarray,  # [B] int32 — last sampled token per slot
-    cache: KVCache,
-    *,
-    active: Optional[jnp.ndarray] = None,  # [B] bool; inactive slots are frozen
-    kv_chunk: Optional[int] = None,  # static: chunked length-aware KV read
-    attn_fp8: bool = False,  # static: fp8 in-dot attention (needs kv_chunk + fp8 cache)
-) -> tuple[jnp.ndarray, KVCache]:
-    """One autoregressive step for every active slot -> (logits [B,V] f32, cache).
-
-    ``kv_chunk`` (static) switches the attention read to the length-bucketed
-    chunked path (ops/attention.chunked_gqa_decode_attention): only cache
-    chunks up to the batch's maximum valid position are read, instead of the
-    whole allocated ``max_len`` every step — the decode-side analog of the
-    prefill flash kernel's chunked-KV discipline.  Must divide ``max_len``;
-    ``None`` (or a chunk >= ``max_len``) keeps the full-cache read.
-
-    ``attn_fp8`` (static) keeps the fp8 cache operand at storage width
-    through the attention dots (docs/QUANT.md "fp8 in-dot").  Only the
-    chunked read implements the in-dot scheme, so it requires ``kv_chunk``.
-    """
-    B = tokens.shape[0]
-    if active is None:
-        active = jnp.ones((B,), bool)
-    # Freeze slots whose cache is full: dynamic_update_slice would silently clamp the
-    # write onto the last real entry.  The engine layer finishes such requests with
-    # length_limited=True; this guard keeps the cache sound regardless.
-    active = active & (cache.lengths < cache.max_len)
-    positions = jnp.minimum(cache.lengths, cache.max_len - 1)
-    cos_t, sin_t = _rope_tables(cfg, cache.max_len)
-    cos = cos_t[positions][:, None, :]  # [B,1,hd/2] — per-slot position
-    sin = sin_t[positions][:, None, :]
-
-    x = _embed(params, cfg, tokens)[:, None, :]  # [B,1,E]
-    S = cache.max_len
-    if kv_chunk is not None and kv_chunk < S and (kv_chunk <= 0 or S % kv_chunk):
-        raise ValueError(
-            f"kv_chunk={kv_chunk} must divide cache max_len={S} "
-            "(or be None / >= max_len for the full-cache read)"
-        )
-    chunked = kv_chunk is not None and kv_chunk < S
-    if attn_fp8 and not chunked:
-        raise ValueError(
-            "attn_fp8 requires the chunked KV read (set decode_kv_chunk) — "
-            "the full-cache gqa path has no in-dot fp8 scheme"
-        )
-    kpos = jnp.arange(S)[None, :]
-    causal_keep = (kpos <= positions[:, None])[:, None, None, :]  # [B,1,1,S]
-
-    def make_body(window):
-        attn_mask = causal_keep
-        if window is not None:
-            # banded mask over the slot cache: per-slot absolute positions
-            attn_mask = attn_mask & (
-                kpos > (positions[:, None] - window)
-            )[:, None, None, :]
-
-        def body(x, inputs):
-            p, k_cache, v_cache = inputs
-            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q, k, v = _decode_qkv(cfg, p, h, cos, sin)
-            k_cache = _write_cache(k_cache, k, positions)
-            v_cache = _write_cache(v_cache, v, positions)
-            # grouped attention: the multi-GB slot cache is read ONCE per step
-            # instead of being materialized q_per_kv-fold by a head repeat —
-            # the decode path's dominant memory traffic after the weights
-            if chunked:
-                o = chunked_gqa_decode_attention(
-                    q, k_cache, v_cache, positions,
-                    chunk=kv_chunk, active=active, window=window,
-                    fp8_dot=attn_fp8,
-                )  # [B,H,1,D]
-            else:
-                o = gqa_dot_product_attention(q, k_cache, v_cache, mask=attn_mask)  # [B,H,1,D]
-            o = o.transpose(0, 2, 1, 3).reshape(B, 1, -1)
-            x = x + _attn_out(cfg, p, o)
-            h = rms_norm(x, p["mlp_norm"], cfg.rms_norm_eps)
-            x = x + _mlp(cfg, p, h)
-            return x, (k_cache, v_cache)
-
-        return body
-
-    x, (ks, vs) = _scan_window_split(cfg, make_body, x, (params["layers"], cache.k, cache.v))
-    # Inactive (free) slots do get a garbage K/V write at their current `lengths`
-    # position, but their lengths don't advance and every new request's prefill
-    # overwrites the slot from 0 — so it is never read.  Skipping the masking keeps
-    # the decode step a pure scatter (no full-cache select), which matters at
-    # multi-GB cache sizes.
-    new_cache = KVCache(
-        k=ks,
-        v=vs,
-        lengths=jnp.where(active, cache.lengths + 1, cache.lengths),
-    )
-    x = rms_norm(x[:, 0], params["final_norm"], cfg.rms_norm_eps)
-    logits = _head_logits(params, cfg, x)
-    return logits.astype(jnp.float32), new_cache

@@ -89,14 +89,11 @@ class LatentKVCache(NamedTuple):
 _NOT_HERE = "is not implemented for the latent-attention MoE block (models/mla_moe.py)"
 
 
-def check_serving(*, kv_layout="paged", speculative=0, prefix_cache=0, kv_cache_dtype=None,
+def check_serving(*, speculative=0, prefix_cache=0, kv_cache_dtype=None,
                   attn_fp8=False, kv_host_tier=False, quantize=None) -> None:
     """Refuse, with the reason, every serving option this block does not
     implement, before any weight is loaded or program built (the registry and
     the engine both ask)."""
-    if kv_layout != "paged":
-        raise ValueError(f"kv_layout={kv_layout!r}: the contiguous (non-paged) cache {_NOT_HERE}; "
-                         "use kv_layout='paged' with a page size that divides max_seq_len")
     if speculative:
         raise ValueError(f"speculative={speculative}: tree verification (verify_tree_step_paged) {_NOT_HERE}")
     if prefix_cache:

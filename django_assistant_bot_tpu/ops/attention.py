@@ -84,7 +84,7 @@ def gqa_dot_product_attention(
     KV heads directly — no ``repeat(q_per_kv)`` materialization.
 
     On the decode path the repeat is the single biggest memory consumer: a
-    [B, KH, S, D] slot cache repeated to H heads writes+reads q_per_kv x the
+    [B, KH, S, D] view of a slot's keys repeated to H heads writes+reads q_per_kv x the
     cache bytes EVERY step (multi-GB of pure copy traffic at serving shapes).
     Grouping the einsum reads the cache once.
     """
@@ -110,126 +110,6 @@ def gqa_dot_product_attention(
 
 
 @jax.named_scope("attn/kv_read")
-def chunked_gqa_decode_attention(
-    q: jnp.ndarray,  # [B, H, 1, D]
-    k: jnp.ndarray,  # [B, KH, S, D] slot cache, storage dtype (bf16 / fp8)
-    v: jnp.ndarray,  # [B, KH, S, D]
-    positions: jnp.ndarray,  # [B] int32 — absolute position of each slot's query
-    *,
-    chunk: int,
-    active: Optional[jnp.ndarray] = None,  # [B] bool; inactive rows don't widen the read
-    window: Optional[int] = None,
-    fp8_dot: bool = False,
-) -> jnp.ndarray:
-    """Length-aware decode attention: read the slot cache in fixed ``chunk``-wide
-    slices and SKIP every chunk past the batch's maximum valid position.
-
-    The static-shape decode path otherwise reads the whole allocated
-    ``[B, KH, S, D]`` cache every step — at 16k–32k allocated contexts serving
-    short/ragged traffic, most of that bandwidth is spent on invalid positions
-    (PERF.md's byte ledger: the KV read rivals the weights).  Here the chunk
-    count actually read is a *traced* ``fori_loop`` bound derived from
-    ``positions`` — one compiled program for every fill level (the "buckets"
-    are chunk multiples), no dynamic shapes, no recompiles.  Per-slot validity
-    inside the boundary chunk is handled by masking, exactly like the full
-    read.
-
-    Reduced-precision caches dequantize PER CHUNK: the ``astype`` sits on the
-    sliced operand inside the loop body, so XLA reads fp8 from HBM and upcasts
-    in registers/VMEM — never materializing a bf16-sized copy of the cache
-    (the fix for the fp8-KV bandwidth regression, VERDICT r5 #2).
-
-    Numerics: online softmax (flash discipline) with f32 running max/sum/acc —
-    equal to the full-cache softmax up to reduction order (tested to per-dtype
-    tolerance across ragged lengths and chunk boundaries).  A row whose band
-    starts past the first processed chunk self-corrects: its all-masked chunks
-    contribute with ``m = -inf`` and are zeroed by ``alpha = exp(-inf - m_new)``
-    once a live chunk arrives.
-
-    ``fp8_dot`` (docs/QUANT.md "fp8 in-dot"): keep the fp8 cache operand in
-    its storage dtype THROUGH the QK dot instead of upcasting first.  The
-    query is quantized to the cache's fp8 format once, outside the loop, and
-    its per-(kv-head, group) f32 scale multiplies the f32 score partials —
-    the same scale-on-partials discipline as the int4 ``qeinsum`` (the cache
-    side's per-page scale is 1.0 by the storage contract, so only the query
-    scale appears).  The PV dot likewise runs with fp8 probabilities against
-    the fp8 values; the softmax normalizer ``l`` stays computed from the f32
-    probabilities, matching the baseline's discipline.
-    """
-    B, H, Sq, D = q.shape
-    if Sq != 1:
-        raise ValueError(f"decode attention expects Sq=1 queries, got {Sq}")
-    KH = k.shape[1]
-    S = k.shape[2]
-    if S % chunk:
-        raise ValueError(f"chunk={chunk} must divide cache length {S}")
-    G = H // KH
-    scale = D ** -0.5
-    if active is None:
-        active = jnp.ones((B,), bool)
-    qg = q.reshape(B, KH, G, D)
-    if fp8_dot:
-        _check_fp8_dot(k.dtype, "chunked_gqa_decode_attention")
-        # quantize the query once, outside the chunk loop: [B, KH, G, D] fp8
-        # plus a [B, KH, G, 1] f32 scale that rides on the score partials
-        qg_q, qg_s = quantize_fp8(qg, axis=-1, dtype=k.dtype)
-
-    # chunks [lo, hi) cover every active row's valid keys; inactive rows are
-    # excluded so one stale long slot can't widen a short batch's read window
-    act_pos = jnp.where(active, positions, 0)
-    hi = jnp.max(act_pos) // chunk + 1
-    if window is not None:
-        # lowest key any active row may see: its position - window + 1
-        min_pos = jnp.min(jnp.where(active, positions, S))
-        lo = jnp.minimum(jnp.maximum(min_pos - window + 1, 0) // chunk, hi)
-    else:
-        lo = jnp.zeros((), hi.dtype)
-
-    def body(ci, carry):
-        m, l, acc = carry
-        start = ci * chunk
-        k_blk = jax.lax.dynamic_slice(k, (0, 0, start, 0), (B, KH, chunk, D))
-        v_blk = jax.lax.dynamic_slice(v, (0, 0, start, 0), (B, KH, chunk, D))
-        if fp8_dot:
-            # in-dot fp8: both operands stay at storage width through the
-            # MXU; the query's f32 scale multiplies the f32 partials
-            s = jnp.einsum(
-                "bkgd,bksd->bkgs", qg_q, k_blk,
-                preferred_element_type=jnp.float32,
-            ) * (qg_s * scale)  # [B, KH, G, chunk]
-        else:
-            if k_blk.dtype != q.dtype:
-                # per-chunk dequant: a pure convert on the sliced operand,
-                # fused into the dot — the cache streams at its own width
-                k_blk = k_blk.astype(q.dtype)
-                v_blk = v_blk.astype(q.dtype)
-            s = jnp.einsum(
-                "bkgd,bksd->bkgs", qg, k_blk, preferred_element_type=jnp.float32
-            ) * scale  # [B, KH, G, chunk]
-        kpos = start + jnp.arange(chunk)
-        keep = kpos[None, :] <= positions[:, None]  # [B, chunk]
-        if window is not None:
-            keep &= kpos[None, :] > positions[:, None] - window
-        s = jnp.where(keep[:, None, None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = alpha * acc + jnp.einsum(
-            "bkgs,bksd->bkgd", p.astype(v_blk.dtype), v_blk,
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
-
-    m0 = jnp.full((B, KH, G, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, KH, G, 1), jnp.float32)
-    a0 = jnp.zeros((B, KH, G, D), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, a0))
-    out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
-    return out.reshape(B, H, 1, D)
-
-
-@jax.named_scope("attn/kv_read")
 def paged_gqa_decode_attention(
     q: jnp.ndarray,  # [B, H, 1, D]
     k_pool: jnp.ndarray,  # [P, KH, page, D] page pool, storage dtype (bf16 / fp8)
@@ -242,26 +122,38 @@ def paged_gqa_decode_attention(
     window: Optional[int] = None,
     fp8_dot: bool = False,
 ) -> jnp.ndarray:
-    """Block-table variant of :func:`chunked_gqa_decode_attention`: the KV
-    "row" of a slot is a chain of fixed-size pages scattered through a shared
-    pool, resolved one gather per logical block.
+    """Length-aware decode attention over the page pool: the KV "row" of a
+    slot is a chain of fixed-size pages scattered through a shared pool,
+    resolved one gather per logical block, and every block past the batch's
+    maximum valid position is SKIPPED.
 
-    Chunk == page: the loop structure, masking, and online-softmax state are
-    EXACTLY :func:`chunked_gqa_decode_attention`'s with ``chunk = page`` — so
-    for pools whose pages mirror a contiguous cache's chunks the result is
-    bit-identical (the byte-identity contract tests/test_kv_paging.py pins).
-    Logical blocks past a row's allocation gather a clamped page whose keys
-    are masked out (scores pinned to ``NEG_INF`` -> exact zero contribution,
-    the same discipline the contiguous path applies to garbage positions).
+    The count of blocks read is a *traced* ``fori_loop`` bound derived from
+    ``positions`` — one compiled program for every fill level, no dynamic
+    shapes, no recompiles; inactive rows are excluded so one stale long slot
+    cannot widen a short batch's read.  Per-slot validity inside the boundary
+    page is handled by masking.  Logical blocks past a row's allocation
+    gather a clamped page whose keys are masked out (scores pinned to
+    ``NEG_INF`` -> exact zero contribution).
 
     Reduced-precision pools dequantize PER PAGE: the ``astype`` sits on the
-    gathered operand, so the pool streams from HBM at its own width — same
-    placement as the contiguous path's per-chunk dequant.
+    gathered operand, so the pool streams from HBM at its own width and no
+    bf16-sized copy of the cache is ever made.
 
-    ``fp8_dot``: in-dot fp8 compute, exactly the contiguous path's scheme —
-    the query is quantized to the pool's fp8 format once outside the page
-    loop and its f32 scale multiplies the f32 score partials (per-page pool
-    scale is 1.0 by the storage contract); the PV dot runs fp8 x fp8.
+    Numerics: online softmax (flash discipline) with f32 running max/sum/acc —
+    equal to the full softmax up to reduction order (tests/test_kv_paging.py,
+    tests/test_longctx_decode.py: per-dtype tolerance across ragged lengths
+    and page boundaries).  A row whose band starts past the first processed
+    page self-corrects: its all-masked pages contribute with ``m = -inf`` and
+    are zeroed by ``alpha = exp(-inf - m_new)`` once a live page arrives.
+
+    ``fp8_dot`` (docs/QUANT.md "fp8 in-dot"): keep the fp8 pool operand in its
+    storage dtype THROUGH the QK dot instead of upcasting first.  The query is
+    quantized to the pool's fp8 format once, outside the page loop, and its
+    per-(kv-head, group) f32 scale multiplies the f32 score partials — the
+    same scale-on-partials discipline as the int4 ``qeinsum`` (the per-page
+    pool scale is 1.0 by the storage contract, so only the query scale
+    appears).  The PV dot likewise runs fp8 x fp8; the softmax normalizer
+    ``l`` stays computed from the f32 probabilities.
     ``paged_tree_attention`` deliberately keeps the dequant read: the verify
     forward is one tick amortized over K+1 tokens, so its attention dot is
     not the bandwidth bottleneck the per-step decode dot is.

@@ -207,20 +207,6 @@ KV_CACHE_DTYPES = {
 
 
 @dataclasses.dataclass
-class _Prefix:
-    """One cached prompt prefix: post-RoPE K/V at absolute positions [0, pb).
-
-    ``length`` is the true prefix token count; ``pb`` the padded bucket the
-    device tensors carry ([L, KH, pb, D] each) — the garbage tail [length, pb)
-    is overwritten or masked by the consuming suffix prefill."""
-
-    pk: Any
-    pv: Any
-    length: int
-    pb: int
-
-
-@dataclasses.dataclass
 class _HostHit:
     """A prefix found in the HOST tier (the HBM registry missed).  Admission
     allocates fresh pages, uploads the spilled K/V into them ahead of the
@@ -340,11 +326,9 @@ class GenerationEngine:
         spec_width: int = 4,
         spec_probe_every: int = 64,
         spec_explore_every: int = 32,
-        decode_kv_chunk: Optional[int] = 0,
         prefill_piggyback: bool = True,
         prefill_wave: int = 0,
         attn_fp8: bool = False,
-        kv_layout: str = "paged",
         kv_page_size: int = 0,
         kv_pages: int = 0,
         kv_host_bytes: int = 0,
@@ -394,9 +378,9 @@ class GenerationEngine:
         # picked once, here; every device program below goes through it
         self._model = module_for(cfg)
         check = getattr(self._model, "check_serving", None)
-        if check is not None:  # a block that implements the paged plane only
+        if check is not None:  # a block that refuses what it does not implement
             check(
-                kv_layout=kv_layout, speculative=speculative, prefix_cache=prefix_cache_size,
+                speculative=speculative, prefix_cache=prefix_cache_size,
                 kv_cache_dtype=kv_cache_dtype, attn_fp8=attn_fp8,
                 kv_host_tier=bool(int(kv_host_bytes) > 0 or kv_spill_dir),
             )
@@ -519,31 +503,23 @@ class GenerationEngine:
         self.spec_skipped_accept = 0  # plain ticks forced by the controller
         self._spec_probe_every = max(1, int(spec_probe_every))
         self._spec_explore_every = max(1, int(spec_explore_every))
-        # Prefix KV cache: K/V of shared prompt prefixes (system + packed RAG
-        # context) are kept on device and re-inserted into slots instead of
-        # being re-prefilled — the reference re-sends and recomputes that
-        # context EVERY turn (assistant/bot/services/context_service/steps/
-        # final_prompt.py:14).  LRU over at most `prefix_cache_size` prefixes
-        # of >= `prefix_min_tokens` tokens; 0 disables the path (and its
-        # warmup compiles).
+        # Prefix KV cache: the pages holding shared prompt prefixes (system +
+        # packed RAG context) stay registered in the page pool and are shared,
+        # refcounted, instead of being re-prefilled — the reference re-sends
+        # and recomputes that context EVERY turn (assistant/bot/services/
+        # context_service/steps/final_prompt.py:14).  LRU over at most
+        # `prefix_cache_size` prefixes of >= `prefix_min_tokens` tokens; 0
+        # disables the path (and its warmup compiles).
         self.prefix_cache_size = max(0, int(prefix_cache_size))
         self.prefix_min_tokens = max(1, int(prefix_min_tokens))
-        # Hard HBM budget for pinned prefix K/V: entries evict (LRU) until the
-        # total fits.  Without it, long shared contexts on a deep model pin
-        # multi-GB of cache next to the weights (e.g. 8B/32L/8KV/128D bf16 at
-        # pb=8192 is ~1 GB per entry).
+        # Hard HBM budget for shared prefix pages: entries evict (LRU) until
+        # the total fits.  Without it, long shared contexts on a deep model
+        # pin multi-GB of cache next to the weights (e.g. 8B/32L/8KV/128D bf16
+        # at 8192 tokens is ~1 GB per entry).
         self.prefix_cache_max_bytes = int(prefix_cache_max_bytes)
-        self._prefix_lru: "collections.OrderedDict[tuple, _Prefix]" = (
-            collections.OrderedDict()
-        )
-        self._prefix_bytes = 0
         self.prefix_hits = 0
         self.prefix_misses = 0
-        # Mesh-scoped serving (TP/DP): the KV cache shards over the mesh (kv_heads →
-        # `model`, slots → `data` — llama.CACHE_AXES) and every device step is jit'd
-        # with explicit cache out_shardings so donation updates shards in place.
-        # Without it a v5e-8 would hold 8 *replicas* of a multi-GB cache.
-        # Reduced-precision slot cache: "fp8" halves KV bytes (the dominant
+        # Reduced-precision page pool: "fp8" halves KV bytes (the dominant
         # HBM consumer after the weights at long context) — K/V convert to
         # fp8 at cache-write and upcast inside the attention dot at read.
         # Lossy (~2 significand bits): opt-in per model.
@@ -553,39 +529,15 @@ class GenerationEngine:
                 f"expected one of {sorted(k for k in KV_CACHE_DTYPES if k)}"
             )
         self.kv_cache_dtype = KV_CACHE_DTYPES[kv_cache_dtype]
-        # Length-aware decode attention: read the slot cache in `decode_kv_chunk`
-        # -wide slices and skip chunks past the batch's max valid position
-        # (models/llama.decode_step kv_chunk -> ops/attention.
-        # chunked_gqa_decode_attention).  0 = auto (largest of 512/256/128 that
-        # divides max_seq_len, when that leaves >= 2 chunks); None disables —
-        # the full-cache read.  The per-tick fraction actually read is tracked
-        # host-side and reported as ``kv_read_frac`` in :meth:`tick_stats`.
-        self.decode_kv_chunk = self._resolve_kv_chunk(decode_kv_chunk)
+        # the share of a slot's pages the decode read covers, per tick, tracked
+        # host-side and reported as ``kv_read_frac`` in :meth:`tick_stats`
         self._kv_frac_sum = 0.0
         # --- paged KV memory plane (docs/KV_PAGING.md) ------------------------
-        # "paged" (default): the KV cache is a fixed pool of fixed-size pages
-        # plus per-slot block tables — requests reserve only
-        # ceil((prompt + max_tokens) / page) pages, common prompt prefixes
-        # share pages refcounted (copy-on-write at the boundary page), and
-        # admission sheds on KV pressure.  "legacy" keeps the contiguous
-        # [max_slots, max_seq_len] layout — the rollback / bench-A/B flag.
-        # Paged decode is bit-identical to legacy-with-chunked-read (the page
-        # IS the chunk), asserted in tests/test_kv_paging.py.
-        if kv_layout not in ("paged", "legacy"):
-            raise ValueError(
-                f"unknown kv_layout {kv_layout!r}; expected 'paged' or 'legacy'"
-            )
-        # what the config ASKED for — the non-dividing-context fallback below
-        # may silently demote paged to legacy, and kv_stats() reports
-        # requested vs effective so operators can see a replica running the
-        # legacy plane without grepping boot logs.  (Speculative engines run
-        # paged natively since the tree-verify rewrite: the accepted path
-        # commits through the block table — commit_tree_path_paged.)
-        self.kv_layout_requested = kv_layout
-        self.paged = kv_layout == "paged"
-        self.kv_page_size = 0
-        self._kv_blocks = 0
-        self._kv_pool = None
+        # The KV cache is a fixed pool of fixed-size pages plus per-slot block
+        # tables — requests reserve only ceil((prompt + max_tokens) / page)
+        # pages, common prompt prefixes share pages refcounted (copy-on-write
+        # at the boundary page), and admission sheds on KV pressure.  The
+        # decode read skips every page past the batch's longest live position.
         self._kv_host = None
         # host-tier restore bookkeeping: counters + a bounded window of
         # restore DISPATCH times (host fetch + upload issue — the async
@@ -597,86 +549,70 @@ class GenerationEngine:
         # fleet prefix listener (router-owned registry): tier-transition
         # events forward here AFTER the engine's own flight recording
         self._prefix_listener: Optional[Callable[..., None]] = None
-        if self.paged:
-            page = int(kv_page_size) or self.decode_kv_chunk or 0
-            if not page:
-                # decode_kv_chunk disabled: pick the largest page that still
-                # divides the context into >= 2 pages (the paged read is
-                # inherently page-chunked — there is no "full read" layout)
-                for c in (512, 256, 128, 64, 32, 16, 8):
-                    if self.max_seq_len % c == 0 and self.max_seq_len // c >= 2:
-                        page = c
-                        break
-            if not page or self.max_seq_len % page or self.max_seq_len // page < 2:
-                logger.warning(
-                    "kv_layout='paged' needs a page size dividing "
-                    "max_seq_len=%d into >= 2 pages (got %s); falling back to "
-                    "the legacy slot cache",
-                    self.max_seq_len,
-                    page or None,
-                )
-                if check is not None:
-                    check(kv_layout="legacy")
-                self.paged = False
-            else:
-                self.kv_page_size = page
-                self._kv_blocks = self.max_seq_len // page
-                n_pages = int(kv_pages) or self.max_slots * self._kv_blocks
-                if n_pages < self._kv_blocks:
-                    raise ValueError(
-                        f"kv_pages={n_pages} cannot hold even one max-length "
-                        f"request ({self._kv_blocks} pages of {page})"
-                    )
-                import jax.numpy as _jnp
+        page = int(kv_page_size)
+        if not page:
+            # the largest page that still divides the context into >= 2 pages
+            for c in (512, 256, 128, 64, 32, 16, 8):
+                if self.max_seq_len % c == 0 and self.max_seq_len // c >= 2:
+                    page = c
+                    break
+        if page <= 0 or self.max_seq_len % page or self.max_seq_len // page < 2:
+            raise ValueError(
+                f"max_seq_len={self.max_seq_len} is not divided into >= 2 pages by "
+                f"kv_page_size={int(kv_page_size)} (0 tries 512, 256, ... 8): choose a "
+                "max_seq_len that is a multiple of 8 and at least 16, or name a "
+                "kv_page_size that divides it"
+            )
+        self.kv_page_size = page
+        self._kv_blocks = self.max_seq_len // page
+        n_pages = int(kv_pages) or self.max_slots * self._kv_blocks
+        if n_pages < self._kv_blocks:
+            raise ValueError(
+                f"kv_pages={n_pages} cannot hold even one max-length "
+                f"request ({self._kv_blocks} pages of {page})"
+            )
+        import os as _os
 
-                from .kv_pool import PageAllocator
+        from .kv_pool import HostKVTier, PageAllocator
 
-                page_bytes = page * self._model.kv_bytes_per_token(
-                    cfg, self.kv_cache_dtype
-                )
-                # --- host KV tier (docs/KV_PAGING.md "Tiered KV") ---------
-                # kv_host_bytes > 0 (or a spill dir) arms the durability
-                # tier: evicted/registered prefixes keep a host-DRAM copy
-                # (then disk), admission restores them into fresh pages ahead
-                # of the suffix prefill, and crash-only _restart re-seeds
-                # warm sessions from here instead of losing them.
-                import os as _os
-
-                from .kv_pool import HostKVTier
-
-                spill_dir = kv_spill_dir or _os.environ.get(
-                    "DABT_KV_SPILL_DIR", ""
-                ).strip() or None
-                host_tier = None
-                if int(kv_host_bytes) > 0 or spill_dir:
-                    host_tier = HostKVTier(
-                        # a spill dir alone gets a small DRAM staging budget
-                        # (entries flow through host DRAM on their way down)
-                        int(kv_host_bytes) or 64 * page_bytes,
-                        page_size=page,
-                        page_bytes=page_bytes,
-                        spill_dir=spill_dir,
-                        name=f"{name}-kv-host",
-                    )
-                self._kv_host = host_tier
-                # the r4 prefix-LRU knobs map straight onto the page pool:
-                # entry count -> registry entries, byte budget -> shared-page
-                # budget, min tokens -> registration threshold
-                self._kv_pool = PageAllocator(
-                    n_pages,
-                    page,
-                    page_bytes=page_bytes,
-                    max_shared_bytes=self.prefix_cache_max_bytes,
-                    max_shared_entries=self.prefix_cache_size,
-                    min_prefix_tokens=self.prefix_min_tokens,
-                    host_tier=host_tier,
-                    writethrough=bool(kv_host_writethrough),
-                )
-                self._kv_pool.bind_spill_fetch(self._fetch_pages_host)
-                self._kv_pool.on_event = self._on_kv_tier_event
-                if host_tier is not None:
-                    host_tier.on_event = self._on_kv_tier_event
-                self._kv_sentinel = n_pages  # block-table "unallocated" marker
+        page_bytes = page * self._model.kv_bytes_per_token(cfg, self.kv_cache_dtype)
+        # --- host KV tier (docs/KV_PAGING.md "Tiered KV") ---------
+        # kv_host_bytes > 0 (or a spill dir) arms the durability tier:
+        # evicted/registered prefixes keep a host-DRAM copy (then disk),
+        # admission restores them into fresh pages ahead of the suffix
+        # prefill, and crash-only _restart re-seeds warm sessions from here
+        # instead of losing them.
+        spill_dir = kv_spill_dir or _os.environ.get("DABT_KV_SPILL_DIR", "").strip() or None
+        host_tier = None
+        if int(kv_host_bytes) > 0 or spill_dir:
+            host_tier = HostKVTier(
+                # a spill dir alone gets a small DRAM staging budget
+                # (entries flow through host DRAM on their way down)
+                int(kv_host_bytes) or 64 * page_bytes,
+                page_size=page,
+                page_bytes=page_bytes,
+                spill_dir=spill_dir,
+                name=f"{name}-kv-host",
+            )
+        self._kv_host = host_tier
+        # the r4 prefix-LRU knobs map straight onto the page pool:
+        # entry count -> registry entries, byte budget -> shared-page
+        # budget, min tokens -> registration threshold
+        self._kv_pool = PageAllocator(
+            n_pages,
+            page,
+            page_bytes=page_bytes,
+            max_shared_bytes=self.prefix_cache_max_bytes,
+            max_shared_entries=self.prefix_cache_size,
+            min_prefix_tokens=self.prefix_min_tokens,
+            host_tier=host_tier,
+            writethrough=bool(kv_host_writethrough),
+        )
+        self._kv_pool.bind_spill_fetch(self._fetch_pages_host)
+        self._kv_pool.on_event = self._on_kv_tier_event
+        if host_tier is not None:
+            host_tier.on_event = self._on_kv_tier_event
+        self._kv_sentinel = n_pages  # block-table "unallocated" marker
         # --- continuous batching: piggybacked chunked prefill ----------------
         # One jitted program runs a bounded prefill chunk for the admitting
         # slot AND the fused decode scan for resident slots per dispatch, so
@@ -684,13 +620,13 @@ class GenerationEngine:
         # Token-identical to the sequential chunk-then-tick path: the chunk
         # consumes no rng, writes only its own slot's pages/rows, and runs
         # before the decode scan inside the program — the same order the
-        # sequential loop executes them.  prefill_piggyback=False is the
-        # one-flag rollback (and the bench A/B off-arm).
+        # sequential loop executes them.  prefill_piggyback=False keeps the
+        # sequential path (one prefill program per bucket to compile, not one
+        # per bucket and tick shape: a.x-k1-ep16 boots with it).
         self.prefill_piggyback = bool(prefill_piggyback)
         # fp8 in-dot attention (docs/QUANT.md): keep the fp8 KV read operand
         # at storage width through the decode attention dots.  Requires an
-        # fp8 cache; the legacy layout additionally needs the chunked read
-        # (the full-cache gqa path has no in-dot scheme).
+        # fp8 cache.
         self.attn_fp8 = bool(attn_fp8)
         if self.attn_fp8:
             import jax.numpy as _jnp
@@ -701,28 +637,18 @@ class GenerationEngine:
                     "attn_fp8=True requires an fp8 KV cache "
                     "(kv_cache_dtype='fp8' or 'fp8_e5m2')"
                 )
-            if not self.paged and not self.decode_kv_chunk:
-                raise ValueError(
-                    "attn_fp8=True on the legacy KV layout requires the "
-                    "chunked decode read (decode_kv_chunk != None)"
-                )
         # Which implementation the decode tick's K/V write and attention read
         # take (docs/KV_PAGING.md "Decode read/write"): "kernel" — the Pallas
         # call that writes one row per slot in place and reads only the pages
-        # the block tables name — on a TPU with the paged layout, "xla"
-        # everywhere else.  A gauge and a boot log line, so a run that took
-        # the plain path on a chip cannot pass for the kernel.
-        self.decode_kv_path = (
-            self._model.decode_kv_path(
-                cfg, self.kv_cache_dtype, self.kv_page_size, fp8_dot=self.attn_fp8
-            )
-            if self.paged
-            else "xla"
+        # the block tables name — on a TPU, "xla" everywhere else.  A gauge
+        # and a boot log line, so a run that took the plain path on a chip
+        # cannot pass for the kernel.
+        self.decode_kv_path = self._model.decode_kv_path(
+            cfg, self.kv_cache_dtype, self.kv_page_size, fp8_dot=self.attn_fp8
         )
         logger.info(
-            "decode K/V path: %s (kv_layout=%s, platform=%s)",
-            self.decode_kv_path, "paged" if self.paged else "legacy",
-            jax.default_backend(),
+            "decode K/V path: %s (page=%d, platform=%s)",
+            self.decode_kv_path, self.kv_page_size, jax.default_backend(),
         )
         # ... and the held experts of an expert-parallel rank (ops/moe.py):
         # "kernel" reads only the experts a token landed on, where they lie;
@@ -735,25 +661,24 @@ class GenerationEngine:
         # Admission-controlled scheduling (serving/scheduler.py): when present,
         # submit() runs its admission test (bounded queue, estimated wait) and
         # _admit pulls requests in weighted-fair-share order instead of FIFO.
-        # None = the legacy unbounded FIFO path (kept as the baseline the
-        # overload bench compares against).
+        # None = the unbounded FIFO path (what an engine built without a
+        # registry gets, e.g. in tests).
         self.scheduler = scheduler
         if scheduler is not None:
             scheduler.bind_slots(max_slots)
-            if self._kv_pool is not None:
-                # KV-pressure admission: the scheduler compares a request's
-                # projected page demand against the pool's obtainable pages
-                # (free + evictable cached prefixes) minus what the queue has
-                # already reserved — shedding with its own 429 reason instead
-                # of queueing work the pool cannot place (docs/SCHEDULING.md)
-                scheduler.bind_kv(
-                    self._kv_pool.available, self._kv_pool.n_pages
-                )
-                if self._kv_host is not None:
-                    # host/disk-tier gauges ride in the scheduler's stats()
-                    # block so operators (and the autoscaler) read pool
-                    # pressure and warm-tier depth side by side
-                    scheduler.bind_kv_tier(self._kv_host.stats)
+            # KV-pressure admission: the scheduler compares a request's
+            # projected page demand against the pool's obtainable pages
+            # (free + evictable cached prefixes) minus what the queue has
+            # already reserved — shedding with its own 429 reason instead
+            # of queueing work the pool cannot place (docs/SCHEDULING.md)
+            scheduler.bind_kv(
+                self._kv_pool.available, self._kv_pool.n_pages
+            )
+            if self._kv_host is not None:
+                # host/disk-tier gauges ride in the scheduler's stats()
+                # block so operators (and the autoscaler) read pool
+                # pressure and warm-tier depth side by side
+                scheduler.bind_kv_tier(self._kv_host.stats)
             if self.obs is not None:
                 # predictive admission (docs/AUTOSCALING.md): once warm, the
                 # obs plane's queue-wait histogram floors the estimated-wait
@@ -807,14 +732,14 @@ class GenerationEngine:
         # the engine thread returns to device work (engine-thread-only state)
         self._stream_notify: set = set()
         self.mesh = mesh
-        if mesh is not None:
-            self._cache_shardings = (
-                self._model.paged_cache_shardings(cfg, mesh, max_slots)
-                if self.paged
-                else self._model.cache_shardings(cfg, mesh, max_slots)
-            )
-        else:
-            self._cache_shardings = None
+        # Mesh-scoped serving (TP): the page pool shards over the mesh (kv_heads
+        # -> `model`) and every device step is jit'd with explicit cache
+        # out_shardings so donation updates shards in place.
+        self._cache_shardings = (
+            self._model.paged_cache_shardings(cfg, mesh, max_slots)
+            if mesh is not None
+            else None
+        )
         # --- mesh-sliced fleet identity (parallel/slicing.py;
         # docs/MULTICHIP.md) ------------------------------------------------
         # slice_id/release_slice are set by the registry when this replica is
@@ -844,23 +769,19 @@ class GenerationEngine:
         self._slot_epoch = [0] * max_slots
         self._inflight: "collections.deque[_TickRef]" = collections.deque()
         self._cache = self._fresh_cache()
-        # KV side of the per-slice HBM ledger: the paged pool or legacy slot
-        # cache allocation (fixed for the engine's lifetime — restarts
-        # rebuild the same shape on the same devices)
+        # KV side of the per-slice HBM ledger: the page pool's allocation
+        # (fixed for the engine's lifetime — restarts rebuild the same shape
+        # on the same devices)
         self.hbm_kv_bytes = _resident_bytes(self._cache)
-        # per-slot block tables (host-owned, paged layout): logical block ->
-        # physical page, with n_pages as the "unallocated" sentinel.  Uploaded
-        # lazily like the sampling arrays (committed replicated array, re-sent
-        # only when admissions/frees change it) — NOT part of the donated
-        # cache chain, so host edits never race a device step.
-        if self.paged:
-            self._slot_pages: List[List[int]] = [[] for _ in range(max_slots)]
-            self._block_tables = np.full(
-                (max_slots, self._kv_blocks), self._kv_sentinel, np.int32
-            )
-        else:
-            self._slot_pages = []
-            self._block_tables = np.zeros((1, 1), np.int32)  # inert legacy stub
+        # per-slot block tables (host-owned): logical block -> physical page,
+        # with n_pages as the "unallocated" sentinel.  Uploaded lazily like
+        # the sampling arrays (committed replicated array, re-sent only when
+        # admissions/frees change it) — NOT part of the donated cache chain,
+        # so host edits never race a device step.
+        self._slot_pages: List[List[int]] = [[] for _ in range(max_slots)]
+        self._block_tables = np.full(
+            (max_slots, self._kv_blocks), self._kv_sentinel, np.int32
+        )
         self._bt_dev = jax.device_put(
             jnp.asarray(self._block_tables),
             _replicated(mesh) if mesh is not None else None,
@@ -912,7 +833,7 @@ class GenerationEngine:
         # continuous-batching program: prefill chunk + decode scan fused into
         # one dispatch.  Speculative engines keep sequential chunking (the
         # spec tick owns the token/history chain the piggyback scan would
-        # fork); the knob is the rollback/A-B flag.
+        # fork).
         self._piggyback_tick = (
             self._make_piggyback_tick()
             if self.prefill_piggyback and not self.speculative
@@ -963,100 +884,61 @@ class GenerationEngine:
 
         self._prefill = jax.jit(_prefill)
         # donate the cache here too: slot insertion is a scatter into HBM, not a copy
-        if self.paged:
-            self._insert = jax.jit(
-                self._model.insert_sequences_paged,
-                donate_argnums=(0,),
-                out_shardings=insert_out,
+        self._insert = jax.jit(
+            self._model.insert_sequences_paged,
+            donate_argnums=(0,),
+            out_shardings=insert_out,
+        )
+
+        def _prefill_chunk_paged(params, ids, cache, bt_row, slot, start, valid):
+            return self._model.prefill_chunk_paged(
+                params, cfg_c, ids, cache, bt_row, slot, start, valid
             )
 
-            def _prefill_chunk_paged(params, ids, cache, bt_row, slot, start, valid):
-                return self._model.prefill_chunk_paged(
-                    params, cfg_c, ids, cache, bt_row, slot, start, valid
-                )
+        self._prefill_chunk = jax.jit(
+            _prefill_chunk_paged, donate_argnums=(2,), out_shardings=chunk_out
+        )
 
-            self._prefill_chunk = jax.jit(
-                _prefill_chunk_paged, donate_argnums=(2,), out_shardings=chunk_out
-            )
-
-            def _prefill_suffix_paged(params, ids, cache, bt, slots, starts, valids):
-                return self._model.prefill_suffix_paged(
-                    params, cfg_c, ids, cache, bt, slots, starts, valids
-                )
-
-            suffix_out = (
-                (_replicated(mesh), self._cache_shardings)
-                if mesh is not None
-                else None
-            )
-            self._prefill_suffix = jax.jit(
-                _prefill_suffix_paged, donate_argnums=(2,), out_shardings=suffix_out
-            )
-            # the allocator's COW primitive: clone the boundary page a prefix
-            # sharer will write its own suffix into
-            self._copy_pages = jax.jit(
-                self._model.copy_pages, donate_argnums=(0,), out_shardings=insert_out
-            )
-            # host-tier spill/restore primitives (docs/KV_PAGING.md "Tiered
-            # KV").  The gather does NOT donate the cache — it is a read-only
-            # device->host copy off the hot path (the spill side); the write
-            # donates like every other cache mutation (the restore side: the
-            # upload is dispatched ahead of the slot's suffix prefill and the
-            # device stream orders them, so admission never blocks on it).
-            def _gather_pages(cache, idx):
-                return (
-                    jnp.take(cache.k, idx, axis=1),
-                    jnp.take(cache.v, idx, axis=1),
-                )
-
-            gather_out = (
-                (_replicated(mesh), _replicated(mesh)) if mesh is not None else None
-            )
-            self._gather_pages = jax.jit(_gather_pages, out_shardings=gather_out)
-
-            def _write_pages(cache, idx, k, v):
-                return self._model.PagedKVCache(
-                    k=cache.k.at[:, idx].set(k.astype(cache.k.dtype)),
-                    v=cache.v.at[:, idx].set(v.astype(cache.v.dtype)),
-                    lengths=cache.lengths,
-                )
-
-            self._write_pages = jax.jit(
-                _write_pages, donate_argnums=(0,), out_shardings=insert_out
-            )
-            self._insert_prefix = self._extract_prefix = None
-        else:
-            self._insert = jax.jit(
-                self._model.insert_sequences, donate_argnums=(0,), out_shardings=insert_out
+        def _prefill_suffix_paged(params, ids, cache, bt, slots, starts, valids):
+            return self._model.prefill_suffix_paged(
+                params, cfg_c, ids, cache, bt, slots, starts, valids
             )
 
-            def _prefill_chunk(params, ids, cache, slot, start, valid):
-                return self._model.prefill_chunk(params, cfg_c, ids, cache, slot, start, valid)
-
-            self._prefill_chunk = jax.jit(
-                _prefill_chunk, donate_argnums=(2,), out_shardings=chunk_out
+        self._prefill_suffix = jax.jit(
+            _prefill_suffix_paged, donate_argnums=(2,), out_shardings=chunk_out
+        )
+        # the allocator's COW primitive: clone the boundary page a prefix
+        # sharer will write its own suffix into
+        self._copy_pages = jax.jit(
+            self._model.copy_pages, donate_argnums=(0,), out_shardings=insert_out
+        )
+        # host-tier spill/restore primitives (docs/KV_PAGING.md "Tiered
+        # KV").  The gather does NOT donate the cache — it is a read-only
+        # device->host copy off the hot path (the spill side); the write
+        # donates like every other cache mutation (the restore side: the
+        # upload is dispatched ahead of the slot's suffix prefill and the
+        # device stream orders them, so admission never blocks on it).
+        def _gather_pages(cache, idx):
+            return (
+                jnp.take(cache.k, idx, axis=1),
+                jnp.take(cache.v, idx, axis=1),
             )
 
-            def _prefill_suffix(params, ids, cache, slots, starts, valids):
-                return self._model.prefill_suffix(params, cfg_c, ids, cache, slots, starts, valids)
+        gather_out = (
+            (_replicated(mesh), _replicated(mesh)) if mesh is not None else None
+        )
+        self._gather_pages = jax.jit(_gather_pages, out_shardings=gather_out)
 
-            if mesh is not None:
-                pfx = self._model.prefix_shardings(cfg, mesh)
-                suffix_out = (_replicated(mesh), self._cache_shardings)
-                extract_out = (pfx, pfx)
-            else:
-                suffix_out = extract_out = None
-            self._prefill_suffix = jax.jit(
-                _prefill_suffix, donate_argnums=(2,), out_shardings=suffix_out
+        def _write_pages(cache, idx, k, v):
+            return self._model.PagedKVCache(
+                k=cache.k.at[:, idx].set(k.astype(cache.k.dtype)),
+                v=cache.v.at[:, idx].set(v.astype(cache.v.dtype)),
+                lengths=cache.lengths,
             )
-            self._insert_prefix = jax.jit(
-                self._model.insert_prefix, donate_argnums=(0,), out_shardings=insert_out
-            )
-            self._extract_prefix = jax.jit(
-                self._model.extract_prefix, static_argnums=(2,), out_shardings=extract_out
-            )
-            self._copy_pages = None
-            self._gather_pages = self._write_pages = None
+
+        self._write_pages = jax.jit(
+            _write_pages, donate_argnums=(0,), out_shardings=insert_out
+        )
 
     def _make_activate(self, json_mode: bool):
         """Build the jitted activation: mask (JSON), sample the first token per
@@ -1099,8 +981,6 @@ class GenerationEngine:
     def _n_tick_aux(self) -> int:
         """How many counter outputs a tick program of this engine's cache kind
         appends (:func:`_take_stats`), from a toy cache's shapes alone."""
-        if not self.paged:
-            return 0
         return len(jax.eval_shape(lambda: _take_stats(self._model.init_paged_cache(self.cfg, 1, 2, 8))[1]))
 
     def _with_aux(self, jitted, n_aux: int):
@@ -1141,8 +1021,6 @@ class GenerationEngine:
 
         cfg_c, top_k_c = self.cfg, self.top_k
         burst_c = int(steps) if steps is not None else self.burst
-        kv_chunk_c = self.decode_kv_chunk
-        paged_c = self.paged
         fp8_c = self.attn_fp8
 
         def tick(params, tokens, cache, active, bt, temps, top_ps, rng,
@@ -1161,16 +1039,10 @@ class GenerationEngine:
                 # skip it.
                 p = jax.lax.optimization_barrier(params) if burst_c > 1 else params
                 rng, sub = jax.random.split(rng)
-                if paged_c:
-                    logits, cache = self._model.decode_step_paged(
-                        p, cfg_c, tokens, cache, bt, active=active,
-                        attn_fp8=fp8_c,
-                    )
-                else:
-                    logits, cache = self._model.decode_step(
-                        p, cfg_c, tokens, cache, active=active,
-                        kv_chunk=kv_chunk_c, attn_fp8=fp8_c,
-                    )
+                logits, cache = self._model.decode_step_paged(
+                    p, cfg_c, tokens, cache, bt, active=active,
+                    attn_fp8=fp8_c,
+                )
                 if json_mode:
                     ok = allowed_tab[fsm_s]  # [B, V]
                     logits = jnp.where(jmask[:, None] & ~ok, NEG_INF, logits)
@@ -1187,7 +1059,7 @@ class GenerationEngine:
                 # No scan wrapper: at flagship (8B) geometry the scanned tick's
                 # compiled scratch is what tips a 16 GB chip into OOM — the
                 # unrolled single step compiles with the same footprint as the
-                # plain decode_step program.
+                # plain decode_step_paged program.
                 carry, tok = body(carry, None)
                 tokens, cache, rng, fsm_s = carry
                 toks = tok[None]
@@ -1232,38 +1104,25 @@ class GenerationEngine:
 
         cfg_c, top_k_c = self.cfg, self.top_k
         burst_c = self.burst
-        kv_chunk_c = self.decode_kv_chunk
-        paged_c = self.paged
         fp8_c = self.attn_fp8
 
         def tick(params, tokens, cache, active, bt, temps, top_ps, rng,
                  c_ids, c_slot, c_start, c_valid):
             # --- the piggybacked prefill chunk (admitting slot only) -------
-            if paged_c:
-                bt_row = jax.lax.dynamic_index_in_dim(bt, c_slot, 0, keepdims=False)
-                _, cache = self._model.prefill_chunk_paged(
-                    params, cfg_c, c_ids, cache, bt_row, c_slot, c_start, c_valid
-                )
-            else:
-                _, cache = self._model.prefill_chunk(
-                    params, cfg_c, c_ids, cache, c_slot, c_start, c_valid
-                )
+            bt_row = jax.lax.dynamic_index_in_dim(bt, c_slot, 0, keepdims=False)
+            _, cache = self._model.prefill_chunk_paged(
+                params, cfg_c, c_ids, cache, bt_row, c_slot, c_start, c_valid
+            )
 
             # --- the fused decode scan (resident slots) --------------------
             def body(carry, _):
                 tokens, cache, rng = carry
                 p = jax.lax.optimization_barrier(params) if burst_c > 1 else params
                 rng, sub = jax.random.split(rng)
-                if paged_c:
-                    logits, cache = self._model.decode_step_paged(
-                        p, cfg_c, tokens, cache, bt, active=active,
-                        attn_fp8=fp8_c,
-                    )
-                else:
-                    logits, cache = self._model.decode_step(
-                        p, cfg_c, tokens, cache, active=active,
-                        kv_chunk=kv_chunk_c, attn_fp8=fp8_c,
-                    )
+                logits, cache = self._model.decode_step_paged(
+                    p, cfg_c, tokens, cache, bt, active=active,
+                    attn_fp8=fp8_c,
+                )
                 nxt = sample_logits(
                     logits, sub, temperature=temps, top_k=top_k_c, top_p=top_ps
                 )
@@ -1344,11 +1203,10 @@ class GenerationEngine:
         """Fused tree-speculative tick for one (width, depth) rung: on-device
         n-gram TREE draft -> one read-only verify forward over every node
         (ancestor-masked) -> longest root-to-leaf acceptance -> accepted-path
-        K/V commit (contiguous write on the legacy layout; drop-masked
-        block-table scatter on the paged plane) -> history/length update —
-        all chained device state (lookahead-compatible; zero host round trips
-        per tick).  See ops/speculative.py for the acceptance semantics and
-        models/llama.verify_tree_step for the forward.
+        K/V commit (a drop-masked block-table scatter) -> history/length
+        update — all chained device state (lookahead-compatible; zero host
+        round trips per tick).  See ops/speculative.py for the acceptance
+        semantics and models/llama.verify_tree_step_paged for the forward.
 
         Spec x fused composition (docs/SPECULATIVE.md): a verify step IS a
         multi-token tick, so ``decode_steps`` scans N whole
@@ -1372,7 +1230,6 @@ class GenerationEngine:
         spec = make_tree_spec(N, K)
         depths_c = jnp.asarray(spec.depths)
         anc_c = jnp.asarray(spec.anc_mask)
-        paged_c = self.paged
 
         def tick(params, tokens, history, cache, bt, active, temps, top_ps, rng):
             def body(carry, _):
@@ -1382,33 +1239,21 @@ class GenerationEngine:
                 p = jax.lax.optimization_barrier(params) if steps_c > 1 else params
                 draft = build_tree_draft(history, cache.lengths, tokens, N, K)
                 tree = flatten_tree(tokens, draft)  # [B, 1 + N*K]
-                if paged_c:
-                    logits, tks, tvs = self._model.verify_tree_step_paged(
-                        p, cfg_c, tree, cache, bt, depths_c, anc_c
-                    )
-                else:
-                    logits, tks, tvs = self._model.verify_tree_step(
-                        p, cfg_c, tree, cache, depths_c, anc_c
-                    )
+                logits, tks, tvs = self._model.verify_tree_step_paged(
+                    p, cfg_c, tree, cache, bt, depths_c, anc_c
+                )
                 out, n_new, bonus, path_idx, rng = accept_tree(
                     logits, tree, spec, rng,
                     temperature=temps, top_k=top_k_c, top_p=top_ps,
                 )
                 n_new = jnp.where(active, n_new, 0)
-                if paged_c:
-                    # accepted-prefix-only commit: everything past the
-                    # accepted run (and every inactive row) drops at the page
-                    # sentinel — a paged garbage write could land in a page
-                    # since handed to another request, so masking is part of
-                    # the contract
-                    cache = self._model.commit_tree_path_paged(
-                        cache, tks, tvs, path_idx, bt, n_new, active
-                    )
-                else:
-                    # contiguous rows tolerate the rejected tail: it sits
-                    # past the new valid length, masked/overwritten like all
-                    # garbage
-                    cache = self._model.commit_tree_path(cache, tks, tvs, path_idx)
+                # accepted-prefix-only commit: everything past the accepted
+                # run (and every inactive row) drops at the page sentinel — a
+                # garbage write could land in a page since handed to another
+                # request, so masking is part of the contract
+                cache = self._model.commit_tree_path_paged(
+                    cache, tks, tvs, path_idx, bt, n_new, active
+                )
                 # persist this step's input token + accepted tokens into the
                 # history at sequence positions lengths..lengths+K+1;
                 # positions beyond the accepted run hold garbage that later
@@ -1457,22 +1302,16 @@ class GenerationEngine:
 
     def _fresh_cache(self):
         dt = self.kv_cache_dtype
-        if self.paged:
-            n_pages, page = self._kv_pool.n_pages, self.kv_page_size
+        n_pages, page = self._kv_pool.n_pages, self.kv_page_size
 
-            def make():
-                return self._model.init_paged_cache(
-                    self.cfg, self.max_slots, n_pages, page, dtype=dt
-                )
-        else:
-            def make():
-                return self._model.init_cache(
-                    self.cfg, self.max_slots, self.max_seq_len, dtype=dt
-                )
+        def make():
+            return self._model.init_paged_cache(
+                self.cfg, self.max_slots, n_pages, page, dtype=dt
+            )
 
         if self._cache_shardings is not None:
-            # Allocate *sharded*: an eager init_cache would materialise the whole
-            # cache on device 0 first — at slice-sized caches that alone overflows
+            # Allocate *sharded*: an eager init would materialise the whole
+            # pool on device 0 first — at slice-sized pools that alone overflows
             # one chip's HBM.
             with self.mesh:
                 return jax.jit(make, out_shardings=self._cache_shardings)()
@@ -1628,15 +1467,13 @@ class GenerationEngine:
             prompt_ids = prompt_ids[-limit:]
             prefix_len = 0  # truncation drops leading tokens — prefix gone
         prefix_len = max(0, min(int(prefix_len), len(prompt_ids) - 1))
-        kv_pages = 0
-        if self.paged:
-            # worst-case page reservation: the whole prompt plus every token
-            # the request may generate, capped at the context.  Reserving up
-            # front means decode can never run out of pages mid-stream — the
-            # pool pressure surfaces at ADMISSION (429), not as a mid-decode
-            # stall.  Prefix sharing only reduces the pages actually taken.
-            demand_tokens = min(len(prompt_ids) + int(max_tokens), self.max_seq_len)
-            kv_pages = -(-demand_tokens // self.kv_page_size)
+        # worst-case page reservation: the whole prompt plus every token
+        # the request may generate, capped at the context.  Reserving up
+        # front means decode can never run out of pages mid-stream — the
+        # pool pressure surfaces at ADMISSION (429), not as a mid-decode
+        # stall.  Prefix sharing only reduces the pages actually taken.
+        demand_tokens = min(len(prompt_ids) + int(max_tokens), self.max_seq_len)
+        kv_pages = -(-demand_tokens // self.kv_page_size)
         admitted = False
         if self.scheduler is not None:
             if deadline_s is None:
@@ -1652,15 +1489,14 @@ class GenerationEngine:
                 raise SchedulerRejected(adm.reason, adm.retry_after_s)
             if adm.clamp_max_tokens is not None:
                 max_tokens = min(max_tokens, adm.clamp_max_tokens)
-                if self.paged:
-                    # the clamp shrinks the worst case; release the difference
-                    demand_tokens = min(
-                        len(prompt_ids) + int(max_tokens), self.max_seq_len
-                    )
-                    new_pages = -(-demand_tokens // self.kv_page_size)
-                    if new_pages < kv_pages:
-                        self.scheduler.release_kv(kv_pages - new_pages)
-                        kv_pages = new_pages
+                # the clamp shrinks the worst case; release the difference
+                demand_tokens = min(
+                    len(prompt_ids) + int(max_tokens), self.max_seq_len
+                )
+                new_pages = -(-demand_tokens // self.kv_page_size)
+                if new_pages < kv_pages:
+                    self.scheduler.release_kv(kv_pages - new_pages)
+                    kv_pages = new_pages
             admitted = True
         now = self._clock()
         fut: Future = Future()
@@ -1867,27 +1703,16 @@ class GenerationEngine:
         """Does this engine's KV plane already hold a usable cached prefix of
         this prompt?  Read-only, LRU-neutral, safe from any thread — the
         router's affinity dispatch asks every replica this.  False whenever
-        prefix caching is off or the layout keeps no registry worth routing
-        for (the legacy LRU is engine-thread-owned; a cross-thread scan is
-        best-effort and swallows the resize race)."""
+        prefix caching is off."""
         if self.prefix_cache_size <= 0 or prefix_len < self.prefix_min_tokens:
             return False
-        if self.paged:
-            if self._kv_pool.holds_prefix(prompt_ids, prefix_len):
-                return True
-            # a host/disk-tier copy is still a reason to route here: the
-            # restore costs an upload, not a prefill
-            return self._kv_host is not None and self._kv_host.holds(
-                prompt_ids, prefix_len
-            )
-        n = len(prompt_ids)
-        try:
-            for key, ent in list(self._prefix_lru.items()):
-                if ent.length < n and tuple(prompt_ids[: ent.length]) == key:
-                    return True
-        except RuntimeError:  # dict resized mid-scan (engine thread won)
-            return False
-        return False
+        if self._kv_pool.holds_prefix(prompt_ids, prefix_len):
+            return True
+        # a host/disk-tier copy is still a reason to route here: the
+        # restore costs an upload, not a prefill
+        return self._kv_host is not None and self._kv_host.holds(
+            prompt_ids, prefix_len
+        )
 
     # ------------------------------------------------------- host KV tier
     @property
@@ -1949,7 +1774,7 @@ class GenerationEngine:
         Takes ``_iter_lock`` so the page gather cannot interleave with a loop
         iteration (the probe_decode discipline); resolves no futures under
         it.  Returns how many entries were newly spilled."""
-        if not self.paged or self._kv_host is None:
+        if self._kv_host is None:
             return 0
         n = 0
         with self._iter_lock:
@@ -1984,7 +1809,7 @@ class GenerationEngine:
         first: a mismatched peer's bytes would reinterpret, not restore.
         Thread-safe (host-tier lock); returns whether the entry stored."""
         tier = self._kv_host
-        if tier is None or not self.paged:
+        if tier is None:
             return False
         key = tuple(int(t) for t in key)
         k = np.asarray(k)
@@ -2157,8 +1982,7 @@ class GenerationEngine:
                 _safe_resolve(s.request.future, exc=err)
                 self._slots[i] = None
                 self._slot_epoch[i] += 1
-            if self.paged:
-                self._free_slot_pages(i)
+            self._free_slot_pages(i)
         self._drain_queue(err)
 
     def _reap_dead_slots(self) -> None:
@@ -2231,39 +2055,28 @@ class GenerationEngine:
         previous turn's registered prefix is a proper prefix of the new prompt
         even though the declared split point moved.  LRU-touches the winner.
 
-        Paged layout: the allocator's registry answers (a
-        :class:`~.kv_pool.SharedPrefix` of physical pages); legacy: the
-        pinned-K/V LRU (:class:`_Prefix`).  Both carry ``.length``."""
+        The allocator's registry answers (a :class:`~.kv_pool.SharedPrefix` of
+        physical pages), then the host tier (a :class:`_HostHit`).  Both carry
+        ``.length``."""
         if self.prefix_cache_size <= 0 or req.prefix_len < self.prefix_min_tokens:
             return None
-        if self.paged:
-            hit = self._kv_pool.lookup(req.prompt_ids, req.prefix_len)
-            hit = self._paged_usable_hit(req, hit)
-            if hit is not None:
-                return hit
-            if self._kv_host is not None:
-                # HBM missed (evicted, or a pre-restart registration): the
-                # host tier may still hold the prefix — admission restores
-                # it into fresh pages instead of re-prefilling.  An HBM hit
-                # always wins over a host hit (no upload, no fresh pages).
-                ent = self._kv_host.lookup(
-                    req.prompt_ids,
-                    req.prefix_len,
-                    min_tokens=self.prefix_min_tokens,
-                )
-                if ent is not None:
-                    return self._paged_usable_hit(req, _HostHit(ent))
-            return None
-        n = len(req.prompt_ids)
-        best_key = None
-        best: Optional[_Prefix] = None
-        for key, ent in self._prefix_lru.items():
-            if ent.length < n and (best is None or ent.length > best.length):
-                if tuple(req.prompt_ids[: ent.length]) == key:
-                    best, best_key = ent, key
-        if best_key is not None:
-            self._prefix_lru.move_to_end(best_key)
-        return best
+        hit = self._kv_pool.lookup(req.prompt_ids, req.prefix_len)
+        hit = self._paged_usable_hit(req, hit)
+        if hit is not None:
+            return hit
+        if self._kv_host is not None:
+            # HBM missed (evicted, or a pre-restart registration): the
+            # host tier may still hold the prefix — admission restores
+            # it into fresh pages instead of re-prefilling.  An HBM hit
+            # always wins over a host hit (no upload, no fresh pages).
+            ent = self._kv_host.lookup(
+                req.prompt_ids,
+                req.prefix_len,
+                min_tokens=self.prefix_min_tokens,
+            )
+            if ent is not None:
+                return self._paged_usable_hit(req, _HostHit(ent))
+        return None
 
     def _paged_usable_hit(self, req: _Request, hit):
         """Reject a registry hit whose bucketed suffix prefill would have to
@@ -2385,7 +2198,7 @@ class GenerationEngine:
         quarantined).  Registered prefix entries keep their own refs, so
         shared pages survive the owner; everything refcount-0 returns to the
         free list for the next admission."""
-        if not self.paged or not self._slot_pages[slot]:
+        if not self._slot_pages[slot]:
             return
         self._kv_pool.decref(self._slot_pages[slot])
         self._slot_pages[slot] = []
@@ -2464,7 +2277,7 @@ class GenerationEngine:
             if n_eff > self.chunk_size and (self._chunking is not None or batch):
                 break  # one chunked prefill at a time; scheduling order preserved
             slot = free[0]
-            if self.paged and not self._paged_admit_slot(slot, req, hit):
+            if not self._paged_admit_slot(slot, req, hit):
                 if hit is not None:
                     # the pinned hit itself may be what eviction needed — drop
                     # it and retry as a full prefill (the entry becomes
@@ -2504,7 +2317,7 @@ class GenerationEngine:
                 ):
                     self._requeue_front(req)
                     break
-                if self.paged and not self._paged_admit_slot(slot, req, hit):
+                if not self._paged_admit_slot(slot, req, hit):
                     self._requeue_front(req)
                     break
             free.pop(0)
@@ -2521,7 +2334,7 @@ class GenerationEngine:
             # Prefix-hit rows prefill only their SUFFIX (bucketed by suffix
             # length) via prefill_suffix; misses take the full-prompt path.
             full_groups: Dict[int, List[tuple[int, _Request]]] = {}
-            suffix_groups: Dict[int, List[tuple[int, _Request, _Prefix]]] = {}
+            suffix_groups: Dict[int, List[tuple[int, _Request, Any]]] = {}
             for slot, req, hit in batch:
                 if hit is not None:
                     b = pick_bucket(
@@ -2553,7 +2366,7 @@ class GenerationEngine:
             admitted = True
         return admitted
 
-    def _count_prefix(self, req: _Request, hit: Optional[_Prefix]) -> None:
+    def _count_prefix(self, req: _Request, hit) -> None:
         if self.prefix_cache_size > 0 and req.prefix_len >= self.prefix_min_tokens:
             if hit is not None:
                 self.prefix_hits += 1
@@ -2572,8 +2385,8 @@ class GenerationEngine:
         racy — a multi-second XLA compile can land mid-measurement (or mid-SLA).
         ``json=True`` additionally builds the token FSM and compiles the
         JSON-constrained activation/tick variants.  Call before :meth:`start`:
-        the zero-length insert writes touch only slot 0's cache row and set its
-        length to 0."""
+        every warm-up write targets the slot / page sentinels and drops on the
+        device."""
         if self._running:
             raise RuntimeError("warmup() must run before start() — the engine "
                                "thread owns the cache once running")
@@ -2594,23 +2407,18 @@ class GenerationEngine:
                     ids = jnp.zeros((bp, bucket), jnp.int32)
                     lengths = jnp.zeros((bp,), jnp.int32)
                     logits, ks, vs = self._prefill(self.params, ids, lengths)
-                    if self.paged:
-                        # sentinel slots + block tables: the compiled scatter
-                        # shapes are exercised, every write drops on device
-                        self._cache = self._insert(
-                            self._cache,
-                            ks,
-                            vs,
-                            lengths,
-                            jnp.full((bp,), self.max_slots, jnp.int32),
-                            jnp.full(
-                                (bp, self._kv_blocks), self._kv_sentinel, jnp.int32
-                            ),
-                        )
-                    else:
-                        self._cache = self._insert(
-                            self._cache, ks, vs, lengths, jnp.zeros((bp,), jnp.int32)
-                        )
+                    # sentinel slots + block tables: the compiled scatter
+                    # shapes are exercised, every write drops on device
+                    self._cache = self._insert(
+                        self._cache,
+                        ks,
+                        vs,
+                        lengths,
+                        jnp.full((bp,), self.max_slots, jnp.int32),
+                        jnp.full(
+                            (bp, self._kv_blocks), self._kv_sentinel, jnp.int32
+                        ),
+                    )
                     # the fused activation program keys on the batch bucket too
                     # — compile it here, discarding results (all rows OOB-drop)
                     self._activate_fn(
@@ -2639,25 +2447,15 @@ class GenerationEngine:
                 # chunked prefill (prompts > chunk_size) has one fixed shape;
                 # unreachable (and not worth compiling) when prompts are
                 # truncated to max_seq_len - 1 <= chunk_size
-                if self.paged:
-                    _, self._cache = self._prefill_chunk(
-                        self.params,
-                        jnp.zeros((1, self.chunk_size), jnp.int32),
-                        self._cache,
-                        jnp.full((self._kv_blocks,), self._kv_sentinel, jnp.int32),
-                        jnp.asarray(0, jnp.int32),
-                        jnp.asarray(0, jnp.int32),
-                        jnp.asarray(0, jnp.int32),
-                    )
-                else:
-                    _, self._cache = self._prefill_chunk(
-                        self.params,
-                        jnp.zeros((1, self.chunk_size), jnp.int32),
-                        self._cache,
-                        jnp.asarray(0, jnp.int32),
-                        jnp.asarray(0, jnp.int32),
-                        jnp.asarray(0, jnp.int32),
-                    )
+                _, self._cache = self._prefill_chunk(
+                    self.params,
+                    jnp.zeros((1, self.chunk_size), jnp.int32),
+                    self._cache,
+                    jnp.full((self._kv_blocks,), self._kv_sentinel, jnp.int32),
+                    jnp.asarray(0, jnp.int32),
+                    jnp.asarray(0, jnp.int32),
+                    jnp.asarray(0, jnp.int32),
+                )
                 if self._piggyback_tick is not None:
                     # the continuous-batching program (chunk + decode scan):
                     # valid=0 drops every chunk write, all-False active
@@ -2678,10 +2476,10 @@ class GenerationEngine:
                             jnp.asarray(0, jnp.int32),
                         )
                     )
-            if self.prefix_cache_size > 0 and self.paged:
-                # paged prefix path: the batched suffix prefill per (batch,
-                # seq) bucket plus the COW page clone — sentinel targets, so
-                # every warmup write drops
+            if self.prefix_cache_size > 0:
+                # prefix path: the batched suffix prefill per (batch, seq)
+                # bucket plus the COW page clone — sentinel targets, so every
+                # warmup write drops
                 for bucket in buckets:
                     for bp in self._batch_buckets():
                         logits, self._cache = self._prefill_suffix(
@@ -2714,37 +2512,6 @@ class GenerationEngine:
                         self._cache = self._write_pages(
                             self._cache, idx, wk, wv
                         )
-            elif self.prefix_cache_size > 0:
-                # prefix-cache path: suffix prefill per (batch, seq) bucket +
-                # the extract/insert copies per prefix bucket.  All warmup
-                # writes land in slot 0 with length 0 — same discipline as the
-                # zero-length inserts above.
-                for bucket in buckets:
-                    for bp in self._batch_buckets():
-                        logits, self._cache = self._prefill_suffix(
-                            self.params,
-                            jnp.zeros((bp, bucket), jnp.int32),
-                            self._cache,
-                            jnp.zeros((bp,), jnp.int32),
-                            jnp.zeros((bp,), jnp.int32),
-                            jnp.zeros((bp,), jnp.int32),
-                        )
-                # every shape _prefix_bucket can produce: the prefill buckets
-                # plus multiples of the largest one up to max_seq_len (each is
-                # a trivial copy kernel — compiles in milliseconds)
-                pbs = set(self.prefill_buckets)
-                step = self.prefill_buckets[-1]
-                pbs.update(
-                    min(m * step, self.max_seq_len)
-                    for m in range(1, -(-self.max_seq_len // step) + 1)
-                )
-                for pb in sorted(pbs):
-                    pk, pv = self._extract_prefix(
-                        self._cache, jnp.asarray(0, jnp.int32), pb
-                    )
-                    self._cache = self._insert_prefix(
-                        self._cache, pk, pv, jnp.asarray(0, jnp.int32)
-                    )
             toks, last, self._cache, self._rng = self._decode_tick(
                 self.params,
                 self._tokens_dev,
@@ -2798,43 +2565,18 @@ class GenerationEngine:
                 )
             jax.block_until_ready(last)
 
-    def _resolve_kv_chunk(self, decode_kv_chunk: Optional[int]) -> Optional[int]:
-        """Concrete decode KV chunk width, or None for the full-cache read.
-
-        0 = auto: the largest of (512, 256, 128) that divides ``max_seq_len``
-        into at least 2 chunks — below that the "chunked" read covers the whole
-        cache anyway and the plain path has one fewer loop."""
-        if decode_kv_chunk is None:
-            return None
-        if decode_kv_chunk == 0:
-            for c in (512, 256, 128):
-                if self.max_seq_len % c == 0 and self.max_seq_len // c >= 2:
-                    return c
-            return None
-        c = int(decode_kv_chunk)
-        if c <= 0 or self.max_seq_len % c or self.max_seq_len // c < 2:
-            raise ValueError(
-                f"decode_kv_chunk={decode_kv_chunk} must divide "
-                f"max_seq_len={self.max_seq_len} into >= 2 chunks "
-                f"(or be 0=auto / None=disabled)"
-            )
-        return c
-
     def _kv_read_frac(self) -> float:
-        """Host-side mirror of the device's chunked-read window for THIS tick:
-        chunks covering the longest live slot / total chunks.  An estimate (a
-        burst advances positions mid-tick; in-flight speculation lags a little),
-        but it tracks the device's traced ``hi`` bound to within one chunk."""
-        c = self.decode_kv_chunk
-        if not c:
-            return 1.0
-        n_chunks = self.max_seq_len // c
+        """Host-side mirror of the device's paged-read window for THIS tick:
+        pages covering the longest live slot / pages of a full context.  An
+        estimate (a burst advances positions mid-tick; in-flight speculation
+        lags a little), but it tracks the device's traced ``hi`` bound to
+        within one page."""
         mx = 0
         for s in self._slots:
             if s is not None:
                 pos = len(s.request.prompt_ids) + len(s.generated)
                 mx = max(mx, min(pos, self.max_seq_len - 1))
-        return (mx // c + 1) / n_chunks
+        return (mx // self.kv_page_size + 1) / self._kv_blocks
 
     def _batch_buckets(self) -> tuple:
         """Prefill batch-dim buckets: {1, 4, prefill_wave} (the wave is
@@ -2859,8 +2601,8 @@ class GenerationEngine:
         """One prefill dispatch for every request admitted this wave.
 
         The batch dim pads to a bucket; pad rows carry zero lengths, PRECEDE the
-        real rows, and alias the first real slot — ``insert_sequences`` scans in
-        row order, so the real row overwrites the pad's zero-length write."""
+        real rows, and carry the ``max_slots`` / page sentinels, so their
+        writes drop on the device."""
         reqs = [r for _, r in batch]
         slots = [s for s, _ in batch]
         B = len(batch)
@@ -2872,12 +2614,7 @@ class GenerationEngine:
             pad = Bp - B
             ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
             lengths = np.zeros((Bp,), np.int32)
-            # pad rows: legacy aliases the first real slot (the insert scan's row
-            # order makes the real row win); paged scatters with drop semantics,
-            # so pads carry the max_slots / page sentinels instead
-            slot_arr = np.full(
-                (Bp,), self.max_slots if self.paged else slots[0], np.int32
-            )
+            slot_arr = np.full((Bp,), self.max_slots, np.int32)
             for j, req in enumerate(reqs):
                 n = len(req.prompt_ids)
                 ids[pad + j, :n] = req.prompt_ids
@@ -2887,22 +2624,17 @@ class GenerationEngine:
                 logits, ks, vs = self._prefill(
                     self.params, jnp.asarray(ids), jnp.asarray(lengths)
                 )
-                if self.paged:
-                    self._cache = self._insert(
-                        self._cache,
-                        ks,
-                        vs,
-                        jnp.asarray(lengths),
-                        jnp.asarray(slot_arr),
-                        jnp.asarray(self._wave_block_tables(slots, pad)),
-                    )
-                else:
-                    self._cache = self._insert(
-                        self._cache, ks, vs, jnp.asarray(lengths), jnp.asarray(slot_arr)
-                    )
+                self._cache = self._insert(
+                    self._cache,
+                    ks,
+                    vs,
+                    jnp.asarray(lengths),
+                    jnp.asarray(slot_arr),
+                    jnp.asarray(self._wave_block_tables(slots, pad)),
+                )
             self._note_wave(reqs, int(lengths.sum()), bucket, Bp)
-            # a miss with a declared prefix: capture its K/V for future requests
-            # (pure device slice, async — admission never blocks on it)
+            # a miss with a declared prefix: register its pages for future
+            # requests (pure refcounting — admission never blocks on it)
             for slot, req in batch:
                 self._maybe_register_prefix(slot, req)
             # activation consumes the FULL [Bp, V] logits so its (eager) sampling
@@ -2920,12 +2652,10 @@ class GenerationEngine:
             req.wave_rows_padded = Bp
 
     def _start_suffix_batch(self, group: List[tuple[int, _Request, Any]]):
-        """Admit a wave of prefix-cache hits: make each slot's cache row carry
-        the prefix K/V — legacy copies the pinned prefix into the slot row,
-        paged already wired the shared pages into the block table at admission
-        — then ONE batched suffix prefill continues all rows from their
-        prefix lengths; the skipped work is exactly the prefix recompute the
-        reference pays every turn."""
+        """Admit a wave of prefix-cache hits: admission already wired the
+        shared pages into each slot's block table, so ONE batched suffix
+        prefill continues all rows from their prefix lengths; the skipped work
+        is exactly the prefix recompute the reference pays every turn."""
         slots = [s for s, _, _ in group]
         reqs = [r for _, r, _ in group]
         hits = [h for _, _, h in group]
@@ -2943,17 +2673,13 @@ class GenerationEngine:
             ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
             starts = np.zeros((Bp,), np.int32)
             valids = np.zeros((Bp,), np.int32)
-            slot_arr = np.full(
-                (Bp,), self.max_slots if self.paged else slots[0], np.int32
-            )
+            slot_arr = np.full((Bp,), self.max_slots, np.int32)
             for j, (req, hit) in enumerate(zip(reqs, hits)):
                 # the bucketed write window [start, start+bucket) must not cross
                 # max_seq_len — dynamic_update_slice would CLAMP the start and
-                # smear the window over the prefix.  Slide the window left instead
-                # (prefill_chunk's final-chunk discipline): the re-fed prefix
-                # tokens recompute to identical K/V at identical positions.
-                # (Paged hits never need the slide: _paged_usable_hit rejects
-                # them, because a slid window would re-write SHARED pages.)
+                # smear the window over the prefix.  _paged_usable_hit rejects
+                # every hit whose window would (a slid window would re-write
+                # SHARED pages), so the min() is a guard that never binds.
                 start = min(hit.length, self.max_seq_len - bucket)
                 chunk = req.prompt_ids[start : start + bucket]
                 ids[pad + j, : len(chunk)] = chunk
@@ -2961,29 +2687,15 @@ class GenerationEngine:
                 valids[pad + j] = len(chunk)
                 slot_arr[pad + j] = slots[j]
             with self._mesh_scope():
-                if self.paged:
-                    logits, self._cache = self._prefill_suffix(
-                        self.params,
-                        jnp.asarray(ids),
-                        self._cache,
-                        jnp.asarray(self._wave_block_tables(slots, pad)),
-                        jnp.asarray(slot_arr),
-                        jnp.asarray(starts),
-                        jnp.asarray(valids),
-                    )
-                else:
-                    for slot, hit in zip(slots, hits):
-                        self._cache = self._insert_prefix(
-                            self._cache, hit.pk, hit.pv, jnp.asarray(slot, jnp.int32)
-                        )
-                    logits, self._cache = self._prefill_suffix(
-                        self.params,
-                        jnp.asarray(ids),
-                        self._cache,
-                        jnp.asarray(slot_arr),
-                        jnp.asarray(starts),
-                        jnp.asarray(valids),
-                    )
+                logits, self._cache = self._prefill_suffix(
+                    self.params,
+                    jnp.asarray(ids),
+                    self._cache,
+                    jnp.asarray(self._wave_block_tables(slots, pad)),
+                    jnp.asarray(slot_arr),
+                    jnp.asarray(starts),
+                    jnp.asarray(valids),
+                )
             self._note_wave(
                 reqs, sum(len(r.prompt_ids) - h.length for r, h in zip(reqs, hits)), bucket, Bp
             )
@@ -2993,78 +2705,36 @@ class GenerationEngine:
                 self._maybe_register_prefix(slot, req)
             self._activate_batch(slots, reqs, logits, pad=pad)
 
-    def _prefix_bucket(self, prefix_len: int) -> int:
-        """Device shape for a cached prefix: the smallest prefill bucket that
-        fits, else the smallest MULTIPLE of the largest bucket that does (never
-        the max_seq_len fallback — at 8B geometry that would pin a full-context
-        ~1 GB K/V copy per entry to save a few hundred tokens of recompute).
-        Capped at max_seq_len; waste is bounded by one bucket of padding."""
-        for b in self.prefill_buckets:
-            if prefix_len <= b:
-                return b
-        step = self.prefill_buckets[-1]
-        return min(-(-prefix_len // step) * step, self.max_seq_len)
-
-    def _prefix_nbytes(self, ent: _Prefix) -> int:
-        try:
-            return int(ent.pk.nbytes) + int(ent.pv.nbytes)
-        except Exception:  # non-array stand-ins in tests
-            return 0
-
     def _maybe_register_prefix(self, slot: int, req: _Request) -> None:
         """After a full prefill of ``slot``, make the request's declared prefix
-        shareable.  Paged: register the pages covering it with the allocator
-        (pure refcounting — no copy, no extra HBM beyond what the request
-        already holds).  Legacy: slice the prefix K/V out of the slot row into
-        the pinned LRU (post-RoPE, positions [0, P))."""
+        shareable: register the pages covering it with the allocator (pure
+        refcounting — no copy, no extra HBM beyond what the request already
+        holds)."""
         if self.prefix_cache_size <= 0 or req.prefix_len < self.prefix_min_tokens:
             return
-        if self.paged:
-            nbp = -(-req.prefix_len // self.kv_page_size)
-            pages = [int(p) for p in self._block_tables[slot, :nbp]]
-            if any(p >= self._kv_sentinel for p in pages):
-                return  # allocation didn't cover the prefix (shouldn't happen)
-            self._kv_pool.register(req.prompt_ids, req.prefix_len, pages)
-            return
-        key = tuple(req.prompt_ids[: req.prefix_len])
-        if key in self._prefix_lru:
-            return
-        pb = self._prefix_bucket(req.prefix_len)
-        with self._mesh_scope():
-            pk, pv = self._extract_prefix(self._cache, jnp.asarray(slot, jnp.int32), pb)
-        ent = _Prefix(pk=pk, pv=pv, length=req.prefix_len, pb=pb)
-        self._prefix_lru[key] = ent
-        self._prefix_bytes += self._prefix_nbytes(ent)
-        while self._prefix_lru and (
-            len(self._prefix_lru) > self.prefix_cache_size
-            or self._prefix_bytes > self.prefix_cache_max_bytes
-        ):
-            _, old = self._prefix_lru.popitem(last=False)
-            self._prefix_bytes -= self._prefix_nbytes(old)
+        nbp = -(-req.prefix_len // self.kv_page_size)
+        pages = [int(p) for p in self._block_tables[slot, :nbp]]
+        if any(p >= self._kv_sentinel for p in pages):
+            return  # allocation didn't cover the prefix (shouldn't happen)
+        self._kv_pool.register(req.prompt_ids, req.prefix_len, pages)
 
-    def _begin_chunked(self, slot: int, req: _Request, prefix: Optional[_Prefix] = None):
+    def _begin_chunked(self, slot: int, req: _Request, prefix=None):
         """Split a long prompt into full-size chunks.  The final chunk *slides left*
         to end exactly at the prompt end (re-feeding a few already-written positions
         — their K/V recompute to identical values) so no chunk ever carries pad
         tokens and no cache write can cross ``max_seq_len``.
 
-        With a cached ``prefix``, its K/V are copied into the slot first and
-        chunking covers only the remainder (starts begin at the prefix length;
-        a sliding final chunk may re-feed a few prefix-covered positions —
-        identical recompute, same as the no-prefix overlap)."""
+        With a cached ``prefix`` (its shared pages already wired into the
+        block table, and the boundary page COW-cloned, by
+        :meth:`_paged_admit_slot`) chunking covers only the remainder: starts
+        begin at the prefix length, and the final chunk never slides into the
+        prefix (remainder > chunk_size)."""
         n = len(req.prompt_ids)
         base = prefix.length if prefix is not None else 0
         c = self.chunk_size
         flat = np.asarray(req.prompt_ids, np.int32)
         starts = list(range(base, n - c, c)) + [n - c]
         ids = np.stack([flat[s : s + c] for s in starts])
-        if prefix is not None and not self.paged:
-            # paged: the shared pages are already wired into the block table
-            # (and the boundary page COW-cloned) by _paged_admit_slot
-            with self._mesh_scope():
-                self._cache = self._insert_prefix(
-                    self._cache, prefix.pk, prefix.pv, jnp.asarray(slot, jnp.int32)
-                )
         req.started_at = self._clock()
         self._chunking = _ChunkedPrefill(
             request=req, slot=slot, ids=ids, starts=starts, n=n
@@ -3082,25 +2752,15 @@ class GenerationEngine:
         j = st.step
         self._note_chunk(st, j)
         with self._mesh_scope():
-            if self.paged:
-                logits, self._cache = self._prefill_chunk(
-                    self.params,
-                    jnp.asarray(st.ids[j : j + 1]),
-                    self._cache,
-                    jnp.asarray(self._block_tables[st.slot]),
-                    jnp.asarray(st.slot, jnp.int32),
-                    jnp.asarray(st.starts[j], jnp.int32),
-                    jnp.asarray(self.chunk_size, jnp.int32),
-                )
-            else:
-                logits, self._cache = self._prefill_chunk(
-                    self.params,
-                    jnp.asarray(st.ids[j : j + 1]),
-                    self._cache,
-                    jnp.asarray(st.slot, jnp.int32),
-                    jnp.asarray(st.starts[j], jnp.int32),
-                    jnp.asarray(self.chunk_size, jnp.int32),
-                )
+            logits, self._cache = self._prefill_chunk(
+                self.params,
+                jnp.asarray(st.ids[j : j + 1]),
+                self._cache,
+                jnp.asarray(self._block_tables[st.slot]),
+                jnp.asarray(st.slot, jnp.int32),
+                jnp.asarray(st.starts[j], jnp.int32),
+                jnp.asarray(self.chunk_size, jnp.int32),
+            )
         st.step += 1
         if st.request.future.cancelled():
             # the consumer vanished mid-prefill: abandon the remaining chunks
@@ -3343,9 +3003,9 @@ class GenerationEngine:
             "block_ms": round(
                 led.seconds("tick_block") / max(1, self._ticks_processed) * 1e3, 3
             ),
-            # average fraction of the allocated KV cache the decode attention
-            # actually read (< 1 whenever live contexts are shorter than the
-            # allocation and the chunked read is on; 1.0 with it disabled)
+            # average fraction of a full context's pages the decode attention
+            # actually read (< 1 whenever live contexts are shorter than
+            # max_seq_len)
             "kv_read_frac": round(self._kv_frac_sum / n, 4)
             if self._ticks_issued
             else 1.0,
@@ -3361,7 +3021,7 @@ class GenerationEngine:
         if self.speculative:
             out.update(self.spec_stats())
         # KV memory plane gauges: pool occupancy, sharing fraction, allocator
-        # eviction/COW counters (paged), or the pinned-prefix footprint (legacy)
+        # eviction/COW counters
         out["kv"] = self.kv_stats()
         moe = self.moe_stats()
         if moe is not None:
@@ -3397,8 +3057,7 @@ class GenerationEngine:
         live), ``json_downgraded_ticks``, ``upload_overlap_frac`` (fraction
         of sampling/block-table upload cycles double-buffered against an
         in-flight tick), and ``weight_bits`` (16/8/4 — the weight format the
-        decode dot is reading).  Same operator pattern as PR 7's
-        ``kv_layout_effective``: the active configuration is a gauge, not a
+        decode dot is reading): the active configuration is a gauge, not a
         boot log line."""
         return {
             "decode_steps": self.decode_steps,
@@ -3496,47 +3155,39 @@ class GenerationEngine:
         return out
 
     def kv_stats(self) -> dict:
-        """KV memory plane snapshot for tick_stats / healthz: layout, pool
-        gauges (``kv_pages_used`` / ``kv_pages_free`` / ``kv_shared_page_frac``
-        and the allocator's eviction/COW counters) when paged; the pinned
-        prefix-LRU footprint when legacy.  Prefix hit/miss counters ride along
-        in both layouts."""
-        out: dict = {"kv_layout": "paged" if self.paged else "legacy"}
-        # requested vs effective: a non-dividing context silently falls back
-        # to the legacy plane at load — surfaced here (tick_stats + /healthz)
-        # instead of only as a boot-log warning.  (Speculative engines no
-        # longer fall back: the tree verify commits through the block table.)
-        out["kv_layout_requested"] = self.kv_layout_requested
-        out["kv_layout_effective"] = out["kv_layout"]
+        """KV memory plane snapshot for tick_stats / healthz: what a cached
+        token is, the pool gauges (``kv_pages_used`` / ``kv_pages_free`` /
+        ``kv_shared_page_frac`` and the allocator's eviction/COW counters),
+        the host tier's restore gauges, and the prefix hit/miss counters."""
         # what a cached token is ("kv": keys and values per KV head; "latent":
         # one latent row read as both) and what it takes over all layers
-        out["kv_cache_kind"] = self._model.KV_KIND
-        out["kv_bytes_per_token"] = int(self._model.kv_bytes_per_token(self.cfg, self.kv_cache_dtype))
-        if self.paged:
-            out.update(self._kv_pool.stats())
-            if self._kv_host is not None:
-                # restore-side gauges (the tier's own spill/disk gauges ride
-                # in through the allocator's stats): counts, in-flight, and
-                # the host-visible restore-dispatch latency percentiles
-                out["kv_restores"] = self.kv_restores
-                out["kv_host_hits"] = self.kv_host_hits
-                out["kv_restores_inflight"] = self._kv_restores_inflight
-                # the engine thread appends concurrently; CPython's deque
-                # raises RuntimeError when a copy races an append, which
-                # must not fail a /metrics scrape mid-restore
-                for _ in range(4):
-                    try:
-                        restore = list(self._restore_s)
-                        break
-                    except RuntimeError:
-                        continue
-                else:
-                    restore = []
-                out["kv_restore_p50_ms"] = self._pctl_ms(restore, 0.50)
-                out["kv_restore_p95_ms"] = self._pctl_ms(restore, 0.95)
-        else:
-            out["prefix_entries"] = len(self._prefix_lru)
-            out["prefix_bytes"] = self._prefix_bytes
+        out: dict = {
+            "kv_cache_kind": self._model.KV_KIND,
+            "kv_bytes_per_token": int(
+                self._model.kv_bytes_per_token(self.cfg, self.kv_cache_dtype)
+            ),
+        }
+        out.update(self._kv_pool.stats())
+        if self._kv_host is not None:
+            # restore-side gauges (the tier's own spill/disk gauges ride
+            # in through the allocator's stats): counts, in-flight, and
+            # the host-visible restore-dispatch latency percentiles
+            out["kv_restores"] = self.kv_restores
+            out["kv_host_hits"] = self.kv_host_hits
+            out["kv_restores_inflight"] = self._kv_restores_inflight
+            # the engine thread appends concurrently; CPython's deque
+            # raises RuntimeError when a copy races an append, which
+            # must not fail a /metrics scrape mid-restore
+            for _ in range(4):
+                try:
+                    restore = list(self._restore_s)
+                    break
+                except RuntimeError:
+                    continue
+            else:
+                restore = []
+            out["kv_restore_p50_ms"] = self._pctl_ms(restore, 0.50)
+            out["kv_restore_p95_ms"] = self._pctl_ms(restore, 0.95)
         out["prefix_hits"] = self.prefix_hits
         out["prefix_misses"] = self.prefix_misses
         return out
@@ -3611,7 +3262,7 @@ class GenerationEngine:
         self._cache = self._cache._replace(lengths=lens)
 
     def _probe_decode_locked(self, iters: int, fill_len: Optional[int]) -> float:
-        if fill_len is not None and self.paged:
+        if fill_len is not None:
             # give every slot a DISTINCT round-robin page chain so the probe's
             # block-table gathers stream the same page spread real traffic at
             # this fill would (sentinel rows would collapse every gather onto
@@ -3646,14 +3297,13 @@ class GenerationEngine:
             if fill_len is not None:
                 # every slot is free (probe requires an idle engine): stale
                 # lengths carry no meaning, and zeroing keeps the next live
-                # batch's chunked read window minimal.  In a finally so a
+                # batch's paged read window minimal.  In a finally so a
                 # mid-probe dispatch error can't leave phantom fill lengths
                 # widening every later batch's read window.
                 self._set_cache_lengths(np.zeros((self.max_slots,), np.int32))
-                if self.paged:
-                    self._block_tables[:] = self._kv_sentinel
-                    self._bt_dirty = True
-                    self._refresh_sampling()
+                self._block_tables[:] = self._kv_sentinel
+                self._bt_dirty = True
+                self._refresh_sampling()
 
     def _probe_decode_timed(self, iters: int, active) -> float:
         with self._mesh_scope():
@@ -3695,8 +3345,7 @@ class GenerationEngine:
         (same lock discipline as :meth:`probe_decode`): seconds per plain
         tick, seconds per speculative tick for every (width, depth) rung,
         the cost ratios, and each rung's breakeven accept rate.  Feeds the
-        controller's cost table as a side effect — the bench's tick-cost
-        sweep and the honest breakeven report both come from here."""
+        controller's cost table as a side effect."""
         if not self.speculative:
             raise RuntimeError("probe_spec requires a speculative engine")
         deadline = self._clock() + 10.0
@@ -4045,10 +3694,10 @@ class GenerationEngine:
             return True
         if len(s.generated) >= s.request.max_tokens:
             return True
-        # cache full -> decode_step freezes the slot; finish as length-limited.
+        # cache full -> decode_step_paged freezes the slot; finish as length-limited.
         # Speculative mode leaves N*(K+1)-1 tokens of headroom: one tick's N
         # scanned verify steps commit up to N*(K+1) accepted-path positions,
-        # so live rows must always fit them (commit_tree_path docstring) —
+        # so live rows must always fit them (commit_tree_path_paged docstring) —
         # those last tokens would have been length_limited a tick later
         # anyway.  (N=1 reduces to the historical K-token headroom.)
         headroom = (
@@ -4266,32 +3915,27 @@ class GenerationEngine:
             self._slot_epoch[i] += 1
         self._json[:] = False
         self._sampling_dirty = True
-        # cached prefixes were sliced out of the (possibly poisoned) cache
-        # lineage — drop them with the rest of the device state
-        self._prefix_lru.clear()
-        self._prefix_bytes = 0
-        if self.paged:
-            # crash-only discipline for the page plane too: every page back on
-            # the free list, every block table unallocated, the registry
-            # emptied (its pages were part of the poisoned lineage).  The
-            # device pool itself is rebuilt below with the rest.  The HOST
-            # tier deliberately survives: its numpy copies were taken from a
-            # healthy pool (write-through at registration), so warmed
-            # sessions re-seed the fresh pool via restore on their next hit
-            # instead of paying a cold prefill — the durability contract
-            # docs/KV_PAGING.md "Tiered KV" chaos-tests.
-            self._kv_pool.reset()
-            self._kv_restores_inflight = 0
-            self._slot_pages = [[] for _ in range(self.max_slots)]
-            self._block_tables[:] = self._kv_sentinel
-            self._bt_dirty = True
-            if self.obs is not None and self._kv_host is not None:
-                hs = self._kv_host.stats()
-                self.obs.flight.record(
-                    "kv_tier_survives_restart",
-                    host_entries=hs["kv_host_entries"],
-                    disk_entries=hs["kv_disk_entries"],
-                )
+        # crash-only discipline for the page plane too: every page back on
+        # the free list, every block table unallocated, the registry
+        # emptied (its pages were part of the poisoned lineage).  The
+        # device pool itself is rebuilt below with the rest.  The HOST
+        # tier deliberately survives: its numpy copies were taken from a
+        # healthy pool (write-through at registration), so warmed
+        # sessions re-seed the fresh pool via restore on their next hit
+        # instead of paying a cold prefill — the durability contract
+        # docs/KV_PAGING.md "Tiered KV" chaos-tests.
+        self._kv_pool.reset()
+        self._kv_restores_inflight = 0
+        self._slot_pages = [[] for _ in range(self.max_slots)]
+        self._block_tables[:] = self._kv_sentinel
+        self._bt_dirty = True
+        if self.obs is not None and self._kv_host is not None:
+            hs = self._kv_host.stats()
+            self.obs.flight.record(
+                "kv_tier_survives_restart",
+                host_entries=hs["kv_host_entries"],
+                disk_entries=hs["kv_disk_entries"],
+            )
         # a failure inside _activate_batch can leave a request both slotted
         # AND in _starting_batch — salvage each request once
         seen: set = set()

@@ -1,10 +1,10 @@
 """Host-side page allocator for the paged KV memory plane.
 
-The engine's legacy layout reserves one contiguous ``[max_seq_len]`` KV region
-per decode slot, so HBM *capacity* — not bandwidth — caps concurrency at long
-context: a slot serving a 200-token dialog turn pins the same multi-MB cache
-row as one serving a 16k-token RAG prompt.  The paged plane (vLLM-style block
-tables) carves the same byte budget into fixed-size pages and reserves only
+A cache that reserves one contiguous ``[max_seq_len]`` KV region per decode
+slot lets HBM *capacity* — not bandwidth — cap concurrency at long context: a
+slot serving a 200-token dialog turn pins the same multi-MB cache row as one
+serving a 16k-token RAG prompt.  The paged plane (vLLM-style block tables)
+carves the byte budget into fixed-size pages and reserves only
 ``ceil((prompt_len + max_tokens) / page_size)`` pages per request, so short
 traffic packs many more concurrent slots into the same HBM.
 

@@ -40,7 +40,7 @@ class ModelSpec:
     # jit call advances every live slot N tokens, amortizing host
     # bookkeeping, sampling-array uploads, and dispatch overhead over N.
     # 0 = inherit `burst` (the historical alias — same machinery); >= 1 is
-    # the canonical knob and the one-flag rollback is decode_steps=1.
+    # the canonical knob (1 = single-step ticks).
     # json_fsm slots downgrade live ticks to single-step
     # (decode_steps_effective in tick_stats).  Composes with speculative > 0:
     # the spec tick scans decode_steps full draft->verify->commit passes per
@@ -54,7 +54,8 @@ class ModelSpec:
     # ONE bounded prefill chunk AND the full N-step decode scan for resident
     # slots, so a long admit no longer displaces decode ticks
     # (prefill_displacement_frac in tick_stats).  Token-identical to the
-    # sequential path; False is the one-flag rollback (sequential chunking).
+    # sequential path; False keeps sequential chunking (and one prefill
+    # program per bucket to compile: a.x-k1-ep16 boots with it).
     prefill_piggyback: bool = True
     # prefill program shapes (serving/engine.py warm-up compiles seq buckets x
     # {1, 4, wave}): the sequence buckets (None = the engine's powers of two
@@ -78,7 +79,7 @@ class ModelSpec:
     # int4 group width along the contraction axis (accuracy knob: smaller
     # groups -> tighter scales -> lower logit error, more scale bytes);
     # default IS ops.quant.INT4_GROUP_SIZE — the single source the synthetic
-    # inits and the bench arms also read
+    # inits also read
     quant_group_size: int = INT4_GROUP_SIZE
     # prefix KV cache: LRU size for shared prompt-prefix K/V (system + RAG
     # context) reused across requests; 0 disables (serving/engine.py)
@@ -102,36 +103,29 @@ class ModelSpec:
     # `spec_auto_disabled` in tick_stats.
     speculative: int = 0
     spec_width: int = 4
-    # length-aware decode attention: KV-cache chunk width for the bucketed
-    # decode read (serving/engine.py decode_kv_chunk).  0 = auto (512/256/128,
-    # whichever divides max_seq_len into >= 2 chunks), None/"off" disables —
-    # every decode step then reads the whole allocated max_slots x max_seq_len
-    # cache regardless of live lengths.
-    decode_kv_chunk: Optional[int] = 0
-    # --- paged KV memory plane (docs/KV_PAGING.md) ---
-    # "paged" (default): a fixed pool of KV pages + per-request block tables
-    # with refcounted copy-on-write prefix sharing and KV-pressure admission;
-    # requests reserve ceil((prompt + max_tokens) / page) pages instead of a
-    # whole max_seq_len row.  "legacy": the contiguous slot cache — the
-    # one-flag rollback and the bench A/B arm.
-    kv_layout: str = "paged"
     # the block the checkpoint must be (``DecoderConfig.arch``: "llama",
     # "mla_moe"); None = whatever the checkpoint says.  A deployment that
     # states it is refused at load, before any program is built, when the
     # checkpoint holds another block.
     arch: Optional[str] = None
-    # page size in tokens; 0 = align with decode_kv_chunk (or its auto pick)
+    # --- paged KV memory plane (docs/KV_PAGING.md) ---
+    # a fixed pool of KV pages + per-request block tables with refcounted
+    # copy-on-write prefix sharing and KV-pressure admission; requests reserve
+    # ceil((prompt + max_tokens) / page) pages, and the decode read skips the
+    # pages past the longest live position.
+    # page size in tokens; 0 = the largest of 512, 256, 128, 64, 32, 16, 8
+    # that divides max_seq_len at least twice.  A max_seq_len no page divides
+    # is refused at load.
     kv_page_size: int = 0
-    # pool size in pages; 0 = byte parity with the legacy layout
-    # (max_slots * max_seq_len / page_size) — raise max_slots past the legacy
-    # count to actually bank the freed capacity as extra concurrency
+    # pool size in pages; 0 = max_slots * max_seq_len / page_size (a whole
+    # context per slot) — raise max_slots past that to bank what short
+    # requests leave free as extra concurrency
     kv_pages: int = 0
     # --- tiered KV durability (docs/KV_PAGING.md "Tiered KV") ---
     # host-DRAM byte budget for spilled prefix K/V: > 0 arms the host tier —
     # evicted/registered prefixes keep a host copy, admission restores them
     # into fresh pages instead of re-prefilling, crash-only restarts and
-    # scale-down migrations preserve warm sessions.  0 = off (the bench's
-    # HBM-only A/B arm and the pre-tiering behavior).
+    # scale-down migrations preserve warm sessions.  0 = off (HBM only).
     kv_host_bytes: int = 0
     # optional disk tier under this dir (host-tier evictions demote to .npz
     # files instead of dropping); None also honors DABT_KV_SPILL_DIR
@@ -151,7 +145,7 @@ class ModelSpec:
     normalize: bool = False
     num_experts: int = 0
     # --- admission-controlled scheduling (serving/scheduler.py) ---
-    # scheduler=False reverts to the legacy unbounded FIFO admission path
+    # scheduler=False reverts to the unbounded FIFO admission path
     scheduler: bool = True
     # bound on queued-but-not-slotted generation requests; past it /dialog/
     # sheds with 429 + Retry-After instead of queueing unboundedly
@@ -207,8 +201,8 @@ class ModelSpec:
     # with its own scheduler, KV page pool, and fault injector — seeds offset
     # per replica) behind an EngineRouter doing health- and prefix-affinity-
     # aware dispatch with per-replica circuit breakers and token-less
-    # re-route.  1 = the single-engine path, byte-identical to before (the
-    # bench baseline; no router object exists at all).  With a dynamic fleet
+    # re-route.  1 = the single-engine path (no router object exists at
+    # all).  With a dynamic fleet
     # (max_replicas above this, or autoscale on) this is the INITIAL and
     # MINIMUM size, not a fixed count.
     replicas: int = 1
@@ -220,8 +214,7 @@ class ModelSpec:
     # replica_devices=2 -> up to 4 replicas x TP-2.  Scale-up past the last
     # free slice is an honest `no_capacity` rejection instead of another
     # cache clone on the same chips.  0 (default) = every replica traces onto
-    # the registry's one global mesh (the pre-slicing behavior, and the bench
-    # A/B baseline arm).
+    # the registry's one global mesh.
     replica_devices: int = 0
     # ceiling for the dynamic fleet: the router's add_replica/remove_replica
     # (and the autoscaler driving them) keep the fleet within
@@ -274,7 +267,16 @@ class ModelSpec:
                     name,
                 )
                 d["prefix_cache"] = val
-        return cls(name=name, **{k: v for k, v in d.items() if k != "name"})
+        d.pop("name", None)
+        # an operator's file: name the key and the model, not the dataclass
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = sorted(k for k in d if k not in known)
+        if unknown:
+            raise ValueError(
+                f"model {name}: unknown setting(s) {', '.join(map(repr, unknown))} "
+                "(ModelSpec in serving/registry.py lists what a model entry may name)"
+            )
+        return cls(name=name, **d)
 
 
 class ModelRegistry:
@@ -376,17 +378,6 @@ class ModelRegistry:
                 f"model {name}: kv_host_bytes/kv_spill_dir are decoder-only "
                 "(encoders have no KV cache)"
             )
-        if (spec.kv_host_bytes or spec.kv_spill_dir) and spec.kv_layout == "legacy":
-            # not an error — kv_layout="legacy" is the documented one-flag
-            # paged rollback and must not force the operator to also unset
-            # the tiering knobs — but the engine only arms the host tier on
-            # the paged plane, so durability is OFF and that must be said
-            logger.warning(
-                "model %s: kv_host_bytes/kv_spill_dir have no effect with "
-                "kv_layout='legacy' — the host KV tier (spill/restore "
-                "durability) only runs on the paged plane",
-                name,
-            )
         if spec.replicas < 1:
             raise ValueError(f"model {name}: replicas must be >= 1")
         if spec.replicas > 1 and spec.kind == "encoder":
@@ -444,7 +435,7 @@ class ModelRegistry:
 
                     try:
                         mla_moe.check_serving(
-                            kv_layout=spec.kv_layout, speculative=spec.speculative,
+                            speculative=spec.speculative,
                             prefix_cache=spec.prefix_cache, kv_cache_dtype=spec.kv_cache_dtype,
                             attn_fp8=spec.attn_fp8, quantize=spec.quantize,
                             kv_host_tier=bool(spec.kv_host_bytes or spec.kv_spill_dir),
@@ -729,10 +720,6 @@ class ModelRegistry:
                     kv_cache_dtype=spec.kv_cache_dtype,
                     speculative=spec.speculative,
                     spec_width=spec.spec_width,
-                    decode_kv_chunk=(
-                        None if spec.decode_kv_chunk in (None, "off")
-                        else int(spec.decode_kv_chunk)
-                    ),
                     prefill_piggyback=spec.prefill_piggyback,
                     prefill_wave=spec.prefill_wave,
                     **(
@@ -741,7 +728,6 @@ class ModelRegistry:
                         else {}
                     ),
                     attn_fp8=spec.attn_fp8,
-                    kv_layout=spec.kv_layout,
                     kv_page_size=spec.kv_page_size,
                     kv_pages=spec.kv_pages,
                     kv_host_bytes=spec.kv_host_bytes,
@@ -766,8 +752,7 @@ class ModelRegistry:
 
             engines = [_build_engine(i) for i in range(spec.replicas)]
             if not fleet:
-                # single fixed engine, no router object: byte-identical to
-                # the pre-router serving path (the bench baseline)
+                # single fixed engine, no router object
                 self.generators[name] = engines[0]
             else:
                 from .router import EngineRouter

@@ -513,7 +513,8 @@ class EngineRouter:
             # the fleet registry answers in one lookup (and knows the TIER:
             # an HBM holder beats a host/disk holder — zero-copy sharing vs
             # a restore upload); the per-replica peek remains as a fallback
-            # for engines that emit no tier events (legacy layout, stubs)
+            # for engines that emit no tier events (stubs, a replica whose
+            # listener is unset)
             tiers = self.prefix_registry.holders(state.prompt_ids, prefix_len)
             hbm = [rep for rep in cands if tiers.get(rep.name) == TIER_HBM]
             warm = [
@@ -1365,15 +1366,9 @@ class EngineRouter:
         }
 
     def kv_stats(self) -> dict:
-        """Aggregated KV gauges + the per-replica blocks (each carries its
-        own kv_layout_requested/effective so one replica silently on the
-        legacy plane is visible)."""
+        """Aggregated KV gauges + the per-replica blocks."""
         per = [rep.engine.kv_stats() for rep in list(self.replicas)]
-        layouts = {p["kv_layout_effective"] for p in per}
         out: dict = {
-            "kv_layout": per[0]["kv_layout"] if len(layouts) == 1 else "mixed",
-            "kv_layout_requested": per[0]["kv_layout_requested"],
-            "kv_layout_effective": layouts.pop() if len(layouts) == 1 else "mixed",
             "prefix_hits": sum(p.get("prefix_hits", 0) for p in per),
             "prefix_misses": sum(p.get("prefix_misses", 0) for p in per),
             "replicas": per,

@@ -491,7 +491,7 @@ def create_app(
                 # decode fast-path gauges (docs/QUANT.md): fused-tick depth
                 # configured vs effective (json downgrade), weight bits, and
                 # the double-buffered upload fraction — which fast path is
-                # ACTUALLY active, same pattern as kv_layout_effective
+                # ACTUALLY active
                 g["decode"] = dec()
             spec = getattr(eng, "spec_stats", None)
             if callable(spec):

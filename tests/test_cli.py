@@ -350,3 +350,20 @@ def test_compile_cache_can_be_turned_off_for_the_process_and_slicing_leaves_it_o
 
         jax.config.update("jax_enable_compilation_cache", prev)
         compilation_cache.reset_cache()
+
+
+def test_serve_knows_page_options_and_no_layout_option(capsys):
+    """``serve`` names the page pool's size and page (``--kv-pages``,
+    ``--kv-page-size``); ``--kv-layout`` went with the contiguous cache and is
+    a usage error, not a silently ignored flag."""
+    from django_assistant_bot_tpu.cli import serve
+
+    parser = argparse.ArgumentParser()
+    serve.add_parser(parser.add_subparsers(dest="command"))
+    args = parser.parse_args(["serve", "--tiny", "--kv-pages", "12", "--kv-page-size", "32"])
+    assert (args.kv_pages, args.kv_page_size) == (12, 32)
+    with pytest.raises(SystemExit) as e:
+        parser.parse_args(["serve", "--tiny", "--kv-layout", "legacy"])
+    assert e.value.code == 2
+    assert "--kv-layout" in capsys.readouterr().err
+

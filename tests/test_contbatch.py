@@ -22,7 +22,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from django_assistant_bot_tpu.models import DecoderConfig, llama
 from django_assistant_bot_tpu.ops.attention import (
-    chunked_gqa_decode_attention,
+    gqa_dot_product_attention,
     paged_gqa_decode_attention,
 )
 from django_assistant_bot_tpu.ops.quant import quantize_decoder_params
@@ -95,17 +95,16 @@ def _ab_run(cfg, params, piggyback, **kw):
 @pytest.mark.parametrize(
     "kw",
     [
-        {"kv_layout": "paged"},
-        {"kv_layout": "legacy"},
-        {"kv_layout": "paged", "quantize": "int8", "kv_cache_dtype": "fp8"},
-        {"kv_layout": "paged", "quantize": "int4"},
+        {},
+        {"quantize": "int8", "kv_cache_dtype": "fp8"},
+        {"quantize": "int4"},
     ],
-    ids=["paged", "legacy", "paged-int8-fp8kv", "paged-int4"],
+    ids=["paged", "paged-int8-fp8kv", "paged-int4"],
 )
 def test_piggybacked_prefill_bit_identical_to_sequential(tiny, kw):
     """Greedy AND sampled outputs must match bit-for-bit with the chunk
     folded into the decode tick vs the sequential chunk-then-tick path,
-    across layouts and weight/KV formats — and the gauges must prove each
+    across weight/KV formats — and the gauges must prove each
     path actually ran (piggybacked chunks on, displaced ticks off)."""
     cfg, params = tiny
     kw = dict(kw)
@@ -224,69 +223,62 @@ def test_spec_default_verify_depth_is_one(tiny):
 
 
 # ------------------------------------------------------------- fp8 in-dot
-def _fp8_operands(seed=0, B=2, H=4, KH=2, S=64, D=16):
-    rng = np.random.default_rng(seed)
+def _fp8_pool(page=16, B=2, H=4, KH=2, S=64, D=16):
+    """A bf16 query and fp8 K/V rows, the rows also cut into a page pool (page
+    j of row b at index b*nb+j): ``q, (k8, v8), k_pool, v_pool, bt, positions``."""
+    rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((B, H, 1, D)), jnp.bfloat16)
     k = jnp.asarray(rng.standard_normal((B, KH, S, D)) * 0.5, jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, KH, S, D)) * 0.5, jnp.float32)
     k8 = k.astype(jnp.float8_e4m3fn)
     v8 = v.astype(jnp.float8_e4m3fn)
     positions = jnp.asarray([S - 1, S // 3], jnp.int32)
-    return q, k8, v8, positions
+    nb = S // page
+
+    def pool(x8):
+        return jnp.asarray(
+            np.asarray(x8.astype(jnp.float32))
+            .reshape(B, KH, nb, page, D)
+            .transpose(0, 2, 1, 3, 4)
+            .reshape(B * nb, KH, page, D)
+        ).astype(jnp.float8_e4m3fn)
+
+    bt = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
+    return q, (k8, v8), pool(k8), pool(v8), bt, positions
 
 
-def test_fp8_indot_chunked_within_bound():
-    q, k8, v8, positions = _fp8_operands()
-    ref = chunked_gqa_decode_attention(q, k8, v8, positions, chunk=16)
-    got = chunked_gqa_decode_attention(
-        q, k8, v8, positions, chunk=16, fp8_dot=True
-    )
-    err = float(
-        jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32)))
-    )
-    assert 0.0 < err < FP8_INDOT_MAX_ABS_ERR, err
+def _max_abs_err(got, ref):
+    return float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32))))
+
+
+def test_fp8_indot_within_bound_of_masked_gqa():
+    """In-dot fp8 against plain masked attention over the upcast rows."""
+    q, (k8, v8), k_pool, v_pool, bt, positions = _fp8_pool()
+    S = k8.shape[2]
+    mask = (jnp.arange(S)[None, :] <= positions[:, None])[:, None, None, :]
+    ref = gqa_dot_product_attention(q, k8.astype(q.dtype), v8.astype(q.dtype), mask=mask)
+    got = paged_gqa_decode_attention(q, k_pool, v_pool, bt, positions, fp8_dot=True)
+    assert 0.0 < _max_abs_err(got, ref) < FP8_INDOT_MAX_ABS_ERR
 
 
 def test_fp8_indot_paged_within_bound():
-    q, k8, v8, positions = _fp8_operands()
-    B, KH, S, D = k8.shape
-    page = 16
-    nb = S // page
-    # pool mirroring the contiguous cache: page j of row b at index b*nb+j
-    k_pool = jnp.asarray(
-        np.asarray(k8.astype(jnp.float32))
-        .reshape(B, KH, nb, page, D)
-        .transpose(0, 2, 1, 3, 4)
-        .reshape(B * nb, KH, page, D)
-    ).astype(jnp.float8_e4m3fn)
-    v_pool = jnp.asarray(
-        np.asarray(v8.astype(jnp.float32))
-        .reshape(B, KH, nb, page, D)
-        .transpose(0, 2, 1, 3, 4)
-        .reshape(B * nb, KH, page, D)
-    ).astype(jnp.float8_e4m3fn)
-    bt = jnp.asarray(
-        [[b * nb + j for j in range(nb)] for b in range(B)], jnp.int32
-    )
+    q, _, k_pool, v_pool, bt, positions = _fp8_pool()
     ref = paged_gqa_decode_attention(q, k_pool, v_pool, bt, positions)
     got = paged_gqa_decode_attention(
         q, k_pool, v_pool, bt, positions, fp8_dot=True
     )
-    err = float(
-        jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32)))
-    )
-    assert 0.0 < err < FP8_INDOT_MAX_ABS_ERR, err
+    assert 0.0 < _max_abs_err(got, ref) < FP8_INDOT_MAX_ABS_ERR
 
 
 def test_fp8_indot_rejects_non_fp8_kv():
-    q, k8, v8, positions = _fp8_operands()
+    q, _, k_pool, v_pool, bt, positions = _fp8_pool()
     with pytest.raises(ValueError, match="fp8"):
-        chunked_gqa_decode_attention(
+        paged_gqa_decode_attention(
             q,
-            k8.astype(jnp.bfloat16),
-            v8.astype(jnp.bfloat16),
+            k_pool.astype(jnp.bfloat16),
+            v_pool.astype(jnp.bfloat16),
+            bt,
             positions,
-            chunk=16,
             fp8_dot=True,
         )
 
@@ -345,7 +337,6 @@ def test_tick_raise_mid_piggyback_restart_leaves_page_pool_clean(tiny):
     cfg, params = tiny
     inj = FaultInjector({})
     eng = _lockstep(_engine(cfg, params, decode_steps=2, faults=inj, max_slots=2))
-    assert eng.paged
     f0 = eng.submit(list(range(3, 12)), max_tokens=40, temperature=0.0)
     for _ in range(5):
         eng._loop_iteration()

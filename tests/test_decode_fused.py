@@ -38,10 +38,10 @@ def _drive(eng, futs, limit=4000):
         assert steps < limit, "engine made no progress"
 
 
-def _run(cfg, params, prompts, *, kv_layout="paged", temps=None, **kw):
+def _run(cfg, params, prompts, *, temps=None, **kw):
     eng = GenerationEngine(
         cfg, params, ByteTokenizer(), max_slots=4, max_seq_len=256,
-        prefix_cache_size=0, kv_layout=kv_layout, **kw,
+        prefix_cache_size=0, **kw,
     )
     eng._running = True
     temps = temps or [0.0] * len(prompts)
@@ -71,18 +71,17 @@ def test_decode_steps_one_byte_identical_to_unfused_burst():
     assert a == b
 
 
-@pytest.mark.parametrize("kv_layout", ["paged", "legacy"])
 @pytest.mark.parametrize("quantize", [None, "int8", "int4"])
-def test_fused_greedy_token_identical(kv_layout, quantize):
-    """N>1 fused ticks are greedy token-identical to N=1 across layouts and
-    weight formats over ragged prompt fills — the acceptance criterion's
+def test_fused_greedy_token_identical(quantize):
+    """N>1 fused ticks are greedy token-identical to N=1 across weight
+    formats over ragged prompt fills — the acceptance criterion's
     bit-identity subset."""
     cfg, params = _tiny()
     if quantize:
         params = quantize_decoder_params(params, fmt=quantize)
     prompts = _ragged_prompts()
-    a, ea = _run(cfg, params, prompts, kv_layout=kv_layout, decode_steps=1)
-    b, eb = _run(cfg, params, prompts, kv_layout=kv_layout, decode_steps=3)
+    a, ea = _run(cfg, params, prompts, decode_steps=1)
+    b, eb = _run(cfg, params, prompts, decode_steps=3)
     assert a == b
     assert ea.decode_steps == 1 and eb.decode_steps == 3
     if quantize == "int4":
@@ -215,7 +214,6 @@ def test_tick_raise_mid_fused_tick_restart_leaves_page_pool_clean():
         cfg, params, tok, max_slots=2, max_seq_len=96, decode_steps=4,
         prefix_cache_size=0, faults=inj,
     )
-    assert eng.paged
     eng.start()
     try:
         f0 = eng.submit(tok.encode("ab ab ab ab"), max_tokens=6, temperature=0.0)

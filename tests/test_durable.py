@@ -635,17 +635,141 @@ def test_registry_routes_durable_and_ingest_document(tmp_db, tmp_path):
 
 
 # -------------------------------------------------------------- kill-replay
+# kill-replay child: ingests ledgered documents one at a time into a durable
+# index, logging each applied doc's top-k AFTER the WAL fsync — the parent
+# SIGKILLs it mid-stream, so the last complete line is the pre-crash truth
+# the recovered index must reproduce (storage/durable.py, docs/DURABILITY.md)
+_DURABLE_CHILD = """
+import json, os, sys, time
+import numpy as np
+from django_assistant_bot_tpu.storage.ann import make_clustered
+from django_assistant_bot_tpu.storage.durable import DurableANN
+
+dirp, progress, docs, rows_per, dim = (
+    sys.argv[1], sys.argv[2], int(sys.argv[3]), int(sys.argv[4]), int(sys.argv[5])
+)
+rows = make_clustered(docs * rows_per, dim, seed=7)
+q = rows[:: max(1, docs * rows_per // 8)][:8]
+dur = DurableANN(dirp, dim=dim, fsync="always", snapshot_every_records=6, seed=7)
+pf = open(progress, "a")
+for d in range(docs):
+    ids = list(range(d * rows_per, (d + 1) * rows_per))
+    dur.ingest(ids, rows[ids], ledger_key=f"doc{d}")
+    if d == 3:
+        dur.train(nlist=8, seed=7)
+    topk = [[int(i) for i, _ in dur.search(qq, k=10)] for qq in q]
+    pf.write(json.dumps({"doc": d, "n": len(dur), "topk": topk}) + "\\n")
+    pf.flush()
+    os.fsync(pf.fileno())
+    time.sleep(0.05)
+"""
+
+
+def _kill_replay() -> dict:
+    """Durability kill-replay (storage/durable.py evidence).
+
+    A child process live-ingests 24 ledgered documents into a WAL+snapshot
+    backed index and is SIGKILLed mid-stream (>= 8 applied).  The parent then
+    recovers the SAME directory — latest valid snapshot + WAL-tail replay —
+    and asserts the three durability claims: (1) recovered top-k is identical
+    to the child's last fsynced pre-crash answer on the pinned corpus, (2)
+    zero duplicate vectors, (3) re-ingesting EVERY document with the original
+    ledger keys no-ops exactly the already-applied ones and lands the rest,
+    finishing at the full corpus.  Recovery wall time and replayed-record
+    counts ride along as the operator-facing cost of the crash.
+    """
+    import signal
+    import subprocess
+    import sys
+    import tempfile
+    import time
+
+    docs, rows_per, dim = 24, 32, 64
+    out: dict = {"durable_ingested_docs": docs}
+    with tempfile.TemporaryDirectory(prefix="dabt-durable-") as tmp:
+        dur_dir = os.path.join(tmp, "index")
+        progress = os.path.join(tmp, "progress.jsonl")
+        open(progress, "w").close()
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        child = subprocess.Popen(
+            [sys.executable, "-c", _DURABLE_CHILD, dur_dir, progress, str(docs), str(rows_per), str(dim)],
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            env=env,
+            stdout=subprocess.DEVNULL,
+            stderr=subprocess.PIPE,
+        )
+        deadline = time.monotonic() + 300
+        while time.monotonic() < deadline:
+            lines = open(progress).read().splitlines()
+            if len(lines) >= 8 or child.poll() is not None:
+                break
+            time.sleep(0.02)
+        if child.poll() is None:
+            child.send_signal(signal.SIGKILL)  # no atexit, no flush — a real crash
+        else:
+            err = (child.stderr.read() or b"").decode(errors="replace")
+            raise RuntimeError(f"durable child exited early rc={child.returncode}: {err[-2000:]}")
+        child.wait()
+        pre_crash = [
+            json.loads(ln) for ln in open(progress).read().splitlines() if ln.strip()
+        ]
+
+        rows = make_clustered(docs * rows_per, dim, seed=7)
+        q = rows[:: max(1, docs * rows_per // 8)][:8]
+        t0 = time.perf_counter()
+        dur = DurableANN(dur_dir, dim=dim, fsync="always", seed=7)
+        st = dur.durability_stats()
+        out["durable_recovery_s"] = round(time.perf_counter() - t0, 3)
+        out["durable_replayed_records"] = st["replayed_records"]
+        out["durable_wal_records"] = st["wal_records"]
+        out["durable_snapshot_count"] = st["snapshot_count"]
+        applied = sum(1 for d in range(docs) if dur.ledger_has(f"doc{d}"))
+        out["durable_recovered_docs"] = applied
+
+        live = dur.index.live_ids()
+        expect = set(range(applied * rows_per))
+        out["durable_duplicate_vectors"] = len(live) - len(set(live))
+        assert set(live) == expect, "recovered id set != ledgered documents"
+
+        topk = [[int(i) for i, _ in dur.search(qq, k=10)] for qq in q]
+        truth = next((p["topk"] for p in pre_crash if p["doc"] == applied - 1), None)
+        if truth is None:
+            # crash landed between the WAL fsync and the progress fsync: the
+            # last applied doc has no logged answer, so rebuild the pre-crash
+            # index from scratch (same data/order/seed => same placement)
+            ctl = DurableANN(os.path.join(tmp, "control"), dim=dim, fsync="never", snapshot_every_records=6, seed=7)
+            for d in range(applied):
+                ids = list(range(d * rows_per, (d + 1) * rows_per))
+                ctl.ingest(ids, rows[ids], ledger_key=f"doc{d}")
+                if d == 3:
+                    ctl.train(nlist=8, seed=7)
+            truth = [[int(i) for i, _ in ctl.search(qq, k=10)] for qq in q]
+            ctl.close()
+        out["durable_topk_identical"] = bool(topk == truth)
+
+        # crash-resume: the worker re-runs its WHOLE ingest loop; applied
+        # docs must no-op on the ledger, the rest must land exactly once
+        deduped = 0
+        for d in range(docs):
+            ids = list(range(d * rows_per, (d + 1) * rows_per))
+            n = dur.ingest(ids, rows[ids], ledger_key=f"doc{d}")
+            deduped += int(n == 0)
+        out["durable_resume_dedup_docs"] = deduped
+        assert deduped == applied, "ledger dedup did not cover the applied docs"
+        live = dur.index.live_ids()
+        assert len(live) == docs * rows_per and len(set(live)) == len(live)
+        out["durable_duplicate_vectors"] += len(live) - len(set(live))
+        dur.close()
+    return out
+
+
 @pytest.mark.slow
 def test_durable_kill_replay_subprocess():
-    """The headline chaos bench, as CI's smoke: a child process is SIGKILLed
-    mid-ingest, the parent recovers the directory and must reproduce the
-    child's last fsynced pre-crash top-k exactly, with zero duplicate
-    vectors, and a full re-run of the ingest loop must dedup every
-    already-applied document (bench.bench_durable is the single
-    implementation the bench record and this test share)."""
-    import bench
-
-    out = bench.bench_durable()
+    """A child process is SIGKILLed mid-ingest, the parent recovers the
+    directory and must reproduce the child's last fsynced pre-crash top-k
+    exactly, with zero duplicate vectors, and a full re-run of the ingest
+    loop must dedup every already-applied document."""
+    out = _kill_replay()
     assert out["durable_recovered_docs"] >= 8
     assert out["durable_recovered_docs"] < out["durable_ingested_docs"]
     assert out["durable_topk_identical"] is True

@@ -232,7 +232,6 @@ def test_tick_raise_restart_rebuilds_paged_pool_and_keeps_serving():
         faults=inj, max_slots=2, max_seq_len=64,
         prefix_cache_size=4, prefix_min_tokens=8,
     ).start()
-    assert eng.paged
     prefix = list(range(1, 13))  # 12 tokens >= prefix_min_tokens
     try:
         eng.submit(

@@ -67,10 +67,9 @@ def _tiny_engine(**kw):
         _shared_params["params"] = llama.init(cfg, jax.random.key(7))
     kw.setdefault("max_slots", 2)
     kw.setdefault("max_seq_len", 256)
-    kw.setdefault("decode_kv_chunk", 64)
+    kw.setdefault("kv_page_size", 64)
     kw.setdefault("prefix_cache_size", 4)
     kw.setdefault("prefix_min_tokens", 16)
-    kw.setdefault("kv_layout", "paged")
     return GenerationEngine(
         _shared_params["cfg"], _shared_params["params"], ByteTokenizer(), **kw
     )
@@ -691,39 +690,6 @@ def test_detach_migrate_off_counts_disk_entries(tmp_path):
         router.stop()
 
 
-def test_legacy_layout_warns_that_host_tier_is_inert(caplog):
-    """kv_layout="legacy" is the documented one-flag paged rollback, so
-    kv_host_bytes/kv_spill_dir stay VALID — but the host tier only runs on
-    the paged plane, and losing durability on a rollback must be said out
-    loud, not discovered from missing kv_host_* gauges."""
-    import logging
-
-    from django_assistant_bot_tpu.serving.registry import (
-        ModelRegistry,
-        ModelSpec,
-    )
-
-    reg = ModelRegistry()
-    with caplog.at_level(
-        logging.WARNING, logger="django_assistant_bot_tpu.serving.registry"
-    ):
-        reg.load(
-            ModelSpec(
-                name="legacy-rollback", kind="decoder", tiny=True,
-                kv_layout="legacy", kv_host_bytes=1 << 20,
-                max_slots=2, max_seq_len=64,
-            )
-        )
-    try:
-        assert any(
-            "no effect with" in r.getMessage() for r in caplog.records
-        )
-        eng = reg.get_generator("legacy-rollback")
-        assert getattr(eng, "kv_host_tier", None) is None
-    finally:
-        reg.stop()
-
-
 def test_fallback_peek_covers_non_emitting_replica():
     """The per-replica holds_prefix peek must run for every candidate the
     fleet registry has NO answer for — not only when the registry is empty
@@ -734,8 +700,8 @@ def test_fallback_peek_covers_non_emitting_replica():
     rng = np.random.default_rng(41)
     prefix = rng.integers(1, 255, 100).tolist()
     try:
-        # replica r1 stops emitting tier events (the stub/legacy shape the
-        # fallback exists for), then warms the session HBM-directly
+        # replica r1 stops emitting tier events (the shape the fallback
+        # exists for), then warms the session HBM-directly
         b = router.replicas[1]
         b.engine.set_prefix_listener(None)
         b.engine.submit(

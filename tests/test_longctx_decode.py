@@ -1,9 +1,11 @@
-"""Length-aware decode attention: the bucketed KV read must be a pure
-optimization — identical outputs to the full-cache read across ragged per-slot
-lengths, chunk-boundary transitions mid-decode, sliding windows, and the
-fp8-KV per-chunk dequant path.  All CPU (f32 mesh), so tier-1 gates the
-tentpole without hardware.
+"""Length-aware decode attention: the paged read skips every page past the
+batch's longest live position, and must be a pure optimization — the same
+logits as the full forward across ragged per-slot lengths, page-boundary
+transitions mid-decode, sliding windows, and the fp8-KV per-page dequant path.
+All CPU (f32), so tier-1 gates it without hardware.
 """
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -12,139 +14,56 @@ import jax
 import jax.numpy as jnp
 
 from django_assistant_bot_tpu.models import DecoderConfig, llama
-from django_assistant_bot_tpu.ops.attention import (
-    chunked_gqa_decode_attention,
-    gqa_dot_product_attention,
-)
 from django_assistant_bot_tpu.serving import ByteTokenizer, GenerationEngine
+from paged import Paged
+
+_FP8 = jnp.float8_e4m3fn
+_WINDOWED = dict(sliding_window=48, window_layer_start=1)
 
 
-def _random_cache(cfg, B, S, lengths, seed=0, dtype=None):
-    rng = np.random.default_rng(seed)
-    KH, D, L = cfg.num_kv_heads, cfg.head_dim, cfg.num_layers
-    k = rng.normal(size=(L, B, KH, S, D)).astype(np.float32)
-    v = rng.normal(size=(L, B, KH, S, D)).astype(np.float32)
-    kd = jnp.asarray(k).astype(dtype) if dtype else jnp.asarray(k)
-    vd = jnp.asarray(v).astype(dtype) if dtype else jnp.asarray(v)
-    return llama.KVCache(k=kd, v=vd, lengths=jnp.asarray(lengths, jnp.int32))
-
-
-def test_op_matches_masked_gqa_ragged():
-    """Op level: chunked online-softmax == masked full softmax for ragged
-    positions, including positions exactly on / either side of a boundary."""
-    rng = np.random.default_rng(1)
-    B, H, KH, S, D, chunk = 5, 8, 2, 128, 16, 32
-    q = jnp.asarray(rng.normal(size=(B, H, 1, D)).astype(np.float32))
-    k = jnp.asarray(rng.normal(size=(B, KH, S, D)).astype(np.float32))
-    v = jnp.asarray(rng.normal(size=(B, KH, S, D)).astype(np.float32))
-    positions = jnp.asarray([0, 31, 32, 33, 127], jnp.int32)
-
-    kpos = jnp.arange(S)[None, :]
-    mask = (kpos <= positions[:, None])[:, None, None, :]  # [B,1,1,S]
-    full = gqa_dot_product_attention(q, k, v, mask=mask)
-    chunked = chunked_gqa_decode_attention(q, k, v, positions, chunk=chunk)
-    np.testing.assert_allclose(np.asarray(full), np.asarray(chunked), atol=2e-6)
-
-
-def test_op_skips_tail_chunks():
-    """Garbage (NaN) planted beyond the bucketed window must never be read —
-    the proof the tail chunks are actually skipped, not just masked."""
-    rng = np.random.default_rng(2)
-    B, H, KH, S, D, chunk = 2, 4, 2, 128, 8, 32
-    q = jnp.asarray(rng.normal(size=(B, H, 1, D)).astype(np.float32))
-    k = rng.normal(size=(B, KH, S, D)).astype(np.float32)
-    v = rng.normal(size=(B, KH, S, D)).astype(np.float32)
-    positions = jnp.asarray([10, 40], jnp.int32)  # window = chunks [0, 2)
-    k_nan, v_nan = k.copy(), v.copy()
-    k_nan[:, :, 64:] = np.nan  # chunks [2, 4) — beyond every valid position
-    v_nan[:, :, 64:] = np.nan
-    clean = chunked_gqa_decode_attention(
-        q, jnp.asarray(k), jnp.asarray(v), positions, chunk=chunk
-    )
-    poisoned = chunked_gqa_decode_attention(
-        q, jnp.asarray(k_nan), jnp.asarray(v_nan), positions, chunk=chunk
-    )
-    assert not np.any(np.isnan(np.asarray(poisoned)))
-    np.testing.assert_array_equal(np.asarray(clean), np.asarray(poisoned))
-
-
-def test_decode_step_bucketed_equivalence_ragged():
-    """decode_step with kv_chunk == full-cache decode_step across a ragged
-    batch whose lengths straddle chunk boundaries."""
-    cfg = DecoderConfig.tiny()
-    params = llama.init(cfg, jax.random.key(0))
-    B, S = 4, 256
-    lengths = np.asarray([3, 63, 64, 200], np.int32)
-    cache_a = _random_cache(cfg, B, S, lengths)
-    cache_b = _random_cache(cfg, B, S, lengths)
-    toks = jnp.asarray([7, 11, 13, 17], jnp.int32)
-    lg_full, ca = llama.decode_step(params, cfg, toks, cache_a)
-    lg_chunk, cb = llama.decode_step(params, cfg, toks, cache_b, kv_chunk=64)
-    np.testing.assert_allclose(
-        np.asarray(lg_full), np.asarray(lg_chunk), atol=1e-4, rtol=1e-4
-    )
-    np.testing.assert_array_equal(np.asarray(ca.lengths), np.asarray(cb.lengths))
-
-
-def test_decode_step_boundary_transition_mid_decode():
-    """Greedy decode across a chunk boundary: the bucketed path must track the
-    full path token-for-token as the read window grows by a chunk mid-run."""
-    cfg = DecoderConfig.tiny()
-    params = llama.init(cfg, jax.random.key(1))
-    B, S, chunk = 2, 256, 64
-    lengths = np.asarray([60, 61], np.int32)  # crosses 64 a few steps in
-    cache_a = _random_cache(cfg, B, S, lengths, seed=3)
-    cache_b = _random_cache(cfg, B, S, lengths, seed=3)
-    ta = tb = jnp.asarray([5, 9], jnp.int32)
-    for step in range(8):
-        la, cache_a = llama.decode_step(params, cfg, ta, cache_a)
-        lb, cache_b = llama.decode_step(params, cfg, tb, cache_b, kv_chunk=chunk)
-        ta = jnp.argmax(la, -1).astype(jnp.int32)
-        tb = jnp.argmax(lb, -1).astype(jnp.int32)
-        assert np.array_equal(np.asarray(ta), np.asarray(tb)), f"diverged at {step}"
-        np.testing.assert_allclose(
-            np.asarray(la), np.asarray(lb), atol=1e-4, rtol=1e-4
-        )
-
-
-def test_decode_step_fp8_kv_per_chunk_dequant():
-    """fp8 slot cache: the chunked path's per-chunk upcast must equal the full
-    read's whole-cache upcast (same values, different dequant placement)."""
-    cfg = DecoderConfig.tiny()
-    params = llama.init(cfg, jax.random.key(2))
-    B, S = 3, 128
-    lengths = np.asarray([5, 64, 100], np.int32)
-    fp8 = jnp.float8_e4m3fn
-    cache_a = _random_cache(cfg, B, S, lengths, seed=4, dtype=fp8)
-    cache_b = _random_cache(cfg, B, S, lengths, seed=4, dtype=fp8)
-    toks = jnp.asarray([3, 4, 5], jnp.int32)
-    lg_full, ca = llama.decode_step(params, cfg, toks, cache_a)
-    lg_chunk, cb = llama.decode_step(params, cfg, toks, cache_b, kv_chunk=32)
-    assert ca.k.dtype == fp8 and cb.k.dtype == fp8
-    np.testing.assert_allclose(
-        np.asarray(lg_full), np.asarray(lg_chunk), atol=1e-4, rtol=1e-4
-    )
-
-
-def test_decode_step_windowed_chunked_equivalence():
-    """Sliding-window layers through the chunked path: band masking inside the
-    window chunks, leading chunks below the band skipped."""
-    import dataclasses
-
-    cfg = dataclasses.replace(
-        DecoderConfig.tiny(), sliding_window=48, window_layer_start=1
-    )
-    params = llama.init(cfg, jax.random.key(3))
-    B, S = 3, 256
-    lengths = np.asarray([10, 120, 200], np.int32)
-    cache_a = _random_cache(cfg, B, S, lengths, seed=5)
-    cache_b = _random_cache(cfg, B, S, lengths, seed=5)
-    toks = jnp.asarray([2, 3, 4], jnp.int32)
-    lg_full, _ = llama.decode_step(params, cfg, toks, cache_a)
-    lg_chunk, _ = llama.decode_step(params, cfg, toks, cache_b, kv_chunk=64)
-    np.testing.assert_allclose(
-        np.asarray(lg_full), np.asarray(lg_chunk), atol=1e-4, rtol=1e-4
-    )
+@pytest.mark.parametrize(
+    "lengths, page, steps, kv_dtype, cfg_kw, atol",
+    [
+        # ragged lengths on and either side of a page boundary
+        pytest.param([3, 63, 64, 200], 64, 1, None, {}, 2e-4, id="ragged"),
+        # greedy decode across a page boundary: the read window grows by a page mid-run
+        pytest.param([60, 61], 64, 8, None, {}, 2e-4, id="boundary_mid_decode"),
+        # fp8 pool, dequantised per page; forward keeps no cache to round, so the
+        # tolerance is e4m3's 2^-4 relative step on K and V carried to the logits
+        pytest.param([5, 64, 100], 32, 1, _FP8, {}, 0.15, id="fp8_kv_per_page_dequant"),
+        # windowed layers: band masking inside the live pages, pages below the band skipped
+        pytest.param([10, 120, 200], 64, 1, None, _WINDOWED, 2e-4, id="windowed"),
+    ],
+)
+def test_decode_step_paged_matches_forward(lengths, page, steps, kv_dtype, cfg_kw, atol):
+    """decode_step_paged over prefilled pages == llama.forward over the same
+    sequences, row by row and step by step."""
+    cfg = dataclasses.replace(DecoderConfig.tiny(), **cfg_kw)
+    params = llama.init(cfg, jax.random.key(len(lengths) + page))
+    rng = np.random.default_rng(page + steps)
+    B, S = len(lengths), 256
+    seqs = [rng.integers(1, cfg.vocab_size, n).tolist() for n in lengths]
+    ids = np.zeros((B, max(lengths)), np.int32)
+    for b, seq in enumerate(seqs):
+        ids[b, : len(seq)] = seq
+    kv = Paged(cfg, batch=B, max_len=S, page=page, dtype=kv_dtype or jnp.float32)
+    logits = kv.prefill(params, ids, lengths)
+    for step in range(steps + 1):
+        for b, seq in enumerate(seqs):
+            want = llama.forward(params, cfg, jnp.asarray([seq], jnp.int32))[0, -1]
+            np.testing.assert_allclose(
+                np.asarray(logits[b]), np.asarray(want), atol=atol, rtol=atol,
+                err_msg=f"row {b} at step {step}",
+            )
+            if kv_dtype is None:
+                assert int(jnp.argmax(logits[b])) == int(jnp.argmax(want)), (b, step)
+        if step == steps:
+            break
+        for b, seq in enumerate(seqs):
+            seq.append(int(jnp.argmax(logits[b])))
+        logits = kv.decode(params, [seq[-1] for seq in seqs])
+    assert kv.cache.k.dtype == (kv_dtype or jnp.float32)
+    assert np.asarray(kv.cache.lengths).tolist() == [len(seq) for seq in seqs]
 
 
 def test_engine_bucketed_greedy_matches_forward_and_reports_frac():
@@ -155,7 +74,7 @@ def test_engine_bucketed_greedy_matches_forward_and_reports_frac():
     params = llama.init(cfg, jax.random.key(4))
     tok = ByteTokenizer()
     eng = GenerationEngine(
-        cfg, params, tok, max_slots=2, max_seq_len=256, decode_kv_chunk=64,
+        cfg, params, tok, max_slots=2, max_seq_len=256, kv_page_size=64,
         prefix_cache_size=0,
     ).start()
     try:
@@ -174,46 +93,74 @@ def test_engine_bucketed_greedy_matches_forward_and_reports_frac():
         assert result.token_ids == expected
         stats = eng.tick_stats()
         assert stats["ticks"] >= 1
-        # prompt + 5 tokens ≈ 20 positions of a 256-slot cache in 64-wide
-        # chunks -> 1 of 4 chunks read
+        # prompt + 5 tokens ≈ 20 positions of a 256-token context in 64-wide
+        # pages -> 1 of 4 pages read
         assert 0 < stats["kv_read_frac"] < 1
     finally:
         eng.stop()
 
 
-def test_engine_kv_chunk_validation_and_auto():
+def test_engine_kv_page_size_validation_and_auto():
     cfg = DecoderConfig.tiny()
     params = llama.init(cfg, jax.random.key(5))
     tok = ByteTokenizer()
-    # auto at 256 ctx -> 128 (largest of 512/256/128 leaving >= 2 chunks)
+    # auto at 256 ctx -> 128 (the largest of 512 ... 8 leaving >= 2 pages)
     eng = GenerationEngine(cfg, params, tok, max_slots=1, max_seq_len=256)
-    assert eng.decode_kv_chunk == 128
-    # disabled -> full read, frac pinned at 1.0
+    assert eng.kv_page_size == 128
+    assert eng.tick_stats()["kv_read_frac"] == 1.0  # no tick issued yet
+    for bad in (100, 256, -64):  # does not divide; one page only; nonsense
+        with pytest.raises(ValueError, match=r"max_seq_len=256.*kv_page_size=" + str(bad)):
+            GenerationEngine(
+                cfg, params, tok, max_slots=1, max_seq_len=256, kv_page_size=bad
+            )
+
+
+@pytest.mark.parametrize("max_seq_len,page", [
+    (32, 16), (64, 32), (96, 32), (128, 64), (256, 128), (1024, 512), (2048, 512),
+])
+def test_engine_page_picked_with_nothing_named(max_seq_len, page):
+    """The page decides ``decode_kv_path`` and the pool's shape, so what an
+    engine picks with no ``kv_page_size`` named stays what it was when the
+    pick went through ``decode_kv_chunk`` (values read off that tree)."""
+    cfg = dataclasses.replace(DecoderConfig.tiny(), max_seq_len=max_seq_len)
     eng = GenerationEngine(
-        cfg, params, tok, max_slots=1, max_seq_len=256, decode_kv_chunk=None
+        cfg, llama.init(cfg, jax.random.key(5)), ByteTokenizer(), max_slots=1,
+        max_seq_len=max_seq_len, prefix_cache_size=0,
     )
-    assert eng.decode_kv_chunk is None
-    assert eng.tick_stats()["kv_read_frac"] == 1.0
-    with pytest.raises(ValueError, match="decode_kv_chunk"):
-        GenerationEngine(
-            cfg, params, tok, max_slots=1, max_seq_len=256, decode_kv_chunk=100
-        )
-    with pytest.raises(ValueError, match="decode_kv_chunk"):
-        GenerationEngine(
-            cfg, params, tok, max_slots=1, max_seq_len=256, decode_kv_chunk=256
-        )
+    assert eng.max_seq_len == max_seq_len
+    assert eng.kv_page_size == page
+    assert eng.kv_stats()["kv_pages_total"] == max_seq_len // page
+
+
+def test_kv_read_frac_counts_pages():
+    """``kv_read_frac`` is pages covering the longest live slot over pages of
+    a full context, whatever the page: 2 pages of 64, a 10-token request lies
+    in the first, so every tick reads half (it read 1.0 while the estimate
+    went by ``decode_kv_chunk``, which had no width for a 128-token context)."""
+    cfg = DecoderConfig.tiny()
+    eng = GenerationEngine(
+        cfg, llama.init(cfg, jax.random.key(4)), ByteTokenizer(), max_slots=2,
+        max_seq_len=128, prefix_cache_size=0,
+    ).start()
+    try:
+        assert eng.kv_page_size == 64
+        eng.submit([1, 2, 3, 4], max_tokens=6, temperature=0.0).result(timeout=120)
+        stats = eng.tick_stats()
+        assert stats["ticks"] >= 1 and stats["kv_read_frac"] == 0.5
+    finally:
+        eng.stop()
 
 
 def test_probe_decode_fill_len_leaves_engine_serviceable():
-    """A fill-pinned probe (the representative-probe mode the bench uses) must
-    reset lengths and leave the engine able to serve real traffic."""
+    """A fill-pinned probe must reset lengths and leave the engine able to
+    serve real traffic."""
     import asyncio
 
     cfg = DecoderConfig.tiny()
     params = llama.init(cfg, jax.random.key(6))
     eng = GenerationEngine(
         cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=128,
-        decode_kv_chunk=64, prefix_cache_size=0,
+        kv_page_size=64, prefix_cache_size=0,
     ).start()
     try:
         step_s = eng.probe_decode(iters=2, fill_len=100)

@@ -44,7 +44,7 @@ def served(tmp_path_factory):
 
 def _spec(path, **over):
     spec = dict(kind="decoder", checkpoint=path, dtype="float32", arch="mla_moe", max_slots=4, max_seq_len=128,
-                chunk_size=64, kv_layout="paged", kv_page_size=16, kv_pages=32, prefix_cache=0, warmup=False)
+                chunk_size=64, kv_page_size=16, kv_pages=32, prefix_cache=0, warmup=False)
     spec.update(over)
     return {"m": spec}
 
@@ -172,7 +172,6 @@ def test_engine_names_the_held_experts_path_and_the_share_of_experts_skipped(ser
 
 @pytest.mark.parametrize("over,why", [
     ({"speculative": 4}, "tree verification"),
-    ({"kv_layout": "legacy"}, "contiguous"),
     ({"prefix_cache": 8}, "prefix cache"),
     ({"quantize": "int8"}, "int8/int4"),
     ({"kv_cache_dtype": "fp8"}, "reduced-precision latent cache"),

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from django_assistant_bot_tpu.models import DecoderConfig, encoder, llama
 from django_assistant_bot_tpu.models.hf_loader import load_decoder, load_encoder
+from paged import Paged
 
 
 @pytest.fixture(scope="module")
@@ -376,34 +377,21 @@ def test_windowed_prefill_chunk_decode_matches_forward(tmp_path):
     expected = seq[0, prompt.shape[1]:].tolist()
 
     # monolithic prefill + decode
-    cache = llama.init_cache(cfg, batch=1, max_len=32, dtype=jnp.float32)
-    lengths = jnp.asarray([prompt.shape[1]], jnp.int32)
-    logits, ks, vs = llama.prefill(params, cfg, jnp.asarray(prompt), lengths)
-    cache = llama.insert_sequences(cache, ks, vs, lengths, jnp.asarray([0], jnp.int32))
+    kv = Paged(cfg, batch=1, max_len=32, dtype=jnp.float32)
+    logits = kv.prefill(params, prompt, [prompt.shape[1]], slots=[0])
     got = [int(jnp.argmax(logits[0]))]
     for _ in range(n_new - 1):
-        logits, cache = llama.decode_step(
-            params, cfg, jnp.asarray([got[-1]], jnp.int32), cache
-        )
+        logits = kv.decode(params, [got[-1]])
         got.append(int(jnp.argmax(logits[0])))
     assert got == expected
 
     # chunked prefill (two chunks of 5; the second spans the window boundary)
-    cache = llama.init_cache(cfg, batch=1, max_len=32, dtype=jnp.float32)
-    slot = jnp.asarray(0, jnp.int32)
-    logits, cache = llama.prefill_chunk(
-        params, cfg, jnp.asarray(prompt[:, :5]), cache, slot,
-        jnp.asarray(0, jnp.int32), jnp.asarray(5, jnp.int32),
-    )
-    logits, cache = llama.prefill_chunk(
-        params, cfg, jnp.asarray(prompt[:, 5:]), cache, slot,
-        jnp.asarray(5, jnp.int32), jnp.asarray(5, jnp.int32),
-    )
+    kv = Paged(cfg, batch=1, max_len=32, dtype=jnp.float32)
+    kv.chunk(params, prompt[:, :5], slot=0, start=0, valid=5)
+    logits = kv.chunk(params, prompt[:, 5:], slot=0, start=5, valid=5)
     got = [int(jnp.argmax(logits[0]))]
     for _ in range(n_new - 1):
-        logits, cache = llama.decode_step(
-            params, cfg, jnp.asarray([got[-1]], jnp.int32), cache
-        )
+        logits = kv.decode(params, [got[-1]])
         got.append(int(jnp.argmax(logits[0])))
     assert got == expected
 
@@ -487,13 +475,11 @@ def test_phi3_longrope_matches_hf(tmp_path):
         lg = llama.forward(params, jcfg, jnp.asarray(seq))
         seq = np.concatenate([seq, [[int(jnp.argmax(lg[0, -1]))]]], axis=1)
     expected = seq[0, prompt.shape[1]:].tolist()
-    cache = llama.init_cache(jcfg, batch=1, max_len=64, dtype=jnp.float32)
-    lengths = jnp.asarray([prompt.shape[1]], jnp.int32)
-    lg, ks, vs = llama.prefill(params, jcfg, jnp.asarray(prompt), lengths)
-    cache = llama.insert_sequences(cache, ks, vs, lengths, jnp.asarray([0], jnp.int32))
+    kv = Paged(jcfg, batch=1, max_len=64, dtype=jnp.float32)
+    lg = kv.prefill(params, prompt, [prompt.shape[1]], slots=[0])
     got = [int(jnp.argmax(lg[0]))]
     for _ in range(2):
-        lg, cache = llama.decode_step(params, jcfg, jnp.asarray([got[-1]], jnp.int32), cache)
+        lg = kv.decode(params, [got[-1]])
         got.append(int(jnp.argmax(lg[0])))
     assert got == expected
 
@@ -587,21 +573,17 @@ def test_gemma_prefill_decode_matches_forward(tiny_gemma_dir):
         seq = np.concatenate([seq, [[int(jnp.argmax(logits[0, -1]))]]], axis=1)
     expected = seq[0, prompt.shape[1]:].tolist()
 
-    cache = llama.init_cache(cfg, batch=1, max_len=32, dtype=jnp.float32)
-    lengths = jnp.asarray([prompt.shape[1]], jnp.int32)
-    logits, ks, vs = llama.prefill(params, cfg, jnp.asarray(prompt), lengths)
-    cache = llama.insert_sequences(cache, ks, vs, lengths, jnp.asarray([0], jnp.int32))
+    kv = Paged(cfg, batch=1, max_len=32, dtype=jnp.float32)
+    logits = kv.prefill(params, prompt, [prompt.shape[1]], slots=[0])
     got = [int(jnp.argmax(logits[0]))]
     for _ in range(3):
-        logits, cache = llama.decode_step(
-            params, cfg, jnp.asarray([got[-1]], jnp.int32), cache
-        )
+        logits = kv.decode(params, [got[-1]])
         got.append(int(jnp.argmax(logits[0])))
     assert got == expected
 
 
 def test_qwen2_prefill_decode_matches_forward(tiny_qwen2_dir):
-    """The decode_step bias path must agree with the full forward."""
+    """The decode step's bias path must agree with the full forward."""
     d, _ = tiny_qwen2_dir
     cfg, params = load_decoder(d, dtype=jnp.float32)
     prompt = np.array([[1, 5, 9, 17, 3]], np.int32)
@@ -611,15 +593,11 @@ def test_qwen2_prefill_decode_matches_forward(tiny_qwen2_dir):
         seq = np.concatenate([seq, [[int(jnp.argmax(logits[0, -1]))]]], axis=1)
     expected = seq[0, prompt.shape[1]:].tolist()
 
-    cache = llama.init_cache(cfg, batch=1, max_len=32, dtype=jnp.float32)
-    lengths = jnp.asarray([prompt.shape[1]], jnp.int32)
-    logits, ks, vs = llama.prefill(params, cfg, jnp.asarray(prompt), lengths)
-    cache = llama.insert_sequences(cache, ks, vs, lengths, jnp.asarray([0], jnp.int32))
+    kv = Paged(cfg, batch=1, max_len=32, dtype=jnp.float32)
+    logits = kv.prefill(params, prompt, [prompt.shape[1]], slots=[0])
     got = [int(jnp.argmax(logits[0]))]
     for _ in range(3):
-        logits, cache = llama.decode_step(
-            params, cfg, jnp.asarray([got[-1]], jnp.int32), cache
-        )
+        logits = kv.decode(params, [got[-1]])
         got.append(int(jnp.argmax(logits[0])))
     assert got == expected
 
@@ -640,10 +618,8 @@ def test_prefill_decode_matches_forward(tiny_llama_dir):
     expected = seq[0, prompt.shape[1]:].tolist()
 
     # engine path: prefill into slot 0 of a 2-slot cache, then decode steps
-    cache = llama.init_cache(cfg, batch=2, max_len=32, dtype=jnp.float32)
-    lengths = jnp.asarray([prompt.shape[1]], jnp.int32)
-    logits, ks, vs = llama.prefill(params, cfg, jnp.asarray(prompt), lengths)
-    cache = llama.insert_sequences(cache, ks, vs, lengths, jnp.asarray([0], jnp.int32))
+    kv = Paged(cfg, batch=2, max_len=32, dtype=jnp.float32)
+    logits = kv.prefill(params, prompt, [prompt.shape[1]], slots=[0])
     got = []
     tok = int(jnp.argmax(logits[0]))
     got.append(tok)
@@ -651,7 +627,7 @@ def test_prefill_decode_matches_forward(tiny_llama_dir):
     active = jnp.asarray([True, False])
     for _ in range(n_new - 1):
         tokens = tokens.at[0].set(tok)
-        logits, cache = llama.decode_step(params, cfg, tokens, cache, active=active)
+        logits = kv.decode(params, tokens, active=active)
         tok = int(jnp.argmax(logits[0]))
         got.append(tok)
     assert got == expected

@@ -21,6 +21,7 @@ from django_assistant_bot_tpu.ops.quant import (
     unpack_int4,
     weight_bits,
 )
+from paged import Paged
 
 
 def test_quantize_tensor_roundtrip_error_bounded():
@@ -119,17 +120,9 @@ def test_init_int4_shapes_and_decode():
     assert isinstance(wq, QTensor4) and wq.q.dtype == jnp.uint8
     assert wq.group_size == 16
     # prefill + decode run end to end on the packed weights
-    prompt = jnp.asarray([[5, 6, 7, 8, 9]], jnp.int32)
-    lengths = jnp.asarray([5], jnp.int32)
-    cache = llama.init_cache(cfg, batch=1, max_len=32)
-    logits, ks, vs = llama.prefill(p4, cfg, prompt, lengths)
-    cache = llama.insert_sequences(
-        cache, ks, vs, lengths, jnp.asarray([0], jnp.int32)
-    )
-    tok = int(jnp.argmax(logits[0]))
-    logits2, cache = llama.decode_step(
-        p4, cfg, jnp.asarray([tok], jnp.int32), cache
-    )
+    kv = Paged(cfg, batch=1, max_len=32)
+    logits = kv.prefill(p4, [[5, 6, 7, 8, 9]], [5])
+    logits2 = kv.decode(p4, [int(jnp.argmax(logits[0]))])
     assert np.isfinite(np.asarray(logits2)).all()
 
 
@@ -156,15 +149,11 @@ def test_quantized_forward_close_and_decode_consistent():
         seq = np.concatenate([seq, [[int(jnp.argmax(logits[0, -1]))]]], axis=1)
     expected = seq[0, prompt.shape[1]:].tolist()
 
-    cache = llama.init_cache(cfg, batch=1, max_len=32)
-    lengths = jnp.asarray([prompt.shape[1]], jnp.int32)
-    logits, ks, vs = llama.prefill(qparams, cfg, jnp.asarray(prompt), lengths)
-    cache = llama.insert_sequences(cache, ks, vs, lengths, jnp.asarray([0], jnp.int32))
+    kv = Paged(cfg, batch=1, max_len=32)
+    logits = kv.prefill(qparams, prompt, [prompt.shape[1]])
     got = [int(jnp.argmax(logits[0]))]
     for _ in range(3):
-        logits, cache = llama.decode_step(
-            qparams, cfg, jnp.asarray([got[-1]], jnp.int32), cache
-        )
+        logits = kv.decode(qparams, [got[-1]])
         got.append(int(jnp.argmax(logits[0])))
     assert got == expected
 
@@ -246,16 +235,9 @@ def test_init_int8_quantize_embed_serves():
     ids = np.arange(1, 9, dtype=np.int32)[None]
     logits = llama.forward(p_q, cfg, ids)
     assert np.isfinite(np.asarray(logits)).all()
-    lg, ks, vs = llama.prefill(
-        p_q, cfg, ids, np.asarray([ids.shape[1]], np.int32)
-    )
-    cache = llama.init_cache(cfg, 1, 32)
-    cache = llama.insert_sequences(
-        cache, ks, vs, np.asarray([8], np.int32), np.asarray([0], np.int32)
-    )
-    step_logits, cache = llama.decode_step(
-        p_q, cfg, np.asarray([3], np.int32), cache
-    )
+    kv = Paged(cfg, batch=1, max_len=32)
+    kv.prefill(p_q, ids, [ids.shape[1]])
+    step_logits = kv.decode(p_q, [3])
     assert np.isfinite(np.asarray(step_logits)).all()
 
 

@@ -420,7 +420,11 @@ def test_drain_deadline_forces_and_counts_shed():
                 timeout=120
             )
         r.replicas[1].draining = True  # pin the trace onto replica0
-        _stall(engines[0], delay_s=0.2, fires=8)
+        # a second a tick: the three requests need three or four ticks, so
+        # replica0 holds work for seconds, however late this thread gets to
+        # drain() under the suite's six workers (at 0.2 s the trace could be
+        # over first, and the drain then reads "drained")
+        _stall(engines[0], delay_s=1.0, fires=8)
         futs = [
             r.submit([5, 6, 7 + i], max_tokens=4, temperature=0.0)
             for i in range(3)
@@ -534,7 +538,7 @@ def test_router_registry_serves_and_healthz_aggregates(replica_registry):
         assert g["router"]["n_replicas"] == 2
         assert len(g["router"]["replicas"]) == 2
         assert len(g["supervision"]["replicas"]) == 2
-        assert g["kv"]["kv_layout_effective"] == "paged"
+        assert g["kv"]["kv_pages_total"] > 0 and len(g["kv"]["replicas"]) == 2
         assert g["decode"]["decode_kv_path"] == "xla"  # CPU replicas: the plain path
 
         # one dead replica of two: the fleet reports degraded with the dead
@@ -615,27 +619,18 @@ def test_server_graceful_drain_finishes_inflight_then_503s():
         registry.stop()
 
 
-# ------------------------------------------------- kv_layout_effective gauge
-def test_kv_layout_effective_surfaces_requested_vs_effective():
-    """The requested-vs-effective gauge still exists for genuinely
-    non-pageable configs (a context no page size divides), and speculative
-    engines — which used to be the silent-legacy case — now report the
-    paged plane as effective."""
+# ------------------------------------------------- a context no page divides
+def test_context_no_page_divides_is_refused_at_boot():
+    """Speculative engines serve from the page pool like every other, and a
+    prime-length context — no page size divides it — is refused at boot with
+    the number named, where it once silently served from another cache."""
     cfg, params = _params()
     eng = GenerationEngine(
         cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=64,
         speculative=2,
     )
-    ks = eng.kv_stats()
-    assert ks["kv_layout_requested"] == "paged"
-    assert ks["kv_layout_effective"] == "paged"
-    assert eng.tick_stats()["kv"]["kv_layout_effective"] == "paged"
+    assert eng.kv_page_size == 32
+    assert eng.tick_stats()["kv"]["kv_pages_total"] == 4
 
-    # a prime-length context: no page size divides it -> legacy fallback,
-    # and the gauge is how operators see it
-    odd = GenerationEngine(
-        cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=61
-    )
-    ks = odd.kv_stats()
-    assert ks["kv_layout_requested"] == "paged"
-    assert ks["kv_layout_effective"] == "legacy"
+    with pytest.raises(ValueError, match=r"max_seq_len=61 .*kv_page_size=0"):
+        GenerationEngine(cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=61)

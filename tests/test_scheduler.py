@@ -307,20 +307,28 @@ def test_engine_deadline_frees_live_slot_mid_decode(sched_engine):
     """An expired deadline fails the future with DeadlineExceeded AND frees
     the slot promptly (within ~a decode tick) — the request stops burning
     decode work and the next request proceeds."""
+    from django_assistant_bot_tpu.serving.faults import FaultInjector
+
     eng = sched_engine
-    # warm: full greedy decode duration bounds the deadline we pick
-    t0 = time.monotonic()
+    # warm: the programs compile here, not inside the deadline below
     eng.submit([1, 2, 3], max_tokens=200, temperature=0.0).result(timeout=120)
-    warm_s = time.monotonic() - t0
+    for _ in range(100):
+        eng.scheduler.note_service(0.001)  # the deadline passes the admission test
     before = eng.reclaimed_slots
-    fut = eng.submit(
-        [1, 2, 3], max_tokens=200, temperature=0.0, deadline_s=max(0.02, warm_s / 4)
-    )
-    with pytest.raises(DeadlineExceeded):
-        fut.result(timeout=120)
-    deadline = time.monotonic() + 10
-    while eng.num_active > 0 and time.monotonic() < deadline:
-        time.sleep(0.005)
+    # a warm jit cache decodes 200 tokens in an unknown share of any deadline
+    # taken from the wall clock, racing the expiry this test exists to observe
+    # — injected per-tick latency (serving/faults.py slow_tick) pins the
+    # request's residency past its deadline: 25 ticks of 8 steps, 30 ms each
+    eng._faults = FaultInjector({"slow_tick": {"every": 1, "delay_s": 0.03}})
+    try:
+        fut = eng.submit([1, 2, 3], max_tokens=200, temperature=0.0, deadline_s=0.25)
+        with pytest.raises(DeadlineExceeded):
+            fut.result(timeout=120)
+        deadline = time.monotonic() + 10
+        while eng.num_active > 0 and time.monotonic() < deadline:
+            time.sleep(0.005)
+    finally:
+        eng._faults = None
     assert eng.num_active == 0
     assert eng.reclaimed_slots == before + 1
     # engine still healthy

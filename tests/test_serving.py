@@ -22,6 +22,7 @@ from django_assistant_bot_tpu.serving import (
     ModelRegistry,
 )
 from django_assistant_bot_tpu.serving.server import create_app
+from paged import Paged
 
 
 @pytest.fixture(scope="module")
@@ -622,9 +623,9 @@ def test_chat_template_absent_falls_back_to_plain_join():
 # ------------------------------------------------------------- prefix KV cache
 @pytest.mark.slow
 def test_prefill_suffix_matches_full_prefill():
-    """insert_prefix + prefill_suffix must produce the same logits and cache
-    state as one monolithic prefill of prefix+suffix (the prefix cache must
-    not change the math)."""
+    """A suffix prefill over shared prefix pages must produce the same logits
+    and cache state as one monolithic prefill of prefix+suffix (the prefix
+    cache must not change the math)."""
     cfg = DecoderConfig.tiny()
     params = llama.init(cfg, jax.random.key(7))
     rng = np.random.default_rng(11)
@@ -639,39 +640,27 @@ def test_prefill_suffix_matches_full_prefill():
         params, cfg, jnp.asarray(full_ids), jnp.asarray(lengths)
     )
 
-    # prefix path: prefill the prefix once, extract, insert into fresh slots,
-    # then batched suffix prefill
-    p_logits, p_ks, p_vs = llama.prefill(
-        params, cfg, jnp.asarray([prefix], np.int32), jnp.asarray([P], np.int32)
-    )
-    cache = llama.init_cache(cfg, 3, S)
-    cache = llama.insert_sequences(
-        cache, p_ks, p_vs, jnp.asarray([P], np.int32), jnp.asarray([0], np.int32)
-    )
-    pk, pv = llama.extract_prefix(cache, jnp.asarray(0, jnp.int32), P)
-    for slot in (1, 2):
-        cache = llama.insert_prefix(cache, pk, pv, jnp.asarray(slot, jnp.int32))
-    suffix_ids = jnp.asarray(suffixes, np.int32)
-    logits, cache = llama.prefill_suffix(
-        params,
-        cfg,
-        suffix_ids,
-        cache,
-        jnp.asarray([1, 2], np.int32),
-        jnp.asarray([P, P], np.int32),
-        jnp.asarray([C, C], np.int32),
-    )
+    # prefix path: prefill the prefix once into slot 0, wire its pages (the
+    # prefix is a whole number of pages) into slots 1 and 2 as the engine's
+    # admission does, then one batched suffix prefill
+    kv = Paged(cfg, batch=3, max_len=S, page=8)
+    kv.prefill(params, [prefix], [P], slots=[0])
+    nbp = P // 8
+    kv.bt = kv.bt.at[1:, :nbp].set(kv.bt[0, :nbp])
+    shared_before = np.asarray(kv.cache.k[:, kv.bt[0, :nbp]])
+    logits = kv.suffix(params, suffixes, slots=[1, 2], starts=[P, P], valids=[C, C])
     np.testing.assert_allclose(
         np.asarray(logits), np.asarray(ref_logits), rtol=2e-4, atol=2e-4
     )
-    assert np.asarray(cache.lengths)[1:3].tolist() == [P + C, P + C]
-    # cache K/V of the suffix region must match the monolithic prefill's
+    assert np.asarray(kv.cache.lengths)[1:3].tolist() == [P + C, P + C]
+    # the shared pages were read, never re-written
+    np.testing.assert_array_equal(np.asarray(kv.cache.k[:, kv.bt[0, :nbp]]), shared_before)
+    # cache K/V of each row (prefix + suffix) must match the monolithic prefill's
     for slot, row in ((1, 0), (2, 1)):
+        pages = np.asarray(kv.cache.k[:, kv.bt[slot]])  # [L, NB, KH, page, D]
+        rows = pages.transpose(0, 2, 1, 3, 4).reshape(pages.shape[0], pages.shape[2], -1, pages.shape[4])
         np.testing.assert_allclose(
-            np.asarray(cache.k[:, slot, :, : P + C]),
-            np.asarray(ref_ks[:, row, :, : P + C]),
-            rtol=2e-4,
-            atol=2e-4,
+            rows[:, :, : P + C], np.asarray(ref_ks[:, row, :, : P + C]), rtol=2e-4, atol=2e-4
         )
 
 
@@ -812,53 +801,6 @@ def test_probe_decode_and_tick_stats():
         eng.probe_decode(iters=1)
 
 
-def test_prefix_cache_byte_cap_and_bucket():
-    """Prefix device shape never falls back to max_seq_len (the ~1 GB/entry
-    pinning at 8B geometry), and the byte budget LRU-evicts."""
-    cfg = DecoderConfig.tiny()
-    params = llama.init(cfg, jax.random.key(5))
-    eng = GenerationEngine(
-        cfg, params, ByteTokenizer(), max_slots=2, max_seq_len=512,
-        prefill_buckets=(32, 64), chunk_size=64,
-        prefix_cache_size=8, prefix_min_tokens=8,
-        kv_layout="legacy",  # this test pins the legacy pinned-K/V LRU path
-    )
-    # bucket: fits a prefill bucket -> that bucket; else multiples of the
-    # largest bucket, capped at the engine's (cfg-clamped) max_seq_len —
-    # never the raw max_seq_len fallback for short prefixes
-    assert eng._prefix_bucket(20) == 32
-    assert eng._prefix_bucket(64) == 64
-    assert eng._prefix_bucket(65) == 128
-    assert eng._prefix_bucket(130) == 192
-    assert eng._prefix_bucket(10_000) == eng.max_seq_len
-
-    eng.start()
-    try:
-        sys_a = "context block alpha " * 4
-        sys_b = "context block beta " * 4
-        for s in (sys_a, sys_b):
-            asyncio.run(eng.generate(
-                [{"role": "system", "content": s}, {"role": "user", "content": "q"}],
-                max_tokens=2, temperature=0.0,
-            ))
-        assert len(eng._prefix_lru) == 2
-        assert eng._prefix_bytes == sum(
-            e.pk.nbytes + e.pv.nbytes for e in eng._prefix_lru.values()
-        )
-        # shrink the budget below one entry: next registration evicts to fit
-        one = next(iter(eng._prefix_lru.values()))
-        eng.prefix_cache_max_bytes = one.pk.nbytes + one.pv.nbytes
-        asyncio.run(eng.generate(
-            [{"role": "system", "content": "context block gamma " * 4},
-             {"role": "user", "content": "q"}],
-            max_tokens=2, temperature=0.0,
-        ))
-        assert len(eng._prefix_lru) == 1
-        assert eng._prefix_bytes <= eng.prefix_cache_max_bytes
-    finally:
-        eng.stop()
-
-
 def test_encode_chat_split_memoizes_head_encoding():
     """The shared head's encode is cached on the tokenizer (the prefix-KV
     workload re-sends a near-identical multi-KB head every turn)."""
@@ -962,14 +904,9 @@ def test_engine_fp8_kv_cache_serves():
     lg, ks, vs = llama.prefill(params, cfg, jnp.asarray(ids), jnp.asarray(lengths))
     outs = {}
     for dt in (None, jnp.float8_e4m3fn):
-        cache = llama.init_cache(cfg, 1, 64, dtype=dt)
-        cache = llama.insert_sequences(
-            cache, ks, vs, jnp.asarray(lengths), jnp.asarray([0], np.int32)
-        )
-        step_lg, _ = llama.decode_step(
-            params, cfg, jnp.asarray([5], np.int32), cache
-        )
-        outs[dt] = np.asarray(step_lg[0])
+        kv = Paged(cfg, batch=1, max_len=64, dtype=dt)
+        kv.insert(ks, vs, lengths)
+        outs[dt] = np.asarray(kv.decode(params, [5])[0])
     a, b = outs[None], outs[jnp.float8_e4m3fn]
     cos = float(np.dot(a, b) / (np.linalg.norm(a) * np.linalg.norm(b)))
     assert cos > 0.98, cos

@@ -29,6 +29,7 @@ from django_assistant_bot_tpu.ops.speculative import (
     default_rungs,
     make_tree_spec,
 )
+from paged import Paged
 
 
 @pytest.fixture(scope="module")
@@ -39,33 +40,29 @@ def tiny():
 
 
 def _prefill_into(cfg, params, prompt, batch=2, max_len=64):
-    cache = llama.init_cache(cfg, batch=batch, max_len=max_len, dtype=jnp.float32)
-    lengths = jnp.asarray([prompt.shape[1]], jnp.int32)
-    logits, ks, vs = llama.prefill(params, cfg, jnp.asarray(prompt), lengths)
-    cache = llama.insert_sequences(
-        cache, ks, vs, lengths, jnp.asarray([0], jnp.int32)
-    )
-    return int(jnp.argmax(logits[0])), cache
+    kv = Paged(cfg, batch=batch, max_len=max_len, dtype=jnp.float32)
+    logits = kv.prefill(params, prompt, [prompt.shape[1]], slots=[0])
+    return int(jnp.argmax(logits[0])), kv
 
 
 def _greedy_reference(cfg, params, prompt, n_new):
-    tok, cache = _prefill_into(cfg, params, prompt)
+    tok, kv = _prefill_into(cfg, params, prompt)
     got = [tok]
     tokens = jnp.zeros((2,), jnp.int32)
     active = jnp.asarray([True, False])
     for _ in range(n_new - 1):
         tokens = tokens.at[0].set(got[-1])
-        logits, cache = llama.decode_step(params, cfg, tokens, cache, active=active)
+        logits = kv.decode(params, tokens, active=active)
         got.append(int(jnp.argmax(logits[0])))
     return got
 
 
-def _run_tree(cfg, params, cache, tree_tokens, spec, temps=None):
-    """verify_tree_step + accept_tree on a [2, T] batch (row 1 inert)."""
+def _run_tree(cfg, params, kv, tree_tokens, spec, temps=None):
+    """verify_tree_step_paged + accept_tree on a [2, T] batch (row 1 inert)."""
     depths = jnp.asarray(spec.depths)
     anc = jnp.asarray(spec.anc_mask)
-    logits, tks, tvs = llama.verify_tree_step(
-        params, cfg, jnp.asarray(tree_tokens, jnp.int32), cache, depths, anc
+    logits, tks, tvs = llama.verify_tree_step_paged(
+        params, cfg, jnp.asarray(tree_tokens, jnp.int32), kv.cache, kv.bt, depths, anc
     )
     out, n_new, bonus, path_idx, _ = accept_tree(
         logits,
@@ -145,7 +142,7 @@ def test_tree_accepts_oracle_branch_at_any_position(tiny):
     prompt = np.array([[1, 5, 9, 17, 3]], np.int32)
     K, N = 3, 3
     ref = _greedy_reference(cfg, params, prompt, K + 2)
-    tok, cache = _prefill_into(cfg, params, prompt)
+    tok, kv = _prefill_into(cfg, params, prompt)
     assert tok == ref[0]
     spec = make_tree_spec(N, K)
     tree = np.zeros((2, spec.size), np.int32)
@@ -154,7 +151,7 @@ def test_tree_accepts_oracle_branch_at_any_position(tiny):
     tree[0, spec.branch_nodes[1]] = ref[1 : K + 1]  # the oracle branch
     tree[0, spec.branch_nodes[2]] = [3, 499, 3]
     _, tks, tvs, out, n_new, bonus, path_idx = _run_tree(
-        cfg, params, cache, tree, spec
+        cfg, params, kv, tree, spec
     )
     assert int(n_new[0]) == K + 1  # every oracle draft accepted + bonus
     assert np.asarray(out)[0, : K + 1].tolist() == ref[1 : K + 2]
@@ -171,27 +168,27 @@ def test_tree_rejects_garbage_and_cache_stays_sound(tiny):
     prompt = np.array([[2, 11, 4, 30]], np.int32)
     n_total = 6
     ref = _greedy_reference(cfg, params, prompt, n_total)
-    tok, cache = _prefill_into(cfg, params, prompt)
+    tok, kv = _prefill_into(cfg, params, prompt)
     K, N = 3, 2
     spec = make_tree_spec(N, K)
     tree = np.full((2, spec.size), 499, np.int32)
     tree[0, 0] = tok
     tree[1, :] = 0
     _, tks, tvs, out, n_new, bonus, path_idx = _run_tree(
-        cfg, params, cache, tree, spec
+        cfg, params, kv, tree, spec
     )
     assert int(n_new[0]) == 1
     assert int(out[0, 0]) == ref[1]
-    cache = llama.commit_tree_path(cache, tks, tvs, path_idx)
-    cache = cache._replace(
+    active = jnp.asarray([True, False])
+    cache = llama.commit_tree_path_paged(kv.cache, tks, tvs, path_idx, kv.bt, n_new, active)
+    kv.cache = cache._replace(
         lengths=cache.lengths.at[0].set(int(cache.lengths[0]) + 1)
     )
     got = [tok, int(out[0, 0])]
     tokens = jnp.zeros((2,), jnp.int32)
-    active = jnp.asarray([True, False])
     for _ in range(n_total - 2):
         tokens = tokens.at[0].set(got[-1])
-        lg, cache = llama.decode_step(params, cfg, tokens, cache, active=active)
+        lg = kv.decode(params, tokens, active=active)
         got.append(int(jnp.argmax(lg[0])))
     assert got == ref
 
@@ -219,19 +216,19 @@ def test_accept_tree_sampled_rows_take_position_zero():
 
 def test_verify_tree_is_read_only_wrt_cache(tiny):
     """The tree verify must not mutate the cache — the accepted-path commit
-    is the ONLY write (what lets the paged layout carry speculation)."""
+    is the ONLY write."""
     cfg, params = tiny
     prompt = np.array([[1, 5, 9, 17, 3]], np.int32)
-    tok, cache = _prefill_into(cfg, params, prompt)
-    k_before = np.asarray(cache.k)
+    tok, kv = _prefill_into(cfg, params, prompt)
+    k_before = np.asarray(kv.cache.k)
     spec = make_tree_spec(2, 2)
     tree = np.zeros((2, spec.size), np.int32)
     tree[0, 0] = tok
-    llama.verify_tree_step(
-        params, cfg, jnp.asarray(tree), cache,
+    llama.verify_tree_step_paged(
+        params, cfg, jnp.asarray(tree), kv.cache, kv.bt,
         jnp.asarray(spec.depths), jnp.asarray(spec.anc_mask),
     )
-    assert np.array_equal(k_before, np.asarray(cache.k))
+    assert np.array_equal(k_before, np.asarray(kv.cache.k))
 
 
 # ---------------------------------------------------------------- controller
@@ -387,42 +384,7 @@ def test_spec_engine_greedy_equivalence_property():
             else:  # sampled rows: just complete within bounds
                 assert 1 <= len(spec[i]) <= 16
         assert stats["spec_drafted"] > 0
-        assert stats["kv"]["kv_layout_effective"] == "paged"
-
-
-def test_spec_engine_paged_vs_legacy_byte_identity():
-    """The same speculative workload on the paged plane and the legacy slot
-    cache must produce identical greedy tokens — the paged tree commit is a
-    layout change, never a numerics change.  (The legacy arm pins
-    decode_kv_chunk to the paged arm's page size so any plain fallback ticks
-    run the byte-identical chunked read, per the PR 6 contract.)"""
-    from django_assistant_bot_tpu.serving import ByteTokenizer
-
-    tok = ByteTokenizer()
-    cfg = DecoderConfig.tiny()
-    params = llama.init(cfg, jax.random.PRNGKey(11))
-    jobs = [
-        (tok.encode("ab ab ab ab ab ab ab"), 12, 0.0),
-        (tok.encode("context: x y z. context: x y"), 12, 0.0),
-    ]
-    paged_eng = _spec_engine(
-        cfg, params, tok, spec=3, spec_width=2, max_seq_len=128,
-        decode_kv_chunk=32, kv_layout="paged",
-    )
-    page = paged_eng.kv_page_size
-    assert paged_eng.paged and page == 32
-    paged, pstats = _run_engine(paged_eng, jobs)
-    legacy, _ = _run_engine(
-        _spec_engine(
-            cfg, params, tok, spec=3, spec_width=2, max_seq_len=128,
-            decode_kv_chunk=page, kv_layout="legacy",
-        ),
-        jobs,
-    )
-    assert paged == legacy
-    assert pstats["kv"]["kv_layout_requested"] == "paged"
-    assert pstats["kv"]["kv_layout_effective"] == "paged"
-    assert pstats["spec_drafted"] > 0
+        assert stats["kv"]["kv_pages_total"] > 0
 
 
 def test_spec_k_bounded_against_max_seq_len():
@@ -456,7 +418,6 @@ def test_tick_raise_mid_verify_restart_leaves_page_pool_clean():
         cfg, params, tok, max_slots=2, max_seq_len=96, speculative=3,
         spec_width=2, spec_probe_every=1, prefix_cache_size=0, faults=inj,
     )
-    assert eng.paged
     eng.start()
     try:
         # let the engine go live, then arm: the NEXT dispatch — a speculative
@@ -687,7 +648,7 @@ def test_healthz_carries_spec_gauges():
                 "spec_load_disabled", "spec_skipped_load", "spec_skipped_accept",
             ):
                 assert key in spec, key
-            assert g["kv"]["kv_layout_effective"] == "paged"
+            assert g["kv"]["kv_pages_total"] > 0
             # the scheduler's stats carry the same gauge (bind_spec): load-
             # vs acceptance-disable side by side where queue pressure lives
             assert "spec_disabled" in g["sched"]
