@@ -863,7 +863,7 @@ def phase_multichip(cfg: SmokeConfig, new_tokens: int = 8) -> Dict[str, Any]:
         out: Dict[str, Any] = {"logits": {}, "tokens": {}, "kernel": {}}
         try:
             for name, ids in prompts.items():
-                bucket = pick_bucket(len(ids), eng.prefill_buckets, eng.chunk_size)
+                bucket = pick_bucket(len(ids), eng.prefill_shapes, eng.chunk_size)
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, : len(ids)] = ids
                 lengths = np.asarray([len(ids)], np.int32)

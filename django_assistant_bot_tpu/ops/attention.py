@@ -978,6 +978,12 @@ def _flash_kernel(
         o_ref[:] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
 
 
+# the flash kernel's block of queries (and of keys): prefill sequences that
+# are whole blocks, two or more, reach the kernel (:func:`attention`), and the
+# engine steps its prefill buckets by it (serving/engine.py ``prefill_shapes``)
+FLASH_BLOCK = 128
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -990,8 +996,8 @@ def flash_attention(
     v: jnp.ndarray,  # [B, H, Sk, Dv]: Dv may differ from D (latent attention: 192 / 128)
     *,
     causal: bool = False,
-    block_q: int = 128,
-    block_kv: int = 128,
+    block_q: int = FLASH_BLOCK,
+    block_kv: int = FLASH_BLOCK,
     interpret: bool = False,
     window: Optional[int] = None,
     chunk_kv: Optional[int] = None,  # default: min(8192, Sk); tests force smaller
@@ -1147,9 +1153,9 @@ def attention(
     D, Dv = q.shape[-1], v.shape[-1]
     kernel_shaped = (
         mask is None
-        and q.shape[2] >= 256
-        and q.shape[2] % 128 == 0
-        and k.shape[2] % 128 == 0
+        and q.shape[2] >= 2 * FLASH_BLOCK
+        and q.shape[2] % FLASH_BLOCK == 0
+        and k.shape[2] % FLASH_BLOCK == 0
         and (D == 64 or D % 128 == 0)
         and (Dv == 64 or Dv % 128 == 0)
         and isinstance(q_offset, int)

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import DecoderConfig, EncoderConfig, encoder, module_for
+from ..ops.attention import FLASH_BLOCK
 from ..ops.sampling import sample_logits
 from ..parallel.sharding import mesh_scope
 from .obs import EngineObs, LoopLedger, new_trace_id
@@ -46,8 +47,6 @@ from .scheduler import DeadlineExceeded, RequestScheduler, SchedulerRejected
 from .tokenizer import Tokenizer
 
 logger = logging.getLogger(__name__)
-
-PREFILL_BUCKETS = (32, 64, 128, 256, 512, 1024, 2048, 4096, 8192)
 
 
 class RequestPoisoned(RuntimeError):
@@ -109,6 +108,68 @@ def pick_bucket(n: int, buckets: Sequence[int], cap: int) -> int:
         if n <= b and b <= cap:
             return b
     return cap
+
+
+def prefill_shapes(
+    chunk_size: int, wave: int, buckets: Optional[Sequence[int]] = None
+) -> Dict[int, tuple]:
+    """Every prefill program an engine dispatches, as ``{bucket: row counts}``:
+    what warm-up compiles and what :func:`plan_prefill` picks from, so the two
+    cannot drift apart.
+
+    Sequence buckets: the ones a deployment names, or else half a flash block
+    (a chat format alone is ~18 tokens) and then every whole block up to
+    ``chunk_size``, so a prompt of more than a block is padded by less than
+    one; ``chunk_size`` itself is always the last (a prompt of up to a chunk
+    rides one program).  Rows: a program holds at most one chunk's positions
+    (rows x bucket <= ``chunk_size``) and at most ``wave`` rows; under that
+    cap a bucket has 1, 2 and the largest power of two, so a wave of one or
+    two pays for no padding row and many short prompts still share one read
+    of the weights."""
+    seq = buckets or (FLASH_BLOCK // 2, *range(FLASH_BLOCK, chunk_size, FLASH_BLOCK))
+    shapes = {}
+    for b in sorted({int(b) for b in seq if b < chunk_size} | {chunk_size}):
+        cap = max(1, min(chunk_size // b, wave))
+        shapes[b] = tuple(sorted({1, min(2, cap), 1 << (cap.bit_length() - 1)}))
+    return shapes
+
+
+# What a prefill program costs beyond its positions, in positions: its read of
+# the weights and its dispatch, which it pays whatever its size.  Set from one
+# ``_prefill`` program's time by shape on a v5e at Qwen2.5-7B int8 widths
+# (``tools/time_prefill.py``; CHANGES.md, PR 35: 1 x 128 15.1 ms, 1 x 512
+# 45.8, 8 x 128 77.0): with 96 to 127 here, 1-8 rows of any one bucket ride
+# the programs those times make cheapest; at 128 six rows of 128 take 8 x 128
+# (77.0 ms) where 2 + 2 + 2 reads 67.7, below 48 seven take 2 + 2 + 2 + 1.
+PREFILL_PROGRAM_POSITIONS = 96
+
+
+def plan_prefill(shapes: Dict[int, tuple], lengths: Sequence[int]) -> List[tuple]:
+    """The programs one admission wave dispatches: ``[(rows, bucket, members)]``
+    with ``members`` the indices into ``lengths`` (the tokens each admitted row
+    runs, in admission order) that ride the program, ``rows - len(members)`` of
+    it padding.  Rows are grouped by their own bucket (a short prompt never
+    pays a long one's), and a group takes the programs of ``shapes`` that cost
+    least: a program costs its positions plus ``PREFILL_PROGRAM_POSITIONS``,
+    fewer programs on a tie.  3 rows at 128 ride 2 + 1, not 8; 5 rows at 64
+    ride one program of 8."""
+    groups: Dict[int, List[int]] = {}
+    for i, n in enumerate(lengths):
+        groups.setdefault(pick_bucket(n, shapes, max(shapes)), []).append(i)
+    programs = []
+    for bucket, members in groups.items():
+        # best[n]: (cost, programs, their rows) of the cheapest cover of n rows
+        best: List[tuple] = [(0, 0, ())]
+        for n in range(1, len(members) + 1):
+            best.append(min(
+                (cost + r * bucket + PREFILL_PROGRAM_POSITIONS, count + 1, rows + (r,))
+                for r in shapes[bucket]
+                for cost, count, rows in (best[max(0, n - r)],)
+            ))
+        for rows in sorted(best[-1][2], reverse=True):
+            programs.append((rows, bucket, members[:rows]))
+            members = members[rows:]
+    return programs
 
 
 @dataclasses.dataclass
@@ -187,9 +248,8 @@ class _Request:
     # was the entry point
     received_at: Optional[float] = None
     # what the prefill program this request rode looked like: its sequence
-    # bucket, the wave's real rows and the batch bucket they padded to
-    # (chunked prefills: chunk_size, 1, 1), and the prompt tokens a prefix
-    # hit spared it
+    # bucket, its real rows and the rows it was compiled for (chunked
+    # prefills: chunk_size, 1, 1), and the prompt tokens a prefix hit spared it
     prefill_bucket: int = 0
     wave_rows: int = 0
     wave_rows_padded: int = 0
@@ -312,7 +372,7 @@ class GenerationEngine:
         max_slots: int = 8,
         max_seq_len: Optional[int] = None,
         top_k: int = 50,
-        prefill_buckets: Sequence[int] = PREFILL_BUCKETS,
+        prefill_buckets: Optional[Sequence[int]] = None,
         idle_poll_s: float = 0.002,
         chunk_size: int = 512,
         lookahead: int = 3,
@@ -391,20 +451,24 @@ class GenerationEngine:
         self.params = params
         self.tokenizer = tokenizer
         self.max_slots = max_slots
-        # the most rows one prefill dispatch admits (0 = all slots): bounds the
-        # largest prefill program's temporaries, and with them the programs
-        # warm-up compiles ({1, 4, wave} x seq buckets)
+        # the most rows one admission wave takes (0 = all slots), and so the
+        # most one prefill program holds (prefill_shapes)
         self.prefill_wave = min(max_slots, int(prefill_wave) or max_slots)
         self.max_seq_len = int(min(max_seq_len or cfg.max_seq_len, cfg.max_seq_len))
         self.top_k = top_k
-        self.prefill_buckets = tuple(b for b in prefill_buckets if b <= self.max_seq_len) or (
-            self.max_seq_len,
-        )
         self.idle_poll_s = idle_poll_s
         # Prompts longer than one chunk prefill incrementally: one chunk per engine
         # loop iteration, a decode tick for the live slots in between.  Decode
         # head-of-line blocking is bounded by a chunk, not by the longest prompt.
         self.chunk_size = int(min(chunk_size, self.max_seq_len))
+        # the (rows, bucket) shapes of the prefill programs: derived from the
+        # chunk, the wave and the flash kernel's block unless the deployment
+        # names its buckets; warm-up compiles them all and admission
+        # dispatches no other (tick_stats()["prefill_shapes"] counts each)
+        self.prefill_shapes = prefill_shapes(self.chunk_size, self.prefill_wave, prefill_buckets)
+        self._ledger.prefill_shapes = {
+            f"{rows}x{b}": 0 for b, rs in self.prefill_shapes.items() for rows in rs
+        }
         # Decode lookahead pipeline: ticks are issued with the *device* token array
         # chained tick-to-tick (no host value needed), results stream back via
         # copy_to_host_async, and the host processes them `lookahead` ticks behind.
@@ -2083,14 +2147,16 @@ class GenerationEngine:
         slide left past the prefix boundary (prefix within one bucket of the
         context end): the slid window would re-WRITE physically shared pages,
         and a duplicate-index scatter with near-identical recomputed values is
-        undefined.  The chunked path never slides into the prefix
-        (remainder > chunk_size guarantees the final chunk starts past it)."""
+        undefined.  The smallest derived bucket is 64, so a hit whose prefix
+        ends within 64 tokens of the context's end is prefilled in full.  The
+        chunked path never slides into the prefix (remainder > chunk_size
+        guarantees the final chunk starts past it)."""
         if hit is None:
             return None
         n_eff = len(req.prompt_ids) - hit.length
         if n_eff > self.chunk_size:
             return hit
-        b = pick_bucket(n_eff, self.prefill_buckets, self.chunk_size)
+        b = pick_bucket(n_eff, self.prefill_shapes, self.chunk_size)
         if hit.length + b > self.max_seq_len:
             return None
         return hit
@@ -2329,37 +2395,32 @@ class GenerationEngine:
             else:
                 batch.append((slot, req, hit))
         if batch:
-            # group the wave by seq bucket: short prompts must not pay the
-            # longest prompt's O(S^2) attention; one dispatch per bucket group.
-            # Prefix-hit rows prefill only their SUFFIX (bucketed by suffix
-            # length) via prefill_suffix; misses take the full-prompt path.
-            full_groups: Dict[int, List[tuple[int, _Request]]] = {}
-            suffix_groups: Dict[int, List[tuple[int, _Request, Any]]] = {}
-            for slot, req, hit in batch:
-                if hit is not None:
-                    b = pick_bucket(
-                        len(req.prompt_ids) - hit.length,
-                        self.prefill_buckets,
-                        self.chunk_size,
-                    )
-                    suffix_groups.setdefault(b, []).append((slot, req, hit))
-                else:
-                    b = pick_bucket(
-                        len(req.prompt_ids), self.prefill_buckets, self.chunk_size
-                    )
-                    full_groups.setdefault(b, []).append((slot, req))
+            # one dispatch per program of the plan (plan_prefill): the wave
+            # grouped by seq bucket, so short prompts do not pay the longest
+            # prompt's O(S^2) attention, and each group on the fewest
+            # positions the warmed shapes allow.  Prefix-hit rows prefill only
+            # their SUFFIX (bucketed by suffix length) via prefill_suffix;
+            # misses take the full-prompt path.
+            full = [(slot, req) for slot, req, hit in batch if hit is None]
+            suffix = [(slot, req, hit) for slot, req, hit in batch if hit is not None]
             # every not-yet-slotted request of the wave stays in
-            # _starting_batch until its group succeeds — if an earlier group's
-            # prefill raises, _restart salvages the rest instead of orphaning
-            remaining = [pair for group in full_groups.values() for pair in group]
-            remaining += [(s, r) for group in suffix_groups.values() for s, r, _ in group]
+            # _starting_batch until its program succeeds — if an earlier
+            # program's prefill raises, _restart salvages the rest instead of
+            # orphaning them
+            remaining = full + [(s, r) for s, r, _ in suffix]
             self._starting_batch = remaining
-            for group in full_groups.values():
-                self._start_batch(group)
+            for rows, bucket, members in plan_prefill(
+                self.prefill_shapes, [len(r.prompt_ids) for _, r in full]
+            ):
+                group = [full[i] for i in members]
+                self._start_batch(group, rows, bucket)
                 for pair in group:
                     remaining.remove(pair)
-            for sgroup in suffix_groups.values():
-                self._start_suffix_batch(sgroup)
+            for rows, bucket, members in plan_prefill(
+                self.prefill_shapes, [len(r.prompt_ids) - h.length for _, r, h in suffix]
+            ):
+                sgroup = [suffix[i] for i in members]
+                self._start_suffix_batch(sgroup, rows, bucket)
                 for s, r, _ in sgroup:
                     remaining.remove((s, r))
             self._starting_batch = None
@@ -2376,11 +2437,10 @@ class GenerationEngine:
             else:
                 self.prefix_misses += 1
 
-    def warmup(
-        self, seq_buckets: Optional[Sequence[int]] = None, json: bool = False
-    ) -> None:
-        """Deterministically compile every (batch-bucket, seq-bucket) prefill +
-        insert + activation shape and the decode tick.  Admission-wave sizes are
+    def warmup(self, json: bool = False) -> None:
+        """Deterministically compile every (rows, bucket) prefill + insert +
+        activation shape of ``prefill_shapes`` (the set admission picks from)
+        and the decode tick.  Admission-wave sizes are
         timing-dependent, so relying on warm *traffic* to hit every shape is
         racy — a multi-second XLA compile can land mid-measurement (or mid-SLA).
         ``json=True`` additionally builds the token FSM and compiles the
@@ -2390,20 +2450,11 @@ class GenerationEngine:
         if self._running:
             raise RuntimeError("warmup() must run before start() — the engine "
                                "thread owns the cache once running")
-        buckets = set(
-            b
-            for b in (seq_buckets if seq_buckets is not None else self.prefill_buckets)
-            if b <= self.chunk_size
-        )
-        # pick_bucket falls back to the cap when no bucket fits — that shape
-        # must be warm too or an odd-length prompt compiles at serve time
-        buckets.add(self.chunk_size)
-        buckets = tuple(sorted(buckets))
         if json:
             self._ensure_fsm()
         with self._mesh_scope():
-            for bucket in buckets:
-                for bp in self._batch_buckets():
+            for bucket, row_counts in self.prefill_shapes.items():
+                for bp in row_counts:
                     ids = jnp.zeros((bp, bucket), jnp.int32)
                     lengths = jnp.zeros((bp,), jnp.int32)
                     logits, ks, vs = self._prefill(self.params, ids, lengths)
@@ -2480,8 +2531,8 @@ class GenerationEngine:
                 # prefix path: the batched suffix prefill per (batch, seq)
                 # bucket plus the COW page clone — sentinel targets, so every
                 # warmup write drops
-                for bucket in buckets:
-                    for bp in self._batch_buckets():
+                for bucket, row_counts in self.prefill_shapes.items():
+                    for bp in row_counts:
                         logits, self._cache = self._prefill_suffix(
                             self.params,
                             jnp.zeros((bp, bucket), jnp.int32),
@@ -2578,14 +2629,6 @@ class GenerationEngine:
                 mx = max(mx, min(pos, self.max_seq_len - 1))
         return (mx // self.kv_page_size + 1) / self._kv_blocks
 
-    def _batch_buckets(self) -> tuple:
-        """Prefill batch-dim buckets: {1, 4, prefill_wave} (the wave is
-        max_slots unless capped) — a whole admission wave prefills in ONE
-        dispatch while the compiled-shape space stays 3 x seq-buckets
-        (pow-of-two padding would explode it) and single-request admission
-        pays no padding."""
-        return tuple(sorted({1, min(4, self.prefill_wave), self.prefill_wave}))
-
     def _wave_block_tables(self, slots: List[int], pad: int) -> np.ndarray:
         """Block-table rows for a prefill wave ([Bp, n_blocks]); the first
         ``pad`` rows are batch-bucket padding and carry the page sentinel
@@ -2597,19 +2640,15 @@ class GenerationEngine:
             bt[pad + j] = self._block_tables[slot]
         return bt
 
-    def _start_batch(self, batch: List[tuple[int, _Request]]):
-        """One prefill dispatch for every request admitted this wave.
+    def _start_batch(self, batch: List[tuple[int, _Request]], Bp: int, bucket: int):
+        """One prefill dispatch: the ``Bp`` x ``bucket`` program of the wave's
+        plan (:func:`plan_prefill`) that ``batch`` rides.
 
-        The batch dim pads to a bucket; pad rows carry zero lengths, PRECEDE the
-        real rows, and carry the ``max_slots`` / page sentinels, so their
-        writes drop on the device."""
+        Pad rows carry zero lengths, PRECEDE the real rows, and carry the
+        ``max_slots`` / page sentinels, so their writes drop on the device."""
         reqs = [r for _, r in batch]
         slots = [s for s, _ in batch]
         B = len(batch)
-        bucket = pick_bucket(
-            max(len(r.prompt_ids) for r in reqs), self.prefill_buckets, self.chunk_size
-        )
-        Bp = pick_bucket(B, self._batch_buckets(), self.prefill_wave)
         with self._ledger.span("prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp):
             pad = Bp - B
             ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
@@ -2637,8 +2676,8 @@ class GenerationEngine:
             # requests (pure refcounting — admission never blocks on it)
             for slot, req in batch:
                 self._maybe_register_prefix(slot, req)
-            # activation consumes the FULL [Bp, V] logits so its (eager) sampling
-            # and scatter shapes key on the batch bucket, not the wave size —
+            # activation consumes the FULL [Bp, V] logits so its sampling and
+            # scatter shapes key on the program's rows, not the wave size —
             # otherwise every distinct wave size would trigger fresh compiles
             self._activate_batch(slots, reqs, logits, pad=pad)
 
@@ -2651,21 +2690,18 @@ class GenerationEngine:
             req.wave_rows = len(reqs)
             req.wave_rows_padded = Bp
 
-    def _start_suffix_batch(self, group: List[tuple[int, _Request, Any]]):
-        """Admit a wave of prefix-cache hits: admission already wired the
-        shared pages into each slot's block table, so ONE batched suffix
-        prefill continues all rows from their prefix lengths; the skipped work
-        is exactly the prefix recompute the reference pays every turn."""
+    def _start_suffix_batch(
+        self, group: List[tuple[int, _Request, Any]], Bp: int, bucket: int
+    ):
+        """Admit prefix-cache hits on one ``Bp`` x ``bucket`` program of the
+        wave's plan: admission already wired the shared pages into each
+        slot's block table, so ONE batched suffix prefill continues all rows
+        from their prefix lengths; the skipped work is exactly the prefix
+        recompute the reference pays every turn."""
         slots = [s for s, _, _ in group]
         reqs = [r for _, r, _ in group]
         hits = [h for _, _, h in group]
         B = len(group)
-        bucket = pick_bucket(
-            max(len(r.prompt_ids) - h.length for r, h in zip(reqs, hits)),
-            self.prefill_buckets,
-            self.chunk_size,
-        )
-        Bp = pick_bucket(B, self._batch_buckets(), self.prefill_wave)
         with self._ledger.span(
             "prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp, suffix=1
         ):
@@ -3042,12 +3078,15 @@ class GenerationEngine:
         running totals: ``loop`` = ``{phase: {"s": exclusive seconds, "n":
         spans}}`` over the engine thread's whole life (the phases tile its
         wall time), and the prefill positions run: prompt tokens (``real``)
-        against rows x bucket of the dispatched programs (``padded``)."""
+        against rows x bucket of the dispatched programs (``padded``), and
+        the dispatches by shape (``prefill_shapes``: ``"<rows>x<bucket>"`` ->
+        count, every warmed shape listed; a chunk counts as 1 x chunk)."""
         led = self._ledger
         return {
             "loop": led.snapshot(),
             "prefill_tokens_real": led.prefill_tokens_real,
             "prefill_tokens_padded": led.prefill_tokens_padded,
+            "prefill_shapes": dict(led.prefill_shapes),
         }
 
     def decode_path_stats(self) -> dict:

@@ -533,7 +533,9 @@ class LoopLedger:
 
     Also carries the prefill padding counters: tokens the prompts held
     (``real``) against rows x bucket of the programs that ran them
-    (``padded``)."""
+    (``padded``), and the dispatches by shape (``prefill_shapes``, keyed
+    ``"<rows>x<bucket>"``; the engine lists every shape it warms at 0, so the
+    keys never change under a reader)."""
 
     def __init__(
         self,
@@ -549,6 +551,7 @@ class LoopLedger:
         self._spans: Dict[str, _PhaseSpan] = {p: _PhaseSpan(self, p) for p in phases}
         self.prefill_tokens_real = 0
         self.prefill_tokens_padded = 0
+        self.prefill_shapes: Dict[str, int] = {}
 
     def span(self, name: str, **args: Any) -> _PhaseSpan:
         """The phase's enter/exit pair; ``args`` become the profiler
@@ -560,6 +563,8 @@ class LoopLedger:
     def note_prefill(self, real: int, rows_padded: int, bucket: int) -> None:
         self.prefill_tokens_real += int(real)
         self.prefill_tokens_padded += int(rows_padded) * int(bucket)
+        shape = f"{rows_padded}x{bucket}"
+        self.prefill_shapes[shape] = self.prefill_shapes.get(shape, 0) + 1
 
     def seconds(self, name: str) -> float:
         return self._spans[name].s
@@ -917,6 +922,8 @@ def render_prometheus(registry: Any) -> str:
                 x.add("dabt_engine_loop_spans_total", "counter", "engine-loop spans closed, by phase", tot["n"], plab)
             x.add("dabt_prefill_tokens_total", "counter", "prefill positions: prompt tokens run (real) vs rows x bucket of the programs (padded)", ls["prefill_tokens_real"], {**lab, "kind": "real"})
             x.add("dabt_prefill_tokens_total", "counter", "prefill positions: prompt tokens run (real) vs rows x bucket of the programs (padded)", ls["prefill_tokens_padded"], {**lab, "kind": "padded"})
+            for shape, n in ls["prefill_shapes"].items():
+                x.add("dabt_prefill_programs_total", "counter", "prefill programs dispatched, by rows x bucket (every warmed shape is listed)", n, {**lab, "shape": shape})
         moe_fn = getattr(eng, "moe_stats", None)
         moe = moe_fn() if callable(moe_fn) else None
         if moe:

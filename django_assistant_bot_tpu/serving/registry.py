@@ -57,12 +57,14 @@ class ModelSpec:
     # sequential path; False keeps sequential chunking (and one prefill
     # program per bucket to compile: a.x-k1-ep16 boots with it).
     prefill_piggyback: bool = True
-    # prefill program shapes (serving/engine.py warm-up compiles seq buckets x
-    # {1, 4, wave}): the sequence buckets (None = the engine's powers of two
-    # up to chunk_size) and the most rows one prefill dispatch admits (0 = all
-    # slots).  A deployment whose prompts span two buckets and arrive one at a
-    # time names them and boots in a fraction of the compile time; a 32-slot
-    # engine's full wave at bucket 1024 is also its largest program by far.
+    # prefill program shapes (serving/engine.py prefill_shapes: what warm-up
+    # compiles and admission dispatches).  Unnamed, the engine derives them:
+    # sequence buckets 64, then every 128 (the flash kernel's block) up to
+    # chunk_size; rows 1, 2 and the largest power of two with rows x bucket
+    # <= chunk_size (17 programs at chunk 1024 and 8 slots, none larger than
+    # one chunk's positions).  prefill_buckets names the buckets instead, for
+    # a deployment that wants fewer programs (chunk_size stays the last one);
+    # prefill_wave caps the rows of an admission wave (0 = all slots).
     prefill_buckets: Optional[List[int]] = None
     prefill_wave: int = 0
     # fp8 in-dot decode attention: keep the fp8 KV read operand in fp8
@@ -722,11 +724,7 @@ class ModelRegistry:
                     spec_width=spec.spec_width,
                     prefill_piggyback=spec.prefill_piggyback,
                     prefill_wave=spec.prefill_wave,
-                    **(
-                        {"prefill_buckets": tuple(int(b) for b in spec.prefill_buckets)}
-                        if spec.prefill_buckets
-                        else {}
-                    ),
+                    prefill_buckets=spec.prefill_buckets,
                     attn_fp8=spec.attn_fp8,
                     kv_page_size=spec.kv_page_size,
                     kv_pages=spec.kv_pages,

@@ -903,15 +903,23 @@ def test_fleet_peer_kill_reroute_and_degraded_healthz(fleet_pair):
         router.close()
 
 
-def test_fleet_disagg_prefill_decode_output_identity(fleet_pair):
+@pytest.mark.parametrize("first, n, restored", [(11, 80, False), (101, 64, True)])
+def test_fleet_disagg_prefill_decode_output_identity(fleet_pair, first, n, restored):
     """The acceptance disaggregation arm: a decode-pool replica serves a
     session whose prefill ran in the prefill pool, output identical to the
-    unified arm, with pages shipped over the wire and admitted via restore."""
+    unified arm, with pages shipped over the wire and admitted via restore.
+
+    The decode peer's one-token suffix rides its smallest prefill bucket, 64,
+    whose write window has to end inside the 128-token context
+    (``_paged_usable_hit``): 63 + 64 does, 79 + 64 does not, so the 80-token
+    prompt's pages are shipped and then not used: the decode peer prefills it
+    in full, to the same tokens (PERF.md section 7 has the loss)."""
     regs, planes, urls = fleet_pair
-    # token alphabet disjoint from every other test in this module: a shared
-    # first-token prefix would let B serve from its device prefix registry
-    # (warmed by an earlier test) and skip the host-tier restore under test
-    prompt = [11 + (i % 180) for i in range(80)]
+    # token alphabets disjoint from each other and from every other test in
+    # this module: a shared first-token prefix would let B serve from its
+    # device prefix registry (warmed by an earlier test) and skip the
+    # host-tier restore under test
+    prompt = [first + (i % 180) for i in range(n)]
     # unified reference first (pools still unified)
     ref = _fleet_generate(
         urls[0],
@@ -945,7 +953,7 @@ def test_fleet_disagg_prefill_decode_output_identity(fleet_pair):
         assert res.peer == "b"
         assert router.handoffs == 1 and router.pages_shipped > 0
         assert planes[1].kv_puts >= 1
-        assert tier_b.stats()["kv_host_restores"] > restores_before
+        assert (tier_b.stats()["kv_host_restores"] > restores_before) == restored
     finally:
         planes[0].pool = "unified"
         planes[1].pool = "unified"

@@ -382,7 +382,11 @@ def test_engine_paged_matches_forward(quantize, kv_dtype):
         from django_assistant_bot_tpu.ops.quant import quantize_decoder_params
 
         params = quantize_decoder_params(params)
-    rng = np.random.default_rng(7)
+    # seed 70: no sampled row draws the end-of-sequence id within its 12
+    # tokens (under seed 7 the 100-token row did at bf16 / fp8 once the wave
+    # rode two prefill programs, not three: the engine's key advances once a
+    # program), so every row is held to all 12
+    rng = np.random.default_rng(70)
     prompts = [rng.integers(1, 255, n).tolist() for n in (9, 33, 65, 100)]
     outs = _run_engine(cfg, params, prompts, kv_dtype=kv_dtype)
     _assert_forward_agrees(cfg, params, prompts, outs, tol=0.15 if kv_dtype else 1e-3)

@@ -38,7 +38,7 @@ from django_assistant_bot_tpu.serving import (
     parse_prometheus_text,
     render_prometheus,
 )
-from django_assistant_bot_tpu.serving.engine import pick_bucket
+from django_assistant_bot_tpu.serving.engine import plan_prefill
 from django_assistant_bot_tpu.serving.fleet import FleetResult
 from django_assistant_bot_tpu.serving.obs import (
     LOOP_PHASES,
@@ -155,8 +155,9 @@ class _SlowResult:
 def _rigged_engine(clk, log):
     """Every place the engine thread spends time burns a known amount of the
     fake clock: the prefill and tick dispatches, the results' waits, the
-    detokeniser, the sampling upload."""
-    eng = _engine(clk)
+    detokeniser, the sampling upload.  A chunk of 256: a wave of three short
+    prompts rides one 4 x 64 program (at the default 64 a program holds one)."""
+    eng = _engine(clk, max_seq_len=256)
     eng._running = True  # lockstep: the test cranks _loop_iteration itself
     prefill, insert, activate, tick = eng._prefill, eng._insert, eng._activate_fn, eng._decode_tick
     decode, upload = eng.tokenizer.decode, eng._upload_dirty
@@ -243,8 +244,9 @@ def test_tick_stats_issue_block_and_ticks_read_what_they_read_before(cranked):
     assert ts["block_ms"] == round((0.03 * 2 + 0.1 * n) / (2 + n) * 1e3, 3)
     assert cranked.eng._ticks_processed == 2 + n
     assert ts["prefill_tokens_real"] == 4 + 4 + 4 + 3
-    # waves of 3 and of 1 in the 32 bucket: batch buckets {1, 4}
-    assert ts["prefill_tokens_padded"] == 4 * 32 + 1 * 32
+    # waves of 3 and of 1 in the 64 bucket: programs of {1, 2, 4} rows
+    assert ts["prefill_tokens_padded"] == 4 * 64 + 1 * 64
+    assert {k: n for k, n in ts["prefill_shapes"].items() if n} == {"4x64": 1, "1x64": 1}
 
 
 @pytest.mark.parametrize("phase", LOOP_PHASES)
@@ -257,7 +259,10 @@ def test_metrics_export_the_loop_ledger(cranked, phase):
     assert fams["dabt_engine_loop_seconds_total"]["type"] == "counter"
     assert secs[phase] == pytest.approx(loop[phase]["s"]) and spans[phase] == loop[phase]["n"]
     pads = {lab["kind"]: v for _, lab, v in fams["dabt_prefill_tokens_total"]["samples"]}
-    assert pads == {"real": 15.0, "padded": 160.0}
+    assert pads == {"real": 15.0, "padded": 320.0}
+    shapes = {lab["shape"]: v for _, lab, v in fams["dabt_prefill_programs_total"]["samples"]}
+    assert shapes == {f"{r}x{b}": float((r, b) in ((4, 64), (1, 64)))
+                      for b, rows in cranked.eng.prefill_shapes.items() for r in rows}
 
 
 def test_idle_and_recover_time_is_in_the_ledger_too():
@@ -329,29 +334,32 @@ def test_ledger_and_timings_are_the_engines_own_with_obs_off(obs):
         _assert_tiles(r.usage_dict("m"))
         ts = eng.tick_stats()
         assert set(ts["loop"]) == set(LOOP_PHASES) and ts["loop"]["tick_issue"]["n"] == ts["ticks"] >= 1
-        assert ts["prefill_tokens_real"] == 3 and ts["prefill_tokens_padded"] == 32
+        assert ts["prefill_tokens_real"] == 3 and ts["prefill_tokens_padded"] == 64
+        assert ts["prefill_shapes"] == {"1x64": 1}
     finally:
         eng.stop()
 
 
-def test_prefill_bucket_and_wave_rows_match_pick_bucket():
-    """A wave of 130/200/300-token prompts: two ride a 256 x 4 program as a
-    wave of 2, one a 512 x 1 program."""
+def test_prefill_bucket_and_wave_rows_match_plan_prefill():
+    """A wave of 130/200/300-token prompts: two ride a 2 x 256 program (a
+    wave of 2 is not padded to 4), one a 1 x 384 program (not 512)."""
     eng = _engine(context=1024, max_seq_len=1024, chunk_size=512)
     eng._running = True
     lens = (130, 200, 300)
     futs = [eng.submit([1 + (j % 200) for j in range(n)], max_tokens=2, temperature=0.0) for n in lens]
     _crank(eng, futs)
     tms = [f.result(timeout=5).timings for f in futs]
-    for n, tm in zip(lens, tms):
-        assert tm["prefill_bucket"] == pick_bucket(n, eng.prefill_buckets, eng.chunk_size)
-        assert tm["wave_rows_padded"] == pick_bucket(tm["wave_rows"], eng._batch_buckets(), eng.max_slots)
-        assert tm["prefix_hit_tokens"] == 0 and tm["prefill_chunks"] == 0
+    for rows, bucket, members in plan_prefill(eng.prefill_shapes, lens):
+        for i in members:
+            assert tms[i]["prefill_bucket"] == bucket
+            assert (tms[i]["wave_rows"], tms[i]["wave_rows_padded"]) == (len(members), rows)
+            assert tms[i]["prefix_hit_tokens"] == 0 and tms[i]["prefill_chunks"] == 0
     assert [(t["prefill_bucket"], t["wave_rows"], t["wave_rows_padded"]) for t in tms] == [
-        (256, 2, 4), (256, 2, 4), (512, 1, 1)]
+        (256, 2, 2), (256, 2, 2), (384, 1, 1)]
     ts = eng.tick_stats()
     assert ts["prefill_tokens_real"] == sum(lens)
-    assert ts["prefill_tokens_padded"] == 4 * 256 + 1 * 512
+    assert ts["prefill_tokens_padded"] == 2 * 256 + 1 * 384
+    assert {k: n for k, n in ts["prefill_shapes"].items() if n} == {"2x256": 1, "1x384": 1}
     eng.stop(drain_timeout_s=5.0)
 
 
@@ -498,6 +506,6 @@ def test_spans_are_host_events_on_the_engine_thread_under_the_profiler(tmp_path)
     plane, idx, ev = engine_lines[0]
     assert plane.startswith("/host:")
     assert {"dabt/tick_issue", "dabt/prefill_dispatch", "dabt/tick_block", "dabt/consume"} <= set(ev)
-    assert ev["dabt/prefill_dispatch"] == {"bucket": 32, "rows": 1, "rows_padded": 1}
+    assert ev["dabt/prefill_dispatch"] == {"bucket": 64, "rows": 1, "rows_padded": 1}
     assert "test_thread_mark" not in ev  # this thread's line is another
     assert threading.current_thread().name != "gen-engine"

@@ -25,6 +25,7 @@ from django_assistant_bot_tpu.models.config import DecoderConfig, EncoderConfig 
 from django_assistant_bot_tpu.ops import attention as attn  # noqa: E402
 from django_assistant_bot_tpu.ops.quant import QTensor  # noqa: E402
 from django_assistant_bot_tpu.parallel import MeshAxes, make_mesh  # noqa: E402
+from django_assistant_bot_tpu.serving.engine import prefill_shapes  # noqa: E402
 
 MISTRAL_7B = DecoderConfig(
     vocab_size=32_000, hidden_size=4096, intermediate_size=14_336, num_layers=32,
@@ -340,12 +341,20 @@ def test_paged_prefill_chunk_compiles_at_mistral_7b_widths(topo):
     ).compile()
 
 
-def test_paged_suffix_prefill_wave_loads_beside_the_weights(topo):
-    """The program that stopped the first chip boot: a full admission wave
-    (8 rows x bucket 1024) of prefix-hit suffix prefill.  Gathering every
-    layer's rows up front needed 8.9 GB of temporaries next to 9.7 GB of
-    weights and pool; per layer, with the pool updated in place, it is ~2.3 GB
-    (almost all of it one layer's f32 attention scores)."""
+# the largest prefill programs the smoke's and the benchmark's geometry warms: one chunk's positions
+CHUNK_FULL = [(rows, bucket) for bucket, row_counts in prefill_shapes(1024, SLOTS).items()
+              for rows in row_counts if rows * bucket == 1024]
+
+
+@pytest.mark.parametrize("rows,bucket", CHUNK_FULL, ids=[f"{r}x{b}" for r, b in CHUNK_FULL])
+def test_paged_suffix_prefill_wave_loads_beside_the_weights(topo, rows, bucket):
+    """The kind of program that stopped the first chip boot: a full admission
+    wave of prefix-hit suffix prefill (then 8 rows x bucket 1024: gathering
+    every layer's rows up front needed 8.9 GB of temporaries next to 9.7 GB of
+    weights and pool; per layer, with the pool updated in place, ~2.3 GB,
+    almost all of it one layer's f32 attention scores).  A program now holds
+    one chunk's positions at most (``prefill_shapes``): every row count that
+    fills a chunk is compiled here."""
     one = SingleDeviceSharding(topo.devices[0])
     params = _int8_decoder_shapes(MISTRAL_7B, one)
     cache = _paged_cache_shapes(MISTRAL_7B, jnp.bfloat16, one)
@@ -360,8 +369,8 @@ def test_paged_suffix_prefill_wave_loads_beside_the_weights(topo):
             ),
             donate_argnums=(2,),
         )
-        .lower(params, i32(SLOTS, 1024), cache, i32(SLOTS, MAX_SEQ // PAGE),
-               i32(SLOTS), i32(SLOTS), i32(SLOTS))
+        .lower(params, i32(rows, bucket), cache, i32(rows, MAX_SEQ // PAGE),
+               i32(rows), i32(rows), i32(rows))
         .compile()
     )
     mem = compiled.memory_analysis()
@@ -370,15 +379,17 @@ def test_paged_suffix_prefill_wave_loads_beside_the_weights(topo):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.0e9
 
 
-def test_whole_bucket_prefill_takes_the_kernel_at_mistral_7b_widths(topo, monkeypatch):
+@pytest.mark.parametrize("rows,bucket", [(1, 1024), (2, 384), (1, 640)])
+def test_whole_bucket_prefill_takes_the_kernel_at_mistral_7b_widths(topo, monkeypatch, rows, bucket):
     """The program the smoke's 512-1024-token prompt runs: ``llama.prefill`` at
-    bucket 1024, int8 — the Pallas kernel inside the layer scan."""
+    bucket 1024, int8 — the Pallas kernel inside the layer scan; and buckets
+    that are whole flash blocks without being powers of two take it too."""
     monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
     one = SingleDeviceSharding(topo.devices[0])
     params = _int8_decoder_shapes(MISTRAL_7B, one)
     compiled = (
         jax.jit(lambda p, i, n: llama.prefill(p, MISTRAL_7B, i, n))
-        .lower(params, _sds((1, 1024), jnp.int32, one), _sds((1,), jnp.int32, one))
+        .lower(params, _sds((rows, bucket), jnp.int32, one), _sds((rows,), jnp.int32, one))
         .compile()
     )
     assert "tpu_custom_call" in compiled.as_text()
