@@ -17,6 +17,7 @@ Both take ``[batch, heads, seq, head_dim]`` and support causal masking and GQA
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -878,110 +879,206 @@ def latent_decode_attention(
 # Pallas flash attention
 # ---------------------------------------------------------------------------
 
+# The granularity of ADMISSION, not the kernel's tile: a sequence of two or
+# more whole blocks of this many positions reaches the kernel
+# (:func:`attention`), and the engine steps its prefill buckets by it
+# (serving/engine.py ``prefill_shapes``).  The tile a call runs with is sized
+# from its shape by :func:`flash_tiles`.
+FLASH_BLOCK = 128
+
+_LANES = 128
+
+
+def _lanes(x: jnp.ndarray, n: int) -> jnp.ndarray:
+    """``[rows, 128]`` whose lanes all hold their row's value -> ``[rows, n]``:
+    whole lane tiles repeat the registers as they are, no cross-lane move."""
+    reps, rem = divmod(n, _LANES)
+    if rem:  # narrower than a lane tile, or off it (the tests' small tiles)
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if reps == 1 else jnp.tile(x, (1, reps))
+
 
 def _flash_kernel(
-    q_ref,
-    k_ref,
-    v_ref,
-    o_ref,
-    m_scr,
-    l_scr,
-    acc_scr,
+    q_ref,  # [heads, block_q, D]
+    k_ref,  # [heads, chunk_kv, D]
+    v_ref,  # [heads, chunk_kv, Dv]
+    o_ref,  # [heads, block_q, Dv]
+    m_scr,  # [heads or 1, block_q, 128] running maximum, a value a row in every lane
+    l_scr,  # [heads or 1, block_q, 128] running sum, likewise
+    acc_scr,  # [heads or 1, block_q, Dv]
     *,
     kv_len: int,
+    block_q: int,
     block_kv: int,
     chunk_kv: int,
     causal: bool,
-    q_block: int,
-    window: "Optional[int]" = None,
-    scale: "Optional[float]" = None,
+    window: "Optional[int]",
+    scale: "Optional[float]",
 ):
-    """One (batch*head, q-block, kv-chunk) program: online softmax, chunked KV.
+    """One (group of heads, query tile, kv chunk) program: online softmax over
+    ``[block_q, block_kv]`` tiles of scores, a head after another.
 
-    q_ref: [q_block, D]; k_ref/v_ref: [chunk_kv, D] — K/V stream through VMEM
-    one CHUNK per grid step instead of residing whole-row (a [Sk, D] resident
-    block caps context at ~8k before the 16 MB VMEM scoped-stack limit; the
-    chunked pipeline scales to any Sk).  The online-softmax state (m, l, acc)
-    lives in VMEM scratch across the kv-chunk grid dimension; o_ref is
-    written once, on the final chunk.
-
-    Inside a chunk the kv loop runs at ``block_kv`` granularity with the same
-    skip logic as before: causal q-blocks stop at the diagonal, and ``window``
-    (sliding-window attention, HF semantics) skips sub-blocks entirely below
-    the band — O(S*W) compute for long windowed prefill.
+    K and V stream through VMEM a CHUNK a grid step (whole for keys up to
+    8,192) and the kernel walks the chunk in tiles of ``block_kv`` keys, the
+    chunk's remainder as one short last tile.  The tile is the kernel's unit of
+    work, chosen by :func:`flash_tiles`; ``FLASH_BLOCK`` is only what
+    :func:`attention` admits.  Keys are contracted as they lie (``q . k^T`` as
+    an NT matmul, no transpose of the tile).  Tiles wholly above the causal
+    diagonal or wholly below the ``window`` band are never visited; tiles
+    wholly inside take no mask at all (no iota, compare or select); only tiles
+    the diagonal or the band's edge crosses are masked.  The softmax state
+    (m, l, acc) lives in VMEM scratch, m and l a value a row repeated over the
+    lanes so that every load and store is a whole register; it is kept a head
+    each only where it must outlive a grid step (more than one chunk).
+    ``o_ref`` is written once, on the last chunk.
     """
-    qi = pl.program_id(1)
-    ci = pl.program_id(2)
-    num_chunks = pl.num_programs(2)
+    heads, dv = q_ref.shape[0], v_ref.shape[-1]
+    qi, ci = pl.program_id(1), pl.program_id(2)
+    last_chunk = pl.num_programs(2) - 1
+    q0 = qi * block_q  # this tile's first query position
+    c0 = ci * chunk_kv  # this chunk's first key position
+    whole, tail = divmod(chunk_kv, block_kv)
+    edges = causal or window is not None
 
-    @pl.when(ci == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # keep operands in their storage dtype (bf16): the MXU's fast path; accumulate
-    # in f32 via preferred_element_type.  Scaling folds into the f32 scores.
-    q = q_ref[:]
-    if scale is None:
-        scale = q.shape[-1] ** -0.5
-
-    spc = chunk_kv // block_kv  # sub-blocks per chunk
-    num_kv_blocks = kv_len // block_kv
-    if causal:
-        # only kv blocks up to and including the diagonal participate
-        last_block = ((qi + 1) * q_block + block_kv - 1) // block_kv
-        num_iter = jnp.minimum(num_kv_blocks, last_block)
-    else:
-        num_iter = num_kv_blocks
-    if window is not None:
-        # lowest key any query in this block may see: qpos_min - window + 1
-        first_iter = jnp.maximum(0, qi * q_block - window + 1) // block_kv
-    else:
-        first_iter = 0
-    # intersect the global [first_iter, num_iter) range with this chunk
-    lo = jnp.maximum(first_iter, ci * spc) - ci * spc
-    hi = jnp.minimum(num_iter, (ci + 1) * spc) - ci * spc
-
-    def body(ki, carry):
-        m, l, o = carry
-        k_blk = k_ref[pl.ds(ki * block_kv, block_kv), :]
-        v_blk = v_ref[pl.ds(ki * block_kv, block_kv), :]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32) * scale  # [qb, kb]
-        if causal or window is not None:
-            qpos = qi * q_block + jax.lax.broadcasted_iota(jnp.int32, (q_block, block_kv), 0)
-            kpos = (ci * spc + ki) * block_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (q_block, block_kv), 1
-            )
-            keep = qpos >= kpos if causal else (qpos == qpos)
-            if window is not None:
-                keep &= kpos > qpos - window
-            s = jnp.where(keep, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        o_new = alpha * o + jnp.dot(
-            p.astype(v_blk.dtype), v_blk, preferred_element_type=jnp.float32
+    # the keys some query of this tile sees are [first_key, end_key); in whole
+    # tiles of this chunk that is [lo, hi)
+    end_key = jnp.minimum(kv_len, q0 + block_q) if causal else kv_len
+    first_key = jnp.maximum(0, q0 - window + 1) if window is not None else 0
+    if edges:
+        lo = jnp.minimum(jnp.maximum(first_key - c0, 0) // block_kv, whole)
+        hi = jnp.minimum((jnp.maximum(end_key - c0, 0) + block_kv - 1) // block_kv, whole)
+        # key position minus query position, for a tile whose first key is the first query
+        d = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_kv), 1) - jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_kv), 0
         )
-        return m_new, l_new, o_new
+    else:
+        lo, hi = 0, whole
 
-    m, l, o = jax.lax.fori_loop(
-        lo, hi, body, (m_scr[:, :1], l_scr[:, :1], acc_scr[:])
+    def head(h, carry):
+        hs = h if m_scr.shape[0] == heads else 0
+
+        def step(off, size, masked):
+            """Fold the ``[block_q, size]`` tile of scores at key ``off`` of the chunk into the state."""
+            k = k_ref[h, pl.ds(off, size), :]
+            v = v_ref[h, pl.ds(off, size), :]
+            # operands stay in their storage dtype (bf16, the MXU's fast path), sums in f32
+            s = jax.lax.dot_general(
+                q_ref[h], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            if scale is not None:
+                s = s * scale
+            if masked:
+                rel = q0 - (c0 + off)  # key <= query  <=>  d <= rel
+                dd = d[:, :size]
+                keep = dd <= rel if causal else None
+                if window is not None:
+                    band = dd > rel - window
+                    keep = band if keep is None else keep & band
+                s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[hs]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - _lanes(m_new, size))
+            m_scr[hs] = m_new
+            l_scr[hs] = alpha * l_scr[hs] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[hs] = _lanes(alpha, dv) * acc_scr[hs] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32
+            )
+
+        @pl.when(ci == 0)
+        def _init():
+            m_scr[hs] = jnp.full(m_scr.shape[1:], NEG_INF, jnp.float32)
+            l_scr[hs] = jnp.zeros(l_scr.shape[1:], jnp.float32)
+            acc_scr[hs] = jnp.zeros(acc_scr.shape[1:], jnp.float32)
+
+        def tile(t, carry):
+            off = pl.multiple_of(t * block_kv, block_kv)
+            if not edges:
+                step(off, block_kv, False)
+                return carry
+            k0 = c0 + off
+            on_edge = k0 + block_kv - 1 > q0 if causal else False
+            if window is not None:
+                on_edge = on_edge | (k0 <= q0 + block_q - 1 - window)
+            pl.when(on_edge)(lambda: step(off, block_kv, True))
+            pl.when(jnp.logical_not(on_edge))(lambda: step(off, block_kv, False))
+            return carry
+
+        jax.lax.fori_loop(lo, hi, tile, 0)
+        if tail:  # the chunk's remainder, masked wherever a mask exists: it is the diagonal's tile when it is live
+            off = whole * block_kv
+            if edges:
+                live = (end_key > c0 + off) & (first_key < c0 + chunk_kv)
+                pl.when(live)(lambda: step(off, tail, True))
+            else:
+                step(off, tail, False)
+
+        @pl.when(ci == last_chunk)
+        def _finalize():
+            o_ref[h] = (acc_scr[hs] / _lanes(jnp.maximum(l_scr[hs], 1e-30), dv)).astype(o_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, heads, head, 0)
+
+
+def _flash_chunk(Sk: int) -> int:
+    """Keys a grid step holds in VMEM, double-buffered by the pipeline: the
+    whole sequence up to 8,192, from there the largest chunk under that which
+    divides it (in whole admission blocks where the sequence has them)."""
+    chunk = min(8192, Sk)
+    while Sk % chunk:
+        chunk -= FLASH_BLOCK if Sk % FLASH_BLOCK == 0 else 8
+    return chunk
+
+
+# what one program's blocks, state and score tiles may take of VMEM (v5e: 128 MiB a core,
+# of which a kernel is given 16 MiB unless it asks); heads are grouped up to this
+_FLASH_VMEM_BUDGET = 24 << 20
+
+
+def _flash_vmem_bytes(block_q: int, block_kv: int, heads: int, chunk_kv: int, D: int, Dv: int, itemsize: int, chunks: int) -> int:
+    """VMEM one program needs: its blocks twice (the pipeline's double buffer),
+    the softmax state, and the score tile's temporaries (scores, probabilities
+    in f32 and the operand's type, the position differences)."""
+    D, Dv = -(-D // _LANES) * _LANES, -(-Dv // _LANES) * _LANES  # a narrower head still fills its lanes
+    blocks = 2 * heads * itemsize * (block_q * (D + Dv) + chunk_kv * (D + Dv))
+    state = (heads if chunks > 1 else 1) * block_q * (2 * _LANES + Dv) * 4
+    tile = block_q * block_kv * (4 + 4 + 4 + itemsize)
+    return blocks + state + tile
+
+
+def flash_tiles(
+    Sq: int, Sk: int, D: int, Dv: int, heads: int, *, window: Optional[int] = None, itemsize: int = 2
+) -> "tuple[int, int, int]":
+    """-> (query tile, key tile, heads a program) for a call of this shape.
+
+    Measured on a v5e (``tools/time_prefill.py --flash`` and PR 38's sweep of
+    every tile at the benchmark's shapes, PERF.md section 6): a pass over the
+    score tile costs about a cycle a register whatever the tile, so what is
+    left to save is per tile and per grid step, and the largest tile wins until
+    it computes too much above the diagonal.  Keys: 512 a tile (1,024 is
+    5-20% slower, 256 10-20%, 128 40-60%), the remainder as a short last tile.
+    Queries: the whole sequence as one tile below 1,024 positions (no second
+    grid step a head, though nothing above the diagonal is skipped), and from
+    there the largest of 512, 384 and 256 that divides it, else 512 with a
+    short last tile.  A ``window`` narrower than that caps both, so that a tile
+    is not mostly masked scores.  Heads: up to 4 a program (2-18% over one;
+    8 and 7 no better), as many as divide the call's heads and fit VMEM.
+    """
+    block_q = Sq if Sq < 1024 else next((t for t in (512, 384, 256) if Sq % t == 0), 512)
+    block_kv = min(512, Sk)
+    if window is not None:
+        cap = max(FLASH_BLOCK, window // FLASH_BLOCK * FLASH_BLOCK)
+        block_q, block_kv = min(block_q, cap), min(block_kv, cap)
+    chunk_kv = _flash_chunk(Sk)
+    chunks = Sk // chunk_kv
+    group = max(
+        g for g in (1, 2, 3, 4)
+        if heads % g == 0
+        and (g == 1 or _flash_vmem_bytes(block_q, block_kv, g, chunk_kv, D, Dv, itemsize, chunks) <= _FLASH_VMEM_BUDGET)
     )
-    m_scr[:, :1] = m
-    l_scr[:, :1] = l
-    acc_scr[:] = o
-
-    @pl.when(ci == num_chunks - 1)
-    def _finalize():
-        o_ref[:] = (acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)).astype(o_ref.dtype)
-
-
-# the flash kernel's block of queries (and of keys): prefill sequences that
-# are whole blocks, two or more, reach the kernel (:func:`attention`), and the
-# engine steps its prefill buckets by it (serving/engine.py ``prefill_shapes``)
-FLASH_BLOCK = 128
+    return block_q, block_kv, group
 
 
 @functools.partial(
@@ -996,58 +1093,60 @@ def flash_attention(
     v: jnp.ndarray,  # [B, H, Sk, Dv]: Dv may differ from D (latent attention: 192 / 128)
     *,
     causal: bool = False,
-    block_q: int = FLASH_BLOCK,
-    block_kv: int = FLASH_BLOCK,
+    block_q: Optional[int] = None,  # default: from the shape (flash_tiles); tests force smaller
+    block_kv: Optional[int] = None,
     interpret: bool = False,
     window: Optional[int] = None,
     chunk_kv: Optional[int] = None,  # default: min(8192, Sk); tests force smaller
     scale: Optional[float] = None,  # default: D ** -0.5
 ) -> jnp.ndarray:
+    """Blocked online-softmax attention: bf16 (storage dtype) operands, f32
+    scores, sums and accumulator; causal and ``window`` as
+    :func:`dot_product_attention` has them.  The tiles and the heads a program
+    come from the call's static shape (:func:`flash_tiles`); a sequence the
+    tile does not divide runs a short last tile."""
     B, H, Sq, D = q.shape
     Sk, Dv = k.shape[2], v.shape[3]
-    block_q = min(block_q, Sq)
-    block_kv = min(block_kv, Sk)
-    if Sq % block_q or Sk % block_kv:
-        raise ValueError(f"seq lens ({Sq},{Sk}) must be multiples of blocks ({block_q},{block_kv})")
-    if block_q % 8 or block_kv % 8 or D % 128 and D != 64 or Dv % 128 and Dv != 64:
+    if Sq % 8 or Sk % 8 or D % 128 and D != 64 or Dv % 128 and Dv != 64:
         # Mosaic requires (8,128)-tile-aligned loads; reject early with a clear error
         # instead of a deep compiler failure.  Callers pad to a bucket first.
         raise ValueError(
-            f"flash_attention needs 8-aligned seq blocks and head_dim 64/128k, got "
-            f"blocks=({block_q},{block_kv}), head_dim={D}; pad sequences to a multiple of 8"
+            f"flash_attention needs 8-aligned sequences and head_dim 64/128k, got seq lens "
+            f"({Sq},{Sk}), head_dim ({D},{Dv}); pad sequences to a multiple of 8"
         )
-
-    qf = q.reshape(B * H, Sq, D)
-    kf = k.reshape(B * H, Sk, D)
-    vf = v.reshape(B * H, Sk, Dv)
-
-    # K/V stream through VMEM one chunk per grid step (double-buffered by the
-    # pallas pipeline).  A whole-row [Sk, D] resident block dies at Sk=16k
-    # (16 MB VMEM scoped-stack limit — measured 16.12M at exactly 16k/D=64);
-    # 8192-wide chunks stay at the old kernel's single-chunk performance for
-    # Sk <= 8k (measured: chunking 8k into 2048s cost ~33% — extra per-chunk
-    # programs + causal upper-triangle fetches) while scaling to any context.
+    tile_q, tile_kv, heads = flash_tiles(Sq, Sk, D, Dv, B * H, window=window, itemsize=q.dtype.itemsize)
+    block_q = min(block_q or tile_q, Sq)
+    block_kv = block_kv or tile_kv
+    if block_q % 8 or block_kv % 8:
+        raise ValueError(f"flash_attention needs 8-aligned tiles, got ({block_q},{block_kv})")
     if chunk_kv is None:
-        # largest chunk <= 8192 that divides Sk into block multiples (a
-        # drop straight to block_kv at e.g. Sk=12288 would mean 96 chunk
-        # programs per q-block — per-chunk overhead far beyond the ~33%
-        # measured at 2048-wide chunks)
-        chunk_kv = min(8192, Sk)
-        while Sk % chunk_kv or chunk_kv % block_kv:
-            chunk_kv -= block_kv
-    if Sk % chunk_kv or chunk_kv % min(block_kv, chunk_kv):
-        raise ValueError(f"chunk_kv={chunk_kv} must divide Sk={Sk} into block multiples")
+        chunk_kv = _flash_chunk(Sk)
+    if Sk % chunk_kv or chunk_kv % 8:
+        raise ValueError(f"chunk_kv={chunk_kv} must divide Sk={Sk} and be 8-aligned")
+    block_kv = min(block_kv, chunk_kv)
+    chunks = Sk // chunk_kv
+    if scale is None:
+        scale = D ** -0.5
+    if math.frexp(scale)[0] == 0.5:
+        # a power of two is exact in q's own type; any other scale stays on the f32 scores
+        q, scale = q * jnp.asarray(scale, q.dtype), None
+
+    qf = q.reshape(B * H // heads, heads, Sq, D)
+    kf = k.reshape(B * H // heads, heads, Sk, D)
+    vf = v.reshape(B * H // heads, heads, Sk, Dv)
+
     kernel = functools.partial(
         _flash_kernel,
         kv_len=Sk,
-        block_kv=min(block_kv, chunk_kv),
+        block_q=block_q,
+        block_kv=block_kv,
         chunk_kv=chunk_kv,
         causal=causal,
-        q_block=block_q,
         window=window,
         scale=scale,
     )
-    def kv_index(bh, qi, ci):
+
+    def kv_index(g, qi, ci):
         # Clamp dead chunks onto the nearest live one: grid steps whose chunk
         # is entirely past the causal diagonal (or below the window band) run
         # zero kernel iterations, and mapping them to a repeated block index
@@ -1056,28 +1155,34 @@ def flash_attention(
         # its O(S*W) traffic property.
         c = ci
         if causal:
-            last = ((qi + 1) * block_q - 1) // chunk_kv
+            last = (jnp.minimum((qi + 1) * block_q, Sk) - 1) // chunk_kv
             c = jnp.minimum(c, last)
         if window is not None:
             first = jnp.maximum(0, qi * block_q - window + 1) // chunk_kv
             c = jnp.maximum(c, first)
-        return (bh, c, 0)
+        return (g, 0, c, 0)
 
+    state_heads = heads if chunks > 1 else 1
     out = pl.pallas_call(
         kernel,
-        grid=(B * H, Sq // block_q, Sk // chunk_kv),
+        grid=(B * H // heads, pl.cdiv(Sq, block_q), chunks),
         in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda bh, qi, ci: (bh, qi, 0)),
-            pl.BlockSpec((None, chunk_kv, D), kv_index),
-            pl.BlockSpec((None, chunk_kv, Dv), kv_index),
+            pl.BlockSpec((None, heads, block_q, D), lambda g, qi, ci: (g, 0, qi, 0)),
+            pl.BlockSpec((None, heads, chunk_kv, D), kv_index),
+            pl.BlockSpec((None, heads, chunk_kv, Dv), kv_index),
         ],
-        out_specs=pl.BlockSpec((None, block_q, Dv), lambda bh, qi, ci: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
+        out_specs=pl.BlockSpec((None, heads, block_q, Dv), lambda g, qi, ci: (g, 0, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * H // heads, heads, Sq, Dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),  # m (col 0 used)
-            pltpu.VMEM((block_q, 128), jnp.float32),  # l (col 0 used)
-            pltpu.VMEM((block_q, Dv), jnp.float32),  # acc
+            pltpu.VMEM((state_heads, block_q, _LANES), jnp.float32),  # m
+            pltpu.VMEM((state_heads, block_q, _LANES), jnp.float32),  # l
+            pltpu.VMEM((state_heads, block_q, Dv), jnp.float32),  # acc
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_flash_vmem_bytes(block_q, block_kv, heads, chunk_kv, D, Dv, q.dtype.itemsize, chunks) + (16 << 20),
+        ),
+        name="flash_attention",
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(B, H, Sq, Dv)

@@ -118,7 +118,8 @@ def prefill_shapes(
     cannot drift apart.
 
     Sequence buckets: the ones a deployment names, or else half a flash block
-    (a chat format alone is ~18 tokens) and then every whole block up to
+    (``FLASH_BLOCK``: the 128 positions by which the flash kernel is admitted,
+    not its tile; a chat format alone is ~18 tokens) and then every whole block up to
     ``chunk_size``, so a prompt of more than a block is padded by less than
     one; ``chunk_size`` itself is always the last (a prompt of up to a chunk
     rides one program).  Rows: a program holds at most one chunk's positions
@@ -462,7 +463,7 @@ class GenerationEngine:
         # head-of-line blocking is bounded by a chunk, not by the longest prompt.
         self.chunk_size = int(min(chunk_size, self.max_seq_len))
         # the (rows, bucket) shapes of the prefill programs: derived from the
-        # chunk, the wave and the flash kernel's block unless the deployment
+        # chunk, the wave and the flash kernel's admission block unless the deployment
         # names its buckets; warm-up compiles them all and admission
         # dispatches no other (tick_stats()["prefill_shapes"] counts each)
         self.prefill_shapes = prefill_shapes(self.chunk_size, self.prefill_wave, prefill_buckets)

@@ -59,7 +59,7 @@ class ModelSpec:
     prefill_piggyback: bool = True
     # prefill program shapes (serving/engine.py prefill_shapes: what warm-up
     # compiles and admission dispatches).  Unnamed, the engine derives them:
-    # sequence buckets 64, then every 128 (the flash kernel's block) up to
+    # sequence buckets 64, then every 128 (what admits the flash kernel) up to
     # chunk_size; rows 1, 2 and the largest power of two with rows x bucket
     # <= chunk_size (17 programs at chunk 1024 and 8 slots, none larger than
     # one chunk's positions).  prefill_buckets names the buckets instead, for

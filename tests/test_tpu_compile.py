@@ -12,6 +12,7 @@ the paged decode/prefill programs and the encoder/KNN kernels at full widths.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
@@ -112,56 +113,89 @@ def _paged_cache_shapes(cfg: DecoderConfig, kv_dtype, sharding):
 
 
 FLASH_CASES = [
-    # (B, H, S, D, window, id)
-    (1, 32, 256, 128, 4096, "mistral-bucket-256"),
-    (1, 32, 512, 128, 4096, "mistral-bucket-512"),
-    (1, 32, 1024, 128, 4096, "mistral-bucket-1024"),
-    (1, 32, 2048, 128, 4096, "mistral-bucket-2048"),
-    (8, 32, 512, 128, 4096, "mistral-wave-8x512"),
-    (1, 32, 1024, 64, None, "head-dim-64"),
-    (1, 32, 1024, 128, 256, "window-256-bites"),
-    (1, 32, 16384, 64, None, "chunked-kv-16384"),
+    # (B, H, S, D, Dv, window, id)
+    (1, 32, 256, 128, 128, 4096, "mistral-bucket-256"),
+    (1, 32, 512, 128, 128, 4096, "mistral-bucket-512"),
+    (1, 32, 1024, 128, 128, 4096, "mistral-bucket-1024"),
+    (1, 32, 2048, 128, 128, 4096, "mistral-bucket-2048"),
+    (8, 32, 512, 128, 128, 4096, "mistral-wave-8x512"),
+    (1, 32, 1024, 64, 64, None, "head-dim-64"),
+    (1, 32, 1024, 128, 128, 256, "window-256-bites"),
+    (1, 32, 16384, 64, 64, None, "chunked-kv-16384"),
+    # what the benchmark's two configurations dispatch (tick_stats()["prefill_shapes"]):
+    # Qwen2.5-7B's 28 heads at every bucket of whole blocks and its waves, A.X-K1's 64
+    # heads at key width 192 padded to 256 against value width 128
+    *[(1, 28, S, 128, 128, None, f"qwen-1x{S}") for S in (256, 384, 512, 640, 768, 896, 1024)],
+    (2, 28, 512, 128, 128, None, "qwen-2x512"),
+    (4, 28, 256, 128, 128, None, "qwen-4x256"),
+    (1, 64, 512, 256, 128, None, "a.x-k1-1x512"),
+    (1, 64, 1024, 256, 128, None, "a.x-k1-1x1024"),
 ]
+# what a flash program may ask of a core's 128 MiB of VMEM (its blocks twice, its
+# state, a score tile's temporaries, and the room Mosaic wants): half
+FLASH_VMEM_CAP = 64 << 20
+
+
+def _flash_call_vmem_bytes(text: str) -> int:
+    """The scoped VMEM the compiled flash call was given (its ``vmem_limit_bytes``),
+    from the custom call's own line of the optimised HLO."""
+    line = next(l for l in text.splitlines() if "tpu_custom_call" in l and "flash_attention" in l)
+    return int(re.search(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+","size":"(\d+)"', line).group(1))
 
 
 @pytest.mark.parametrize(
-    "B,H,S,D,window", [c[:5] for c in FLASH_CASES], ids=[c[5] for c in FLASH_CASES]
+    "B,H,S,D,Dv,window", [c[:6] for c in FLASH_CASES], ids=[c[6] for c in FLASH_CASES]
 )
-def test_flash_kernel_compiles_for_one_v5e(topo, B, H, S, D, window):
+def test_flash_kernel_compiles_for_one_v5e(topo, B, H, S, D, Dv, window):
+    """Mosaic takes the kernel at the tile ``flash_tiles`` picks for the shape
+    (it refuses a program whose VMEM passes what it asked for), the call asks
+    for no more than half a core's VMEM, and nothing is copied around it
+    at lane-wide heads."""
     one = SingleDeviceSharding(topo.devices[0])
-    x = _sds((B, H, S, D), jnp.bfloat16, one)
+    qk, vv = _sds((B, H, S, D), jnp.bfloat16, one), _sds((B, H, S, Dv), jnp.bfloat16, one)
     compiled = (
-        jax.jit(lambda q, k, v: attn.flash_attention(q, k, v, causal=True, window=window))
-        .lower(x, x, x)
+        jax.jit(lambda q, k, v: attn.flash_attention(q, k, v, causal=True, window=window, scale=0.1309))
+        .lower(qk, qk, vv)
         .compile()
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    assert _flash_call_vmem_bytes(compiled.as_text()) <= FLASH_VMEM_CAP
+    if D % 128 == 0:  # a 64-wide head is laid out anew around the call by XLA, as it was before
+        assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize(
-    "axes,B",
-    [(MeshAxes(model=4), 1), (MeshAxes(data=2, model=2), 8), (MeshAxes(data=2, model=2), 1)],
-    ids=["tp4-one-row", "dp2xtp2-wave", "dp2xtp2-one-row-replicated-batch"],
+    "axes,B,H,S,D,Dv",
+    [
+        (MeshAxes(model=4), 1, 32, 1024, 128, 128),
+        (MeshAxes(data=2, model=2), 8, 32, 1024, 128, 128),
+        (MeshAxes(data=2, model=2), 1, 32, 1024, 128, 128),
+        (MeshAxes(model=4), 1, 28, 384, 128, 128),
+        (MeshAxes(model=4), 1, 28, 1024, 128, 128),
+        (MeshAxes(model=4), 1, 64, 1024, 256, 128),
+    ],
+    ids=["tp4-one-row", "dp2xtp2-wave", "dp2xtp2-one-row-replicated-batch",
+         "tp4-qwen-7-heads-a-device-384", "tp4-qwen-7-heads-a-device-1024", "tp4-a.x-k1-16-heads-a-device"],
 )
-def test_shard_mapped_flash_kernel_compiles_on_a_four_device_mesh(topo, axes, B):
+def test_shard_mapped_flash_kernel_compiles_on_a_four_device_mesh(topo, axes, B, H, S, D, Dv):
     """Bare, this call is refused on a mesh ("Mosaic kernels cannot be
-    automatically partitioned"); under shard_map every device gets its heads."""
+    automatically partitioned"); under shard_map every device gets its heads,
+    and the tile is sized from the heads a device holds."""
     mesh = make_mesh(axes, devices=topo.devices)
     sharding = NamedSharding(mesh, P("data" if B % axes.data == 0 else None, "model", None, None))
-    x = _sds((B, 32, 1024, 128), jnp.bfloat16, sharding)
+    qk, vv = _sds((B, H, S, D), jnp.bfloat16, sharding), _sds((B, H, S, Dv), jnp.bfloat16, sharding)
     compiled = (
         jax.jit(
             lambda q, k, v: attn.sharded_flash_attention(
                 q, k, v, mesh, causal=True, window=4096
             )
         )
-        .lower(x, x, x)
+        .lower(qk, qk, vv)
         .compile()
     )
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    assert _flash_call_vmem_bytes(text) <= FLASH_VMEM_CAP
     # heads split over `model`: no device computes more than its share
-    assert f"bf16[{B // axes.data if B % axes.data == 0 else B},{32 // axes.model},1024,128]" in text
+    assert f"bf16[{B // axes.data if B % axes.data == 0 else B},{H // axes.model},{S},{Dv}]" in text
 
 
 def test_bare_flash_kernel_is_refused_on_a_mesh(topo):

@@ -1,18 +1,36 @@
 #!/usr/bin/env python3
 """One prefill program alone, by shape, on the chip: what sets
-``serving/engine.py`` ``PREFILL_PROGRAM_POSITIONS``.
+``serving/engine.py`` ``PREFILL_PROGRAM_POSITIONS``; and the flash prefill
+kernel alone, by shape: what sets ``ops/attention.py`` ``flash_tiles``.
 
-    python3 tools/time_prefill.py            # on a TPU; ~2 minutes
+    python3 tools/time_prefill.py                       # on a TPU; ~2 minutes
+    python3 tools/time_prefill.py --config a.x-k1-ep16  # another configuration's programs
+    python3 tools/time_prefill.py --flash               # the kernel alone; ~1 minute
+    python3 tools/time_prefill.py --trace 1x512,1x1024  # where a program's device time goes
 
-Builds the Qwen2.5-7B int8 engine of ``benchmarks/configs/qwen2.5-7b-instruct.json``
-(seeded weights, nothing served), and for every shape of its ``prefill_shapes``
-times ``_prefill`` with full rows: the median of 7 calls, each ended by
+Builds the engine of ``benchmarks/configs/<config>.json`` (default
+``qwen2.5-7b-instruct``: Qwen2.5-7B int8; the configuration's seeded weights,
+nothing served), and for every shape of its ``prefill_shapes`` times
+``_prefill`` with full rows: the median of 7 calls, each ended by
 ``block_until_ready``, after 2 warm ones.  Prints ``{"<rows>x<bucket>": ms}``
-as its last line and writes the same to ``chiprun_out/time_prefill.json``.
+as its last line and writes the same to ``chiprun_out/time_prefill.json``
+(``time_prefill.<config>.json`` for another configuration).
+
+``--flash`` times ``flash_attention`` alone (causal, bfloat16, one row) at the
+shapes the two configurations dispatch, ``{"<heads>x<S>x<D>/<Dv>": ms a
+call}``: programs of 24 and of 8 calls, each call's output the next call's
+values so that nothing is copied between them; the difference of their medians
+of 7 runs over 16, so that a program's launch and the wait for its end (0.7 ms
+on a v5e's host, 0.09 ms a call of 8) cancel; to ``chiprun_out/time_flash.json``.  ``--trace <rows>x<bucket>[,...]`` profiles 3
+calls of each such program and prints its device time by named scope
+(``attn/core``, ``attn/kv_up``, ...) and by operation, ms a call; to
+``chiprun_out/trace_prefill.json``.
 """
 
+import argparse
 import json
 import os
+import shutil
 import statistics
 import sys
 import time
@@ -28,37 +46,133 @@ from benchmarks import families, sut  # noqa: E402
 from django_assistant_bot_tpu.models.config import DecoderConfig  # noqa: E402
 from django_assistant_bot_tpu.serving import ByteTokenizer, GenerationEngine  # noqa: E402
 
+FLASH_CALLS = (24, 8)  # the kernel's time is the difference of two programs of this many calls
+# (heads, [S], D, Dv, the configuration whose softmax scale the call carries)
+FLASH_SHAPES = (
+    (64, (512, 1024), 256, 128, "a.x-k1-ep16"),
+    (28, (256, 384, 512, 768, 1024), 128, 128, "qwen2.5-7b-instruct"),
+)
 
-def main() -> int:
-    sut.enable_compile_cache()
-    print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
-    with open(os.path.join(ROOT, "benchmarks", "configs", "qwen2.5-7b-instruct.json")) as f:
-        conf = json.load(f)
+
+def load_conf(name: str) -> dict:
+    with open(os.path.join(ROOT, "benchmarks", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+def median_ms(fn, *args) -> float:
+    """Median wall time of 7 calls of ``fn(*args)``, each ended by
+    ``block_until_ready``, after 2 warm ones."""
+    ms = []
+    for i in range(9):
+        t = time.monotonic()
+        jax.block_until_ready(fn(*args))
+        if i >= 2:
+            ms.append((time.monotonic() - t) * 1e3)
+    return statistics.median(ms)
+
+
+def build_engine(conf: dict) -> GenerationEngine:
     family = families.load(conf, os.path.join(ROOT, "benchmarks"))
-    params = sut.wrap_params(family.served_params(conf, 1), jnp.bfloat16)
-    jax.block_until_ready(params)
     s = conf["serving"]
-    eng = GenerationEngine(
-        DecoderConfig.from_hf(conf["hf"], dtype=jnp.bfloat16), params, ByteTokenizer(),
+    act = getattr(jnp, s.get("dtype", "bfloat16"))
+    params = sut.wrap_params(family.served_params(conf, conf["weights"]["seed"]), act)
+    jax.block_until_ready(params)
+    return GenerationEngine(
+        DecoderConfig.from_hf(conf["hf"], dtype=act), params, ByteTokenizer(),
         max_slots=s["max_slots"], max_seq_len=s["max_seq_len"], chunk_size=s["chunk_size"],
         kv_page_size=s["kv_page_size"], kv_pages=s["kv_pages"], prefix_cache_size=0,
+        prefill_buckets=s.get("prefill_buckets"), prefill_wave=s.get("prefill_wave", 0),
+        prefill_piggyback=s.get("prefill_piggyback", True),
     )
-    rng = np.random.default_rng(0)
+
+
+def full_rows(rows: int, bucket: int):
+    ids = np.random.default_rng(0).integers(32, 127, size=(rows, bucket))
+    return jnp.asarray(ids, jnp.int32), jnp.full((rows,), bucket, jnp.int32)
+
+
+def time_programs(eng: GenerationEngine) -> dict:
     out = {}
     for bucket, row_counts in eng.prefill_shapes.items():
         for rows in row_counts:
-            ids = jnp.asarray(rng.integers(32, 127, size=(rows, bucket)), jnp.int32)
-            lengths = jnp.full((rows,), bucket, jnp.int32)
-            ms = []
-            for i in range(9):
-                t = time.monotonic()
-                jax.block_until_ready(eng._prefill(eng.params, ids, lengths))
-                if i >= 2:
-                    ms.append((time.monotonic() - t) * 1e3)
-            out[f"{rows}x{bucket}"] = round(statistics.median(ms), 3)
+            out[f"{rows}x{bucket}"] = round(median_ms(eng._prefill, eng.params, *full_rows(rows, bucket)), 3)
             print(f"{rows}x{bucket}", out[f"{rows}x{bucket}"], flush=True)
+    return out
+
+
+def trace_programs(eng: GenerationEngine, shapes: str, trace_dir: str) -> dict:
+    """Device time of each program of ``shapes`` (``1x512,1x1024``) by named
+    scope and by operation, ms a call, from a profiler trace of 3 calls
+    (``benchmarks/trace_reduce.py``)."""
+    from benchmarks import trace_reduce
+
+    def per_call(seconds: dict) -> dict:
+        return {k: round(v / 3 * 1e3, 4) for k, v in sorted(seconds.items(), key=lambda kv: -kv[1])}
+
+    out = {}
+    for shape in shapes.split(","):
+        rows, bucket = (int(x) for x in shape.split("x"))
+        args = (eng.params, *full_rows(rows, bucket))
+        for _ in range(2):
+            jax.block_until_ready(eng._prefill(*args))
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(3):
+            jax.block_until_ready(eng._prefill(*args))
+        jax.profiler.stop_trace()
+        r = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir))
+        out[shape] = {"program_ms": per_call(r["program_s"]), "scope_ms": per_call(r["scope_s"] or {}),
+                      "op_ms": dict(list(per_call(r["op_s"]).items())[:24])}
+        print(shape, json.dumps(out[shape]), flush=True)
+    return out
+
+
+def time_flash() -> dict:
+    from django_assistant_bot_tpu.models.mla_moe import softmax_scale
+    from django_assistant_bot_tpu.ops.attention import flash_attention
+
+    out = {}
+    for heads, lengths, D, Dv, config in FLASH_SHAPES:
+        cfg = DecoderConfig.from_hf(load_conf(config)["hf"], dtype=jnp.bfloat16)
+        scale = softmax_scale(cfg) if cfg.latent_moe is not None else None
+
+        def chain(calls):
+            def run(q, k, v):
+                for _ in range(calls):
+                    v = flash_attention(q, k, v, causal=True, scale=scale)
+                return v
+            return jax.jit(run)
+
+        for S in lengths:
+            keys = jax.random.split(jax.random.key(S), 3)
+            q, k = (jax.random.normal(key, (1, heads, S, D), jnp.bfloat16) for key in keys[:2])
+            v = jax.random.normal(keys[2], (1, heads, S, Dv), jnp.bfloat16)
+            name = f"{heads}x{S}x{D}/{Dv}"
+            # what a program costs whatever it holds (launch, the wait for its end) cancels
+            long, short = (median_ms(chain(n), q, k, v) for n in FLASH_CALLS)
+            out[name] = round((long - short) / (FLASH_CALLS[0] - FLASH_CALLS[1]), 4)
+            print(name, out[name], flush=True)
+    return out
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--config", default="qwen2.5-7b-instruct", help="a file of benchmarks/configs/, without .json")
+    ap.add_argument("--flash", action="store_true", help="time the flash kernel alone instead of the programs")
+    ap.add_argument("--trace", metavar="ROWSxBUCKET[,...]", help="trace these programs instead: device ms a call by scope and operation")
+    args = ap.parse_args()
+    sut.enable_compile_cache()
+    print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
+    suffix = ".json" if args.config == "qwen2.5-7b-instruct" else f".{args.config}.json"
+    if args.flash:
+        out, name = time_flash(), "time_flash.json"
+    elif args.trace:
+        trace_dir = os.path.join(ROOT, ".cache", "time_prefill_trace")
+        out, name = trace_programs(build_engine(load_conf(args.config)), args.trace, trace_dir), "trace_prefill" + suffix
+    else:
+        out, name = time_programs(build_engine(load_conf(args.config))), "time_prefill" + suffix
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "time_prefill.json"), "w") as f:
+    with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
     return 0
