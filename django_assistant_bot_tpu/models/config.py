@@ -85,7 +85,16 @@ class LatentMoEConfig:
     ``[ep_rank * experts_held, (ep_rank + 1) * experts_held)`` of a
     ``ep_size``-way expert-parallel deployment; it routes over all of them,
     computes its own, and leaves the rest of the sum to the absent ranks (no
-    code stands in for them or their exchange).
+    code stands in for them or their exchange).  ``router_bias``: the router
+    carries a score-correction bias (``topk_method: noaux_tc``) that enters the
+    scores which PICK groups and experts and never the weights.
+
+    Learned sparse attention (``deepseek_v32``; ``index_topk`` 0 = none, and
+    then the block is the dense one, weight for weight and program for
+    program): an indexer of ``index_n_heads`` heads of ``index_head_dim``
+    scores every cached token for every query, the ``index_topk`` best are
+    attended, and the cache holds an ``index_head_dim``-wide index key per
+    token per layer beside the latent row.
     """
 
     q_lora_rank: int
@@ -106,6 +115,10 @@ class LatentMoEConfig:
     softmax_scale_mult: float = 1.0
     ep_size: int = 1
     ep_rank: int = 0
+    router_bias: bool = False
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self):
         if self.router_experts % self.ep_size or not 0 <= self.ep_rank < self.ep_size:
@@ -117,6 +130,11 @@ class LatentMoEConfig:
             raise ValueError(
                 f"{self.router_experts} experts do not split into n_group={self.n_group} "
                 f"groups of which topk_group={self.topk_group} are kept"
+            )
+        if self.index_topk and not (self.index_n_heads > 0 and self.qk_rope_head_dim <= self.index_head_dim):
+            raise ValueError(
+                f"index_topk={self.index_topk} needs an indexer: index_n_heads={self.index_n_heads}, "
+                f"index_head_dim={self.index_head_dim} (at least qk_rope_head_dim={self.qk_rope_head_dim} wide)"
             )
 
     @property
@@ -141,7 +159,7 @@ class LatentMoEConfig:
 # config carrying one is another family, never a dense Llama
 _OTHER_BLOCK_KEYS = ("kv_lora_rank", "n_routed_experts", "layer_types")
 _PLAIN_MODEL_TYPES = ("llama", "mistral", "mixtral", "qwen2", "phi3", "gemma")
-_LATENT_MOE_MODEL_TYPES = ("axk1", "deepseek_v3")
+_LATENT_MOE_MODEL_TYPES = ("axk1", "deepseek_v3", "deepseek_v32")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -356,7 +374,12 @@ class DecoderConfig:
 
     @classmethod
     def _from_hf_latent_moe(cls, hf: Mapping[str, Any], dtype) -> "DecoderConfig":
-        """The DeepSeek-V3 family's keys (``model_type`` ``deepseek_v3`` / ``axk1``).
+        """The DeepSeek-V3 family's keys (``model_type`` ``deepseek_v3`` / ``axk1``
+        / ``deepseek_v32``: the same block with a lightning indexer and top-k
+        sparse attention, ``index_*``).  ``num_nextn_predict_layers`` (the
+        multi-token-prediction module, a draft head for speculation) is
+        accepted and NO such layer is built or loaded: speculation is refused
+        for this block (:func:`..mla_moe.check_serving`).
 
         What cannot be honoured is refused, not dropped.  The expert share: a
         published config has ``ep_size`` 1 and ``n_routed_experts`` is all of
@@ -370,10 +393,12 @@ class DecoderConfig:
 
         if hf.get("scoring_func", "sigmoid") != "sigmoid":
             refuse(f"scoring_func {hf.get('scoring_func')!r}: only sigmoid scores are implemented")
-        if hf.get("topk_method", "none") not in ("none", "group_limited_greedy"):
-            # noaux_tc adds a learnt correction bias to the scores that pick the
-            # experts; a checkpoint that has one must not be served without it
-            refuse(f"topk_method {hf.get('topk_method')!r}: the score-correction bias is not implemented")
+        topk_method = hf.get("topk_method", "none")
+        if topk_method not in ("none", "group_limited_greedy", "noaux_tc"):
+            refuse(f"topk_method {topk_method!r}: only none, group_limited_greedy and noaux_tc are implemented")
+        index_topk = int(hf.get("index_topk") or 0)
+        if hf.get("model_type") == "deepseek_v32" and not index_topk:
+            refuse("deepseek_v32 without index_topk: the sparse attention's size is not a default")
         if int(hf.get("moe_layer_freq", 1)) != 1:
             refuse("moe_layer_freq != 1: only 'leading dense layers, then expert layers' is implemented")
         if hf.get("attention_bias"):
@@ -418,6 +443,11 @@ class DecoderConfig:
             routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
             norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
             softmax_scale_mult=scale_mult, ep_size=ep_size, ep_rank=ep_rank,
+            # noaux_tc: a learnt correction bias on the scores that pick the experts
+            router_bias=topk_method == "noaux_tc",
+            index_n_heads=int(hf.get("index_n_heads") or 0) if index_topk else 0,
+            index_head_dim=int(hf.get("index_head_dim") or 0) if index_topk else 0,
+            index_topk=index_topk,
         )
         if not 0 < lm.first_dense_layers < int(hf["num_hidden_layers"]):
             refuse("needs at least one leading dense layer and one expert layer")

@@ -90,26 +90,29 @@ MOE_STAT_HEAD = 4
 
 
 @jax.named_scope("moe/router")
-def route_sigmoid_groups(lm, top_k: int, xt: jnp.ndarray, router: jnp.ndarray):
+def route_sigmoid_groups(lm, top_k: int, xt: jnp.ndarray, router: jnp.ndarray, bias=None):
     """-> (expert ids [T, K] over ALL ``router_experts``, weights [T, K] f32).
 
     float32 sigmoid scores; experts in ``n_group`` contiguous groups, a group
     scored by the sum of its two highest experts, the ``topk_group`` best
     groups kept, top-``K`` scores inside them; weights are the picked scores
     normalised over the picks (``norm_topk_prob``) times
-    ``routed_scaling_factor``.  No score-correction bias: the config names none."""
+    ``routed_scaling_factor``.  ``bias`` [router_experts] (``topk_method:
+    noaux_tc``'s score-correction bias, float32) is added to the scores that
+    PICK groups and experts; the weights stay the unbiased scores of the picks."""
     T = xt.shape[0]
     scores = jax.nn.sigmoid(
         jnp.einsum("te,ex->tx", xt.astype(jnp.float32), router.astype(jnp.float32),
                    precision=jax.lax.Precision.HIGHEST)
     )
-    choice = scores
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
     if lm.n_group > 1:
-        grouped = scores.reshape(T, lm.n_group, -1)
+        grouped = choice.reshape(T, lm.n_group, -1)
         group_score = jax.lax.top_k(grouped, min(2, grouped.shape[-1]))[0].sum(-1)  # [T, G]
         kept = jax.lax.top_k(group_score, lm.topk_group)[1]  # [T, topk_group]
         keep = jnp.zeros((T, lm.n_group), bool).at[jnp.arange(T)[:, None], kept].set(True)
-        choice = jnp.where(keep[:, :, None], grouped, -1.0).reshape(T, -1)
+        # below every score a kept group can hold: sigmoid > 0, a bias may go under -1
+        choice = jnp.where(keep[:, :, None], grouped, -1.0 if bias is None else -jnp.inf).reshape(T, -1)
     idx = jax.lax.top_k(choice, top_k)[1]
     w = jnp.take_along_axis(scores, idx, axis=-1)
     if lm.norm_topk_prob:
@@ -175,7 +178,7 @@ def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray, 
         stack, layer = tuple(w[None] for w in stack), jnp.zeros((), jnp.int32)
     elif not kernel and layer is not None:
         stack = tuple(jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False) for w in stack)
-    idx, w = route_sigmoid_groups(lm, K, xt, p["router"])
+    idx, w = route_sigmoid_groups(lm, K, xt, p["router"], p.get("router_bias"))
     with jax.named_scope("moe/dispatch"):
         local = idx - lm.first_expert  # [T, K]
         here = (local >= 0) & (local < Xh) & ok[:, None]

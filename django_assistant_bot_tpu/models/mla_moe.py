@@ -20,6 +20,20 @@ The block, per layer (``n`` RMSNorm; ``cfg.latent_moe`` has the sizes):
   capacity, no dropped token).  What the absent ranks' experts would add is
   left out, and that partial result goes on to the next layer.
 
+- learned sparse attention (``cfg.latent_moe.index_topk`` > 0; DeepSeek-V3.2's
+  lightning indexer): index queries ``c_q W_IQ`` per index head, ONE index key
+  ``LayerNorm(h W_IK)`` per token (rotary over the first ``qk_rope_head_dim``
+  lanes of both, the same tables), head weights ``h W_Iw * Hi^-0.5 * Di^-0.5``
+  in float32; ``I(t, s) = sum_j w[t,j] relu(q[t,j] . k[s])``; a query attends
+  the ``index_topk`` positions ``s <= t`` of largest ``I`` (all of them below
+  that many), exactly.  The cache holds the index key beside the latent row
+  (``LatentKVCache.idx``, the same block tables).  Prefill scores and selects a
+  tile at a time and attends under the selection as a mask
+  (``ops.attention.sparse_select`` / ``sparse_attention``); a decode step
+  scores the slot's index keys, takes the top-k and gathers ONLY the selected
+  latent rows.  With ``index_topk`` 0 none of this exists: the same weights,
+  cache and programs as before it was written.
+
 Two stacks of weights, ``dense_layers`` and ``moe_layers``, each scanned over
 its own leading axis, but for the held experts' three matrices: the scan
 bodies close over those stacks whole and index them by ``(layer, expert)``
@@ -47,13 +61,19 @@ import jax.numpy as jnp
 
 from ..ops.attention import (
     attention,
+    index_scores,
+    sparse_kernel_shaped,
     latent_decode_attention,
     latent_decode_kv_path,
     latent_decode_update_attend,
     paged_decode_plan,
+    sparse_attention,
+    sparse_decode_select,
+    sparse_latent_decode_attention,
+    sparse_select,
 )
 from ..ops.moe import held_experts_path
-from ..ops.norms import rms_norm
+from ..ops.norms import layer_norm, rms_norm
 from ..ops.rope import apply_rope, rope_frequencies
 from ..parallel.sharding import with_constraint
 from .config import DecoderConfig
@@ -63,19 +83,33 @@ from .mixtral import HELD_KEYS, MOE_STAT_HEAD, held_experts_mlp, shared_experts_
 Params = Dict[str, Any]
 
 KV_KIND = "latent"
+# counters of the sparse attention, the trailing columns of ``LatentKVCache.stats``
+# where the block selects: [programs, queries, causal pairs, selected pairs] of
+# decode steps (row 0), and of chunk programs then every other prefill program
+# (row 1), each a layer's worth (every layer selects as many)
+DSA_STAT = 8
+
+
+def kv_kind(cfg: DecoderConfig) -> str:
+    """What a cached token is: one latent row, or a latent row and an index key."""
+    return "latent+index" if cfg.latent_moe.index_topk else KV_KIND
 
 
 class LatentKVCache(NamedTuple):
     """Page pool of latent rows.  kv: [L, P, page, W], one row per token per
     layer (``c_kv | k_rope | zero pad``); lengths: [B] tokens present per slot;
-    stats: int32 [2, MOE_STAT_HEAD + experts_held], the routed layers' counters
-    since the last tick read them (row 0: decode steps, row 1: prefill), summed
-    on the device and handed out with a tick's tokens.  Block tables are the
-    host's, as for :class:`.llama.PagedKVCache`."""
+    stats: int32 [2, MOE_STAT_HEAD + experts_held (+ DSA_STAT)], the routed
+    layers' counters since the last tick read them (row 0: decode steps, row 1:
+    prefill) and, where the block selects, the sparse attention's, summed
+    on the device and handed out with a tick's tokens; idx: [L, P, page, Di]
+    the indexer's key of the same token on the same page, or None (a block
+    without an indexer).  Block tables are the host's, as for
+    :class:`.llama.PagedKVCache`."""
 
     kv: jnp.ndarray
     lengths: jnp.ndarray
     stats: jnp.ndarray
+    idx: Optional[jnp.ndarray] = None
 
     @property
     def n_pages(self) -> int:
@@ -98,7 +132,8 @@ def check_serving(*, speculative=0, prefix_cache=0, kv_cache_dtype=None,
         raise ValueError(f"speculative={speculative}: tree verification (verify_tree_step_paged) {_NOT_HERE}")
     if prefix_cache:
         raise ValueError(f"prefix_cache={prefix_cache}: the prefix cache's page gather/restore names a K and a V pool "
-                         f"and {_NOT_HERE}; set prefix_cache=0")
+                         f"(this block has a latent row and, with an indexer, a second kind of row) and {_NOT_HERE}; "
+                         "set prefix_cache=0")
     if kv_host_tier:
         raise ValueError(f"kv_host_bytes / kv_spill_dir: the host KV tier {_NOT_HERE}")
     if kv_cache_dtype or attn_fp8:
@@ -109,8 +144,11 @@ def check_serving(*, speculative=0, prefix_cache=0, kv_cache_dtype=None,
 
 
 def kv_bytes_per_token(cfg: DecoderConfig, kv_dtype=None) -> int:
-    """Bytes one cached token takes over all layers (pad lanes included)."""
-    return cfg.num_layers * cfg.latent_moe.latent_width * jnp.dtype(kv_dtype or cfg.dtype).itemsize
+    """Bytes one cached token takes over all layers (pad lanes included, and
+    the index key where the block has an indexer)."""
+    lm = cfg.latent_moe
+    width = lm.latent_width + (lm.index_head_dim if lm.index_topk else 0)
+    return cfg.num_layers * width * jnp.dtype(kv_dtype or cfg.dtype).itemsize
 
 
 def decode_kv_path(cfg: DecoderConfig, kv_dtype, page: int, *, fp8_dot: bool = False) -> str:
@@ -124,10 +162,12 @@ def moe_experts_path(cfg: DecoderConfig) -> str:
 
 def init_paged_cache(cfg: DecoderConfig, batch: int, n_pages: int, page_size: int, dtype=None) -> LatentKVCache:
     lm = cfg.latent_moe
+    dsa = bool(lm.index_topk)
     return LatentKVCache(
         kv=jnp.zeros((cfg.num_layers, n_pages, page_size, lm.latent_width), dtype or cfg.dtype),
         lengths=jnp.zeros((batch,), jnp.int32),
-        stats=jnp.zeros((2, MOE_STAT_HEAD + lm.experts_held), jnp.int32),
+        stats=jnp.zeros((2, MOE_STAT_HEAD + lm.experts_held + (DSA_STAT if dsa else 0)), jnp.int32),
+        idx=jnp.zeros((cfg.num_layers, n_pages, page_size, lm.index_head_dim), dtype or cfg.dtype) if dsa else None,
     )
 
 
@@ -137,7 +177,7 @@ def paged_cache_shardings(cfg: DecoderConfig, mesh, batch: int) -> LatentKVCache
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     rep = NamedSharding(mesh, P())
-    return LatentKVCache(kv=rep, lengths=rep, stats=rep)
+    return LatentKVCache(kv=rep, lengths=rep, stats=rep, idx=rep if cfg.latent_moe.index_topk else None)
 
 
 def _stack_sizes(cfg: DecoderConfig) -> tuple[int, int]:
@@ -153,6 +193,9 @@ def logical_axes(cfg: DecoderConfig) -> Params:
         "w_uk": (None, None, "heads"), "w_uv": (None, None, "heads"), "wo": (None, "heads", E),
         "mlp_norm": (None, E),
     }
+    if cfg.latent_moe.index_topk:  # the indexer is small and whole on every device
+        attn.update(w_iq=(None, None, None), w_ik=(None, E, None), ik_norm=(None, None), ik_bias=(None, None),
+                    w_iw=(None, E, None))
     dense = dict(attn, w_gate=(None, E, F), w_up=(None, E, F), w_down=(None, F, E))
     # the held experts stay whole on every device of this process: "expert" is the
     # axis ACROSS ranks (parallel/sharding.py), and a rank is one process here
@@ -161,6 +204,8 @@ def logical_axes(cfg: DecoderConfig) -> Params:
         w_gate=(None, None, E, F), w_up=(None, None, E, F), w_down=(None, None, F, E),
         ws_gate=(None, E, F), ws_up=(None, E, F), ws_down=(None, F, E),
     )
+    if cfg.latent_moe.router_bias:
+        moe["router_bias"] = (None, None)
     axes = {"tok_embed": ("vocab_in", E), "final_norm": (E,), "dense_layers": dense, "moe_layers": moe}
     if not cfg.tie_embeddings:
         axes["lm_head"] = (E, "vocab_out")
@@ -175,19 +220,24 @@ def init(cfg: DecoderConfig, rng: jax.Array) -> Params:
     R, C, dn, dr, dv = lm.q_lora_rank, lm.kv_lora_rank, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim
     Fm, Fs, Xh = lm.moe_intermediate_size, lm.moe_intermediate_size * lm.n_shared_experts, lm.experts_held
     nd, nm = _stack_sizes(cfg)
-    keys = iter(jax.random.split(rng, 40))
+    Hi, Di = lm.index_n_heads, lm.index_head_dim
+    keys = iter(jax.random.split(rng, 60))
 
     def dense(shape, fan_in):
         return (jax.random.normal(next(keys), shape) * fan_in ** -0.5).astype(cfg.dtype)
 
     def attn(L):
-        return {
+        out = {
             "attn_norm": jnp.ones((L, E), cfg.dtype), "w_dq": dense((L, E, R), E),
             "q_norm": jnp.ones((L, R), cfg.dtype), "w_uq": dense((L, R, H * (dn + dr)), R),
             "w_dkv": dense((L, E, C + dr), E), "kv_norm": jnp.ones((L, C), cfg.dtype),
             "w_uk": dense((L, C, H * dn), C), "w_uv": dense((L, C, H * dv), C),
             "wo": dense((L, H * dv, E), H * dv), "mlp_norm": jnp.ones((L, E), cfg.dtype),
         }
+        if lm.index_topk:
+            out.update(w_iq=dense((L, R, Hi * Di), R), w_ik=dense((L, E, Di), E), ik_norm=jnp.ones((L, Di), cfg.dtype),
+                       ik_bias=jnp.zeros((L, Di), cfg.dtype), w_iw=dense((L, E, Hi), E))
+        return out
 
     params = {
         "tok_embed": dense((cfg.vocab_size, E), 1.0),
@@ -200,6 +250,8 @@ def init(cfg: DecoderConfig, rng: jax.Array) -> Params:
             ws_gate=dense((nm, E, Fs), E), ws_up=dense((nm, E, Fs), E), ws_down=dense((nm, Fs, E), Fs),
         ),
     }
+    if lm.router_bias:
+        params["moe_layers"]["router_bias"] = jnp.zeros((nm, lm.router_experts), cfg.dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense((E, cfg.vocab_size), E)
     return params
@@ -228,8 +280,9 @@ def _mm(pattern: str, x, w, dtype):
 
 
 def _queries_and_row(cfg: DecoderConfig, p: Params, h: jnp.ndarray, cos, sin):
-    """-> (q_nope [B,S,H,dn], q_rope [B,S,H,dr] rotated, row [B,S,W]): the
-    queries of ``h`` and the row the cache keeps for it."""
+    """-> (q_nope [B,S,H,dn], q_rope [B,S,H,dr] rotated, row [B,S,W], c_q
+    [B,S,R]): the queries of ``h``, the row the cache keeps for it, and the
+    query latent (the indexer's queries come from it too)."""
     lm = cfg.latent_moe
     B, S, _ = h.shape
     H, dn, dr, C = cfg.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.kv_lora_rank
@@ -244,33 +297,137 @@ def _queries_and_row(cfg: DecoderConfig, p: Params, h: jnp.ndarray, cos, sin):
         k_rope = apply_rope(ckv[..., None, C:], cos, sin)[..., 0, :]
         pad = lm.latent_width - C - dr
         row = jnp.concatenate([c_kv, k_rope] + ([jnp.zeros((B, S, pad), c_kv.dtype)] if pad else []), axis=-1)
-    return q_nope, q_rope, row
+    return q_nope, q_rope, row, c_q
 
 
-def _expanded_attention(cfg: DecoderConfig, p: Params, q_nope, q_rope, rows, *, causal=False, mask=None):
-    """Prefill's form: keys and values expanded from latent ``rows`` [B,Sk,W]
-    -> o [B,Sq,H*dv].  The flash kernel takes it where it is kernel-shaped
-    (``ops.attention.attention``): query/key width padded with zeros to whole
-    lane tiles (192 -> 256), value width as it is."""
+def _index_parts(cfg: DecoderConfig, p: Params, h: jnp.ndarray, c_q: jnp.ndarray, cos, sin):
+    """The indexer's share of a token -> (q_idx [B,S,Hi,Di] rotated, w_idx
+    [B,S,Hi] float32, k_idx [B,S,Di] rotated: what the second cache keeps).
+    Rotary over the FIRST ``qk_rope_head_dim`` lanes of a head, MLA's tables."""
+    lm = cfg.latent_moe
+    B, S, _ = h.shape
+    Hi, Di, dr = lm.index_n_heads, lm.index_head_dim, lm.qk_rope_head_dim
+
+    def rotate(x):  # [B, S, heads, Di]
+        return jnp.concatenate([apply_rope(x[..., :dr], cos, sin), x[..., dr:]], axis=-1)
+
+    with jax.named_scope("attn/index_q"):
+        q_idx = rotate(_mm("bsr,ro->bso", c_q, p["w_iq"], cfg.dtype).reshape(B, S, Hi, Di))
+        w_idx = jnp.einsum("bse,eh->bsh", h.astype(jnp.float32), p["w_iw"].astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST) * float(Hi ** -0.5 * Di ** -0.5)
+    with jax.named_scope("attn/index_k"):
+        k = layer_norm(_mm("bse,ed->bsd", h, p["w_ik"], cfg.dtype), p["ik_norm"], p["ik_bias"], cfg.rms_norm_eps)
+        k_idx = rotate(k[:, :, None, :])[:, :, 0, :]
+    return q_idx, w_idx, k_idx
+
+
+def _dsa_counts(real: jnp.ndarray, qpos: jnp.ndarray, keep_sum) -> jnp.ndarray:
+    """One layer's [queries, causal pairs, selected pairs] over the real queries."""
+    return jnp.stack([real.sum(), jnp.where(real, qpos + 1, 0).sum(), keep_sum]).astype(jnp.int32)
+
+
+def _stats_row(cfg: DecoderConfig, moe_layers: jnp.ndarray, dsa_layers, kind: int) -> jnp.ndarray:
+    """A program's counters as one row of ``LatentKVCache.stats``: the routed
+    layers' summed over layers and, where the block selects, the sparse
+    attention's of ONE layer (every layer counts the same) in the columns of
+    ``kind`` (0: a decode step or a chunk program, 1: any other prefill)."""
+    row = moe_layers.sum(0)
+    if dsa_layers is None:
+        return row
+    per = dsa_layers.sum(0) // cfg.num_layers
+    mine = jnp.concatenate([(per[:1] > 0).astype(jnp.int32), per])
+    zero = jnp.zeros((DSA_STAT // 2,), jnp.int32)
+    return jnp.concatenate([row, mine, zero] if kind == 0 else [row, zero, mine])
+
+
+def _lane_pad(cfg: DecoderConfig) -> int:
+    """Zero lanes that bring the query/key width to whole lane tiles (192 -> 256)."""
+    D = cfg.latent_moe.qk_head_dim
+    return (-D) % 128 if D > 64 else 0
+
+
+@jax.named_scope("attn/kv_up")
+def _expanded_queries(cfg: DecoderConfig, q_nope, q_rope):
+    """-> q [B,H,Sq,D] in the expanded form's layout, padded as the keys are."""
+    pad = _lane_pad(cfg)
+    zq = [jnp.zeros(q_nope.shape[:-1] + (pad,), q_nope.dtype)] if pad else []
+    q = jnp.concatenate([q_nope, q_rope] + zq, axis=-1).transpose(0, 2, 1, 3)
+    return with_constraint(q, ("batch", "heads", "length", "head_dim"))
+
+
+@jax.named_scope("attn/kv_up")
+def _expanded_keys_values(cfg: DecoderConfig, p: Params, rows):
+    """Latent ``rows`` [B,Sk,W] -> (k [B,H,Sk,D], v [B,H,Sk,dv]): keys ``[rows
+    W_UK | k_rope | zero pad]``, values ``rows W_UV``."""
     lm = cfg.latent_moe
     B, Sk, _ = rows.shape
     H, dn, dr, dv, C = cfg.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim, lm.kv_lora_rank
-    with jax.named_scope("attn/kv_up"):
-        c_kv = rows[..., :C].astype(cfg.dtype)
-        k_rope = rows[..., C:C + dr].astype(cfg.dtype)
-        k_nope = _mm("bsc,co->bso", c_kv, p["w_uk"], cfg.dtype).reshape(B, Sk, H, dn)
-        v = _mm("bsc,co->bso", c_kv, p["w_uv"], cfg.dtype).reshape(B, Sk, H, dv)
-        D = dn + dr
-        pad = (-D) % 128 if D > 64 else 0
-        zq = [jnp.zeros(q_nope.shape[:-1] + (pad,), q_nope.dtype)] if pad else []
-        zk = [jnp.zeros((B, Sk, H, pad), k_nope.dtype)] if pad else []
-        q = jnp.concatenate([q_nope, q_rope] + zq, axis=-1).transpose(0, 2, 1, 3)
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, Sk, H, dr))] + zk, axis=-1)
-        k = k.transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
-        q = with_constraint(q, ("batch", "heads", "length", "head_dim"))
-    o = attention(q, k, v, causal=causal, mask=mask, scale=softmax_scale(cfg))  # attn/core
+    c_kv = rows[..., :C].astype(cfg.dtype)
+    k_rope = rows[..., C:C + dr].astype(cfg.dtype)
+    k_nope = _mm("bsc,co->bso", c_kv, p["w_uk"], cfg.dtype).reshape(B, Sk, H, dn)
+    v = _mm("bsc,co->bso", c_kv, p["w_uv"], cfg.dtype).reshape(B, Sk, H, dv)
+    pad = _lane_pad(cfg)
+    zk = [jnp.zeros((B, Sk, H, pad), k_nope.dtype)] if pad else []
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, Sk, H, dr))] + zk, axis=-1)
+    return k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+
+
+def _expanded_attention(cfg: DecoderConfig, p: Params, q_nope, q_rope, rows, *, causal=False, mask=None, keep=None,
+                        live=None):
+    """Prefill's form: keys and values expanded from latent ``rows`` [B,Sk,W]
+    -> o [B,Sq,H*dv].  The flash kernel takes it where it is kernel-shaped
+    (``ops.attention.attention``): query/key width padded with zeros to whole
+    lane tiles, value width as it is.  ``keep`` [B,Sq,Sk] (with ``live`` [B], the
+    keys any query of a row can keep) is the sparse attention's selection,
+    causality included: the pairs attended, under the masked flash kernel
+    (``ops.attention.sparse_attention``)."""
+    B, H, dv = rows.shape[0], cfg.num_heads, cfg.latent_moe.v_head_dim
+    q = _expanded_queries(cfg, q_nope, q_rope)
+    k, v = _expanded_keys_values(cfg, p, rows)
+    if keep is not None:
+        o = sparse_attention(q, k.swapaxes(2, 3), v, keep, live, scale=softmax_scale(cfg))  # attn/sparse_core
+    else:
+        o = attention(q, k, v, causal=causal, mask=mask, scale=softmax_scale(cfg))  # attn/core
     return o.transpose(0, 2, 1, 3).reshape(B, q_nope.shape[1], H * dv)
+
+
+def _sparse_attention_over_pages(cfg: DecoderConfig, p: Params, q_nope, q_rope, q_idx, w_idx, pool, ipool, layer,
+                                 block_tables, pos, ok, live):
+    """A chunk's sparse attention over its rows' pages -> (o [B,C,H*dv], pairs
+    kept).  The view is built a PAGE at a time, and only the pages that hold a
+    live key (``live`` [B]: keys below it can be kept): the page's latent rows
+    and index keys are read where they lie, the rows expanded into keys and
+    values, and all three written into the view's buffers in place.  What a
+    chunk costs then follows the context held, not ``max_seq_len``: dead pages
+    are never gathered or expanded, and on a TPU their part of the buffers is
+    never written or read (the two kernels skip tiles past the live keys)."""
+    lm = cfg.latent_moe
+    B, C = q_nope.shape[:2]
+    L, P, page, W = pool.shape
+    S = block_tables.shape[1] * page
+    H, dv, Di = cfg.num_heads, lm.v_head_dim, lm.index_head_dim
+    D = lm.qk_head_dim + _lane_pad(cfg)
+    # the plain path reads the whole view under a mask: there the dead part has to be finite
+    make = jax.lax.empty if sparse_kernel_shaped(C, S, D, dv) else jnp.zeros
+    # the keys transposed, positions on the last axis: how a page's expansion comes out of its matmul and how
+    # the kernel contracts them; any other layout costs a copy of the whole view a layer
+    view = (make((B, H, D, S), cfg.dtype), make((B, H, S, dv), cfg.dtype), make((B, S, Di), ipool.dtype))
+
+    def add_page(j, view):
+        kb, vb, ib = view
+        pages = jnp.clip(jax.lax.dynamic_index_in_dim(block_tables, j, 1, keepdims=False), 0, P - 1)  # [B]
+        with jax.named_scope("attn/kv_read"):
+            rows, keys = pool[layer, pages], ipool[layer, pages]
+        k, v = _expanded_keys_values(cfg, p, rows)
+        with jax.named_scope("attn/kv_up"):
+            at = j * page
+            return (jax.lax.dynamic_update_slice_in_dim(kb, k.swapaxes(2, 3), at, 3),
+                    jax.lax.dynamic_update_slice_in_dim(vb, v, at, 2), jax.lax.dynamic_update_slice_in_dim(ib, keys, at, 1))
+
+    kb, vb, ib = jax.lax.fori_loop(0, jnp.max(-(-live // page)), add_page, view)
+    keep = sparse_select(q_idx, w_idx, ib, pos, ok, lm.index_topk)
+    o = sparse_attention(_expanded_queries(cfg, q_nope, q_rope), kb, vb, keep, live, scale=softmax_scale(cfg))  # attn/sparse_core
+    return o.transpose(0, 2, 1, 3).reshape(B, C, H * dv), keep.sum()
 
 
 @jax.named_scope("attn/out")
@@ -333,28 +490,50 @@ def _finish(params: Params, cfg: DecoderConfig, x_last: jnp.ndarray) -> jnp.ndar
 
 def prefill(params: Params, cfg: DecoderConfig, input_ids: jnp.ndarray, lengths: jnp.ndarray):
     """Right-padded prompts through the model -> (last-token logits [B,V] f32,
-    latent rows [L,B,S,W], routed-layer counters): the second and third go to
-    :func:`insert_sequences_paged` where the llama path hands ``ks, vs``."""
+    latent rows [L,B,S,W], counters): the second and third go to
+    :func:`insert_sequences_paged` where the llama path hands ``ks, vs``.  With
+    an indexer the second is ``(latent rows, index keys [L,B,S,Di])``, and a
+    sequence longer than ``index_topk`` attends under the selection; a shorter
+    one selects everything, which is the causal attention itself."""
     B, S = input_ids.shape
+    lm = cfg.latent_moe
     cos, sin = _rope_tables(cfg, S)
     valid = jnp.arange(S)[None, :] < lengths[:, None]
     x = _embed(params, cfg, input_ids)
+    qpos = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+    select = bool(lm.index_topk) and S > lm.index_topk
+    if select:  # right-padded: causal and real keeps real queries on real keys
+        ok = (jnp.arange(S)[None, None, :] <= qpos[:, :, None]) & valid[:, :, None]
 
     def make_body(held):
         def body(x, inputs):
             p, layer = inputs
             h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_rope, row = _queries_and_row(cfg, p, h, cos, sin)
-            # right-padded input: causal masking alone keeps real queries on real keys
-            x = x + _attn_out(cfg, p, _expanded_attention(cfg, p, q_nope, q_rope, row, causal=True))
+            q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
+            out = (row,)
+            if lm.index_topk:
+                q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
+            if select:
+                keep = sparse_select(q_idx, w_idx, k_idx, qpos, ok, lm.index_topk)
+                o = _expanded_attention(cfg, p, q_nope, q_rope, row, keep=keep, live=lengths)
+                selected = keep.sum()
+            else:
+                # right-padded input: causal masking alone keeps real queries on real keys
+                o = _expanded_attention(cfg, p, q_nope, q_rope, row, causal=True)
+                selected = jnp.where(valid, qpos + 1, 0).sum()
+            x = x + _attn_out(cfg, p, o)
             y, stats = _ffn(cfg, p, x, valid, held, layer)
-            return with_constraint(x + y, ("batch", "length", "embed")), (row, stats)
+            if lm.index_topk:
+                out = (row, k_idx, _dsa_counts(valid, qpos, selected))
+            return with_constraint(x + y, ("batch", "length", "embed")), (out, stats)
 
         return body
 
-    x, (rows, stats) = _scan_stacks(cfg, params, make_body, x)
+    x, (out, stats) = _scan_stacks(cfg, params, make_body, x)
     last = jnp.take_along_axis(x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
-    return _finish(params, cfg, last), rows, stats.sum(0)
+    if lm.index_topk:
+        return _finish(params, cfg, last), (out[0], out[1]), _stats_row(cfg, stats, out[2], 1)
+    return _finish(params, cfg, last), out[0], stats.sum(0)
 
 
 def insert_sequences_paged(
@@ -366,48 +545,71 @@ def insert_sequences_paged(
     block_tables: jnp.ndarray,  # [B, NB]; pad rows carry the P sentinel
 ) -> LatentKVCache:
     """Write prefilled rows into their slots' pages (positions [0, Sb)), whole
-    pages at a time; blocks past a row's allocation and pad rows drop."""
-    L, P, page, W = cache.kv.shape
-    Sb = rows.shape[2]
-    nbw = min(block_tables.shape[1], -(-Sb // page))
-    if nbw * page != Sb:
-        rows = jnp.pad(rows, ((0, 0), (0, 0), (0, nbw * page - Sb), (0, 0)))
-    kv = cache.kv
-    for j in range(nbw):
-        blk = jax.lax.slice_in_dim(rows, j * page, (j + 1) * page, axis=2)
-        kv = kv.at[:, jnp.minimum(block_tables[:, j], P)].set(blk.astype(kv.dtype), mode="drop")
+    pages at a time; blocks past a row's allocation and pad rows drop.  With an
+    indexer ``rows`` is :func:`prefill`'s pair and the index keys go to the
+    same pages of the second pool."""
+    P, page = cache.n_pages, cache.page_size
+
+    def write(pool, rows):
+        Sb = rows.shape[2]
+        nbw = min(block_tables.shape[1], -(-Sb // page))
+        if nbw * page != Sb:
+            rows = jnp.pad(rows, ((0, 0), (0, 0), (0, nbw * page - Sb), (0, 0)))
+        for j in range(nbw):
+            blk = jax.lax.slice_in_dim(rows, j * page, (j + 1) * page, axis=2)
+            pool = pool.at[:, jnp.minimum(block_tables[:, j], P)].set(blk.astype(pool.dtype), mode="drop")
+        return pool
+
+    if cache.idx is None:
+        kv, idx = write(cache.kv, rows), None
+    else:
+        kv, idx = write(cache.kv, rows[0]), write(cache.idx, rows[1])
     return LatentKVCache(
         kv=kv,
         lengths=cache.lengths.at[slots].set(lengths.astype(cache.lengths.dtype), mode="drop"),
         stats=cache.stats.at[1].add(stats),
+        idx=idx,
     )
 
 
 def copy_pages(cache: LatentKVCache, src: jnp.ndarray, dst: jnp.ndarray) -> LatentKVCache:
     """Clone whole pages inside the pool (the allocator's copy-on-write
-    primitive); dst entries >= P drop."""
+    primitive), index keys with their latent rows; dst entries >= P drop."""
     P = cache.n_pages
-    kv = cache.kv.at[:, jnp.minimum(dst, P)].set(jnp.take(cache.kv, jnp.clip(src, 0, P - 1), axis=1), mode="drop")
-    return cache._replace(kv=kv)
+
+    def clone(pool):
+        return pool.at[:, jnp.minimum(dst, P)].set(jnp.take(pool, jnp.clip(src, 0, P - 1), axis=1), mode="drop")
+
+    return cache._replace(kv=clone(cache.kv), idx=None if cache.idx is None else clone(cache.idx))
 
 
 def _gather_rows(pool, layer, block_tables):
-    """One layer's logical view of each row's pages -> [B, NB*page, W]."""
+    """One layer's logical view of each row's pages -> [B, NB*page, W], gathered
+    from the whole pool by (layer, page): slicing the layer out first is a copy
+    the size of a layer of the pool, every layer of every chunk."""
     L, P, page, W = pool.shape
     B, NB = block_tables.shape
-    layer_pool = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
-    rows = jnp.take(layer_pool, jnp.clip(block_tables, 0, P - 1).reshape(-1), axis=0)
-    return rows.reshape(B, NB * page, W)
+    return pool[layer, jnp.clip(block_tables, 0, P - 1)].reshape(B, NB * page, W)
 
 
-def _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, valids):
+def _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, valids, *, chunk: bool):
     """Chunk and suffix prefill: ``C`` new tokens per row at positions
     ``starts + [0, C)`` against what the row's pages already hold.  Per layer
     the new rows are scattered into the pool token by token (pad tokens and
     unallocated blocks drop, so a shared prefix page is never written), then
-    the row's logical view is gathered and attended in the expanded form."""
+    the row's logical view is gathered and attended in the expanded form.
+
+    With an indexer the index keys are written and gathered the same way, and
+    where the view is longer than ``index_topk`` the queries attend under the
+    selection: index scores reduced over the indexer's heads a tile at a time,
+    the top-k as a threshold found by counting, the attention as a flash
+    kernel that takes the selection as its mask, so that nothing of [heads,
+    chunk, context] size exists.  Only positions below a query's own and on an
+    allocated page can be selected: a reused page's stale rows lie past the
+    slot's position.  -> (logits, latent pool, index pool or None, counters)."""
     B, C = input_ids.shape
     L, P, page, W = cache.kv.shape
+    lm = cfg.latent_moe
     NB = block_tables.shape[1]
     S = NB * page
     pos = starts[:, None] + jnp.arange(C)[None, :]  # [B, C]
@@ -418,48 +620,66 @@ def _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, 
     phys = jnp.take_along_axis(block_tables, jnp.minimum(pos // page, NB - 1), axis=1)
     phys = jnp.where(real & (pos < S), jnp.minimum(phys, P), P)
     off = pos % page
-    mask = (jnp.arange(S)[None, None, None, :] <= pos[:, None, :, None])  # [B,1,C,S]
+    select = bool(lm.index_topk) and S > lm.index_topk
+    if select:
+        allocated = jnp.repeat((block_tables >= 0) & (block_tables < P), page, axis=1)  # [B, S]
+        ok = (jnp.arange(S)[None, None, :] <= pos[:, :, None]) & allocated[:, None, :] & real[:, :, None]
+        live = jnp.minimum(starts + valids, S)
+    else:
+        mask = (jnp.arange(S)[None, None, None, :] <= pos[:, None, :, None])  # [B,1,C,S]
     x = _embed(params, cfg, input_ids)
 
     def make_body(held):
         def body(carry, inputs):
-            x, pool = carry
+            x, pool, ipool = carry
             p, layer = inputs
             h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_rope, row = _queries_and_row(cfg, p, h, cos, sin)
+            q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
+            if lm.index_topk:
+                q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
             with jax.named_scope("attn/kv_write"):
                 pool = pool.at[layer, phys, off].set(row.astype(pool.dtype), mode="drop")
-            with jax.named_scope("attn/kv_read"):
-                rows = _gather_rows(pool, layer, block_tables)
-            x = x + _attn_out(cfg, p, _expanded_attention(cfg, p, q_nope, q_rope, rows, mask=mask))
+                if lm.index_topk:
+                    ipool = ipool.at[layer, phys, off].set(k_idx.astype(ipool.dtype), mode="drop")
+            if select:
+                o, selected = _sparse_attention_over_pages(
+                    cfg, p, q_nope, q_rope, q_idx, w_idx, pool, ipool, layer, block_tables, pos, ok, live)
+            else:
+                with jax.named_scope("attn/kv_read"):
+                    rows = _gather_rows(pool, layer, block_tables)
+                o = _expanded_attention(cfg, p, q_nope, q_rope, rows, mask=mask)
+                selected = jnp.where(real, pos + 1, 0).sum()
+            x = x + _attn_out(cfg, p, o)
             y, stats = _ffn(cfg, p, x, real, held, layer)
-            return (x + y, pool), stats
+            return (x + y, pool, ipool), (stats, _dsa_counts(real, pos, selected) if lm.index_topk else None)
 
         return body
 
-    (x, pool), stats = _scan_stacks(cfg, params, make_body, (x, cache.kv))
+    (x, pool, ipool), (stats, dsa) = _scan_stacks(cfg, params, make_body, (x, cache.kv, cache.idx))
     last = jnp.take_along_axis(x, jnp.maximum(valids - 1, 0)[:, None, None], axis=1)[:, 0]
-    return _finish(params, cfg, last), pool, stats.sum(0)
+    return _finish(params, cfg, last), pool, ipool, _stats_row(cfg, stats, dsa, 0 if chunk else 1)
 
 
 def prefill_suffix_paged(params, cfg, input_ids, cache, block_tables, slots, starts, valids):
     """Suffix tokens ``[B, C]`` after each row's ``starts`` cached tokens ->
     (logits [B,V] f32, cache)."""
-    logits, pool, stats = _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, valids)
+    logits, pool, ipool, stats = _prefill_against_cache(
+        params, cfg, input_ids, cache, block_tables, starts, valids, chunk=False)
     lengths = cache.lengths.at[slots].set((starts + valids).astype(cache.lengths.dtype), mode="drop")
-    return logits, LatentKVCache(kv=pool, lengths=lengths, stats=cache.stats.at[1].add(stats))
+    return logits, LatentKVCache(kv=pool, lengths=lengths, stats=cache.stats.at[1].add(stats), idx=ipool)
 
 
 def prefill_chunk_paged(params, cfg, input_ids, cache, block_table, slot, start, valid):
     """One chunk ``[1, C]`` of one long prompt extends the slot's page chain ->
     (logits [1,V] f32, cache)."""
-    logits, pool, stats = _prefill_against_cache(
-        params, cfg, input_ids, cache, block_table[None, :], jnp.reshape(start, (1,)), jnp.reshape(valid, (1,))
+    logits, pool, ipool, stats = _prefill_against_cache(
+        params, cfg, input_ids, cache, block_table[None, :], jnp.reshape(start, (1,)), jnp.reshape(valid, (1,)),
+        chunk=True,
     )
     lengths = jax.lax.dynamic_update_index_in_dim(
         cache.lengths, (start + valid).astype(cache.lengths.dtype), slot, 0
     )
-    return logits, LatentKVCache(kv=pool, lengths=lengths, stats=cache.stats.at[1].add(stats))
+    return logits, LatentKVCache(kv=pool, lengths=lengths, stats=cache.stats.at[1].add(stats), idx=ipool)
 
 
 # ---------------------------------------------------------------------------
@@ -490,7 +710,14 @@ def decode_step_paged(
     nothing in the step makes a value the size of a layer of the pool (PERF.md
     section 5, PR 25).  Elsewhere a drop-mode scatter and the plain gather
     (:func:`~..ops.attention.latent_decode_attention`) do the same.  Inactive
-    rows and rows past their allocation write nothing on either path."""
+    rows and rows past their allocation write nothing on either path.
+
+    With an indexer (``index_topk``) and a view longer than it, a layer writes
+    the slot's latent row and index key where they belong, scores the index
+    keys of the slot's pages, takes the top-k among positions ``<= pos`` on
+    allocated pages, and gathers ONLY those latent rows for the absorbed
+    attention (:func:`~..ops.attention.sparse_latent_decode_attention`): the
+    whole-page kernel is not on this path."""
     if attn_fp8:
         raise NotImplementedError("attn_fp8 is not implemented for the latent cache")
     lm = cfg.latent_moe
@@ -506,7 +733,8 @@ def decode_step_paged(
     cos_t, sin_t = _rope_tables(cfg, S)
     cos, sin = cos_t[positions][:, None, :], sin_t[positions][:, None, :]
     scale = softmax_scale(cfg)
-    kernel = latent_decode_kv_path(cache.kv.dtype, page, W) == "kernel"
+    select = bool(lm.index_topk) and S > lm.index_topk
+    kernel = latent_decode_kv_path(cache.kv.dtype, page, W) == "kernel" and not lm.index_topk
     if kernel:
         plan = paged_decode_plan(block_tables, positions, active, n_pages=P, page=page)
     else:
@@ -515,13 +743,16 @@ def decode_step_paged(
         off = positions % page
     x = _embed(params, cfg, tokens)[:, None, :]
     valid = active[:, None]
+    if select:
+        allocated = jnp.repeat((block_tables >= 0) & (block_tables < P), page, axis=1)  # [B, S]
+        ok = (jnp.arange(S)[None, :] <= positions[:, None]) & allocated & active[:, None]
 
     def make_body(held):
         def body(carry, inputs):
-            x, pool = carry
+            x, pool, ipool = carry
             p, layer = inputs
             h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_rope, row = _queries_and_row(cfg, p, h, cos, sin)
+            q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
             with jax.named_scope("attn/absorb"):
                 q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], p["w_uk"].astype(cfg.dtype).reshape(C, H, dn))
                 pad = W - C - dr
@@ -533,24 +764,38 @@ def decode_step_paged(
                     q, row[:, 0], pool, layer, block_tables, positions, plan, scale=scale, value_width=C
                 )
             else:
+                if lm.index_topk:
+                    q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
                 with jax.named_scope("attn/kv_write"):
                     pool = pool.at[layer, phys_w, off].set(row[:, 0].astype(pool.dtype), mode="drop")
-                o_lat = latent_decode_attention(
-                    q, jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False), block_tables, positions,
-                    scale=scale, value_width=C, active=active,
-                )
+                    if lm.index_topk:
+                        ipool = ipool.at[layer, phys_w, off].set(k_idx[:, 0].astype(ipool.dtype), mode="drop")
+                if select:
+                    with jax.named_scope("attn/kv_read"):
+                        keys = _gather_rows(ipool, layer, block_tables)  # the index keys of the slot's pages
+                    idx, picked = sparse_decode_select(index_scores(q_idx, w_idx, keys)[:, 0], ok, lm.index_topk)
+                    o_lat = sparse_latent_decode_attention(
+                        q, pool, layer, block_tables, idx, picked, scale=scale, value_width=C)
+                    selected = picked.sum()
+                else:
+                    o_lat = latent_decode_attention(
+                        q, jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False), block_tables, positions,
+                        scale=scale, value_width=C, active=active,
+                    )
+                    selected = jnp.where(active, positions + 1, 0).sum()
             with jax.named_scope("attn/absorb"):
                 o = jnp.einsum("bhc,chd->bhd", o_lat, p["w_uv"].astype(cfg.dtype).reshape(C, H, dv))
             x = x + _attn_out(cfg, p, o.reshape(B, 1, H * dv))
             y, stats = _ffn(cfg, p, x, valid, held, layer)
-            return (x + y, pool), stats
+            return (x + y, pool, ipool), (stats, _dsa_counts(active, positions, selected) if lm.index_topk else None)
 
         return body
 
-    (x, pool), stats = _scan_stacks(cfg, params, make_body, (x, cache.kv))
+    (x, pool, ipool), (stats, dsa) = _scan_stacks(cfg, params, make_body, (x, cache.kv, cache.idx))
     new_cache = LatentKVCache(
         kv=pool,
         lengths=jnp.where(active, cache.lengths + 1, cache.lengths),
-        stats=cache.stats.at[0].add(stats.sum(0)),
+        stats=cache.stats.at[0].add(_stats_row(cfg, stats, dsa, 0)),
+        idx=ipool,
     )
     return _finish(params, cfg, x[:, 0]), new_cache

@@ -1278,3 +1278,361 @@ def attention(
     return dot_product_attention(
         q, k, v, causal=causal, mask=mask, q_offset=q_offset, window=window, scale=scale
     )
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention: a lightning indexer's scores, an exact top-k, and
+# attention over what was selected (models/mla_moe.py, ``index_topk``)
+# ---------------------------------------------------------------------------
+#
+# I(t, s) = sum_j w[t, j] * relu(q[t, j] . k[s]) over the indexer's heads; the
+# ``topk`` positions s <= t of largest I are the keys query t attends.  Nothing
+# here makes a value of [heads, queries, keys] size on a TPU: the scores are
+# reduced over the indexer's heads tile by tile as they are made, the selection
+# is a threshold found by counting over the reduced [keys, queries] scores, and
+# the attention is a flash kernel that takes the selection as a mask.
+
+
+def sparse_kernel_shaped(Sq: int, Sk: int, D: int, Dv: int) -> bool:
+    """Whether a prefill call of this shape takes the two Pallas kernels on a
+    TPU (:func:`index_scores_t`, :func:`masked_flash_attention`); anything else
+    (the CPU, toy shapes) takes the plain path."""
+    return (
+        jax.default_backend() == "tpu"
+        and Sq % 256 == 0
+        and Sk % 512 == 0
+        and D % 128 == 0
+        and Dv % 128 == 0
+    )
+
+
+def _index_score_kernel(
+    starts_ref,  # scalar prefetch [B]: position of each row's first query
+    q_ref,  # [Hi, block_q, Di]
+    w_ref,  # [Hi, 1, block_q] f32
+    k_ref,  # [block_k, Di]
+    o_ref,  # [block_k, block_q] f32: keys on the sublanes, queries on the lanes
+):
+    """One (row, query tile, key tile) program: ``sum over heads of w * relu(k .
+    q^T)`` accumulated in registers a head after another, so the per-head
+    scores never leave VMEM.  Transposed on purpose: with the queries on the
+    lanes a head's weights are one row that broadcasts over the sublanes for
+    free, and the selection downstream reduces over the major axis.  A tile
+    wholly above the causal diagonal is written as zeros and costs nothing."""
+    b, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    block_k, block_q = o_ref.shape
+    last_query = starts_ref[b] + (qi + 1) * block_q - 1
+    live = ki * block_k <= last_query
+
+    @pl.when(live)
+    def _score():
+        k = k_ref[...]
+
+        def head(h, acc):
+            s = jax.lax.dot_general(k, q_ref[h], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            return acc + jnp.maximum(s, 0.0) * w_ref[h]
+
+        o_ref[...] = jax.lax.fori_loop(0, q_ref.shape[0], head, jnp.zeros(o_ref.shape, jnp.float32))
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+
+@jax.named_scope("attn/index_score")
+def index_scores_t(
+    q: jnp.ndarray,  # [B, C, Hi, Di] index queries (rotated)
+    w: jnp.ndarray,  # [B, C, Hi] f32 head weights (scaled)
+    k: jnp.ndarray,  # [B, S, Di] index keys of each row's logical view
+    starts: jnp.ndarray,  # [B] int32: position of each row's first query
+    *,
+    block_q: int = 256,
+    block_k: int = 512,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The indexer's scores, TRANSPOSED: ``[B, S, C]`` float32 (Pallas; the
+    plain form is :func:`index_scores`).  Entries above the causal diagonal
+    are unspecified (whole dead tiles are zeros): the selection masks them."""
+    B, C, Hi, Di = q.shape
+    S = k.shape[1]
+    block_q, block_k = min(block_q, C), min(block_k, S)
+    if C % block_q or S % block_k:
+        raise ValueError(f"index_scores_t needs queries and keys in whole tiles, got C={C}, S={S}")
+    qt = q.transpose(0, 2, 1, 3)  # [B, Hi, C, Di]
+    wt = w.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, :]  # [B, Hi, 1, C]
+    return pl.pallas_call(
+        _index_score_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            # keys innermost: a query tile's 64 heads stay in VMEM while its key tiles stream by
+            grid=(B, C // block_q, S // block_k),
+            in_specs=[
+                pl.BlockSpec((None, Hi, block_q, Di), lambda b, qi, ki, st: (b, 0, qi, 0)),
+                pl.BlockSpec((None, Hi, 1, block_q), lambda b, qi, ki, st: (b, 0, 0, qi)),
+                pl.BlockSpec((None, block_k, Di), lambda b, qi, ki, st: (b, ki, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, block_k, block_q), lambda b, qi, ki, st: (b, ki, qi)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, S, C), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=4 * Hi * block_q * Di * q.dtype.itemsize + 8 * block_q * block_k * 4 + (16 << 20),
+        ),
+        name="index_scores",
+        interpret=interpret,
+    )(starts.astype(jnp.int32), qt, wt, k)
+
+
+@jax.named_scope("attn/index_score")
+def index_scores(q: jnp.ndarray, w: jnp.ndarray, k: jnp.ndarray) -> jnp.ndarray:
+    """The plain form -> ``[B, C, S]`` float32: every head's scores at once
+    (``[B, C, Hi, S]``), which a decode step (``C`` = 1) and the CPU can
+    afford."""
+    s = jnp.einsum("bchd,bsd->bchs", q, k, preferred_element_type=jnp.float32)
+    return jnp.einsum("bchs,bch->bcs", jnp.maximum(s, 0.0), w.astype(jnp.float32))
+
+
+def _sortable(scores: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> uint32 that orders as the floats do (-0.0 just under +0.0)."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32)
+    bits = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    return jax.lax.bitcast_convert_type(bits, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+@jax.named_scope("attn/select")
+def topk_mask(scores: jnp.ndarray, k: int, ok: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Exact top-``k`` along ``axis`` as a mask: True at the ``k`` largest
+    ``scores`` among the entries ``ok`` marks (all of them where fewer are
+    marked), ties at the k-th value going to the lowest index, as
+    ``jax.lax.top_k`` breaks them.
+
+    No sort: the k-th largest value is found by counting, one bit of the
+    score a pass (32 passes of a compare and a sum over the array; a sort of
+    16,384 keys a query is an order of magnitude more traffic).  The tie rule
+    needs a running count along ``axis`` and is computed only in a call where
+    some k-th value is in fact shared."""
+    axis = axis % scores.ndim
+    if scores.shape[axis] <= k:
+        return ok
+    key = jnp.where(ok, _sortable(scores), jnp.uint32(0))
+
+    def bit(i, prefix):
+        cand = prefix | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
+        enough = jnp.sum(key >= cand, axis=axis, keepdims=True, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, prefix)
+
+    shape = tuple(1 if a == axis else n for a, n in enumerate(scores.shape))
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(shape, jnp.uint32))  # 0 where fewer than k are marked
+    above = (key >= kth) & ok
+    shared = jnp.sum(above, axis=axis, keepdims=True, dtype=jnp.int32) > k
+
+    def with_ties(_):
+        gt = (key > kth) & ok
+        eq = (key == kth) & ok
+        room = k - jnp.sum(gt, axis=axis, keepdims=True, dtype=jnp.int32)
+        return gt | (eq & (jnp.cumsum(eq, axis=axis, dtype=jnp.int32) <= room))
+
+    return jax.lax.cond(jnp.any(shared), with_ties, lambda _: above, None)
+
+
+def _masked_flash_kernel(
+    live_ref,  # scalar prefetch [B]: keys at or past it are dead for every query of the row
+    q_ref,  # [heads, block_q, D]
+    kt_ref,  # [heads, D, chunk_kv]: the keys TRANSPOSED, positions on the lanes
+    v_ref,  # [heads, chunk_kv, Dv]
+    mask_ref,  # [block_q, chunk_kv] int8, shared by every head
+    o_ref,  # [heads, block_q, Dv]
+    m_scr,  # [heads, block_q, 128]
+    l_scr,  # [heads, block_q, 128]
+    acc_scr,  # [heads, block_q, Dv]
+    *,
+    groups: int,
+    block_kv: int,
+    chunk_kv: int,
+    scale: float,
+):
+    """:func:`_flash_kernel` under an arbitrary mask: a tile of the mask is
+    loaded once and serves every head of the program; tiles at or past the
+    row's live keys are never visited.  A query may have no kept key in the
+    tiles seen so far (its selection lies further on), so the probabilities
+    are zeroed under the mask and not only the scores.  The keys come
+    transposed (``q . kT`` is a plain matmul): that is how a view built a page
+    at a time lies in memory (models/mla_moe.py), and no copy re-lays it."""
+    heads, dv = q_ref.shape[0], v_ref.shape[-1]
+    b = pl.program_id(0) // groups
+    ci = pl.program_id(2)
+    c0 = ci * chunk_kv
+    n_tiles = jnp.clip((live_ref[b] - c0 + block_kv - 1) // block_kv, 0, chunk_kv // block_kv)
+
+    @pl.when(ci == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    def tile(t, carry):
+        off = pl.multiple_of(t * block_kv, block_kv)
+        keep = mask_ref[:, pl.ds(off, block_kv)].astype(jnp.int32) != 0
+        for h in range(heads):
+            v = v_ref[h, pl.ds(off, block_kv), :]
+            s = jnp.dot(q_ref[h], kt_ref[h, :, pl.ds(off, block_kv)], preferred_element_type=jnp.float32)
+            s = jnp.where(keep, s * scale, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(keep, jnp.exp(s - _lanes(m_new, block_kv)), 0.0)
+            m_scr[h] = m_new
+            l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = _lanes(alpha, dv) * acc_scr[h] + jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, tile, 0)
+
+    @pl.when(ci == pl.num_programs(2) - 1)
+    def _finalize():
+        for h in range(heads):
+            o_ref[h] = (acc_scr[h] / _lanes(jnp.maximum(l_scr[h], 1e-30), dv)).astype(o_ref.dtype)
+
+
+def masked_flash_attention(
+    q: jnp.ndarray,  # [B, H, Sq, D]
+    kt: jnp.ndarray,  # [B, H, D, Sk]: the keys transposed
+    v: jnp.ndarray,  # [B, H, Sk, Dv]
+    mask: jnp.ndarray,  # [B, Sq, Sk] int8 (non-zero = keep), the same for every head
+    live: jnp.ndarray,  # [B] int32: no key at or past it is kept by any query of the row
+    *,
+    scale: float,
+    block_q: Optional[int] = None,
+    block_kv: Optional[int] = None,
+    chunk_kv: Optional[int] = None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Blocked online-softmax attention over the pairs ``mask`` keeps (causality
+    included: the kernel adds none): bf16 operands, f32 scores and sums, as
+    :func:`flash_attention`; the ``[Sq, Sk]`` mask is int8 in HBM and no value
+    of ``[heads, Sq, Sk]`` size exists.  A query that keeps nothing reads 0."""
+    B, H, Sq, D = q.shape
+    Sk, Dv = kt.shape[3], v.shape[3]
+    # the causal rule's key tile and heads a program; a whole chunk of queries (up to 1,024) as ONE query
+    # tile: nothing above a diagonal is skipped here, and every further query tile streams the keys again
+    _, tile_kv, heads = flash_tiles(Sq, Sk, D, Dv, H, itemsize=q.dtype.itemsize)
+    tile_q = next(t for t in (1024, 512, 256, Sq) if Sq % t == 0)
+    block_q, block_kv = min(block_q or tile_q, Sq), min(block_kv or tile_kv, Sk)
+    chunk_kv = chunk_kv or _flash_chunk(Sk)
+    if Sq % block_q or chunk_kv % block_kv or Sk % chunk_kv:
+        raise ValueError(f"masked_flash_attention needs whole tiles, got Sq={Sq}/{block_q}, Sk={Sk}/{chunk_kv}/{block_kv}")
+    groups = H // heads
+    qf, kf, vf = (x.reshape(B * groups, heads, *x.shape[2:]) for x in (q, kt, v))
+
+    def last_chunk(g, live_ref):  # a chunk past the live keys repeats the last live one: no copy
+        return jnp.maximum(live_ref[g // groups] - 1, 0) // chunk_kv
+
+    def k_index(g, qi, ci, live_ref):
+        return (g, 0, 0, jnp.minimum(ci, last_chunk(g, live_ref)))
+
+    def v_index(g, qi, ci, live_ref):
+        return (g, 0, jnp.minimum(ci, last_chunk(g, live_ref)), 0)
+
+    def mask_index(g, qi, ci, live_ref):
+        return (g // groups, qi, jnp.minimum(ci, last_chunk(g, live_ref)))
+
+    out = pl.pallas_call(
+        functools.partial(_masked_flash_kernel, groups=groups, block_kv=block_kv, chunk_kv=chunk_kv, scale=float(scale)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B * groups, Sq // block_q, Sk // chunk_kv),
+            in_specs=[
+                pl.BlockSpec((None, heads, block_q, D), lambda g, qi, ci, lv: (g, 0, qi, 0)),
+                pl.BlockSpec((None, heads, D, chunk_kv), k_index),
+                pl.BlockSpec((None, heads, chunk_kv, Dv), v_index),
+                pl.BlockSpec((None, block_q, chunk_kv), mask_index),
+            ],
+            out_specs=pl.BlockSpec((None, heads, block_q, Dv), lambda g, qi, ci, lv: (g, 0, qi, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((heads, block_q, _LANES), jnp.float32),
+                pltpu.VMEM((heads, block_q, _LANES), jnp.float32),
+                pltpu.VMEM((heads, block_q, Dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B * groups, heads, Sq, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_flash_vmem_bytes(block_q, block_kv, heads, chunk_kv, D, Dv, q.dtype.itemsize, 2)
+            + 2 * block_q * chunk_kv + (16 << 20),
+        ),
+        name="masked_flash_attention",
+        interpret=interpret,
+    )(live.astype(jnp.int32), qf, kf, vf, mask)
+    return out.reshape(B, H, Sq, Dv)
+
+
+@jax.named_scope("attn/sparse_core")
+def sparse_attention(q, kt, v, keep: jnp.ndarray, live: jnp.ndarray, *, scale: float) -> jnp.ndarray:
+    """Attention of ``q`` [B,H,Sq,D] over keys ``kt`` [B,H,D,Sk] (transposed)
+    and values ``v`` [B,H,Sk,Dv] at the pairs ``keep`` [B,Sq,Sk] marks (the
+    selection, causality included): the masked flash kernel where the call is
+    kernel-shaped on a TPU, the plain masked softmax elsewhere (rows that keep
+    nothing come back zero on both)."""
+    if sparse_kernel_shaped(q.shape[2], v.shape[2], q.shape[3], v.shape[3]):
+        return masked_flash_attention(q, kt, v, keep.astype(jnp.int8), live, scale=scale)
+    o = dot_product_attention(q, kt.swapaxes(2, 3), v, mask=keep[:, None], scale=scale)
+    return jnp.where(keep.any(-1)[:, None, :, None], o, 0.0).astype(o.dtype)
+
+
+def sparse_select(
+    q_idx: jnp.ndarray,  # [B, C, Hi, Di]
+    w_idx: jnp.ndarray,  # [B, C, Hi] f32
+    k_idx: jnp.ndarray,  # [B, S, Di]
+    qpos: jnp.ndarray,  # [B, C] int32 position of each query
+    ok: jnp.ndarray,  # [B, C, S] bool: the keys a query may attend at all (causal, written, real query)
+    topk: int,
+) -> jnp.ndarray:
+    """-> keep [B, C, S] bool: for each query the ``topk`` keys of largest index
+    score among those ``ok`` marks (all of them where there are fewer)."""
+    B, C, Hi, Di = q_idx.shape
+    S = k_idx.shape[1]
+    if S <= topk:
+        return ok
+    if sparse_kernel_shaped(C, S, Di, Di):
+        scores = index_scores_t(q_idx, w_idx, k_idx, qpos[:, 0])  # [B, S, C]: the selection reduces over the major axis
+        with jax.named_scope("attn/select"):
+            ok_t = ok.transpose(0, 2, 1)
+        keep_t = topk_mask(scores, topk, ok_t, axis=1)
+        with jax.named_scope("attn/select"):
+            return keep_t.transpose(0, 2, 1)
+    return topk_mask(index_scores(q_idx, w_idx, k_idx), topk, ok, axis=2)
+
+
+def sparse_decode_select(scores: jnp.ndarray, ok: jnp.ndarray, topk: int):
+    """A decode step's selection, as POSITIONS: ``scores``/``ok`` [B, S] ->
+    (idx [B, K] int32, picked [B, K] bool), ``K = min(topk, S)``; where fewer
+    than ``K`` keys are ``ok`` the rest of ``idx`` is marked not picked."""
+    with jax.named_scope("attn/select"):
+        vals, idx = jax.lax.top_k(jnp.where(ok, scores, -jnp.inf), min(topk, scores.shape[-1]))
+        return idx.astype(jnp.int32), vals > -jnp.inf
+
+
+@jax.named_scope("attn/sparse_core")
+def sparse_latent_decode_attention(
+    q: jnp.ndarray,  # [B, H, W] absorbed query | rotary query | zeros
+    pool: jnp.ndarray,  # [L, P, page, W] the whole latent pool, the step's rows written
+    layer: jnp.ndarray,
+    block_tables: jnp.ndarray,  # [B, NB]
+    idx: jnp.ndarray,  # [B, K] selected positions
+    picked: jnp.ndarray,  # [B, K] bool
+    *,
+    scale: float,
+    value_width: int,
+) -> jnp.ndarray:
+    """:func:`latent_decode_attention` over SELECTED rows: the ``K`` rows each
+    slot's indexer picked are gathered from the pool where they lie (by layer,
+    page and offset: no page is read whole, no layer of the pool copied) and
+    attended in the absorbed form -> ``[B, H, value_width]``."""
+    L, P, page, W = pool.shape
+    phys = jnp.take_along_axis(block_tables, idx // page, axis=1)
+    picked = picked & (phys >= 0) & (phys < P)
+    rows = pool[layer, jnp.clip(phys, 0, P - 1), idx % page].astype(q.dtype)  # [B, K, W]
+    sc = jnp.einsum("bhw,bkw->bhk", q, rows, preferred_element_type=jnp.float32) * scale
+    sc = jnp.where(picked[:, None, :], sc, NEG_INF)
+    probs = jnp.where(picked[:, None, :], jax.nn.softmax(sc, axis=-1), 0.0).astype(q.dtype)
+    return jnp.einsum("bhk,bkw->bhw", probs, rows[..., :value_width])

@@ -3063,6 +3063,9 @@ class GenerationEngine:
         moe = self.moe_stats()
         if moe is not None:
             out["moe"] = moe
+        dsa = self.dsa_stats()
+        if dsa is not None:
+            out["dsa"] = dsa
         out["reclaimed_slots"] = self.reclaimed_slots
         # device-slice identity + per-slice HBM ledger (docs/MULTICHIP.md)
         out["slice"] = self.slice_stats()
@@ -3145,9 +3148,28 @@ class GenerationEngine:
             out[kind] = {
                 "picks": int(tot[row, 0]), "picks_local": int(tot[row, 1]),
                 "layer_steps": int(tot[row, 2]), "experts_hit": int(tot[row, 3]),
-                "tokens_per_expert": [int(v) for v in tot[row, 4:]],
+                "tokens_per_expert": [int(v) for v in tot[row, 4:4 + lm.experts_held]],
                 "experts_skipped_share": round(1.0 - float(tot[row, 3]) / max(1, lm.experts_held * int(tot[row, 2])), 4),
             }
+        return out
+
+    def dsa_stats(self) -> Optional[dict]:
+        """Counters of the learned sparse attention (``tick_stats()["dsa"]``,
+        ``dabt_dsa_*``), or None for a block without an indexer: running totals,
+        for decode steps, chunk programs and every other prefill program apart,
+        of programs run, queries, the (query, key) pairs a dense causal
+        attention would attend and the pairs the selection kept, each of ONE
+        layer (every layer selects as many).  Summed on the device and handed
+        out with a tick's tokens, as the ``moe`` counters are."""
+        lm = getattr(self.cfg, "latent_moe", None)
+        if lm is None or not lm.index_topk:
+            return None
+        tot = self._moe_totals
+        tail = np.zeros((2, self._model.DSA_STAT), np.int64) if tot is None else tot[:, 4 + lm.experts_held:]
+        names = ("programs", "queries", "pairs_causal", "pairs_selected")
+        out: dict = {"index_topk": lm.index_topk}
+        for kind, vals in (("decode", tail[0, :4]), ("chunk", tail[1, :4]), ("prefill", tail[1, 4:8])):
+            out[kind] = {k: int(v) for k, v in zip(names, vals)}
         return out
 
     def slice_stats(self) -> dict:
@@ -3200,9 +3222,10 @@ class GenerationEngine:
         ``kv_shared_page_frac`` and the allocator's eviction/COW counters),
         the host tier's restore gauges, and the prefix hit/miss counters."""
         # what a cached token is ("kv": keys and values per KV head; "latent":
-        # one latent row read as both) and what it takes over all layers
+        # one latent row read as both; "latent+index": that and an indexer's
+        # key) and what it takes over all layers
         out: dict = {
-            "kv_cache_kind": self._model.KV_KIND,
+            "kv_cache_kind": getattr(self._model, "kv_kind", lambda cfg: self._model.KV_KIND)(self.cfg),
             "kv_bytes_per_token": int(
                 self._model.kv_bytes_per_token(self.cfg, self.kv_cache_dtype)
             ),

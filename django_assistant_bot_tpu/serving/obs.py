@@ -939,6 +939,18 @@ def render_prometheus(registry: Any) -> str:
                 x.add("dabt_moe_experts_skipped_share", "gauge", "held experts no token landed on, of held x layer-steps (the kernel path does not read them)", moe[kind]["experts_skipped_share"], klab)
                 for e, n in enumerate(moe[kind]["tokens_per_expert"]):
                     x.add("dabt_moe_expert_tokens_total", "counter", "tokens routed to a held expert", n, {**klab, "expert": str(moe["first_expert"] + e)})
+        dsa_fn = getattr(eng, "dsa_stats", None)
+        dsa = dsa_fn() if callable(dsa_fn) else None
+        if dsa:
+            # learned sparse attention (an indexer's top-k): what a dense causal attention
+            # would have attended against what the selection kept, one layer's worth
+            x.add("dabt_dsa_index_topk", "gauge", "keys a query attends at most (the indexer's top-k)", dsa["index_topk"], lab)
+            for kind in ("decode", "chunk", "prefill"):
+                klab = {**lab, "kind": kind}
+                x.add("dabt_dsa_programs_total", "counter", "decode steps / chunk programs / other prefill programs that ran a query", dsa[kind]["programs"], klab)
+                x.add("dabt_dsa_queries_total", "counter", "queries scored by the indexer", dsa[kind]["queries"], klab)
+                x.add("dabt_dsa_pairs_causal_total", "counter", "(query, key) pairs a dense causal attention attends, a layer", dsa[kind]["pairs_causal"], klab)
+                x.add("dabt_dsa_pairs_selected_total", "counter", "(query, key) pairs the selection kept, a layer", dsa[kind]["pairs_selected"], klab)
         dec_fn = getattr(eng, "decode_path_stats", None)
         if callable(dec_fn):
             # decode fast-path gauges (docs/QUANT.md): configured vs

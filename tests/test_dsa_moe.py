@@ -1,0 +1,410 @@
+"""The latent-attention MoE block with a lightning indexer, top-k sparse
+attention and a score-correction bias (``models/mla_moe.py`` under a
+``deepseek_v32`` config) against its plain reference
+(``benchmarks/reference/dsa_moe.py``, which imports nothing of the program), at
+a tiny preset on the CPU in float32: ``tests/data/dsa_moe_tiny.json`` is
+``mla_moe_tiny.json`` with 4 index heads of 16, ``index_topk`` 8 and
+``noaux_tc``, so every context here is several times the selection.
+
+Tolerances as ``tests/test_mla_moe.py`` has them (``ATOL = 2e-4`` on O(1)
+logits, float32 on both sides).  A selection is a discrete choice: where the
+program and the reference kept different keys the logits would differ by far
+more than the tolerance (the ``dense`` control moves them by ~3), so equal
+logits at every position also say the selected sets were the same; the sets are
+compared directly too.
+"""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import families
+from benchmarks.reference import dsa_moe as ref
+from django_assistant_bot_tpu.models import DecoderConfig, mixtral, mla_moe, module_for
+from django_assistant_bot_tpu.ops import attention as A
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+DATA = os.path.join(ROOT, "benchmarks")
+ATOL = 2e-4
+SEED = 2**31 + 77
+
+
+def _conf(**hf):
+    with open(os.path.join(HERE, "data", "dsa_moe_tiny.json")) as f:
+        conf = json.load(f)
+    conf["hf"].update(hf)
+    return conf
+
+
+@pytest.fixture(scope="module")
+def family():
+    return families.load(_conf(), DATA)
+
+
+def _program(family, conf):
+    cfg = dataclasses.replace(DecoderConfig.from_hf(conf["hf"], dtype=jnp.float32), max_seq_len=256)
+    params = jax.tree.map(lambda x: x.astype(jnp.float32), family.served_params(conf, SEED))
+    return cfg, params
+
+
+def _reference(family, conf, seqs, firsts=None, control=None):
+    cols = list(range(conf["hf"]["vocab_size"]))
+    return family.reference_logits(conf, SEED, seqs, firsts or [0] * len(seqs), cols, control=control)
+
+
+def _ids(n, seed=0, vocab=512):
+    return [int(t) for t in np.random.default_rng(seed).integers(0, vocab, n)]
+
+
+def test_from_hf_reads_deepseek_v32_the_indexer_the_bias_and_the_draft_head():
+    hf = _conf()["hf"]
+    cfg = DecoderConfig.from_hf(hf)
+    lm = cfg.latent_moe
+    assert cfg.arch == "mla_moe" and module_for(cfg) is mla_moe
+    assert (lm.index_n_heads, lm.index_head_dim, lm.index_topk, lm.router_bias) == (4, 16, 8, True)
+    assert hf["num_nextn_predict_layers"] == 1 and cfg.num_layers == 3  # accepted, and no such layer is built
+    assert mla_moe.kv_kind(cfg) == "latent+index"
+    # one cached token: the padded latent row and the index key, in every layer
+    assert mla_moe.kv_bytes_per_token(cfg) == 3 * (128 + 16) * 2
+    plain = DecoderConfig.from_hf({k: v for k, v in hf.items() if not k.startswith("index_")} | {"model_type": "deepseek_v3"})
+    assert (plain.latent_moe.index_topk, plain.latent_moe.router_bias) == (0, True)  # noaux_tc alone is read too
+    assert mla_moe.kv_kind(plain) == "latent" and mla_moe.kv_bytes_per_token(plain) == 3 * 128 * 2
+    with pytest.raises(ValueError, match="index_topk"):
+        DecoderConfig.from_hf({**hf, "index_topk": None})
+    with pytest.raises(ValueError, match="speculative"):
+        mla_moe.check_serving(speculative=2)
+    with pytest.raises(ValueError, match="second kind of row"):
+        mla_moe.check_serving(prefix_cache=4)
+
+
+@pytest.mark.parametrize("first_dense", [1, 2])
+def test_any_number_of_leading_dense_layers_is_read(first_dense):
+    cfg = DecoderConfig.from_hf(_conf(first_k_dense_replace=first_dense)["hf"])
+    assert cfg.latent_moe.first_dense_layers == first_dense
+    p = jax.eval_shape(lambda: mla_moe.init(dataclasses.replace(cfg, dtype=jnp.float32), jax.random.key(0)))
+    assert p["dense_layers"]["w_iq"].shape == (first_dense, 32, 64) and p["moe_layers"]["router_bias"].shape == (3 - first_dense, 16)
+    assert jax.tree.structure(mla_moe.logical_axes(cfg), is_leaf=lambda x: isinstance(x, tuple)) == jax.tree.structure(p)
+
+
+@pytest.mark.parametrize("lengths", [[18, 7], [40, 29], [48, 33]])
+def test_prefill_logits_equal_the_plain_reference_at_contexts_several_times_the_selection(family, lengths):
+    conf = _conf()
+    cfg, params = _program(family, conf)
+    seqs = [_ids(48, 1), _ids(48, 2)]
+    logits, (rows, keys), stats = mla_moe.prefill(params, cfg, jnp.asarray(seqs), jnp.asarray(lengths))
+    want = _reference(family, conf, [s[:n] + [0] for s, n in zip(seqs, lengths)])
+    for i in range(2):
+        np.testing.assert_allclose(np.asarray(logits[i]), want[i][-1], atol=ATOL)
+    assert rows.shape == (3, 2, 48, 128) and keys.shape == (3, 2, 48, 16) and stats.shape == (4 + 16 + 8,)
+    # the sparse attention's counters, a layer's worth, in the columns of "every other prefill program"
+    n = np.asarray(lengths)
+    causal = int((n * (n + 1) // 2).sum())
+    kept = int(sum(sum(min(8, t + 1) for t in range(m)) for m in lengths))
+    assert [int(x) for x in stats[-8:]] == [0, 0, 0, 0, 1, int(n.sum()), causal, kept]
+    assert float(np.abs(want[0][-1]).max()) > 1.0  # logits are O(1): the tolerance means something
+
+
+def test_the_dense_control_is_another_function(family):
+    """Attending every s <= t (the block without its indexer) moves the logits by far more than any tolerance."""
+    conf = _conf()
+    seqs = [_ids(48, 1)]
+    sparse, dense = _reference(family, conf, seqs), _reference(family, conf, seqs, control="dense")
+    assert np.abs(sparse[0][:8] - dense[0][:8]).max() < 1e-5  # the first index_topk positions select everything
+    assert np.abs(sparse[0][16:] - dense[0][16:]).max() > 0.5
+
+
+def _paged(cfg, page=8, NB=8, P=32, slots=4):
+    cache = mla_moe.init_paged_cache(cfg, slots, P, page)
+    bt = np.full((slots, NB), P, np.int32)
+    bt[0, :7], bt[2, :7] = [3, 5, 7, 9, 11, 13, 15], [2, 4, 6, 8, 10, 12, 14]
+    return cache, jnp.asarray(bt)
+
+
+def test_prefill_then_paged_decode_equals_the_reference_at_every_position(family):
+    """Both caches: a step writes its latent row and its index key, scores the slot's index keys, and
+    gathers only the selected latent rows (absorbed form) where the reference expands every position."""
+    conf = _conf()
+    cfg, params = _program(family, conf)
+    seqs = [_ids(52, 3), _ids(45, 4)]
+    want = _reference(family, conf, seqs)
+    cache, bt = _paged(cfg)
+    n0 = [20, 12]
+    ids = np.zeros((2, 24), np.int32)
+    for i, s in enumerate(seqs):
+        ids[i, : n0[i]] = s[: n0[i]]
+    logits, rows, stats = mla_moe.prefill(params, cfg, jnp.asarray(ids), jnp.asarray(n0))
+    cache = mla_moe.insert_sequences_paged(cache, rows, stats, jnp.asarray(n0), jnp.asarray([0, 2]), bt[jnp.asarray([0, 2])])
+    step = jax.jit(lambda t, c, a: mla_moe.decode_step_paged(params, cfg, t, c, bt, active=a))
+    active = jnp.asarray([True, False, True, False])
+    for k in range(25):
+        toks = jnp.asarray([seqs[0][n0[0] + k], 0, seqs[1][n0[1] + k], 0], jnp.int32)
+        logits, cache = step(toks, cache, active)
+        np.testing.assert_allclose(np.asarray(logits[0]), want[0][n0[0] + k], atol=ATOL)
+        np.testing.assert_allclose(np.asarray(logits[2]), want[1][n0[1] + k], atol=ATOL)
+    assert [int(x) for x in cache.lengths] == [45, 0, 37, 0]  # frozen slots wrote nothing
+    # decode's counters: 25 steps, 2 rows each, every row past the selection keeps exactly index_topk
+    steps, queries, causal, kept = (int(x) for x in cache.stats[0, -8:-4])
+    assert (steps, queries, kept) == (25, 50, 50 * 8)
+    assert causal == sum(n + k + 1 for n in n0 for k in range(25))
+    assert int(cache.stats[0, 2]) == 25 * 2  # the routed layers' counters stay where they were
+
+
+@pytest.mark.parametrize("chunk", [24, 16])
+def test_chunked_prefill_against_the_cache_equals_the_reference(family, chunk):
+    conf = _conf()
+    cfg, params = _program(family, conf)
+    s = _ids(53, 5)
+    want = _reference(family, conf, [s + [0]])[0][-1]
+    page, NB, P = 8, 8, 16
+    bt_row = jnp.asarray([9, 1, 4, 2, 7, 11, 3, 0], jnp.int32)
+    cache = mla_moe.init_paged_cache(cfg, 2, P, page)
+    for start in range(0, 53, chunk):
+        valid = min(chunk, 53 - start)
+        ids = (s[start:start + valid] + [0] * chunk)[:chunk]
+        logits, cache = mla_moe.prefill_chunk_paged(
+            params, cfg, jnp.asarray([ids]), cache, bt_row, jnp.int32(1), jnp.int32(start), jnp.int32(valid))
+    np.testing.assert_allclose(np.asarray(logits[0]), want, atol=ATOL)
+    assert int(cache.lengths[1]) == 53
+    programs, queries, causal, kept = (int(x) for x in cache.stats[1, -8:-4])  # the chunk programs' columns
+    assert (programs, queries, causal) == (-(-53 // chunk), 53, 53 * 54 // 2)
+    assert kept == sum(min(8, t + 1) for t in range(53))
+    assert not np.asarray(cache.stats[1, -4:]).any()
+    # the sliding last chunk of the engine re-feeds positions already written: the same rows, the same answer
+    again, cache = mla_moe.prefill_chunk_paged(
+        params, cfg, jnp.asarray([s[53 - chunk:]]), cache, bt_row, jnp.int32(1), jnp.int32(53 - chunk), jnp.int32(chunk))
+    np.testing.assert_allclose(np.asarray(again[0]), want, atol=ATOL)
+
+
+def test_suffix_prefill_and_copy_pages_carry_the_index_keys(family):
+    conf = _conf()
+    cfg, params = _program(family, conf)
+    s = _ids(53, 5)
+    want = _reference(family, conf, [s + [0]])[0][-1]
+    page, NB, P = 8, 8, 16
+    bt_row = jnp.asarray([9, 1, 4, 2, 7, 11, 3, 0], jnp.int32)
+    cache = mla_moe.init_paged_cache(cfg, 2, P, page)
+    _, cache = mla_moe.prefill_chunk_paged(
+        params, cfg, jnp.asarray([s[:24]]), cache, bt_row, jnp.int32(0), jnp.int32(0), jnp.int32(24))
+    # clone the three prefix pages elsewhere and continue from the clones: the index keys came along
+    cache = mla_moe.copy_pages(cache, jnp.asarray([9, 1, 4], jnp.int32), jnp.asarray([13, 14, 15], jnp.int32))
+    np.testing.assert_array_equal(np.asarray(cache.idx[:, [13, 14, 15]]), np.asarray(cache.idx[:, [9, 1, 4]]))
+    np.testing.assert_array_equal(np.asarray(cache.kv[:, [13, 14, 15]]), np.asarray(cache.kv[:, [9, 1, 4]]))
+    assert float(np.abs(np.asarray(cache.idx[:, 13])).max()) > 0.1
+    bts = jnp.stack([bt_row.at[:3].set(jnp.asarray([13, 14, 15])), jnp.full((NB,), P, jnp.int32)])
+    suffix = jnp.asarray([(s[24:] + [0] * 3), [0] * 32])
+    logits, cache = mla_moe.prefill_suffix_paged(
+        params, cfg, suffix, cache, bts, jnp.asarray([0, 2]), jnp.asarray([24, 0]), jnp.asarray([29, 0]))
+    np.testing.assert_allclose(np.asarray(logits[0]), want, atol=ATOL)
+    assert [int(x) for x in cache.stats[1, -4:]] == [1, 29, sum(range(25, 54)), 29 * 8]
+
+
+def test_a_reused_pages_stale_rows_are_never_selected(family):
+    """A freed page keeps its rows.  Handed to another slot it holds index keys that would score high:
+    positions at or past the slot's length, and blocks the table does not name, are never selected."""
+    conf = _conf()
+    cfg, params = _program(family, conf)
+    s = _ids(40, 8)
+    want = _reference(family, conf, [s])[0]
+    page, NB, P = 8, 8, 16
+    bt_row = jnp.asarray([5, 6, 7, 8, 9, 10, P, P], jnp.int32)
+    cache = mla_moe.init_paged_cache(cfg, 1, P, page)
+    # every page starts full of "stale" rows: large index keys aligned with everything, and latent rows of ones
+    cache = cache._replace(idx=jnp.full(cache.idx.shape, 50.0, cache.idx.dtype), kv=jnp.ones(cache.kv.shape, cache.kv.dtype))
+    logits, cache = mla_moe.prefill_chunk_paged(
+        params, cfg, jnp.asarray([s[:32]]), cache, bt_row, jnp.int32(0), jnp.int32(0), jnp.int32(32))
+    np.testing.assert_allclose(np.asarray(logits[0]), want[31], atol=ATOL)
+    step = jax.jit(lambda t, c: mla_moe.decode_step_paged(params, cfg, t, c, bt_row[None]))
+    for k in range(32, 39):
+        logits, cache = step(jnp.asarray([s[k]], jnp.int32), cache)
+        np.testing.assert_allclose(np.asarray(logits[0]), want[k], atol=ATOL)
+
+
+def test_with_index_topk_at_least_the_context_the_block_is_the_dense_one(family):
+    """The same weights under a selection that keeps everything equal the ``mla_moe`` path without an
+    indexer (its programs: the causal flash attention, the whole-page decode), logit for logit."""
+    conf = _conf(index_topk=4096)
+    cfg, params = _program(family, conf)
+    hf = {k: v for k, v in conf["hf"].items() if not k.startswith("index_")} | {"model_type": "deepseek_v3"}
+    plain_cfg = dataclasses.replace(DecoderConfig.from_hf(hf, dtype=jnp.float32), max_seq_len=256)
+    drop = ("w_iq", "w_ik", "w_iw", "ik_norm", "ik_bias")
+    plain = {k: ({n: w for n, w in v.items() if n not in drop} if isinstance(v, dict) else v) for k, v in params.items()}
+    seqs = [_ids(40, 11), _ids(40, 12)]
+    lengths = jnp.asarray([40, 23])
+    a, (rows, keys), _ = mla_moe.prefill(params, cfg, jnp.asarray(seqs), lengths)
+    b, rows_b, _ = mla_moe.prefill(plain, plain_cfg, jnp.asarray(seqs), lengths)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(rows_b))
+    want = _reference(family, conf, [seqs[0] + [0]], control="dense")[0][-1]
+    np.testing.assert_allclose(np.asarray(a[0]), want, atol=ATOL)
+    # and a paged decode step on both
+    ca, bt = _paged(cfg)
+    cb, _ = _paged(plain_cfg)
+    st = jnp.zeros((4 + 16,), jnp.int32)
+    ca = mla_moe.insert_sequences_paged(ca, (rows, keys), jnp.zeros((28,), jnp.int32), lengths, jnp.asarray([0, 2]), bt[jnp.asarray([0, 2])])
+    cb = mla_moe.insert_sequences_paged(cb, rows_b, st, lengths, jnp.asarray([0, 2]), bt[jnp.asarray([0, 2])])
+    toks, active = jnp.asarray([7, 0, 9, 0], jnp.int32), jnp.asarray([True, False, True, False])
+    la, _ = mla_moe.decode_step_paged(params, cfg, toks, ca, bt, active=active)
+    lb, _ = mla_moe.decode_step_paged(plain, plain_cfg, toks, cb, bt, active=active)
+    np.testing.assert_allclose(np.asarray(la)[[0, 2]], np.asarray(lb)[[0, 2]], atol=1e-5)
+
+
+def test_the_bias_changes_picks_and_never_weights(family):
+    conf = _conf()
+    cfg, params = _program(family, conf)
+    lm = cfg.latent_moe
+    h = jnp.asarray(np.random.default_rng(6).standard_normal((400, 64)), jnp.float32)
+    router, bias = params["moe_layers"]["router"][0], params["moe_layers"]["router_bias"][0]
+    assert 0.005 < float(jnp.std(bias)) < 0.05
+    idx, w = mixtral.route_sigmoid_groups(lm, 4, h, router, bias)
+    idx0, w0 = mixtral.route_sigmoid_groups(lm, 4, h, router)
+    changed = np.mean([set(a) != set(b) for a, b in zip(np.asarray(idx).tolist(), np.asarray(idx0).tolist())])
+    assert 0.02 < changed < 0.5  # a small bias: some tokens pick another set of experts, most do not
+    # the weights are the UNBIASED sigmoid scores of the picks, normalised, times 2.5
+    sigma = jax.nn.sigmoid(jnp.einsum("te,ex->tx", h, router, precision=jax.lax.Precision.HIGHEST))
+    picked = jnp.take_along_axis(sigma, idx, axis=-1)
+    np.testing.assert_allclose(np.asarray(w), np.asarray(2.5 * picked / picked.sum(-1, keepdims=True)), atol=1e-6)
+    # a large bias on one expert puts it in every token's picks (its group wins too) at its own small weight
+    big = jnp.zeros((16,)).at[9].set(10.0)
+    idx1, w1 = mixtral.route_sigmoid_groups(lm, 4, h, router, big)
+    assert bool((idx1 == 9).any(-1).all())
+    w9 = jnp.where(idx1 == 9, w1, 0.0).sum(-1)
+    assert float(w9.max()) < 2.5 * 0.9 and float(np.asarray(w1).sum(-1).max()) == pytest.approx(2.5, abs=1e-5)
+    # and the reference routes the same way
+    with jax.default_matmul_precision("highest"):
+        ridx, rw, _ = ref.route(conf["hf"], h, router, bias)
+    assert np.array_equal(np.sort(np.asarray(idx), -1), np.sort(np.asarray(ridx), -1))
+    order, rorder = np.argsort(np.asarray(idx), -1), np.argsort(np.asarray(ridx), -1)
+    np.testing.assert_allclose(np.take_along_axis(np.asarray(w), order, -1), np.take_along_axis(np.asarray(rw), rorder, -1), atol=1e-6)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(family):
+    """Over ep_rank 0..3 the routed parts under the router's bias, with the shared expert counted once,
+    equal what the uncut reference gives for the whole layer (model-configs guide, section 4)."""
+    hf = _conf()["hf"]
+    layer = family.float32_layer(hf, SEED, 2)
+    h = jnp.asarray(np.random.default_rng(9).standard_normal((1, 256, 64)), jnp.float32)
+    held_keys = ("w_gate", "w_up", "w_down")
+    with jax.default_matmul_precision("highest"):
+        uncut, _ = ref.moe_ffn(hf, layer, h)
+        shared, _ = ref.moe_ffn(hf, {k: (v[:0] if k in held_keys else v) for k, v in layer.items()}, h)
+    program, reference = np.asarray(shared), np.asarray(shared)
+    for rank in range(4):
+        share = _conf(n_routed_experts=4, ep_size=4, ep_rank=rank)["hf"]
+        held = {k: (v[4 * rank: 4 * rank + 4] if k in held_keys else v) for k, v in layer.items()}
+        cfg = DecoderConfig.from_hf(share, dtype=jnp.float32)
+        y, stats = mixtral.held_experts_mlp(cfg, held, h, jnp.ones((1, 256), bool))
+        program = program + np.asarray(y)
+        with jax.default_matmul_precision("highest"):
+            reference = reference + np.asarray(ref.moe_ffn(share, held, h, first_expert=4 * rank)[0]) - np.asarray(shared)
+        assert int(stats[0]) == 256 * 4 and 0 < int(stats[1]) < 256 * 4
+    np.testing.assert_allclose(program, np.asarray(uncut), atol=ATOL)
+    np.testing.assert_allclose(reference, np.asarray(uncut), atol=ATOL)
+    assert float(np.abs(np.asarray(uncut) - np.asarray(shared)).max()) > 0.1
+
+
+# ---------------------------------------------------------------------------
+# the selection, as sets
+# ---------------------------------------------------------------------------
+
+
+def _index_inputs(B=2, C=16, S=64, Hi=4, Di=16, seed=0):
+    r = np.random.default_rng(seed)
+    q = jnp.asarray(r.standard_normal((B, C, Hi, Di)), jnp.float32)
+    w = jnp.asarray(r.standard_normal((B, C, Hi)), jnp.float32)
+    k = jnp.asarray(r.standard_normal((B, S, Di)), jnp.float32)
+    return q, w, k
+
+
+@pytest.mark.parametrize("topk", [8, 24, 63])
+def test_the_programs_selected_sets_equal_the_references(topk):
+    """``sparse_select`` (scores, then the threshold found by counting) against the reference's sort,
+    in float32, on the same scores: the same set for every query, queries with fewer candidates than
+    ``topk`` included."""
+    q, w, k = _index_inputs()
+    starts = np.asarray([40, 3])
+    qpos = jnp.asarray(starts[:, None] + np.arange(16)[None, :])
+    ok = jnp.arange(64)[None, None, :] <= qpos[:, :, None]
+    keep = A.sparse_select(q, w, k, qpos, ok, topk)
+    with jax.default_matmul_precision("highest"):
+        si = jnp.einsum("bqhk,bqh->bqk", jnp.maximum(jnp.einsum("bqhd,bkd->bqhk", q, k), 0.0), w)
+    want, _ = ref.select_block(si, ok, topk)
+    np.testing.assert_array_equal(np.asarray(keep), np.asarray(want))
+    n = np.asarray(keep).sum(-1)
+    np.testing.assert_array_equal(n, np.minimum(topk, np.asarray(qpos) + 1))
+
+
+@pytest.mark.parametrize("axis", [1, 2])
+def test_topk_mask_is_exact_under_ties_negative_scores_and_too_few_candidates(axis):
+    r = np.random.default_rng(3)
+    scores = r.standard_normal((3, 40, 40)).astype(np.float32)
+    scores[0] = np.round(scores[0] * 2) / 2  # many exact ties, of both signs, and zeros of both signs
+    scores[0, :4, :4] = -0.0
+    ok = r.random((3, 40, 40)) < 0.8
+    ok[1, 5] = False  # nothing to select
+    ok[1, 6] = np.arange(40) < 3  # fewer than k
+    if axis == 1:
+        ok[1, :, 5], ok[1, :, 6] = False, (np.arange(40) < 3)
+    k = 7
+    got = np.asarray(A.topk_mask(jnp.asarray(scores), k, jnp.asarray(ok), axis=axis))
+    s, o = (scores, ok) if axis == 2 else (scores.transpose(0, 2, 1), ok.transpose(0, 2, 1))
+    g = got if axis == 2 else got.transpose(0, 2, 1)
+    for b in range(3):
+        for row in range(40):
+            cand = np.flatnonzero(o[b, row])
+            order = cand[np.lexsort((cand, -s[b, row, cand]))]  # by score descending, lowest position first
+            want = np.zeros(40, bool)
+            want[order[:k]] = True
+            assert np.array_equal(g[b, row], want), (b, row)
+    assert got[1].sum() > 0 and not (got & ~ok).any()
+
+
+def test_the_decode_selection_picks_what_the_mask_picks():
+    r = np.random.default_rng(4)
+    scores = jnp.asarray(r.standard_normal((3, 64)), jnp.float32)
+    ok = jnp.asarray(np.arange(64)[None, :] <= np.asarray([50, 5, 63])[:, None])
+    idx, picked = A.sparse_decode_select(scores, ok, 8)
+    mask = np.asarray(A.topk_mask(scores, 8, ok, axis=1))
+    for b in range(3):
+        assert set(np.asarray(idx[b])[np.asarray(picked[b])].tolist()) == set(np.flatnonzero(mask[b]).tolist())
+    assert [int(x) for x in picked.sum(-1)] == [8, 6, 8]
+
+
+# ---------------------------------------------------------------------------
+# the two Pallas kernels, interpreted on the CPU, against the plain forms
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("starts", [[0, 0], [96, 32]])
+def test_the_index_score_kernel_equals_the_plain_scores_below_the_diagonal(starts):
+    q, w, k = _index_inputs(B=2, C=32, S=128, Hi=4, Di=128, seed=5)
+    got = A.index_scores_t(q, w, k, jnp.asarray(starts, jnp.int32), block_q=16, block_k=32, interpret=True)
+    want = A.index_scores(q, w, k).transpose(0, 2, 1)  # [B, S, C]
+    qpos = np.asarray(starts)[:, None] + np.arange(32)[None, :]
+    causal = np.arange(128)[None, :, None] <= qpos[:, None, :]
+    np.testing.assert_allclose(np.asarray(got)[causal], np.asarray(want)[causal], atol=1e-4)
+    assert got.shape == (2, 128, 32)
+    dead = np.arange(128)[None, :, None] >= (np.asarray(starts)[:, None, None] + 32 + 32)  # whole tiles past every query
+    assert not np.asarray(got)[np.broadcast_to(dead, got.shape)].any()
+
+
+@pytest.mark.parametrize("live", [[128, 128], [70, 17]])
+def test_the_masked_flash_kernel_equals_the_masked_softmax(live):
+    r = np.random.default_rng(6)
+    B, H, Sq, Sk, D, Dv = 2, 4, 32, 128, 128, 128
+    q, k, v = (jnp.asarray(r.standard_normal(s), jnp.float32) for s in ((B, H, Sq, D), (B, H, Sk, D), (B, H, Sk, Dv)))
+    keep = r.random((B, Sq, Sk)) < 0.3
+    keep &= np.arange(Sk)[None, None, :] < np.asarray(live)[:, None, None]
+    keep[0, 3] = False  # a query that keeps nothing reads zero
+    keep[1, 4] = np.arange(Sk) == 16  # a query whose only key lies past the first tile
+    kt = k.swapaxes(2, 3)  # the kernel takes the keys transposed
+    got = A.masked_flash_attention(q, kt, v, jnp.asarray(keep, jnp.int8), jnp.asarray(live, jnp.int32), scale=0.1,
+                                   block_q=16, block_kv=32, chunk_kv=64, interpret=True)
+    want = A.sparse_attention(q, kt, v, jnp.asarray(keep), jnp.asarray(live, jnp.int32), scale=0.1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert not np.asarray(got[0, :, 3]).any() and float(np.abs(np.asarray(got[1, :, 4])).max()) > 0

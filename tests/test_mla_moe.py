@@ -124,7 +124,7 @@ def test_from_hf_refuses_an_unknown_model_type_whose_keys_change_the_mathematics
 
 
 @pytest.mark.parametrize("change,why", [
-    ({"scoring_func": "softmax"}, "scoring_func"), ({"topk_method": "noaux_tc"}, "score-correction bias"),
+    ({"scoring_func": "softmax"}, "scoring_func"), ({"topk_method": "greedy_v9"}, "topk_method"),
     ({"moe_layer_freq": 2}, "moe_layer_freq"), ({"ep_size": 4}, "ep_size 4 without ep_rank"),
     ({"q_lora_rank": None}, "q_lora_rank"), ({"attention_bias": True}, "attention_bias"),
 ])

@@ -580,3 +580,100 @@ def test_latent_moe_chunk_prefill_reads_the_held_experts_in_place(topo, monkeypa
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * cfg.num_layers * LM_PAGES * PAGE * cfg.latent_moe.latent_width
     assert mem.temp_size_in_bytes < 1.0e9
+
+
+# ---------------------------------------------------------------------------
+# the same block with a lightning indexer and top-k sparse attention, at the
+# benchmark configuration's widths and serving geometry and a depth of two
+# (benchmarks/configs/deepseek-v3.2-ep16.json: 128 heads, 8 slots x 16,384,
+# pages of 512; one dense + one expert layer: the two scan bodies)
+# ---------------------------------------------------------------------------
+
+DSA_SLOTS, DSA_SEQ, DSA_PAGES = 8, 16_384, 264  # 264: a layer of a pool is no other value's shape
+
+
+def _dsa_args(one):
+    import json
+
+    from django_assistant_bot_tpu.models import mla_moe
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "deepseek-v3.2-ep16.json")
+    with open(path) as f:
+        hf = dict(json.load(f)["hf"], num_hidden_layers=2)
+    cfg = DecoderConfig.from_hf(hf, dtype=jnp.bfloat16)
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one),
+                          jax.eval_shape(lambda: mla_moe.init(cfg, jax.random.key(0))))
+    cache = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one),
+                         jax.eval_shape(lambda: mla_moe.init_paged_cache(cfg, DSA_SLOTS, DSA_PAGES, PAGE)))
+    return cfg, params, cache
+
+
+def _dsa_pool_shapes(cfg):
+    """One layer of either pool.  (A whole pool is the result of the in-place
+    scatters that write a step's rows; that it is not copied shows in the
+    aliased bytes and in temporaries smaller than it.)"""
+    lm = cfg.latent_moe
+    layers = [f"{DSA_PAGES},{PAGE},{w}" for w in (lm.latent_width, lm.index_head_dim)]
+    return layers + [f"1,{s}" for s in layers]
+
+
+def test_sparse_chunk_prefill_makes_nothing_of_heads_x_chunk_x_context_size(topo, monkeypatch):
+    """A 1,024-token chunk against 16,384 positions of pages: both Pallas
+    kernels lower for the v5e (index scores reduced over the 64 index heads in
+    VMEM; the flash kernel under the selection as an int8 mask), the donated
+    pools are aliased through, no layer of either pool is copied, and the
+    temporaries hold no [heads, chunk, context] scores (8.6 GB in float32 at
+    128 heads; 2.1 GB in bfloat16 at the indexer's 64)."""
+    from django_assistant_bot_tpu.models import mla_moe
+
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg, params, cache = _dsa_args(one)
+    scalar = _sds((), jnp.int32, one)
+    compiled = (
+        jax.jit(lambda p, i, c, bt, s, st, v: mla_moe.prefill_chunk_paged(p, cfg, i, c, bt, s, st, v),
+                donate_argnums=(2,))
+        .lower(params, _sds((1, 1024), jnp.int32, one), cache, _sds((DSA_SEQ // PAGE,), jnp.int32, one),
+               scalar, scalar, scalar)
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "index_scores" in text and "masked_flash_attention" in text and "held_experts" in text
+    lm = cfg.latent_moe
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cfg.num_layers * DSA_PAGES * PAGE * (lm.latent_width + lm.index_head_dim)
+    # the expanded keys and values of the view (1.6 GB), the [context, chunk] index scores and
+    # the selection (64 + 16 MB) and the experts' tiles: under 3 GB, where the scores alone were 8.6
+    assert mem.temp_size_in_bytes < 3.0e9
+    for heads in (cfg.num_heads, lm.index_n_heads):
+        for dims in (f"{heads},1024,{DSA_SEQ}", f"1024,{heads},{DSA_SEQ}", f"{DSA_SEQ},{heads},1024", f"{DSA_SEQ},1024,{heads}"):
+            assert not re.search(rf"= \(?(bf16|f32)\[(1,)?{dims}\]", text), dims
+    assert _pool_sized_values_made_in_loops(text, _dsa_pool_shapes(cfg)) == []
+
+
+def test_sparse_decode_tick_reads_index_keys_and_gathers_only_selected_rows(topo, monkeypatch):
+    """The decode step with an indexer: row and index key scattered into the
+    donated pools, the slots' index keys gathered by page, the top-k, and the
+    selected latent rows gathered where they lie: no copy of a layer of either
+    pool, no second pool among the temporaries."""
+    from django_assistant_bot_tpu.models import mla_moe
+
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    cfg, params, cache = _dsa_args(one)
+    compiled = (
+        jax.jit(lambda p, t, c, bt, a: mla_moe.decode_step_paged(p, cfg, t, c, bt, active=a), donate_argnums=(2,))
+        .lower(params, _sds((DSA_SLOTS,), jnp.int32, one), cache, _sds((DSA_SLOTS, DSA_SEQ // PAGE), jnp.int32, one),
+               _sds((DSA_SLOTS,), jnp.bool_, one))
+        .compile()
+    )
+    text = compiled.as_text()
+    lm = cfg.latent_moe
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * cfg.num_layers * DSA_PAGES * PAGE * (lm.latent_width + lm.index_head_dim)
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert _pool_sized_values_made_in_loops(text, _dsa_pool_shapes(cfg)) == []
+    # the selected rows: 8 slots x 2,048 x 640, gathered; never the 16,384 of a slot's whole view
+    assert f"bf16[{DSA_SLOTS},{lm.index_topk},{lm.latent_width}]" in text
+    assert f"bf16[{DSA_SLOTS},{DSA_SEQ},{lm.latent_width}]" not in text
