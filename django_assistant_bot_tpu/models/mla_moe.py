@@ -67,10 +67,12 @@ from ..ops.attention import (
     latent_decode_kv_path,
     latent_decode_update_attend,
     paged_decode_plan,
+    paged_index_scores,
     sparse_attention,
     sparse_decode_select,
     sparse_latent_decode_attention,
     sparse_select,
+    topk_select_paged,
 )
 from ..ops.moe import held_experts_path
 from ..ops.norms import layer_norm, rms_norm
@@ -152,7 +154,12 @@ def kv_bytes_per_token(cfg: DecoderConfig, kv_dtype=None) -> int:
 
 
 def decode_kv_path(cfg: DecoderConfig, kv_dtype, page: int, *, fp8_dot: bool = False) -> str:
-    return latent_decode_kv_path(kv_dtype or cfg.dtype, page, cfg.latent_moe.latent_width)
+    """``"kernel"`` or ``"xla"``: what :func:`decode_step_paged` does with the
+    pools, asked by the step itself and by the engine's label; with an indexer
+    ``"kernel"`` means its three sparse stages are Pallas calls too."""
+    lm = cfg.latent_moe
+    return latent_decode_kv_path(kv_dtype or cfg.dtype, page, lm.latent_width,
+                                 index_width=lm.index_head_dim if lm.index_topk else 0)
 
 
 def moe_experts_path(cfg: DecoderConfig) -> str:
@@ -715,9 +722,16 @@ def decode_step_paged(
     With an indexer (``index_topk``) and a view longer than it, a layer writes
     the slot's latent row and index key where they belong, scores the index
     keys of the slot's pages, takes the top-k among positions ``<= pos`` on
-    allocated pages, and gathers ONLY those latent rows for the absorbed
-    attention (:func:`~..ops.attention.sparse_latent_decode_attention`): the
-    whole-page kernel is not on this path."""
+    allocated pages, and attends those latent rows in the absorbed form.  On a
+    TPU all three stages follow the same work list, the two pools left in HBM:
+    :func:`~..ops.attention.paged_index_scores` (the key written in place, a
+    ``[B, NB, page]`` row of scores out), :func:`~..ops.attention.
+    topk_select_paged` (exact, by counting) and the latent kernel under the
+    selection as a mask; nothing of ``[slots, view]`` x heads or key width is
+    made, nothing is sorted, and a slot that is not active is not worked on.
+    Elsewhere the plain functions: the index keys gathered, ``top_k``, the
+    selected rows gathered (:func:`~..ops.attention.
+    sparse_latent_decode_attention`)."""
     if attn_fp8:
         raise NotImplementedError("attn_fp8 is not implemented for the latent cache")
     lm = cfg.latent_moe
@@ -734,18 +748,20 @@ def decode_step_paged(
     cos, sin = cos_t[positions][:, None, :], sin_t[positions][:, None, :]
     scale = softmax_scale(cfg)
     select = bool(lm.index_topk) and S > lm.index_topk
-    kernel = latent_decode_kv_path(cache.kv.dtype, page, W) == "kernel" and not lm.index_topk
+    kernel = decode_kv_path(cfg, cache.kv.dtype, page) == "kernel"
     if kernel:
         plan = paged_decode_plan(block_tables, positions, active, n_pages=P, page=page)
-    else:
+    if not kernel or (lm.index_topk and not select):  # where a row or a key is written by a scatter
         phys = jnp.take_along_axis(block_tables, (positions // page)[:, None], axis=1)[:, 0]
         phys_w = jnp.where(active, jnp.minimum(phys, P), P)
         off = positions % page
     x = _embed(params, cfg, tokens)[:, None, :]
     valid = active[:, None]
-    if select:
+    if select and not kernel:
         allocated = jnp.repeat((block_tables >= 0) & (block_tables < P), page, axis=1)  # [B, S]
         ok = (jnp.arange(S)[None, :] <= positions[:, None]) & allocated & active[:, None]
+    if lm.index_topk and not select:  # the counters' selected pairs where the view keeps everything
+        every_pair = jnp.where(active, positions + 1, 0).sum()
 
     def make_body(held):
         def body(carry, inputs):
@@ -759,13 +775,23 @@ def decode_step_paged(
                 q = jnp.concatenate(
                     [q_abs, q_rope[:, 0]] + ([jnp.zeros((B, H, pad), q_abs.dtype)] if pad else []), axis=-1
                 )
+            if lm.index_topk:
+                q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
             if kernel:
+                keep = None
+                if select:
+                    scores, ipool = paged_index_scores(
+                        q_idx[:, 0], w_idx[:, 0], k_idx[:, 0], ipool, layer, block_tables, positions, plan)
+                    keep = topk_select_paged(scores, active, lm.index_topk)
+                    with jax.named_scope("attn/select"):
+                        selected = keep.sum()
+                elif lm.index_topk:  # everything is attended: the key is kept for a longer view's sake
+                    with jax.named_scope("attn/kv_write"):
+                        ipool = ipool.at[layer, phys_w, off].set(k_idx[:, 0].astype(ipool.dtype), mode="drop")
                 o_lat, pool = latent_decode_update_attend(
-                    q, row[:, 0], pool, layer, block_tables, positions, plan, scale=scale, value_width=C
+                    q, row[:, 0], pool, layer, block_tables, positions, plan, scale=scale, value_width=C, keep=keep
                 )
             else:
-                if lm.index_topk:
-                    q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
                 with jax.named_scope("attn/kv_write"):
                     pool = pool.at[layer, phys_w, off].set(row[:, 0].astype(pool.dtype), mode="drop")
                     if lm.index_topk:
@@ -782,12 +808,12 @@ def decode_step_paged(
                         q, jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False), block_tables, positions,
                         scale=scale, value_width=C, active=active,
                     )
-                    selected = jnp.where(active, positions + 1, 0).sum()
             with jax.named_scope("attn/absorb"):
                 o = jnp.einsum("bhc,chd->bhd", o_lat, p["w_uv"].astype(cfg.dtype).reshape(C, H, dv))
             x = x + _attn_out(cfg, p, o.reshape(B, 1, H * dv))
             y, stats = _ffn(cfg, p, x, valid, held, layer)
-            return (x + y, pool, ipool), (stats, _dsa_counts(active, positions, selected) if lm.index_topk else None)
+            dsa = _dsa_counts(active, positions, selected if select else every_pair) if lm.index_topk else None
+            return (x + y, pool, ipool), (stats, dsa)
 
         return body
 

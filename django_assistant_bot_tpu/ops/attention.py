@@ -659,53 +659,35 @@ def paged_decode_update_attend(
 # ---------------------------------------------------------------------------
 
 
-def latent_decode_kv_path(kv_dtype, page: int, width: int) -> str:
+def latent_decode_kv_path(kv_dtype, page: int, width: int, index_width: int = 0) -> str:
     """:func:`paged_decode_kv_path` for a latent pool ``[L, P, page, width]``:
     ``"kernel"`` (:func:`latent_decode_update_attend`) on a TPU when rows are
-    whole lane tiles and pages whole packed tiles, ``"xla"`` elsewhere."""
+    whole lane tiles and pages whole packed tiles, ``"xla"`` elsewhere.  With
+    an indexer (``index_width``: its key's width, the second pool's rows) the
+    step's sparse stages are Pallas calls too (:func:`paged_index_scores`,
+    :func:`topk_select_paged`, the kernel above under their mask), which lay a
+    page's scores on the lanes: index keys and pages in whole lane tiles, or
+    the whole step takes the plain path."""
     kernel_shaped = width % 128 == 0 and page % _packed_rows(kv_dtype) == 0
+    if index_width:
+        kernel_shaped = kernel_shaped and index_width % 128 == 0 and page % 128 == 0
     return "kernel" if kernel_shaped and jax.default_backend() == "tpu" else "xla"
 
 
-def _latent_decode_kernel(
-    # scalar prefetch (SMEM)
-    items_ref, n_ref, bt_ref, pos_ref, layer_ref,
-    # inputs
-    q_ref,  # [B, H, W] VMEM: absorbed query | rotary query | zero pad
-    new_ref,  # [B, 1, W] f32 VMEM: the step's latent row per slot
-    pool_hbm,  # [L, P, page, W] HBM: never loaded whole
-    # outputs
-    o_ref,  # [B, H, Wv] f32 VMEM
-    pool_out,  # the same buffer as pool_hbm (input_output_aliases)
-    # scratch
-    buf,  # [2, page, W] double-buffered page
-    m_scr,  # [B, H, 128] f32 running max (every lane the same)
-    l_scr,  # [B, H, 128] f32 running sum
-    rsem,  # DMA [2 (buffer)]
-    wsem,  # DMA [1]
-    *,
-    nb: int,
-    page: int,
-    sub: int,
-    scale: float,
-    value_width: int,
+def _walk_plan_pages(
+    items_ref, n, bt_ref, pos_ref, layer, new_ref, pool_hbm, pool_out, buf, rsem, wsem, *, nb: int, page: int, sub: int, visit
 ):
-    """:func:`_paged_decode_kernel` for a latent pool: per work item one page of
-    ONE array is DMA'd in, patched with the step's row where it is the slot's
-    write page (that ``sub``-row tile DMA'd back: the only bytes written), and
-    folded into the slot's online softmax with all heads as the matmul's M
-    dimension.  The same page is the keys (all ``W`` lanes) and the values (the
-    first ``value_width`` lanes): it is read from HBM once."""
-    H = q_ref.shape[1]
-    layer = layer_ref[0]
-    n = n_ref[0]
+    """The walk over :func:`paged_decode_plan`'s work list that the kernels of
+    a pool of ONE array share (``[L, P, page, width]``: latent rows, index
+    keys).  Per item, one page of one slot, the page is DMA'd HBM -> ``buf``
+    (double-buffered: the next item's is in flight), patched with the step's
+    own row ``new_ref[slot]`` where it is the page the slot's position falls
+    in, the one ``sub``-row tile that holds the row DMA'd back (the only bytes
+    of the pool a step writes), and handed to ``visit(slot, j, pos, s)``: block
+    ``j`` of ``slot``, at position ``pos``, in ``buf[s]``."""
 
     def page_copy(i, s):
         return pltpu.make_async_copy(pool_hbm.at[layer, bt_ref[items_ref[i]]], buf.at[s], rsem.at[s])
-
-    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
-    o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
     @pl.when(n > 0)
     def _first():
@@ -739,23 +721,7 @@ def _latent_decode_kernel(
             buf[s, pl.ds(al, sub), :] = jnp.where(at_row, new_ref[slot], tile).astype(buf.dtype)
             tile_copy().start()
 
-        q = q_ref[slot]  # [H, W]
-        rows = buf[s].astype(q.dtype)  # [page, W]
-        sc = jax.lax.dot_general(
-            q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [H, page]
-        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (H, page), 1)
-        sc = jnp.where(kpos <= pos, sc, NEG_INF)
-        m_prev = m_scr[slot][:, :1]
-        l_prev = l_scr[slot][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        o_ref[slot] = alpha * o_ref[slot] + jnp.dot(
-            p.astype(q.dtype), rows[:, :value_width], preferred_element_type=jnp.float32
-        )
-        m_scr[slot] = jnp.broadcast_to(m_new, (H, 128))
-        l_scr[slot] = jnp.broadcast_to(alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), (H, 128))
+        visit(slot, j, pos, s)
 
         @pl.when(writes)
         def _drain():  # the buffer is the next-but-one item's DMA target
@@ -765,6 +731,76 @@ def _latent_decode_kernel(
 
     jax.lax.fori_loop(0, n, item, 0)
 
+
+def _latent_decode_kernel(
+    # scalar prefetch (SMEM)
+    items_ref, n_ref, bt_ref, pos_ref, layer_ref,
+    # inputs
+    q_ref,  # [B, H, W] VMEM: absorbed query | rotary query | zero pad
+    new_ref,  # [B, 1, W] f32 VMEM: the step's latent row per slot
+    pool_hbm,  # [L, P, page, W] HBM: never loaded whole
+    keep_ref,  # [B, NB, page] int32 VMEM: the selection (non-zero = attend), or None: no such operand
+    # outputs
+    o_ref,  # [B, H, Wv] f32 VMEM
+    pool_out,  # the same buffer as pool_hbm (input_output_aliases)
+    # scratch
+    buf,  # [2, page, W] double-buffered page
+    m_scr,  # [B, H, 128] f32 running max (every lane the same)
+    l_scr,  # [B, H, 128] f32 running sum
+    rsem,  # DMA [2 (buffer)]
+    wsem,  # DMA [1]
+    *,
+    nb: int,
+    page: int,
+    sub: int,
+    scale: float,
+    value_width: int,
+):
+    """:func:`_paged_decode_kernel` for a latent pool (:func:`_walk_plan_pages`):
+    per work item one page of ONE array, the step's row patched in, is folded
+    into the slot's online softmax with all heads as the matmul's M dimension.
+    The same page is the keys (all ``W`` lanes) and the values (the first
+    ``value_width`` lanes): it is read from HBM once.
+
+    Under ``keep_ref`` (the sparse attention's decode step) a key is attended
+    where the selection kept it, ``keep & (kpos <= pos)`` in place of ``kpos
+    <= pos``.  A row's first pages may then hold no kept key, so the
+    probabilities are zeroed under the mask and not only the scores."""
+    masked = keep_ref is not None
+    H = q_ref.shape[1]
+    layer = layer_ref[0]
+    n = n_ref[0]
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+
+    def attend(slot, j, pos, s):
+        q = q_ref[slot]  # [H, W]
+        rows = buf[s].astype(q.dtype)  # [page, W]
+        sc = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [H, page]
+        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (H, page), 1)
+        seen = kpos <= pos
+        if masked:
+            seen &= keep_ref[slot, pl.ds(j, 1), :] != 0  # [1, page]: every head attends the same keys
+        sc = jnp.where(seen, sc, NEG_INF)
+        m_prev = m_scr[slot][:, :1]
+        l_prev = l_scr[slot][:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        if masked:
+            p = jnp.where(seen, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        o_ref[slot] = alpha * o_ref[slot] + jnp.dot(
+            p.astype(q.dtype), rows[:, :value_width], preferred_element_type=jnp.float32
+        )
+        m_scr[slot] = jnp.broadcast_to(m_new, (H, 128))
+        l_scr[slot] = jnp.broadcast_to(alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), (H, 128))
+
+    _walk_plan_pages(items_ref, n, bt_ref, pos_ref, layer, new_ref, pool_hbm, pool_out, buf, rsem, wsem,
+                     nb=nb, page=page, sub=sub, visit=attend)
+
     def normalise(b, carry):
         o_ref[b] = o_ref[b] / jnp.maximum(l_scr[b][:, :1], 1e-30)
         return carry
@@ -772,7 +808,12 @@ def _latent_decode_kernel(
     jax.lax.fori_loop(0, q_ref.shape[0], normalise, 0)
 
 
-@jax.named_scope("attn/kv_read")
+def _latent_decode_kernel_unmasked(*refs, **static):
+    """:func:`_latent_decode_kernel` for the call without a selection, whose
+    refs hold no ``keep_ref`` after the three inputs."""
+    _latent_decode_kernel(*refs[:8], None, *refs[8:], **static)
+
+
 def latent_decode_update_attend(
     q: jnp.ndarray,  # [B, H, W]: absorbed query | rotary query | zeros, pool's lane layout
     row_new: jnp.ndarray,  # [B, W] the step's latent row per slot (norm and rotation applied)
@@ -784,6 +825,7 @@ def latent_decode_update_attend(
     *,
     scale: float,
     value_width: int,
+    keep: Optional[jnp.ndarray] = None,  # [B, NB, page] int32: topk_select_paged's selection
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """A decode step's latent-row write and attention read over the latent
@@ -796,55 +838,64 @@ def latent_decode_update_attend(
     nothing.  Scores are ``q . row`` over all ``W`` lanes (the absorbed and the
     rotary part in one contraction; pad lanes are zero on both sides) in
     float32, values are the row's first ``value_width`` lanes.  Not sharded:
-    the latent row is shared by every head, so a mesh replicates the pool."""
-    B, H, W = q.shape
-    L, P, page, _ = pool.shape
-    NB = block_tables.shape[1]
-    sub = _packed_rows(pool.dtype)
-    if page % sub or W % 128 or value_width % 128 or H % 8:
-        raise ValueError(
-            f"latent decode kernel needs page % {sub} == 0, row and value widths in whole "
-            f"lane tiles and heads % 8 == 0, got page={page}, W={W}, Wv={value_width}, H={H}"
-        )
-    items, n_items = plan
-    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    o, pool = pl.pallas_call(
-        functools.partial(
-            _latent_decode_kernel, nb=NB, page=page, sub=sub, scale=float(scale),
-            value_width=value_width,
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(1,),
-            in_specs=[vmem, vmem, hbm],
-            out_specs=[vmem, hbm],
-            scratch_shapes=[
-                pltpu.VMEM((2, page, W), pool.dtype),
-                pltpu.VMEM((B, H, 128), jnp.float32),
-                pltpu.VMEM((B, H, 128), jnp.float32),
-                pltpu.SemaphoreType.DMA((2,)),
-                pltpu.SemaphoreType.DMA((1,)),
+    the latent row is shared by every head, so a mesh replicates the pool.
+
+    ``keep`` is the learned sparse attention's selection (static: without it
+    the call traces the kernel it always did): a slot attends the keys it
+    marks among ``[0, pos]``, each live page still read once and whole; a slot
+    whose selection is empty comes back zero.  The call then sits under
+    ``attn/sparse_core``, not ``attn/kv_read``."""
+    with jax.named_scope("attn/kv_read" if keep is None else "attn/sparse_core"):
+        B, H, W = q.shape
+        L, P, page, _ = pool.shape
+        NB = block_tables.shape[1]
+        sub = _packed_rows(pool.dtype)
+        if page % sub or W % 128 or value_width % 128 or H % 8:
+            raise ValueError(
+                f"latent decode kernel needs page % {sub} == 0, row and value widths in whole "
+                f"lane tiles and heads % 8 == 0, got page={page}, W={W}, Wv={value_width}, H={H}"
+            )
+        items, n_items = plan
+        vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        masked = keep is not None
+        o, pool = pl.pallas_call(
+            functools.partial(
+                _latent_decode_kernel if masked else _latent_decode_kernel_unmasked,
+                nb=NB, page=page, sub=sub, scale=float(scale), value_width=value_width,
+            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(1,),
+                in_specs=[vmem, vmem, hbm] + ([vmem] if masked else []),
+                out_specs=[vmem, hbm],
+                scratch_shapes=[
+                    pltpu.VMEM((2, page, W), pool.dtype),
+                    pltpu.VMEM((B, H, 128), jnp.float32),
+                    pltpu.VMEM((B, H, 128), jnp.float32),
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SemaphoreType.DMA((1,)),
+                ],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((B, H, value_width), jnp.float32),
+                jax.ShapeDtypeStruct(pool.shape, pool.dtype),
             ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, value_width), jnp.float32),
-            jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-        ],
-        input_output_aliases={7: 1},  # counts the five scalar-prefetch arguments
-        compiler_params=pltpu.CompilerParams(
-            # queries, the float32 accumulator and running state of every slot,
-            # two page buffers; room for the score tile and Pallas's own copies
-            vmem_limit_bytes=2 * (B * H * (W * 2 + value_width * 4 + 2 * 128 * 4)) + 4 * page * W * 2 + (16 << 20),
-        ),
-        name="latent_decode",
-        interpret=interpret,
-    )(
-        items, n_items, block_tables.reshape(-1).astype(jnp.int32),
-        positions.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
-        q, row_new.astype(jnp.float32)[:, None, :], pool,
-    )
-    return o.astype(q.dtype), pool
+            input_output_aliases={7: 1},  # counts the five scalar-prefetch arguments
+            compiler_params=pltpu.CompilerParams(
+                # queries, the float32 accumulator and running state of every slot,
+                # two page buffers; room for the score tile and Pallas's own copies
+                vmem_limit_bytes=2 * (B * H * (W * 2 + value_width * 4 + 2 * 128 * 4)) + 4 * page * W * 2 + (16 << 20)
+                + (2 * B * NB * page * 4 if masked else 0),
+            ),
+            name="sparse_latent_decode" if masked else "latent_decode",
+            interpret=interpret,
+        )(
+            items, n_items, block_tables.reshape(-1).astype(jnp.int32),
+            positions.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+            q, row_new.astype(jnp.float32)[:, None, :], pool, *([keep] if masked else []),
+        )
+        return o.astype(q.dtype), pool
 
 
 @jax.named_scope("attn/kv_read")
@@ -1636,3 +1687,201 @@ def sparse_latent_decode_attention(
     sc = jnp.where(picked[:, None, :], sc, NEG_INF)
     probs = jnp.where(picked[:, None, :], jax.nn.softmax(sc, axis=-1), 0.0).astype(q.dtype)
     return jnp.einsum("bhk,bkw->bhw", probs, rows[..., :value_width])
+
+
+# ---------------------------------------------------------------------------
+# The sparse decode step over the plan's pages: index scores and an exact
+# selection by counting, as Pallas calls; the attention is the latent decode
+# kernel under the selection (``latent_decode_update_attend(..., keep=...)``)
+# ---------------------------------------------------------------------------
+
+
+def _paged_index_score_kernel(
+    # scalar prefetch (SMEM)
+    items_ref, n_ref, bt_ref, pos_ref, layer_ref,
+    # inputs
+    q_ref,  # [B, Hi, Di] VMEM: the step's index queries per slot
+    w_ref,  # [B, Hi, 128] f32 VMEM: a head's weight on every lane
+    new_ref,  # [B, 1, Di] f32 VMEM: the step's index key per slot
+    pool_hbm,  # [L, P, page, Di] HBM: never loaded whole
+    # outputs
+    s_ref,  # [B, NB, page] f32 VMEM: -inf wherever no key can be selected
+    pool_out,  # the same buffer as pool_hbm (input_output_aliases)
+    # scratch
+    buf,  # [2, page, Di] double-buffered page
+    rsem,  # DMA [2 (buffer)]
+    wsem,  # DMA [1]
+    *,
+    nb: int,
+    page: int,
+    sub: int,
+):
+    """:func:`_walk_plan_pages` over the pool of index keys (the step's own key
+    patched into its page): a page is scored against the slot's 64 index
+    queries in one matmul and reduced over the heads in VMEM; one ``[1, page]``
+    row of scores leaves the kernel."""
+    s_ref[...] = jnp.full(s_ref.shape, -jnp.inf, jnp.float32)
+
+    def score(slot, j, pos, s):
+        q = q_ref[slot]  # [Hi, Di]
+        sc = jax.lax.dot_general(
+            q, buf[s].astype(q.dtype), (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )  # [Hi, page]
+        row = jnp.sum(jnp.maximum(sc, 0.0) * w_ref[slot][:, :1], axis=0, keepdims=True)  # [1, page]
+        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        s_ref[slot, pl.ds(j, 1), :] = jnp.where(kpos <= pos, row, -jnp.inf)
+
+    _walk_plan_pages(items_ref, n_ref[0], bt_ref, pos_ref, layer_ref[0], new_ref, pool_hbm, pool_out, buf, rsem, wsem,
+                     nb=nb, page=page, sub=sub, visit=score)
+
+
+@jax.named_scope("attn/index_score")
+def paged_index_scores(
+    q: jnp.ndarray,  # [B, Hi, Di] the step's index queries (rotated)
+    w: jnp.ndarray,  # [B, Hi] f32 head weights (scaled)
+    key_new: jnp.ndarray,  # [B, Di] the step's index key per slot
+    pool: jnp.ndarray,  # [L, P, page, Di] the whole pool of index keys, every layer
+    layer: jnp.ndarray,  # scalar int32
+    block_tables: jnp.ndarray,  # [B, NB] int32
+    positions: jnp.ndarray,  # [B] int32
+    plan: tuple[jnp.ndarray, jnp.ndarray],  # paged_decode_plan(...)
+    *,
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """A decode step's index-key write and index scores over the pages on the
+    plan, as ONE Pallas call -> ``(scores [B, NB, page] f32, pool)``.
+
+    :func:`latent_decode_update_attend`'s contract on the second pool: it stays
+    in HBM and comes back aliased, a slot writes its key at ``(block_table[b,
+    pos // page], pos % page)`` and reads the pages its table names over ``[0,
+    pos]``, the step's own key among them; slots and blocks off the plan read
+    and write nothing.  ``scores[b, j, o]`` is :func:`index_scores` of position
+    ``j * page + o`` (``sum_h w_h relu(k . q_h)``, the per-head products in
+    float32 from bfloat16 operands, summed over the heads in float32), and
+    ``-inf`` at every position the slot cannot select: past ``pos``, on a block
+    without a page, of a slot that is not active.  Neither a copy of the keys
+    nor a per-head score leaves VMEM."""
+    B, Hi, Di = q.shape
+    L, P, page, _ = pool.shape
+    NB = block_tables.shape[1]
+    sub = _packed_rows(pool.dtype)
+    if page % sub or Di % 128 or Hi % 8:
+        raise ValueError(
+            f"index score kernel needs page % {sub} == 0, keys in whole lane tiles and heads % 8 == 0, "
+            f"got page={page}, Di={Di}, Hi={Hi}"
+        )
+    items, n_items = plan
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_paged_index_score_kernel, nb=NB, page=page, sub=sub),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(1,),
+            in_specs=[vmem, vmem, vmem, hbm],
+            out_specs=[vmem, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, page, Di), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((1,)),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((B, NB, page), jnp.float32),
+            jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        ],
+        input_output_aliases={8: 1},  # counts the five scalar-prefetch arguments
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * B * (NB * page * 4 + Hi * (Di * 2 + 128 * 4)) + 4 * page * Di * 2 + (16 << 20),
+        ),
+        name="paged_index_scores",
+        interpret=interpret,
+    )(
+        items, n_items, block_tables.reshape(-1).astype(jnp.int32),
+        positions.astype(jnp.int32), jnp.reshape(layer, (1,)).astype(jnp.int32),
+        q, jnp.broadcast_to(w.astype(jnp.float32)[:, :, None], (B, Hi, 128)),
+        key_new.astype(jnp.float32)[:, None, :], pool,
+    )
+
+
+_INT_MIN = -(2**31)
+
+
+def _topk_select_kernel(
+    active_ref,  # scalar prefetch [B] int32
+    s_ref,  # [NB, page] f32: one slot's scores, -inf where nothing can be selected
+    keep_ref,  # [NB, page] int32
+    *,
+    k: int,
+):
+    """One slot's exact top-``k``, by counting (:func:`topk_mask`'s method on a
+    slot's scores in VMEM): the k-th largest value a bit a pass, 32 compares
+    and sums over the ``[NB, page]`` tile; then, among the entries equal to it,
+    the position up to which they fit, 14 more of the same, so that ties go to
+    the lowest positions as ``jax.lax.top_k`` gives them.  Nothing is sorted
+    and a slot that is not active costs a store of zeros."""
+    nb, page = s_ref.shape
+    live = active_ref[pl.program_id(0)] != 0
+
+    @pl.when(jnp.logical_not(live))
+    def _idle():
+        keep_ref[...] = jnp.zeros(keep_ref.shape, jnp.int32)
+
+    @pl.when(live)
+    def _select():
+        s = s_ref[...]
+        ok = s > -jnp.inf
+        bits = pltpu.bitcast(s, jnp.int32)
+        # an int32 that orders as the floats do (-0.0 just under +0.0, as _sortable); below them all where not ok
+        key = jnp.where(ok, jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits), jnp.int32(_INT_MIN))
+
+        def count(m):
+            return jnp.sum(m.astype(jnp.int32), keepdims=True)  # [1, 1]
+
+        def value_bit(i, prefix):  # the k-th largest key, built from its top bit down, in the offset (unsigned) domain
+            cand = prefix | jnp.left_shift(jnp.int32(1), 31 - i)
+            return jnp.where(count(key >= (cand ^ jnp.int32(_INT_MIN))) >= k, cand, prefix)
+
+        kth = jax.lax.fori_loop(0, 32, value_bit, jnp.zeros((1, 1), jnp.int32)) ^ jnp.int32(_INT_MIN)
+        above = key > kth  # never a key that is not ok: kth is at least theirs
+        tied = (key == kth) & ok
+        room = k - count(above)
+        at = jax.lax.broadcasted_iota(jnp.int32, (nb, page), 0) * page + jax.lax.broadcasted_iota(jnp.int32, (nb, page), 1)
+        n_bits = max(1, (nb * page - 1).bit_length())
+
+        def position_bit(i, prefix):  # the largest position below which fewer than `room` of the tied lie
+            cand = prefix | jnp.left_shift(jnp.int32(1), n_bits - 1 - i)
+            return jnp.where(count(tied & (at < cand)) < room, cand, prefix)
+
+        last = jax.lax.fori_loop(0, n_bits, position_bit, jnp.zeros((1, 1), jnp.int32))
+        keep_ref[...] = (above | (tied & (at <= last))).astype(jnp.int32)
+
+
+@jax.named_scope("attn/select")
+def topk_select_paged(
+    scores: jnp.ndarray,  # [B, NB, page] f32 from paged_index_scores: -inf = cannot be selected
+    active: jnp.ndarray,  # [B] bool
+    topk: int,
+    *,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """A decode step's selection as a mask over the slot's pages -> ``keep [B,
+    NB, page]`` int32: non-zero at the ``topk`` largest finite ``scores`` of a
+    slot (all of them where it has fewer), ties at the k-th value going to the
+    lowest positions: the set :func:`sparse_decode_select` returns, key for
+    key.  Exact and not a sort (:func:`_topk_select_kernel`); a slot that is
+    not active keeps nothing and is not worked on."""
+    B, NB, page = scores.shape
+    return pl.pallas_call(
+        functools.partial(_topk_select_kernel, k=int(topk)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((None, NB, page), lambda b, act: (b, 0, 0))],
+            out_specs=pl.BlockSpec((None, NB, page), lambda b, act: (b, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, NB, page), jnp.int32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name="topk_select",
+        interpret=interpret,
+    )(active.astype(jnp.int32), scores)

@@ -408,3 +408,183 @@ def test_the_masked_flash_kernel_equals_the_masked_softmax(live):
     want = A.sparse_attention(q, kt, v, jnp.asarray(keep), jnp.asarray(live, jnp.int32), scale=0.1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     assert not np.asarray(got[0, :, 3]).any() and float(np.abs(np.asarray(got[1, :, 4])).max()) > 0
+
+
+# ---------------------------------------------------------------------------
+# the sparse decode step's Pallas calls, interpreted on the CPU, against the three plain functions
+# ---------------------------------------------------------------------------
+
+SD_PAGE, SD_NB, SD_L, SD_HI, SD_DI, SD_H, SD_W, SD_WV, SD_K = 32, 4, 2, 8, 128, 8, 256, 128, 40
+
+
+def _sparse_decode_case(case):
+    """-> inputs of one layer of one step: 5 slots on 4 pages of 32, ``index_topk`` 40.  The index queries, keys and
+    head weights are small integers and powers of two, so a score is the same float in whatever order the heads are
+    summed, and many keys share one: the k-th value is shared in every row, the ties run over page boundaries, and
+    (the weights have both signs) half the scores are negative."""
+    r = np.random.default_rng(sum(case.encode()))
+    B = 5
+    P = B * SD_NB
+    ints = lambda lo, hi, *s: jnp.asarray(r.integers(lo, hi + 1, s), jnp.float32).astype(jnp.bfloat16)  # noqa: E731
+    draw = lambda *s: jnp.asarray(r.standard_normal(s) * 0.5, jnp.float32).astype(jnp.bfloat16)  # noqa: E731
+    q_idx, k_new, ipool = ints(-2, 2, B, SD_HI, SD_DI), ints(-1, 1, B, SD_DI), ints(-1, 1, SD_L, P, SD_PAGE, SD_DI)
+    w_idx = jnp.asarray(r.choice([-1.0, -0.5, 0.25, 0.5, 1.0], (B, SD_HI)), jnp.float32)
+    q, row, pool = draw(B, SD_H, SD_W), draw(B, SD_W), draw(SD_L, P, SD_PAGE, SD_W)
+    bt = r.permutation(P).reshape(B, SD_NB).astype(np.int32)
+    positions = np.asarray([127, 64, 95, 70, 100])  # the whole view; one key on a page; a page's last row; partly live
+    active = np.ones(B, bool)
+    if case == "ties-over-page-boundaries":
+        # two heads over two lanes, one weighted below zero: ~20 distinct scores, a good part of them negative
+        ipool, w_idx = ipool.at[:, :, :, 2:].set(0), w_idx.at[:, 2:].set(0).at[:, 1].set(-0.5)
+    elif case == "fewer-keys-than-topk":
+        positions = np.asarray([5, 38, 39, 40, 31])  # 6, 39, 40 (exactly topk), 41 keys; one whole page
+    elif case == "inactive-and-unallocated":
+        active[1] = False
+        bt[3, 2] = P  # its write block has no page: it selects among the pages below and writes nothing
+        bt[4, 1] = P  # a hole in the middle: those 32 positions are never selected
+    elif case == "stale-rows-past-pos":
+        ipool = ipool.at[:, :, 20:].set(jnp.asarray(8.0, jnp.bfloat16))  # a reused page's rows: huge scores if ever read
+        positions = np.asarray([83, 64, 51, 70, 115])  # all but one row end below row 20 of their last page
+    elif case == "own-key-wins":
+        k_new = (q_idx[:, 0] * 4).astype(jnp.bfloat16)  # aligned with head 0's query: far the largest score
+        w_idx = w_idx.at[:, 0].set(1.0)
+    elif case.startswith("active-"):
+        active[:] = False
+        active[: int(case.split("-")[1])] = True
+    return dict(q_idx=q_idx, w_idx=w_idx, k_new=k_new, ipool=ipool, q=q, row=row, pool=pool, bt=jnp.asarray(bt),
+                positions=jnp.asarray(positions, jnp.int32), active=jnp.asarray(active), P=P)
+
+
+SPARSE_DECODE_CASES = ["ties-over-page-boundaries", "fewer-keys-than-topk", "inactive-and-unallocated",
+                       "stale-rows-past-pos", "own-key-wins", "last-page-partly-live", "active-1", "active-2", "active-5"]
+
+
+@pytest.mark.parametrize("case", SPARSE_DECODE_CASES)
+def test_the_sparse_decode_kernels_select_the_same_keys_and_attend_them(case):
+    """Index scores, selection and attention over the plan's pages against ``index_scores`` +
+    ``sparse_decode_select`` + ``sparse_latent_decode_attention`` on the scattered pools: every byte of both
+    pools, every score, THE SELECTED SET KEY FOR KEY, and the output of every live row."""
+    c = _sparse_decode_case(case)
+    P, bt, positions, active = c["P"], c["bt"], c["positions"], c["active"]
+    S = SD_NB * SD_PAGE
+    layer = jnp.int32(1)
+    plan = A.paged_decode_plan(bt, positions, active, n_pages=P, page=SD_PAGE)
+
+    @jax.jit
+    def kernels(q_idx, w_idx, k_new, ipool, q, row, pool):
+        scores, ipool = A.paged_index_scores(q_idx, w_idx, k_new, ipool, layer, bt, positions, plan, interpret=True)
+        keep = A.topk_select_paged(scores, active, SD_K, interpret=True)
+        o, pool = A.latent_decode_update_attend(q, row, pool, layer, bt, positions, plan, scale=0.11, value_width=SD_WV,
+                                                keep=keep, interpret=True)
+        return scores, keep, o, ipool, pool
+
+    scores, keep, o, ipool, pool = kernels(*(c[k] for k in ("q_idx", "w_idx", "k_new", "ipool", "q", "row", "pool")))
+    phys = jnp.take_along_axis(bt, (positions // SD_PAGE)[:, None], axis=1)[:, 0]
+    phys_w, off = jnp.where(active, jnp.minimum(phys, P), P), positions % SD_PAGE
+    want_ipool = c["ipool"].at[1, phys_w, off].set(c["k_new"], mode="drop")
+    want_pool = c["pool"].at[1, phys_w, off].set(c["row"], mode="drop")
+    assert np.array_equal(np.asarray(ipool, np.float32), np.asarray(want_ipool, np.float32))
+    assert np.array_equal(np.asarray(pool, np.float32), np.asarray(want_pool, np.float32))
+    keys = want_ipool[1, jnp.clip(bt, 0, P - 1)].reshape(5, S, SD_DI)
+    want_scores = np.asarray(A.index_scores(c["q_idx"][:, None], c["w_idx"][:, None], keys)[:, 0])
+    allocated = np.repeat((np.asarray(bt) >= 0) & (np.asarray(bt) < P), SD_PAGE, axis=1)
+    ok = (np.arange(S)[None, :] <= np.asarray(positions)[:, None]) & allocated & np.asarray(active)[:, None]
+    got_scores = np.asarray(scores).reshape(5, S)
+    assert np.array_equal(got_scores[ok], want_scores[ok]) and np.isneginf(got_scores[~ok]).all()
+    idx, picked = A.sparse_decode_select(jnp.asarray(want_scores), jnp.asarray(ok), SD_K)
+    got = np.asarray(keep).reshape(5, S) != 0
+    for b in range(5):
+        want_set = set(np.asarray(idx[b])[np.asarray(picked[b])].tolist())
+        assert set(np.flatnonzero(got[b]).tolist()) == want_set, (b, sorted(want_set ^ set(np.flatnonzero(got[b]).tolist())))
+        assert len(want_set) == min(SD_K, int(ok[b].sum()))
+    assert not (got & ~ok).any()
+    if case == "own-key-wins":
+        assert all(got[b, int(positions[b])] for b in range(5))
+    if case == "ties-over-page-boundaries":  # the k-th value is shared, in and out of the set, on more than one page
+        for b in range(5):
+            kth = np.sort(want_scores[b][ok[b]])[-SD_K]
+            tied = ok[b] & (want_scores[b] == kth)
+            assert (tied & got[b]).any() and (tied & ~got[b]).any() and len(set(np.flatnonzero(tied) // SD_PAGE)) > 1
+            assert (want_scores[b][ok[b]] < 0).sum() > 10
+    want = A.sparse_latent_decode_attention(c["q"], want_pool, layer, bt, idx, picked, scale=0.11, value_width=SD_WV)
+    live = np.asarray(active)
+    np.testing.assert_allclose(np.asarray(o, np.float32)[live], np.asarray(want, np.float32)[live], rtol=2.0**-7, atol=2.0**-9)
+    assert not np.asarray(o, np.float32)[~live].any()
+
+
+@pytest.mark.parametrize("kind", ["normal", "all-negative", "signed-zeros"])
+def test_the_selection_kernel_equals_top_k_on_any_floats(kind):
+    """The counting works on the floats' bit patterns: a k-th value below zero, and -0.0 against +0.0."""
+    r = np.random.default_rng(len(kind))
+    s = r.standard_normal((3, 4, 32)).astype(np.float32)
+    if kind == "all-negative":
+        s = -np.abs(s) - 0.5
+    if kind == "signed-zeros":
+        s = np.where(r.random(s.shape) < 0.5, np.float32(-0.0), np.float32(0.0)) * (r.random(s.shape) < 0.8) + (r.random(s.shape) < 0.1)
+        s = s.astype(np.float32)
+    ok = np.arange(128)[None, :] <= np.asarray([127, 90, 20])[:, None]
+    scores = jnp.asarray(np.where(ok.reshape(3, 4, 32), s, -np.inf))
+    keep = np.asarray(A.topk_select_paged(scores, jnp.asarray([True, True, True]), 24, interpret=True)).reshape(3, 128) != 0
+    want = np.asarray(A.topk_mask(jnp.asarray(s.reshape(3, 128)), 24, jnp.asarray(ok), axis=1))
+    assert np.array_equal(keep, want)
+    assert [int(x) for x in keep.sum(-1)] == [24, 24, 21]
+
+
+def _kernel_step(monkeypatch):
+    """Steer ``decode_step_paged`` onto its Pallas calls, interpreted (the CPU's answer is ``xla``)."""
+    import functools
+
+    monkeypatch.setattr(mla_moe, "latent_decode_kv_path", lambda *a, **k: "kernel")
+    for name in ("paged_index_scores", "topk_select_paged", "latent_decode_update_attend"):
+        monkeypatch.setattr(mla_moe, name, functools.partial(getattr(A, name), interpret=True))
+
+
+@pytest.mark.parametrize("topk", [8, 4096], ids=["selects", "view-within-topk"])
+def test_the_kernel_decode_step_equals_the_plain_step_and_the_reference_at_every_position(family, monkeypatch, topk):
+    """``test_prefill_then_paged_decode_equals_the_reference_at_every_position`` with the step on its Pallas calls
+    (index heads and key widths the kernels admit): logits, both pools and the counters against the plain path's at
+    every step, and the logits against the reference's.  ``selects``: the three calls of the sparse path.
+    ``view-within-topk``: an indexer whose ``index_topk`` covers the view, so the latent kernel attends everything,
+    the index key is scattered for a longer view's sake and the counters count every causal pair."""
+    conf = _conf(num_attention_heads=8, num_key_value_heads=8, index_n_heads=8, index_head_dim=128, kv_lora_rank=128,
+                 index_topk=topk)
+    selects = topk == 8
+    cfg, params = _program(families.load(conf, DATA), conf)
+    seqs = [_ids(52, 3), _ids(45, 4)]
+    want = _reference(families.load(conf, DATA), conf, seqs)
+    cache, bt = _paged(cfg)
+    n0 = [20, 12]
+    ids = np.zeros((2, 24), np.int32)
+    for i, s in enumerate(seqs):
+        ids[i, : n0[i]] = s[: n0[i]]
+    logits, rows, stats = mla_moe.prefill(params, cfg, jnp.asarray(ids), jnp.asarray(n0))
+    plain = mla_moe.insert_sequences_paged(cache, rows, stats, jnp.asarray(n0), jnp.asarray([0, 2]), bt[jnp.asarray([0, 2])])
+    kern = jax.tree.map(jnp.copy, plain)
+    plain_step = jax.jit(lambda t, c, a: mla_moe.decode_step_paged(params, cfg, t, c, bt, active=a))
+    assert mla_moe.decode_kv_path(cfg, jnp.float32, 8) == "xla"
+    plain_text = str(jax.make_jaxpr(lambda t, c, a: mla_moe.decode_step_paged(params, cfg, t, c, bt, active=a))(
+        jnp.zeros((4,), jnp.int32), plain, jnp.ones((4,), bool)))
+    _kernel_step(monkeypatch)
+    kernel_fn = lambda t, c, a: mla_moe.decode_step_paged(params, cfg, t, c, bt, active=a)  # noqa: E731
+    active = jnp.asarray([True, False, True, False])
+    text = str(jax.make_jaxpr(kernel_fn)(jnp.zeros((4,), jnp.int32), kern, active))
+    # the router's top_k stays; the selection's (k = index_topk = 8), one in each stack's scan body, is gone
+    assert ("top_k[axis=1 k=8]" in plain_text) == selects and "k=8]" not in text and " sort[" not in text
+    assert text.count("top_k[") == plain_text.count("top_k[") - 2 * selects
+    for name in ("paged_index_scores", "topk_select", "sparse_latent_decode"):
+        assert (name in text) == selects, name
+    assert selects or text.count("name=latent_decode") == 2  # the latent kernel as a block without an indexer calls it
+    kernel_step = jax.jit(kernel_fn)
+    for k in range(25):
+        toks = jnp.asarray([seqs[0][n0[0] + k], 0, seqs[1][n0[1] + k], 0], jnp.int32)
+        lp, plain = plain_step(toks, plain, active)
+        lk, kern = kernel_step(toks, kern, active)
+        np.testing.assert_allclose(np.asarray(lk)[[0, 2]], np.asarray(lp)[[0, 2]], atol=ATOL)
+        np.testing.assert_allclose(np.asarray(lk[0]), want[0][n0[0] + k], atol=ATOL)
+        np.testing.assert_allclose(np.asarray(lk[2]), want[1][n0[1] + k], atol=ATOL)
+        for a, b in zip(jax.tree.leaves(kern), jax.tree.leaves(plain)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    assert [int(x) for x in kern.lengths] == [45, 0, 37, 0]
+    steps, queries, causal, kept = (int(x) for x in kern.stats[0, -8:-4])
+    assert causal == sum(n + k + 1 for n in n0 for k in range(25))
+    assert (steps, queries, kept) == (25, 50, 50 * 8 if selects else causal)

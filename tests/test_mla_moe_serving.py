@@ -91,6 +91,51 @@ def test_latent_decode_kernel_equals_the_plain_function_and_writes_one_row(case)
     assert not np.asarray(o, np.float32)[~live].any()
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_pallas_calls(sub))
+    return out
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["no-indexer", "under-a-selection"])
+def test_latent_decode_call_takes_a_mask_operand_only_where_the_block_selects(masked):
+    """Without ``keep`` (``index_topk`` 0: A.X-K1's step) the call is the one it was before the sparse decode path
+    was written: eight operands, the pool aliased through, the kernel's name; the selection adds one operand."""
+    B = 3
+    P = B * NB
+    q, row, pool = jnp.zeros((B, H, W), jnp.bfloat16), jnp.zeros((B, W), jnp.bfloat16), jnp.zeros((L, P, PAGE, W), jnp.bfloat16)
+    bt, positions = jnp.arange(P, dtype=jnp.int32).reshape(B, NB), jnp.asarray([5, 40, 100], jnp.int32)
+    plan = attn.paged_decode_plan(bt, positions, jnp.ones(B, bool), n_pages=P, page=PAGE)
+    keep = jnp.ones((B, NB, PAGE), jnp.int32) if masked else None
+    jaxpr = jax.make_jaxpr(functools.partial(attn.latent_decode_update_attend, scale=0.11, value_width=WV))(
+        q, row, pool, jnp.int32(1), bt, positions, plan, keep=keep)
+    (call,) = _pallas_calls(jaxpr.jaxpr)
+    assert call.params["input_output_aliases"] == ((7, 1),)
+    assert [tuple(v.aval.shape) for v in call.invars[5:]] == [(B, H, W), (B, 1, W), (L, P, PAGE, W)] + [(B, NB, PAGE)] * masked
+    assert len(call.invars) == 8 + masked
+    assert ("name=sparse_latent_decode" if masked else "name=latent_decode") in str(call)
+    assert "attn/sparse_core" in str(call.source_info.name_stack) if masked else "attn/kv_read" in str(call.source_info.name_stack)
+
+
+def test_the_step_without_an_indexer_makes_the_one_kernel_call_it_made(served, monkeypatch):
+    """``index_topk`` 0 on the kernel path: one ``latent_decode`` call a scan body, no score or selection call."""
+    conf, family, cfg, params, _ = served
+    monkeypatch.setattr(mla_moe, "latent_decode_kv_path", lambda *a, **k: "kernel")
+    # the kernel wants heads in whole sublane tiles and a lane-wide latent; nothing runs here
+    cfg8 = dataclasses.replace(cfg, num_heads=8, latent_moe=dataclasses.replace(cfg.latent_moe, kv_lora_rank=128))
+    params8 = jax.eval_shape(lambda: mla_moe.init(cfg8, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: mla_moe.init_paged_cache(cfg8, 4, 32, 16))
+    jaxpr = jax.make_jaxpr(lambda p, t, c, bt: mla_moe.decode_step_paged(p, cfg8, t, c, bt))(
+        params8, jnp.zeros((4,), jnp.int32), cache, jnp.zeros((4, 8), jnp.int32))
+    calls = _pallas_calls(jaxpr.jaxpr)
+    assert len(calls) == 2 and all("name=latent_decode" in str(c) and len(c.invars) == 8 for c in calls)
+
+
 # --- checkpoint, registry, engine ---------------------------------------------
 
 

@@ -652,16 +652,19 @@ def test_sparse_chunk_prefill_makes_nothing_of_heads_x_chunk_x_context_size(topo
     assert _pool_sized_values_made_in_loops(text, _dsa_pool_shapes(cfg)) == []
 
 
-def test_sparse_decode_tick_reads_index_keys_and_gathers_only_selected_rows(topo, monkeypatch):
-    """The decode step with an indexer: row and index key scattered into the
-    donated pools, the slots' index keys gathered by page, the top-k, and the
-    selected latent rows gathered where they lie: no copy of a layer of either
-    pool, no second pool among the temporaries."""
+def test_sparse_decode_step_follows_the_live_pages_in_three_pallas_calls(topo, monkeypatch):
+    """The decode step with an indexer: index scores, the selection by counting
+    and the attention under it lower for the v5e as Pallas calls over the
+    block tables; both donated pools are aliased through and no layer of
+    either is copied; nothing of [slots, view] x (heads or key width) size is
+    made, and nothing the size of a slot's view is sorted (the router's and
+    the sampler's sorts are over experts and vocabulary)."""
     from django_assistant_bot_tpu.models import mla_moe
 
     monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
     one = SingleDeviceSharding(topo.devices[0])
     cfg, params, cache = _dsa_args(one)
+    assert mla_moe.decode_kv_path(cfg, jnp.bfloat16, PAGE) == "kernel"
     compiled = (
         jax.jit(lambda p, t, c, bt, a: mla_moe.decode_step_paged(p, cfg, t, c, bt, active=a), donate_argnums=(2,))
         .lower(params, _sds((DSA_SLOTS,), jnp.int32, one), cache, _sds((DSA_SLOTS, DSA_SEQ // PAGE), jnp.int32, one),
@@ -669,11 +672,21 @@ def test_sparse_decode_tick_reads_index_keys_and_gathers_only_selected_rows(topo
         .compile()
     )
     text = compiled.as_text()
+    for kernel in ("paged_index_scores", "topk_select", "sparse_latent_decode"):
+        assert kernel in text, kernel
     lm = cfg.latent_moe
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * cfg.num_layers * DSA_PAGES * PAGE * (lm.latent_width + lm.index_head_dim)
     assert mem.temp_size_in_bytes < 0.3e9
     assert _pool_sized_values_made_in_loops(text, _dsa_pool_shapes(cfg)) == []
-    # the selected rows: 8 slots x 2,048 x 640, gathered; never the 16,384 of a slot's whole view
-    assert f"bf16[{DSA_SLOTS},{lm.index_topk},{lm.latent_width}]" in text
-    assert f"bf16[{DSA_SLOTS},{DSA_SEQ},{lm.latent_width}]" not in text
+    NB = DSA_SEQ // PAGE
+    # a slot's view appears only as the [slots, blocks, page] scores and selection: never with a heads or a width axis,
+    # never flat (the plain path's [slots, view] scores, `ok` and top_k), never gathered rows
+    assert f"f32[{DSA_SLOTS},{NB},{PAGE}]" in text and f"s32[{DSA_SLOTS},{NB},{PAGE}]" in text
+    # (bf16[slots, 16384] is the attention's output, heads x value width, on its way into `wo`)
+    assert not re.search(rf"(f32|s32|u32|pred)\[{DSA_SLOTS},{DSA_SEQ}\]", text)
+    assert not re.search(rf"\[{DSA_SLOTS},(1,)?\d+,({DSA_SEQ}|{NB},{PAGE})\]", text)
+    assert not re.search(rf"\[{DSA_SLOTS},({DSA_SEQ}|{NB},{PAGE}|{lm.index_topk}),\d+\]", text)
+    for line in text.splitlines():
+        if re.search(r"\b(sort|topk|top_k|TopK)\b", line):
+            assert str(DSA_SEQ) not in line and f"{NB},{PAGE}" not in line, line

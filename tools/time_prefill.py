@@ -7,6 +7,8 @@ kernel alone, by shape: what sets ``ops/attention.py`` ``flash_tiles``.
     python3 tools/time_prefill.py --config a.x-k1-ep16  # another configuration's programs
     python3 tools/time_prefill.py --flash               # the kernel alone; ~1 minute
     python3 tools/time_prefill.py --trace 1x512,1x1024  # where a program's device time goes
+    python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --decode                 # the decode tick alone
+    python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --decode --trace 2x8192  # ... and by scope
 
 Builds the engine of ``benchmarks/configs/<config>.json`` (default
 ``qwen2.5-7b-instruct``: Qwen2.5-7B int8; the configuration's seeded weights,
@@ -25,6 +27,14 @@ on a v5e's host, 0.09 ms a call of 8) cancel; to ``chiprun_out/time_flash.json``
 calls of each such program and prints its device time by named scope
 (``attn/core``, ``attn/kv_up``, ...) and by operation, ms a call; to
 ``chiprun_out/trace_prefill.json``.
+
+``--decode`` times the decode tick alone (the engine's fused tick of
+``decode_steps`` steps over a seeded pool; greedy) at rows 1 / 2 / all slots x
+contexts of a quarter, a half and seven eighths of ``max_seq_len`` (4k / 8k /
+14k for ``deepseek-v3.2-ep16``): ``{"<rows>x<context>": ms a STEP}`` to
+``chiprun_out/time_decode.<config>.json``; with ``--trace <rows>x<context>[,...]``
+the same ticks by scope and operation, ms a step, to
+``chiprun_out/trace_decode.<config>.json``.
 """
 
 import argparse
@@ -100,30 +110,87 @@ def time_programs(eng: GenerationEngine) -> dict:
     return out
 
 
-def trace_programs(eng: GenerationEngine, shapes: str, trace_dir: str) -> dict:
+def trace_programs(program, shapes: str, trace_dir: str, per: int = 1) -> dict:
     """Device time of each program of ``shapes`` (``1x512,1x1024``) by named
-    scope and by operation, ms a call, from a profiler trace of 3 calls
+    scope and by operation, ms a call (``per`` steps a call: ms a step), from a
+    profiler trace of 3 calls of ``program(rows, size)()``
     (``benchmarks/trace_reduce.py``)."""
     from benchmarks import trace_reduce
 
     def per_call(seconds: dict) -> dict:
-        return {k: round(v / 3 * 1e3, 4) for k, v in sorted(seconds.items(), key=lambda kv: -kv[1])}
+        return {k: round(v / (3 * per) * 1e3, 4) for k, v in sorted(seconds.items(), key=lambda kv: -kv[1])}
 
     out = {}
     for shape in shapes.split(","):
-        rows, bucket = (int(x) for x in shape.split("x"))
-        args = (eng.params, *full_rows(rows, bucket))
+        call = program(*(int(x) for x in shape.split("x")))
         for _ in range(2):
-            jax.block_until_ready(eng._prefill(*args))
+            jax.block_until_ready(call())
         shutil.rmtree(trace_dir, ignore_errors=True)
         jax.profiler.start_trace(trace_dir)
         for _ in range(3):
-            jax.block_until_ready(eng._prefill(*args))
+            jax.block_until_ready(call())
         jax.profiler.stop_trace()
         r = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir))
         out[shape] = {"program_ms": per_call(r["program_s"]), "scope_ms": per_call(r["scope_s"] or {}),
                       "op_ms": dict(list(per_call(r["op_s"]).items())[:24])}
         print(shape, json.dumps(out[shape]), flush=True)
+    return out
+
+
+def prefill_program(eng: GenerationEngine):
+    def program(rows: int, bucket: int):
+        args = (eng.params, *full_rows(rows, bucket))
+        return lambda: eng._prefill(*args)
+
+    return program
+
+
+def decode_program(eng: GenerationEngine):
+    """-> ``program(rows, context)``: the engine's decode tick over ``rows``
+    slots that each hold ``context`` tokens, as a call that runs one tick
+    (``eng.burst`` steps) and keeps the donated cache for the next.  The pools
+    are filled once with seeded values (a zero pool ties every index score);
+    slot ``i`` owns an equal share of the pages; every call starts from ``context``
+    again, so the ticks timed are the same tick."""
+    cache = eng._cache
+    eng._cache = None  # donated below: the engine's own reference must not outlive it
+    keys = iter(jax.random.split(jax.random.key(0), 4))
+    fill = jax.jit(lambda key, like: (jax.random.normal(key, like.shape, jnp.float32) * 0.5).astype(like.dtype))
+    pools = {name: fill(next(keys), pool) for name, pool in cache._asdict().items()
+             if name not in ("lengths", "stats") and pool is not None}
+    state = {"cache": cache._replace(**pools), "rng": eng._rng}
+    B, NB, pages = eng.max_slots, eng.max_seq_len // eng.kv_page_size, cache.n_pages
+    bt = np.full((B, NB), pages, np.int32)
+    per_slot = min(pages // B, NB)
+    for i in range(B):
+        bt[i, :per_slot] = np.arange(i * per_slot, (i + 1) * per_slot)
+    bt = jnp.asarray(bt)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(32, 127, size=(B,)), jnp.int32)
+    temps, top_ps = jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32)
+
+    def program(rows: int, context: int):
+        if context + eng.burst > per_slot * eng.kv_page_size:
+            raise ValueError(f"a slot holds {per_slot} pages: no room for {context} + {eng.burst} tokens")
+        active = jnp.arange(B) < rows
+        lengths = jnp.where(active, context, 0).astype(state["cache"].lengths.dtype)
+
+        def call():
+            cache = state["cache"]._replace(lengths=jnp.copy(lengths))  # the tick donates every leaf it is handed
+            toks, _, state["cache"], state["rng"] = eng._decode_tick(
+                eng.params, tokens, cache, active, bt, temps, top_ps, state["rng"])
+            return toks
+
+        return call
+
+    return program
+
+
+def time_decode(eng: GenerationEngine) -> dict:
+    program, out = decode_program(eng), {}
+    for rows in sorted({1, 2, eng.max_slots}):
+        for context in (eng.max_seq_len // 4, eng.max_seq_len // 2, eng.max_seq_len * 7 // 8):
+            out[f"{rows}x{context}"] = round(median_ms(program(rows, context)) / eng.burst, 4)
+            print(f"{rows}x{context}", out[f"{rows}x{context}"], flush=True)
     return out
 
 
@@ -159,16 +226,25 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", default="qwen2.5-7b-instruct", help="a file of benchmarks/configs/, without .json")
     ap.add_argument("--flash", action="store_true", help="time the flash kernel alone instead of the programs")
+    ap.add_argument("--decode", action="store_true", help="time the decode tick alone, by rows and context, instead of the prefill programs")
     ap.add_argument("--trace", metavar="ROWSxBUCKET[,...]", help="trace these programs instead: device ms a call by scope and operation")
     args = ap.parse_args()
     sut.enable_compile_cache()
     print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
     suffix = ".json" if args.config == "qwen2.5-7b-instruct" else f".{args.config}.json"
+    trace_dir = os.path.join(ROOT, ".cache", "time_prefill_trace")
     if args.flash:
         out, name = time_flash(), "time_flash.json"
+    elif args.decode:
+        eng = build_engine(load_conf(args.config))
+        if args.trace:
+            out = trace_programs(decode_program(eng), args.trace, trace_dir, per=eng.burst)
+        else:
+            out = time_decode(eng)
+        name = ("trace_decode" if args.trace else "time_decode") + suffix
     elif args.trace:
-        trace_dir = os.path.join(ROOT, ".cache", "time_prefill_trace")
-        out, name = trace_programs(build_engine(load_conf(args.config)), args.trace, trace_dir), "trace_prefill" + suffix
+        out = trace_programs(prefill_program(build_engine(load_conf(args.config))), args.trace, trace_dir)
+        name = "trace_prefill" + suffix
     else:
         out, name = time_programs(build_engine(load_conf(args.config))), "time_prefill" + suffix
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
