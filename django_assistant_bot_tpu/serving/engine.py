@@ -346,6 +346,9 @@ class _TickRef:
     # the program's own counters for this tick (a cache that carries `stats`):
     # a device array that arrives with `nxt`, or None
     aux: Any = None
+    # the number of the last dispatch this result covers (LoopLedger.seq where
+    # it was enqueued): when the host has it, all of them have ended
+    seq: int = 0
 
 
 @dataclasses.dataclass
@@ -467,9 +470,9 @@ class GenerationEngine:
         # names its buckets; warm-up compiles them all and admission
         # dispatches no other (tick_stats()["prefill_shapes"] counts each)
         self.prefill_shapes = prefill_shapes(self.chunk_size, self.prefill_wave, prefill_buckets)
-        self._ledger.prefill_shapes = {
-            f"{rows}x{b}": 0 for b, rs in self.prefill_shapes.items() for rows in rs
-        }
+        self._ledger.list_shapes(
+            f"{rows}x{b}" for b, rs in self.prefill_shapes.items() for rows in rs
+        )
         # Decode lookahead pipeline: ticks are issued with the *device* token array
         # chained tick-to-tick (no host value needed), results stream back via
         # copy_to_host_async, and the host processes them `lookahead` ticks behind.
@@ -904,7 +907,6 @@ class GenerationEngine:
             if self.prefill_piggyback and not self.speculative
             else None
         )
-        self._prefill_displaced_ticks = 0
         self._prefill_chunks_piggybacked = 0
         self._activate_fn = self._make_activate(json_mode=False)
         self._activate_fn_json = None  # built in _ensure_fsm
@@ -1799,6 +1801,7 @@ class GenerationEngine:
         decode hot path (dabtlint DABT104 stays at 0 findings)."""
         if not pages:
             return None
+        self._ledger.note_dispatch("spill")
         with self._mesh_scope():
             k, v = self._gather_pages(
                 self._cache, jnp.asarray(list(pages), jnp.int32)
@@ -1933,26 +1936,26 @@ class GenerationEngine:
                     # final chunk always runs sequentially: its logits feed
                     # the activation (first-token sample), which is its own
                     # program.
-                    with span("tick_issue", piggyback=1):
+                    with span("tick_issue", piggyback=1, seq=self._ledger.seq + 1):
                         self._piggyback_step()
                     ticked = True
                 else:
-                    if self.num_active > 0:
-                        # decode waited a full dispatch on this prefill
-                        # chunk — the displacement the piggybacked path
-                        # exists to remove (prefill_displacement_frac)
-                        self._prefill_displaced_ticks += 1
+                    # decode waits this chunk's whole program out: the
+                    # displacement the piggybacked path exists to remove (the
+                    # ledger's `chunk+tick` / `chunk` segments against its
+                    # `piggyback` ones)
                     with span(
                         "prefill_dispatch",
                         bucket=self.chunk_size,
                         rows=1,
                         rows_padded=1,
                         chunk=self._chunking.step,
+                        seq=self._ledger.seq + 1,
                     ):
                         self._chunk_step()
                 admitted = True
             if self.num_active > 0 and not ticked:
-                with span("tick_issue"):
+                with span("tick_issue", seq=self._ledger.seq + 1):
                     self._issue_tick()
             # process results `lookahead` ticks behind; drain fully
             # when no slot is live (remaining in-flight ticks carry
@@ -2042,6 +2045,7 @@ class GenerationEngine:
         self._running = False
         err = RuntimeError("generation engine stopped")
         self._inflight.clear()
+        self._ledger.reset_queue()
         for i, s in enumerate(self._slots):
             if s is not None:
                 _safe_resolve(s.request.future, exc=err)
@@ -2180,6 +2184,7 @@ class GenerationEngine:
             return False
         t0 = self._clock()
         prefix_pages = pages[: ent.pages]
+        self._ledger.note_dispatch("restore")
         with self._mesh_scope():
             self._cache = self._write_pages(
                 self._cache,
@@ -2244,6 +2249,7 @@ class GenerationEngine:
             # it (positions below the prefix length carry the owner's valid
             # prefix K/V; at/above it the clone holds garbage the sharer's
             # suffix prefill overwrites before it is ever unmasked)
+            self._ledger.note_dispatch("cow")
             with self._mesh_scope():
                 self._cache = self._copy_pages(
                     self._cache,
@@ -2650,7 +2656,9 @@ class GenerationEngine:
         reqs = [r for _, r in batch]
         slots = [s for s, _ in batch]
         B = len(batch)
-        with self._ledger.span("prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp):
+        with self._ledger.span(
+            "prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp, seq=self._ledger.seq + 1
+        ):
             pad = Bp - B
             ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
             lengths = np.zeros((Bp,), np.int32)
@@ -2660,6 +2668,7 @@ class GenerationEngine:
                 ids[pad + j, :n] = req.prompt_ids
                 lengths[pad + j] = n
                 slot_arr[pad + j] = slots[j]
+            self._note_wave("prefill", reqs, int(lengths.sum()), bucket, Bp)
             with self._mesh_scope():
                 logits, ks, vs = self._prefill(
                     self.params, jnp.asarray(ids), jnp.asarray(lengths)
@@ -2672,7 +2681,6 @@ class GenerationEngine:
                     jnp.asarray(slot_arr),
                     jnp.asarray(self._wave_block_tables(slots, pad)),
                 )
-            self._note_wave(reqs, int(lengths.sum()), bucket, Bp)
             # a miss with a declared prefix: register its pages for future
             # requests (pure refcounting — admission never blocks on it)
             for slot, req in batch:
@@ -2682,10 +2690,14 @@ class GenerationEngine:
             # otherwise every distinct wave size would trigger fresh compiles
             self._activate_batch(slots, reqs, logits, pad=pad)
 
-    def _note_wave(self, reqs: List[_Request], real: int, bucket: int, Bp: int) -> None:
-        """One prefill program dispatched: the padding counters, and on each
-        request the shape of the program it rode (``usage.timings``)."""
-        self._ledger.note_prefill(real, Bp, bucket)
+    def _note_wave(
+        self, kind: str, reqs: List[_Request], real: int, bucket: int, Bp: int, start: int = 0
+    ) -> None:
+        """One prefill program about to be dispatched (with the insert and the
+        activation that follow it, one group): the ledger's dispatch, which
+        feeds the padding counters, and on each request the shape of the
+        program it rides (``usage.timings``)."""
+        self._ledger.note_dispatch(kind, Bp, bucket, real, start)
         for req in reqs:
             req.prefill_bucket = bucket
             req.wave_rows = len(reqs)
@@ -2704,7 +2716,8 @@ class GenerationEngine:
         hits = [h for _, _, h in group]
         B = len(group)
         with self._ledger.span(
-            "prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp, suffix=1
+            "prefill_dispatch", bucket=bucket, rows=B, rows_padded=Bp, suffix=1,
+            seq=self._ledger.seq + 1,
         ):
             pad = Bp - B
             ids = np.full((Bp, bucket), self.tokenizer.pad_id, np.int32)
@@ -2723,6 +2736,10 @@ class GenerationEngine:
                 starts[pad + j] = start
                 valids[pad + j] = len(chunk)
                 slot_arr[pad + j] = slots[j]
+            self._note_wave(
+                "suffix", reqs, sum(len(r.prompt_ids) - h.length for r, h in zip(reqs, hits)),
+                bucket, Bp,
+            )
             with self._mesh_scope():
                 logits, self._cache = self._prefill_suffix(
                     self.params,
@@ -2733,9 +2750,6 @@ class GenerationEngine:
                     jnp.asarray(starts),
                     jnp.asarray(valids),
                 )
-            self._note_wave(
-                reqs, sum(len(r.prompt_ids) - h.length for r, h in zip(reqs, hits)), bucket, Bp
-            )
             # a hit whose DECLARED split extends past the matched prefix (multi-turn:
             # the history grew) registers the longer prefix for the next turn
             for slot, req in zip(slots, reqs):
@@ -2777,17 +2791,18 @@ class GenerationEngine:
             request=req, slot=slot, ids=ids, starts=starts, n=n
         )
 
-    def _note_chunk(self, st: "_ChunkedPrefill", j: int) -> None:
-        """Chunk ``j`` dispatched (alone or inside a tick): one row of
-        ``chunk_size``; the sliding last chunk re-feeds what it overlaps."""
+    def _note_chunk(self, kind: str, st: "_ChunkedPrefill", j: int) -> None:
+        """Chunk ``j`` about to be dispatched (alone, ``chunk``, or inside a
+        tick, ``piggyback``): one row of ``chunk_size``; the sliding last
+        chunk re-feeds what it overlaps."""
         new = self.chunk_size if j == 0 else st.starts[j] - st.starts[j - 1]
-        self._note_wave([st.request], new, self.chunk_size, 1)
+        self._note_wave(kind, [st.request], new, self.chunk_size, 1, st.starts[j])
 
     def _chunk_step(self):
         st = self._chunking
         assert st is not None
         j = st.step
-        self._note_chunk(st, j)
+        self._note_chunk("chunk", st, j)
         with self._mesh_scope():
             logits, self._cache = self._prefill_chunk(
                 self.params,
@@ -2855,7 +2870,7 @@ class GenerationEngine:
         self._refresh_sampling()
         self._decode_steps_effective = self.burst
         j = st.step
-        self._note_chunk(st, j)
+        self._note_chunk("piggyback", st, j)
         with self._mesh_scope():
             toks, last, self._cache, self._rng = self._piggyback_tick(
                 self.params,
@@ -2879,7 +2894,9 @@ class GenerationEngine:
         live = [
             (i, self._slot_epoch[i]) for i, s in enumerate(self._slots) if s is not None
         ]
-        self._inflight.append(_TickRef(nxt=toks, slots=live, aux=self._take_aux()))
+        self._inflight.append(
+            _TickRef(nxt=toks, slots=live, aux=self._take_aux(), seq=self._ledger.seq)
+        )
         st.step += 1
         self._prefill_chunks_piggybacked += 1
         # the same mid-prefill reaping as _chunk_step (the decode side of the
@@ -2973,7 +2990,7 @@ class GenerationEngine:
         self._sampling_dirty = True
         first.copy_to_host_async()
         self._inflight.append(
-            _TickRef(nxt=first, slots=ref_slots, first=True, offset=pad)
+            _TickRef(nxt=first, slots=ref_slots, first=True, offset=pad, seq=self._ledger.seq)
         )
 
     def _upload_dirty(self) -> bool:
@@ -3077,21 +3094,31 @@ class GenerationEngine:
             out["sched"] = self.scheduler.stats()
         return out
 
-    def loop_stats(self) -> dict:
+    def loop_stats(self, recent: bool = False) -> dict:
         """The engine-loop time ledger (serving/obs.py ``LoopLedger``), as
         running totals: ``loop`` = ``{phase: {"s": exclusive seconds, "n":
         spans}}`` over the engine thread's whole life (the phases tile its
-        wall time), and the prefill positions run: prompt tokens (``real``)
-        against rows x bucket of the dispatched programs (``padded``), and
-        the dispatches by shape (``prefill_shapes``: ``"<rows>x<bucket>"`` ->
-        count, every warmed shape listed; a chunk counts as 1 x chunk)."""
+        wall time); ``device_queue`` = the device's time by what its queue
+        held between two results the host waited for
+        (``LoopLedger.queue_snapshot``: segments by kind and shape, the time
+        the queue stood empty by loop phase, markers waited for or not); and
+        the prefill positions run: prompt tokens (``real``) against rows x
+        bucket of the dispatched programs (``padded``), and the dispatches by
+        shape (``prefill_shapes``: ``"<rows>x<bucket>"`` -> count, every
+        warmed shape listed; a chunk counts as 1 x chunk).  ``recent=True``
+        adds ``device_queue_recent``: the last 512 segments one by one, on
+        the engine's clock (to hold against a trace, or after a stall)."""
         led = self._ledger
-        return {
+        out = {
             "loop": led.snapshot(),
+            "device_queue": led.queue_snapshot(),
             "prefill_tokens_real": led.prefill_tokens_real,
             "prefill_tokens_padded": led.prefill_tokens_padded,
             "prefill_shapes": dict(led.prefill_shapes),
         }
+        if recent:
+            out["device_queue_recent"] = led.recent_segments()
+        return out
 
     def decode_path_stats(self) -> dict:
         """Decode fast-path gauges for tick_stats / /healthz / /metrics:
@@ -3109,15 +3136,12 @@ class GenerationEngine:
             "upload_overlap_frac": self.upload_overlap_frac(),
             "weight_bits": self.weight_bits,
             # continuous batching (docs/SCHEDULING.md "Continuous batching"):
-            # is the piggyback program armed, how many chunks rode a decode
-            # tick, and what fraction of dispatches decode still spent
-            # waiting on a sequential prefill chunk — the displacement the
-            # tentpole removes (0.0 with piggyback on and no json traffic)
+            # is the piggyback program armed, and how many chunks rode a
+            # decode tick (what decode still waits out on sequential chunks
+            # is device time: tick_stats()["device_queue"] `chunk+tick` /
+            # `chunk` against `piggyback`)
             "prefill_piggyback": bool(self._piggyback_tick is not None),
             "prefill_chunks_piggybacked": self._prefill_chunks_piggybacked,
-            "prefill_displacement_frac": round(
-                self._prefill_displaced_ticks / max(1, self._ticks_issued), 4
-            ),
             # fp8 in-dot attention (docs/QUANT.md): whether the decode
             # attention dots read the KV operand at fp8 storage width
             "attn_fp8": self.attn_fp8,
@@ -3522,6 +3546,7 @@ class GenerationEngine:
             # whole batch rides the single-step json program this tick
             self._json_downgraded_ticks += 1
         self._decode_steps_effective = issued_steps
+        self._ledger.note_dispatch("tick")
         with self._mesh_scope():
             if json_live:
                 toks, last, self._cache, self._rng, self._fsm_states_dev = (
@@ -3559,7 +3584,9 @@ class GenerationEngine:
         live = [
             (i, self._slot_epoch[i]) for i, s in enumerate(self._slots) if s is not None
         ]
-        self._inflight.append(_TickRef(nxt=toks, slots=live, aux=self._take_aux()))
+        self._inflight.append(
+            _TickRef(nxt=toks, slots=live, aux=self._take_aux(), seq=self._ledger.seq)
+        )
 
     def _issue_spec_tick(self, rung: tuple):
         """Dispatch one fused tree-speculative tick at the controller's
@@ -3567,6 +3594,7 @@ class GenerationEngine:
         device, chained state — same pipelining discipline as the burst
         tick, but each of its ``decode_steps`` scanned verify steps advances
         a variable 1..depth+1 tokens/slot)."""
+        self._ledger.note_dispatch("spec")
         with self._mesh_scope():
             toks, n_new, last, self._history_dev, self._cache, self._rng = (
                 self._spec_ticks[rung](
@@ -3593,7 +3621,7 @@ class GenerationEngine:
             (i, self._slot_epoch[i]) for i, s in enumerate(self._slots) if s is not None
         ]
         self._inflight.append(
-            _TickRef(nxt=toks, slots=live, n_new=n_new, spec_rung=rung)
+            _TickRef(nxt=toks, slots=live, n_new=n_new, spec_rung=rung, seq=self._ledger.seq)
         )
 
     def _process_tick(self):
@@ -3601,11 +3629,14 @@ class GenerationEngine:
         ref = self._inflight.popleft()
         led = self._ledger
         blocked = led.seconds("tick_block")
-        with led.span("tick_block"):
+        with led.span("tick_block", seq=ref.seq):
             vals = np.asarray(ref.nxt)
             if ref.aux is not None:  # same program as `nxt`: already here
                 aux = np.asarray(ref.aux).astype(np.int64)
                 self._moe_totals = aux if self._moe_totals is None else self._moe_totals + aux
+        # the result is here, so every program up to ref.seq has ended on the
+        # device: the ledger's device half closes a segment at this stamp
+        led.note_marker(ref.seq)
         with led.span("consume"):
             try:
                 self._process_tick_inner(ref, vals, led.seconds("tick_block") - blocked)
@@ -3968,6 +3999,7 @@ class GenerationEngine:
             salvage.append(self._chunking.request)
             self._chunking = None
         self._inflight.clear()
+        self._ledger.reset_queue()
         for i, s in enumerate(self._slots):
             if s is not None:
                 if s.generated:

@@ -59,7 +59,7 @@ import tempfile
 import threading
 import time
 import uuid
-from typing import Any, Callable, Dict, List, Mapping, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Mapping, Optional, Tuple
 
 logger = logging.getLogger(__name__)
 
@@ -474,19 +474,52 @@ LOOP_PHASES: Tuple[str, ...] = (
     "recover",           # crash-only restart after an engine-fatal error
 )
 TRACE_PREFIX = "dabt/"
+# The device-queue half of the ledger (LoopLedger.note_dispatch / note_marker).
+# The wait for a result ends when its program ends on the device; a wait
+# shorter than this found the result already there, so its end says only "no
+# later than": such a marker closes no segment.
+MARKER_PHASE = "tick_block"
+MARKER_WAIT_MIN_S = 100e-6
+QUEUE_RING = 512  # segments kept for loop_stats(recent=True)
+# what a dispatch advances: decode rows, a prompt, or (piggyback) both; any
+# other kind (a page clone, a host-tier restore or spill) rides with the
+# programs around it and names no segment
+TICK_KINDS = frozenset(("tick", "piggyback", "spec"))
+PREFILL_KINDS = frozenset(("prefill", "suffix", "chunk", "piggyback"))
+QUEUE_FIELDS = ("s", "n", "ticks", "groups", "tokens", "start_tokens", "lag_s", "lag_n")
+
+
+def _zero_totals() -> List[float]:
+    """One segment key's running totals, in :data:`QUEUE_FIELDS`' order."""
+    return [0.0, 0, 0, 0, 0, 0, 0.0, 0]
+
+
+def _segment_key(held: List[tuple]) -> str:
+    """What a segment is filed under: a single tick, chunk or group by its
+    kind (a group by its shape too), a chunk with the tick behind it, and
+    ``mixed`` for anything else.  Riders (``cow``, ``restore``, ``spill``)
+    do not count."""
+    main = [d for d in held if d[1] in TICK_KINDS or d[1] in PREFILL_KINDS]
+    if len(main) == 1:
+        _, kind, shape, _, _, _ = main[0]
+        return f"{kind}:{shape}" if kind in ("prefill", "suffix") else kind
+    if [d[1] for d in main] == ["chunk", "tick"]:
+        return "chunk+tick"
+    return "mixed"
 
 
 class _PhaseSpan:
     """One phase's reusable enter/exit pair (phases never nest in themselves,
     so the hot path allocates nothing but the profiler's own annotation)."""
 
-    __slots__ = ("_ledger", "name", "_label", "_t0", "_args", "_ann", "s", "n")
+    __slots__ = ("_ledger", "name", "_label", "_t0", "_t1", "_args", "_ann", "s", "n")
 
     def __init__(self, ledger: "LoopLedger", name: str):
         self._ledger = ledger
         self.name = name
         self._label = TRACE_PREFIX + name
         self._t0 = 0.0
+        self._t1 = 0.0  # when it last closed
         self._args: Optional[dict] = None
         self._ann: Any = None
         self.s = 0.0  # exclusive seconds
@@ -513,6 +546,7 @@ class _PhaseSpan:
         led = self._ledger
         now = led._clock()
         self.s += now - self._t0
+        self._t1 = now
         self.n += 1
         stack = led._stack
         stack.pop()
@@ -522,7 +556,8 @@ class _PhaseSpan:
 
 
 class LoopLedger:
-    """Where the engine thread's time went, by phase (see :data:`LOOP_PHASES`).
+    """Where the engine thread's time went, by phase (see :data:`LOOP_PHASES`),
+    and where the device's went, by what its queue held.
 
     ``with ledger.span("tick_issue"):`` costs two reads of the injectable
     clock and one flag test.  While a ``jax.profiler`` session runs, the
@@ -531,11 +566,28 @@ class LoopLedger:
     (nested there, exclusive here).  Written by the engine thread only;
     :meth:`snapshot` may be read from any thread (each total is one float).
 
-    Also carries the prefill padding counters: tokens the prompts held
-    (``real``) against rows x bucket of the programs that ran them
-    (``padded``), and the dispatches by shape (``prefill_shapes``, keyed
-    ``"<rows>x<bucket>"``; the engine lists every shape it warms at 0, so the
-    keys never change under a reader)."""
+    **The device's queue.**  The device runs one program at a time in the
+    order enqueued, and the engine reads one small result per tick and per
+    admission wave, in that order, and waits for nearly every one.  So
+    :meth:`note_dispatch` (where a program is enqueued: a running number
+    ``seq`` and a stamp) and :meth:`note_marker` (where the wait for a result
+    ended) cut the device's time into **segments**: the dispatches between
+    two results waited for, from ``max(previous result ready, first of them
+    enqueued)`` to ``this result ready``.  Running totals by what a segment
+    held (:meth:`queue_snapshot`): ``tick``, ``piggyback``, ``spec``,
+    ``chunk`` (one program alone), ``prefill:<rows>x<bucket>`` /
+    ``suffix:<rows>x<bucket>`` (one group alone: the program, its insert, its
+    activation), ``chunk+tick`` and ``mixed`` for anything else; the time
+    the queue stood empty, by the loop phase the engine thread spent it in;
+    and the markers by whether they were waited for.  No profiler, no sync,
+    no transfer: one clock read a dispatch, none a marker.
+
+    Also carries the prefill padding counters, fed by the same dispatches:
+    tokens the prompts held (``real``) against rows x bucket of the programs
+    that ran them (``padded``), and the dispatches by shape
+    (``prefill_shapes``, keyed ``"<rows>x<bucket>"``; :meth:`list_shapes`
+    lists every shape the engine warms at 0, so no key appears under a
+    reader)."""
 
     def __init__(
         self,
@@ -552,19 +604,115 @@ class LoopLedger:
         self.prefill_tokens_real = 0
         self.prefill_tokens_padded = 0
         self.prefill_shapes: Dict[str, int] = {}
+        # -- the device's queue
+        self.seq = 0  # the last dispatch's number
+        # dispatches no waited marker has covered yet, oldest first:
+        # (seq, kind, shape, tokens, start, enqueue stamp)
+        self._pending: "collections.deque[tuple]" = collections.deque()
+        self._ready: Optional[float] = None  # when the last closing marker's result was there
+        # the phase totals at that moment, kept only while nothing is pending
+        # (only then can the next dispatch find the queue empty)
+        self._ready_totals: Optional[Dict[str, float]] = None
+        self._queue: Dict[str, List[float]] = {
+            k: _zero_totals() for k in ("tick", "piggyback", "spec", "chunk", "chunk+tick", "mixed")
+        }
+        self._idle_s = 0.0
+        self._idle_n = 0
+        self._idle_by_phase: Dict[str, float] = {p: 0.0 for p in phases}
+        self._markers = [0, 0]  # waited, not waited
+        self._recent: "collections.deque[tuple]" = collections.deque(maxlen=QUEUE_RING)
 
     def span(self, name: str, **args: Any) -> _PhaseSpan:
         """The phase's enter/exit pair; ``args`` become the profiler
-        annotation's arguments (bucket, rows, ...) when a session runs."""
+        annotation's arguments (bucket, rows, seq, ...) when a session runs."""
         sp = self._spans[name]
         sp._args = args or None
         return sp
 
-    def note_prefill(self, real: int, rows_padded: int, bucket: int) -> None:
-        self.prefill_tokens_real += int(real)
-        self.prefill_tokens_padded += int(rows_padded) * int(bucket)
-        shape = f"{rows_padded}x{bucket}"
-        self.prefill_shapes[shape] = self.prefill_shapes.get(shape, 0) + 1
+    def list_shapes(self, shapes: Iterable[str]) -> None:
+        """Every ``"<rows>x<bucket>"`` the engine warms, listed at 0."""
+        for shape in shapes:
+            self.prefill_shapes.setdefault(shape, 0)
+            for kind in ("prefill", "suffix"):
+                self._queue.setdefault(f"{kind}:{shape}", _zero_totals())
+
+    def note_dispatch(
+        self, kind: str, rows: int = 0, bucket: int = 0, tokens: int = 0, start: int = 0
+    ) -> int:
+        """A program (or a group: prefill + insert + activation) is about to
+        be enqueued.  ``rows`` x ``bucket`` is a prefill program's shape,
+        ``tokens`` the prompt tokens it really runs, ``start`` a chunk's start
+        position.  Returns the dispatch's ``seq``."""
+        now = self._clock()
+        self.seq = seq = self.seq + 1
+        shape = ""
+        if kind in PREFILL_KINDS:
+            shape = f"{rows}x{bucket}"
+            self.prefill_tokens_real += int(tokens)
+            self.prefill_tokens_padded += int(rows) * int(bucket)
+            self.prefill_shapes[shape] = self.prefill_shapes.get(shape, 0) + 1
+        if not self._pending and self._ready_totals is not None:
+            # every earlier program is known to have ended: the queue stood
+            # empty since then, while the engine thread was in these phases
+            before, self._ready_totals = self._ready_totals, None
+            self._idle_s += now - self._ready
+            self._idle_n += 1
+            by = self._idle_by_phase
+            for p, s in self._totals_at(now).items():
+                by[p] += s - before[p]
+        self._pending.append((seq, kind, shape, int(tokens), int(start), now))
+        return seq
+
+    def note_marker(self, seq: int) -> None:
+        """The wait for a result has just ended (the :data:`MARKER_PHASE` span
+        closed): every dispatch up to ``seq`` has ended on the device.  If the
+        host did wait, that is when, and the dispatches since the last such
+        moment become one segment."""
+        sp = self._spans[MARKER_PHASE]
+        ready = sp._t1
+        if ready - sp._t0 < MARKER_WAIT_MIN_S:
+            self._markers[1] += 1
+            return
+        self._markers[0] += 1
+        pending = self._pending
+        held = []
+        while pending and pending[0][0] <= seq:
+            held.append(pending.popleft())
+        if held:
+            begin = held[0][5] if self._ready is None else max(self._ready, held[0][5])
+            key = _segment_key(held)
+            tot = self._queue.get(key)
+            if tot is None:  # a shape nobody listed
+                tot = self._queue[key] = _zero_totals()
+            tot[0] += ready - begin
+            tot[1] += 1
+            for _, kind, _, tokens, start, enq in held:
+                tot[2] += kind in TICK_KINDS
+                if kind in PREFILL_KINDS:
+                    tot[3] += 1
+                    tot[4] += tokens
+                    tot[5] += start
+                    tot[6] += max(0.0, begin - enq)
+                    tot[7] += 1
+            self._recent.append(
+                (held[0][0], held[-1][0], key, tuple(d[1] for d in held),
+                 tuple(d[2] for d in held), begin, ready)
+            )
+        self._ready = ready
+        self._ready_totals = None if pending else self._totals_at(ready)
+
+    def reset_queue(self) -> None:
+        """The programs in flight are gone (a crash-only restart): what was
+        pending closes nothing, and no idle time is charged across the gap."""
+        self._pending.clear()
+        self._ready = self._ready_totals = None
+
+    def _totals_at(self, now: float) -> Dict[str, float]:
+        tot = {p: sp.s for p, sp in self._spans.items()}
+        if self._stack:
+            top = self._stack[-1]
+            tot[top.name] += now - top._t0
+        return tot
 
     def seconds(self, name: str) -> float:
         return self._spans[name].s
@@ -572,6 +720,28 @@ class LoopLedger:
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         """``{phase: {"s": exclusive seconds, "n": spans closed}}``."""
         return {p: {"s": sp.s, "n": sp.n} for p, sp in self._spans.items()}
+
+    def queue_snapshot(self) -> Dict[str, Any]:
+        """The device-queue totals: ``{key: {"s", "n", "ticks", "groups",
+        "tokens", "start_tokens", "lag_s", "lag_n"}}`` by what a segment held
+        (``lag_s``: over its prefill dispatches, segment start - enqueue: what
+        was queued in front), ``idle`` (the queue empty: seconds, times, and
+        seconds by loop phase) and ``markers`` (results waited for or not)."""
+        out: Dict[str, Any] = {
+            k: dict(zip(QUEUE_FIELDS, v)) for k, v in list(self._queue.items())
+        }
+        out["idle"] = {"s": self._idle_s, "n": self._idle_n, "by_phase": dict(self._idle_by_phase)}
+        out["markers"] = {"waited": self._markers[0], "not_waited": self._markers[1]}
+        return out
+
+    def recent_segments(self) -> List[Dict[str, Any]]:
+        """The last :data:`QUEUE_RING` segments, oldest first, on the
+        ledger's clock."""
+        return [
+            {"seq": [lo, hi], "key": key, "kinds": list(kinds), "shapes": list(shapes),
+             "start": begin, "ready": ready}
+            for lo, hi, key, kinds, shapes, begin, ready in list(self._recent)
+        ]
 
 
 # --------------------------------------------------------- per-request timings
@@ -924,6 +1094,23 @@ def render_prometheus(registry: Any) -> str:
             x.add("dabt_prefill_tokens_total", "counter", "prefill positions: prompt tokens run (real) vs rows x bucket of the programs (padded)", ls["prefill_tokens_padded"], {**lab, "kind": "padded"})
             for shape, n in ls["prefill_shapes"].items():
                 x.add("dabt_prefill_programs_total", "counter", "prefill programs dispatched, by rows x bucket (every warmed shape is listed)", n, {**lab, "shape": shape})
+            # the device's half: its time by what the queue held between two
+            # results the host waited for, and the time the queue stood empty
+            dq = ls["device_queue"]
+            lag_s = lag_n = 0.0
+            for key, tot in dq.items():
+                if key in ("idle", "markers"):
+                    continue
+                kind, _, shape = key.partition(":")
+                klab = {**lab, "kind": kind, "shape": shape}
+                x.add("dabt_device_queue_seconds_total", "counter", "device seconds by what its queue held between two results the host waited for", tot["s"], klab)
+                x.add("dabt_device_queue_segments_total", "counter", "such segments closed, by what they held", tot["n"], klab)
+                lag_s += tot["lag_s"]
+                lag_n += tot["lag_n"]
+            for phase, sec in dq["idle"]["by_phase"].items():
+                x.add("dabt_device_queue_idle_seconds_total", "counter", "seconds the device's queue stood empty, by the loop phase the engine thread spent them in", sec, {**lab, "phase": phase})
+            x.add("dabt_prefill_start_lag_seconds_total", "counter", "enqueue to start on the device, summed over prefill dispatches (what was queued in front)", lag_s, lab)
+            x.add("dabt_prefill_start_lag_dispatches_total", "counter", "prefill dispatches whose start lag was summed", lag_n, lab)
         moe_fn = getattr(eng, "moe_stats", None)
         moe = moe_fn() if callable(moe_fn) else None
         if moe:
@@ -964,9 +1151,8 @@ def render_prometheus(registry: Any) -> str:
             x.add("dabt_upload_overlap_frac", "gauge", "sampling/block-table upload cycles overlapped with an in-flight tick", dec.get("upload_overlap_frac"), lab)
             x.add("dabt_weight_bits", "gauge", "decode weight format width in bits (16/8/4)", dec.get("weight_bits"), lab)
             # continuous batching (docs/QUANT.md "Continuous batching"):
-            # how often decode still waits on a sequential prefill chunk,
-            # and how many chunks rode inside fused ticks instead
-            x.add("dabt_prefill_displacement_frac", "gauge", "fraction of decode ticks displaced by a sequential prefill chunk", dec.get("prefill_displacement_frac"), lab)
+            # how many chunks rode inside fused ticks (what decode waits out
+            # on the others is dabt_device_queue_seconds_total{kind="chunk+tick"})
             x.add("dabt_prefill_chunks_piggybacked_total", "counter", "prefill chunks run inside a fused decode tick", dec.get("prefill_chunks_piggybacked"), lab)
             x.add("dabt_prefill_piggyback", "gauge", "piggybacked-prefill program compiled for this engine", dec.get("prefill_piggyback"), lab)
             x.add("dabt_attn_fp8", "gauge", "fp8 in-dot decode attention engaged", dec.get("attn_fp8"), lab)

@@ -52,8 +52,9 @@ class ModelSpec:
     # chunked prefill piggybacked into the fused decode tick (continuous
     # batching): while one slot is mid-chunked-prefill, each dispatch runs
     # ONE bounded prefill chunk AND the full N-step decode scan for resident
-    # slots, so a long admit no longer displaces decode ticks
-    # (prefill_displacement_frac in tick_stats).  Token-identical to the
+    # slots, so a long admit no longer displaces decode ticks (the
+    # `piggyback` segments of tick_stats()["device_queue"] against its
+    # `chunk+tick` ones).  Token-identical to the
     # sequential path; False keeps sequential chunking (and one prefill
     # program per bucket to compile: a.x-k1-ep16 boots with it).
     prefill_piggyback: bool = True

@@ -1403,18 +1403,11 @@ class EngineRouter:
                 sum(p["upload_overlap_frac"] for p in per) / len(per), 4
             ),
             "weight_bits": per[0]["weight_bits"],
-            # continuous batching: the fleet displacement fraction is the
-            # mean (every replica ticks at roughly the same rate), chunks
-            # piggybacked is a plain counter sum; the feature flags are
-            # uniform by construction
+            # continuous batching: chunks piggybacked is a plain counter
+            # sum; the feature flags are uniform by construction
             "prefill_piggyback": per[0].get("prefill_piggyback", False),
             "prefill_chunks_piggybacked": sum(
                 p.get("prefill_chunks_piggybacked", 0) for p in per
-            ),
-            "prefill_displacement_frac": round(
-                sum(p.get("prefill_displacement_frac", 0.0) for p in per)
-                / len(per),
-                4,
             ),
             "attn_fp8": per[0].get("attn_fp8", False),
             "decode_kv_path": per[0].get("decode_kv_path", "xla"),

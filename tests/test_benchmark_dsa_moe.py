@@ -78,7 +78,11 @@ def test_the_cell_joins_the_metrics_the_issue_names_and_no_other():
 
     bench = _bench()
     names = [m["name"] for m in run.metrics_for(bench, "per_layer", CELL)]
-    assert len(names) == 19 and set(READERS) <= set(names) and "decode_step_dev_ms" in names and "stream_lag_ms_p99" in names
+    assert len(names) == 24 and set(READERS) <= set(names) and "decode_step_dev_ms" in names and "stream_lag_ms_p99" in names
+    # PR 42's readers of the device-queue ledger: the five this cell lists (its tick rides behind a chunk, so no
+    # `decode_step_ms_window`; it runs no one-shot prefill, so no `prefill_ms_per_ktok_window`)
+    assert names[-5:] == ["prefill_dev_share_window", "prefill_chunk_ms_window", "prefill_start_lag_ms",
+                          "device_queue_idle_share", "device_queue_observed_share"]
     assert not {"prefill_dev_ms_per_ktok", "mla_decode_roofline", "mla_moe_decode_step_roofline", "moe_experts_roofline"} & set(names)
     # `out_tok_per_s` is not this cell's: 96 tokens x the requests a 51 s window happens to prefill (40-44 of 4k-14k
     # tokens) spread 6.6% over six seeds against the 2% that admits a metric (PERF.md section 6), so the cell is judged

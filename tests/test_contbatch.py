@@ -71,12 +71,30 @@ def _crank(eng, futs, iters=600):
 LONG_PROMPT = list(range(1, 41))  # 40 ids > chunk_size=16 -> 3 prefill chunks
 
 
+class _TickingClock:
+    """A millisecond passes at every reading: each wait for a result is then
+    longer than the ledger's threshold, so every marker closes a segment and
+    the device-queue seconds count clock reads, the same in every run."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def __call__(self):
+        self.t += 1e-3
+        return self.t
+
+    def sleep(self, dt):
+        self.t += dt
+
+
 def _ab_run(cfg, params, piggyback, **kw):
     """Two ragged resident slots (one greedy, one sampled) decode while a
     40-token prompt admits through chunked prefill; returns every request's
-    token ids plus the decode-path gauges."""
+    token ids plus the decode-path gauges and the ledger's device queue."""
+    clk = _TickingClock()
     eng = _lockstep(
-        _engine(cfg, params, prefill_piggyback=piggyback, decode_steps=2, **kw)
+        _engine(cfg, params, prefill_piggyback=piggyback, decode_steps=2,
+                clock=clk, sleep=clk.sleep, **kw)
     )
     futs = [
         eng.submit(list(range(3, 12)), max_tokens=20, temperature=0.0),
@@ -87,7 +105,7 @@ def _ab_run(cfg, params, piggyback, **kw):
     futs.append(eng.submit(LONG_PROMPT, max_tokens=6, temperature=0.7))
     _crank(eng, futs)
     out = [f.result(timeout=10).token_ids for f in futs]
-    dec = eng.decode_path_stats()
+    dec = dict(eng.decode_path_stats(), device_queue=eng.loop_stats()["device_queue"])
     eng.stop(drain_timeout_s=10.0)
     return out, dec
 
@@ -118,9 +136,17 @@ def test_piggybacked_prefill_bit_identical_to_sequential(tiny, kw):
     assert dec_on["prefill_chunks_piggybacked"] >= 2  # all but the final chunk
     assert dec_off["prefill_piggyback"] is False
     assert dec_off["prefill_chunks_piggybacked"] == 0
-    # the sequential path displaced decode ticks; the piggybacked one
-    # displaced strictly fewer (only the final, activation-feeding chunk)
-    assert dec_off["prefill_displacement_frac"] > dec_on["prefill_displacement_frac"]
+    # the sequential path displaced decode ticks (a chunk's whole program in
+    # front of a tick: the ledger's `chunk+tick` segments, and `chunk` for the
+    # final, activation-feeding one); the piggybacked one only by that last
+    # chunk, the others rode a tick (`piggyback` segments)
+    q_on, q_off = dec_on["device_queue"], dec_off["device_queue"]
+    displaced = lambda q: q["chunk+tick"]["s"] + q["chunk"]["s"]
+    assert displaced(q_off) > displaced(q_on) > 0
+    assert q_off["chunk+tick"]["n"] >= 2 and q_off["piggyback"]["n"] == 0
+    assert q_on["chunk+tick"]["n"] == 0 and q_on["chunk"]["n"] == 1
+    assert q_on["piggyback"]["n"] == dec_on["prefill_chunks_piggybacked"]
+    assert q_on["markers"]["not_waited"] == q_off["markers"]["not_waited"] == 0
 
 
 def test_piggyback_gauges_and_knob_defaults(tiny):
@@ -130,7 +156,10 @@ def test_piggyback_gauges_and_knob_defaults(tiny):
     dec = eng.decode_path_stats()
     assert dec["prefill_piggyback"] is True
     assert dec["prefill_chunks_piggybacked"] == 0
-    assert dec["prefill_displacement_frac"] == 0.0
+    # nothing dispatched yet: no chunk displaced a tick, none rode one; the
+    # ledger lists the keys from the start
+    dq = eng.loop_stats()["device_queue"]
+    assert dq["chunk+tick"]["s"] == dq["chunk"]["s"] == dq["piggyback"]["s"] == 0.0
     assert dec["attn_fp8"] is False
     eng.stop(drain_timeout_s=5.0)
     # speculative engines never piggyback (the spec tick has its own shape)

@@ -352,7 +352,8 @@ def test_dabt104_obs_recorder_entry_points_are_roots(tmp_path):
         ("serving/obs.py", "*_PhaseSpan.__exit__"),
         ("serving/obs.py", "*LoopLedger.span"),
         ("serving/obs.py", "*LoopLedger.seconds"),
-        ("serving/obs.py", "*LoopLedger.note_prefill"),
+        ("serving/obs.py", "*LoopLedger.note_dispatch"),
+        ("serving/obs.py", "*LoopLedger.note_marker"),
         ("serving/server.py", "*_StreamLag.note"),
     ],
 )

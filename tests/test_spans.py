@@ -41,7 +41,11 @@ from django_assistant_bot_tpu.serving import (
 from django_assistant_bot_tpu.serving.engine import plan_prefill
 from django_assistant_bot_tpu.serving.fleet import FleetResult
 from django_assistant_bot_tpu.serving.obs import (
+    _SPAN_KEYS,
     LOOP_PHASES,
+    MARKER_WAIT_MIN_S,
+    QUEUE_FIELDS,
+    QUEUE_RING,
     TIMING_KEYS,
     LoopLedger,
     request_spans,
@@ -118,8 +122,11 @@ def test_ledger_nested_spans_are_exclusive_and_tile():
     assert snap["prefill_dispatch"] == {"s": 5.0, "n": 1}
     assert snap["tick_issue"]["s"] == 2.0
     assert sum(v["s"] for v in snap.values()) == clk() - t0
-    led.note_prefill(330, 4, 256)
+    led.note_dispatch("prefill", 4, 256, 330)
     assert (led.prefill_tokens_real, led.prefill_tokens_padded) == (330, 1024)
+    assert led.prefill_shapes == {"4x256": 1}
+    led.note_dispatch("tick")  # a decode program pads nothing
+    assert (led.prefill_tokens_real, led.prefill_tokens_padded, led.seq) == (330, 1024, 2)
 
 
 def test_ledger_span_closes_on_exception_and_parent_resumes():
@@ -134,6 +141,139 @@ def test_ledger_span_closes_on_exception_and_parent_resumes():
         clk.burn(1.0)
     assert led.seconds("tick_issue") == 2.0 and led.seconds("consume") == 2.0
     assert not led._stack
+
+
+# ------------------------------------------------- the device's queue: units
+def _queue_ledger():
+    clk = _Clock()
+    led = LoopLedger(clk, annotation=_NoAnnotation)
+    led.list_shapes(["2x128", "1x64"])
+    return clk, led
+
+
+def _result(led, clk, seq, wait_s):
+    """What ``_process_tick`` does with a result: the wait, then the marker."""
+    with led.span("tick_block", seq=seq):
+        clk.burn(wait_s)
+    led.note_marker(seq)
+
+
+def _nonzero(queue):
+    return {k: v for k, v in queue.items() if k not in ("idle", "markers") and v["n"]}
+
+
+def test_device_queue_lists_every_key_at_zero_before_anything_ran():
+    _, led = _queue_ledger()
+    q = led.queue_snapshot()
+    assert set(q) == {"tick", "piggyback", "spec", "chunk", "chunk+tick", "mixed", "prefill:2x128", "suffix:2x128",
+                      "prefill:1x64", "suffix:1x64", "idle", "markers"}
+    assert all(q[k] == dict.fromkeys(QUEUE_FIELDS, 0) for k in q if k not in ("idle", "markers"))
+    assert q["idle"] == {"s": 0.0, "n": 0, "by_phase": dict.fromkeys(LOOP_PHASES, 0.0)}
+    assert q["markers"] == {"waited": 0, "not_waited": 0} and led.recent_segments() == []
+
+
+def test_device_queue_scripted_sequence_gives_exactly_these_segments():
+    """tick, tick, a 2 x 128 prefill group with its first-token result, a
+    chunk with no result of its own, a tick: the queue is never empty, so a
+    segment runs from the result before it to its own."""
+    clk, led = _queue_ledger()
+    t0 = clk.t
+    a = led.note_dispatch("tick")
+    clk.burn(0.002)
+    b = led.note_dispatch("tick")
+    clk.burn(0.002)
+    _result(led, clk, a, 0.096)  # ready at t0 + 0.100
+    c = led.note_dispatch("prefill", 2, 128, 200)  # enqueued at 0.100, behind tick b
+    clk.burn(0.005)
+    _result(led, clk, b, 0.095)  # ready at 0.200
+    d = led.note_dispatch("chunk", 1, 64, 64, 192)  # at 0.200; starts once the prefill group has ended
+    e = led.note_dispatch("tick")
+    _result(led, clk, c, 0.040)  # the wave's first tokens: ready at 0.240
+    _result(led, clk, e, 0.160)  # covers the chunk and the tick: ready at 0.400
+    assert (a, b, c, d, e) == (1, 2, 3, 4, 5) == tuple(range(1, led.seq + 1))
+    q = led.queue_snapshot()
+    got = _nonzero(q)
+    assert set(got) == {"tick", "prefill:2x128", "chunk+tick"}
+    assert got["tick"] == pytest.approx({"s": 0.100 + 0.100, "n": 2, "ticks": 2, "groups": 0, "tokens": 0,
+                                         "start_tokens": 0, "lag_s": 0.0, "lag_n": 0})
+    # the prefill waited 0.100 in the queue behind tick b, the chunk 0.040 behind the prefill
+    assert got["prefill:2x128"] == pytest.approx({"s": 0.040, "n": 1, "ticks": 0, "groups": 1, "tokens": 200,
+                                                  "start_tokens": 0, "lag_s": 0.100, "lag_n": 1})
+    assert got["chunk+tick"] == pytest.approx({"s": 0.160, "n": 1, "ticks": 1, "groups": 1, "tokens": 64,
+                                               "start_tokens": 192, "lag_s": 0.040, "lag_n": 1})
+    assert q["idle"]["s"] == 0.0 and q["markers"] == {"waited": 4, "not_waited": 0}
+    assert sum(v["s"] for v in got.values()) == pytest.approx(clk.t - t0)  # first dispatch to last result
+    # the padding counters are fed by the same dispatches
+    assert (led.prefill_tokens_real, led.prefill_tokens_padded) == (264, 2 * 128 + 64)
+    assert led.prefill_shapes == {"2x128": 1, "1x64": 1}
+    ring = led.recent_segments()
+    assert [r["key"] for r in ring] == ["tick", "tick", "prefill:2x128", "chunk+tick"]
+    assert ring[-1] == {"seq": [4, 5], "key": "chunk+tick", "kinds": ["chunk", "tick"], "shapes": ["1x64", ""],
+                        "start": pytest.approx(t0 + 0.240), "ready": pytest.approx(t0 + 0.400)}
+    assert all(x["ready"] == y["start"] for x, y in zip(ring, ring[1:]))  # back to back: the queue never emptied
+
+
+def test_a_result_that_was_ready_early_closes_nothing_and_its_dispatches_ride_on():
+    clk, led = _queue_ledger()
+    a = led.note_dispatch("tick")
+    _result(led, clk, a, 0.100)
+    b = led.note_dispatch("tick")
+    clk.burn(0.300)  # the host was busy elsewhere: by now the tick has long ended
+    c = led.note_dispatch("prefill", 1, 64, 40)
+    _result(led, clk, b, MARKER_WAIT_MIN_S / 2)  # no wait: when it ended is not known
+    q = led.queue_snapshot()
+    assert q["markers"] == {"waited": 1, "not_waited": 1} and _nonzero(q).keys() == {"tick"}
+    _result(led, clk, c, 0.050)
+    q = led.queue_snapshot()
+    assert _nonzero(q).keys() == {"tick", "mixed"}
+    assert q["mixed"] == pytest.approx({"s": 0.300 + MARKER_WAIT_MIN_S / 2 + 0.050, "n": 1, "ticks": 1, "groups": 1,
+                                        "tokens": 40, "start_tokens": 0, "lag_s": 0.0, "lag_n": 1})
+    assert led.recent_segments()[-1]["seq"] == [b, c]
+
+
+def test_an_empty_queue_is_idle_time_charged_to_the_phase_the_thread_spent_it_in():
+    clk, led = _queue_ledger()
+    t0 = clk.t
+    a = led.note_dispatch("tick")
+    _result(led, clk, a, 0.100)
+    with led.span("consume"):
+        clk.burn(0.004)
+    with led.span("idle_wait"):
+        clk.burn(0.500)
+    with led.span("admit"):
+        clk.burn(0.001)
+        with led.span("prefill_dispatch"):
+            clk.burn(0.002)  # building the wave's arrays
+            b = led.note_dispatch("prefill", 1, 64, 30)
+            clk.burn(0.003)
+    _result(led, clk, b, 0.030)
+    q = led.queue_snapshot()
+    assert q["idle"]["s"] == pytest.approx(0.507) and q["idle"]["n"] == 1
+    assert {p: s for p, s in q["idle"]["by_phase"].items() if s} == pytest.approx(
+        {"consume": 0.004, "idle_wait": 0.500, "admit": 0.001, "prefill_dispatch": 0.002})
+    # the segment starts where the program was enqueued, not where the last result was read: no lag
+    assert q["prefill:1x64"] == pytest.approx({"s": 0.033, "n": 1, "ticks": 0, "groups": 1, "tokens": 30,
+                                               "start_tokens": 0, "lag_s": 0.0, "lag_n": 1})
+    assert q["tick"]["s"] + q["prefill:1x64"]["s"] + q["idle"]["s"] == pytest.approx(clk.t - t0)
+
+
+def test_riders_name_no_segment_a_restart_drops_what_was_pending_and_the_ring_is_bounded():
+    clk, led = _queue_ledger()
+    led.note_dispatch("cow")  # a page clone rides with the suffix prefill behind it
+    a = led.note_dispatch("suffix", 2, 128, 90)
+    _result(led, clk, a, 0.020)
+    assert _nonzero(led.queue_snapshot()).keys() == {"suffix:2x128"}
+    assert led.recent_segments()[-1]["kinds"] == ["cow", "suffix"]
+    led.note_dispatch("tick")
+    led.reset_queue()  # crash-only restart: that tick's result never comes
+    clk.burn(5.0)
+    for _ in range(QUEUE_RING + 7):
+        _result(led, clk, led.note_dispatch("spec"), 0.010)
+    q = led.queue_snapshot()
+    assert q["spec"]["n"] == QUEUE_RING + 7 and q["mixed"]["n"] == 0
+    assert q["idle"]["s"] == 0.0  # nothing is charged across a restart; after it the results were all waited for
+    ring = led.recent_segments()
+    assert len(ring) == QUEUE_RING and ring[-1]["seq"] == [led.seq, led.seq] and ring[0]["key"] == "spec"
 
 
 # ------------------------------------------- the loop, cranked on a fake clock
@@ -249,6 +389,70 @@ def test_tick_stats_issue_block_and_ticks_read_what_they_read_before(cranked):
     assert {k: n for k, n in ts["prefill_shapes"].items() if n} == {"4x64": 1, "1x64": 1}
 
 
+def test_device_queue_segments_and_idle_tile_first_dispatch_to_last_result(cranked):
+    """The device's half, as the phases' test does the host's: on the rig's
+    clock every result is waited for, so the segments and the idle time
+    between them add up to the time from the first dispatch to the last
+    result, and what a segment held is what the loop enqueued."""
+    ls = cranked.eng.loop_stats(recent=True)
+    q, ring = ls["device_queue"], ls["device_queue_recent"]
+    segs = _nonzero(q)
+    assert set(segs) == {"tick", "prefill:4x64", "prefill:1x64"}  # waves of 3 and of 1; every tick alone
+    assert set(q) - {"idle", "markers"} == {"tick", "piggyback", "spec", "chunk", "chunk+tick", "mixed"} | {
+        f"{kind}:{r}x{b}" for kind in ("prefill", "suffix") for b, rows in cranked.eng.prefill_shapes.items() for r in rows}
+    assert q["markers"] == {"waited": 2 + cranked.log["ticks"], "not_waited": 0}
+    assert segs["tick"]["n"] == segs["tick"]["ticks"] == cranked.log["ticks"]
+    assert (segs["prefill:4x64"]["tokens"], segs["prefill:1x64"]["tokens"]) == (12, 3)
+    assert sum(v["groups"] for v in segs.values()) == sum(v["lag_n"] for v in segs.values()) == 2
+    wall = ring[-1]["ready"] - ring[0]["start"]
+    assert 0.3 < wall <= cranked.wall
+    assert sum(v["s"] for v in segs.values()) + q["idle"]["s"] == pytest.approx(wall)
+    assert sum(r["ready"] - r["start"] for r in ring) == pytest.approx(wall - q["idle"]["s"])
+    assert [r["seq"][0] for r in ring] == list(range(1, len(ring) + 1))  # one dispatch a segment, none lost
+    # a tick's segment is its result's wait plus what the host did since the result before it
+    assert all(r["ready"] - r["start"] >= 0.1 for r in ring if r["key"] == "tick")
+    # the second wave was enqueued behind a tick in flight: its lag is that tick's remaining time
+    assert segs["prefill:1x64"]["lag_s"] > 0.05
+    assert "device_queue_recent" not in cranked.eng.tick_stats()  # the ring is not on the scrape path
+
+
+def test_an_idle_engine_charges_the_empty_queue_to_idle_wait():
+    clk = _Clock()
+    eng = _rigged_engine(clk, {"prefill_s": 0.0, "ticks": 0, "detok_s": 0.0, "upload_s": 0.0})
+    _crank(eng, [eng.submit([1, 2, 3], max_tokens=3, temperature=0.0)])
+    assert eng.loop_stats()["device_queue"]["idle"]["n"] == 0
+    with eng._ledger.span("idle_wait"):  # what _loop does between iterations that found nothing
+        clk.sleep(0.75)
+    _crank(eng, [eng.submit([4, 5, 6], max_tokens=3, temperature=0.0)])
+    idle = eng.loop_stats()["device_queue"]["idle"]
+    assert idle["n"] == 1 and idle["by_phase"]["idle_wait"] == pytest.approx(0.75)
+    # the rest of the gap: the last results' bookkeeping, and building the wave up to its enqueue
+    assert idle["s"] == pytest.approx(sum(idle["by_phase"].values())) and 0.75 <= idle["s"] < 0.76
+    assert idle["by_phase"]["tick_block"] == idle["by_phase"]["tick_issue"] == 0.0
+    eng.stop(drain_timeout_s=5.0)
+
+
+@pytest.mark.parametrize("family", ["dabt_device_queue_seconds_total", "dabt_device_queue_segments_total",
+                                    "dabt_device_queue_idle_seconds_total", "dabt_prefill_start_lag_seconds_total",
+                                    "dabt_prefill_start_lag_dispatches_total"])
+def test_metrics_export_the_device_queue(cranked, family):
+    reg = SimpleNamespace(generators={"m": cranked.eng}, embedders={})
+    fam = parse_prometheus_text(render_prometheus(reg))[family]
+    q = cranked.eng.tick_stats()["device_queue"]
+    assert fam["type"] == "counter"
+    keyed = {(lab.get("kind"), lab.get("shape"), lab.get("phase")): v for _, lab, v in fam["samples"]}
+    segs = {k: v for k, v in q.items() if k not in ("idle", "markers")}
+    if family == "dabt_device_queue_idle_seconds_total":
+        assert {k[2]: v for k, v in keyed.items()} == pytest.approx(q["idle"]["by_phase"])
+    elif family.startswith("dabt_prefill_start_lag"):
+        field = "lag_s" if "seconds" in family else "lag_n"
+        assert list(keyed.values()) == [pytest.approx(sum(v[field] for v in segs.values()))] and keyed[(None, None, None)] > 0
+    else:
+        field = "s" if "seconds" in family else "n"
+        want = {(k.partition(":")[0], k.partition(":")[2], None): v[field] for k, v in segs.items()}
+        assert keyed == pytest.approx(want) and keyed[("prefill", "4x64", None)] > 0 and keyed[("tick", "", None)] > 0
+
+
 @pytest.mark.parametrize("phase", LOOP_PHASES)
 def test_metrics_export_the_loop_ledger(cranked, phase):
     reg = SimpleNamespace(generators={"m": cranked.eng}, embedders={})
@@ -296,14 +500,18 @@ def _assert_tiles(usage):
     assert usage["ttft_s"] == pytest.approx(tm["queue_s"] + tm["prefill_s"], abs=1e-9)
     assert usage["latency_s"] == pytest.approx(
         tm["queue_s"] + tm["prefill_s"] + tm["decode_s"], abs=1e-9)
-    # back to back from receipt: every span starts where the one before ended
+    # back to back from receipt: every span starts where the one before ended.
+    # Held against the timings as they are: a span's `t_s` and `dur_s` are each
+    # rounded to 1e-6, so a sum of rounded durations drifts half a microsecond a span
     spans = request_spans(tm)
     assert spans[0]["name"] == "request" and spans[0]["parent"] is None
+    durs = {name: tm[key] for name, key in _SPAN_KEYS if tm.get(key) is not None}
     t = 0.0
     for sp in spans[1:]:
-        assert sp["parent"] == "request" and sp["t_s"] == pytest.approx(t, abs=2e-6)
-        t += sp["dur_s"]
-    assert spans[0]["dur_s"] == pytest.approx(t, abs=1e-5)
+        assert sp["parent"] == "request" and sp["t_s"] == pytest.approx(t, abs=1e-6)
+        assert sp["dur_s"] == pytest.approx(durs[sp["name"]], abs=1e-6)
+        t += durs[sp["name"]]
+    assert spans[0]["dur_s"] == pytest.approx(t, abs=1e-6)
     return tm
 
 
@@ -336,6 +544,11 @@ def test_ledger_and_timings_are_the_engines_own_with_obs_off(obs):
         assert set(ts["loop"]) == set(LOOP_PHASES) and ts["loop"]["tick_issue"]["n"] == ts["ticks"] >= 1
         assert ts["prefill_tokens_real"] == 3 and ts["prefill_tokens_padded"] == 64
         assert ts["prefill_shapes"] == {"1x64": 1}
+        # the device's half too: the wave's group and every tick noted, every result a marker
+        q = ts["device_queue"]
+        # (the last tick's result may still be on its way to the engine thread)
+        assert 1 <= q["markers"]["waited"] + q["markers"]["not_waited"] <= 1 + ts["ticks"] == eng._ledger.seq
+        assert {"tick", "prefill:1x64", "suffix:1x64", "chunk+tick", "mixed", "idle"} <= set(q)
     finally:
         eng.stop()
 
@@ -497,15 +710,25 @@ def test_spans_are_host_events_on_the_engine_thread_under_the_profiler(tmp_path)
     finally:
         eng.stop()
     (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
-    lines = [(pl.name, i, {e.name: dict(e.stats) for e in ln.events})
+    lines = [(pl.name, i, [(e.name, dict(e.stats)) for e in ln.events])
              for pl in ProfileData.from_file(path).planes for i, ln in enumerate(pl.lines)]
     # (another engine idling in this process has a line of its own: reap,
     # admit, prestage, idle_wait and never a tick)
-    engine_lines = [(p, i, ev) for p, i, ev in lines if "dabt/tick_issue" in ev]
+    engine_lines = [(p, i, evs) for p, i, evs in lines if any(n == "dabt/tick_issue" for n, _ in evs)]
     assert len(engine_lines) == 1  # one thread issued them all
-    plane, idx, ev = engine_lines[0]
+    plane, idx, evs = engine_lines[0]
+    ev = dict(evs)
     assert plane.startswith("/host:")
     assert {"dabt/tick_issue", "dabt/prefill_dispatch", "dabt/tick_block", "dabt/consume"} <= set(ev)
-    assert ev["dabt/prefill_dispatch"] == {"bucket": 64, "rows": 1, "rows_padded": 1}
+    seq = ev["dabt/prefill_dispatch"]["seq"]
+    assert ev["dabt/prefill_dispatch"] == {"bucket": 64, "rows": 1, "rows_padded": 1, "seq": seq}
     assert "test_thread_mark" not in ev  # this thread's line is another
     assert threading.current_thread().name != "gen-engine"
+    # the device-queue ledger's numbers join the three: a dispatch's span carries
+    # its seq, the wait for a result the seq of the last dispatch it covers
+    issued = [st["seq"] for n, st in evs if n in ("dabt/tick_issue", "dabt/prefill_dispatch")]
+    waited = [st["seq"] for n, st in evs if n == "dabt/tick_block"]
+    assert issued == sorted(set(issued)) and seq in issued  # one number a dispatch, in order
+    # (a result of the first request's last tick may still be read as the session starts)
+    assert waited == sorted(waited) and all(w in issued or w < issued[0] for w in waited)
+    assert seq in waited  # the wave's first tokens are a result of their own

@@ -70,13 +70,16 @@ HOT_PATH_PATTERNS: Tuple[str, ...] = (
     "*FlightRecorder.record",
     # engine-loop time ledger (serving/obs.py LoopLedger): span enter/exit
     # run ~10 times per loop iteration around every dispatch and the tick's
-    # result wait; the padding counters ride each prefill dispatch.  The
-    # server's per-delta stream-lag stamp sits between a token and its write
+    # result wait; its device-queue half stamps every dispatch
+    # (note_dispatch, which also feeds the padding counters) and closes a
+    # segment where a result's wait ended (note_marker).  The server's
+    # per-delta stream-lag stamp sits between a token and its write
     "*_PhaseSpan.__enter__",
     "*_PhaseSpan.__exit__",
     "*LoopLedger.span",
     "*LoopLedger.seconds",
-    "*LoopLedger.note_prefill",
+    "*LoopLedger.note_dispatch",
+    "*LoopLedger.note_marker",
     "*_StreamLag.note",
 )
 
