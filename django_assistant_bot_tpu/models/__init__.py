@@ -26,3 +26,13 @@ from . import encoder, llama, mixtral, mla_moe  # noqa: F401
 def module_for(cfg: DecoderConfig):
     """The module whose entry points run ``cfg``: chosen once, by ``cfg.arch``."""
     return {"llama": llama, "mla_moe": mla_moe}[cfg.arch]
+
+
+def held_params(cfg: DecoderConfig, params):
+    """A checkpoint's tree of ``cfg`` in the form its module holds on the device
+    and its entry points and ``logical_axes`` take: the one call between
+    loading weights and placing them.  :mod:`.mla_moe` re-lays out six leaves
+    (``mla_moe.held_params``); a module without such a function holds the
+    checkpoint's own tree."""
+    relay = getattr(module_for(cfg), "held_params", None)
+    return params if relay is None else relay(cfg, params)

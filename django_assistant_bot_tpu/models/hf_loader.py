@@ -117,8 +117,8 @@ def load_decoder(model_dir: str, dtype=None) -> tuple[DecoderConfig, Dict[str, A
         raise ValueError(
             f"model_type {model_type!r}: loading a latent-attention MoE checkpoint directory is not "
             "implemented (its parameter names are not mapped, and kv_b_proj and the rotary pairs need "
-            "permuting into models/mla_moe.py's layout); serve it from a native checkpoint written by "
-            "checkpoint.save_model"
+            "permuting into models/mla_moe.py's checkpoint layout); serve it from a native checkpoint written "
+            "by checkpoint.save_model (the registry re-lays it out for the device: models.held_params)"
         )
     if model_type is not None and model_type not in _SUPPORTED_DECODERS:
         raise ValueError(

@@ -50,6 +50,32 @@ registry refuses them.
 Rotary pairs are half-split (``ops/rope.py``), as everywhere in this repo; a
 published checkpoint (interleaved pairs, ``kv_b_proj`` with keys and values
 interleaved per head) is permuted at load time into ``w_uk`` / ``w_uv``.
+
+**Two forms of the tree.**  A checkpoint (``save_model``, :func:`init`, the
+benchmark's families) holds every projection as ``[layers, in, out]``.  The
+device holds six leaves otherwise (:func:`held_params`, made once at load; the
+only function that knows both forms; :func:`logical_axes` describes this one),
+each in the form the decode step's dot contracts, so that the layer scan's
+slice is the dot's operand and the weight is read once a layer a step:
+
+- ``w_uq``  ``[L, R, H*(dn+dr)]`` -> ``w_uq_nope`` ``[L, H, dn, R]`` and
+  ``w_uq_rope`` ``[L, dr, H, R]`` (the rotation splits ``dr`` in halves, so
+  its lanes lie major; a head's ``dn+dr`` = 192 columns are no whole lane
+  tiles, so the two parts are two leaves)
+- ``w_uk``  ``[L, C, H*dn]``      -> ``[L, H, dn, C]``
+- ``w_uv``  ``[L, C, H*dv]``      -> ``[L, H, dv, C]``
+- ``w_iq``  ``[L, R, Hi*Di]``     -> ``[L, Hi, Di, R]``
+- ``w_dkv`` ``[L, E, C+dr]``      -> ``[L, E, latent_width]`` (zero columns)
+- ``lm_head`` ``[E, V]``          -> ``[E, V up to whole lane tiles]`` (zero
+  columns; the logits are cut back to ``V``)
+
+Held as in the checkpoint, the compiled tick re-laid out every one of them
+once a tick at its entry (0.9 GB written at DeepSeek-V3.2's widths: the dots
+of a head-shaped result take the contracted axis last, and the device's own
+default layout of a width that is not whole lane tiles is the transposed one)
+and inside the scan copied a layer of the first four into fast memory before
+the dot ran from there, the read and the dot one after the other (PERF.md
+section 5, PR 43).  Prefill contracts the same leaves.
 """
 
 from __future__ import annotations
@@ -58,6 +84,7 @@ from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.attention import (
     attention,
@@ -193,15 +220,19 @@ def _stack_sizes(cfg: DecoderConfig) -> tuple[int, int]:
 
 
 def logical_axes(cfg: DecoderConfig) -> Params:
+    """Axes of the HELD tree (:func:`held_params`; the module docstring has the
+    forms): the up-projections carry their heads on an axis of their own
+    (``w_uq_nope`` ``[L, H, dn, R]``, ``w_uq_rope`` ``[L, dr, H, R]``, ``w_uk`` /
+    ``w_uv`` ``[L, H, d, C]``), which is the axis a mesh shards."""
     E, F = "embed", "mlp"
     attn = {
         "attn_norm": (None, E), "w_dq": (None, E, None), "q_norm": (None, None),
-        "w_uq": (None, None, "heads"), "w_dkv": (None, E, None), "kv_norm": (None, None),
-        "w_uk": (None, None, "heads"), "w_uv": (None, None, "heads"), "wo": (None, "heads", E),
-        "mlp_norm": (None, E),
+        "w_uq_nope": (None, "heads", None, None), "w_uq_rope": (None, None, "heads", None), "w_dkv": (None, E, None),
+        "kv_norm": (None, None), "w_uk": (None, "heads", None, None), "w_uv": (None, "heads", None, None),
+        "wo": (None, "heads", E), "mlp_norm": (None, E),
     }
     if cfg.latent_moe.index_topk:  # the indexer is small and whole on every device
-        attn.update(w_iq=(None, None, None), w_ik=(None, E, None), ik_norm=(None, None), ik_bias=(None, None),
+        attn.update(w_iq=(None, None, None, None), w_ik=(None, E, None), ik_norm=(None, None), ik_bias=(None, None),
                     w_iw=(None, E, None))
     dense = dict(attn, w_gate=(None, E, F), w_up=(None, E, F), w_down=(None, F, E))
     # the held experts stay whole on every device of this process: "expert" is the
@@ -220,8 +251,9 @@ def logical_axes(cfg: DecoderConfig) -> Params:
 
 
 def init(cfg: DecoderConfig, rng: jax.Array) -> Params:
-    """Random parameters in the served layout: ``dense_layers`` and
-    ``moe_layers`` stacked on a leading axis each, only the held experts drawn."""
+    """Random parameters as a checkpoint holds them (:func:`held_params` makes
+    the tree the entry points take): ``dense_layers`` and ``moe_layers``
+    stacked on a leading axis each, only the held experts drawn."""
     lm = cfg.latent_moe
     E, F, H = cfg.hidden_size, cfg.intermediate_size, cfg.num_heads
     R, C, dn, dr, dv = lm.q_lora_rank, lm.kv_lora_rank, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim
@@ -264,6 +296,54 @@ def init(cfg: DecoderConfig, rng: jax.Array) -> Params:
     return params
 
 
+def _zero_columns(x, n: int):
+    """``x`` with ``n`` zero columns after its last axis (``x`` itself for 0)."""
+    if not n:
+        return x
+    xp = np if isinstance(x, np.ndarray) else jnp
+    return xp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, n)])
+
+
+def held_params(cfg: DecoderConfig, params: Params) -> Params:
+    """A checkpoint's tree -> the tree the device holds and every entry point
+    takes (the module docstring has the six leaves that differ and why).  Called
+    once where weights are loaded, before they are placed (``models.held_params``:
+    the registry, ``tools/``), and nothing of the checkpoint's form is kept.
+    The up-projections are transposed ON THE DEVICE, one program a stack, and
+    come back as device arrays whatever they came as: the same 0.5 GB moved by
+    NumPy on the host was 8 s of a 100 s boot (PERF.md section 6, PR 43; a
+    registry that keeps a host copy for replicas of its own keeps these leaves
+    on the default device).  What is only padded stays where it was.  The
+    widths are ``cfg``'s; a tree without indexer leaves (``index_topk`` 0) or
+    ``lm_head`` (tied) stays without."""
+    lm = cfg.latent_moe
+    H, dn, dr, dv, C, R = (cfg.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim, lm.kv_lora_rank,
+                           lm.q_lora_rank)
+
+    @jax.jit
+    def contracted_last(p: Params) -> Params:
+        L = p["w_uq"].shape[0]
+        w_uq = p["w_uq"].reshape(L, R, H, dn + dr)
+        out = dict(
+            w_uq_nope=w_uq[..., :dn].transpose(0, 2, 3, 1), w_uq_rope=w_uq[..., dn:].transpose(0, 3, 2, 1),
+            w_uk=p["w_uk"].reshape(L, C, H, dn).transpose(0, 2, 3, 1),
+            w_uv=p["w_uv"].reshape(L, C, H, dv).transpose(0, 2, 3, 1),
+        )
+        if "w_iq" in p:
+            out["w_iq"] = p["w_iq"].reshape(L, R, lm.index_n_heads, lm.index_head_dim).transpose(0, 2, 3, 1)
+        return out
+
+    def stack(p: Params) -> Params:
+        moved = {k: p[k] for k in ("w_uq", "w_uk", "w_uv", "w_iq") if k in p}
+        kept = {k: v for k, v in p.items() if k not in moved}
+        return dict(kept, w_dkv=_zero_columns(p["w_dkv"], lm.latent_width - C - dr), **contracted_last(moved))
+
+    held = dict(params, dense_layers=stack(params["dense_layers"]), moe_layers=stack(params["moe_layers"]))
+    if "lm_head" in held:
+        held["lm_head"] = _zero_columns(held["lm_head"], (-cfg.vocab_size) % 128)
+    return held
+
+
 # ---------------------------------------------------------------------------
 # the block
 # ---------------------------------------------------------------------------
@@ -292,16 +372,16 @@ def _queries_and_row(cfg: DecoderConfig, p: Params, h: jnp.ndarray, cos, sin):
     query latent (the indexer's queries come from it too)."""
     lm = cfg.latent_moe
     B, S, _ = h.shape
-    H, dn, dr, C = cfg.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.kv_lora_rank
+    dr, C = lm.qk_rope_head_dim, lm.kv_lora_rank
     with jax.named_scope("attn/q_down"):
         c_q = rms_norm(_mm("bse,er->bsr", h, p["w_dq"], cfg.dtype), p["q_norm"], cfg.rms_norm_eps)
     with jax.named_scope("attn/q_up"):
-        q = _mm("bsr,ro->bso", c_q, p["w_uq"], cfg.dtype).reshape(B, S, H, dn + dr)
-        q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], cos, sin)
+        q_nope = _mm("bsr,hdr->bshd", c_q, p["w_uq_nope"], cfg.dtype)
+        q_rope = apply_rope(_mm("bsr,dhr->bshd", c_q, p["w_uq_rope"], cfg.dtype), cos, sin)
     with jax.named_scope("attn/kv_down"):
         ckv = _mm("bse,ec->bsc", h, p["w_dkv"], cfg.dtype)
         c_kv = rms_norm(ckv[..., :C], p["kv_norm"], cfg.rms_norm_eps)
-        k_rope = apply_rope(ckv[..., None, C:], cos, sin)[..., 0, :]
+        k_rope = apply_rope(ckv[..., None, C:C + dr], cos, sin)[..., 0, :]
         pad = lm.latent_width - C - dr
         row = jnp.concatenate([c_kv, k_rope] + ([jnp.zeros((B, S, pad), c_kv.dtype)] if pad else []), axis=-1)
     return q_nope, q_rope, row, c_q
@@ -312,14 +392,13 @@ def _index_parts(cfg: DecoderConfig, p: Params, h: jnp.ndarray, c_q: jnp.ndarray
     [B,S,Hi] float32, k_idx [B,S,Di] rotated: what the second cache keeps).
     Rotary over the FIRST ``qk_rope_head_dim`` lanes of a head, MLA's tables."""
     lm = cfg.latent_moe
-    B, S, _ = h.shape
     Hi, Di, dr = lm.index_n_heads, lm.index_head_dim, lm.qk_rope_head_dim
 
     def rotate(x):  # [B, S, heads, Di]
         return jnp.concatenate([apply_rope(x[..., :dr], cos, sin), x[..., dr:]], axis=-1)
 
     with jax.named_scope("attn/index_q"):
-        q_idx = rotate(_mm("bsr,ro->bso", c_q, p["w_iq"], cfg.dtype).reshape(B, S, Hi, Di))
+        q_idx = rotate(_mm("bsr,hdr->bshd", c_q, p["w_iq"], cfg.dtype))
         w_idx = jnp.einsum("bse,eh->bsh", h.astype(jnp.float32), p["w_iw"].astype(jnp.float32),
                            precision=jax.lax.Precision.HIGHEST) * float(Hi ** -0.5 * Di ** -0.5)
     with jax.named_scope("attn/index_k"):
@@ -365,14 +444,18 @@ def _expanded_queries(cfg: DecoderConfig, q_nope, q_rope):
 @jax.named_scope("attn/kv_up")
 def _expanded_keys_values(cfg: DecoderConfig, p: Params, rows):
     """Latent ``rows`` [B,Sk,W] -> (k [B,H,Sk,D], v [B,H,Sk,dv]): keys ``[rows
-    W_UK | k_rope | zero pad]``, values ``rows W_UV``."""
+    W_UK | k_rope | zero pad]``, values ``rows W_UV``.  Each over its held
+    leaf as ONE ``[H*d, C]`` matrix (a view: heads are the leaf's major axis),
+    positions major: a head-batched product leaves its result positions-minor,
+    and the chunk's values of a whole context (537 MB at 16,384) or the plain
+    path's scores would then be copied once a layer."""
     lm = cfg.latent_moe
     B, Sk, _ = rows.shape
     H, dn, dr, dv, C = cfg.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim, lm.kv_lora_rank
     c_kv = rows[..., :C].astype(cfg.dtype)
     k_rope = rows[..., C:C + dr].astype(cfg.dtype)
-    k_nope = _mm("bsc,co->bso", c_kv, p["w_uk"], cfg.dtype).reshape(B, Sk, H, dn)
-    v = _mm("bsc,co->bso", c_kv, p["w_uv"], cfg.dtype).reshape(B, Sk, H, dv)
+    k_nope = _mm("bsc,oc->bso", c_kv, p["w_uk"].reshape(H * dn, C), cfg.dtype).reshape(B, Sk, H, dn)
+    v = _mm("bsc,oc->bso", c_kv, p["w_uv"].reshape(H * dv, C), cfg.dtype).reshape(B, Sk, H, dv)
     pad = _lane_pad(cfg)
     zk = [jnp.zeros((B, Sk, H, pad), k_nope.dtype)] if pad else []
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, Sk, H, dr))] + zk, axis=-1)
@@ -487,7 +570,8 @@ def _scan_stacks(cfg: DecoderConfig, params: Params, make_body, carry):
 
 
 def _finish(params: Params, cfg: DecoderConfig, x_last: jnp.ndarray) -> jnp.ndarray:
-    return _head_logits(params, cfg, rms_norm(x_last, params["final_norm"], cfg.rms_norm_eps)).astype(jnp.float32)
+    logits = _head_logits(params, cfg, rms_norm(x_last, params["final_norm"], cfg.rms_norm_eps))
+    return logits[..., :cfg.vocab_size].astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -739,7 +823,7 @@ def decode_step_paged(
     L, P, page, W = cache.kv.shape
     NB = block_tables.shape[1]
     S = NB * page
-    H, dn, dr, dv, C = cfg.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim, lm.kv_lora_rank
+    H, dr, dv, C = cfg.num_heads, lm.qk_rope_head_dim, lm.v_head_dim, lm.kv_lora_rank
     if active is None:
         active = jnp.ones((B,), bool)
     active = active & (cache.lengths < S)
@@ -770,7 +854,7 @@ def decode_step_paged(
             h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
             q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
             with jax.named_scope("attn/absorb"):
-                q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], p["w_uk"].astype(cfg.dtype).reshape(C, H, dn))
+                q_abs = _mm("bhd,hdc->bhc", q_nope[:, 0], p["w_uk"], cfg.dtype)
                 pad = W - C - dr
                 q = jnp.concatenate(
                     [q_abs, q_rope[:, 0]] + ([jnp.zeros((B, H, pad), q_abs.dtype)] if pad else []), axis=-1
@@ -809,7 +893,7 @@ def decode_step_paged(
                         scale=scale, value_width=C, active=active,
                     )
             with jax.named_scope("attn/absorb"):
-                o = jnp.einsum("bhc,chd->bhd", o_lat, p["w_uv"].astype(cfg.dtype).reshape(C, H, dv))
+                o = _mm("bhc,hdc->bhd", o_lat, p["w_uv"], cfg.dtype)
             x = x + _attn_out(cfg, p, o.reshape(B, 1, H * dv))
             y, stats = _ffn(cfg, p, x, valid, held, layer)
             dsa = _dsa_counts(active, positions, selected if select else every_pair) if lm.index_topk else None

@@ -316,7 +316,7 @@ class ModelRegistry:
     def load(self, spec: ModelSpec):
         import jax.numpy as jnp
 
-        from ..models import DecoderConfig, EncoderConfig, encoder, llama, module_for
+        from ..models import DecoderConfig, EncoderConfig, encoder, held_params, llama, module_for
         from ..models.hf_loader import load_decoder, load_encoder
         from ..parallel import shard_pytree
         from .engine import EmbeddingEngine, GenerationEngine
@@ -504,6 +504,9 @@ class ModelRegistry:
                 params = llama.init(cfg, jax.random.key(0))
             else:
                 raise ValueError(f"model {name}: need path, checkpoint, or tiny=true")
+            # the checkpoint's tree -> the form the block holds on the device
+            # (models.held_params): once, here, before anything is placed
+            params = held_params(cfg, params)
             if spec.quantize in ("int8", "int4"):
                 # quantize BEFORE device placement: the packed integers are
                 # what transfers and shards (QTensor/QTensor4 ride the same

@@ -50,7 +50,7 @@ def family():
 def _program(family, conf):
     cfg = dataclasses.replace(DecoderConfig.from_hf(conf["hf"], dtype=jnp.float32), max_seq_len=256)
     params = jax.tree.map(lambda x: x.astype(jnp.float32), family.served_params(conf, SEED))
-    return cfg, params
+    return cfg, mla_moe.held_params(cfg, params)  # the family's tree is a checkpoint's; the entry points take the held one
 
 
 def _reference(family, conf, seqs, firsts=None, control=None):
@@ -87,9 +87,14 @@ def test_from_hf_reads_deepseek_v32_the_indexer_the_bias_and_the_draft_head():
 def test_any_number_of_leading_dense_layers_is_read(first_dense):
     cfg = DecoderConfig.from_hf(_conf(first_k_dense_replace=first_dense)["hf"])
     assert cfg.latent_moe.first_dense_layers == first_dense
-    p = jax.eval_shape(lambda: mla_moe.init(dataclasses.replace(cfg, dtype=jnp.float32), jax.random.key(0)))
+    cfg32 = dataclasses.replace(cfg, dtype=jnp.float32)
+    p = jax.eval_shape(lambda: mla_moe.init(cfg32, jax.random.key(0)))  # a checkpoint's tree
     assert p["dense_layers"]["w_iq"].shape == (first_dense, 32, 64) and p["moe_layers"]["router_bias"].shape == (3 - first_dense, 16)
-    assert jax.tree.structure(mla_moe.logical_axes(cfg), is_leaf=lambda x: isinstance(x, tuple)) == jax.tree.structure(p)
+    held = jax.eval_shape(lambda: mla_moe.held_params(cfg32, mla_moe.init(cfg32, jax.random.key(0))))  # what the device holds
+    assert held["dense_layers"]["w_iq"].shape == (first_dense, 4, 16, 32) and held["moe_layers"]["w_iq"].shape == (3 - first_dense, 4, 16, 32)
+    axes, is_axes = mla_moe.logical_axes(cfg), lambda x: isinstance(x, tuple)
+    assert jax.tree.structure(axes, is_leaf=is_axes) == jax.tree.structure(held)
+    assert all(len(a) == x.ndim for a, x in zip(jax.tree.leaves(axes, is_leaf=is_axes), jax.tree.leaves(held)))
 
 
 @pytest.mark.parametrize("lengths", [[18, 7], [40, 29], [48, 33]])

@@ -51,7 +51,7 @@ def _program(family, conf):
     cfg = DecoderConfig.from_hf(conf["hf"], dtype=jnp.float32)
     cfg = dataclasses.replace(cfg, max_seq_len=256)
     params = jax.tree.map(lambda x: x.astype(jnp.float32), family.served_params(conf, SEED))
-    return cfg, params
+    return cfg, mla_moe.held_params(cfg, params)  # the family's tree is a checkpoint's; the entry points take the held one
 
 
 def _reference(family, conf, seqs, firsts=None):
@@ -283,3 +283,170 @@ def test_the_shares_add_up_to_the_uncut_layer(family):
     np.testing.assert_allclose(program, np.asarray(uncut), atol=ATOL)
     np.testing.assert_allclose(reference, np.asarray(uncut), atol=ATOL)
     assert float(np.abs(np.asarray(uncut) - np.asarray(shared)).max()) > 0.1  # the routed part is not nothing
+
+
+# --- the held form (PR 43): what the device holds is the checkpoint's tree in another order of memory ---------------
+
+TINY = ("mla_moe_tiny.json", "dsa_moe_tiny.json")  # without and with an indexer
+
+
+def _tiny(name, dtype=jnp.float32, **hf):
+    """(conf, cfg, the family's checkpoint-form tree) of one of the two tiny configurations."""
+    with open(os.path.join(HERE, "data", name)) as f:
+        conf = json.load(f)
+    conf["hf"].update(hf)
+    cfg = dataclasses.replace(DecoderConfig.from_hf(conf["hf"], dtype=dtype), max_seq_len=256)
+    tree = jax.tree.map(lambda x: x.astype(dtype), families.load(conf, DATA).served_params(conf, SEED))
+    if hf.get("vocab_size"):  # a vocabulary that is not whole lane tiles: the head cut to it
+        tree = dict(tree, tok_embed=tree["tok_embed"][:cfg.vocab_size], lm_head=tree["lm_head"][:, :cfg.vocab_size])
+    return conf, cfg, tree
+
+
+def _as_the_checkpoint_form_contracted(cfg):
+    """``mla_moe._mm`` as the code before PR 43 wrote each of the contractions that changed, over the weight put back into the
+    checkpoint's form (a transposition and a slice: no arithmetic): the oracle the held form is compared with."""
+    lm = cfg.latent_moe
+    H, dr, C, R = cfg.num_heads, lm.qk_rope_head_dim, lm.kv_lora_rank, lm.q_lora_rank
+
+    def mm(pattern, x, w, dtype):
+        w = w.astype(dtype)
+        B, S = x.shape[:2]
+        if pattern == "bsr,dhr->bshd":  # w_uq_rope [dr, H, R]: the last dr of a head's dn+dr columns
+            return jnp.einsum("bsr,ro->bso", x, w.transpose(2, 1, 0).reshape(R, H * dr)).reshape(B, S, H, dr)
+        if pattern == "bsr,hdr->bshd":  # w_uq_nope [H, dn, R], or w_iq [Hi, Di, R]
+            return jnp.einsum("bsr,ro->bso", x, w.transpose(2, 0, 1).reshape(R, -1)).reshape(B, S, *w.shape[:2])
+        if pattern == "bhd,hdc->bhc":  # w_uk [H, dn, C], absorbed into the query
+            return jnp.einsum("bhd,chd->bhc", x, w.transpose(2, 0, 1))
+        if pattern == "bhc,hdc->bhd":  # w_uv [H, dv, C], applied to the attended latent
+            return jnp.einsum("bhc,chd->bhd", x, w.transpose(2, 0, 1))
+        if pattern == "bsc,oc->bso":  # w_uk [H*dn, C] and w_uv [H*dv, C] expanding a page's keys and values
+            return jnp.einsum("bsc,co->bso", x, w.T)
+        if pattern == "bse,ec->bsc":  # w_dkv without its zero columns
+            return jnp.einsum(pattern, x, w[:, :C + dr])
+        return jnp.einsum(pattern, x, w)
+
+    return mm
+
+
+def _run_every_entry_point(cfg, params):
+    """One-shot prefill, three chunks, a suffix over a cached prefix and five decode steps of two slots -> every
+    logit, the pools' live pages and the counters, as one flat list."""
+    s = _ids(53, 5, vocab=cfg.vocab_size)
+    page, NB, P = 8, 8, 16
+    bt_row = jnp.asarray([9, 1, 4, 2, 7, 11, 3, 0], jnp.int32)
+    out = list(jax.tree.leaves(mla_moe.prefill(params, cfg, jnp.asarray([s + [0] * 3]), jnp.asarray([53]))))
+    cache = mla_moe.init_paged_cache(cfg, 2, P, page)
+    for start, valid in ((0, 24), (24, 24), (48, 5)):
+        logits, cache = mla_moe.prefill_chunk_paged(
+            params, cfg, jnp.asarray([(s[start:start + valid] + [0] * 24)[:24]]), cache, bt_row, jnp.int32(1),
+            jnp.int32(start), jnp.int32(valid))
+        out.append(logits)
+    cache2 = mla_moe.init_paged_cache(cfg, 2, P, page)
+    _, cache2 = mla_moe.prefill_chunk_paged(
+        params, cfg, jnp.asarray([s[:24]]), cache2, bt_row, jnp.int32(0), jnp.int32(0), jnp.int32(24))
+    bts = jnp.stack([bt_row, jnp.full((NB,), P, jnp.int32)])
+    logits, cache2 = mla_moe.prefill_suffix_paged(
+        params, cfg, jnp.asarray([(s[24:] + [0] * 3), [0] * 32]), cache2, bts, jnp.asarray([0, 2]), jnp.asarray([24, 0]),
+        jnp.asarray([29, 0]))
+    out.append(logits)
+    bt2 = jnp.stack([jnp.full((NB,), P, jnp.int32), bt_row])
+    for k in range(5):  # slot 1 holds the chunked prompt's 53 tokens
+        logits, cache = mla_moe.decode_step_paged(
+            params, cfg, jnp.asarray([0, 7 + k], jnp.int32), cache, bt2, active=jnp.asarray([False, True]))
+        out.append(logits[1])
+    return out + [x for c in (cache, cache2) for x in jax.tree.leaves(c)]
+
+
+@pytest.mark.parametrize("vocab", [0, 500], ids=["vocab-512", "vocab-500"])
+@pytest.mark.parametrize("name", TINY)
+def test_every_entry_point_over_the_held_form_equals_the_checkpoint_forms_contractions(name, vocab, monkeypatch):
+    """Float32 on the CPU: prefill, chunk, suffix and decode from ``held_params(checkpoint tree)`` give the logits,
+    cache rows and counters the contractions written over the checkpoint's form give from the same weights; with a
+    vocabulary of 500 the head is held 512 wide and the logits are cut back.  Counters and lengths are equal; the
+    floats are the same float32 products summed in another order (XLA's CPU dot takes another loop nest for a
+    weight whose contracted axis is last), so NOT bit for bit: 2.3e-6 at most was seen on O(1) logits, and the
+    tolerance is a tenth of ``ATOL``, the one the plain reference is held to."""
+    conf, cfg, tree = _tiny(name, **({"vocab_size": vocab} if vocab else {}))
+    held = mla_moe.held_params(cfg, tree)
+    assert held["lm_head"].shape == (64, 512)
+    got = _run_every_entry_point(cfg, held)
+    monkeypatch.setattr(mla_moe, "_mm", _as_the_checkpoint_form_contracted(cfg))
+    want = _run_every_entry_point(cfg, dict(held, lm_head=tree["lm_head"]))
+    assert len(got) == len(want) and got[0].shape == (1, cfg.vocab_size)
+    for a, b in zip(got, want):
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=ATOL / 10)
+
+
+@pytest.mark.parametrize("name", TINY)
+def test_in_bfloat16_the_held_form_is_within_a_rounding_of_the_checkpoint_forms_contractions(name, monkeypatch):
+    """bfloat16 activations: the same products summed in another order, each result rounded once (2^-8 relative)."""
+    conf, cfg, tree = _tiny(name, dtype=jnp.bfloat16)
+    held = mla_moe.held_params(cfg, tree)
+    ids, n = jnp.asarray([_ids(40, 3)]), jnp.asarray([40])
+    got = mla_moe.prefill(held, cfg, ids, n)
+    monkeypatch.setattr(mla_moe, "_mm", _as_the_checkpoint_form_contracted(cfg))
+    want = mla_moe.prefill(held, cfg, ids, n)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), atol=0.06, rtol=2.0**-5)
+    rows = lambda r: np.asarray((r[0] if isinstance(r, tuple) else r).astype(jnp.float32))  # noqa: E731
+    np.testing.assert_allclose(rows(got[1]), rows(want[1]), atol=0.06, rtol=2.0**-5)
+
+
+@pytest.mark.parametrize("name", TINY)
+def test_held_params_moves_six_leaves_and_keeps_every_value(name):
+    """The forms the module docstring states, at the tiny widths; nothing of the checkpoint's form is kept; what is
+    transposed comes back on the device, what is padded or untouched stays the host's; and every value is where the
+    checkpoint's was."""
+    conf, cfg, tree = _tiny(name, vocab_size=500)
+    lm = cfg.latent_moe
+    H, dn, dr, dv, C, R, E = 4, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim, lm.kv_lora_rank, lm.q_lora_rank, 64
+    held = mla_moe.held_params(cfg, tree)
+    assert set(held) == set(tree) and held["lm_head"].shape == (E, 512) and not np.asarray(held["lm_head"][:, 500:]).any()
+    for stack, L in (("dense_layers", 1), ("moe_layers", 2)):
+        ck, p = tree[stack], held[stack]
+        assert set(p) == set(ck) - {"w_uq"} | {"w_uq_nope", "w_uq_rope"}  # a leaf is replaced, never held twice
+        assert p["w_uq_nope"].shape == (L, H, dn, R) and p["w_uq_rope"].shape == (L, dr, H, R)
+        assert p["w_uk"].shape == (L, H, dn, C) and p["w_uv"].shape == (L, H, dv, C)
+        assert p["w_dkv"].shape == (L, E, lm.latent_width) and not np.asarray(p["w_dkv"][..., C + dr:]).any()
+        w_uq = np.concatenate([np.asarray(p["w_uq_nope"]).transpose(0, 3, 1, 2), np.asarray(p["w_uq_rope"]).transpose(0, 3, 2, 1)], -1)
+        np.testing.assert_array_equal(w_uq.reshape(L, R, -1), np.asarray(ck["w_uq"]))  # a head's columns: [nope | rope]
+        np.testing.assert_array_equal(np.asarray(p["w_uk"]).transpose(0, 3, 1, 2).reshape(L, C, -1), np.asarray(ck["w_uk"]))
+        np.testing.assert_array_equal(np.asarray(p["w_uv"]).transpose(0, 3, 1, 2).reshape(L, C, -1), np.asarray(ck["w_uv"]))
+        np.testing.assert_array_equal(np.asarray(p["w_dkv"][..., :C + dr]), np.asarray(ck["w_dkv"]))
+        if lm.index_topk:
+            assert p["w_iq"].shape == (L, lm.index_n_heads, lm.index_head_dim, R)
+            np.testing.assert_array_equal(np.asarray(p["w_iq"]).transpose(0, 3, 1, 2).reshape(L, R, -1), np.asarray(ck["w_iq"]))
+        else:  # index_topk 0: a tree without indexer leaves stays without
+            assert not {"w_iq", "w_ik", "w_iw", "ik_norm", "ik_bias"} & set(p)
+        for k in set(ck) - {"w_uq", "w_uk", "w_uv", "w_dkv", "w_iq"}:
+            assert p[k] is ck[k]
+    host = jax.tree.map(lambda x: np.asarray(x.astype(jnp.bfloat16)), tree)  # what load_model hands the registry
+    from_host = mla_moe.held_params(cfg, host)
+    moved = ("w_uq_nope", "w_uq_rope", "w_uk", "w_uv", "w_iq")
+    for stack in ("dense_layers", "moe_layers"):
+        assert all(isinstance(x, jax.Array) == (k in moved) for k, x in from_host[stack].items())
+    assert isinstance(from_host["lm_head"], np.ndarray) and from_host["tok_embed"] is host["tok_embed"]
+    for a, b in zip(jax.tree.leaves(from_host), jax.tree.leaves(mla_moe.held_params(cfg, jax.tree.map(jnp.asarray, host)))):
+        assert a.dtype == b.dtype and np.array_equal(np.asarray(a).astype(np.float32), np.asarray(b).astype(np.float32))
+
+
+@pytest.mark.parametrize("name", TINY)
+def test_logical_axes_describe_the_held_tree_and_a_mesh_takes_it(name):
+    from django_assistant_bot_tpu.parallel import MeshAxes, make_mesh, shard_pytree
+
+    conf, cfg, tree = _tiny(name)
+    held = mla_moe.held_params(cfg, tree)
+    axes = mla_moe.logical_axes(cfg)
+    is_axes = lambda x: isinstance(x, tuple)  # noqa: E731
+    assert jax.tree.structure(axes, is_leaf=is_axes) == jax.tree.structure(held)
+    for names, leaf in zip(jax.tree.leaves(axes, is_leaf=is_axes), jax.tree.leaves(held)):
+        assert len(names) == leaf.ndim
+        assert all(leaf.shape[i] == cfg.num_heads * (cfg.latent_moe.v_head_dim if leaf.ndim == 3 else 1)
+                   for i, n in enumerate(names) if n == "heads")  # heads on an axis of their own, but for wo's rows
+    mesh = make_mesh(MeshAxes(), devices=jax.devices()[:1])
+    with mesh:
+        placed = shard_pytree(held, axes, mesh)
+    for a, b in zip(jax.tree.leaves(placed), jax.tree.leaves(held)):
+        assert a.shape == b.shape and np.array_equal(np.asarray(a), np.asarray(b))

@@ -128,7 +128,7 @@ def test_the_step_without_an_indexer_makes_the_one_kernel_call_it_made(served, m
     monkeypatch.setattr(mla_moe, "latent_decode_kv_path", lambda *a, **k: "kernel")
     # the kernel wants heads in whole sublane tiles and a lane-wide latent; nothing runs here
     cfg8 = dataclasses.replace(cfg, num_heads=8, latent_moe=dataclasses.replace(cfg.latent_moe, kv_lora_rank=128))
-    params8 = jax.eval_shape(lambda: mla_moe.init(cfg8, jax.random.key(0)))
+    params8 = jax.eval_shape(lambda: mla_moe.held_params(cfg8, mla_moe.init(cfg8, jax.random.key(0))))
     cache = jax.eval_shape(lambda: mla_moe.init_paged_cache(cfg8, 4, 32, 16))
     jaxpr = jax.make_jaxpr(lambda p, t, c, bt: mla_moe.decode_step_paged(p, cfg8, t, c, bt))(
         params8, jnp.zeros((4,), jnp.int32), cache, jnp.zeros((4, 8), jnp.int32))
@@ -156,12 +156,40 @@ def test_family_tree_matches_the_programs_init_tree_leaf_for_leaf(served):
     own = jax.eval_shape(lambda: mla_moe.init(cfg, jax.random.key(0)))
     a, b = (jax.tree_util.tree_flatten_with_path(t)[0] for t in (own, params))
     assert [(k, v.shape) for k, v in a] == [(k, v.shape) for k, v in b]
-    axes = mla_moe.logical_axes(cfg)
-    assert jax.tree.structure(axes, is_leaf=lambda x: isinstance(x, tuple)) == jax.tree.structure(own)
+    axes = mla_moe.logical_axes(cfg)  # of the tree the device holds: `w_uq` in two leaves, five more in another form
+    held = jax.eval_shape(lambda: mla_moe.held_params(cfg, mla_moe.init(cfg, jax.random.key(0))))
+    assert jax.tree.structure(axes, is_leaf=lambda x: isinstance(x, tuple)) == jax.tree.structure(held) != jax.tree.structure(own)
     # the share: a rank's tree holds its experts only, the router stays whole
     share = dict(conf, hf=dict(conf["hf"], n_routed_experts=4, ep_size=4, ep_rank=3))
     tree = jax.eval_shape(lambda: family.served_params(share, 1))
     assert tree["moe_layers"]["w_gate"].shape == (2, 4, 64, 32) and tree["moe_layers"]["router"].shape == (2, 64, 16)
+
+
+@pytest.mark.parametrize("name", ["mla_moe_tiny.json", "dsa_moe_tiny.json"])
+def test_a_checkpoint_of_inits_tree_loads_to_the_tree_the_in_memory_path_holds(name, tmp_path):
+    """``held_params`` is the one place that knows both forms: a checkpoint written from ``init``'s tree (the
+    checkpoint's keys and shapes) reaches the engine, through the registry, as the tree ``models.held_params`` makes
+    of the same arrays in memory, and as ``logical_axes`` describes."""
+    from django_assistant_bot_tpu import models
+
+    with open(os.path.join(HERE, "data", name)) as f:
+        hf = json.load(f)["hf"]
+    cfg = dataclasses.replace(DecoderConfig.from_hf(hf, dtype=jnp.float32), max_seq_len=256)
+    tree = mla_moe.init(cfg, jax.random.key(3))
+    path = str(tmp_path / "ckpt")
+    save_model(path, "decoder", cfg, tree)
+    assert load_model(path)[2]["moe_layers"]["w_uq"].shape == tree["moe_layers"]["w_uq"].shape  # on disk: as it was
+    reg = ModelRegistry.from_config(_spec(path))
+    try:
+        got = reg.get_generator("m").params
+    finally:
+        reg.stop()
+    want = models.held_params(cfg, tree)
+    assert jax.tree.structure(got) == jax.tree.structure(want) == jax.tree.structure(
+        mla_moe.logical_axes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and np.array_equal(np.asarray(a), np.asarray(b))
+    assert models.held_params(models.DecoderConfig.tiny(), {"layers": {}}) == {"layers": {}}  # llama holds a checkpoint's own tree
 
 
 def test_engine_streams_the_references_greedy_tokens_and_counts_the_picks(served):

@@ -9,6 +9,7 @@ kernel alone, by shape: what sets ``ops/attention.py`` ``flash_tiles``.
     python3 tools/time_prefill.py --trace 1x512,1x1024  # where a program's device time goes
     python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --decode                 # the decode tick alone
     python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --decode --trace 2x8192  # ... and by scope
+    python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --decode --trace 2x8192 --hlo  # ... and what each operation reads
 
 Builds the engine of ``benchmarks/configs/<config>.json`` (default
 ``qwen2.5-7b-instruct``: Qwen2.5-7B int8; the configuration's seeded weights,
@@ -34,7 +35,12 @@ contexts of a quarter, a half and seven eighths of ``max_seq_len`` (4k / 8k /
 14k for ``deepseek-v3.2-ep16``): ``{"<rows>x<context>": ms a STEP}`` to
 ``chiprun_out/time_decode.<config>.json``; with ``--trace <rows>x<context>[,...]``
 the same ticks by scope and operation, ms a step, to
-``chiprun_out/trace_decode.<config>.json``.
+``chiprun_out/trace_decode.<config>.json``.  With ``--hlo`` besides, the
+optimised HLO of the engine's ``jit_tick`` goes to
+``chiprun_out/hlo_decode.<config>.txt`` and every operation over 0.05 ms a
+step is printed with its scope, the parameter leaf it reads and the bytes it
+reads and writes (``tools/hlo_table.py``; ``"table"`` in the JSON): how PERF.md
+section 5's table of PR 43 was made.
 """
 
 import argparse
@@ -53,6 +59,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from benchmarks import families, sut  # noqa: E402
+from django_assistant_bot_tpu import models  # noqa: E402
 from django_assistant_bot_tpu.models.config import DecoderConfig  # noqa: E402
 from django_assistant_bot_tpu.serving import ByteTokenizer, GenerationEngine  # noqa: E402
 
@@ -85,10 +92,12 @@ def build_engine(conf: dict) -> GenerationEngine:
     family = families.load(conf, os.path.join(ROOT, "benchmarks"))
     s = conf["serving"]
     act = getattr(jnp, s.get("dtype", "bfloat16"))
-    params = sut.wrap_params(family.served_params(conf, conf["weights"]["seed"]), act)
+    cfg = DecoderConfig.from_hf(conf["hf"], dtype=act)
+    # the family's tree is a checkpoint's: the form the block holds, as the registry makes it at load
+    params = models.held_params(cfg, sut.wrap_params(family.served_params(conf, conf["weights"]["seed"]), act))
     jax.block_until_ready(params)
     return GenerationEngine(
-        DecoderConfig.from_hf(conf["hf"], dtype=act), params, ByteTokenizer(),
+        cfg, params, ByteTokenizer(),
         max_slots=s["max_slots"], max_seq_len=s["max_seq_len"], chunk_size=s["chunk_size"],
         kv_page_size=s["kv_page_size"], kv_pages=s["kv_pages"], prefix_cache_size=0,
         prefill_buckets=s.get("prefill_buckets"), prefill_wave=s.get("prefill_wave", 0),
@@ -110,12 +119,15 @@ def time_programs(eng: GenerationEngine) -> dict:
     return out
 
 
-def trace_programs(program, shapes: str, trace_dir: str, per: int = 1) -> dict:
+def trace_programs(program, shapes: str, trace_dir: str, per: int = 1, hlo: str = "") -> dict:
     """Device time of each program of ``shapes`` (``1x512,1x1024``) by named
     scope and by operation, ms a call (``per`` steps a call: ms a step), from a
     profiler trace of 3 calls of ``program(rows, size)()``
-    (``benchmarks/trace_reduce.py``)."""
+    (``benchmarks/trace_reduce.py``).  ``hlo``: the program's optimised HLO
+    text; every operation over 0.05 ms is then listed with what it reads and
+    writes (``tools/hlo_table.py``)."""
     from benchmarks import trace_reduce
+    from tools import hlo_table
 
     def per_call(seconds: dict) -> dict:
         return {k: round(v / (3 * per) * 1e3, 4) for k, v in sorted(seconds.items(), key=lambda kv: -kv[1])}
@@ -131,9 +143,13 @@ def trace_programs(program, shapes: str, trace_dir: str, per: int = 1) -> dict:
             jax.block_until_ready(call())
         jax.profiler.stop_trace()
         r = trace_reduce.reduce(trace_reduce.find_xplane(trace_dir))
+        ops = per_call(r["op_s"])
         out[shape] = {"program_ms": per_call(r["program_s"]), "scope_ms": per_call(r["scope_s"] or {}),
-                      "op_ms": dict(list(per_call(r["op_s"]).items())[:24])}
+                      "op_ms": dict(list(ops.items())[:24])}
         print(shape, json.dumps(out[shape]), flush=True)
+        if hlo:  # every operation then, so that tools/hlo_table.py can make the table again from the two files
+            out[shape].update(op_ms=ops, table=hlo_table.table(hlo, ops))
+            print(hlo_table.render(out[shape]["table"]), flush=True)
     return out
 
 
@@ -182,6 +198,9 @@ def decode_program(eng: GenerationEngine):
 
         return call
 
+    # the tick as the calls above compile it (lowering donates nothing)
+    program.hlo = lambda: eng._decode_tick.lower(
+        eng.params, tokens, state["cache"], jnp.arange(B) < 1, bt, temps, top_ps, state["rng"]).compile().as_text()
     return program
 
 
@@ -228,17 +247,24 @@ def main() -> int:
     ap.add_argument("--flash", action="store_true", help="time the flash kernel alone instead of the programs")
     ap.add_argument("--decode", action="store_true", help="time the decode tick alone, by rows and context, instead of the prefill programs")
     ap.add_argument("--trace", metavar="ROWSxBUCKET[,...]", help="trace these programs instead: device ms a call by scope and operation")
+    ap.add_argument("--hlo", action="store_true", help="with --decode --trace: write the tick's optimised HLO and list what each operation over 0.05 ms a step reads and writes")
     args = ap.parse_args()
     sut.enable_compile_cache()
     print("device", jax.devices()[0].platform, jax.devices()[0].device_kind, flush=True)
     suffix = ".json" if args.config == "qwen2.5-7b-instruct" else f".{args.config}.json"
     trace_dir = os.path.join(ROOT, ".cache", "time_prefill_trace")
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     if args.flash:
         out, name = time_flash(), "time_flash.json"
     elif args.decode:
         eng = build_engine(load_conf(args.config))
         if args.trace:
-            out = trace_programs(decode_program(eng), args.trace, trace_dir, per=eng.burst)
+            program, hlo = decode_program(eng), ""
+            if args.hlo:
+                hlo = program.hlo()
+                with open(os.path.join(ROOT, "chiprun_out", "hlo_decode" + suffix.replace(".json", ".txt")), "w") as f:
+                    f.write(hlo)
+            out = trace_programs(program, args.trace, trace_dir, per=eng.burst, hlo=hlo)
         else:
             out = time_decode(eng)
         name = ("trace_decode" if args.trace else "time_decode") + suffix
@@ -247,7 +273,6 @@ def main() -> int:
         name = "trace_prefill" + suffix
     else:
         out, name = time_programs(build_engine(load_conf(args.config))), "time_prefill" + suffix
-    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", name), "w") as f:
         json.dump(out, f, indent=1)
     print(json.dumps(out))
