@@ -12,8 +12,10 @@ with three families, all jit/pjit-first:
   dense dispatch einsums (MXU-friendly), experts sharded over the ``expert`` axis;
   and the dropless sigmoid-routed layer that is told which experts it holds.
 - :mod:`.mla_moe` — latent-attention (MLA) decoder over a paged latent cache with
-  a leading dense layer and routed + shared experts (DeepSeek-V3 family), with
-  :mod:`.llama`'s paged entry points; :func:`module_for` picks by ``cfg.arch``.
+  a leading dense layer and routed + shared experts (DeepSeek-V3 family), or as
+  shortcut-connected double layers with a softmax router and identity experts
+  (LongCat-Flash), with :mod:`.llama`'s paged entry points; :func:`module_for`
+  picks by ``cfg.arch``.
 
 Parameters are plain pytrees of jnp arrays with a parallel pytree of logical axis
 names consumed by :mod:`..parallel.sharding`.

@@ -95,6 +95,17 @@ class LatentMoEConfig:
     scores every cached token for every query, the ``index_topk`` best are
     attended, and the cache holds an ``index_head_dim``-wide index key per
     token per layer beside the latent row.
+
+    The shortcut-connected double layer (``longcat_flash``; ``double_layer``):
+    a layer is attention, dense FFN, attention, dense FFN, and ONE expert layer
+    that reads the first sublayer's normed hidden state and joins the residual
+    at the layer's end.  No dense stack and no shared expert; the cache holds a
+    row per attention SUBLAYER, two a layer.  Its router scores by softmax
+    (``scoring_func``) over ``router_experts`` routed experts and
+    ``zero_experts`` identity experts after them: a pick of one adds its weight
+    times the expert layer's input, on the rank the token lives on.  ``q_scale``
+    / ``kv_scale`` multiply the queries after their up-projection and the normed
+    latent (``mla_scale_q_lora`` / ``mla_scale_kv_lora``); 1.0 elsewhere.
     """
 
     q_lora_rank: int
@@ -119,8 +130,21 @@ class LatentMoEConfig:
     index_n_heads: int = 0
     index_head_dim: int = 0
     index_topk: int = 0
+    scoring_func: str = "sigmoid"
+    zero_experts: int = 0
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
+    double_layer: bool = False
 
     def __post_init__(self):
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring_func {self.scoring_func!r}: sigmoid or softmax")
+        if self.scoring_func == "softmax" and self.n_group != 1:
+            raise ValueError("softmax scores are not picked by groups (n_group 1)")
+        if self.double_layer and (self.first_dense_layers or self.n_shared_experts or self.index_topk):
+            raise ValueError("the double layer has no leading dense layer, no shared expert and no indexer")
+        if not self.double_layer and (self.first_dense_layers < 1 or self.zero_experts):
+            raise ValueError("only the double layer runs without a leading dense layer or with identity experts")
         if self.router_experts % self.ep_size or not 0 <= self.ep_rank < self.ep_size:
             raise ValueError(
                 f"{self.router_experts} experts do not divide over ep_size={self.ep_size}, "
@@ -150,6 +174,11 @@ class LatentMoEConfig:
         return self.ep_rank * self.experts_held
 
     @property
+    def router_width(self) -> int:
+        """The router's outputs: every rank's routed experts, then the identity experts."""
+        return self.router_experts + self.zero_experts
+
+    @property
     def latent_width(self) -> int:
         """One cached row: latent | rotary key, padded to whole 128-lane tiles."""
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
@@ -160,6 +189,20 @@ class LatentMoEConfig:
 _OTHER_BLOCK_KEYS = ("kv_lora_rank", "n_routed_experts", "layer_types")
 _PLAIN_MODEL_TYPES = ("llama", "mistral", "mixtral", "qwen2", "phi3", "gemma")
 _LATENT_MOE_MODEL_TYPES = ("axk1", "deepseek_v3", "deepseek_v32")
+_DOUBLE_LAYER_MODEL_TYPES = ("longcat_flash",)
+
+
+def _expert_share(hf: Mapping[str, Any], refuse):
+    """-> (ep_size, ep_rank, routed experts over all ranks).  A published config
+    has ``ep_size`` 1 and ``n_routed_experts`` is all of them; a deployment's
+    names ``ep_rank`` beside ``ep_size``, and ``n_routed_experts`` counts the
+    experts held by THIS rank."""
+    ep_size, held = int(hf.get("ep_size", 1)), int(hf["n_routed_experts"])
+    if "ep_rank" in hf:
+        return ep_size, int(hf["ep_rank"]), held * ep_size
+    if ep_size == 1:
+        return 1, 0, held
+    refuse(f"ep_size {ep_size} without ep_rank: which share of the experts is held here?")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,6 +288,8 @@ class DecoderConfig:
     def from_hf(cls, hf: Mapping[str, Any], dtype=jnp.bfloat16) -> "DecoderConfig":
         model_type = hf.get("model_type")
         other = [k for k in _OTHER_BLOCK_KEYS if hf.get(k)]
+        if model_type in _DOUBLE_LAYER_MODEL_TYPES:
+            return cls._from_hf_longcat_flash(hf, dtype)
         if model_type in _LATENT_MOE_MODEL_TYPES or {"kv_lora_rank", "n_routed_experts"} <= set(other):
             return cls._from_hf_latent_moe(hf, dtype)
         if other and model_type not in _PLAIN_MODEL_TYPES:
@@ -407,14 +452,7 @@ class DecoderConfig:
             refuse("q_lora_rank is null: full-rank queries are not implemented")
         if (hf.get("hidden_act") or "silu") != "silu":
             refuse(f"hidden_act {hf.get('hidden_act')!r}")
-        ep_size = int(hf.get("ep_size", 1))
-        held = int(hf["n_routed_experts"])
-        if "ep_rank" in hf:
-            ep_rank, router = int(hf["ep_rank"]), held * ep_size
-        elif ep_size == 1:
-            ep_rank, router = 0, held
-        else:
-            refuse(f"ep_size {ep_size} without ep_rank: which share of the experts is held here?")
+        ep_size, ep_rank, router = _expert_share(hf, refuse)
         rs = hf.get("rope_scaling")
         rope_scaling, scale_mult = None, 1.0
         if rs:
@@ -433,6 +471,8 @@ class DecoderConfig:
                 float(rs.get("original_max_position_embeddings") or hf.get("max_position_embeddings", 4096)),
                 mscale(rs.get("mscale") or 1) / mscale(m_all), True,
             )
+        if not 0 < int(hf.get("first_k_dense_replace", 0)) < int(hf["num_hidden_layers"]):
+            refuse("needs at least one leading dense layer and one expert layer")
         lm = LatentMoEConfig(
             q_lora_rank=int(hf["q_lora_rank"]), kv_lora_rank=int(hf["kv_lora_rank"]),
             qk_nope_head_dim=int(hf["qk_nope_head_dim"]), qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
@@ -449,8 +489,6 @@ class DecoderConfig:
             index_head_dim=int(hf.get("index_head_dim") or 0) if index_topk else 0,
             index_topk=index_topk,
         )
-        if not 0 < lm.first_dense_layers < int(hf["num_hidden_layers"]):
-            refuse("needs at least one leading dense layer and one expert layer")
         return cls(
             vocab_size=hf["vocab_size"], hidden_size=hf["hidden_size"],
             intermediate_size=hf["intermediate_size"], num_layers=hf["num_hidden_layers"],
@@ -460,6 +498,53 @@ class DecoderConfig:
             rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
             tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
             experts_per_token=int(hf["num_experts_per_tok"]), latent_moe=lm, dtype=dtype,
+        )
+
+    @classmethod
+    def _from_hf_longcat_flash(cls, hf: Mapping[str, Any], dtype) -> "DecoderConfig":
+        """LongCat-Flash's keys (``model_type`` ``longcat_flash``): ``num_layers``
+        shortcut-connected double layers, ``ffn_hidden_size`` /
+        ``expert_ffn_hidden_size``, ``moe_topk`` of ``n_routed_experts`` +
+        ``zero_expert_num`` softmax scores under a correction bias, weights not
+        normalised, ``mla_scale_q_lora`` / ``mla_scale_kv_lora``.  The expert
+        share as for the DeepSeek-V3 family: a deployment's ``n_routed_experts``
+        counts the experts held by ``ep_rank`` of ``ep_size``."""
+
+        def refuse(why):
+            raise ValueError(f"shortcut-connected MoE config (model_type {hf.get('model_type')!r}): {why}")
+
+        zero = int(hf.get("zero_expert_num") or 0)
+        if zero and hf.get("zero_expert_type", "identity") != "identity":
+            refuse(f"zero_expert_type {hf.get('zero_expert_type')!r}: only identity experts are implemented")
+        if hf.get("attention_bias") or hf.get("router_bias"):
+            refuse("attention_bias / router_bias: the projections and the router carry no biases here")
+        if not hf.get("q_lora_rank"):
+            refuse("q_lora_rank is null: full-rank queries are not implemented")
+        if hf.get("rope_scaling"):
+            refuse("rope_scaling: only plain rotary tables are implemented for this block")
+        if (hf.get("hidden_act") or "silu") != "silu":
+            refuse(f"hidden_act {hf.get('hidden_act')!r}")
+        ep_size, ep_rank, router = _expert_share(hf, refuse)
+        E, R, C = int(hf["hidden_size"]), int(hf["q_lora_rank"]), int(hf["kv_lora_rank"])
+        lm = LatentMoEConfig(
+            q_lora_rank=R, kv_lora_rank=C,
+            qk_nope_head_dim=int(hf["qk_nope_head_dim"]), qk_rope_head_dim=int(hf["qk_rope_head_dim"]),
+            v_head_dim=int(hf["v_head_dim"]), moe_intermediate_size=int(hf["expert_ffn_hidden_size"]),
+            router_experts=router, first_dense_layers=0, n_shared_experts=0,
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+            ep_size=ep_size, ep_rank=ep_rank, router_bias=True, scoring_func="softmax", zero_experts=zero,
+            q_scale=(E / R) ** 0.5 if hf.get("mla_scale_q_lora") else 1.0,
+            kv_scale=(E / C) ** 0.5 if hf.get("mla_scale_kv_lora") else 1.0,
+            double_layer=True,
+        )
+        return cls(
+            vocab_size=hf["vocab_size"], hidden_size=E, intermediate_size=hf["ffn_hidden_size"],
+            num_layers=hf["num_layers"], num_heads=hf["num_attention_heads"], num_kv_heads=hf["num_attention_heads"],
+            head_dim=lm.qk_head_dim, max_seq_len=hf.get("max_position_embeddings", 8192),
+            rope_theta=float(hf.get("rope_theta", 10_000.0)), rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+            tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+            experts_per_token=int(hf["moe_topk"]), latent_moe=lm, dtype=dtype,
         )
 
     @classmethod

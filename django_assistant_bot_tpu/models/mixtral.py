@@ -75,8 +75,9 @@ def moe_mlp(cfg: DecoderConfig, p, x: jnp.ndarray) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Dropless sigmoid-routed experts, a layer told which experts it holds
-# (DeepSeek-V3 family; models/mla_moe.py)
+# Dropless routed experts, a layer told which experts it holds: sigmoid scores
+# picked by groups (DeepSeek-V3 family) or softmax scores with identity experts
+# (LongCat-Flash); models/mla_moe.py
 # ---------------------------------------------------------------------------
 
 # rows of one grouped-matmul tile, and the largest token count the dense pass takes
@@ -85,8 +86,15 @@ DENSE_MAX_TOKENS = 64
 # the held experts' three matrices: one layer's ``[held, ...]`` or the whole stack ``[layers, held, ...]``
 HELD_KEYS = ("w_gate", "w_up", "w_down")
 # counters a routed layer returns, per call: [picks, picks on held experts,
-# layer-steps with a token, held experts hit] then tokens per held expert
+# layer-steps with a token, held experts hit] then tokens per held expert and,
+# where the router has identity experts (:func:`zero_stat_width`), the picks
+# that fell on them and the tokens by their number of REAL picks, 0..top_k
 MOE_STAT_HEAD = 4
+
+
+def zero_stat_width(cfg: DecoderConfig) -> int:
+    """Trailing counters of a layer whose router has identity experts: 0 without."""
+    return 2 + cfg.experts_per_token if cfg.latent_moe.zero_experts else 0
 
 
 @jax.named_scope("moe/router")
@@ -120,6 +128,25 @@ def route_sigmoid_groups(lm, top_k: int, xt: jnp.ndarray, router: jnp.ndarray, b
     return idx, w * lm.routed_scaling_factor
 
 
+@jax.named_scope("moe/router")
+def route_softmax(lm, top_k: int, xt: jnp.ndarray, router: jnp.ndarray, bias=None):
+    """-> (ids [T, K] over the router's whole width, weights [T, K] f32).
+
+    float32 softmax scores over ``router_width`` outputs (every rank's routed
+    experts, then the identity experts); top-``K`` of the scores plus ``bias``
+    (the correction bias: on the picks only); weights are the picked scores
+    themselves times ``routed_scaling_factor``, normalised over the picks only
+    under ``norm_topk_prob``."""
+    scores = jax.nn.softmax(
+        jnp.einsum("te,ex->tx", xt.astype(jnp.float32), router.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST), axis=-1)
+    idx = jax.lax.top_k(scores if bias is None else scores + bias.astype(jnp.float32), top_k)[1]
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if lm.norm_topk_prob:
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-20)
+    return idx, w * lm.routed_scaling_factor
+
+
 def _swiglu_tile(x, wg, wu, wd, w_row, dtype):
     """One expert over one tile of rows, each row's result times its weight
     BEFORE the down-projection (linear, so equal to weighting after it)."""
@@ -131,7 +158,7 @@ def _swiglu_tile(x, wg, wu, wd, w_row, dtype):
 def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray, layer=None):
     """The routed part of an expert layer, as THIS rank computes it:
     ``sum over picked experts held here of g_e * expert_e(x)`` -> (y [B, S, E],
-    stats int32 [MOE_STAT_HEAD + experts_held]).
+    stats int32 [MOE_STAT_HEAD + experts_held + zero_stat_width]).
 
     ``p["w_gate"]``, ``p["w_up"]``, ``p["w_down"]`` are one layer's held experts
     ``[held, ...]`` or, with ``layer`` (a traced index), the WHOLE stack
@@ -141,7 +168,10 @@ def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray, 
     Routes over all ``router_experts``; picks that land on another rank's
     experts add nothing here (their ranks add them; nothing stands in for the
     exchange).  **No capacity and no dropped token**, whatever the router does.
-    Two call shapes, by the static row count:
+    A pick past the routed experts is an **identity expert** (``zero_experts``):
+    it adds its weight times ``x`` itself, in full on the rank the token lives
+    on, and costs nothing: such picks never enter the work lists, the tiles or
+    the tokens per expert.  Two call shapes, by the static row count:
 
     - up to ``DENSE_MAX_TOKENS`` tokens (a decode step): an expert runs over
       every row with its column of a ``[T, held]`` combine matrix as row
@@ -178,7 +208,17 @@ def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray, 
         stack, layer = tuple(w[None] for w in stack), jnp.zeros((), jnp.int32)
     elif not kernel and layer is not None:
         stack = tuple(jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False) for w in stack)
-    idx, w = route_sigmoid_groups(lm, K, xt, p["router"], p.get("router_bias"))
+    route = route_softmax if lm.scoring_func == "softmax" else route_sigmoid_groups
+    idx, w = route(lm, K, xt, p["router"], p.get("router_bias"))
+    zero = idx >= lm.router_experts  # [T, K]: picks on identity experts (none without them)
+
+    def finish(y):
+        """The held experts' sum ``y`` [T, E] plus the identity experts' part -> the layer's result."""
+        if lm.zero_experts:
+            with jax.named_scope("moe/zero"):
+                y = y.astype(jnp.float32) + jnp.where(zero, w, 0.0).sum(-1)[:, None] * xt.astype(jnp.float32)
+        return y.astype(cfg.dtype).reshape(B, S, E), stats
+
     with jax.named_scope("moe/dispatch"):
         local = idx - lm.first_expert  # [T, K]
         here = (local >= 0) & (local < Xh) & ok[:, None]
@@ -190,6 +230,9 @@ def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray, 
             jnp.stack([ok.sum() * K, here.sum(), ok.any().astype(jnp.int32), hit.sum()]).astype(jnp.int32),
             per_expert,
         ])
+        if lm.zero_experts:
+            by_real = jax.nn.one_hot(K - zero.sum(-1), K + 1, dtype=jnp.int32) * ok[:, None]
+            stats = jnp.concatenate([stats, (zero & ok[:, None]).sum()[None].astype(jnp.int32), by_real.sum(0)])
     if T <= DENSE_MAX_TOKENS:
         with jax.named_scope("moe/dispatch"):
             combine = jnp.einsum("tkx,tk->tx", onehot, w)  # [T, Xh] f32
@@ -210,7 +253,7 @@ def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray, 
                 h = jax.nn.silu(jnp.einsum("te,xef->txf", xt, wg)) * jnp.einsum("te,xef->txf", xt, wu)
                 h = (h.astype(jnp.float32) * combine[:, :, None]).astype(cfg.dtype)
                 y = jnp.einsum("txf,xfe->te", h, wd, preferred_element_type=jnp.float32)
-        return y.astype(cfg.dtype).reshape(B, S, E), stats
+        return finish(y)
 
     tm = GROUP_TILE
     with jax.named_scope("moe/dispatch"):
@@ -255,8 +298,7 @@ def held_experts_mlp(cfg: DecoderConfig, p, x: jnp.ndarray, valid: jnp.ndarray, 
             with jax.named_scope("moe/combine"):
                 return acc.at[tok[i]].add(y_tile)  # dead rows add zeros
 
-    y = jax.lax.fori_loop(0, n_tiles, tile_body, jnp.zeros((T, E), jnp.float32))
-    return y.astype(cfg.dtype).reshape(B, S, E), stats
+    return finish(jax.lax.fori_loop(0, n_tiles, tile_body, jnp.zeros((T, E), jnp.float32)))
 
 
 @jax.named_scope("moe/shared")

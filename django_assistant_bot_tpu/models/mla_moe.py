@@ -1,5 +1,6 @@
-"""Latent-attention + routed-expert decoder (DeepSeek-V3 family), served as one
-rank of an expert-parallel deployment.
+"""Latent-attention + routed-expert decoder (DeepSeek-V3 family; LongCat-Flash's
+shortcut-connected double layer), served as one rank of an expert-parallel
+deployment.
 
 The block, per layer (``n`` RMSNorm; ``cfg.latent_moe`` has the sizes):
 
@@ -34,16 +35,35 @@ The block, per layer (``n`` RMSNorm; ``cfg.latent_moe`` has the sizes):
   latent rows.  With ``index_topk`` 0 none of this exists: the same weights,
   cache and programs as before it was written.
 
+- the shortcut-connected double layer (``cfg.latent_moe.double_layer``;
+  LongCat-Flash): ``x1 = x + A_0(n(x))``, ``h = n(x1)``, ``m = MoE(h)``,
+  ``x2 = x1 + D_0(h)``, ``x3 = x2 + A_1(n(x2))``, ``out = x3 + D_1(n(x3)) + m``:
+  two attention sublayers and two dense SwiGLUs, each with its own norms and
+  weights, and ONE expert layer that reads the first sublayer's normed hidden
+  state and joins the residual at the layer's end (in a deployment its
+  exchange hides behind the second sublayer; on one chip there is none and
+  the order is XLA's).  No leading dense layer, no shared expert; softmax
+  scores over the routed experts and ``zero_experts`` identity experts
+  (:func:`.mixtral.held_experts_mlp`); queries and the normed latent scaled
+  (``q_scale``, ``kv_scale``: the cached row holds the SCALED latent).
+  ``dense_layers`` then holds the ``2 * num_layers`` sublayers in order, the
+  weights a dense layer has, and ``moe_layers`` the routers and held experts
+  of the ``num_layers`` expert layers; the cache's layer axis counts
+  attention sublayers (:func:`cache_layers`), rows ``2l`` and ``2l + 1``.
+
 Two stacks of weights, ``dense_layers`` and ``moe_layers``, each scanned over
 its own leading axis, but for the held experts' three matrices: the scan
 bodies close over those stacks whole and index them by ``(layer, expert)``
-(:func:`_scan_stacks`), so that a step reads only the experts a token landed
+(:func:`_scan_layers`), so that a step reads only the experts a token landed
 on and no program copies a layer's experts.  The same entry points as
 :mod:`.llama`'s paged path (``serving/engine.py`` picks the module once, by
 ``cfg.arch``):
 ``prefill``, ``insert_sequences_paged``, ``copy_pages``,
 ``prefill_chunk_paged``, ``prefill_suffix_paged``, ``decode_step_paged``,
-``init_paged_cache``, ``paged_cache_shardings``.  The contiguous cache,
+``init_paged_cache``, ``paged_cache_shardings``.  Each of the three programs
+(one-shot prefill, prefill against the cache, a decode step) defines only its
+attention sublayer; :func:`_scan_layers` runs the layers around it, whatever
+the block form.  The contiguous cache,
 speculation and quantised weights are not implemented for this block; the
 registry refuses them.
 
@@ -107,7 +127,7 @@ from ..ops.rope import apply_rope, rope_frequencies
 from ..parallel.sharding import with_constraint
 from .config import DecoderConfig
 from .llama import _embed, _head_logits
-from .mixtral import HELD_KEYS, MOE_STAT_HEAD, held_experts_mlp, shared_experts_mlp
+from .mixtral import HELD_KEYS, MOE_STAT_HEAD, held_experts_mlp, shared_experts_mlp, zero_stat_width
 
 Params = Dict[str, Any]
 
@@ -127,7 +147,7 @@ def kv_kind(cfg: DecoderConfig) -> str:
 class LatentKVCache(NamedTuple):
     """Page pool of latent rows.  kv: [L, P, page, W], one row per token per
     layer (``c_kv | k_rope | zero pad``); lengths: [B] tokens present per slot;
-    stats: int32 [2, MOE_STAT_HEAD + experts_held (+ DSA_STAT)], the routed
+    stats: int32 [2, MOE_STAT_HEAD + experts_held (+ zero_stat_width) (+ DSA_STAT)], the routed
     layers' counters since the last tick read them (row 0: decode steps, row 1:
     prefill) and, where the block selects, the sparse attention's, summed
     on the device and handed out with a tick's tokens; idx: [L, P, page, Di]
@@ -172,12 +192,17 @@ def check_serving(*, speculative=0, prefix_cache=0, kv_cache_dtype=None,
                          "serve the weights in the checkpoint's dtype")
 
 
+def cache_layers(cfg: DecoderConfig) -> int:
+    """Rows of the cache's layer axis: one per attention sublayer (two a double layer)."""
+    return cfg.num_layers * (2 if cfg.latent_moe.double_layer else 1)
+
+
 def kv_bytes_per_token(cfg: DecoderConfig, kv_dtype=None) -> int:
     """Bytes one cached token takes over all layers (pad lanes included, and
     the index key where the block has an indexer)."""
     lm = cfg.latent_moe
     width = lm.latent_width + (lm.index_head_dim if lm.index_topk else 0)
-    return cfg.num_layers * width * jnp.dtype(kv_dtype or cfg.dtype).itemsize
+    return cache_layers(cfg) * width * jnp.dtype(kv_dtype or cfg.dtype).itemsize
 
 
 def decode_kv_path(cfg: DecoderConfig, kv_dtype, page: int, *, fp8_dot: bool = False) -> str:
@@ -198,9 +223,9 @@ def init_paged_cache(cfg: DecoderConfig, batch: int, n_pages: int, page_size: in
     lm = cfg.latent_moe
     dsa = bool(lm.index_topk)
     return LatentKVCache(
-        kv=jnp.zeros((cfg.num_layers, n_pages, page_size, lm.latent_width), dtype or cfg.dtype),
+        kv=jnp.zeros((cache_layers(cfg), n_pages, page_size, lm.latent_width), dtype or cfg.dtype),
         lengths=jnp.zeros((batch,), jnp.int32),
-        stats=jnp.zeros((2, MOE_STAT_HEAD + lm.experts_held + (DSA_STAT if dsa else 0)), jnp.int32),
+        stats=jnp.zeros((2, MOE_STAT_HEAD + lm.experts_held + zero_stat_width(cfg) + (DSA_STAT if dsa else 0)), jnp.int32),
         idx=jnp.zeros((cfg.num_layers, n_pages, page_size, lm.index_head_dim), dtype or cfg.dtype) if dsa else None,
     )
 
@@ -215,6 +240,9 @@ def paged_cache_shardings(cfg: DecoderConfig, mesh, batch: int) -> LatentKVCache
 
 
 def _stack_sizes(cfg: DecoderConfig) -> tuple[int, int]:
+    """Leading axes of ``dense_layers`` and ``moe_layers``."""
+    if cfg.latent_moe.double_layer:
+        return 2 * cfg.num_layers, cfg.num_layers
     nd = cfg.latent_moe.first_dense_layers
     return nd, cfg.num_layers - nd
 
@@ -238,10 +266,11 @@ def logical_axes(cfg: DecoderConfig) -> Params:
     # the held experts stay whole on every device of this process: "expert" is the
     # axis ACROSS ranks (parallel/sharding.py), and a rank is one process here
     moe = dict(
-        attn, router=(None, E, None),
+        {} if cfg.latent_moe.double_layer else attn, router=(None, E, None),
         w_gate=(None, None, E, F), w_up=(None, None, E, F), w_down=(None, None, F, E),
-        ws_gate=(None, E, F), ws_up=(None, E, F), ws_down=(None, F, E),
     )
+    if cfg.latent_moe.n_shared_experts:
+        moe.update(ws_gate=(None, E, F), ws_up=(None, E, F), ws_down=(None, F, E))
     if cfg.latent_moe.router_bias:
         moe["router_bias"] = (None, None)
     axes = {"tok_embed": ("vocab_in", E), "final_norm": (E,), "dense_layers": dense, "moe_layers": moe}
@@ -284,13 +313,14 @@ def init(cfg: DecoderConfig, rng: jax.Array) -> Params:
         "dense_layers": dict(attn(nd), w_gate=dense((nd, E, F), E), w_up=dense((nd, E, F), E),
                              w_down=dense((nd, F, E), F)),
         "moe_layers": dict(
-            attn(nm), router=dense((nm, E, lm.router_experts), E),
+            {} if lm.double_layer else attn(nm), router=dense((nm, E, lm.router_width), E),
             w_gate=dense((nm, Xh, E, Fm), E), w_up=dense((nm, Xh, E, Fm), E), w_down=dense((nm, Xh, Fm, E), Fm),
-            ws_gate=dense((nm, E, Fs), E), ws_up=dense((nm, E, Fs), E), ws_down=dense((nm, Fs, E), Fs),
         ),
     }
+    if Fs:
+        params["moe_layers"].update(ws_gate=dense((nm, E, Fs), E), ws_up=dense((nm, E, Fs), E), ws_down=dense((nm, Fs, E), Fs))
     if lm.router_bias:
-        params["moe_layers"]["router_bias"] = jnp.zeros((nm, lm.router_experts), cfg.dtype)
+        params["moe_layers"]["router_bias"] = jnp.zeros((nm, lm.router_width), cfg.dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense((E, cfg.vocab_size), E)
     return params
@@ -334,6 +364,8 @@ def held_params(cfg: DecoderConfig, params: Params) -> Params:
         return out
 
     def stack(p: Params) -> Params:
+        if "w_uq" not in p:  # the double layer's expert stack: no attention of its own
+            return p
         moved = {k: p[k] for k in ("w_uq", "w_uk", "w_uv", "w_iq") if k in p}
         kept = {k: v for k, v in p.items() if k not in moved}
         return dict(kept, w_dkv=_zero_columns(p["w_dkv"], lm.latent_width - C - dr), **contracted_last(moved))
@@ -366,21 +398,27 @@ def _mm(pattern: str, x, w, dtype):
     return jnp.einsum(pattern, x, w.astype(dtype))
 
 
+def _scaled(x: jnp.ndarray, s: float) -> jnp.ndarray:
+    """``x * s`` from float32, rounded once (a bfloat16 ``sqrt(12)`` would itself be 0.13% off); ``x`` for 1.0."""
+    return x if s == 1.0 else (x.astype(jnp.float32) * s).astype(x.dtype)
+
+
 def _queries_and_row(cfg: DecoderConfig, p: Params, h: jnp.ndarray, cos, sin):
     """-> (q_nope [B,S,H,dn], q_rope [B,S,H,dr] rotated, row [B,S,W], c_q
-    [B,S,R]): the queries of ``h``, the row the cache keeps for it, and the
-    query latent (the indexer's queries come from it too)."""
+    [B,S,R]): the queries of ``h`` (times ``q_scale``), the row the cache keeps
+    for it (the normed latent times ``kv_scale``, the rotary key as it is), and
+    the query latent (the indexer's queries come from it too)."""
     lm = cfg.latent_moe
     B, S, _ = h.shape
     dr, C = lm.qk_rope_head_dim, lm.kv_lora_rank
     with jax.named_scope("attn/q_down"):
         c_q = rms_norm(_mm("bse,er->bsr", h, p["w_dq"], cfg.dtype), p["q_norm"], cfg.rms_norm_eps)
     with jax.named_scope("attn/q_up"):
-        q_nope = _mm("bsr,hdr->bshd", c_q, p["w_uq_nope"], cfg.dtype)
-        q_rope = apply_rope(_mm("bsr,dhr->bshd", c_q, p["w_uq_rope"], cfg.dtype), cos, sin)
+        q_nope = _scaled(_mm("bsr,hdr->bshd", c_q, p["w_uq_nope"], cfg.dtype), lm.q_scale)
+        q_rope = apply_rope(_scaled(_mm("bsr,dhr->bshd", c_q, p["w_uq_rope"], cfg.dtype), lm.q_scale), cos, sin)
     with jax.named_scope("attn/kv_down"):
         ckv = _mm("bse,ec->bsc", h, p["w_dkv"], cfg.dtype)
-        c_kv = rms_norm(ckv[..., :C], p["kv_norm"], cfg.rms_norm_eps)
+        c_kv = _scaled(rms_norm(ckv[..., :C], p["kv_norm"], cfg.rms_norm_eps), lm.kv_scale)
         k_rope = apply_rope(ckv[..., None, C:C + dr], cos, sin)[..., 0, :]
         pad = lm.latent_width - C - dr
         row = jnp.concatenate([c_kv, k_rope] + ([jnp.zeros((B, S, pad), c_kv.dtype)] if pad else []), axis=-1)
@@ -546,16 +584,26 @@ def _ffn(cfg: DecoderConfig, p: Params, x: jnp.ndarray, valid, held: Optional[Pa
     return y, stats
 
 
-def _scan_stacks(cfg: DecoderConfig, params: Params, make_body, carry):
-    """``lax.scan`` over the dense stack, then the expert stack; ``make_body
-    (held)`` returns a scan body over ``(layer params, layer index)`` that
-    hands ``held`` and the index to :func:`_ffn`.  Two compiled bodies whatever
-    the depth; per-layer outputs concatenate on the layer axis.
+def _scan_layers(cfg: DecoderConfig, params: Params, attend, valid, x, pools, *, constrain: bool = False):
+    """Every layer as ``lax.scan``s -> (x, pools, per-sublayer outputs on a
+    leading axis of :func:`cache_layers`, routed-layer counters ``[layers, n]``).
+
+    ``attend(p, x, pools, row) -> (x + A(n(x)), pools, out)`` is the calling
+    program's attention sublayer over one sublayer's weights ``p``, writing and
+    reading the cache's layer ``row``; the feed-forward parts and the residual
+    stream's order are here, once for the three programs.  Two block forms:
+
+    - one attention and one FFN a layer: a scan over the dense stack, then one
+      over the expert stack (two compiled bodies whatever the depth);
+    - the shortcut-connected double layer (the module docstring has its
+      equations): one scan, a body of two attention sublayers, two dense FFNs
+      and the expert layer, the sublayers' weights ``dense_layers[2l]`` and
+      ``[2l + 1]`` and their cache rows the same two.
 
     The held experts do NOT ride the expert scan's ``xs``: a scan's slice of
     them is a value the size of a layer's experts (1.06 GB at A.X-K1's widths)
     that XLA either reads whole (the decode step's einsums) or first copies out
-    so that a loop can index it (prefill).  The body closes over the whole
+    so that a loop can index it (prefill).  The bodies close over the whole
     stack and the layer index comes from ``xs``, as the latent pool rides the
     carry, so the experts are read in place, by ``(layer, expert)``
     (:func:`.mixtral.held_experts_mlp`).  Router, norms, shared expert and
@@ -564,9 +612,44 @@ def _scan_stacks(cfg: DecoderConfig, params: Params, make_body, carry):
     moe = params["moe_layers"]
     held = {k: moe[k] for k in HELD_KEYS}
     sliced = {k: v for k, v in moe.items() if k not in HELD_KEYS}
-    carry, y_d = jax.lax.scan(make_body(None), carry, (params["dense_layers"], jnp.arange(nd)))
-    carry, y_m = jax.lax.scan(make_body(held), carry, (sliced, jnp.arange(nd, nd + nm)))
-    return carry, jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), y_d, y_m)
+
+    def out_of(x):
+        return with_constraint(x, ("batch", "length", "embed")) if constrain else x
+
+    if cfg.latent_moe.double_layer:
+        sub = params["dense_layers"]
+
+        def body(carry, inputs):
+            x, pools = carry
+            pm, layer = inputs
+            # a sublayer's weights sliced from the stack by its own row, as a scan slices its xs: the slice is the
+            # dot's operand.  (The stack as xs in [layers, 2, ...] form made a copy of both sublayers' weights a layer)
+            p0, p1 = ({k: jax.lax.dynamic_index_in_dim(v, 2 * layer + i, 0, keepdims=False) for k, v in sub.items()}
+                      for i in (0, 1))
+            x, pools, out0 = attend(p0, x, pools, 2 * layer)
+            h = rms_norm(x, p0["mlp_norm"], cfg.rms_norm_eps)
+            m, stats = held_experts_mlp(cfg, dict(pm, **held), h, valid, layer)  # the shortcut: joins below
+            x, pools, out1 = attend(p1, x + _dense_mlp(cfg, p0, h), pools, 2 * layer + 1)
+            x = x + _dense_mlp(cfg, p1, rms_norm(x, p1["mlp_norm"], cfg.rms_norm_eps)) + m
+            return (out_of(x), pools), (jax.tree.map(lambda a, b: jnp.stack([a, b]), out0, out1), stats)
+
+        (x, pools), (out, stats) = jax.lax.scan(body, (x, pools), (sliced, jnp.arange(nm)))
+        return x, pools, jax.tree.map(lambda a: a.reshape((nd,) + a.shape[2:]), out), stats
+
+    def make_body(held):
+        def body(carry, inputs):
+            x, pools = carry
+            p, layer = inputs
+            x, pools, out = attend(p, x, pools, layer)
+            y, stats = _ffn(cfg, p, x, valid, held, layer)
+            return (out_of(x + y), pools), (out, stats)
+
+        return body
+
+    carry, y_d = jax.lax.scan(make_body(None), (x, pools), (params["dense_layers"], jnp.arange(nd)))
+    (x, pools), y_m = jax.lax.scan(make_body(held), carry, (sliced, jnp.arange(nd, nd + nm)))
+    out, stats = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), y_d, y_m)
+    return x, pools, out, stats
 
 
 def _finish(params: Params, cfg: DecoderConfig, x_last: jnp.ndarray) -> jnp.ndarray:
@@ -596,31 +679,23 @@ def prefill(params: Params, cfg: DecoderConfig, input_ids: jnp.ndarray, lengths:
     if select:  # right-padded: causal and real keeps real queries on real keys
         ok = (jnp.arange(S)[None, None, :] <= qpos[:, :, None]) & valid[:, :, None]
 
-    def make_body(held):
-        def body(x, inputs):
-            p, layer = inputs
-            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
-            out = (row,)
-            if lm.index_topk:
-                q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
-            if select:
-                keep = sparse_select(q_idx, w_idx, k_idx, qpos, ok, lm.index_topk)
-                o = _expanded_attention(cfg, p, q_nope, q_rope, row, keep=keep, live=lengths)
-                selected = keep.sum()
-            else:
-                # right-padded input: causal masking alone keeps real queries on real keys
-                o = _expanded_attention(cfg, p, q_nope, q_rope, row, causal=True)
-                selected = jnp.where(valid, qpos + 1, 0).sum()
-            x = x + _attn_out(cfg, p, o)
-            y, stats = _ffn(cfg, p, x, valid, held, layer)
-            if lm.index_topk:
-                out = (row, k_idx, _dsa_counts(valid, qpos, selected))
-            return with_constraint(x + y, ("batch", "length", "embed")), (out, stats)
+    def attend(p, x, pools, row_at):
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
+        if lm.index_topk:
+            q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
+        if select:
+            keep = sparse_select(q_idx, w_idx, k_idx, qpos, ok, lm.index_topk)
+            o = _expanded_attention(cfg, p, q_nope, q_rope, row, keep=keep, live=lengths)
+            selected = keep.sum()
+        else:
+            # right-padded input: causal masking alone keeps real queries on real keys
+            o = _expanded_attention(cfg, p, q_nope, q_rope, row, causal=True)
+            selected = jnp.where(valid, qpos + 1, 0).sum()
+        out = (row, k_idx, _dsa_counts(valid, qpos, selected)) if lm.index_topk else (row,)
+        return x + _attn_out(cfg, p, o), pools, out
 
-        return body
-
-    x, (out, stats) = _scan_stacks(cfg, params, make_body, x)
+    x, _, out, stats = _scan_layers(cfg, params, attend, valid, x, None, constrain=True)
     last = jnp.take_along_axis(x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
     if lm.index_topk:
         return _finish(params, cfg, last), (out[0], out[1]), _stats_row(cfg, stats, out[2], 1)
@@ -720,33 +795,27 @@ def _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, 
         mask = (jnp.arange(S)[None, None, None, :] <= pos[:, None, :, None])  # [B,1,C,S]
     x = _embed(params, cfg, input_ids)
 
-    def make_body(held):
-        def body(carry, inputs):
-            x, pool, ipool = carry
-            p, layer = inputs
-            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
+    def attend(p, x, pools, layer):
+        pool, ipool = pools
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
+        if lm.index_topk:
+            q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
+        with jax.named_scope("attn/kv_write"):
+            pool = pool.at[layer, phys, off].set(row.astype(pool.dtype), mode="drop")
             if lm.index_topk:
-                q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
-            with jax.named_scope("attn/kv_write"):
-                pool = pool.at[layer, phys, off].set(row.astype(pool.dtype), mode="drop")
-                if lm.index_topk:
-                    ipool = ipool.at[layer, phys, off].set(k_idx.astype(ipool.dtype), mode="drop")
-            if select:
-                o, selected = _sparse_attention_over_pages(
-                    cfg, p, q_nope, q_rope, q_idx, w_idx, pool, ipool, layer, block_tables, pos, ok, live)
-            else:
-                with jax.named_scope("attn/kv_read"):
-                    rows = _gather_rows(pool, layer, block_tables)
-                o = _expanded_attention(cfg, p, q_nope, q_rope, rows, mask=mask)
-                selected = jnp.where(real, pos + 1, 0).sum()
-            x = x + _attn_out(cfg, p, o)
-            y, stats = _ffn(cfg, p, x, real, held, layer)
-            return (x + y, pool, ipool), (stats, _dsa_counts(real, pos, selected) if lm.index_topk else None)
+                ipool = ipool.at[layer, phys, off].set(k_idx.astype(ipool.dtype), mode="drop")
+        if select:
+            o, selected = _sparse_attention_over_pages(
+                cfg, p, q_nope, q_rope, q_idx, w_idx, pool, ipool, layer, block_tables, pos, ok, live)
+        else:
+            with jax.named_scope("attn/kv_read"):
+                rows = _gather_rows(pool, layer, block_tables)
+            o = _expanded_attention(cfg, p, q_nope, q_rope, rows, mask=mask)
+            selected = jnp.where(real, pos + 1, 0).sum()
+        return x + _attn_out(cfg, p, o), (pool, ipool), _dsa_counts(real, pos, selected) if lm.index_topk else None
 
-        return body
-
-    (x, pool, ipool), (stats, dsa) = _scan_stacks(cfg, params, make_body, (x, cache.kv, cache.idx))
+    x, (pool, ipool), dsa, stats = _scan_layers(cfg, params, attend, real, x, (cache.kv, cache.idx))
     last = jnp.take_along_axis(x, jnp.maximum(valids - 1, 0)[:, None, None], axis=1)[:, 0]
     return _finish(params, cfg, last), pool, ipool, _stats_row(cfg, stats, dsa, 0 if chunk else 1)
 
@@ -847,61 +916,55 @@ def decode_step_paged(
     if lm.index_topk and not select:  # the counters' selected pairs where the view keeps everything
         every_pair = jnp.where(active, positions + 1, 0).sum()
 
-    def make_body(held):
-        def body(carry, inputs):
-            x, pool, ipool = carry
-            p, layer = inputs
-            h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
-            q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
-            with jax.named_scope("attn/absorb"):
-                q_abs = _mm("bhd,hdc->bhc", q_nope[:, 0], p["w_uk"], cfg.dtype)
-                pad = W - C - dr
-                q = jnp.concatenate(
-                    [q_abs, q_rope[:, 0]] + ([jnp.zeros((B, H, pad), q_abs.dtype)] if pad else []), axis=-1
-                )
-            if lm.index_topk:
-                q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
-            if kernel:
-                keep = None
-                if select:
-                    scores, ipool = paged_index_scores(
-                        q_idx[:, 0], w_idx[:, 0], k_idx[:, 0], ipool, layer, block_tables, positions, plan)
-                    keep = topk_select_paged(scores, active, lm.index_topk)
-                    with jax.named_scope("attn/select"):
-                        selected = keep.sum()
-                elif lm.index_topk:  # everything is attended: the key is kept for a longer view's sake
-                    with jax.named_scope("attn/kv_write"):
-                        ipool = ipool.at[layer, phys_w, off].set(k_idx[:, 0].astype(ipool.dtype), mode="drop")
-                o_lat, pool = latent_decode_update_attend(
-                    q, row[:, 0], pool, layer, block_tables, positions, plan, scale=scale, value_width=C, keep=keep
-                )
-            else:
+    def attend(p, x, pools, layer):
+        pool, ipool = pools
+        h = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+        q_nope, q_rope, row, c_q = _queries_and_row(cfg, p, h, cos, sin)
+        with jax.named_scope("attn/absorb"):
+            q_abs = _mm("bhd,hdc->bhc", q_nope[:, 0], p["w_uk"], cfg.dtype)
+            pad = W - C - dr
+            q = jnp.concatenate(
+                [q_abs, q_rope[:, 0]] + ([jnp.zeros((B, H, pad), q_abs.dtype)] if pad else []), axis=-1
+            )
+        if lm.index_topk:
+            q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
+        if kernel:
+            keep = None
+            if select:
+                scores, ipool = paged_index_scores(
+                    q_idx[:, 0], w_idx[:, 0], k_idx[:, 0], ipool, layer, block_tables, positions, plan)
+                keep = topk_select_paged(scores, active, lm.index_topk)
+                with jax.named_scope("attn/select"):
+                    selected = keep.sum()
+            elif lm.index_topk:  # everything is attended: the key is kept for a longer view's sake
                 with jax.named_scope("attn/kv_write"):
-                    pool = pool.at[layer, phys_w, off].set(row[:, 0].astype(pool.dtype), mode="drop")
-                    if lm.index_topk:
-                        ipool = ipool.at[layer, phys_w, off].set(k_idx[:, 0].astype(ipool.dtype), mode="drop")
-                if select:
-                    with jax.named_scope("attn/kv_read"):
-                        keys = _gather_rows(ipool, layer, block_tables)  # the index keys of the slot's pages
-                    idx, picked = sparse_decode_select(index_scores(q_idx, w_idx, keys)[:, 0], ok, lm.index_topk)
-                    o_lat = sparse_latent_decode_attention(
-                        q, pool, layer, block_tables, idx, picked, scale=scale, value_width=C)
-                    selected = picked.sum()
-                else:
-                    o_lat = latent_decode_attention(
-                        q, jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False), block_tables, positions,
-                        scale=scale, value_width=C, active=active,
-                    )
-            with jax.named_scope("attn/absorb"):
-                o = _mm("bhc,hdc->bhd", o_lat, p["w_uv"], cfg.dtype)
-            x = x + _attn_out(cfg, p, o.reshape(B, 1, H * dv))
-            y, stats = _ffn(cfg, p, x, valid, held, layer)
-            dsa = _dsa_counts(active, positions, selected if select else every_pair) if lm.index_topk else None
-            return (x + y, pool, ipool), (stats, dsa)
+                    ipool = ipool.at[layer, phys_w, off].set(k_idx[:, 0].astype(ipool.dtype), mode="drop")
+            o_lat, pool = latent_decode_update_attend(
+                q, row[:, 0], pool, layer, block_tables, positions, plan, scale=scale, value_width=C, keep=keep
+            )
+        else:
+            with jax.named_scope("attn/kv_write"):
+                pool = pool.at[layer, phys_w, off].set(row[:, 0].astype(pool.dtype), mode="drop")
+                if lm.index_topk:
+                    ipool = ipool.at[layer, phys_w, off].set(k_idx[:, 0].astype(ipool.dtype), mode="drop")
+            if select:
+                with jax.named_scope("attn/kv_read"):
+                    keys = _gather_rows(ipool, layer, block_tables)  # the index keys of the slot's pages
+                idx, picked = sparse_decode_select(index_scores(q_idx, w_idx, keys)[:, 0], ok, lm.index_topk)
+                o_lat = sparse_latent_decode_attention(
+                    q, pool, layer, block_tables, idx, picked, scale=scale, value_width=C)
+                selected = picked.sum()
+            else:
+                o_lat = latent_decode_attention(
+                    q, jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False), block_tables, positions,
+                    scale=scale, value_width=C, active=active,
+                )
+        with jax.named_scope("attn/absorb"):
+            o = _mm("bhc,hdc->bhd", o_lat, p["w_uv"], cfg.dtype)
+        dsa = _dsa_counts(active, positions, selected if select else every_pair) if lm.index_topk else None
+        return x + _attn_out(cfg, p, o.reshape(B, 1, H * dv)), (pool, ipool), dsa
 
-        return body
-
-    (x, pool, ipool), (stats, dsa) = _scan_stacks(cfg, params, make_body, (x, cache.kv, cache.idx))
+    x, (pool, ipool), dsa, stats = _scan_layers(cfg, params, attend, valid, x, (cache.kv, cache.idx))
     new_cache = LatentKVCache(
         kv=pool,
         lengths=jnp.where(active, cache.lengths + 1, cache.lengths),

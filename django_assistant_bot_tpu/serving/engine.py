@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import DecoderConfig, EncoderConfig, encoder, module_for
+from ..models.mixtral import zero_stat_width
 from ..ops.attention import FLASH_BLOCK
 from ..ops.sampling import sample_logits
 from ..parallel.sharding import mesh_scope
@@ -3159,13 +3160,17 @@ class GenerationEngine:
         routed picks and of those that landed on experts held here, tokens per
         held expert, layer-steps run and distinct held experts hit in them;
         ``experts_skipped_share``: 1 - hit / (held x layer-steps), the share of
-        held experts no token landed on, which the ``kernel`` path never reads."""
+        held experts no token landed on, which the ``kernel`` path never reads.
+        Where the router has identity experts, also ``picks_zero`` (picks that
+        fell on them: they cost nothing) and ``real_picks_hist`` (tokens by
+        their number of real picks, 0..top-k: the spread of compute a token)."""
         lm = getattr(self.cfg, "latent_moe", None)
         if lm is None:
             return None
         tot = self._moe_totals
+        zero_at = 4 + lm.experts_held  # the identity experts' counters follow the held experts' tokens
         if tot is None:
-            tot = np.zeros((2, 4 + lm.experts_held), np.int64)
+            tot = np.zeros((2, zero_at + zero_stat_width(self.cfg)), np.int64)
         out = {"experts_held": lm.experts_held, "first_expert": lm.first_expert,
                "router_experts": lm.router_experts, "ep_size": lm.ep_size, "ep_rank": lm.ep_rank}
         for row, kind in enumerate(("decode", "prefill")):
@@ -3175,6 +3180,11 @@ class GenerationEngine:
                 "tokens_per_expert": [int(v) for v in tot[row, 4:4 + lm.experts_held]],
                 "experts_skipped_share": round(1.0 - float(tot[row, 3]) / max(1, lm.experts_held * int(tot[row, 2])), 4),
             }
+            if lm.zero_experts:
+                out[kind]["picks_zero"] = int(tot[row, zero_at])
+                out[kind]["real_picks_hist"] = [int(v) for v in tot[row, zero_at + 1:zero_at + zero_stat_width(self.cfg)]]
+        if lm.zero_experts:
+            out["zero_experts"] = lm.zero_experts
         return out
 
     def dsa_stats(self) -> Optional[dict]:

@@ -1126,6 +1126,10 @@ def render_prometheus(registry: Any) -> str:
                 x.add("dabt_moe_experts_skipped_share", "gauge", "held experts no token landed on, of held x layer-steps (the kernel path does not read them)", moe[kind]["experts_skipped_share"], klab)
                 for e, n in enumerate(moe[kind]["tokens_per_expert"]):
                     x.add("dabt_moe_expert_tokens_total", "counter", "tokens routed to a held expert", n, {**klab, "expert": str(moe["first_expert"] + e)})
+                if "picks_zero" in moe[kind]:  # a router with identity experts: picks that cost nothing
+                    x.add("dabt_moe_picks_zero_total", "counter", "routed picks that fell on identity (zero-compute) experts", moe[kind]["picks_zero"], klab)
+                    for n_real, tokens in enumerate(moe[kind]["real_picks_hist"]):
+                        x.add("dabt_moe_real_picks_tokens_total", "counter", "tokens by their number of picks on real experts (the spread of compute a token)", tokens, {**klab, "real_picks": str(n_real)})
         dsa_fn = getattr(eng, "dsa_stats", None)
         dsa = dsa_fn() if callable(dsa_fn) else None
         if dsa:

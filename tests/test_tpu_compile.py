@@ -587,6 +587,98 @@ def test_latent_moe_chunk_prefill_reads_the_held_experts_in_place(topo, monkeypa
 
 
 # ---------------------------------------------------------------------------
+# the module's second block form, the shortcut-connected double layer, at the
+# benchmark configuration's widths, depth and serving geometry
+# (benchmarks/configs/longcat-flash-omni-ep32.json: hidden 6144, 64 heads, 4 double
+# layers, 16 of 512 routed experts held beside 256 identity experts, 32 slots x 2,048)
+# ---------------------------------------------------------------------------
+
+SC_LAYER_EXPERTS = ["16,6144,2048", "16,2048,6144"]  # one layer's held experts: gate / up, down
+SC_SUBLAYER_FFN = ["2,6144,12288", "2,12288,6144", "6144,12288", "12288,6144"]  # a dense FFN matrix, of both sublayers or one
+
+
+def _scmoe_cfg():
+    import json
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                        "benchmarks", "configs", "longcat-flash-omni-ep32.json")
+    with open(path) as f:
+        return DecoderConfig.from_hf(json.load(f)["hf"], dtype=jnp.bfloat16)
+
+
+def test_double_layer_fused_tick_reads_every_weight_where_it_lies(topo, monkeypatch):
+    """The tick of the double layer at the cell's widths: both Pallas calls, the donated pool (two rows a layer)
+    aliased through, temporaries that hold no copy of a sublayer's weights (with the sublayers' stack as the scan's
+    ``xs`` in ``[layers, 2, ...]`` form the compiler copied both sublayers' FFN matrices out a layer: 302 MB of
+    temporaries, three such copies a layer a step), no layer-sized copy of the pool or of the held experts."""
+    from django_assistant_bot_tpu.models import mla_moe
+    from django_assistant_bot_tpu.ops.sampling import sample_logits
+
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    cfg = _scmoe_cfg()
+    one = SingleDeviceSharding(topo.devices[0])
+    params, cache = _latent_moe_args(cfg, one)
+    assert cache.kv.shape == (8, LM_PAGES, PAGE, 640)
+
+    def tick(params, tokens, cache, active, bt, temps, top_ps, rng):
+        def body(carry, _):
+            tokens, cache, rng = carry
+            p = jax.lax.optimization_barrier(params)
+            rng, sub = jax.random.split(rng)
+            logits, cache = mla_moe.decode_step_paged(p, cfg, tokens, cache, bt, active=active)
+            nxt = sample_logits(logits, sub, temperature=temps, top_k=0, top_p=top_ps)
+            return (nxt, cache, rng), nxt
+
+        (tokens, cache, rng), toks = jax.lax.scan(body, (tokens, cache, rng), None, length=8)
+        return toks, tokens, cache, rng
+
+    args = (
+        params, _sds((LM_SLOTS,), jnp.int32, one), cache, _sds((LM_SLOTS,), jnp.bool_, one),
+        _sds((LM_SLOTS, MAX_SEQ // PAGE), jnp.int32, one), _sds((LM_SLOTS,), jnp.float32, one),
+        _sds((LM_SLOTS,), jnp.float32, one), _sds((2,), jnp.uint32, one),
+    )
+    compiled = jax.jit(tick, donate_argnums=(2,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_decode" in text and "held_experts" in text
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * 8 * LM_PAGES * PAGE * 640
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # weights 10.35 GB + pool 0.67 GB resident; among the temporaries no second pool and no weight
+    assert 10.8e9 < mem.argument_size_in_bytes < 11.3e9
+    assert mem.temp_size_in_bytes < 64e6
+    assert "constant_dynamic-slice_fusion" not in text
+    layer = f"{LM_PAGES},{PAGE},640"
+    assert _pool_sized_values_made_in_loops(text, [layer, f"8,{layer}"]) == []
+    assert _pool_sized_values_made_in_loops(text, SC_LAYER_EXPERTS) == []
+    # a sublayer's weights are the scan body's own slice of the stack and feed the dot: nothing writes one out
+    writes = [l for l in _pool_sized_values_made_in_loops(text, SC_SUBLAYER_FFN) if " fusion(" not in l and " dynamic-slice(" not in l]
+    assert writes == []
+
+
+def test_double_layer_prefill_takes_the_flash_kernel_and_fits(topo, monkeypatch):
+    """One admission's prefill at the cell's larger bucket: the flash kernel at key width 192 (padded to 256), the
+    grouped call over the held experts' live tiles (picks on identity experts are in none), and temporaries
+    that fit beside 11.0 GB of weights and pool."""
+    from django_assistant_bot_tpu.models import mla_moe
+
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    cfg = _scmoe_cfg()
+    one = SingleDeviceSharding(topo.devices[0])
+    params, _ = _latent_moe_args(cfg, one)
+    compiled = (
+        jax.jit(lambda p, i, n: mla_moe.prefill(p, cfg, i, n))
+        .lower(params, _sds((1, 1024), jnp.int32, one), _sds((1,), jnp.int32, one))
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "flash_attention" in text and "held_experts" in text
+    assert _pool_sized_values_made_in_loops(text, SC_LAYER_EXPERTS) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes + 0.67e9 < 14.5e9
+
+
+# ---------------------------------------------------------------------------
 # the same block with a lightning indexer and top-k sparse attention, at the
 # benchmark configuration's widths and serving geometry and a depth of two
 # (benchmarks/configs/deepseek-v3.2-ep16.json: 128 heads, 8 slots x 16,384,
