@@ -29,7 +29,8 @@ The block, per layer (``n`` RMSNorm; ``cfg.latent_moe`` has the sizes):
   the ``index_topk`` positions ``s <= t`` of largest ``I`` (all of them below
   that many), exactly.  The cache holds the index key beside the latent row
   (``LatentKVCache.idx``, the same block tables).  Prefill scores and selects a
-  tile at a time and attends under the selection as a mask
+  tile at a time, over the part of the view its live keys reach (and not at
+  all while every key is kept), and attends under the selection as a mask
   (``ops.attention.sparse_select`` / ``sparse_attention``); a decode step
   scores the slot's index keys, takes the top-k and gathers ONLY the selected
   latent rows.  With ``index_topk`` 0 none of this exists: the same weights,
@@ -133,10 +134,11 @@ Params = Dict[str, Any]
 
 KV_KIND = "latent"
 # counters of the sparse attention, the trailing columns of ``LatentKVCache.stats``
-# where the block selects: [programs, queries, causal pairs, selected pairs] of
-# decode steps (row 0), and of chunk programs then every other prefill program
-# (row 1), each a layer's worth (every layer selects as many)
-DSA_STAT = 8
+# where the block selects: [programs, queries, causal pairs, selected pairs,
+# pairs the selection's counting ran over] of decode steps (row 0), and of chunk
+# programs then every other prefill program (row 1), each a layer's worth
+# (every layer selects as many)
+DSA_STAT = 10
 
 
 def kv_kind(cfg: DecoderConfig) -> str:
@@ -445,9 +447,10 @@ def _index_parts(cfg: DecoderConfig, p: Params, h: jnp.ndarray, c_q: jnp.ndarray
     return q_idx, w_idx, k_idx
 
 
-def _dsa_counts(real: jnp.ndarray, qpos: jnp.ndarray, keep_sum) -> jnp.ndarray:
-    """One layer's [queries, causal pairs, selected pairs] over the real queries."""
-    return jnp.stack([real.sum(), jnp.where(real, qpos + 1, 0).sum(), keep_sum]).astype(jnp.int32)
+def _dsa_counts(real: jnp.ndarray, qpos: jnp.ndarray, keep_sum, scanned) -> jnp.ndarray:
+    """One layer's [queries, causal pairs, selected pairs] over the real queries,
+    and the (query, position) pairs its selection counted over (0: all kept)."""
+    return jnp.stack([real.sum(), jnp.where(real, qpos + 1, 0).sum(), keep_sum, scanned]).astype(jnp.int32)
 
 
 def _stats_row(cfg: DecoderConfig, moe_layers: jnp.ndarray, dsa_layers, kind: int) -> jnp.ndarray:
@@ -522,13 +525,15 @@ def _expanded_attention(cfg: DecoderConfig, p: Params, q_nope, q_rope, rows, *, 
 def _sparse_attention_over_pages(cfg: DecoderConfig, p: Params, q_nope, q_rope, q_idx, w_idx, pool, ipool, layer,
                                  block_tables, pos, ok, live):
     """A chunk's sparse attention over its rows' pages -> (o [B,C,H*dv], pairs
-    kept).  The view is built a PAGE at a time, and only the pages that hold a
+    kept, pairs the selection counted over).  The view is built a PAGE at a time, and only the pages that hold a
     live key (``live`` [B]: keys below it can be kept): the page's latent rows
     and index keys are read where they lie, the rows expanded into keys and
     values, and all three written into the view's buffers in place.  What a
     chunk costs then follows the context held, not ``max_seq_len``: dead pages
-    are never gathered or expanded, and on a TPU their part of the buffers is
-    never written or read (the two kernels skip tiles past the live keys)."""
+    are never gathered or expanded, on a TPU their part of the buffers is
+    never written or read (the two kernels skip tiles past the live keys), and
+    the selection scores and counts the live keys in steps, or nothing where
+    they are within ``index_topk`` (``ops.attention.sparse_select``)."""
     lm = cfg.latent_moe
     B, C = q_nope.shape[:2]
     L, P, page, W = pool.shape
@@ -553,9 +558,9 @@ def _sparse_attention_over_pages(cfg: DecoderConfig, p: Params, q_nope, q_rope, 
                     jax.lax.dynamic_update_slice_in_dim(vb, v, at, 2), jax.lax.dynamic_update_slice_in_dim(ib, keys, at, 1))
 
     kb, vb, ib = jax.lax.fori_loop(0, jnp.max(-(-live // page)), add_page, view)
-    keep = sparse_select(q_idx, w_idx, ib, pos, ok, lm.index_topk)
+    keep, scanned = sparse_select(q_idx, w_idx, ib, pos, ok, lm.index_topk, live)
     o = sparse_attention(_expanded_queries(cfg, q_nope, q_rope), kb, vb, keep, live, scale=softmax_scale(cfg))  # attn/sparse_core
-    return o.transpose(0, 2, 1, 3).reshape(B, C, H * dv), keep.sum()
+    return o.transpose(0, 2, 1, 3).reshape(B, C, H * dv), keep.sum(), scanned
 
 
 @jax.named_scope("attn/out")
@@ -685,14 +690,14 @@ def prefill(params: Params, cfg: DecoderConfig, input_ids: jnp.ndarray, lengths:
         if lm.index_topk:
             q_idx, w_idx, k_idx = _index_parts(cfg, p, h, c_q, cos, sin)
         if select:
-            keep = sparse_select(q_idx, w_idx, k_idx, qpos, ok, lm.index_topk)
+            keep, scanned = sparse_select(q_idx, w_idx, k_idx, qpos, ok, lm.index_topk, lengths)
             o = _expanded_attention(cfg, p, q_nope, q_rope, row, keep=keep, live=lengths)
             selected = keep.sum()
         else:
             # right-padded input: causal masking alone keeps real queries on real keys
             o = _expanded_attention(cfg, p, q_nope, q_rope, row, causal=True)
-            selected = jnp.where(valid, qpos + 1, 0).sum()
-        out = (row, k_idx, _dsa_counts(valid, qpos, selected)) if lm.index_topk else (row,)
+            selected, scanned = jnp.where(valid, qpos + 1, 0).sum(), 0
+        out = (row, k_idx, _dsa_counts(valid, qpos, selected, scanned)) if lm.index_topk else (row,)
         return x + _attn_out(cfg, p, o), pools, out
 
     x, _, out, stats = _scan_layers(cfg, params, attend, valid, x, None, constrain=True)
@@ -770,7 +775,8 @@ def _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, 
     selection: index scores reduced over the indexer's heads a tile at a time,
     the top-k as a threshold found by counting, the attention as a flash
     kernel that takes the selection as its mask, so that nothing of [heads,
-    chunk, context] size exists.  Only positions below a query's own and on an
+    chunk, context] size exists; scores and counting run over the live part of
+    the view, in static steps.  Only positions below a query's own and on an
     allocated page can be selected: a reused page's stale rows lie past the
     slot's position.  -> (logits, latent pool, index pool or None, counters)."""
     B, C = input_ids.shape
@@ -806,14 +812,15 @@ def _prefill_against_cache(params, cfg, input_ids, cache, block_tables, starts, 
             if lm.index_topk:
                 ipool = ipool.at[layer, phys, off].set(k_idx.astype(ipool.dtype), mode="drop")
         if select:
-            o, selected = _sparse_attention_over_pages(
+            o, selected, scanned = _sparse_attention_over_pages(
                 cfg, p, q_nope, q_rope, q_idx, w_idx, pool, ipool, layer, block_tables, pos, ok, live)
         else:
             with jax.named_scope("attn/kv_read"):
                 rows = _gather_rows(pool, layer, block_tables)
             o = _expanded_attention(cfg, p, q_nope, q_rope, rows, mask=mask)
-            selected = jnp.where(real, pos + 1, 0).sum()
-        return x + _attn_out(cfg, p, o), (pool, ipool), _dsa_counts(real, pos, selected) if lm.index_topk else None
+            selected, scanned = jnp.where(real, pos + 1, 0).sum(), 0
+        dsa = _dsa_counts(real, pos, selected, scanned) if lm.index_topk else None
+        return x + _attn_out(cfg, p, o), (pool, ipool), dsa
 
     x, (pool, ipool), dsa, stats = _scan_layers(cfg, params, attend, real, x, (cache.kv, cache.idx))
     last = jnp.take_along_axis(x, jnp.maximum(valids - 1, 0)[:, None, None], axis=1)[:, 0]
@@ -961,7 +968,12 @@ def decode_step_paged(
                 )
         with jax.named_scope("attn/absorb"):
             o = _mm("bhc,hdc->bhd", o_lat, p["w_uv"], cfg.dtype)
-        dsa = _dsa_counts(active, positions, selected if select else every_pair) if lm.index_topk else None
+        if not lm.index_topk:
+            dsa = None
+        elif select:  # a step's selection counts over the whole view of every active row
+            dsa = _dsa_counts(active, positions, selected, active.sum() * S)
+        else:
+            dsa = _dsa_counts(active, positions, every_pair, 0)
         return x + _attn_out(cfg, p, o.reshape(B, 1, H * dv)), (pool, ipool), dsa
 
     x, (pool, ipool), dsa, stats = _scan_layers(cfg, params, attend, valid, x, (cache.kv, cache.idx))

@@ -1630,6 +1630,20 @@ def sparse_attention(q, kt, v, keep: jnp.ndarray, live: jnp.ndarray, *, scale: f
     return jnp.where(keep.any(-1)[:, None, :, None], o, 0.0).astype(o.dtype)
 
 
+def select_widths(S: int, topk: int, tile: int) -> tuple[int, ...]:
+    """The view lengths a prefill's selection can run over, ascending, the last
+    one ``S``: the multiples of a step that lie above ``topk`` (a view within
+    ``topk`` keeps everything and counts nothing), the step the smallest ``tile
+    * 2**i`` that leaves at most 7 of them (2,048 ... 16,384 by 2,048 at
+    ``S`` 16,384, ``topk`` 2,048 and tiles of 512)."""
+    step = tile
+    while True:
+        widths = sorted({min(S, n * step) for n in range(topk // step + 1, -(-S // step) + 1)})
+        if len(widths) <= 7:
+            return tuple(widths)
+        step *= 2
+
+
 def sparse_select(
     q_idx: jnp.ndarray,  # [B, C, Hi, Di]
     w_idx: jnp.ndarray,  # [B, C, Hi] f32
@@ -1637,21 +1651,46 @@ def sparse_select(
     qpos: jnp.ndarray,  # [B, C] int32 position of each query
     ok: jnp.ndarray,  # [B, C, S] bool: the keys a query may attend at all (causal, written, real query)
     topk: int,
-) -> jnp.ndarray:
-    """-> keep [B, C, S] bool: for each query the ``topk`` keys of largest index
-    score among those ``ok`` marks (all of them where there are fewer)."""
+    live: jnp.ndarray,  # [B] int32: ``ok`` marks no key at or past it for any query of the row
+):
+    """-> (keep [B, C, S] bool, pairs scanned): for each query the ``topk`` keys
+    of largest index score among those ``ok`` marks (all of them where there
+    are fewer), and the (query, position) pairs the counting ran over.
+
+    The scores and the counting follow the keys that are live, in static steps
+    (:func:`select_widths`): of the view's ``S`` positions only the first
+    ``width >= max(live)`` are scored and counted, one branch of a conditional a
+    width, and where ``max(live) <= topk`` nothing is, since every ``ok`` key is
+    kept.  The positions cut off are not ``ok``: they entered the counts as
+    zeros and were never kept, so ``keep`` is the whole view's, bit for bit."""
     B, C, Hi, Di = q_idx.shape
     S = k_idx.shape[1]
     if S <= topk:
-        return ok
-    if sparse_kernel_shaped(C, S, Di, Di):
-        scores = index_scores_t(q_idx, w_idx, k_idx, qpos[:, 0])  # [B, S, C]: the selection reduces over the major axis
-        with jax.named_scope("attn/select"):
-            ok_t = ok.transpose(0, 2, 1)
-        keep_t = topk_mask(scores, topk, ok_t, axis=1)
-        with jax.named_scope("attn/select"):
-            return keep_t.transpose(0, 2, 1)
-    return topk_mask(index_scores(q_idx, w_idx, k_idx), topk, ok, axis=2)
+        return ok, jnp.int32(0)
+    kernel = sparse_kernel_shaped(C, S, Di, Di)
+    widths = select_widths(S, topk, 512 if kernel else 8)  # the index kernel's key tile; a sublane tile
+
+    def over(width):
+        def branch(q_idx, w_idx, k_idx, qpos, ok):
+            k, ok = k_idx[:, :width], ok[..., :width]
+            if kernel:
+                scores = index_scores_t(q_idx, w_idx, k, qpos[:, 0])  # [B, width, C]: the selection reduces over the major axis
+                with jax.named_scope("attn/select"):
+                    ok_t = ok.transpose(0, 2, 1)
+                keep_t = topk_mask(scores, topk, ok_t, axis=1)
+                with jax.named_scope("attn/select"):
+                    keep = keep_t.transpose(0, 2, 1)
+            else:
+                keep = topk_mask(index_scores(q_idx, w_idx, k), topk, ok, axis=2)
+            with jax.named_scope("attn/select"):
+                return jnp.pad(keep, ((0, 0), (0, 0), (0, S - width)))
+
+        return branch
+
+    # branch 0 keeps every ``ok`` key; branch i counts over widths[i - 1], the first that holds every live key
+    n = jnp.sum(jnp.max(live) > jnp.asarray((topk,) + widths[:-1], jnp.int32))
+    keep = jax.lax.switch(n, [lambda *args: args[-1]] + [over(w) for w in widths], q_idx, w_idx, k_idx, qpos, ok)
+    return keep, jnp.asarray((0,) + widths, jnp.int32)[n] * (B * C)
 
 
 def sparse_decode_select(scores: jnp.ndarray, ok: jnp.ndarray, topk: int):

@@ -3192,17 +3192,21 @@ class GenerationEngine:
         ``dabt_dsa_*``), or None for a block without an indexer: running totals,
         for decode steps, chunk programs and every other prefill program apart,
         of programs run, queries, the (query, key) pairs a dense causal
-        attention would attend and the pairs the selection kept, each of ONE
-        layer (every layer selects as many).  Summed on the device and handed
-        out with a tick's tokens, as the ``moe`` counters are."""
+        attention would attend, the pairs the selection kept and the (query,
+        position) pairs its counting ran over (``pairs_scanned``: a chunk's
+        queries x the step of the view its live keys reach, 0 where they are
+        within ``index_topk`` and all are kept; a decode step's rows x the
+        view), each of ONE layer (every layer selects as many).  Summed on the
+        device and handed out with a tick's tokens, as the ``moe`` counters are."""
         lm = getattr(self.cfg, "latent_moe", None)
         if lm is None or not lm.index_topk:
             return None
         tot = self._moe_totals
         tail = np.zeros((2, self._model.DSA_STAT), np.int64) if tot is None else tot[:, 4 + lm.experts_held:]
-        names = ("programs", "queries", "pairs_causal", "pairs_selected")
+        names = ("programs", "queries", "pairs_causal", "pairs_selected", "pairs_scanned")
         out: dict = {"index_topk": lm.index_topk}
-        for kind, vals in (("decode", tail[0, :4]), ("chunk", tail[1, :4]), ("prefill", tail[1, 4:8])):
+        n = len(names)  # DSA_STAT is two such groups: a chunk program's, then any other prefill program's
+        for kind, vals in (("decode", tail[0, :n]), ("chunk", tail[1, :n]), ("prefill", tail[1, n:2 * n])):
             out[kind] = {k: int(v) for k, v in zip(names, vals)}
         return out
 

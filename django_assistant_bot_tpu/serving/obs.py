@@ -1142,6 +1142,7 @@ def render_prometheus(registry: Any) -> str:
                 x.add("dabt_dsa_queries_total", "counter", "queries scored by the indexer", dsa[kind]["queries"], klab)
                 x.add("dabt_dsa_pairs_causal_total", "counter", "(query, key) pairs a dense causal attention attends, a layer", dsa[kind]["pairs_causal"], klab)
                 x.add("dabt_dsa_pairs_selected_total", "counter", "(query, key) pairs the selection kept, a layer", dsa[kind]["pairs_selected"], klab)
+                x.add("dabt_dsa_pairs_scanned_total", "counter", "(query, position) pairs the selection's counting ran over, a layer", dsa[kind]["pairs_scanned"], klab)
         dec_fn = getattr(eng, "decode_path_stats", None)
         if callable(dec_fn):
             # decode fast-path gauges (docs/QUANT.md): configured vs

@@ -15,6 +15,7 @@ compared directly too.
 """
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -106,12 +107,14 @@ def test_prefill_logits_equal_the_plain_reference_at_contexts_several_times_the_
     want = _reference(family, conf, [s[:n] + [0] for s, n in zip(seqs, lengths)])
     for i in range(2):
         np.testing.assert_allclose(np.asarray(logits[i]), want[i][-1], atol=ATOL)
-    assert rows.shape == (3, 2, 48, 128) and keys.shape == (3, 2, 48, 16) and stats.shape == (4 + 16 + 8,)
+    assert rows.shape == (3, 2, 48, 128) and keys.shape == (3, 2, 48, 16) and stats.shape == (4 + 16 + 10,)
     # the sparse attention's counters, a layer's worth, in the columns of "every other prefill program"
     n = np.asarray(lengths)
     causal = int((n * (n + 1) // 2).sum())
     kept = int(sum(sum(min(8, t + 1) for t in range(m)) for m in lengths))
-    assert [int(x) for x in stats[-8:]] == [0, 0, 0, 0, 1, int(n.sum()), causal, kept]
+    # the selection counted over the view up to the step the longest row reaches: both rows' 48 queries x that
+    scanned = 2 * 48 * min(w for w in A.select_widths(48, 8, 8) if w >= max(lengths))
+    assert [int(x) for x in stats[-10:]] == [0, 0, 0, 0, 0, 1, int(n.sum()), causal, kept, scanned]
     assert float(np.abs(want[0][-1]).max()) > 1.0  # logits are O(1): the tolerance means something
 
 
@@ -154,8 +157,8 @@ def test_prefill_then_paged_decode_equals_the_reference_at_every_position(family
         np.testing.assert_allclose(np.asarray(logits[2]), want[1][n0[1] + k], atol=ATOL)
     assert [int(x) for x in cache.lengths] == [45, 0, 37, 0]  # frozen slots wrote nothing
     # decode's counters: 25 steps, 2 rows each, every row past the selection keeps exactly index_topk
-    steps, queries, causal, kept = (int(x) for x in cache.stats[0, -8:-4])
-    assert (steps, queries, kept) == (25, 50, 50 * 8)
+    steps, queries, causal, kept, scanned = (int(x) for x in cache.stats[0, -10:-5])
+    assert (steps, queries, kept, scanned) == (25, 50, 50 * 8, 50 * 64)  # a step counts over an active row's whole view
     assert causal == sum(n + k + 1 for n in n0 for k in range(25))
     assert int(cache.stats[0, 2]) == 25 * 2  # the routed layers' counters stay where they were
 
@@ -176,10 +179,12 @@ def test_chunked_prefill_against_the_cache_equals_the_reference(family, chunk):
             params, cfg, jnp.asarray([ids]), cache, bt_row, jnp.int32(1), jnp.int32(start), jnp.int32(valid))
     np.testing.assert_allclose(np.asarray(logits[0]), want, atol=ATOL)
     assert int(cache.lengths[1]) == 53
-    programs, queries, causal, kept = (int(x) for x in cache.stats[1, -8:-4])  # the chunk programs' columns
+    programs, queries, causal, kept, scanned = (int(x) for x in cache.stats[1, -10:-5])  # the chunk programs' columns
     assert (programs, queries, causal) == (-(-53 // chunk), 53, 53 * 54 // 2)
     assert kept == sum(min(8, t + 1) for t in range(53))
-    assert not np.asarray(cache.stats[1, -4:]).any()
+    # every chunk counts over the view up to the first step (8 positions each here) at or past its last key
+    assert scanned == chunk * sum(-(-min(start + chunk, 53) // 8) * 8 for start in range(0, 53, chunk))
+    assert not np.asarray(cache.stats[1, -5:]).any()
     # the sliding last chunk of the engine re-feeds positions already written: the same rows, the same answer
     again, cache = mla_moe.prefill_chunk_paged(
         params, cfg, jnp.asarray([s[53 - chunk:]]), cache, bt_row, jnp.int32(1), jnp.int32(53 - chunk), jnp.int32(chunk))
@@ -206,7 +211,7 @@ def test_suffix_prefill_and_copy_pages_carry_the_index_keys(family):
     logits, cache = mla_moe.prefill_suffix_paged(
         params, cfg, suffix, cache, bts, jnp.asarray([0, 2]), jnp.asarray([24, 0]), jnp.asarray([29, 0]))
     np.testing.assert_allclose(np.asarray(logits[0]), want, atol=ATOL)
-    assert [int(x) for x in cache.stats[1, -4:]] == [1, 29, sum(range(25, 54)), 29 * 8]
+    assert [int(x) for x in cache.stats[1, -5:]] == [1, 29, sum(range(25, 54)), 29 * 8, 2 * 32 * 56]
 
 
 def test_a_reused_pages_stale_rows_are_never_selected(family):
@@ -251,7 +256,7 @@ def test_with_index_topk_at_least_the_context_the_block_is_the_dense_one(family)
     ca, bt = _paged(cfg)
     cb, _ = _paged(plain_cfg)
     st = jnp.zeros((4 + 16,), jnp.int32)
-    ca = mla_moe.insert_sequences_paged(ca, (rows, keys), jnp.zeros((28,), jnp.int32), lengths, jnp.asarray([0, 2]), bt[jnp.asarray([0, 2])])
+    ca = mla_moe.insert_sequences_paged(ca, (rows, keys), jnp.zeros((4 + 16 + mla_moe.DSA_STAT,), jnp.int32), lengths, jnp.asarray([0, 2]), bt[jnp.asarray([0, 2])])
     cb = mla_moe.insert_sequences_paged(cb, rows_b, st, lengths, jnp.asarray([0, 2]), bt[jnp.asarray([0, 2])])
     toks, active = jnp.asarray([7, 0, 9, 0], jnp.int32), jnp.asarray([True, False, True, False])
     la, _ = mla_moe.decode_step_paged(params, cfg, toks, ca, bt, active=active)
@@ -335,13 +340,99 @@ def test_the_programs_selected_sets_equal_the_references(topk):
     starts = np.asarray([40, 3])
     qpos = jnp.asarray(starts[:, None] + np.arange(16)[None, :])
     ok = jnp.arange(64)[None, None, :] <= qpos[:, :, None]
-    keep = A.sparse_select(q, w, k, qpos, ok, topk)
+    keep, _ = A.sparse_select(q, w, k, qpos, ok, topk, jnp.asarray(starts + 16))
     with jax.default_matmul_precision("highest"):
         si = jnp.einsum("bqhk,bqh->bqk", jnp.maximum(jnp.einsum("bqhd,bkd->bqhk", q, k), 0.0), w)
     want, _ = ref.select_block(si, ok, topk)
     np.testing.assert_array_equal(np.asarray(keep), np.asarray(want))
     n = np.asarray(keep).sum(-1)
     np.testing.assert_array_equal(n, np.minimum(topk, np.asarray(qpos) + 1))
+
+
+def _whole_view_select(q, w, k, qpos, ok, topk, live=None, *, kernel=False):
+    """The selection as it ran before it followed the live keys: scores and counting over the WHOLE view."""
+    if kernel:
+        scores = A.index_scores_t(q, w, k, qpos[:, 0], interpret=True)
+        return A.topk_mask(scores, topk, ok.transpose(0, 2, 1), axis=1).transpose(0, 2, 1)
+    return A.topk_mask(A.index_scores(q, w, k), topk, ok, axis=2)
+
+
+# (view, queries a row, index width, topk): the CPU's own path, steps of 8, and the TPU's (the index kernel
+# interpreted, steps of its 512-key tile); ``live`` [2] per case: the second row's is never the longer one but once
+SELECT_SHAPES = {"plain": (64, 16, 16, 8), "kernel": (2048, 256, 128, 512)}
+SELECT_LIVE = {
+    "plain": {"within-topk": [8, 3], "topk+1": [9, 9], "under-an-edge": [31, 30], "at-an-edge": [32, 32],
+              "over-an-edge": [33, 12], "the-second-row-longer": [12, 41], "whole-view": [64, 50]},
+    "kernel": {"within-topk": [512, 100], "topk+1": [513, 513], "under-an-edge": [1023, 700], "at-an-edge": [1024, 1024],
+               "over-an-edge": [1025, 700], "the-second-row-longer": [700, 1537], "whole-view": [2048, 1500]},
+}
+_SELECT_JIT = {}
+
+
+@pytest.mark.parametrize("case", list(SELECT_LIVE["plain"]))
+@pytest.mark.parametrize("path", ["plain", "kernel"])
+def test_the_stepped_selection_equals_the_whole_views_bit_for_bit(monkeypatch, path, case):
+    """``sparse_select`` scores and counts only up to the step its rows' live keys reach, and nothing at all where
+    they are within ``topk``: the mask is ``topk_mask``'s over the whole view, entry for entry, with every key in
+    the view twice (so most k-th values are shared and the tie rule decides) and weights of both signs."""
+    S, C, Di, topk = SELECT_SHAPES[path]
+    kernel = path == "kernel"
+    if kernel:
+        monkeypatch.setattr(A, "sparse_kernel_shaped", lambda *a: True)
+        monkeypatch.setattr(A, "index_scores_t", functools.partial(A.index_scores_t, interpret=True))
+    widths = A.select_widths(S, topk, 512 if kernel else 8)
+    assert widths == ((1024, 1536, 2048) if kernel else (16, 24, 32, 40, 48, 56, 64))
+    q, w, k = _index_inputs(B=2, C=C, S=S, Hi=4, Di=Di, seed=7)
+    k = k.at[:, 1::2].set(k[:, 0::2])
+    live = np.asarray(SELECT_LIVE[path][case])
+    starts = np.maximum(live - C, 0)
+    qpos = jnp.asarray(starts[:, None] + np.arange(C)[None, :], jnp.int32)
+    real = np.arange(C)[None, :] < (live - starts)[:, None]
+    ok = (jnp.arange(S)[None, None, :] <= qpos[:, :, None]) & jnp.asarray(real)[:, :, None]
+    if path not in _SELECT_JIT:
+        _SELECT_JIT[path] = (jax.jit(functools.partial(A.sparse_select, topk=topk)),
+                             jax.jit(functools.partial(_whole_view_select, topk=topk, kernel=kernel)))
+    stepped, whole = _SELECT_JIT[path]
+    keep, scanned = stepped(q, w, k, qpos, ok, live=jnp.asarray(live, jnp.int32))
+    want = whole(q, w, k, qpos, ok)
+    np.testing.assert_array_equal(np.asarray(keep), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(keep).sum(-1), np.where(real, np.minimum(topk, np.asarray(qpos) + 1), 0))
+    width = 0 if live.max() <= topk else min(x for x in widths if x >= live.max())
+    assert int(scanned) == 2 * C * width
+    if live.max() > topk + 1:  # some query's k-th value is shared with the key's twin just outside the selection
+        sc = np.asarray(A.index_scores(q, w, k))
+        kth = np.sort(np.where(np.asarray(ok), sc, -np.inf), -1)[..., -topk]
+        assert ((np.where(np.asarray(ok), sc, -np.inf) == kth[..., None]).sum(-1) > 1)[np.isfinite(kth)].any() and (sc < 0).any()
+
+
+@pytest.mark.parametrize("chunk", [24, 16])
+def test_chunk_logits_and_pairs_selected_equal_the_whole_view_selections(family, monkeypatch, chunk):
+    """``_prefill_against_cache`` over a prompt of three and four chunks: logits and the pairs kept are those of
+    the same program with the selection run over the whole view, bit for bit; only ``pairs_scanned`` differs."""
+    conf = _conf()
+    cfg, params = _program(family, conf)
+    s = _ids(53, 5)
+    bt_row = jnp.asarray([9, 1, 4, 2, 7, 11, 3, 0], jnp.int32)
+
+    def run():
+        cache, out = mla_moe.init_paged_cache(cfg, 2, 16, 8), []
+        for start in range(0, 53, chunk):
+            valid = min(chunk, 53 - start)
+            ids = (s[start:start + valid] + [0] * chunk)[:chunk]
+            logits, cache = mla_moe.prefill_chunk_paged(
+                params, cfg, jnp.asarray([ids]), cache, bt_row, jnp.int32(1), jnp.int32(start), jnp.int32(valid))
+            out.append(np.asarray(logits))
+        return out, np.asarray(cache.stats[1, -10:-5]), cache
+
+    stepped, counts, cache = run()
+    monkeypatch.setattr(mla_moe, "sparse_select", lambda *a: (_whole_view_select(*a), a[4].shape[1] * a[4].shape[2]))
+    whole, counts_whole, cache_whole = run()
+    for a, b in zip(stepped, whole):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(counts[:4], counts_whole[:4])
+    assert counts_whole[4] == -(-53 // chunk) * chunk * 64 and 0 < counts[4] < counts_whole[4]
+    for a, b in zip(jax.tree.leaves(cache._replace(stats=None)), jax.tree.leaves(cache_whole._replace(stats=None))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("axis", [1, 2])
@@ -590,6 +681,6 @@ def test_the_kernel_decode_step_equals_the_plain_step_and_the_reference_at_every
         for a, b in zip(jax.tree.leaves(kern), jax.tree.leaves(plain)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
     assert [int(x) for x in kern.lengths] == [45, 0, 37, 0]
-    steps, queries, causal, kept = (int(x) for x in kern.stats[0, -8:-4])
+    steps, queries, causal, kept, scanned = (int(x) for x in kern.stats[0, -10:-5])
     assert causal == sum(n + k + 1 for n in n0 for k in range(25))
-    assert (steps, queries, kept) == (25, 50, 50 * 8 if selects else causal)
+    assert (steps, queries, kept, scanned) == (25, 50, 50 * 8 if selects else causal, 50 * 64 if selects else 0)

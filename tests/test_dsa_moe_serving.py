@@ -15,6 +15,7 @@ import pytest
 from benchmarks import families
 from django_assistant_bot_tpu.checkpoint import load_model, save_model
 from django_assistant_bot_tpu.models import DecoderConfig, mla_moe
+from django_assistant_bot_tpu.ops.attention import select_widths
 from django_assistant_bot_tpu.serving.registry import ModelRegistry
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -22,20 +23,33 @@ DATA = os.path.join(os.path.dirname(HERE), "benchmarks")
 SEED = 40_000_017
 
 
-def _conf():
+def _conf(**hf):
     with open(os.path.join(HERE, "data", "dsa_moe_tiny.json")) as f:
-        return json.load(f)
+        conf = json.load(f)
+    conf["hf"].update(hf)
+    return conf
 
 
-@pytest.fixture(scope="module")
-def served(tmp_path_factory):
-    conf = _conf()
+def _scanned(view, topk, queries, lives):
+    """What the chunk and prefill programs report as ``pairs_scanned``: ``queries`` x the first step of the
+    view (``select_widths``; the CPU's steps are multiples of 8) at or past a program's last live key, and
+    nothing for a program whose keys are all within ``topk``."""
+    widths = select_widths(view, topk, 8)
+    return sum(queries * min(w for w in widths if w >= live) for live in lives if live > topk)
+
+
+def _serve(tmp_path_factory, conf):
     family = families.load(conf, DATA)
     cfg = dataclasses.replace(DecoderConfig.from_hf(conf["hf"], dtype=jnp.float32), max_seq_len=256)
     params = jax.tree.map(lambda x: x.astype(jnp.float32), family.served_params(conf, SEED))
     path = str(tmp_path_factory.mktemp("dsa_moe") / "ckpt")
     save_model(path, "decoder", cfg, params)
     return conf, family, cfg, params, path
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    return _serve(tmp_path_factory, _conf())
 
 
 def _spec(path, **over):
@@ -90,12 +104,17 @@ def test_engine_streams_the_references_greedy_tokens_and_counts_the_pairs(served
             assert dsa[kind]["programs"] == 3 and dsa[kind]["queries"] == 96
             assert dsa[kind]["pairs_causal"] == sum(t + 1 for s in starts for t in range(s, s + 32))
             assert dsa[kind]["pairs_selected"] == sum(min(8, t + 1) for s in starts for t in range(s, s + 32))
+            # every chunk holds more than index_topk keys: 32 queries x the step of the 128-position view it reaches
+            assert dsa[kind]["pairs_scanned"] == _scanned(128, 8, 32, [s + 32 for s in starts]) == 32 * (32 + 64 + 96)
         else:
+            bucket = min(b for b in eng.prefill_shapes if b >= n)  # one row of the bucket the prompt fits
             assert dsa[kind] == {"programs": 1, "queries": n, "pairs_causal": n * (n + 1) // 2,
-                                 "pairs_selected": sum(min(8, t + 1) for t in range(n))}
-        assert dsa[other] == {"programs": 0, "queries": 0, "pairs_causal": 0, "pairs_selected": 0}
+                                 "pairs_selected": sum(min(8, t + 1) for t in range(n)),
+                                 "pairs_scanned": _scanned(bucket, 8, bucket, [n])}
+        assert dsa[other] == {"programs": 0, "queries": 0, "pairs_causal": 0, "pairs_selected": 0, "pairs_scanned": 0}
         d = dsa["decode"]
         assert d["programs"] >= 7 and d["queries"] == d["programs"]
+        assert d["pairs_scanned"] == 128 * d["queries"]  # a step's selection counts over an active row's whole view
         # every step keeps index_topk keys.  The ticks issued behind the one that ends the request take the block
         # table the host holds when each is issued, with the pages or freed (a freed table names no page: nothing
         # is selectable).  So the first tick, which holds the request's 7 steps, keeps every key, and each later
@@ -111,6 +130,33 @@ def test_engine_streams_the_references_greedy_tokens_and_counts_the_pairs(served
 
         text = render_prometheus(reg)
         assert 'dabt_dsa_pairs_selected_total{' in text and 'kind="chunk"' in text and 'dabt_dsa_index_topk{' in text
+        for k in ("decode", "chunk", "prefill"):
+            line = next(ln for ln in text.splitlines() if ln.startswith("dabt_dsa_pairs_scanned_total{") and f'kind="{k}"' in ln)
+            assert float(line.rsplit(" ", 1)[1]) == dsa[k]["pairs_scanned"]
+    finally:
+        reg.stop()
+
+
+def test_a_chunk_whose_keys_are_all_kept_scans_nothing_and_the_later_ones_scan_their_step(tmp_path_factory):
+    """``index_topk`` 40 over chunks of 32: the first chunk (32 keys) keeps every causal key without a score or a
+    count, the second and third (64 and 75 live keys) count over 64 and 80 of the view's 128 positions; the
+    tokens are the reference's, so the stepped selection kept the reference's keys."""
+    conf, family, cfg, params, path = _serve(tmp_path_factory, _conf(index_topk=40))
+    reg = ModelRegistry.from_config(_spec(path))
+    try:
+        eng = reg.get_generator("m")
+        prompt = [int(t) for t in np.random.default_rng(75).integers(32, 127, 75)]
+        got = eng.submit(prompt, max_tokens=4, temperature=0.0).result(timeout=600)
+        got = list(getattr(got, "token_ids", got))[:4]
+        assert got == _greedy(family, conf, prompt, len(got))
+        dsa = eng.tick_stats()["dsa"]
+        starts = [0, 32, 43]
+        assert select_widths(128, 40, 8) == (48, 64, 80, 96, 112, 128)
+        assert dsa["chunk"]["programs"] == 3 and dsa["chunk"]["queries"] == 96
+        assert dsa["chunk"]["pairs_scanned"] == _scanned(128, 40, 32, [s + 32 for s in starts]) == 32 * (0 + 64 + 80)
+        assert dsa["chunk"]["pairs_selected"] == sum(min(40, t + 1) for s in starts for t in range(s, s + 32))
+        d = dsa["decode"]  # whole ticks only: the ticks behind the one that ends the request still count their steps
+        assert d["queries"] % eng.decode_steps == 0 and d["pairs_scanned"] == 128 * d["queries"]
     finally:
         reg.stop()
 
@@ -128,3 +174,29 @@ def test_a_block_without_an_indexer_reports_no_dsa_counters():
 def test_the_registry_refuses_speculation_and_a_prefix_cache_over_two_kinds_of_row(served, over, why):
     with pytest.raises(ValueError, match=why):
         ModelRegistry.from_config(_spec(served[4], **over))
+
+
+def test_the_timing_tool_runs_a_chunk_program_alone_at_any_start():
+    """``tools/time_prefill.py --config <a configuration with an indexer> --chunk``: the engine's own chunk program
+    over seeded pools, at a start whose keys are all within ``index_topk`` and at two past it; the donated cache
+    comes back each call, and its counters say which step of the view each chunk's selection reached."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("time_prefill", os.path.join(os.path.dirname(HERE), "tools", "time_prefill.py"))
+    tp = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tp)
+    conf = _conf(index_topk=40)
+    conf["weights"]["seed"] = SEED
+    conf["serving"].update(max_seq_len=128, chunk_size=32, kv_page_size=16, kv_pages=16, prefill_piggyback=False, warmup=False)
+    eng = tp.build_engine(conf)
+    try:
+        program = tp.chunk_program(eng)
+        for start in (0, 32, 96):
+            logits = program(1, start)()
+            assert logits.shape == (1, conf["hf"]["vocab_size"]) and np.isfinite(np.asarray(logits)).all()
+        with pytest.raises(ValueError, match="one row"):
+            program(1, 128)
+        programs, queries, _, _, scanned = (int(x) for x in program.cache().stats[1, -10:-5])
+        assert (programs, queries, scanned) == (3, 96, _scanned(128, 40, 32, [32, 64, 128])) and scanned == 32 * (64 + 128)
+    finally:
+        eng.stop()

@@ -482,34 +482,60 @@ def _lowered(hf, program):
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
-@pytest.mark.parametrize("config", ["mla_moe_tiny", "dsa_moe_tiny"])
-def test_the_other_two_blocks_compile_to_the_operations_they_did_before_the_double_layer(config, program):
-    """With the new fields at their defaults the compiled programs are unchanged: the operation counts of the
-    optimised HLO (CPU, the tests' tiny sizes) of the decode step, the one-shot prefill and the chunk prefill, against
-    the counts taken on the commit before this block form was added (``tests/data/mla_moe_hlo_ops.json``: made by
-    this file's ``_lowered`` and ``OP`` on that commit; a PR that changes these programs on purpose makes it anew
-    with ``PYTHONPATH=. JAX_PLATFORMS=cpu python tests/test_scmoe.py``)."""
+@pytest.mark.parametrize("config", ["mla_moe_tiny", "dsa_moe_tiny", "scmoe_tiny"])
+def test_the_blocks_compile_to_the_operations_they_did_before_a_change_to_another(config, program):
+    """A change to one block form leaves the others' compiled programs alone: the operation counts of the optimised
+    HLO (CPU, the tests' tiny sizes) of the decode step, the one-shot prefill and the chunk prefill, against
+    ``tests/data/mla_moe_hlo_ops.json`` (made by this file's ``_lowered`` and ``OP``).  ``mla_moe_tiny``: the counts
+    of the commit before the double layer was added; ``scmoe_tiny``: of the commit before the selection followed the
+    live keys (PR 45), which changed ``dsa_moe_tiny``'s three programs on purpose (its counts are that PR's).  A PR
+    that changes a block's programs on purpose makes that block's counts anew with ``PYTHONPATH=. JAX_PLATFORMS=cpu
+    python tests/test_scmoe.py <config>``."""
     with open(os.path.join(HERE, "data", config + ".json")) as f:
         hf = json.load(f)["hf"]
     with open(os.path.join(HERE, "data", "mla_moe_hlo_ops.json")) as f:
         before = json.load(f)[config][program]
-    text = _lowered(hf, program).compile().as_text()
-    now = {}
+    assert _op_counts(_lowered(hf, program).compile().as_text()) == before
+
+
+def _op_counts(text):
+    counts = {}
     for op in OP.findall(text):
-        now[op] = now.get(op, 0) + 1
-    assert now == before
+        counts[op] = counts.get(op, 0) + 1
+    return dict(sorted(counts.items()))
 
 
-if __name__ == "__main__":  # the operation counts of the tree as it stands -> tests/data/mla_moe_hlo_ops.json
-    out = {}
-    for config in ("mla_moe_tiny", "dsa_moe_tiny"):
+def test_the_sparse_chunk_program_selects_in_one_conditional_over_the_steps_of_its_view():
+    """The chunk program of the block with an indexer (24 queries against a view of 64, ``index_topk`` 8): each of
+    its two scan bodies holds ONE conditional of ``1 + len(select_widths)`` branches (the others are ``topk_mask``'s
+    two-way tie rule, one a counting branch); a counting branch's sortable keys exist at its step's width and at no
+    other, and the branch taken while every key is kept makes no score and no key at all."""
+    from django_assistant_bot_tpu.ops.attention import select_widths
+
+    with open(os.path.join(HERE, "data", "dsa_moe_tiny.json")) as f:
+        hf = json.load(f)["hf"]
+    text = _lowered(hf, "prefill_chunk_paged").compile().as_text()
+    widths = select_widths(64, hf["index_topk"], 8)
+    assert widths == (16, 24, 32, 40, 48, 56, 64)
+    branches = [m.split(", ") for m in re.findall(r" conditional\(.*?branch_computations=\{([^}]*)\}", text)]
+    switches = [b for b in branches if len(b) > 2]
+    assert [len(b) for b in switches] == [1 + len(widths)] * 2 and len(branches) == 2 * (1 + len(widths))
+    for switch in switches:
+        bodies = [re.search(rf"^{re.escape(name)} .*?^}}", text, re.M | re.S).group(0) for name in switch]
+        assert not re.search(r"(f32|u32)\[", bodies[0])  # every key kept: no score, no key to count over
+        for body, width in zip(bodies[1:], widths):  # the counting's sortable keys: [queries, this step of the view]
+            assert set(re.findall(r"u32\[1,24,(\d+)\]", body)) == {str(width), "1"}
+
+
+if __name__ == "__main__":  # the named configurations' operation counts as the tree stands -> tests/data/mla_moe_hlo_ops.json
+    import sys
+
+    path = os.path.join(HERE, "data", "mla_moe_hlo_ops.json")
+    with open(path) as f:
+        out = json.load(f)
+    for config in sys.argv[1:]:
         with open(os.path.join(HERE, "data", config + ".json")) as f:
             hf = json.load(f)["hf"]
-        out[config] = {}
-        for program in PROGRAMS:
-            counts = {}
-            for op in OP.findall(_lowered(hf, program).compile().as_text()):
-                counts[op] = counts.get(op, 0) + 1
-            out[config][program] = dict(sorted(counts.items()))
-    with open(os.path.join(HERE, "data", "mla_moe_hlo_ops.json"), "w") as f:
+        out[config] = {program: _op_counts(_lowered(hf, program).compile().as_text()) for program in PROGRAMS}
+    with open(path, "w") as f:
         json.dump(out, f, indent=1, sort_keys=True)

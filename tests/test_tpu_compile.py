@@ -748,6 +748,32 @@ def test_sparse_chunk_prefill_makes_nothing_of_heads_x_chunk_x_context_size(topo
     assert _pool_sized_values_made_in_loops(text, _dsa_pool_shapes(cfg)) == []
 
 
+def test_sparse_chunk_selection_is_one_conditional_over_the_live_steps_of_the_view(topo, monkeypatch):
+    """``sparse_select`` alone at the cell's shapes (1,024 queries, 64 index heads of 128, a view of 16,384,
+    ``index_topk`` 2,048): ONE conditional of 8 branches; seven score and count, the index kernel lowered at 4,096 ...
+    16,384 keys by 2,048 and the float32 scores of each at that width and no wider; the branch for ``live <=
+    index_topk`` holds no float32 ``[view, queries]`` operand (no score is made, nothing is counted)."""
+    monkeypatch.setattr(attn.jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(topo.devices[0])
+    C, S, Hi, Di, topk = 1024, DSA_SEQ, 64, 128, 2048
+    widths = attn.select_widths(S, topk, 512)
+    assert widths == tuple(range(4096, S + 1, 2048))
+    text = (
+        jax.jit(lambda q, w, k, qpos, ok, live: attn.sparse_select(q, w, k, qpos, ok, topk, live))
+        .lower(_sds((1, C, Hi, Di), jnp.bfloat16, one), _sds((1, C, Hi), jnp.float32, one), _sds((1, S, Di), jnp.bfloat16, one),
+               _sds((1, C), jnp.int32, one), _sds((1, C, S), jnp.bool_, one), _sds((1,), jnp.int32, one))
+        .compile().as_text()
+    )
+    branches = [m.split(", ") for m in re.findall(r" conditional\(.*?branch_computations=\{([^}]*)\}", text)]
+    (switch,) = [b for b in branches if len(b) > 2]
+    assert len(switch) == 1 + len(widths) and len(branches) == 1 + len(widths)  # and a two-way tie rule a counting branch
+    bodies = [re.search(rf"^{re.escape(name)} .*?^}}", text, re.M | re.S).group(0) for name in switch]
+    assert not re.search(r"f32\[", bodies[0]) and "tpu_custom_call" not in bodies[0]
+    for body, width in zip(bodies[1:], widths):
+        assert "index_scores" in body
+        assert set(re.findall(rf"f32\[1,(\d+),{C}\]", body)) == {str(width)}
+
+
 def test_sparse_decode_step_follows_the_live_pages_in_three_pallas_calls(topo, monkeypatch):
     """The decode step with an indexer: index scores, the selection by counting
     and the attention under it lower for the v5e as Pallas calls over the

@@ -10,6 +10,8 @@ kernel alone, by shape: what sets ``ops/attention.py`` ``flash_tiles``.
     python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --decode                 # the decode tick alone
     python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --decode --trace 2x8192  # ... and by scope
     python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --decode --trace 2x8192 --hlo  # ... and what each operation reads
+    python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --chunk                  # a chunk program alone, by its start
+    python3 tools/time_prefill.py --config deepseek-v3.2-ep16 --chunk --trace 1x0,1x8192  # ... and by scope
 
 Builds the engine of ``benchmarks/configs/<config>.json`` (default
 ``qwen2.5-7b-instruct``: Qwen2.5-7B int8; the configuration's seeded weights,
@@ -41,6 +43,13 @@ optimised HLO of the engine's ``jit_tick`` goes to
 step is printed with its scope, the parameter leaf it reads and the bytes it
 reads and writes (``tools/hlo_table.py``; ``"table"`` in the JSON): how PERF.md
 section 5's table of PR 43 was made.
+
+``--chunk`` times the chunk program alone (``_prefill_chunk``: one row of
+``chunk_size`` tokens against the slot's pages, over a seeded pool) with the
+chunk starting at 0, a quarter, a half and ``max_seq_len - 3 * chunk_size``
+(0 / 4,096 / 8,192 / 13,312 for ``deepseek-v3.2-ep16``): ``{"1x<start>": ms}``
+to ``chiprun_out/time_chunk.<config>.json``; with ``--trace 1x<start>[,...]``
+by scope and operation, to ``chiprun_out/trace_chunk.<config>.json``.
 """
 
 import argparse
@@ -161,6 +170,17 @@ def prefill_program(eng: GenerationEngine):
     return program
 
 
+def seeded_cache(eng: GenerationEngine):
+    """The engine's cache, taken from it (the programs timed here donate it: the
+    engine's own reference must not outlive that), its pools filled once with
+    seeded values (a zero pool ties every index score)."""
+    cache, eng._cache = eng._cache, None
+    keys = iter(jax.random.split(jax.random.key(0), 4))
+    fill = jax.jit(lambda key, like: (jax.random.normal(key, like.shape, jnp.float32) * 0.5).astype(like.dtype))
+    return cache._replace(**{name: fill(next(keys), pool) for name, pool in cache._asdict().items()
+                             if name not in ("lengths", "stats") and pool is not None})
+
+
 def decode_program(eng: GenerationEngine):
     """-> ``program(rows, context)``: the engine's decode tick over ``rows``
     slots that each hold ``context`` tokens, as a call that runs one tick
@@ -168,14 +188,8 @@ def decode_program(eng: GenerationEngine):
     are filled once with seeded values (a zero pool ties every index score);
     slot ``i`` owns an equal share of the pages; every call starts from ``context``
     again, so the ticks timed are the same tick."""
-    cache = eng._cache
-    eng._cache = None  # donated below: the engine's own reference must not outlive it
-    keys = iter(jax.random.split(jax.random.key(0), 4))
-    fill = jax.jit(lambda key, like: (jax.random.normal(key, like.shape, jnp.float32) * 0.5).astype(like.dtype))
-    pools = {name: fill(next(keys), pool) for name, pool in cache._asdict().items()
-             if name not in ("lengths", "stats") and pool is not None}
-    state = {"cache": cache._replace(**pools), "rng": eng._rng}
-    B, NB, pages = eng.max_slots, eng.max_seq_len // eng.kv_page_size, cache.n_pages
+    state = {"cache": seeded_cache(eng), "rng": eng._rng}
+    B, NB, pages = eng.max_slots, eng.max_seq_len // eng.kv_page_size, state["cache"].n_pages
     bt = np.full((B, NB), pages, np.int32)
     per_slot = min(pages // B, NB)
     for i in range(B):
@@ -202,6 +216,39 @@ def decode_program(eng: GenerationEngine):
     program.hlo = lambda: eng._decode_tick.lower(
         eng.params, tokens, state["cache"], jnp.arange(B) < 1, bt, temps, top_ps, state["rng"]).compile().as_text()
     return program
+
+
+def chunk_program(eng: GenerationEngine):
+    """-> ``program(1, start)``: the engine's chunk program on ``chunk_size``
+    tokens at positions ``start ...`` of slot 0, which owns the first pages of
+    pools filled once with seeded values, as a call that keeps the donated
+    cache for the next."""
+    state = {"cache": seeded_cache(eng)}
+    C, NB = eng.chunk_size, eng.max_seq_len // eng.kv_page_size
+    bt_row = jnp.arange(NB, dtype=jnp.int32)
+    ids = jnp.asarray(np.random.default_rng(0).integers(32, 127, size=(1, C)), jnp.int32)
+
+    def program(rows: int, start: int):
+        if rows != 1 or start + C > eng.max_seq_len:
+            raise ValueError(f"a chunk is one row of {C} tokens inside {eng.max_seq_len}, got {rows}x{start}")
+        args = (jnp.asarray(0, jnp.int32), jnp.asarray(start, jnp.int32), jnp.asarray(C, jnp.int32))
+
+        def call():
+            logits, state["cache"] = eng._prefill_chunk(eng.params, ids, state["cache"], bt_row, *args)
+            return logits
+
+        return call
+
+    program.cache = lambda: state["cache"]  # the cache as the last call left it: its counters say what ran
+    return program
+
+
+def time_chunks(eng: GenerationEngine) -> dict:
+    program, out = chunk_program(eng), {}
+    for start in (0, eng.max_seq_len // 4, eng.max_seq_len // 2, eng.max_seq_len - 3 * eng.chunk_size):
+        out[f"1x{start}"] = round(median_ms(program(1, start)), 3)
+        print(f"1x{start}", out[f"1x{start}"], flush=True)
+    return out
 
 
 def time_decode(eng: GenerationEngine) -> dict:
@@ -246,6 +293,7 @@ def main() -> int:
     ap.add_argument("--config", default="qwen2.5-7b-instruct", help="a file of benchmarks/configs/, without .json")
     ap.add_argument("--flash", action="store_true", help="time the flash kernel alone instead of the programs")
     ap.add_argument("--decode", action="store_true", help="time the decode tick alone, by rows and context, instead of the prefill programs")
+    ap.add_argument("--chunk", action="store_true", help="time the chunk program alone, by the chunk's start, instead of the prefill programs")
     ap.add_argument("--trace", metavar="ROWSxBUCKET[,...]", help="trace these programs instead: device ms a call by scope and operation")
     ap.add_argument("--hlo", action="store_true", help="with --decode --trace: write the tick's optimised HLO and list what each operation over 0.05 ms a step reads and writes")
     args = ap.parse_args()
@@ -268,6 +316,10 @@ def main() -> int:
         else:
             out = time_decode(eng)
         name = ("trace_decode" if args.trace else "time_decode") + suffix
+    elif args.chunk:
+        eng = build_engine(load_conf(args.config))
+        out = trace_programs(chunk_program(eng), args.trace, trace_dir) if args.trace else time_chunks(eng)
+        name = ("trace_chunk" if args.trace else "time_chunk") + suffix
     elif args.trace:
         out = trace_programs(prefill_program(build_engine(load_conf(args.config))), args.trace, trace_dir)
         name = "trace_prefill" + suffix
